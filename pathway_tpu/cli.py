@@ -30,6 +30,10 @@ def _spawn_once(program: list[str], threads: int, processes: int,
     terminates the survivors immediately — peer-death detection makes
     them abort on their own anyway (parallel/comm.py PeerLostError +
     poison broadcast), this just skips waiting out the heartbeat deadline.
+
+    Device ownership is deterministic: an accelerator belongs to one
+    process at a time, so process 0 inherits the parent's JAX platform
+    and every process >= 1 is started with ``JAX_PLATFORMS=cpu``.
     """
     import time
 
@@ -52,6 +56,8 @@ def _spawn_once(program: list[str], threads: int, processes: int,
     for pid in range(processes):
         env = dict(env_base)
         env["PATHWAY_PROCESS_ID"] = str(pid)
+        if pid >= 1:
+            env["JAX_PLATFORMS"] = "cpu"
         procs.append(subprocess.Popen(program, env=env))
     code = 0
     running = list(procs)
@@ -266,6 +272,12 @@ def main(argv: list[str] | None = None) -> int:
 
         return dashboard_main(argv[1:])
     args = parser.parse_args(argv)
+    if args.command not in ("spawn", "spawn-from-env"):
+        # commands that compile in this process keep what they compile;
+        # the spawn supervisor starts workers and stays off JAX
+        from .compile_cache import enable_compile_cache
+
+        enable_compile_cache()
     if args.command == "spawn":
         program = args.program
         if program and program[0] == "--":

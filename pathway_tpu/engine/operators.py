@@ -110,36 +110,12 @@ class StatelessRowwise(Operator):
         pad->jit->scatter device path); other columns row-evaluate."""
         build = self.env.build
         envs = [build(k, r) for k, r, _d in updates]
-        n = len(updates)
-        out_cols: dict[int, list] = {}
-        for i, e in self._batched:
-            arg_lists = []
-            ok_idx = []
-            results: list = [None] * n
-            for j, env in enumerate(envs):
-                vals = [a._eval(env) for a in e._args]
-                if any(isinstance(v, Error) for v in vals):
-                    results[j] = ERROR
-                elif e._propagate_none and any(v is None for v in vals):
-                    results[j] = None
-                else:
-                    arg_lists.append(vals[0] if len(vals) == 1 else tuple(vals))
-                    ok_idx.append(j)
-            if ok_idx:
-                try:
-                    batch_out = list(e._batch_fn(arg_lists))
-                    if len(batch_out) != len(ok_idx):
-                        raise ValueError(
-                            f"batch_fn returned {len(batch_out)} results for "
-                            f"{len(ok_idx)} inputs"
-                        )
-                except Exception:
-                    # per-row fallback: only genuinely-failing rows poison,
-                    # with error-log provenance (parity with the row path)
-                    batch_out = [e._eval(envs[j]) for j in ok_idx]
-                for j, v in zip(ok_idx, batch_out):
-                    results[j] = v
-            out_cols[i] = results
+        # per-row fallback inside: only genuinely-failing rows poison, with
+        # error-log provenance (parity with the row path)
+        out_cols: dict[int, list] = {
+            i: e._eval_batch(envs, row_fallback=True)
+            for i, e in self._batched
+        }
         out: list[Update] = []
         for j, (key, row, diff) in enumerate(updates):
             vals = []

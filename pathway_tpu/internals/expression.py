@@ -502,6 +502,41 @@ class ApplyExpression(ColumnExpression):
             return ERROR
 
 
+    def _eval_batch(self, envs: list, *, row_fallback: bool) -> list:
+        """One value per env through ONE ``batch_fn`` call (the device-UDF
+        path: pad -> one forward -> per-row results); rows whose arguments
+        are errors or propagated Nones never reach it.  ``row_fallback``:
+        when the batch call fails, evaluate its rows one by one so that
+        only the genuinely failing ones poison (the select operator's
+        parity with the row path); otherwise the failure propagates."""
+        out: list = [None] * len(envs)
+        args, slots = [], []
+        for j, env in enumerate(envs):
+            vals = [a._eval(env) for a in self._args]
+            if any(_is_err(v) for v in vals):
+                out[j] = ERROR
+            elif self._propagate_none and any(v is None for v in vals):
+                out[j] = None
+            else:
+                args.append(vals[0] if len(vals) == 1 else tuple(vals))
+                slots.append(j)
+        if slots:
+            try:
+                res = list(self._batch_fn(args))
+                if len(res) != len(slots):
+                    raise ValueError(
+                        f"batch_fn returned {len(res)} results for "
+                        f"{len(slots)} inputs"
+                    )
+            except Exception:
+                if not row_fallback:
+                    raise
+                res = [self._eval(envs[j]) for j in slots]
+            for j, v in zip(slots, res):
+                out[j] = v
+        return out
+
+
 class FullyAsyncApplyExpression(ApplyExpression):
     """Fully-async UDF: emits Pending first, result arrives as a later update."""
 

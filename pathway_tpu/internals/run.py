@@ -32,6 +32,9 @@ def run(
     sinks = list(pg.G.outputs)
     if not sinks:
         return
+    from ..compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from ..io._synchronization import apply_synchronization_groups
 
     apply_synchronization_groups()

@@ -259,20 +259,27 @@ class _Active:
 def build_engine(cfg, params, fallback_msg: str, logger_name: str,
                  engine_cls=None, **kwargs):
     """Construct a decode engine (:class:`PagedDecodeEngine` by default,
-    or ``engine_cls`` — e.g. kvcache.statecache.StateDecodeEngine), or
-    log at INFO and return None when it cannot be built — the shared
-    fallback shape for hosts whose serial tier keeps working
-    (JaxDecoderLM.paged_engine, Int8DecoderHost.paged_engine)."""
+    or ``engine_cls`` — e.g. kvcache.statecache.StateDecodeEngine).
+
+    The serial tier behind the callers (JaxDecoderLM.paged_engine,
+    Int8DecoderHost.paged_engine) is the CPU's: on a CPU backend an
+    engine that cannot be built logs at INFO and returns None, and the
+    caller keeps its serial loop.  On a TPU backend the engine IS the
+    serving path, so the failure raises with its cause attached — an HBM
+    misfit or a compiler refusal must not read as a working server."""
     cls = engine_cls or PagedDecodeEngine
     try:
         return cls(cfg, params, **kwargs)
-    except Exception as exc:  # noqa: BLE001 - the serial tier works
+    except Exception as exc:  # noqa: BLE001 - the CPU's serial tier works
+        what = "paged KV" if cls is PagedDecodeEngine else cls.__name__
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                f"{what} decode engine cannot be built on the TPU backend"
+            ) from exc
         import logging
 
         logging.getLogger(logger_name).info(
-            "%s decode engine unavailable (%s); %s",
-            "paged KV" if cls is PagedDecodeEngine else cls.__name__,
-            exc, fallback_msg,
+            "%s decode engine unavailable (%s); %s", what, exc, fallback_msg,
         )
         return None
 

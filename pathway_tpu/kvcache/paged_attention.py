@@ -56,17 +56,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops._tiling import pad_to as _pad_to
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e9
-
-try:  # pallas import is deferred-safe: fall back to the gather path
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
 
 
 def _query_context(C: int, context_lens, start_pos, n_valid):
@@ -156,12 +149,13 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def _paged_kernel(bt_ref, c0_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, block_size: int, scale: float):
     """Grid: (B, NB) — blocks innermost, so (m, l, acc) scratch carries the
     online softmax across one sequence's blocks.  Blocks: q (C, H, Dp);
-    o (H, C, Dp); k/v (block_size, H, Dp) — the physical block the
-    scalar-prefetched table maps grid step j to.  Blocks past the row's
+    o (H, C, Dp); k/v (block_size, H, Dp) — the physical block of layer
+    ``li`` that the scalar-prefetched table maps grid step j to (``li_ref``
+    is only read by the index maps).  Blocks past the row's
     context (``j > jlast``) are dead: the index map pins their DMA to the
     last valid block (Pallas elides the repeated copy) and every
     ``@pl.when`` below is false, so they cost nothing."""
@@ -223,33 +217,37 @@ def _paged_kernel(bt_ref, c0_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[:] = (acc_ref[:] / denom).astype(o_ref.dtype)
 
 
-def _paged_ragged_fn(q, k_pool, v_pool, block_tables, c0, cl, *,
+def _paged_ragged_fn(q, k_pool, v_pool, layer, block_tables, c0, cl, *,
                      d_true: int, interpret: bool = False):
-    """q: (B, C, H, Dp); pools (num_blocks, BS, H, Dp), Dp lane-padded;
-    c0/cl: (B,) per-row column-0 / last-column context lengths."""
+    """q: (B, C, H, Dp); pools (L, num_blocks, BS, H, Dp) — ALL layers'
+    stacked pool, read in place at ``layer`` ((1,) int32): the block index
+    maps carry the layer, so no layer is ever sliced out of the pool.  Dp
+    is the pool's own head_dim (Mosaic takes a minor dim that spans the
+    whole array, so nothing is lane-padded); c0/cl: (B,) per-row column-0
+    / last-column context lengths."""
     B, C, H, Dp = q.shape
-    BS = k_pool.shape[1]
+    BS = k_pool.shape[2]
     NB = block_tables.shape[1]
     kernel = functools.partial(
         _paged_kernel, block_size=BS, scale=1.0 / np.sqrt(d_true)
     )
 
-    def _kv_map(b, j, bt, c0, cl):
+    def _kv_map(b, j, li, bt, c0, cl):
         # ragged grid: clamp dead steps to the row's last valid block so
         # their DMA is elided (same index as the previous step)
-        return (bt[b, jnp.minimum(j, (cl[b] - 1) // BS)], 0, 0, 0)
+        return (li[0], bt[b, jnp.minimum(j, (cl[b] - 1) // BS)], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # block_tables, c0, cl
+        num_scalar_prefetch=4,  # layer, block_tables, c0, cl
         grid=(B, NB),
         in_specs=[
             pl.BlockSpec((None, C, H, Dp),
-                         lambda b, j, bt, c0, cl: (b, 0, 0, 0)),
-            pl.BlockSpec((None, BS, H, Dp), _kv_map),
-            pl.BlockSpec((None, BS, H, Dp), _kv_map),
+                         lambda b, j, li, bt, c0, cl: (b, 0, 0, 0)),
+            pl.BlockSpec((None, None, BS, H, Dp), _kv_map),
+            pl.BlockSpec((None, None, BS, H, Dp), _kv_map),
         ],
         out_specs=pl.BlockSpec((None, H, C, Dp),
-                               lambda b, j, bt, c0, cl: (b, 0, 0, 0)),
+                               lambda b, j, li, bt, c0, cl: (b, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((H, C, 128), jnp.float32),  # m
             pltpu.VMEM((H, C, 128), jnp.float32),  # l
@@ -261,7 +259,7 @@ def _paged_ragged_fn(q, k_pool, v_pool, block_tables, c0, cl, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, C, Dp), q.dtype),
         interpret=interpret,
-    )(block_tables, c0, cl, q, k_pool, v_pool)
+    )(layer, block_tables, c0, cl, q, k_pool, v_pool)
     return out.transpose(0, 2, 1, 3)  # (B, C, H, Dp)
 
 
@@ -281,8 +279,8 @@ def _make_paged_ragged():
 _paged_ragged = _make_paged_ragged()
 
 
-def _append_kernel(bt_ref, c0_ref, cl_ref, so_ref, q_ref, k1_ref, v1_ref,
-                   k_ref, v_ref, o_ref, ko_ref, vo_ref, m_ref, l_ref,
+def _append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, q_ref, k1_ref,
+                   v1_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref, m_ref, l_ref,
                    acc_ref, *, block_size: int, scale: float):
     """Round-17 fused append+attend (decode, C=1): the incoming token's
     K/V rides into the kernel as a (H, Dp) operand, is patched into the
@@ -359,47 +357,48 @@ def _append_kernel(bt_ref, c0_ref, cl_ref, so_ref, q_ref, k1_ref, v1_ref,
         o_ref[:] = (acc_ref[:] / denom).astype(o_ref.dtype)
 
 
-def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, block_tables, c0,
-                     cl, slot_offsets, *, d_true: int,
+def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, layer, block_tables,
+                     c0, cl, slot_offsets, *, d_true: int,
                      interpret: bool = False):
     """q: (B, 1, H, Dp); k_new/v_new: (B, H, Dp); pools
-    (num_blocks, BS, H, Dp) — returned UPDATED (aliased in place on
-    TPU).  Contract: the slot is the tail of the attended context
+    (L, num_blocks, BS, H, Dp) — ALL layers' stacked pool, returned
+    UPDATED at ``layer`` ((1,) int32), aliased in place on TPU: one tail
+    block per row is written, nothing else of the pool is touched or
+    copied.  Contract: the slot is the tail of the attended context
     (``slot_blocks[b] == block_tables[b, (cl[b]-1)//BS]`` and
     ``slot_offsets[b] == (cl[b]-1) % BS``) — the decode append the
     engine constructs by definition."""
     B, C, H, Dp = q.shape
-    BS = k_pool.shape[1]
+    BS = k_pool.shape[2]
     NB = block_tables.shape[1]
     kernel = functools.partial(
         _append_kernel, block_size=BS, scale=1.0 / np.sqrt(d_true)
     )
 
-    def _kv_map(b, j, bt, c0, cl, so):
-        return (bt[b, jnp.minimum(j, (cl[b] - 1) // BS)], 0, 0, 0)
+    def _kv_map(b, j, li, bt, c0, cl, so):
+        return (li[0], bt[b, jnp.minimum(j, (cl[b] - 1) // BS)], 0, 0, 0)
 
-    def _slot_map(b, j, bt, c0, cl, so):
+    def _slot_map(b, j, li, bt, c0, cl, so):
         # constant per row: the pool out-block IS the row's slot block
-        return (bt[b, (cl[b] - 1) // BS], 0, 0, 0)
+        return (li[0], bt[b, (cl[b] - 1) // BS], 0, 0, 0)
+
+    def _row(*tail):
+        return lambda b, j, li, bt, c0, cl, so: (b,) + tail
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,  # block_tables, c0, cl, slot_offsets
+        num_scalar_prefetch=5,  # layer, block_tables, c0, cl, slot_offsets
         grid=(B, NB),
         in_specs=[
-            pl.BlockSpec((None, C, H, Dp),
-                         lambda b, j, bt, c0, cl, so: (b, 0, 0, 0)),
-            pl.BlockSpec((None, H, Dp),
-                         lambda b, j, bt, c0, cl, so: (b, 0, 0)),
-            pl.BlockSpec((None, H, Dp),
-                         lambda b, j, bt, c0, cl, so: (b, 0, 0)),
-            pl.BlockSpec((None, BS, H, Dp), _kv_map),
-            pl.BlockSpec((None, BS, H, Dp), _kv_map),
+            pl.BlockSpec((None, C, H, Dp), _row(0, 0, 0)),
+            pl.BlockSpec((None, H, Dp), _row(0, 0)),
+            pl.BlockSpec((None, H, Dp), _row(0, 0)),
+            pl.BlockSpec((None, None, BS, H, Dp), _kv_map),
+            pl.BlockSpec((None, None, BS, H, Dp), _kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((None, H, C, Dp),
-                         lambda b, j, bt, c0, cl, so: (b, 0, 0, 0)),
-            pl.BlockSpec((None, BS, H, Dp), _slot_map),
-            pl.BlockSpec((None, BS, H, Dp), _slot_map),
+            pl.BlockSpec((None, H, C, Dp), _row(0, 0, 0)),
+            pl.BlockSpec((None, None, BS, H, Dp), _slot_map),
+            pl.BlockSpec((None, None, BS, H, Dp), _slot_map),
         ],
         scratch_shapes=[
             pltpu.VMEM((H, C, 128), jnp.float32),  # m
@@ -407,8 +406,8 @@ def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, block_tables, c0,
             pltpu.VMEM((H, C, Dp), jnp.float32),   # acc
         ],
     )
-    # alias indices count the scalar-prefetch operands: pools are
-    # operands 7/8 of (bt, c0, cl, so, q, k_new, v_new, k_pool, v_pool)
+    # alias indices count the scalar-prefetch operands: pools are operands
+    # 8/9 of (layer, bt, c0, cl, so, q, k_new, v_new, k_pool, v_pool)
     o, k_pool, v_pool = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -417,9 +416,10 @@ def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, block_tables, c0,
             jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
             jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
         ],
-        input_output_aliases={7: 1, 8: 2},
+        input_output_aliases={8: 1, 9: 2},
         interpret=interpret,
-    )(block_tables, c0, cl, slot_offsets, q, k_new, v_new, k_pool, v_pool)
+    )(layer, block_tables, c0, cl, slot_offsets, q, k_new, v_new, k_pool,
+      v_pool)
     return o.transpose(0, 2, 1, 3), k_pool, v_pool
 
 
@@ -438,79 +438,99 @@ def _make_paged_append():
 _paged_append = _make_paged_append()
 
 
+def _stacked(k_pool, v_pool, layer):
+    """Resolve the two pool conventions to (stacked pools, (1,) int32
+    layer): ``layer=None`` means one layer's (num_blocks, BS, H, hd)
+    slices (a leading unit axis is free); ``layer=i`` means the stacked
+    (L, num_blocks, BS, H, hd) pool of all layers, used in place."""
+    if layer is None:
+        return k_pool[None], v_pool[None], jnp.zeros((1,), jnp.int32)
+    return k_pool, v_pool, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _layer_of(pool, layer):
+    """One layer's (num_blocks, BS, H, hd) pool, for the gather reference."""
+    return pool if layer is None else pool[layer]
+
+
 def paged_append_attend(q, k_new, v_new, k_pool, v_pool, block_tables,
                         context_lens, slot_blocks, slot_offsets, *,
-                        use_pallas: bool | None = None,
+                        layer=None, use_pallas: bool | None = None,
                         interpret: bool | None = None):
-    """Fused decode append+attend over ONE layer's pool slices: scatter
+    """Fused decode append+attend over one layer of the pool: scatter
     the incoming token's K/V at ``(slot_blocks, slot_offsets)`` and
     attend through ``block_tables`` in a single program.
 
-    q: (B, 1, H, hd); k_new/v_new: (B, H, hd); pools
-    (num_blocks, BS, H, hd); block_tables (B, NB);
+    q: (B, 1, H, hd); k_new/v_new: (B, H, hd); block_tables (B, NB);
     context_lens/slot_blocks/slot_offsets: (B,) int32 with the slot at
     the context tail (``slot_offsets == (context_lens-1) % BS`` and
     ``slot_blocks`` the matching table entry — the decode-step layout).
-    Returns ``(attn_out, k_pool, v_pool)`` with the pools updated;
-    bit-identical to scatter-then-:func:`paged_attention_reference` on
-    the reference path (tier-1), one fused Pallas program on TPU (pool
-    blocks aliased in place — the standalone scatter disappears).
-    head_dim must already be a 128-multiple for the kernel path
-    (lane-padding would copy the pools and break the in-place append);
-    other shapes take the reference path."""
+    Pools: with ``layer=None`` one layer's (num_blocks, BS, H, hd)
+    slices; with ``layer=i`` the stacked (L, num_blocks, BS, H, hd) pool
+    of all layers, updated IN PLACE at layer i — the step programs use
+    this form, so that no layer is sliced out of the pool or written
+    back into it.  Returns ``(attn_out, k_pool, v_pool)`` with the pools
+    updated, in the form they came in; bit-identical to
+    scatter-then-:func:`paged_attention_reference` on the reference path
+    (tier-1), one fused Pallas program on TPU (pool blocks aliased in
+    place — the standalone scatter disappears).  The kernel runs at the
+    pool's own head_dim: nothing is padded."""
     backend = jax.default_backend()
     hd = q.shape[-1]
     if use_pallas is None:
-        use_pallas = _HAVE_PALLAS and backend == "tpu"
-    if not use_pallas or not _HAVE_PALLAS or hd % 128:
-        k_pool = k_pool.at[slot_blocks, slot_offsets].set(k_new)
-        v_pool = v_pool.at[slot_blocks, slot_offsets].set(v_new)
+        use_pallas = backend == "tpu"
+    if not use_pallas:
+        at = (slot_blocks, slot_offsets) if layer is None \
+            else (layer, slot_blocks, slot_offsets)
+        k_pool = k_pool.at[at].set(k_new)
+        v_pool = v_pool.at[at].set(v_new)
         a = paged_attention_reference(
-            q, k_pool, v_pool, block_tables, context_lens
+            q, _layer_of(k_pool, layer), _layer_of(v_pool, layer),
+            block_tables, context_lens,
         )
         return a, k_pool, v_pool
     _require_positive_context(1, context_lens, None, None)
     c0, cl_last = _query_context(1, context_lens, None, None)
-    return _paged_append(
-        q, k_new, v_new, k_pool, v_pool,
+    kk, vv, li = _stacked(k_pool, v_pool, layer)
+    a, kk, vv = _paged_append(
+        q, k_new, v_new, kk, vv, li,
         jnp.asarray(block_tables, jnp.int32),
         c0.astype(jnp.int32), cl_last.astype(jnp.int32),
         jnp.asarray(slot_offsets, jnp.int32),
         d_true=hd,
         interpret=(backend != "tpu") if interpret is None else interpret,
     )
+    return (a, kk[0], vv[0]) if layer is None else (a, kk, vv)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, context_lens=None, *,
-                    start_pos=None, n_valid=None,
+                    start_pos=None, n_valid=None, layer=None,
                     use_pallas: bool | None = None,
                     interpret: bool | None = None):
     """Dispatch: Pallas kernel on TPU, gather reference elsewhere (the
     interpreted kernel is for tests).  Same signature/shape/raggedness
-    contract as :func:`paged_attention_reference`.
-
-    The kernel path lane-pads head_dim to 128 on the fly — production
-    pools meant to live on the kernel path should be allocated with
-    ``head_dim`` already a 128-multiple to avoid the copy."""
+    contract as :func:`paged_attention_reference`, plus ``layer``: None
+    for one layer's (num_blocks, BS, H, hd) pool slices, ``i`` for the
+    stacked (L, num_blocks, BS, H, hd) pool read in place at layer i.
+    The kernel reads the pools where they are, at their own head_dim:
+    nothing pool-sized is padded, sliced or copied by this function."""
     backend = jax.default_backend()
     if use_pallas is None:
-        use_pallas = _HAVE_PALLAS and backend == "tpu"
-    if not use_pallas or not _HAVE_PALLAS:
+        use_pallas = backend == "tpu"
+    if not use_pallas:
         return paged_attention_reference(
-            q, k_pool, v_pool, block_tables, context_lens,
+            q, _layer_of(k_pool, layer), _layer_of(v_pool, layer),
+            block_tables, context_lens,
             start_pos=start_pos, n_valid=n_valid,
         )
     B, C, H, hd = q.shape
     _require_positive_context(C, context_lens, start_pos, n_valid)
     c0, cl_last = _query_context(C, context_lens, start_pos, n_valid)
-    qq = _pad_to(q, 3, 128)
-    kk = _pad_to(k_pool, 3, 128)
-    vv = _pad_to(v_pool, 3, 128)
-    out = _paged_ragged(
-        qq, kk, vv,
+    kk, vv, li = _stacked(k_pool, v_pool, layer)
+    return _paged_ragged(
+        q, kk, vv, li,
         jnp.asarray(block_tables, jnp.int32),
         c0.astype(jnp.int32), cl_last.astype(jnp.int32),
         d_true=hd,
         interpret=(backend != "tpu") if interpret is None else interpret,
     )
-    return out[:, :, :, :hd]

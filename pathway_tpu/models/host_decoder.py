@@ -182,9 +182,9 @@ class Int8DecoderHost:
 
     def paged_engine(self, **kwargs):
         """The paged-KV batched decode engine (kvcache/engine.py) built
-        from this host's weights, lazily constructed; None when the engine
-        cannot be built (construction failure falls back to the serialized
-        int8 tier)."""
+        from this host's weights, lazily constructed.  On the CPU backend
+        a construction failure yields None (the serialized int8 tier
+        serves); on a TPU backend it raises (kvcache.engine.build_engine)."""
         if self._paged_engine is not None:
             cached_kwargs = getattr(self, "_paged_engine_kwargs", None)
             if kwargs and self._paged_engine and kwargs != cached_kwargs:

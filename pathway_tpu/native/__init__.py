@@ -1,15 +1,22 @@
 """ctypes bindings for the native runtime tier (src/pw_native.cpp).
 
-Builds on first use with g++ (cached .so next to the source); falls back to
-pure-Python implementations when no compiler is available.  The native hash
-is the canonical row-key hash whenever the library is active — it must stay
-bit-stable across versions (persisted state depends on it).
+Builds on first use with g++; falls back to pure-Python implementations
+when no compiler is available.  The library is compiled ``-march=native``,
+so it belongs to the machine that built it: its file name carries a key of
+the source AND this host's CPU flags, and a library under any other key
+(an older source, or a tree copied from another machine) is never loaded.
+The native hash is the canonical row-key hash whenever the library is
+active — it must stay bit-stable across versions (persisted state depends
+on it).
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 
@@ -17,31 +24,64 @@ import numpy as np
 
 _HERE = os.path.dirname(__file__)
 _SRC = os.path.join(_HERE, "src", "pw_native.cpp")
-_SO = os.path.join(_HERE, "src", "libpw_native.so")
 
 _lib = None
 _lock = threading.Lock()
 _build_failed = False
 
 
+def _cpu_identity() -> str:
+    """What ``-march=native`` keys on: the CPU model and its feature flags."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            lines = f.read().splitlines()
+        keep = sorted({
+            ln for ln in lines
+            if ln.startswith(("flags", "Features", "model name"))
+        })
+        if keep:
+            return "\n".join(keep)
+    except OSError:
+        pass
+    return f"{platform.machine()} {platform.processor()}"
+
+
+def _so_path() -> str:
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(_cpu_identity().encode())
+    return os.path.join(
+        _HERE, "src", f"libpw_native.{h.hexdigest()[:16]}.so"
+    )
+
+
 def _build() -> str | None:
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return _SO
-    tmp = f"{_SO}.{os.getpid()}.tmp"
+    so = _so_path()
+    if os.path.exists(so):
+        return so
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
              _SRC, "-o", tmp],
             check=True, capture_output=True, timeout=120,
         )
-        os.replace(tmp, _SO)  # atomic: concurrent builders never dlopen a torn file
-        return _SO
+        os.replace(tmp, so)  # atomic: concurrent builders never dlopen a torn file
     except Exception:
         try:
             os.unlink(tmp)
         except OSError:
             pass
         return None
+    # libraries under other keys (older source, another machine) are dead
+    for stale in glob.glob(os.path.join(_HERE, "src", "libpw_native*.so")):
+        if stale != so:
+            try:
+                os.unlink(stale)
+            except OSError:
+                pass
+    return so
 
 
 def get_lib():
@@ -61,8 +101,8 @@ def get_lib():
             ctypes.POINTER(ctypes.c_uint64), ctypes.POINTER(ctypes.c_uint64),
         ]
         # self-test against the Python mirror before adopting the native
-        # tier: a stale/foreign .so (e.g. a copied workdir) must never become
-        # the canonical row-key hash
+        # tier: a miscompiled library must never become the canonical
+        # row-key hash
         hi = ctypes.c_uint64()
         lo = ctypes.c_uint64()
         probe = b"pw-native-selftest\x00\x01\x02"
@@ -87,16 +127,6 @@ def get_lib():
             ctypes.POINTER(ctypes.c_uint64), ctypes.POINTER(ctypes.c_int64),
             ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
         ]
-        if not hasattr(lib, "pw_auto_row_keys"):
-            # stale cached .so from older source (copied workdir): fall
-            # back to pure Python now and clear it so a fresh process
-            # rebuilds from the current source
-            try:
-                os.unlink(so)
-            except OSError:
-                pass
-            _build_failed = True
-            return None
         lib.pw_auto_row_keys.restype = None
         lib.pw_auto_row_keys.argtypes = [
             ctypes.c_int64, ctypes.c_uint64,

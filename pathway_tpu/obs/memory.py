@@ -20,8 +20,9 @@ alternative named:
 
 The budget resolves from (in order) an explicit argument, the
 ``PW_HBM_BUDGET_BYTES`` env, or the device's ``memory_stats()`` limit
-on a real TPU backend.  With no budget known (the CPU test fallback),
-``hbm_plan`` still reports the ledger but ``fits`` is not enforced.
+on a TPU backend (a TPU that reports no limit raises).  On the CPU backend
+no budget is known: ``hbm_plan`` still reports the ledger but ``fits`` is
+not enforced.
 """
 
 from __future__ import annotations
@@ -40,16 +41,18 @@ def resolve_budget(explicit: int | None = None) -> tuple[int | None, str]:
             return int(float(env)), "env:PW_HBM_BUDGET_BYTES"
         except ValueError:
             pass
-    try:
-        import jax
+    import jax
 
-        if jax.default_backend() == "tpu":
-            stats = jax.devices()[0].memory_stats() or {}
-            lim = stats.get("bytes_limit")
-            if lim:
-                return int(lim), "device:memory_stats"
-    except Exception:  # noqa: BLE001 - budget degrades to unenforced
-        pass
+    if jax.default_backend() == "tpu":
+        # on the chip the budget is never "unenforced": a device that
+        # does not report its limit is an error, not a free pass
+        lim = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+        if not lim:
+            raise RuntimeError(
+                "TPU backend reports no memory_stats()['bytes_limit']; "
+                "pass hbm_budget_bytes or set PW_HBM_BUDGET_BYTES"
+            )
+        return int(lim), "device:memory_stats"
     return None, "none"
 
 

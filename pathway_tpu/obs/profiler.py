@@ -487,6 +487,7 @@ class ProgramRegistry:
         return {
             "peak_flops_per_s": peak_flops,
             "membw_bytes_per_s": membw,
+            "peak_source": _roofline()["source"] if analyze else None,
             **self.totals(),
             "programs": rows,
             "recompile_events": [
@@ -659,76 +660,86 @@ def profiled_jit(program: str, fn, **jit_kwargs) -> ProfiledFunction:
     return ProfiledFunction(program, fn, **jit_kwargs)
 
 
-# -- machine roofline probes (lazy, cached) ---------------------------------
+# -- machine roofline (lazy, cached) ----------------------------------------
+
+# Published per-chip peaks, keyed by jax's ``device_kind``.  On a TPU
+# backend the roofline comes from this table and nowhere else: a kind
+# that is not listed yields no peak (``mfu`` stays null, with the reason
+# in the summary) rather than a probe's guess.
+TPU_PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops_per_s": 197e12,
+        "int8_ops_per_s": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "source": 'Google Cloud documentation, "TPU v5e"',
+    },
+}
 
 _PROBE_CACHE: dict = {}
 _PROBE_LOCK = threading.Lock()
 
 
-def measured_peak_flops() -> float | None:
-    """Measured matmul roofline of the active backend (best-of-3 jitted
-    1024^3 matmul) — the denominator for per-program MFU when the caller
-    (bench.py has its own spec-sheet-aware `_backend_peak`) does not
-    supply one.  ~100ms once per process; cached."""
-    with _PROBE_LOCK:
-        if "peak" in _PROBE_CACHE:
-            return _PROBE_CACHE["peak"]
-        try:
-            import jax
-            import jax.numpy as jnp
-            import numpy as np
+def _best_of_3(f, a) -> float:
+    with _own_compiles():
+        f(a).block_until_ready()
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        f(a).block_until_ready()
+        best = min(best, time.perf_counter() - t0)
+    return best
 
+
+def _roofline() -> dict:
+    """``{"peak", "membw", "source"}`` of the active backend, resolved
+    once per process.  TPU: the :data:`TPU_PEAKS` row of the device's
+    kind (bf16 peak — the serving dtype), or nulls with the reason as
+    ``source``.  CPU: a best-of-3 jitted 1024^3 f32 matmul and a 32MB
+    copy (read+write counted), ~100ms together."""
+    with _PROBE_LOCK:
+        if "roof" in _PROBE_CACHE:
+            return _PROBE_CACHE["roof"]
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        if jax.default_backend() == "tpu":
+            kind = jax.devices()[0].device_kind
+            row = TPU_PEAKS.get(kind)
+            roof = (
+                {"peak": row["bf16_flops_per_s"],
+                 "membw": row["hbm_bytes_per_s"],
+                 "source": f"{row['source']} ({kind}, bf16)"}
+                if row else
+                {"peak": None, "membw": None,
+                 "source": f"device_kind {kind!r} is not in TPU_PEAKS"}
+            )
+        else:
             n = 1024
             a = jnp.asarray(
                 np.random.default_rng(0).standard_normal((n, n)),
                 jnp.float32,
             )
-            f = jax.jit(lambda x: x @ x)
-            with _own_compiles():
-                f(a).block_until_ready()
-            best = float("inf")
-            for _ in range(3):
-                t0 = time.perf_counter()
-                f(a).block_until_ready()
-                best = min(best, time.perf_counter() - t0)
-            _PROBE_CACHE["peak"] = 2.0 * n ** 3 / best
-        except Exception:  # noqa: BLE001 - MFU degrades to null
-            _PROBE_CACHE["peak"] = None
-        return _PROBE_CACHE["peak"]
+            t_mm = _best_of_3(jax.jit(lambda x: x @ x), a)
+            m = 8 * 1024 * 1024  # 32MB f32
+            t_cp = _best_of_3(jax.jit(lambda x: x + 1.0),
+                              jnp.zeros((m,), jnp.float32))
+            roof = {"peak": 2.0 * n ** 3 / t_mm, "membw": 2.0 * 4 * m / t_cp,
+                    "source": "measured on this host (f32 matmul, 32MB copy)"}
+        _PROBE_CACHE["roof"] = roof
+        return roof
 
 
-def set_peak_flops(peak: float | None) -> None:
-    """Install an externally measured peak (bench._backend_peak knows TPU
-    spec sheets) so every surface reports MFU against the same roof."""
-    with _PROBE_LOCK:
-        if peak:
-            _PROBE_CACHE["peak"] = float(peak)
+def measured_peak_flops() -> float | None:
+    """Peak FLOP/s of the active backend — the denominator for
+    per-program MFU (see :func:`_roofline` for where it comes from)."""
+    return _roofline()["peak"]
 
 
 def measured_membw() -> float | None:
-    """Measured device memory bandwidth (best-of-3 jitted copy of a 32MB
-    array, read+write counted) — the roofline's ridge point."""
-    with _PROBE_LOCK:
-        if "membw" in _PROBE_CACHE:
-            return _PROBE_CACHE["membw"]
-        try:
-            import jax
-            import jax.numpy as jnp
-
-            n = 8 * 1024 * 1024  # 32MB f32
-            a = jnp.zeros((n,), jnp.float32)
-            f = jax.jit(lambda x: x + 1.0)
-            with _own_compiles():
-                f(a).block_until_ready()
-            best = float("inf")
-            for _ in range(3):
-                t0 = time.perf_counter()
-                f(a).block_until_ready()
-                best = min(best, time.perf_counter() - t0)
-            _PROBE_CACHE["membw"] = 2.0 * 4 * n / best
-        except Exception:  # noqa: BLE001
-            _PROBE_CACHE["membw"] = None
-        return _PROBE_CACHE["membw"]
+    """Memory bandwidth of the active backend — the roofline's ridge
+    point (see :func:`_roofline`)."""
+    return _roofline()["membw"]
 
 
 # -- surfaces ---------------------------------------------------------------
@@ -765,7 +776,8 @@ def render_prometheus_lines() -> list[str]:
         "# TYPE pathway_xla_program_flops gauge",
         "# TYPE pathway_xla_program_mfu gauge",
     ]
-    peak = _PROBE_CACHE.get("peak")  # never probe on a scrape
+    # never probe on a scrape
+    peak = (_PROBE_CACHE.get("roof") or {}).get("peak")
     for rec in recs:
         lbl = f'program="{rec.program}",bucket="{rec.label}"'
         lines.append(
@@ -849,7 +861,7 @@ def publish_to_costdb(db=None, *, peak_flops=None) -> int:
     if db is None:
         db = _costdb.default_db()
     if peak_flops is None:
-        peak_flops = _PROBE_CACHE.get("peak")
+        peak_flops = (_PROBE_CACHE.get("roof") or {}).get("peak")
     n = 0
     for rec in _REGISTRY.records():
         ms = rec.ms_percentile(0.5)

@@ -7,8 +7,9 @@ grid dimension (sequential on TPU, so VMEM scratch persists between k
 steps).  Complements the sequence-parallel paths in models/attention.py:
 ring/Ulysses shard T across chips; this kernel is what each chip runs.
 
-Falls back to the XLA reference implementation when Pallas is unavailable;
-interpret=True exercises the same kernel body on CPU in tests.
+The compiled kernel serves on a TPU backend and the XLA reference
+implementation elsewhere; interpret=True exercises the same kernel body on
+CPU in tests.
 """
 
 from __future__ import annotations
@@ -18,6 +19,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ._tiling import pad_to as _pad_to
 
@@ -83,15 +86,6 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         o_ref[:] = (acc_ref[:] / denom).astype(o_ref.dtype)
 
 
-try:  # pallas import is deferred-safe: fall back to XLA when absent
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
-
-
 @functools.partial(
     jax.jit, static_argnames=("causal", "t_valid", "d_true", "interpret")
 )
@@ -136,8 +130,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
     an interpreted T=4096 run would stall for minutes)."""
     backend = jax.default_backend()
     if use_pallas is None:
-        use_pallas = _HAVE_PALLAS and backend == "tpu"
-    if not use_pallas or not _HAVE_PALLAS:
+        use_pallas = backend == "tpu"
+    if not use_pallas:
         from ..models.attention import reference_attention
 
         return reference_attention(q, k, v, causal=causal)

@@ -5,8 +5,9 @@ Docs and queries are pre-normalized for cosine; the kernel is a blocked
 matmul with f32 accumulation over bf16 inputs, padded to MXU-friendly tiles.
 Top-k runs on the scores via lax.top_k (XLA's native implementation).
 
-Falls back to plain jnp when Pallas is unavailable; `interpret=True` is used
-on CPU so tests exercise the same kernel body.
+Kernel selection is one rule (`knn_topk`): the compiled kernel on a TPU
+backend, the jnp matmul elsewhere; `use_pallas=True` off-TPU runs the same
+kernel body interpreted (tests).  A selected kernel that fails raises.
 """
 
 from __future__ import annotations
@@ -16,12 +17,12 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+
+from ._tiling import pad_to as _pad_to
 
 TILE_Q = 128
 TILE_N = 256
-
-
-from ._tiling import pad_to as _pad_to  # noqa: E402
 
 
 def _scores_kernel(q_ref, m_ref, out_ref):
@@ -38,8 +39,6 @@ def _scores_kernel(q_ref, m_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def pallas_scores(queries: jax.Array, matrix: jax.Array, *, interpret: bool = False):
     """(Q,d) x (N,d) -> (Q,N) f32 scores via a tiled Pallas matmul."""
-    from jax.experimental import pallas as pl
-
     Q0, d = queries.shape
     N0 = matrix.shape[0]
     # f32 inputs keep results identical to the host path (the MXU still
@@ -71,8 +70,8 @@ def knn_topk(matrix: np.ndarray, queries: np.ndarray, k: int, metric: str = "cos
              *, use_pallas: bool | None = None):
     """Batched exact KNN: returns (scores (Q,k), indices (Q,k)).
 
-    use_pallas default: real accelerator -> compiled kernel; CPU -> interpreted
-    kernel for small inputs is wasteful, so jnp path is used instead.
+    use_pallas default: the compiled kernel on a TPU backend, the jnp
+    matmul elsewhere (an interpreted kernel is only worth running in tests).
     """
     backend = jax.default_backend()
     if use_pallas is None:
@@ -99,8 +98,5 @@ def knn_topk(matrix: np.ndarray, queries: np.ndarray, k: int, metric: str = "cos
 
 def _dispatch_scores(q, m, use_pallas: bool):
     if use_pallas:
-        try:
-            return pallas_scores(q, m, interpret=jax.default_backend() != "tpu")
-        except Exception:
-            pass
-    return (q.astype(jnp.float32) @ m.astype(jnp.float32).T)
+        return pallas_scores(q, m, interpret=jax.default_backend() != "tpu")
+    return q.astype(jnp.float32) @ m.astype(jnp.float32).T

@@ -35,14 +35,6 @@ def _sharded_topk_fn(mesh, axis: str, k: int, metric: str):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map  # jax >= 0.8 (check_rep renamed)
-        _smap_kw = {"check_vma": False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
-        _smap_kw = {"check_rep": False}
-
     def local_topk(m_shard, qs, n_live):
         # m_shard: (rows/n_dev, d) local rows; qs: (Q, d) replicated;
         # n_live: scalar — rows with global id >= n_live are padding
@@ -72,12 +64,12 @@ def _sharded_topk_fn(mesh, axis: str, k: int, metric: str):
         return mvals, midx
 
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             local_topk,
             mesh=mesh,
             in_specs=(P(axis, None), P(), P()),
             out_specs=(P(), P()),
-            **_smap_kw,
+            check_vma=False,
         )
     )
     _FNS[key] = fn

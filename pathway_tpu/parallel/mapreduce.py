@@ -317,7 +317,7 @@ def hash_join_membership(probe, build):
             _record_cost("pw.join.member.numpy", probe.size,
                          (_time.perf_counter() - t0) * 1e3)
         return out
-    from jax.experimental import enable_x64
+    import jax
 
     bs = np.sort(build)
     n_pad = _pow2_bucket(probe.size)
@@ -326,7 +326,7 @@ def hash_join_membership(probe, build):
     p[: probe.size] = probe
     b = np.full(b_pad, bs[-1], bs.dtype)  # pad with the max: order kept,
     b[: bs.size] = bs                     # membership unchanged
-    with enable_x64():
+    with jax.enable_x64(True):
         mask = _jit_membership(n_pad, b_pad, str(probe.dtype))(p, b)
     out = np.asarray(mask)[: probe.size]
     if record:

@@ -27,6 +27,7 @@ import heapq
 import itertools
 import threading
 import time
+import weakref
 from typing import Any, Callable, Sequence
 
 from .. import obs
@@ -129,7 +130,17 @@ class RequestScheduler:
         self._inflight = 0
         self._inflight_waiters: Sequence = ()
         self._thread: threading.Thread | None = None
-        self.stats = serve_stats(name, depth_fn=lambda: len(self._heap))
+        # the stats registry is process-global and never pruned: hand it a
+        # weakref-backed gauge, or every scheduler ever built — and the
+        # engine, pools and weights its batch_fn holds — would live for
+        # the life of the process (block_pool.py does the same)
+        wref = weakref.ref(self)
+
+        def _depth() -> int:
+            sched = wref()
+            return 0 if sched is None else len(sched._heap)
+
+        self.stats = serve_stats(name, depth_fn=_depth)
         # scheduler-scoped trace: batch-formation spans (which cut across
         # requests) land here; per-request spans live on each request's
         # own trace

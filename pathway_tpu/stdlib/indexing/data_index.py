@@ -10,6 +10,7 @@ Lowered to a single engine operator keeping an InnerIndex plus the data rows
 from __future__ import annotations
 
 import time as _time
+from collections import deque
 from typing import Any, Callable
 
 from ... import obs
@@ -18,7 +19,8 @@ from ...engine.runner import register_lowering, _env_for, _compile
 from ...engine.types import consolidate
 from ...internals import dtype as dt
 from ...internals import parse_graph as pg
-from ...internals.expression import ColumnExpression, ColumnReference, wrap
+from ...internals.expression import (ApplyExpression, ColumnExpression,
+                                     ColumnReference, wrap)
 from ...internals.table import Table, Universe
 from ...internals.value import ERROR, Error
 
@@ -39,12 +41,18 @@ class ExternalIndexOperator(DiffOutputOperator):
         n_data_cols: int,
         as_of_now: bool,
         name="external_index",
+        query_expr=None,
+        data_expr=None,
     ):
         super().__init__(2, name)
         self.query_env, self.data_env = query_env, data_env
         self.index = index_factory()
         self.query_item_fn = query_item_fn
         self.data_item_fn = data_item_fn
+        # the item expressions themselves: an embedder's apply carries a
+        # batch_fn, which _eval_items calls once per micro-batch
+        self._query_expr, self._data_expr = query_expr, data_expr
+        self._items: deque = deque()  # this batch's precomputed data items
         self.data_meta_fn = data_meta_fn
         self.k_fn = k_fn
         self.filter_fn = filter_fn
@@ -54,12 +62,34 @@ class ExternalIndexOperator(DiffOutputOperator):
         self._pending: list = []
 
     # -- index maintenance -------------------------------------------------
+    @staticmethod
+    def _eval_items(expr, item_fn, env_builder, rows: list) -> list:
+        """The item of every ``(key, row)``.  An apply with a ``batch_fn``
+        (the embedders': pad -> one device forward -> per-row handles) is
+        called ONCE for the whole micro-batch, as a select would call it —
+        that is what keeps ingested vectors on the device; any other
+        expression evaluates row by row."""
+        envs = [env_builder.build(k, r) for k, r in rows]
+        if (len(rows) > 1 and isinstance(expr, ApplyExpression)
+                and expr._batch_fn is not None and not expr._kwargs):
+            # a failing batch raises: an embedder that cannot reach the
+            # device must not read as a document that could not be indexed
+            return expr._eval_batch(envs, row_fallback=False)
+        return [item_fn(env) for env in envs]
+
+    def _precompute_data_items(self, updates) -> None:
+        self._items = deque(self._eval_items(
+            self._data_expr, self.data_item_fn, self.data_env,
+            [(k, r) for k, r, d in updates if d > 0],
+        ))
+
     def pre_apply(self, port, key, row, diff):
         if port != 1:
             return
-        env = self.data_env.build(key, row)
         if diff > 0:
-            item = self.data_item_fn(env)
+            env = self.data_env.build(key, row)
+            item = (self._items.popleft() if self._items
+                    else self.data_item_fn(env))
             if item is None or isinstance(item, Error):
                 return
             meta = self.data_meta_fn(env) if self.data_meta_fn else None
@@ -75,6 +105,8 @@ class ExternalIndexOperator(DiffOutputOperator):
         return tuple(self.last_out.keys()) or tuple(self.state[0].keys())
 
     def process(self, port, updates, time):
+        if port == 1:
+            self._precompute_data_items(updates)
         if not self.as_of_now:
             if port == 1:
                 # mark all queries dirty BEFORE updating the index
@@ -159,12 +191,12 @@ class ExternalIndexOperator(DiffOutputOperator):
         supports it; per-query filters or odd rows fall back individually."""
         if not hasattr(self.index, "search_batch") or self.filter_fn is not None:
             return [self._answer(k, r) for k, r in inserts]
-        metas = []
-        for key, row in inserts:
-            env = self.query_env.build(key, row)
-            q = self.query_item_fn(env)
-            k = self.k_fn(env)
-            metas.append((q, k))
+        qs = self._eval_items(self._query_expr, self.query_item_fn,
+                              self.query_env, inserts)
+        metas = [
+            (q, self.k_fn(self.query_env.build(key, row)))
+            for q, (key, row) in zip(qs, inserts)
+        ]
         empty = ((), ()) + ((),) * self.n_data_cols
         valid = [
             i for i, (q, k) in enumerate(metas)
@@ -183,7 +215,9 @@ class ExternalIndexOperator(DiffOutputOperator):
         k = ks.pop()
         try:
             results = self.index.search_batch([metas[i][0] for i in valid], k)
-        except Exception:
+        except (TypeError, ValueError):
+            # queries that do not stack into one (Q, d) batch; a device
+            # failure is not one of these and propagates
             for i in valid:
                 answers[i] = self._pack(self.index.search(metas[i][0], k, None))
             return answers
@@ -234,6 +268,8 @@ def _lower_external_index(node, lg):
         _compile(p["filter_expr"]) if p.get("filter_expr") is not None else None,
         len(data._colnames),
         p["as_of_now"],
+        query_expr=p["query_item"],
+        data_expr=p["data_item"],
     )
 
 

@@ -70,16 +70,17 @@ class JaxChat(BaseChat):
         )
 
     def paged_engine(self):
-        """The paged KV decode engine behind :meth:`generate_batch`, or
-        None when it cannot be built — question_answering.py probes this
-        to size the llm scheduler's batches (kvcache/engine.py)."""
+        """The paged KV decode engine behind :meth:`generate_batch` —
+        question_answering.py probes this to size the llm scheduler's
+        batches.  None only on the CPU backend, when it cannot be built;
+        on a TPU backend that raises (kvcache/engine.py build_engine)."""
         return self._lm.paged_engine()
 
     def generate_batch(self, message_batches: list, **kwargs) -> list[str]:
         """Answer a whole coalesced batch in ONE decode-tier pass through
         the paged KV cache (mixed lengths, shared-prefix blocks mapped to
-        the same physical blocks); serial fallback when the engine is
-        unavailable."""
+        the same physical blocks); on the CPU backend a serial loop
+        stands in when the engine cannot be built."""
         prompts = []
         for messages in message_batches:
             if isinstance(messages, str):

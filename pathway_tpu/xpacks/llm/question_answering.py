@@ -145,10 +145,9 @@ class BaseRAGQuestionAnswerer:
                     max_bs = 8
                     probe = getattr(llm, "paged_engine", None)
                     if callable(probe):
-                        try:
-                            engine = probe()
-                        except Exception:  # noqa: BLE001 - probe only
-                            engine = None
+                        # None means the CPU's serial tier; on a TPU an
+                        # engine that cannot be built raises here
+                        engine = probe()
                         if engine is not None:
                             max_bs = max(int(engine.max_batch_size), 2)
                         else:
@@ -246,7 +245,9 @@ class AdaptiveRAGQuestionAnswerer(BaseRAGQuestionAnswerer):
             q.prompt,
             self.indexer.index,
             "text",
-            self.llm,
+            # through the llm scheduler when one was asked for, like the
+            # base class's answer_query
+            self._call_llm,
             n_starting_documents=self.n_starting_documents,
             factor=self.factor,
             max_iterations=self.max_iterations,

@@ -96,7 +96,7 @@ def test_columnar_batch_flows_between_operators():
 
 def test_jax_tier_runs_when_forced(monkeypatch):
     monkeypatch.setenv("PW_FORCE_JAX_TIER", "1")
-    monkeypatch.setattr(vectorize, "_JAX_HEALTHY", None)
+    monkeypatch.setattr(vectorize, "_JAX_TIER_ON", None)
     monkeypatch.setattr(vectorize, "JAX_THRESHOLD", 256)
     rows = _rows(4000, seed=5)
     expected = _run_row_path(rows)
@@ -105,7 +105,7 @@ def test_jax_tier_runs_when_forced(monkeypatch):
     [cap] = run_tables(_pipeline(rows))
     assert cap.squash() == expected
     assert vectorize.STATS["jax_batches"] >= 1, vectorize.STATS
-    monkeypatch.setattr(vectorize, "_JAX_HEALTHY", None)
+    monkeypatch.setattr(vectorize, "_JAX_TIER_ON", None)
 
 
 def test_groupby_minmax_with_retractions():
@@ -233,7 +233,7 @@ def test_is_none_over_method_call_not_vectorized_wrong():
 
 def test_division_by_zero_poisons_even_vectorized(monkeypatch):
     monkeypatch.setenv("PW_FORCE_JAX_TIER", "1")
-    monkeypatch.setattr(vectorize, "_JAX_HEALTHY", None)
+    monkeypatch.setattr(vectorize, "_JAX_TIER_ON", None)
     monkeypatch.setattr(vectorize, "JAX_THRESHOLD", 64)
 
     class SD(pw.Schema):
@@ -249,5 +249,5 @@ def test_division_by_zero_poisons_even_vectorized(monkeypatch):
     assert sum(1 for (q,) in res if q == -1.0) == 10
     assert not any(isinstance(q, float) and (q != q or q in (float("inf"),))
                    for (q,) in res)
-    monkeypatch.setattr(vectorize, "_JAX_HEALTHY", None)
+    monkeypatch.setattr(vectorize, "_JAX_TIER_ON", None)
     pg.G.clear()

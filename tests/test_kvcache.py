@@ -506,11 +506,9 @@ def test_pallas_kernel_matches_reference_interpreted():
     """The TPU kernel path (interpret mode on CPU — slow) must agree with
     the gather reference to f32 tolerance."""
     from pathway_tpu.kvcache.paged_attention import (
-        _HAVE_PALLAS, paged_attention, paged_attention_reference,
+        paged_attention, paged_attention_reference,
     )
 
-    if not _HAVE_PALLAS:
-        pytest.skip("pallas unavailable")
     rng = np.random.default_rng(5)
     B, H, hd, BS, NBLK, NB = 3, 2, 16, 8, 12, 3
     q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), jnp.float32)

@@ -6,15 +6,6 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
-# every kernel here shards through the top-level jax.shard_map alias,
-# which newer jax builds removed (it moved under jax.experimental with a
-# different calling convention); on such builds the whole module is an
-# environment gap, not a regression
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="this jax build has no top-level jax.shard_map",
-)
-
 
 def test_ring_attention_matches_reference():
     import jax.numpy as jnp

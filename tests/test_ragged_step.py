@@ -409,11 +409,9 @@ def test_ragged_kernel_matches_reference_interpreted():
     """The length-aware multi-query kernel (interpret mode on CPU — slow)
     must agree with the gather reference on every VALID query column."""
     from pathway_tpu.kvcache.paged_attention import (
-        _HAVE_PALLAS, paged_attention, paged_attention_reference,
+        paged_attention, paged_attention_reference,
     )
 
-    if not _HAVE_PALLAS:
-        pytest.skip("pallas unavailable")
     rng = np.random.default_rng(5)
     B, C, H, hd, BS, NBLK = 3, 4, 2, 16, 8, 12
     q = jnp.asarray(rng.standard_normal((B, C, H, hd)), jnp.float32)
