@@ -1,0 +1,1 @@
+"""Found by name from the data files; see benchmark/README.md."""
