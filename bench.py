@@ -1355,7 +1355,7 @@ def bench_generation() -> dict:
                 "draft": round(_phase_s("engine.draft") / wall, 4),
                 # host blocked collecting the [B, K] ids (subset of
                 # device-busy — reported separately, not additive)
-                "sync": round(_phase_s("engine.sync") / wall, 4),
+                "sync": round(_phase_s("pw.round.sync") / wall, 4),
                 # host bookkeeping on the critical path (device idle)
                 "host": round(_phase_s("engine.host_gap") / wall, 4),
             }
@@ -1501,7 +1501,7 @@ def bench_generation() -> dict:
                 "verify_device": round(
                     _spec_phase_s("engine.device.verify") / swall, 4
                 ),
-                "sync": round(_spec_phase_s("engine.sync") / swall, 4),
+                "sync": round(_spec_phase_s("pw.round.sync") / swall, 4),
                 "host": round(_spec_phase_s("engine.host_gap") / swall, 4),
             }
         # the measured (drafter, k) verdict lands in the cost store under

@@ -136,15 +136,42 @@ class _WatchdogSync:
             raise box["error"]
         return box["result"]
 
-# jax.profiler.TraceAnnotation wraps every engine dispatch so XLA/TPU
-# profiles (jax.profiler.trace) line up with our flight-recorder spans;
-# a nullcontext fallback keeps old jax versions working
-_TraceAnnotation = getattr(jax.profiler, "TraceAnnotation", None)
-if _TraceAnnotation is None:  # pragma: no cover - modern jax has it
-    import contextlib
 
-    def _TraceAnnotation(_name):  # noqa: N802 - drop-in stand-in
-        return contextlib.nullcontext()
+class _RoundPhase(obs.phase):
+    """One phase of an engine round (:func:`pathway_tpu.obs.phase`: the
+    same name in a device trace's host plane and in the flight recorder,
+    on the engine-run trace) that also adds its time to the pool's
+    ``round_s`` counter under ``key``."""
+
+    __slots__ = ("stats", "key")
+
+    def __init__(self, stats, key: str, name: str, ctx, attrs: dict):
+        super().__init__(name, ctx, **attrs)
+        self.stats = stats
+        self.key = key
+
+    def __exit__(self, exc_type, exc, tb):
+        super().__exit__(exc_type, exc, tb)
+        self.stats.record_round(self.key, self.t1 - self.t0)
+
+
+def _roomy(fn, *args):
+    """``fn(*args)`` from a frame that needs a data-stack chunk of its own.
+
+    CPython keeps a thread's frames in 16 KiB chunks and frees a chunk the
+    moment its first frame returns, so a hot loop whose callees straddle a
+    chunk boundary maps and unmaps a chunk on every call.  jax lowers the
+    step programs ~100 frames beneath the engine loop: whether that loop
+    straddles a boundary depends on how many locals the engine's own
+    frames hold, and when it does the first dispatch of a program lowers
+    in 3.8 s instead of 1.4 (PERF.md, PR 25: 3.5 s of set-up moved by a
+    refactor that touched no device work).  This frame asks for 32,768
+    slots, so it opens a 512 KiB chunk and leaves half of it to everything
+    the engine loop calls: no boundary beneath it."""
+    return fn(*args)
+
+
+_roomy.__code__ = _roomy.__code__.replace(co_stacksize=1 << 15)
 
 
 def _norm_sampling(s) -> tuple | None:
@@ -182,7 +209,9 @@ def _payload_extras(r) -> tuple[int, dict | None]:
 class _Request:
     __slots__ = ("prompt", "max_new", "priority", "stop_token", "emitted",
                  "index", "on_done", "on_error", "t_arrival", "span", "ctx",
-                 "sampling", "session", "on_token")
+                 "sampling", "session", "on_token", "t_admit", "t_chunk0",
+                 "t_first", "t_requeue", "in_prefill", "n_chunks",
+                 "n_rounds", "n_skipped", "n_first", "n_chains", "n_mixed")
 
     def __init__(self, prompt, max_new: int, *, priority: int = 1,
                  stop_token: int | None = None, index: int | None = None,
@@ -214,17 +243,89 @@ class _Request:
         self.sampling = _norm_sampling(sampling)
         self.session = session
         self.on_token = on_token
-        self.t_arrival = time.perf_counter()
         # request-scoped tracing: the root span is opened the moment the
         # engine learns about the request (its trace id is minted here
         # unless the serving path already carries one — e.g. an
         # X-Pathway-Trace header through scheduler submit()) and finished
-        # at delivery; admission/prefill/chain spans parent under it
+        # at delivery; the lifecycle spans below parent under it
         self.span = obs.start_span(
             "engine.request", ctx=trace,
             prompt_tokens=len(self.prompt), max_new=self.max_new,
         )
         self.ctx = self.span.ctx
+        self.t_arrival = self.span.t0
+        # lifecycle marks (one span per transition, none per token):
+        # arrival -> engine.pending -> admitted -> engine.prefill_wait ->
+        # first chunk dispatched -> engine.prefill -> first token ->
+        # engine.decode -> done.  The three before the first token tile
+        # [t_arrival, t_first], so their sum IS the recorded TTFT.
+        self.t_admit: float | None = None    # this admission
+        self.t_chunk0: float | None = None   # its first chunk's dispatch
+        self.t_first: float | None = None    # first token (TTFT closed)
+        self.t_requeue: float | None = None  # preempted at
+        self.in_prefill = False
+        self.n_chunks = self.n_rounds = self.n_skipped = 0
+        self.n_first = self.n_chains = self.n_mixed = 0
+
+    def note_admitted(self, now: float) -> None:
+        """Admission: ``engine.pending`` closes (pool-full waits included).
+        A preempted request's later admissions are marked ``readmit``."""
+        if self.t_admit is None:
+            obs.record_span("engine.pending", self.t_arrival, now,
+                            ctx=self.ctx)
+        else:
+            obs.record_span("engine.pending", self.t_requeue, now,
+                            ctx=self.ctx, readmit=True)
+            self.t_chunk0 = now  # a re-admission has no prefill_wait span
+        self.t_admit = now
+        self.in_prefill = True
+        self.n_chunks = self.n_rounds = self.n_skipped = 0
+
+    def note_chunk(self, t_disp: float) -> None:
+        """A round that carries one of this request's prompt chunks was
+        dispatched at ``t_disp``; the first closes ``engine.prefill_wait``."""
+        if self.t_chunk0 is None:
+            obs.record_span("engine.prefill_wait", self.t_admit, t_disp,
+                            ctx=self.ctx)
+            self.t_chunk0 = t_disp
+        self.n_chunks += 1
+        self.n_rounds += 1
+
+    def note_prefill_end(self, now: float, **attrs) -> None:
+        """``engine.prefill`` closes: at the first token after this
+        admission, or (``preempted=True``) when a preemption cut it."""
+        if self.t_chunk0 is None:  # cut before any chunk: it only waited
+            obs.record_span("engine.prefill_wait", self.t_admit, now,
+                            ctx=self.ctx)
+            self.t_chunk0 = now
+        if self.t_requeue is not None:
+            attrs["readmit"] = True
+        obs.record_span("engine.prefill", self.t_chunk0, now, ctx=self.ctx,
+                        chunks=self.n_chunks, rounds=self.n_rounds,
+                        rounds_skipped=self.n_skipped, **attrs)
+        self.in_prefill = False
+
+    def note_requeued(self, now: float) -> None:
+        """Preempted (or restarted) back into the queue."""
+        if self.in_prefill:
+            self.note_prefill_end(now, preempted=True)
+        self.t_requeue = now
+
+    def finish(self, outcome: str) -> None:
+        """Delivery: ``engine.decode`` (first token -> done) and the root
+        close.  ``finish()`` of a span is idempotent, so a double delivery
+        records once."""
+        if self.span.t1 is not None:
+            return
+        attrs = {"outcome": outcome, "emitted": len(self.emitted)}
+        if self.t_first is not None:
+            obs.record_span("engine.decode", self.t_first,
+                            time.perf_counter(), ctx=self.ctx,
+                            tokens=len(self.emitted) - self.n_first,
+                            chains=self.n_chains,
+                            mixed_rounds=self.n_mixed)
+            attrs["ttft_s"] = self.t_first - self.t_arrival
+        self.span.finish(**attrs)
 
 
 class _Active:
@@ -772,11 +873,12 @@ class PagedDecodeEngine:
         return self._sampled
 
     def _sampling_arrays(self, entries, B: int):
-        """Per-row sampling arrays for one dispatch, or None when EVERY
-        row is greedy (the round then uses the greedy program — no
-        sampled compile).  ``entries``: (row_index, _Request) pairs.
-        Greedy rows riding a sampled dispatch get temperature=0, which
-        the device head pins to the exact argmax."""
+        """Per-row sampling arrays (numpy; the caller transfers them) for
+        one dispatch, or None when EVERY row is greedy (the round then
+        uses the greedy program — no sampled compile).  ``entries``:
+        (row_index, _Request) pairs.  Greedy rows riding a sampled
+        dispatch get temperature=0, which the device head pins to the
+        exact argmax."""
         if not any(req.sampling is not None for _i, req in entries):
             return None
         temp = np.zeros(B, np.float32)
@@ -789,8 +891,7 @@ class PagedDecodeEngine:
             if req.sampling is not None:
                 t, k, p, s = req.sampling
                 temp[i], top_k[i], top_p[i], seed[i] = t, k, p, s
-        return (jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
-                jnp.asarray(seed), jnp.asarray(emit))
+        return (temp, top_k, top_p, seed, emit)
 
     # -- Round-18: speculative verify program ------------------------------
     def _verify_program(self):
@@ -949,12 +1050,9 @@ class PagedDecodeEngine:
         outstanding = {"n": len(requests)}  # batch-origin work still open
 
         def deliver(req: _Request, err: BaseException | None = None) -> None:
-            # delivery closes the request's root span (finish() is
-            # idempotent, so a double-delivered edge case records once)
-            req.span.finish(
-                outcome="error" if err is not None else "done",
-                emitted=len(req.emitted),
-            )
+            # delivery closes the request's root span (and its
+            # engine.decode span); idempotent on a double delivery
+            req.finish("error" if err is not None else "done")
             if req.on_done is None and req.on_error is None:
                 outstanding["n"] -= 1
             if err is not None:
@@ -980,7 +1078,7 @@ class PagedDecodeEngine:
                 return inner_poll(n) if outstanding["n"] > 0 else []
 
         with self._lock:
-            running = self._run_loop(pending, deliver, poll, stop)
+            running = _roomy(self._run_loop, pending, deliver, poll, stop)
             assert not running
         if self._spec is not None:
             # batch end: the controller's measured (drafter, K) aggregate
@@ -1248,22 +1346,26 @@ class PagedDecodeEngine:
 
     def _loop_body(self, running, pending, deliver, poll, stop):
         while pending or running:
-            self._admit_arrivals(running, pending, poll, stop)
-            while pending and len(running) < self.max_batch_size:
-                req = pending[0]
-                t0a = time.perf_counter()
-                status = self._try_admit(req, running, pending, deliver)
-                if status != "wait":
-                    # "wait" recurs every round while the pool is full —
-                    # recording each retry would flood the ring (and the
-                    # request's trace) with duplicates; the blocked time
-                    # is visible as the request-start -> admission gap
-                    obs.record_span("engine.admission", t0a,
-                                    time.perf_counter(), ctx=req.ctx,
-                                    outcome=status)
-                if status == "wait":
-                    break
-                pending.popleft()
+            with self._phase("pw.round.admit") as ph:
+                self._admit_arrivals(running, pending, poll, stop)
+                admitted = 0
+                while pending and len(running) < self.max_batch_size:
+                    req = pending[0]
+                    t0a = time.perf_counter()
+                    status = self._try_admit(req, running, pending, deliver)
+                    if status != "wait":
+                        # "wait" recurs every round while the pool is
+                        # full — recording each retry would flood the ring
+                        # (and the request's trace) with duplicates; the
+                        # blocked time is inside engine.pending
+                        obs.record_span("engine.admission", t0a,
+                                        time.perf_counter(), ctx=req.ctx,
+                                        outcome=status)
+                    if status == "wait":
+                        break
+                    admitted += status == "admitted"
+                    pending.popleft()
+                ph.set(admitted=admitted, pending=len(pending))
             if not running:
                 # nothing admitted implies nothing pending either:
                 # _try_admit only returns "wait" while others run, and the
@@ -1286,6 +1388,8 @@ class PagedDecodeEngine:
         of strictly-lower-priority work, behind equal-or-higher — a
         victim must not leapfrog an urgent arrival (priority inversion)
         nor lose its place to later same-class requests."""
+        if req.t_admit is not None:  # a victim, not a new arrival
+            req.note_requeued(time.perf_counter())
         idx = next(
             (i for i, r in enumerate(pending) if r.priority > req.priority),
             len(pending),
@@ -1339,10 +1443,17 @@ class PagedDecodeEngine:
                     "on_token callback failed; continuing decode",
                     exc_info=True,
                 )
-        if len(req.emitted) == 1:
-            self.pool.stats.record_ttft(
-                time.perf_counter() - req.t_arrival
-            )
+        first = len(req.emitted) == 1
+        if req.in_prefill or first:
+            # one clock reading closes engine.prefill and the TTFT window,
+            # so pending + prefill_wait + prefill is the recorded value
+            now = time.perf_counter()
+            if req.in_prefill:
+                req.note_prefill_end(now)
+            if req.t_first is None:
+                req.t_first, req.n_first = now, len(req.emitted)
+            if first:
+                self.pool.stats.record_ttft(now - req.t_arrival)
         if self._t_failure is not None:
             # first token after a supervised restart: the
             # failure -> first-recovered-token window (engine_restart_s)
@@ -1351,17 +1462,33 @@ class PagedDecodeEngine:
             )
             self._t_failure = None
 
+    def _phase(self, name: str, **attrs) -> _RoundPhase:
+        """A phase of this run's rounds: ``pw.round.<key>``, or a program
+        call under the program's own ``pw.*`` name (key ``dispatch``)."""
+        key = name[9:] if name.startswith("pw.round.") else "dispatch"
+        return _RoundPhase(self.pool.stats, key, name, self._run_ctx, attrs)
+
+    def _h2d(self, host: tuple) -> tuple:
+        """The step's numpy arrays to the device, one transfer each, in
+        order (``pw.round.h2d``)."""
+        with self._phase("pw.round.h2d", arrays=len(host),
+                         bytes=sum(a.nbytes for a in host)):
+            return tuple(jnp.asarray(a) for a in host)
+
     def _sync_host(self, dev_array) -> np.ndarray:
-        """Device->host sync, watchdog-bounded when configured.  The
-        `engine.sync` fault point lives INSIDE the pull so a chaos
-        `hang` wedges exactly where a stuck device program would."""
+        """Device->host sync (``pw.round.sync``), watchdog-bounded when
+        configured.  The `engine.sync` fault point lives INSIDE the pull
+        so a chaos `hang` wedges exactly where a stuck device program
+        would.  ``perf_ns`` is the clock anchor: this clock's reading at
+        the annotation's start on a device trace's clock."""
         def pull():
             faults.fire("engine.sync")
             return np.asarray(dev_array)
 
-        if self._watchdog is None:
-            return pull()
-        return self._watchdog.run(pull, self.watchdog_timeout_s)
+        with self._phase("pw.round.sync", perf_ns=time.perf_counter_ns()):
+            if self._watchdog is None:
+                return pull()
+            return self._watchdog.run(pull, self.watchdog_timeout_s)
 
     # -- admission ---------------------------------------------------------
     def _try_admit(self, req: _Request, running, pending, deliver) -> str:
@@ -1487,6 +1614,7 @@ class PagedDecodeEngine:
                 # recomputes to produce the next-token logits
                 act.n_filled = min(resident, n - 1)
                 act.n_diverted = resident
+                req.note_admitted(time.perf_counter())
                 running.append(act)
                 return "admitted"
             # prefix-shared leading blocks need no recompute: their K/V
@@ -1508,6 +1636,7 @@ class PagedDecodeEngine:
                 for key, blk in zip(keys[len(shared):],
                                     state.block_ids[len(shared):len(keys)]):
                     self._inflight_prefix.setdefault(key, (act, blk))
+            req.note_admitted(time.perf_counter())
             running.append(act)
             return "admitted"
         # -- legacy whole-bucket prefill (chunked_prefill=False) ----------
@@ -1525,35 +1654,39 @@ class PagedDecodeEngine:
             # perturb its remaining decode
             scatter_bt = self.pool.block_table(seq_id, nb)
             scatter_bt[: len(shared)] = 0
-            faults.fire("engine.dispatch.prefill")
-            self._note_dispatch("prefill")
-            t_disp_pf = self._t_dispatch
-            if req.sampling is None:
-                prog_pf = self._prefill
-                with _TraceAnnotation("pw.prefill"):
-                    ids, self.pool.k, self.pool.v = prog_pf(
-                        self.params, jnp.asarray(buf),
-                        jnp.asarray([n], jnp.int32),
-                        self.pool.k, self.pool.v,
-                        jnp.asarray(scatter_bt[None, :]),
-                    )
-            else:
+            host = (buf, np.asarray([n], np.int32), scatter_bt[None, :])
+            if req.sampling is not None:
                 # first token's emit index is len(emitted): a restart /
                 # failover re-admission resumes the seed schedule exactly
                 # where the dead engine left off
                 tv, kv, pv, sv = req.sampling
-                prog_pf = self._sampled_programs()["prefill"]
-                with _TraceAnnotation("pw.prefill_sampled"):
+                host += (np.asarray([tv], np.float32),
+                         np.asarray([kv], np.int32),
+                         np.asarray([pv], np.float32),
+                         np.asarray([sv], np.int32),
+                         np.asarray([len(req.emitted)], np.int32))
+            faults.fire("engine.dispatch.prefill")
+            self._note_dispatch("prefill")
+            t_disp_pf = self._t_dispatch
+            # this path dispatches from INSIDE admission: its h2d, program
+            # and sync phases nest in pw.round.admit, and the request is
+            # admitted and given its one chunk at the same instant
+            req.note_admitted(t_disp_pf)
+            req.note_chunk(t_disp_pf)
+            tok_d, n_d, bt_d, *samp_d = self._h2d(host)
+            if req.sampling is None:
+                prog_pf = self._prefill
+                with self._phase("pw.prefill"):
                     ids, self.pool.k, self.pool.v = prog_pf(
-                        self.params, jnp.asarray(buf),
-                        jnp.asarray([n], jnp.int32),
-                        self.pool.k, self.pool.v,
-                        jnp.asarray(scatter_bt[None, :]),
-                        jnp.asarray([tv], jnp.float32),
-                        jnp.asarray([kv], jnp.int32),
-                        jnp.asarray([pv], jnp.float32),
-                        jnp.asarray([sv], jnp.int32),
-                        jnp.asarray([len(req.emitted)], jnp.int32),
+                        self.params, tok_d, n_d, self.pool.k, self.pool.v,
+                        bt_d,
+                    )
+            else:
+                prog_pf = self._sampled_programs()["prefill"]
+                with self._phase("pw.prefill_sampled"):
+                    ids, self.pool.k, self.pool.v = prog_pf(
+                        self.params, tok_d, n_d, self.pool.k, self.pool.v,
+                        bt_d, *samp_d,
                     )
             # the sync stays INSIDE the failure cleanup: a hung/failed
             # sync (watchdog) with no restart budget must not leak the
@@ -1634,21 +1767,28 @@ class PagedDecodeEngine:
                 return
             if not running:
                 return  # every row was preempted into pending; re-admit
-        victims: list[_Active] = []
-        reserved = self._reserve_slots(running, pending, victims)
-        if victims:
-            # a preempted mid-prefill WRITER strands any sharer still
-            # reading through its half-written blocks — cascade those
-            # back to the queue too (recompute restores them)
-            self._cascade_preempt(victims, running, pending)
-        # chunk membership is decided AFTER slot reservation: reservation
-        # may preempt a mid-prefill sequence, which must then not be
-        # dispatched this round
-        chunks = [a for a in running if a.tokens is not None]
+        with self._phase("pw.round.build") as ph:
+            victims: list[_Active] = []
+            reserved = self._reserve_slots(running, pending, victims)
+            if victims:
+                # a preempted mid-prefill WRITER strands any sharer still
+                # reading through its half-written blocks — cascade those
+                # back to the queue too (recompute restores them)
+                self._cascade_preempt(victims, running, pending)
+            # chunk membership is decided AFTER slot reservation:
+            # reservation may preempt a mid-prefill sequence, which must
+            # then not be dispatched this round
+            chunks = [a for a in running if a.tokens is not None]
+            if chunks:
+                step = self._build_mixed(reserved, chunks, ph)
+            elif reserved:
+                step = self._build_decode(reserved, ph)
+            else:
+                ph.set(kind="none", rows=0, tokens=0, budget=0, waiting=0)
         if chunks:
-            self._mixed_round(reserved, chunks, running, deliver)
+            self._mixed_round(step, running, deliver)
         elif reserved:
-            self._decode_round(reserved, running, deliver)
+            self._decode_round(step, reserved, running, deliver)
 
     # -- Round-18: speculative draft + verify rounds -----------------------
     def _spec_round(self, running, pending, deliver) -> bool:
@@ -1668,6 +1808,17 @@ class PagedDecodeEngine:
         flight, sampled rows (they ride K=1 unchanged this round), or no
         usable proposals (the zero-accept worst case thereby degrades to
         plain chained throughput, not below it)."""
+        with self._phase("pw.round.build", kind="verify") as ph:
+            built = self._build_verify(running, pending, ph)
+        if not isinstance(built, tuple):
+            return built
+        return self._verify_round(*built, running, deliver)
+
+    def _build_verify(self, running, pending, ph):
+        """Draft, reserve and pack one verify dispatch (inside the
+        caller's ``pw.round.build``).  False: no verify this round (see
+        :meth:`_spec_round`); True: every row was preempted into pending;
+        else ``(host arrays, rows, prop_of)``."""
         spec = self._spec
         if any(a.tokens is not None for a in running):
             return False  # mid-prefill chunks stream through mixed
@@ -1757,86 +1908,90 @@ class PagedDecodeEngine:
             tok_col[run] = np.arange(nv)
             logit_idx[base:base + C] = base + cols
             rows.append((act, i, nv))
+        ph.set(rows=len(rows), tokens=sum(nv for _a, _r, nv in rows),
+               budget=T, waiting=0)
+        return (tokens, positions, row_tables, row_start, row_nvalid,
+                row_token_idx, tok_row, tok_col, sb, so, logit_idx), \
+            rows, prop_of
+
+    def _verify_round(self, host, rows, prop_of, running, deliver) -> bool:
+        spec = self._spec
+        pool = self.pool
+        C = spec.k + 1
         faults.fire("engine.dispatch.verify")
         self._note_dispatch("verify")
         t_disp = self._t_dispatch
+        dev = self._h2d(host)
         prog = self._verify_program()
-        with _TraceAnnotation("pw.verify_step"):
-            ids, pool.k, pool.v = prog(
-                self.params, pool.k, pool.v, jnp.asarray(tokens),
-                jnp.asarray(positions), jnp.asarray(row_tables),
-                jnp.asarray(row_start), jnp.asarray(row_nvalid),
-                jnp.asarray(row_token_idx), jnp.asarray(tok_row),
-                jnp.asarray(tok_col), jnp.asarray(sb), jnp.asarray(so),
-                jnp.asarray(logit_idx),
-            )
-        t_sync0 = time.perf_counter()
+        with self._phase("pw.verify_step"):
+            ids, pool.k, pool.v = prog(self.params, pool.k, pool.v, *dev)
         ids = self._sync_host(ids)
-        t_sync1 = time.perf_counter()
-        obs.record_span("engine.sync", t_sync0, t_sync1, ctx=self._run_ctx)
-        self._note_sync()
-        # greedy accept scan: packed position base+c holds the target's
-        # argmax AFTER consuming input token c (c=0: the row's last
-        # emitted token — always valid; c>=1: draft c-1).  Output c is
-        # the true greedy token iff every input before it matched, so we
-        # emit until the input feeding the NEXT position diverges; the
-        # first mismatching position still yields one correct token (the
-        # free bonus).  Causality makes later garbage inputs harmless.
-        n_proposed = sum(nv - 1 for _a, _r, nv in rows)
-        n_accepted = 0
-        n_emitted = 0
-        done: list[_Active] = []
-        for act, i, nv in rows:
-            base = i * C
-            req = act.req
-            prop = prop_of.get(id(act), [])
-            emitted_n = 0
-            finished = False
-            for c in range(nv):
-                self._emit(req, int(ids[base + c]))
-                emitted_n += 1
-                n_emitted += 1
-                if len(req.emitted) >= req.max_new or (
-                    req.stop_token is not None
-                    and req.emitted[-1] == req.stop_token
-                ):
+        with self._phase("pw.round.deliver") as ph:
+            t_sync1 = time.perf_counter()
+            self._note_sync()
+            # greedy accept scan: packed position base+c holds the target's
+            # argmax AFTER consuming input token c (c=0: the row's last
+            # emitted token — always valid; c>=1: draft c-1).  Output c is
+            # the true greedy token iff every input before it matched, so we
+            # emit until the input feeding the NEXT position diverges; the
+            # first mismatching position still yields one correct token (the
+            # free bonus).  Causality makes later garbage inputs harmless.
+            n_proposed = sum(nv - 1 for _a, _r, nv in rows)
+            n_accepted = 0
+            n_emitted = 0
+            done: list[_Active] = []
+            for act, i, nv in rows:
+                base = i * C
+                req = act.req
+                prop = prop_of.get(id(act), [])
+                emitted_n = 0
+                finished = False
+                for c in range(nv):
+                    self._emit(req, int(ids[base + c]))
+                    emitted_n += 1
+                    n_emitted += 1
+                    if len(req.emitted) >= req.max_new or (
+                        req.stop_token is not None
+                        and req.emitted[-1] == req.stop_token
+                    ):
+                        finished = True
+                        break
+                    if c < nv - 1 and prop[c] != int(ids[base + c]):
+                        break  # draft refuted: later positions are phantom
+                n_accepted += emitted_n - 1
+                # roll back the rejected tail NOW: the pool must never hold
+                # phantom K/V past the round (written coverage stays exactly
+                # "every emitted token but the last", the engine invariant)
+                rollback = nv - emitted_n
+                if rollback:
+                    pool.truncate_slots(act.seq_id, rollback)
+                # capacity is judged AFTER rollback — the pre-extended
+                # n_tokens must not close a request its budget keeps open
+                if not finished and pool.sequence(
+                        act.seq_id).n_tokens >= self.max_seq_tokens:
                     finished = True
-                    break
-                if c < nv - 1 and prop[c] != int(ids[base + c]):
-                    break  # draft refuted: later positions are phantom
-            n_accepted += emitted_n - 1
-            # roll back the rejected tail NOW: the pool must never hold
-            # phantom K/V past the round (written coverage stays exactly
-            # "every emitted token but the last", the engine invariant)
-            rollback = nv - emitted_n
-            if rollback:
-                pool.truncate_slots(act.seq_id, rollback)
-            # capacity is judged AFTER rollback — the pre-extended
-            # n_tokens must not close a request its budget keeps open
-            if not finished and pool.sequence(
-                    act.seq_id).n_tokens >= self.max_seq_tokens:
-                finished = True
-            obs.record_span("engine.verify", t_disp, t_sync1, ctx=req.ctx,
-                            k=nv - 1, accepted=emitted_n - 1)
-            if finished:
-                done.append(act)
-        self._record_dispatch(prog, t_disp, t_sync1, items=n_emitted)
-        pool.stats.record_spec(
-            proposed=n_proposed, accepted=n_accepted, emitted=n_emitted,
-        )
-        for act in done:
-            running.remove(act)
-            self._release_seq(act)
-            deliver(act.req)
-            # a finished stream is drafter training data (the n-gram
-            # drafter's cross-request chain-hash table learns from it)
-            base_ctx = (list(act.admitted) if act.admitted is not None
-                        else list(act.req.prompt))
-            spec.note_release(base_ctx + [
-                int(t) for t in act.req.emitted[act.emit_base:]
-            ])
-        spec.note_round(n_proposed, n_accepted, n_emitted,
-                        ms=(t_sync1 - t_disp) * 1000.0)
+                obs.record_span("engine.verify", t_disp, t_sync1, ctx=req.ctx,
+                                k=nv - 1, accepted=emitted_n - 1)
+                if finished:
+                    done.append(act)
+            self._record_dispatch(prog, t_disp, t_sync1, items=n_emitted)
+            pool.stats.record_spec(
+                proposed=n_proposed, accepted=n_accepted, emitted=n_emitted,
+            )
+            for act in done:
+                running.remove(act)
+                self._release_seq(act)
+                deliver(act.req)
+                # a finished stream is drafter training data (the n-gram
+                # drafter's cross-request chain-hash table learns from it)
+                base_ctx = (list(act.admitted) if act.admitted is not None
+                            else list(act.req.prompt))
+                spec.note_release(base_ctx + [
+                    int(t) for t in act.req.emitted[act.emit_base:]
+                ])
+            spec.note_round(n_proposed, n_accepted, n_emitted,
+                            ms=(t_sync1 - t_disp) * 1000.0)
+            ph.set(emitted=n_emitted, finished=len(done))
         return True
 
     # -- Round-10: device-resident chained decode --------------------------
@@ -1880,59 +2035,54 @@ class PagedDecodeEngine:
             # ids are truncated host-side (wasted compute bounded by K)
             return min(K, max(rem, 1))
 
-        victims: list[_Active] = []
-        reserved = self._reserve_slots(running, pending, victims,
-                                       k_for=k_for)
-        if victims:
-            self._cascade_preempt(victims, running, pending)
-        if not reserved:
-            return None
-        B = self.max_batch_size
-        NB = self.max_blocks_per_seq
-        token = np.zeros(B, np.int32)
-        positions = np.zeros(B, np.int32)
-        sb = np.zeros((B, K), np.int32)
-        so = np.zeros((B, K), np.int32)
-        bt = np.zeros((B, NB), np.int32)
-        acts: list[_Active] = []
-        kreal: list[int] = []
-        for i, (act, slots) in enumerate(reserved):
-            seq = pool.sequence(act.seq_id)
-            token[i] = act.req.emitted[-1]
-            # extend_slots already advanced n_tokens by len(slots): the
-            # chain's first token writes at the first reserved position
-            positions[i] = seq.n_tokens - len(slots)
-            for t, (blk, off) in enumerate(slots):
-                sb[i, t] = blk
-                so[i, t] = off
-            bt[i, : len(seq.block_ids)] = seq.block_ids
-            acts.append(act)
-            kreal.append(len(slots))
-        samp = self._sampling_arrays(
-            [(i, act.req) for i, act in enumerate(acts)], B
-        )
-        faults.fire("engine.dispatch.chain")
-        self._note_dispatch("chain")
-        t_disp = self._t_dispatch
-        if samp is None:
-            prog = self._chained
-            with _TraceAnnotation("pw.chain_dispatch"):
-                ids, pool.k, pool.v = prog(
-                    self.params, pool.k, pool.v, jnp.asarray(token),
-                    jnp.asarray(positions), jnp.asarray(bt),
-                    jnp.asarray(sb), jnp.asarray(so),
-                )
-        else:
+        with self._phase("pw.round.build") as ph:
+            victims: list[_Active] = []
+            reserved = self._reserve_slots(running, pending, victims,
+                                           k_for=k_for)
+            if victims:
+                self._cascade_preempt(victims, running, pending)
+            if not reserved:
+                ph.set(kind="chain", rows=0, tokens=0, budget=0, waiting=0)
+                return None
+            B = self.max_batch_size
+            NB = self.max_blocks_per_seq
+            token = np.zeros(B, np.int32)
+            positions = np.zeros(B, np.int32)
+            sb = np.zeros((B, K), np.int32)
+            so = np.zeros((B, K), np.int32)
+            bt = np.zeros((B, NB), np.int32)
+            acts: list[_Active] = []
+            kreal: list[int] = []
+            for i, (act, slots) in enumerate(reserved):
+                seq = pool.sequence(act.seq_id)
+                token[i] = act.req.emitted[-1]
+                # extend_slots already advanced n_tokens by len(slots): the
+                # chain's first token writes at the first reserved position
+                positions[i] = seq.n_tokens - len(slots)
+                for t, (blk, off) in enumerate(slots):
+                    sb[i, t] = blk
+                    so[i, t] = off
+                bt[i, : len(seq.block_ids)] = seq.block_ids
+                acts.append(act)
+                kreal.append(len(slots))
             # the per-row PRNG key rides the scan carry; emit0 is the
             # row's absolute emit index at the chain's first step, so a
             # chain of K tokens lands bit-identically to K single steps
-            prog = self._sampled_programs()["chained"]
-            with _TraceAnnotation("pw.chain_dispatch_sampled"):
-                ids, pool.k, pool.v = prog(
-                    self.params, pool.k, pool.v, jnp.asarray(token),
-                    jnp.asarray(positions), jnp.asarray(bt),
-                    jnp.asarray(sb), jnp.asarray(so), *samp,
-                )
+            samp = self._sampling_arrays(
+                [(i, act.req) for i, act in enumerate(acts)], B
+            )
+            ph.set(kind="chain", rows=len(acts), tokens=sum(kreal),
+                   budget=B * K, waiting=0)
+        host = (token, positions, bt, sb, so)
+        faults.fire("engine.dispatch.chain")
+        self._note_dispatch("chain")
+        t_disp = self._t_dispatch
+        dev = self._h2d(host if samp is None else host + samp)
+        prog = self._chained if samp is None \
+            else self._sampled_programs()["chained"]
+        with self._phase("pw.chain_dispatch" if samp is None
+                         else "pw.chain_dispatch_sampled"):
+            ids, pool.k, pool.v = prog(self.params, pool.k, pool.v, *dev)
         try:
             # start the device->host copy NOW so it overlaps the chain's
             # tail and the host's bookkeeping; np.asarray later just
@@ -1990,27 +2140,30 @@ class PagedDecodeEngine:
             # overlap: poll the scheduler while the chain runs — an
             # arrival discovered here lands in pending and adapts the
             # NEXT round to K=1 (this chain is the bounded latency cost)
-            self._admit_arrivals(running, pending, poll, stop)
+            with self._phase("pw.round.admit") as ph:
+                self._admit_arrivals(running, pending, poll, stop)
+                ph.set(admitted=0, pending=len(pending))
             acts, kreal, ids_dev, t_disp, prog = inflight
-            t_sync0 = time.perf_counter()
-            ids_np = self._sync_host(ids_dev)  # ONE sync per K-token chain
-            t_sync1 = time.perf_counter()
-            # the host-blocked-on-device window (a subset of the
-            # device-busy span _note_sync closes below)
-            obs.record_span("engine.sync", t_sync0, t_sync1,
-                            ctx=self._run_ctx)
-            self._note_sync()
-            # per-request chain spans: the dispatch->sync window each row
-            # rode, under the REQUEST's trace (k = the row's chain depth)
-            for i, act in enumerate(acts):
-                obs.record_span("engine.chain", t_disp, t_sync1,
-                                ctx=act.req.ctx, k=kreal[i])
-            done, n_emitted = self._scan_chain(acts, kreal, ids_np, running)
-            self._record_dispatch(prog, t_disp, t_sync1,
-                                  items=n_emitted)
-            for act in done:
-                running.remove(act)
-                self._release_seq(act)
+            # ONE sync per K-token chain: the host-blocked-on-device
+            # window (a subset of the device-busy span _note_sync closes)
+            ids_np = self._sync_host(ids_dev)
+            with self._phase("pw.round.deliver") as ph:
+                t_sync1 = time.perf_counter()
+                self._note_sync()
+                # per-request chain spans: the dispatch->sync window each
+                # row rode, under the REQUEST's trace (k = its chain depth)
+                for i, act in enumerate(acts):
+                    act.req.n_chains += 1
+                    obs.record_span("engine.chain", t_disp, t_sync1,
+                                    ctx=act.req.ctx, k=kreal[i])
+                done, n_emitted = self._scan_chain(acts, kreal, ids_np,
+                                                   running)
+                self._record_dispatch(prog, t_disp, t_sync1,
+                                      items=n_emitted)
+                for act in done:
+                    running.remove(act)
+                    self._release_seq(act)
+                ph.set(emitted=n_emitted, finished=0)
             nxt = None
             # with a drafter armed, the chain is the FALLBACK, not the
             # hot loop: return after one dispatch so _step_round offers
@@ -2032,18 +2185,23 @@ class PagedDecodeEngine:
             # overlap: chain N's completion bookkeeping runs while the
             # device executes chain N+1 (the _note_sync/_note_dispatch
             # pair above already closed the device-idle window, so this
-            # work is correctly NOT counted as host gap)
-            for act in done:
-                deliver(act.req)
-            self.pool.stats.record_chain(
-                steps=self.chain_steps, slots=len(acts) * self.chain_steps,
-                emitted=n_emitted,
-            )
+            # work is correctly NOT counted as host gap) — a second
+            # pw.round.deliver, AFTER chain N+1's build/h2d/dispatch
+            with self._phase("pw.round.deliver", emitted=0,
+                             finished=len(done)):
+                for act in done:
+                    deliver(act.req)
+                self.pool.stats.record_chain(
+                    steps=self.chain_steps,
+                    slots=len(acts) * self.chain_steps, emitted=n_emitted,
+                )
             if nxt is None:
                 return True
             inflight = nxt
 
-    def _decode_round(self, reserved, running, deliver) -> None:
+    def _build_decode(self, reserved, ph) -> tuple:
+        """The numpy arrays of one 1-token-per-row step (inside the
+        caller's ``pw.round.build``): ``(host arrays, sampled?)``."""
         B = self.max_batch_size
         NB = self.max_blocks_per_seq
         token = np.zeros(B, np.int32)
@@ -2062,56 +2220,52 @@ class PagedDecodeEngine:
         samp = self._sampling_arrays(
             [(i, act.req) for i, (act, _s) in enumerate(reserved)], B
         )
+        ph.set(kind="step", rows=len(reserved), tokens=len(reserved),
+               budget=B, waiting=0)
+        host = (token, positions, bt, sb, so)
+        return (host if samp is None else host + samp), samp is not None
+
+    def _decode_round(self, step, reserved, running, deliver) -> None:
+        host, sampled = step
         faults.fire("engine.dispatch.step")
         self._note_dispatch("step")
         t_disp = self._t_dispatch
-        if samp is None:
-            prog = self._step
-            with _TraceAnnotation("pw.decode_step"):
-                ids, self.pool.k, self.pool.v = prog(
-                    self.params, self.pool.k, self.pool.v,
-                    jnp.asarray(token), jnp.asarray(positions),
-                    jnp.asarray(bt), jnp.asarray(sb), jnp.asarray(so),
-                )
-        else:
-            prog = self._sampled_programs()["step"]
-            with _TraceAnnotation("pw.decode_step_sampled"):
-                ids, self.pool.k, self.pool.v = prog(
-                    self.params, self.pool.k, self.pool.v,
-                    jnp.asarray(token), jnp.asarray(positions),
-                    jnp.asarray(bt), jnp.asarray(sb), jnp.asarray(so),
-                    *samp,
-                )
-        t_sync0 = time.perf_counter()
+        dev = self._h2d(host)
+        prog = self._sampled_programs()["step"] if sampled else self._step
+        with self._phase("pw.decode_step_sampled" if sampled
+                         else "pw.decode_step"):
+            ids, self.pool.k, self.pool.v = prog(
+                self.params, self.pool.k, self.pool.v, *dev,
+            )
         ids = self._sync_host(ids)
-        t_sync1 = time.perf_counter()
-        obs.record_span("engine.sync", t_sync0, t_sync1, ctx=self._run_ctx)
-        self._note_sync()
-        self._record_dispatch(prog, t_disp, t_sync1,
-                              items=len(reserved))
-        for act, _slot in reserved:
-            obs.record_span("engine.decode_step", t_disp, t_sync1,
-                            ctx=act.req.ctx)
-        # a per-step round IS a K=1 chain: recording it keeps the
-        # pathway_kv_chain_steps histogram's le=1 bucket meaningful —
-        # admission pressure forcing K back to 1 is visible there
-        self.pool.stats.record_chain(
-            steps=1, slots=len(reserved), emitted=len(reserved)
-        )
-        for i, (act, _slot) in enumerate(reserved):
-            self._emit(act.req, int(ids[i]))
-            if self._is_done(act.req, act.seq_id):
-                running.remove(act)
-                self._release_seq(act)
-                deliver(act.req)
+        with self._phase("pw.round.deliver") as ph:
+            t_sync1 = time.perf_counter()
+            self._note_sync()
+            self._record_dispatch(prog, t_disp, t_sync1,
+                                  items=len(reserved))
+            # a per-step round IS a K=1 chain: recording it keeps the
+            # pathway_kv_chain_steps histogram's le=1 bucket meaningful —
+            # admission pressure forcing K back to 1 is visible there
+            self.pool.stats.record_chain(
+                steps=1, slots=len(reserved), emitted=len(reserved)
+            )
+            finished = 0
+            for i, (act, _slot) in enumerate(reserved):
+                self._emit(act.req, int(ids[i]))
+                if self._is_done(act.req, act.seq_id):
+                    running.remove(act)
+                    self._release_seq(act)
+                    deliver(act.req)
+                    finished += 1
+            ph.set(emitted=len(reserved), finished=finished)
 
-    def _mixed_round(self, reserved, chunks, running, deliver) -> None:
-        """The ragged fused step over a token-PACKED stream: decode rows
+    def _build_mixed(self, reserved, chunks, ph) -> tuple:
+        """The numpy arrays of the ragged fused step over a token-PACKED
+        stream (inside the caller's ``pw.round.build``): decode rows
         contribute one token each, chunk rows a run of prompt tokens,
         sharing a ``mixed_tokens`` budget — so the dispatch's cost scales
         with the live token count (B + chunk headroom), never
-        B x chunk.  One dispatch serves both kinds; only the [B]
-        argmaxed ids come back."""
+        B x chunk.  Returns ``(host arrays, sampled?, rows, t)``."""
         B = self.max_batch_size
         C = self.prefill_chunk
         T = self.mixed_tokens
@@ -2211,82 +2365,89 @@ class PagedDecodeEngine:
         samp = self._sampling_arrays(
             [(r, act.req) for act, r, _f in rows], B
         )
+        # rows still in prefill that got no token this round: before their
+        # first chunk they wait (engine.prefill_wait), after it they skip
+        chunked = {id(a) for a, _r, f in rows if f >= 0}
+        waiting = [a for a in chunks if id(a) not in chunked]
+        for a in waiting:
+            if a.req.t_chunk0 is not None:
+                a.req.n_skipped += 1
+                a.req.n_rounds += 1
+        ph.set(kind="mixed", rows=len(rows), tokens=t, budget=T,
+               waiting=len(waiting))
+        host = (tokens, positions, row_tables, row_start, row_nvalid,
+                row_token_idx, tok_row, tok_col, sb, so, logit_idx)
+        return (host if samp is None else host + samp), samp is not None, \
+            rows, t
+
+    def _mixed_round(self, step, running, deliver) -> None:
+        """One dispatch serves decode rows and prompt chunks alike; only
+        the [B] argmaxed ids come back."""
+        host, sampled, rows, t = step
         faults.fire("engine.dispatch.mixed")
         self._note_dispatch("mixed")
         t_disp = self._t_dispatch
-        if samp is None:
-            prog = self._mixed
-            with _TraceAnnotation("pw.mixed_step"):
-                ids, self.pool.k, self.pool.v = prog(
-                    self.params, self.pool.k, self.pool.v,
-                    jnp.asarray(tokens), jnp.asarray(positions),
-                    jnp.asarray(row_tables), jnp.asarray(row_start),
-                    jnp.asarray(row_nvalid), jnp.asarray(row_token_idx),
-                    jnp.asarray(tok_row), jnp.asarray(tok_col),
-                    jnp.asarray(sb), jnp.asarray(so),
-                    jnp.asarray(logit_idx),
-                )
-        else:
-            prog = self._sampled_programs()["mixed"]
-            with _TraceAnnotation("pw.mixed_step_sampled"):
-                ids, self.pool.k, self.pool.v = prog(
-                    self.params, self.pool.k, self.pool.v,
-                    jnp.asarray(tokens), jnp.asarray(positions),
-                    jnp.asarray(row_tables), jnp.asarray(row_start),
-                    jnp.asarray(row_nvalid), jnp.asarray(row_token_idx),
-                    jnp.asarray(tok_row), jnp.asarray(tok_col),
-                    jnp.asarray(sb), jnp.asarray(so),
-                    jnp.asarray(logit_idx), *samp,
-                )
-        t_sync0 = time.perf_counter()
-        ids = self._sync_host(ids)
-        t_sync1 = time.perf_counter()
-        obs.record_span("engine.sync", t_sync0, t_sync1, ctx=self._run_ctx)
-        self._note_sync()
-        self._record_dispatch(prog, t_disp, t_sync1, items=t)
-        self.pool.stats.record_mixed_step(len(rows))
-        n_decode = sum(1 for _a, _r, f in rows if f < 0)
-        if n_decode:
-            # mixed rounds advance decode rows one token: a K=1 entry in
-            # the chain histogram (adaptive-K observability)
-            self.pool.stats.record_chain(
-                steps=1, slots=n_decode, emitted=n_decode
+        for act, _row, filled in rows:
+            if filled >= 0:
+                act.req.note_chunk(t_disp)
+        dev = self._h2d(host)
+        prog = self._sampled_programs()["mixed"] if sampled else self._mixed
+        with self._phase("pw.mixed_step_sampled" if sampled
+                         else "pw.mixed_step"):
+            ids, self.pool.k, self.pool.v = prog(
+                self.params, self.pool.k, self.pool.v, *dev,
             )
-        self.pool.stats.record_prefill_chunks(
-            sum(1 for _a, _r, f in rows if f >= 0)
-        )
-        for act, row, filled in rows:
-            if filled < 0:  # decode row
-                obs.record_span("engine.decode_step", t_disp, t_sync1,
-                                ctx=act.req.ctx)
-                self._emit(act.req, int(ids[row]))
-            else:
-                # the chunk's ride through this ragged dispatch, on the
-                # request's trace: [start, end) prompt positions streamed
-                obs.record_span("engine.prefill_chunk", t_disp, t_sync1,
-                                ctx=act.req.ctx, start=act.n_filled,
-                                end=filled)
-                act.n_filled = filled
-                if filled < len(act.tokens):
-                    continue  # mid-prefill: this row's logits are garbage
-                # prefill complete — register the prompt's full blocks for
-                # sharing only NOW that their K/V is actually written
-                # (registering at admission would hand still-empty blocks
-                # to a concurrent request), then emit the first token from
-                # the dispatch's device-side argmax
-                if self.prefix is not None and act.prefix_keys:
-                    self.prefix.insert(
-                        act.prefix_keys,
-                        self.pool.sequence(act.seq_id).block_ids,
-                    )
-                self._drop_inflight_keys(act)
-                act.tokens = None
-                act.prefix_keys = None
-                self._emit(act.req, int(ids[row]))
-            if self._is_done(act.req, act.seq_id):
-                running.remove(act)
-                self._release_seq(act)
-                deliver(act.req)
+        ids = self._sync_host(ids)
+        with self._phase("pw.round.deliver") as ph:
+            t_sync1 = time.perf_counter()
+            self._note_sync()
+            self._record_dispatch(prog, t_disp, t_sync1, items=t)
+            self.pool.stats.record_mixed_step(len(rows))
+            self.pool.stats.record_mixed_tokens(t, self.mixed_tokens)
+            n_decode = sum(1 for _a, _r, f in rows if f < 0)
+            if n_decode:
+                # mixed rounds advance decode rows one token: a K=1 entry
+                # in the chain histogram (adaptive-K observability)
+                self.pool.stats.record_chain(
+                    steps=1, slots=n_decode, emitted=n_decode
+                )
+            self.pool.stats.record_prefill_chunks(len(rows) - n_decode)
+            emitted = finished = 0
+            for act, row, filled in rows:
+                if filled < 0:  # decode row
+                    act.req.n_mixed += 1
+                    self._emit(act.req, int(ids[row]))
+                else:
+                    # the chunk's ride through this ragged dispatch, on
+                    # the request's trace: [start, end) positions streamed
+                    obs.record_span("engine.prefill_chunk", t_disp, t_sync1,
+                                    ctx=act.req.ctx, start=act.n_filled,
+                                    end=filled)
+                    act.n_filled = filled
+                    if filled < len(act.tokens):
+                        continue  # mid-prefill: the row's logits are garbage
+                    # prefill complete — register the prompt's full blocks
+                    # for sharing only NOW that their K/V is actually
+                    # written (registering at admission would hand
+                    # still-empty blocks to a concurrent request), then
+                    # emit the first token from the dispatch's device-side
+                    # argmax
+                    if self.prefix is not None and act.prefix_keys:
+                        self.prefix.insert(
+                            act.prefix_keys,
+                            self.pool.sequence(act.seq_id).block_ids,
+                        )
+                    self._drop_inflight_keys(act)
+                    act.tokens = None
+                    act.prefix_keys = None
+                    self._emit(act.req, int(ids[row]))
+                emitted += 1
+                if self._is_done(act.req, act.seq_id):
+                    running.remove(act)
+                    self._release_seq(act)
+                    deliver(act.req)
+                    finished += 1
+            ph.set(emitted=emitted, finished=finished)
 
     def _drop_inflight_keys(self, act: _Active) -> None:
         """Remove `act`'s registrations from the in-flight prefix map

@@ -63,7 +63,7 @@ from .. import faults, obs
 from .backend import CacheBackend, UnsupportedCacheOp, make_backend
 from .block_pool import PoolExhausted, SequenceState
 from .engine import (PagedDecodeEngine, _Active, _Request,  # noqa: F401
-                     _TraceAnnotation, _WatchdogSync, resolve_tp)
+                     _WatchdogSync, resolve_tp)
 
 # live caches by metrics name — same contract as block_pool._LIVE_POOLS:
 # a second concurrent cache gets a "#n" suffix; a discarded one frees
@@ -363,6 +363,7 @@ class StateDecodeEngine:
     _wrap_failure = PagedDecodeEngine._wrap_failure
     _try_degrade = PagedDecodeEngine._try_degrade
     _emit = PagedDecodeEngine._emit
+    _phase = PagedDecodeEngine._phase
     _sync_host = PagedDecodeEngine._sync_host
     _note_sync = PagedDecodeEngine._note_sync
     _note_dispatch = PagedDecodeEngine._note_dispatch
@@ -699,6 +700,7 @@ class StateDecodeEngine:
             )
             act.n_filled = resident
             act.n_diverted = resident
+        req.note_admitted(time.perf_counter())
         running.append(act)
         return "admitted"
 
@@ -761,27 +763,22 @@ class StateDecodeEngine:
         t_disp = self._t_dispatch
         if samp is None:
             prog = self._step
-            with _TraceAnnotation("pw.ssd_decode_step"):
+            with obs.trace_annotation("pw.ssd_decode_step"):
                 ids, self.pool.state = prog(
                     self.params, self.pool.state, jnp.asarray(token),
                     jnp.asarray(slots),
                 )
         else:
             prog = self._sampled_programs()["step"]
-            with _TraceAnnotation("pw.ssd_decode_step_sampled"):
+            with obs.trace_annotation("pw.ssd_decode_step_sampled"):
                 ids, self.pool.state = prog(
                     self.params, self.pool.state, jnp.asarray(token),
-                    jnp.asarray(slots), *samp,
+                    jnp.asarray(slots), *map(jnp.asarray, samp),
                 )
-        t_sync0 = time.perf_counter()
         ids = self._sync_host(ids)
         t_sync1 = time.perf_counter()
-        obs.record_span("engine.sync", t_sync0, t_sync1, ctx=self._run_ctx)
         self._note_sync()
         self._record_dispatch(prog, t_disp, t_sync1, items=len(acts))
-        for act in acts:
-            obs.record_span("engine.decode_step", t_disp, t_sync1,
-                            ctx=act.req.ctx)
         self.pool.stats.record_chain(
             steps=1, slots=len(acts), emitted=len(acts)
         )
@@ -840,24 +837,26 @@ class StateDecodeEngine:
         faults.fire("engine.dispatch.mixed")
         self._note_dispatch("mixed")
         t_disp = self._t_dispatch
+        for act, _row, filled in rows:
+            if filled >= 0:
+                act.req.note_chunk(t_disp)
         if samp is None:
             prog = self._mixed
-            with _TraceAnnotation("pw.ssd_mixed_step"):
+            with obs.trace_annotation("pw.ssd_mixed_step"):
                 ids, self.pool.state = prog(
                     self.params, self.pool.state, jnp.asarray(tokens),
                     jnp.asarray(n_valid), jnp.asarray(slots),
                 )
         else:
             prog = self._sampled_programs()["mixed"]
-            with _TraceAnnotation("pw.ssd_mixed_step_sampled"):
+            with obs.trace_annotation("pw.ssd_mixed_step_sampled"):
                 ids, self.pool.state = prog(
                     self.params, self.pool.state, jnp.asarray(tokens),
-                    jnp.asarray(n_valid), jnp.asarray(slots), *samp,
+                    jnp.asarray(n_valid), jnp.asarray(slots),
+                    *map(jnp.asarray, samp),
                 )
-        t_sync0 = time.perf_counter()
         ids = self._sync_host(ids)
         t_sync1 = time.perf_counter()
-        obs.record_span("engine.sync", t_sync0, t_sync1, ctx=self._run_ctx)
         self._note_sync()
         self._record_dispatch(prog, t_disp, t_sync1,
                               items=int(n_valid.sum()))
@@ -872,8 +871,7 @@ class StateDecodeEngine:
         )
         for act, row, filled in rows:
             if filled < 0:  # decode row
-                obs.record_span("engine.decode_step", t_disp, t_sync1,
-                                ctx=act.req.ctx)
+                act.req.n_mixed += 1
                 self._emit(act.req, int(ids[row]))
             else:
                 obs.record_span("engine.prefill_chunk", t_disp, t_sync1,
@@ -925,12 +923,12 @@ class StateDecodeEngine:
         )
         if samp is None:
             prog = self._chained
-            with _TraceAnnotation("pw.ssd_chain_dispatch"):
+            with obs.trace_annotation("pw.ssd_chain_dispatch"):
                 ids, self.pool.state = prog(*base)
         else:
             prog = self._sampled_programs()["chained"]
-            with _TraceAnnotation("pw.ssd_chain_dispatch_sampled"):
-                ids, self.pool.state = prog(*base, *samp)
+            with obs.trace_annotation("pw.ssd_chain_dispatch_sampled"):
+                ids, self.pool.state = prog(*base, *map(jnp.asarray, samp))
         try:
             ids.copy_to_host_async()
         except Exception:  # noqa: BLE001 - optional fast path

@@ -16,6 +16,7 @@ from .tracer import (  # noqa: F401
     export_otlp,
     maybe_start_flusher_from_env,
     new_trace_id,
+    phase,
     record_span,
     recorder,
     reset_current,
@@ -25,6 +26,7 @@ from .tracer import (  # noqa: F401
     span,
     start_flusher,
     start_span,
+    trace_annotation,
     use_context,
 )
 
@@ -33,7 +35,7 @@ __all__ = [
     "FlightRecorder", "Span", "chrome_trace_dump",
     "context_from_trace_header", "current_context", "disabled", "event",
     "export_otlp", "maybe_start_flusher_from_env", "new_trace_id",
-    "record_span", "recorder", "reset_current", "sanitize_trace_id",
-    "set_current", "shutdown", "span", "start_flusher", "start_span",
-    "use_context",
+    "phase", "record_span", "recorder", "reset_current",
+    "sanitize_trace_id", "set_current", "shutdown", "span", "start_flusher",
+    "start_span", "trace_annotation", "use_context",
 ]

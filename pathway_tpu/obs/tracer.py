@@ -407,6 +407,58 @@ def event(name: str, ctx: tuple | None = None, **attrs) -> Span:
     return record_span(name, now, now, ctx=ctx, **attrs)
 
 
+_annotation_cls = None
+
+
+def trace_annotation(name: str, **attrs):
+    """``jax.profiler.TraceAnnotation(name, **attrs)``: a span on the
+    host plane of a running ``jax.profiler`` trace, on that trace's own
+    clock (free when no profile runs).  jax is imported on first use —
+    this module stays importable without it — and a nullcontext stands
+    in on a jax too old to have the class."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        import contextlib
+
+        import jax
+
+        cls = getattr(jax.profiler, "TraceAnnotation", None)
+        _annotation_cls = cls if cls is not None else (
+            lambda _name, **_attrs: contextlib.nullcontext())
+    return _annotation_cls(name, **attrs)
+
+
+class phase:
+    """One helper, two sinks, one name: a context manager that enters a
+    :func:`trace_annotation` (so the interval shows in a device trace's
+    host plane, on the device's clock) and on exit lands the same
+    interval in the flight recorder through :func:`record_span`.
+    ``set()`` adds attributes known only at the end of the body; they
+    reach the recorder (the annotation's are fixed at entry)."""
+
+    __slots__ = ("name", "ctx", "attrs", "t0", "t1", "_ann")
+
+    def __init__(self, name: str, ctx: tuple | None = None, **attrs):
+        self.name = name
+        self.ctx = ctx
+        self.attrs = attrs
+        self.t1: float | None = None
+
+    def set(self, **attrs) -> None:
+        self.attrs.update(attrs)
+
+    def __enter__(self) -> "phase":
+        self._ann = trace_annotation(self.name, **self.attrs)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(exc_type, exc, tb)
+        record_span(self.name, self.t0, self.t1, self.ctx, **self.attrs)
+
+
 class disabled:
     """Context manager: suppress recording (the bench's overhead A/B)."""
 

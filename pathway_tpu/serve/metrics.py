@@ -150,6 +150,15 @@ class KVCacheStats:
       path seconds between a chain's results landing and the next chain
       being queued — the window the device may sit idle; ~0 when the
       double-buffered overlap is working)
+    - ``pathway_kv_round_seconds_total{pool,phase}`` counter (engine-thread
+      seconds by phase of a round: ``admit``, ``build``, ``h2d``,
+      ``dispatch`` (the program call's enqueue), ``sync``, ``deliver`` —
+      the ``pw.round.*`` phases of kvcache/engine.py; their sum is the
+      engine thread's time, and everything but ``sync`` is host work)
+    - ``pathway_kv_mixed_tokens_used_total{pool}`` /
+      ``pathway_kv_mixed_tokens_budget_total{pool}`` counters (packed
+      tokens the mixed dispatches carried over the ``mixed_tokens`` they
+      had room for; used/budget = how full the ragged step runs)
     - ``pathway_kv_spec_proposed_total{pool}``  counter (Round-18: draft
       tokens proposed into verify dispatches)
     - ``pathway_kv_spec_accepted_total{pool}``  counter (draft tokens the
@@ -194,6 +203,9 @@ class KVCacheStats:
         self.chain_slots = 0
         self.chain_emitted = 0
         self.host_gap_s = 0.0
+        self.round_s: dict[str, float] = {}
+        self.mixed_tokens_used = 0
+        self.mixed_tokens_budget = 0
         # Round-18 speculative decoding: proposed/accepted/rejected draft
         # tokens, total verify-emitted tokens and verify dispatches
         self.spec_proposed = 0
@@ -278,6 +290,18 @@ class KVCacheStats:
         the next chain being queued on the device."""
         with self._lock:
             self.host_gap_s += seconds
+
+    def record_round(self, phase: str, seconds: float) -> None:
+        """Engine-thread time one phase of a round took (``admit``,
+        ``build``, ``h2d``, ``dispatch``, ``sync``, ``deliver``)."""
+        with self._lock:
+            self.round_s[phase] = self.round_s.get(phase, 0.0) + seconds
+
+    def record_mixed_tokens(self, used: int, budget: int) -> None:
+        """Packed tokens one mixed dispatch carried, of its budget."""
+        with self._lock:
+            self.mixed_tokens_used += used
+            self.mixed_tokens_budget += budget
 
     def record_engine_restart(self, rebuild_seconds: float) -> None:
         """One supervised engine restart (pool rebuild time only; the
@@ -374,6 +398,9 @@ class KVCacheStats:
                 "chain_emitted": self.chain_emitted,
                 "chain_occupancy": self.chain_occupancy,
                 "host_gap_s": self.host_gap_s,
+                "round_s": dict(self.round_s),
+                "mixed_tokens_used": self.mixed_tokens_used,
+                "mixed_tokens_budget": self.mixed_tokens_budget,
                 "spec_proposed": self.spec_proposed,
                 "spec_accepted": self.spec_accepted,
                 "spec_rejected": self.spec_rejected,
@@ -921,6 +948,9 @@ def _render_kv_lines() -> list[str]:
         "# TYPE pathway_kv_chain_emitted_total counter",
         "# TYPE pathway_kv_chain_occupancy gauge",
         "# TYPE pathway_kv_host_gap_seconds_total counter",
+        "# TYPE pathway_kv_round_seconds_total counter",
+        "# TYPE pathway_kv_mixed_tokens_used_total counter",
+        "# TYPE pathway_kv_mixed_tokens_budget_total counter",
         "# TYPE pathway_kv_spec_proposed_total counter",
         "# TYPE pathway_kv_spec_accepted_total counter",
         "# TYPE pathway_kv_spec_rejected_total counter",
@@ -1023,6 +1053,19 @@ def _render_kv_lines() -> list[str]:
         lines.append(
             f"pathway_kv_host_gap_seconds_total{{{lbl}}} "
             f"{snap['host_gap_s']:.6f}"
+        )
+        for phase, seconds in sorted(snap["round_s"].items()):
+            lines.append(
+                f'pathway_kv_round_seconds_total{{{lbl},phase="{phase}"}} '
+                f"{seconds:.6f}"
+            )
+        lines.append(
+            f"pathway_kv_mixed_tokens_used_total{{{lbl}}} "
+            f"{snap['mixed_tokens_used']}"
+        )
+        lines.append(
+            f"pathway_kv_mixed_tokens_budget_total{{{lbl}}} "
+            f"{snap['mixed_tokens_budget']}"
         )
         # Round-18 speculative decoding: draft proposal/acceptance flow
         lines.append(

@@ -281,7 +281,7 @@ def test_chained_request_span_tree_admission_to_delivery(params):
         s.name for s in spans if s.trace_id == run.trace_id
     }
     assert "engine.device.chain" in run_names  # chain dispatch->sync
-    assert "engine.sync" in run_names          # the [B, K] ids collect
+    assert "pw.round.sync" in run_names        # the [B, K] ids collect
     assert "engine.host_gap" in run_names      # host-on-critical-path
     # two requests, distinct traces
     assert len({r.trace_id for r in reqs}) == 2

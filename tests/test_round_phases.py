@@ -1,0 +1,340 @@
+"""Engine rounds and request lifecycles traced from inside (ISSUE 25).
+
+- ``obs.phase``: one helper, two sinks (the profiler's host plane and the
+  flight recorder), one name;
+- the ``pw.round.*`` phases and the program-call phases of an engine run
+  are contiguous, never overlap and cover the engine thread's time, on the
+  mixed, the step and the chained path;
+- a request's ``engine.pending`` + ``engine.prefill_wait`` +
+  ``engine.prefill`` is its recorded TTFT, also across a preemption, with
+  one span per transition and none per token;
+- the hoist of the host-to-device transfers out of the call expressions
+  changed no token (``h2d_hoist_tokens.json``: the parent commit's tokens
+  for the runs of ``_hoist_runs``, taken on this CPU before the hoist).
+"""
+
+import json
+import os
+
+import jax
+import numpy as np
+import pytest
+
+from pathway_tpu import obs
+from pathway_tpu.kvcache import PagedDecodeEngine
+from pathway_tpu.models.decoder import DecoderConfig, init_decoder_params
+from pathway_tpu.obs import tracer
+
+_CFG = DecoderConfig(
+    vocab_size=64, d_model=64, n_layers=2, n_heads=8, d_ff=128, max_len=128
+)
+# wide enough that a round's device work (milliseconds on the CPU) stands
+# well above the microseconds of interpreter time between two phases
+_WIDE = DecoderConfig(
+    vocab_size=64, d_model=512, n_layers=4, n_heads=8, d_ff=1024, max_len=128
+)
+_PHASES = ("admit", "build", "h2d", "sync", "deliver")
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_decoder_params(_CFG, jax.random.PRNGKey(0))
+
+
+@pytest.fixture(autouse=True)
+def _clean_recorder():
+    rec = obs.recorder()
+    rec.clear()
+    rec.enabled = True
+    yield
+    rec.clear()
+    rec.enabled = True
+
+
+@pytest.fixture(scope="module")
+def wide_params():
+    return init_decoder_params(_WIDE, jax.random.PRNGKey(0))
+
+
+def _engine(params, name, cfg=_CFG, **kw):
+    kw.setdefault("num_blocks", 96)
+    kw.setdefault("block_size", 4)
+    kw.setdefault("max_batch_size", 4)
+    kw.setdefault("seq_buckets", (16, 32, 64))
+    kw.setdefault("prefill_chunk", 8)
+    kw.setdefault("chain_steps", 8)
+    return PagedDecodeEngine(cfg, params, name=name, **kw)
+
+
+def _prompts(sizes, seed=25):
+    rng = np.random.default_rng(seed)
+    return [[int(t) for t in rng.integers(0, _CFG.vocab_size, size=n)]
+            for n in sizes]
+
+
+# -- obs.phase ------------------------------------------------------------
+
+
+class _FakeAnnotation:
+    seen: list = []
+
+    def __init__(self, name, **attrs):
+        self.seen.append(["new", name, attrs])
+
+    def __enter__(self):
+        self.seen.append(["enter"])
+
+    def __exit__(self, *exc):
+        self.seen.append(["exit"])
+
+
+@pytest.mark.parametrize("recording", [True, False])
+def test_phase_one_span_and_the_annotation(monkeypatch, recording):
+    """A phase enters the profiler annotation under its own name and
+    attributes, and lands ONE span of the same name in the recorder, with
+    the attributes set in its body too; with recording disabled the
+    annotation is still entered and no span is written."""
+    monkeypatch.setattr(tracer, "_annotation_cls", _FakeAnnotation)
+    _FakeAnnotation.seen = []
+    ctx = (obs.new_trace_id(), 0)
+    if recording:
+        with obs.phase("pw.round.build", ctx, kind="mixed") as ph:
+            ph.set(rows=3)
+    else:
+        with obs.disabled(), obs.phase("pw.round.build", ctx, kind="mixed"):
+            pass
+    assert _FakeAnnotation.seen == [
+        ["new", "pw.round.build", {"kind": "mixed"}], ["enter"], ["exit"]]
+    spans = obs.recorder().snapshot()
+    if not recording:
+        assert spans == []
+        return
+    assert [s.name for s in spans] == ["pw.round.build"]
+    (s,) = spans
+    assert s.trace_id == ctx[0] and s.attrs == {"kind": "mixed", "rows": 3}
+    assert (s.t0, s.t1) == (ph.t0, ph.t1) and s.t1 >= s.t0
+
+
+def test_phase_enters_the_real_annotation_class():
+    """Without the fake: what a phase enters is jax's TraceAnnotation."""
+    tracer._annotation_cls = None
+    with obs.phase("pw.round.sync", perf_ns=1) as ph:
+        assert isinstance(ph._ann, jax.profiler.TraceAnnotation)
+    assert [s.name for s in obs.recorder().snapshot()] == ["pw.round.sync"]
+
+
+# -- the phases of a run ----------------------------------------------------
+
+
+@pytest.mark.parametrize("path,kw,n_new", [
+    # prompts of 3 chunks, one token each: mixed rounds and nothing else
+    ("mixed", {"chain_steps": 8}, 1),
+    # no chaining allowed: prefill, then one-token-per-row steps
+    ("step", {"chain_steps": 1}, 10),
+    # a quiet queue after prefill: double-buffered chains
+    ("chain", {"chain_steps": 4}, 14),
+])
+def test_round_phases_tile_the_engine_thread(wide_params, path, kw, n_new):
+    eng = _engine(wide_params, "t_phases_" + path, cfg=_WIDE, **kw)
+    sizes = (20, 17, 23, 9, 21, 12)
+    # compile every shape first, on other prompts (no prefix-cache hits)
+    eng.generate_batch([(p, n_new) for p in _prompts(sizes, seed=1)])
+    obs.recorder().clear()
+    before = eng.pool.stats.snapshot()
+    eng.generate_batch([(p, n_new) for p in _prompts(sizes)])
+    spans = obs.recorder().snapshot()
+    (run,) = [s for s in spans if s.name == "engine.run"]
+    phases = sorted((s for s in spans if s.trace_id == run.trace_id
+                     and s.name.startswith("pw.")), key=lambda s: s.t0)
+    names = {s.name for s in phases}
+    assert {"pw.round." + p for p in _PHASES} <= names
+    calls = names - {"pw.round." + p for p in _PHASES}
+    assert calls and calls <= {"pw.mixed_step", "pw.decode_step",
+                               "pw.chain_dispatch"}
+    kinds = {s.attrs["kind"] for s in phases if s.name == "pw.round.build"}
+    assert path in kinds, kinds
+    assert kinds <= {"mixed", "step", "chain", "none"}
+    # in program order on one thread: contiguous, never overlapping
+    assert {s.tid for s in phases} == {run.tid}
+    assert phases[0].t0 >= run.t0 and phases[-1].t1 <= run.t1
+    for a, b in zip(phases, phases[1:]):
+        assert b.t0 >= a.t1, (a.name, b.name)
+    covered = sum(s.t1 - s.t0 for s in phases)
+    assert covered >= 0.95 * (run.t1 - run.t0), (
+        covered, run.t1 - run.t0)
+    # the same time, by phase, in the pool's counters
+    snap = eng.pool.stats.snapshot()
+    assert set(snap["round_s"]) == set(_PHASES) | {"dispatch"}
+    grown = sum(snap["round_s"].values()) - sum(before["round_s"].values())
+    assert grown == pytest.approx(covered, rel=1e-6)
+    # every build says what it packed; every sync carries the anchor
+    for s in phases:
+        if s.name == "pw.round.build":
+            assert {"rows", "tokens", "budget", "waiting"} <= set(s.attrs)
+            assert s.attrs["tokens"] <= s.attrs["budget"]
+        elif s.name == "pw.round.sync":
+            assert abs(s.attrs["perf_ns"] * 1e-9 - s.t0) < 1e-3
+        elif s.name == "pw.round.h2d":
+            assert s.attrs["arrays"] in (5, 11) and s.attrs["bytes"] > 0
+    if path == "chain":
+        # chain N's callbacks run AFTER chain N+1 went out: a deliver
+        # follows a chain dispatch before the next sync, truthfully
+        order = [s.name for s in phases]
+        i = order.index("pw.chain_dispatch", order.index(
+            "pw.chain_dispatch") + 1)
+        assert order[i + 1] == "pw.round.deliver"
+        assert order[i + 2] == "pw.round.admit"
+    mixed = [s for s in phases if s.name == "pw.round.build"
+             and s.attrs["kind"] == "mixed"]
+    assert len(mixed) == snap["mixed_steps"] - before["mixed_steps"]
+    assert len(mixed) * (4 + 8) \
+        == snap["mixed_tokens_budget"] - before["mixed_tokens_budget"]
+    assert sum(s.attrs["tokens"] for s in mixed) \
+        == snap["mixed_tokens_used"] - before["mixed_tokens_used"]
+
+
+def test_round_counters_are_on_metrics(params):
+    """The operator's view of the same seconds: /metrics carries the
+    round's time by phase and the mixed steps' fill, beside the host gap."""
+    from pathway_tpu.serve import metrics as M
+
+    eng = _engine(params, "t_round_metrics")
+    eng.generate_batch([(p, 6) for p in _prompts((20, 9, 13))])
+    snap = eng.pool.stats.snapshot()
+    lines = M.render_prometheus_lines()
+    lbl = 'pool="t_round_metrics"'
+    for phase in _PHASES + ("dispatch",):
+        line = next(x for x in lines if x.startswith(
+            f'pathway_kv_round_seconds_total{{{lbl},phase="{phase}"}} '))
+        assert float(line.split()[-1]) == pytest.approx(
+            snap["round_s"][phase], abs=1e-6)
+    assert f"pathway_kv_mixed_tokens_used_total{{{lbl}}} " \
+        f"{snap['mixed_tokens_used']}" in lines
+    assert f"pathway_kv_mixed_tokens_budget_total{{{lbl}}} " \
+        f"{snap['mixed_steps'] * eng.mixed_tokens}" in lines
+    assert 0 < snap["mixed_tokens_used"] <= snap["mixed_tokens_budget"]
+    assert any(x.startswith(f"pathway_kv_host_gap_seconds_total{{{lbl}}}")
+               for x in lines)
+
+
+# -- a request's lifecycle ----------------------------------------------------
+
+
+def _lifecycle(spans):
+    """trace id -> name -> spans, for the engine.* spans of requests."""
+    out: dict = {}
+    for s in spans:
+        if s.name.startswith("engine."):
+            out.setdefault(s.trace_id, {}).setdefault(s.name, []).append(s)
+    return {t: by for t, by in out.items() if "engine.request" in by}
+
+
+@pytest.mark.parametrize("preempt", [False, True])
+def test_request_lifecycle_sums_to_recorded_ttft(params, preempt):
+    if preempt:
+        # 12 usable blocks of 4: four requests of 10 + 10 tokens cannot
+        # coexist, so decode MUST preempt (tests/test_kvcache.py)
+        eng = PagedDecodeEngine(
+            _CFG, params, num_blocks=13, block_size=4, max_batch_size=4,
+            seq_buckets=(12, 20), prefix_sharing=False, name="t_life_oom")
+        reqs = [(p, 10) for p in _prompts((10, 10, 10, 10), seed=3)]
+    else:
+        eng = _engine(params, "t_life")
+        reqs = [(p, 12) for p in _prompts((20, 5, 23, 9, 17, 12))]
+    before = eng.pool.stats.snapshot()
+    out = eng.generate_batch(list(reqs))
+    assert [len(o) for o in out] == [n for _p, n in reqs]
+    after = eng.pool.stats.snapshot()
+    assert (after["preemptions"] > before["preemptions"]) == preempt
+    spans = obs.recorder().snapshot()
+    assert not [s for s in spans if s.name == "engine.decode_step"]
+    assert not [s for s in spans if s.name == "engine.sync"]
+    life = _lifecycle(spans)
+    assert len(life) == len(reqs)
+    ttfts = []
+    readmits = 0
+    for by in life.values():
+        (root,) = by["engine.request"]
+        first = root.t0 + root.attrs["ttft_s"]
+        ttfts.append(root.attrs["ttft_s"])
+        for name in ("engine.pending", "engine.prefill_wait",
+                     "engine.prefill", "engine.decode"):
+            plain = [s for s in by.get(name, ())
+                     if not (s.attrs or {}).get("readmit")]
+            assert len(plain) == 1, (name, by.get(name))
+            readmits += len(by.get(name, ())) - 1
+            assert all(s.parent_id == root.span_id for s in by[name])
+        # what came before the first token tiles [arrival, first token]
+        parts = sorted((s for name in ("engine.pending",
+                                       "engine.prefill_wait",
+                                       "engine.prefill")
+                        for s in by[name] if s.t1 <= first + 1e-9),
+                       key=lambda s: s.t0)
+        assert parts[0].t0 == root.t0
+        for a, b in zip(parts, parts[1:]):
+            assert b.t0 == a.t1
+        assert sum(s.t1 - s.t0 for s in parts) == pytest.approx(
+            root.attrs["ttft_s"], abs=1e-3)
+        (dec,) = by["engine.decode"]
+        assert dec.t0 == pytest.approx(first, abs=1e-9)
+        assert dec.t1 <= root.t1
+        assert dec.attrs["tokens"] == root.attrs["emitted"] - 1
+        (pre,) = [s for s in by["engine.prefill"]
+                  if not (s.attrs or {}).get("readmit")]
+        assert pre.attrs["chunks"] >= 1
+        assert pre.attrs["rounds"] \
+            == pre.attrs["chunks"] + pre.attrs["rounds_skipped"]
+    assert (readmits > 0) == preempt
+    # the very numbers the pool recorded
+    recent = list(eng.pool.stats.recent_ttfts)[-len(reqs):]
+    assert sorted(recent) == pytest.approx(sorted(ttfts), abs=1e-9)
+
+
+def test_state_engine_shares_the_lifecycle(params):
+    """The constant-memory engine borrows the helpers, so its requests
+    carry the same lifecycle and its runs the shared phases."""
+    from pathway_tpu.kvcache.statecache import StateDecodeEngine
+
+    eng = StateDecodeEngine(_CFG, params, max_slots=8, max_batch_size=4,
+                            prefill_chunk=8, chain_steps=4,
+                            name="t_life_state")
+    out = eng.generate_batch([(p, 9) for p in _prompts((20, 5, 11))])
+    assert [len(o) for o in out] == [9, 9, 9]
+    spans = obs.recorder().snapshot()
+    assert not [s for s in spans if s.name == "engine.decode_step"]
+    for by in _lifecycle(spans).values():
+        (root,) = by["engine.request"]
+        parts = [by[n][0] for n in ("engine.pending", "engine.prefill_wait",
+                                    "engine.prefill")]
+        assert sum(s.t1 - s.t0 for s in parts) == pytest.approx(
+            root.attrs["ttft_s"], abs=1e-3)
+        assert len(by["engine.decode"]) == 1
+    names = {s.name for s in spans}
+    assert {"pw.round.admit", "pw.round.sync", "pw.round.deliver"} <= names
+
+
+# -- the hoist changed no token ---------------------------------------------
+
+
+def _hoist_runs(params, name, **kw):
+    eng = _engine(params, "t_hoist_" + name, **kw)
+    prompts = _prompts((5, 19, 11, 26, 7, 14))
+    greedy = eng.generate_batch([(p, 12) for p in prompts])
+    sampled = eng.generate_batch([
+        (p, 12, {"sampling": (0.8, 8, 0.9, 1000 + i)})
+        for i, p in enumerate(prompts)])
+    return greedy, sampled
+
+
+@pytest.mark.parametrize("name,kw", [
+    ("chained", {"chain_steps": 8}),
+    ("step", {"chain_steps": 1}),
+    ("legacy", {"chunked_prefill": False, "chain_steps": 8}),
+])
+def test_tokens_identical_before_and_after_the_h2d_hoist(params, name, kw):
+    with open(os.path.join(os.path.dirname(__file__),
+                           "h2d_hoist_tokens.json")) as f:
+        before = json.load(f)
+    greedy, sampled = _hoist_runs(params, name, **kw)
+    assert greedy == before[name + "_greedy"]
+    assert sampled == before[name + "_sampled"]
