@@ -282,9 +282,10 @@ def program_table(engine, expect_kernel: bool, since: float) -> list[dict]:
             "all_gather": len(re.findall(r"\ball-gather(-start)?\(", txt)),
             "argument_bytes": ma.argument_size_in_bytes,
             "temp_bytes": ma.temp_size_in_bytes,
-            # whole-pool copies in the compiled text: four are the way in
-            # and out between the device's compact layout of the pool and
-            # the row-major tiles the kernel takes
+            # whole-pool copies in the compiled text: none while a block
+            # of the pool fills whole tiles (block_pool.py); four where it
+            # does not (tp = 4: 320 lanes a shard), the way in and out
+            # between XLA's layout of the pool and the kernel's
             "pool_sized_copies": len(re.findall(
                 r"= \w+\[" + ",".join(map(str, shard_shape))
                 + r"\]\{[^}]*\} copy\(", txt)),

@@ -1,10 +1,22 @@
 """Fixed-size HBM block pool for paged KV caching.
 
 One K and one V array hold the entire cache for every live sequence:
-``(n_layers, num_blocks, block_size, n_kv_heads, head_dim)``.  Sequences
-address the pool through per-sequence block tables (ordered lists of
-physical block ids); the attention op gathers blocks through the table
-(paged_attention.py) and decode writes land at ``(block, offset)`` slots.
+``(n_layers, num_blocks, block_size, n_kv_heads * head_dim)`` — a token's
+heads side by side on ONE minor axis, head-major.  This module is the one
+place that knows the shape; everything else derives it from ``pool.k``
+or from ``(n_heads, head_dim)``.  Why fused: the chip tiles the two
+minor axes of an array (8 x 128 words), and a ``(block_size, 1280)``
+block fills whole tiles, so XLA keeps the pool row-major — the layout
+the attention kernels read it in.  With heads and head_dim as separate
+minor axes (20, 64) they did not fill a tile, XLA chose a layout of its
+own for the pool, and every step program converted both pools to the
+kernels' layout and back: four pool-sized copies a dispatch and 7.3 GB
+of temporaries (PERF.md, PR 26).
+
+Sequences address the pool through per-sequence block tables (ordered
+lists of physical block ids); the attention op gathers blocks through the
+table (paged_attention.py) and decode writes land at ``(block, offset)``
+slots.
 
 Allocation is a free-list pop; blocks are refcounted so full prompt
 blocks can be shared between sequences (prefix_cache.py) and sequence
@@ -109,10 +121,11 @@ class BlockPool(CacheBackend):
         self.n_heads = int(n_heads)
         self.head_dim = int(head_dim)
         self.dtype = dtype
-        shape = (n_layers, num_blocks, block_size, n_heads, head_dim)
+        shape = (n_layers, num_blocks, block_size, n_heads * head_dim)
         # Round-9 tensor parallelism: with a mesh, the K/V arrays are laid
-        # out [L, NB, BS, n_kv_heads/tp, hd] PER SHARD via NamedSharding on
-        # the head axis — N x aggregate KV HBM across the mesh.  Block
+        # out [L, NB, BS, n_kv_heads/tp * hd] PER SHARD via NamedSharding on
+        # the fused axis (head-major, so each shard keeps its own heads,
+        # contiguous) — N x aggregate KV HBM across the mesh.  Block
         # tables, the free list, refcounts and every piece of allocation
         # bookkeeping below stay host-side and replicated: a block id means
         # the same (head-split) physical block on every shard, so the
@@ -130,9 +143,7 @@ class BlockPool(CacheBackend):
                 )
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            sharding = NamedSharding(
-                mesh, P(None, None, None, tp_axis, None)
-            )
+            sharding = NamedSharding(mesh, P(None, None, None, tp_axis))
             zeros = jax.jit(
                 lambda: jnp.zeros(shape, dtype), out_shardings=sharding
             )

@@ -37,20 +37,30 @@ both entry points fail loudly on concrete (non-traced) violations
 instead of letting NaNs propagate; idle batch rows must be padded to
 context 1 against the null block (the engine does).
 
-Pool layout: ``(num_blocks, block_size, n_heads, head_dim)`` per layer
-(the per-layer slice of BlockPool's stacked arrays).
+Pool layout: ``(num_blocks, block_size, n_heads * head_dim)`` per layer
+(the per-layer slice of BlockPool's stacked arrays): a token's heads lie
+side by side on one minor axis, head-major.  With ``n_heads * head_dim``
+a multiple of 128 a ``(block_size, n_heads * head_dim)`` block is a whole
+number of the chip's tiles, so the layout XLA keeps the pool in is the
+one the kernels read: no step program converts the pool on the way in
+or out (PERF.md, PR 26; with heads and head_dim as separate minor axes
+of 20 and 64 it did, four pool-sized copies a dispatch).  The kernels
+split heads in VMEM, in groups of whole 128-lane tiles
+(:func:`_heads_per_group`); queries and outputs keep their
+``(..., H, hd)`` form at this module's boundary.
 
 Round-9 tensor parallelism: heads are fully independent here, so the op
 needs NO collectives and no tp-specific code — inside a shard_map over
 the (dp=1, tp=N) mesh each shard simply passes its
-``n_kv_heads/tp``-head pool slice and query slice (the H axis is just
-smaller, the kernel grid is unchanged).  The psum/all-gather points live
+``n_kv_heads/tp``-head pool slice and query slice (the fused axis is just
+narrower, the kernel grid is unchanged).  The psum/all-gather points live
 in the projections around the op (models/decoder.py).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -120,7 +130,7 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     """Gather-based ragged paged attention.
 
     q: (B, C, H, hd) — C consecutive query tokens per row (C=1 decode);
-    k_pool/v_pool: (num_blocks, block_size, H, hd);
+    k_pool/v_pool: (num_blocks, block_size, H * hd);
     block_tables: (B, NB) int32, padded with the null block;
     context_lens: (B,) int32 — the LAST query column's context (position
     of the last query + 1); earlier columns attend to one token less
@@ -133,7 +143,8 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     B, C = q.shape[:2]
     _require_positive_context(C, context_lens, start_pos, n_valid)
     NB = block_tables.shape[1]
-    BS, H, hd = k_pool.shape[1:]
+    BS = k_pool.shape[1]
+    H, hd = q.shape[2:]
     c0, cl_last = _query_context(C, context_lens, start_pos, n_valid)
     # per-(row, column) context: min(c0 + c, cl_last)
     ctx = jnp.minimum(c0[:, None] + jnp.arange(C)[None, :], cl_last[:, None])
@@ -149,11 +160,114 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def _heads_per_group(H: int, hd: int, C: int) -> int:
+    """How many heads one matmul of the kernels takes.  A block of the
+    pool is (BS, H*hd), heads side by side on the lane axis, and a slice
+    of it that starts or ends inside a 128-lane tile costs a shuffle per
+    block; so heads go in groups whose lanes fill whole tiles (two heads
+    of 64), the group's query rows stacked on the sublane axis with the
+    other heads' lanes zeroed.  Where no such group divides H (five heads
+    a shard) or its rows would not fill a sublane tile (one decode row),
+    all heads form one group: the whole lane axis, no slice at all."""
+    g = 128 // math.gcd(hd, 128)
+    return g if H % g == 0 and (g * C) % 8 == 0 else H
+
+
+def _own_lanes(R: int, W: int, C: int, hd: int):
+    """(R, W) bool: lane belongs to the head of its row (row i*C + c of
+    a group is head i, whose lanes are [i*hd, (i+1)*hd))."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (R, W), 0) // C
+            == jax.lax.broadcasted_iota(jnp.int32, (R, W), 1) // hd)
+
+
+def _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, *, C: int, G: int,
+               hd: int):
+    """First grid step of a batch row: reset the online softmax and lay
+    the row's queries out for the grouped matmuls.  ``qm_ref`` row
+    ``h*C + c`` holds query column c of head h in the lanes of h's place
+    in its group and zeros in the other heads' lanes, so that
+    ``qm[group rows] @ k[:, group lanes].T`` is every head's own scores
+    (a zero lane adds an exact 0 to the f32 sum)."""
+    m_ref[:] = jnp.full_like(m_ref, _NEG)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    R, W = G * C, G * hd
+    own = _own_lanes(R, W, C, hd)
+    for g in range(qm_ref.shape[0] // R):
+        # in f32, whose tiles the mask shares (a bf16 select against an
+        # f32-tiled mask is a relayout Mosaic refuses); the way back is exact
+        qg = q_ref[:, g * W:(g + 1) * W].astype(jnp.float32)  # (C, W)
+        qg = jnp.broadcast_to(qg, (R, W)) if C == 1 \
+            else jnp.concatenate([qg] * G, axis=0)
+        qm_ref[g * R:(g + 1) * R, :] = jnp.where(own, qg, 0.0).astype(
+            qm_ref.dtype)
+
+
+def _attend_block(j, c0, ctx, kb, vb, qm_ref, m_ref, l_ref, acc_ref, *,
+                  block_size: int, scale: float, C: int, G: int, hd: int):
+    """One visible K/V block's online-softmax update, shared by both
+    kernels.  kb/vb: (BS, H*hd) VALUES — the pool's block as it lies in
+    HBM.  Per group of G heads: scores (G*C, BS) of the group's stacked
+    query rows against the group's lanes of K, each row's own softmax
+    recurrence (m, l in f32), and ``p @ v`` over the group's lanes of V
+    into acc (G*C, G*hd) f32 — of which row ``i*C + c`` is read only in
+    head i's lanes (:func:`_write_out`)."""
+    R, W = G * C, G * hd
+    rows_i = jax.lax.broadcasted_iota(jnp.int32, (R, block_size), 0)
+    k_pos = j * block_size + jax.lax.broadcasted_iota(
+        jnp.int32, (R, block_size), 1
+    )
+    # column c attends to min(c0 + c, ctx) tokens; row i*C + c is column c
+    col_ctx = jnp.minimum(c0 + (rows_i % C if C > 1 else 0), ctx)
+    valid = k_pos < col_ctx
+    for g in range(qm_ref.shape[0] // R):
+        rows = slice(g * R, (g + 1) * R)
+        lanes = slice(g * W, (g + 1) * W)
+        s = jax.lax.dot_general(
+            qm_ref[rows], kb[:, lanes],
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # (R, BS)
+        s = jnp.where(valid, s, _NEG)
+        m_prev = m_ref[rows, :1]  # (R, 1)
+        m_cur = jnp.max(s, axis=1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        p = jnp.exp(s - m_new)
+        p = jnp.where(valid, p, 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[rows] = jnp.broadcast_to(
+            l_ref[rows, :1] * corr + jnp.sum(p, axis=1, keepdims=True),
+            (R, l_ref.shape[1]),
+        )
+        acc_ref[rows] = acc_ref[rows] * corr + jax.lax.dot_general(
+            p.astype(vb.dtype), vb[:, lanes],
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[rows] = jnp.broadcast_to(m_new, (R, m_ref.shape[1]))
+
+
+def _write_out(o_ref, l_ref, acc_ref, *, C: int, G: int, hd: int):
+    """o (C, H*hd): each head's lanes from its own rows of acc / l."""
+    R, W = G * C, G * hd
+    own = _own_lanes(R, W, C, hd)
+    for g in range(acc_ref.shape[0] // R):
+        rows = slice(g * R, (g + 1) * R)
+        o = jnp.where(
+            own, acc_ref[rows] / jnp.maximum(l_ref[rows, :1], 1e-20), 0.0
+        )
+        # one head's rows are non-zero in a lane: the sum picks them
+        o = jnp.sum(o, axis=0, keepdims=True) if C == 1 \
+            else sum(o[i * C:(i + 1) * C] for i in range(G))
+        o_ref[:, g * W:(g + 1) * W] = o.astype(o_ref.dtype)
+
+
 def _paged_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, block_size: int, scale: float):
+                  qm_ref, m_ref, l_ref, acc_ref, *, block_size: int,
+                  scale: float, **geom):
     """Grid: (B, NB) — blocks innermost, so (m, l, acc) scratch carries the
-    online softmax across one sequence's blocks.  Blocks: q (C, H, Dp);
-    o (H, C, Dp); k/v (block_size, H, Dp) — the physical block of layer
+    online softmax across one sequence's blocks.  Blocks: q and o
+    (C, H*hd); k/v (block_size, H*hd) — the physical block of layer
     ``li`` that the scalar-prefetched table maps grid step j to (``li_ref``
     is only read by the index maps).  Blocks past the row's
     context (``j > jlast``) are dead: the index map pins their DMA to the
@@ -164,9 +278,7 @@ def _paged_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, **geom)
 
     c0 = c0_ref[b]       # column 0's context length
     ctx = cl_ref[b]      # the row's full context (last valid column's)
@@ -174,93 +286,69 @@ def _paged_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j <= jlast)  # skip blocks wholly past the context
     def _visible():
-        qb = q_ref[:]  # (C, H, Dp)
-        kb = k_ref[:]  # (BS, H, Dp)
-        # per-head dot: batch over H, contract Dp -> (H, C, BS)
-        s = jax.lax.dot_general(
-            qb, kb,
-            dimension_numbers=(((2,), (2,)), ((1,), (1,))),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        k_pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 2
-        )
-        # column c attends to min(c0 + c, ctx) tokens
-        col_ctx = jnp.minimum(
-            c0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1), ctx
-        )
-        valid = k_pos < col_ctx
-        s = jnp.where(valid, s, _NEG)
-        m_prev = m_ref[:, :, :1]  # (H, C, 1)
-        m_cur = jnp.max(s, axis=2, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(valid, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[:] = jnp.broadcast_to(
-            l_ref[:, :, :1] * corr + jnp.sum(p, axis=2, keepdims=True),
-            l_ref.shape,
-        )
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[:],
-            dimension_numbers=(((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        _attend_block(j, c0, ctx, k_ref[:], v_ref[:], qm_ref, m_ref, l_ref,
+                      acc_ref, block_size=block_size, scale=scale, **geom)
 
     # write at the row's LAST VALID block, not the grid edge: later grid
     # steps touch nothing, and the (per-row) output block flushes when
     # the grid leaves row b
     @pl.when(j == jlast)
     def _final():
-        denom = jnp.maximum(l_ref[:, :, :1], 1e-20)
-        o_ref[:] = (acc_ref[:] / denom).astype(o_ref.dtype)
+        _write_out(o_ref, l_ref, acc_ref, **geom)
+
+
+def _softmax_scratch(H: int, C: int, hd: int, G: int, dtype):
+    return [
+        pltpu.VMEM((H * C, G * hd), dtype),        # qm: grouped queries
+        pltpu.VMEM((H * C, 128), jnp.float32),     # m
+        pltpu.VMEM((H * C, 128), jnp.float32),     # l
+        pltpu.VMEM((H * C, G * hd), jnp.float32),  # acc
+    ]
 
 
 def _paged_ragged_fn(q, k_pool, v_pool, layer, block_tables, c0, cl, *,
                      d_true: int, interpret: bool = False):
-    """q: (B, C, H, Dp); pools (L, num_blocks, BS, H, Dp) — ALL layers'
+    """q: (B, C, H, hd); pools (L, num_blocks, BS, H*hd) — ALL layers'
     stacked pool, read in place at ``layer`` ((1,) int32): the block index
-    maps carry the layer, so no layer is ever sliced out of the pool.  Dp
-    is the pool's own head_dim (Mosaic takes a minor dim that spans the
-    whole array, so nothing is lane-padded); c0/cl: (B,) per-row column-0
-    / last-column context lengths."""
-    B, C, H, Dp = q.shape
-    BS = k_pool.shape[2]
+    maps carry the layer, so no layer is ever sliced out of the pool.  A
+    block is (BS, H*hd): a whole number of the chip's tiles when H*hd is
+    a multiple of 128, so nothing is lane-padded and the pool's layout in
+    HBM is the one the kernel reads; c0/cl: (B,) per-row column-0 /
+    last-column context lengths."""
+    B, C, H, hd = q.shape
+    BS, D = k_pool.shape[2:]
     NB = block_tables.shape[1]
+    G = _heads_per_group(H, hd, C)
     kernel = functools.partial(
-        _paged_kernel, block_size=BS, scale=1.0 / np.sqrt(d_true)
+        _paged_kernel, block_size=BS, scale=1.0 / np.sqrt(d_true),
+        C=C, G=G, hd=hd,
     )
 
     def _kv_map(b, j, li, bt, c0, cl):
         # ragged grid: clamp dead steps to the row's last valid block so
         # their DMA is elided (same index as the previous step)
-        return (li[0], bt[b, jnp.minimum(j, (cl[b] - 1) // BS)], 0, 0, 0)
+        return (li[0], bt[b, jnp.minimum(j, (cl[b] - 1) // BS)], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,  # layer, block_tables, c0, cl
         grid=(B, NB),
         in_specs=[
-            pl.BlockSpec((None, C, H, Dp),
-                         lambda b, j, li, bt, c0, cl: (b, 0, 0, 0)),
-            pl.BlockSpec((None, None, BS, H, Dp), _kv_map),
-            pl.BlockSpec((None, None, BS, H, Dp), _kv_map),
+            pl.BlockSpec((None, C, D),
+                         lambda b, j, li, bt, c0, cl: (b, 0, 0)),
+            pl.BlockSpec((None, None, BS, D), _kv_map),
+            pl.BlockSpec((None, None, BS, D), _kv_map),
         ],
-        out_specs=pl.BlockSpec((None, H, C, Dp),
-                               lambda b, j, li, bt, c0, cl: (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, C, 128), jnp.float32),  # m
-            pltpu.VMEM((H, C, 128), jnp.float32),  # l
-            pltpu.VMEM((H, C, Dp), jnp.float32),   # acc
-        ],
+        out_specs=pl.BlockSpec((None, C, D),
+                               lambda b, j, li, bt, c0, cl: (b, 0, 0)),
+        scratch_shapes=_softmax_scratch(H, C, hd, G, q.dtype),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, C, Dp), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, C, D), q.dtype),
         interpret=interpret,
-    )(layer, block_tables, c0, cl, q, k_pool, v_pool)
-    return out.transpose(0, 2, 1, 3)  # (B, C, H, Dp)
+    )(layer, block_tables, c0, cl, q.reshape(B, C, D), k_pool, v_pool)
+    return out.reshape(B, C, H, hd)
 
 
 def _make_paged_ragged():
@@ -280,10 +368,10 @@ _paged_ragged = _make_paged_ragged()
 
 
 def _append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, q_ref, k1_ref,
-                   v1_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref, m_ref, l_ref,
-                   acc_ref, *, block_size: int, scale: float):
+                   v1_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref, qm_ref, m_ref,
+                   l_ref, acc_ref, *, block_size: int, scale: float, **geom):
     """Round-17 fused append+attend (decode, C=1): the incoming token's
-    K/V rides into the kernel as a (H, Dp) operand, is patched into the
+    K/V rides into the kernel as a (1, H*hd) operand, is patched into the
     tail block IN REGISTER for the attention math, and is flushed back
     to the pool through the aliased pool outputs — the standalone
     scatter program the unfused path runs before attention disappears.
@@ -296,9 +384,7 @@ def _append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, q_ref, k1_ref,
 
     @pl.when(j == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, **geom)
 
     c0 = c0_ref[b]
     ctx = cl_ref[b]
@@ -308,44 +394,16 @@ def _append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, q_ref, k1_ref,
     def _patched(raw_ref, new_ref, last):
         # tail block with the new token's row substituted (the HBM copy
         # the input DMA'd predates the append)
-        sel = (jax.lax.broadcasted_iota(jnp.int32, (block_size, 1, 1), 0)
+        sel = (jax.lax.broadcasted_iota(jnp.int32, (block_size, 1), 0)
                == so) & last
-        return jnp.where(sel, new_ref[:][None], raw_ref[:])
+        return jnp.where(sel, new_ref[:], raw_ref[:])
 
     @pl.when(j <= jlast)
     def _visible():
-        qb = q_ref[:]  # (C, H, Dp)
-        kb = _patched(k_ref, k1_ref, j == jlast)
-        s = jax.lax.dot_general(
-            qb, kb,
-            dimension_numbers=(((2,), (2,)), ((1,), (1,))),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        k_pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 2
-        )
-        col_ctx = jnp.minimum(
-            c0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1), ctx
-        )
-        valid = k_pos < col_ctx
-        s = jnp.where(valid, s, _NEG)
-        m_prev = m_ref[:, :, :1]
-        m_cur = jnp.max(s, axis=2, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(valid, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[:] = jnp.broadcast_to(
-            l_ref[:, :, :1] * corr + jnp.sum(p, axis=2, keepdims=True),
-            l_ref.shape,
-        )
-        vb = _patched(v_ref, v1_ref, j == jlast)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p.astype(vb.dtype), vb,
-            dimension_numbers=(((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        last = j == jlast
+        _attend_block(j, c0, ctx, _patched(k_ref, k1_ref, last),
+                      _patched(v_ref, v1_ref, last), qm_ref, m_ref, l_ref,
+                      acc_ref, block_size=block_size, scale=scale, **geom)
 
     @pl.when(j == jlast)
     def _final():
@@ -353,34 +411,35 @@ def _append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, q_ref, k1_ref,
         # through the aliased pool output — flushed once per row
         ko_ref[:] = _patched(k_ref, k1_ref, True).astype(ko_ref.dtype)
         vo_ref[:] = _patched(v_ref, v1_ref, True).astype(vo_ref.dtype)
-        denom = jnp.maximum(l_ref[:, :, :1], 1e-20)
-        o_ref[:] = (acc_ref[:] / denom).astype(o_ref.dtype)
+        _write_out(o_ref, l_ref, acc_ref, **geom)
 
 
 def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, layer, block_tables,
                      c0, cl, slot_offsets, *, d_true: int,
                      interpret: bool = False):
-    """q: (B, 1, H, Dp); k_new/v_new: (B, H, Dp); pools
-    (L, num_blocks, BS, H, Dp) — ALL layers' stacked pool, returned
+    """q: (B, 1, H, hd); k_new/v_new: (B, H, hd); pools
+    (L, num_blocks, BS, H*hd) — ALL layers' stacked pool, returned
     UPDATED at ``layer`` ((1,) int32), aliased in place on TPU: one tail
     block per row is written, nothing else of the pool is touched or
     copied.  Contract: the slot is the tail of the attended context
     (``slot_blocks[b] == block_tables[b, (cl[b]-1)//BS]`` and
     ``slot_offsets[b] == (cl[b]-1) % BS``) — the decode append the
     engine constructs by definition."""
-    B, C, H, Dp = q.shape
-    BS = k_pool.shape[2]
+    B, C, H, hd = q.shape
+    BS, D = k_pool.shape[2:]
     NB = block_tables.shape[1]
+    G = _heads_per_group(H, hd, C)
     kernel = functools.partial(
-        _append_kernel, block_size=BS, scale=1.0 / np.sqrt(d_true)
+        _append_kernel, block_size=BS, scale=1.0 / np.sqrt(d_true),
+        C=C, G=G, hd=hd,
     )
 
     def _kv_map(b, j, li, bt, c0, cl, so):
-        return (li[0], bt[b, jnp.minimum(j, (cl[b] - 1) // BS)], 0, 0, 0)
+        return (li[0], bt[b, jnp.minimum(j, (cl[b] - 1) // BS)], 0, 0)
 
     def _slot_map(b, j, li, bt, c0, cl, so):
         # constant per row: the pool out-block IS the row's slot block
-        return (li[0], bt[b, (cl[b] - 1) // BS], 0, 0, 0)
+        return (li[0], bt[b, (cl[b] - 1) // BS], 0, 0)
 
     def _row(*tail):
         return lambda b, j, li, bt, c0, cl, so: (b,) + tail
@@ -389,22 +448,18 @@ def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, layer, block_tables,
         num_scalar_prefetch=5,  # layer, block_tables, c0, cl, slot_offsets
         grid=(B, NB),
         in_specs=[
-            pl.BlockSpec((None, C, H, Dp), _row(0, 0, 0)),
-            pl.BlockSpec((None, H, Dp), _row(0, 0)),
-            pl.BlockSpec((None, H, Dp), _row(0, 0)),
-            pl.BlockSpec((None, None, BS, H, Dp), _kv_map),
-            pl.BlockSpec((None, None, BS, H, Dp), _kv_map),
+            pl.BlockSpec((None, C, D), _row(0, 0)),
+            pl.BlockSpec((None, 1, D), _row(0, 0)),
+            pl.BlockSpec((None, 1, D), _row(0, 0)),
+            pl.BlockSpec((None, None, BS, D), _kv_map),
+            pl.BlockSpec((None, None, BS, D), _kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((None, H, C, Dp), _row(0, 0, 0)),
-            pl.BlockSpec((None, None, BS, H, Dp), _slot_map),
-            pl.BlockSpec((None, None, BS, H, Dp), _slot_map),
+            pl.BlockSpec((None, C, D), _row(0, 0)),
+            pl.BlockSpec((None, None, BS, D), _slot_map),
+            pl.BlockSpec((None, None, BS, D), _slot_map),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((H, C, 128), jnp.float32),  # m
-            pltpu.VMEM((H, C, 128), jnp.float32),  # l
-            pltpu.VMEM((H, C, Dp), jnp.float32),   # acc
-        ],
+        scratch_shapes=_softmax_scratch(H, C, hd, G, q.dtype),
     )
     # alias indices count the scalar-prefetch operands: pools are operands
     # 8/9 of (layer, bt, c0, cl, so, q, k_new, v_new, k_pool, v_pool)
@@ -412,15 +467,15 @@ def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, layer, block_tables,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, C, Dp), q.dtype),
+            jax.ShapeDtypeStruct((B, C, D), q.dtype),
             jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
             jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
         ],
         input_output_aliases={8: 1, 9: 2},
         interpret=interpret,
-    )(layer, block_tables, c0, cl, slot_offsets, q, k_new, v_new, k_pool,
-      v_pool)
-    return o.transpose(0, 2, 1, 3), k_pool, v_pool
+    )(layer, block_tables, c0, cl, slot_offsets, q.reshape(B, C, D),
+      k_new.reshape(B, 1, D), v_new.reshape(B, 1, D), k_pool, v_pool)
+    return o.reshape(B, C, H, hd), k_pool, v_pool
 
 
 def _make_paged_append():
@@ -440,16 +495,16 @@ _paged_append = _make_paged_append()
 
 def _stacked(k_pool, v_pool, layer):
     """Resolve the two pool conventions to (stacked pools, (1,) int32
-    layer): ``layer=None`` means one layer's (num_blocks, BS, H, hd)
+    layer): ``layer=None`` means one layer's (num_blocks, BS, H*hd)
     slices (a leading unit axis is free); ``layer=i`` means the stacked
-    (L, num_blocks, BS, H, hd) pool of all layers, used in place."""
+    (L, num_blocks, BS, H*hd) pool of all layers, used in place."""
     if layer is None:
         return k_pool[None], v_pool[None], jnp.zeros((1,), jnp.int32)
     return k_pool, v_pool, jnp.asarray(layer, jnp.int32).reshape(1)
 
 
 def _layer_of(pool, layer):
-    """One layer's (num_blocks, BS, H, hd) pool, for the gather reference."""
+    """One layer's (num_blocks, BS, H*hd) pool, for the gather reference."""
     return pool if layer is None else pool[layer]
 
 
@@ -465,25 +520,25 @@ def paged_append_attend(q, k_new, v_new, k_pool, v_pool, block_tables,
     context_lens/slot_blocks/slot_offsets: (B,) int32 with the slot at
     the context tail (``slot_offsets == (context_lens-1) % BS`` and
     ``slot_blocks`` the matching table entry — the decode-step layout).
-    Pools: with ``layer=None`` one layer's (num_blocks, BS, H, hd)
-    slices; with ``layer=i`` the stacked (L, num_blocks, BS, H, hd) pool
+    Pools: with ``layer=None`` one layer's (num_blocks, BS, H*hd)
+    slices; with ``layer=i`` the stacked (L, num_blocks, BS, H*hd) pool
     of all layers, updated IN PLACE at layer i — the step programs use
     this form, so that no layer is sliced out of the pool or written
     back into it.  Returns ``(attn_out, k_pool, v_pool)`` with the pools
     updated, in the form they came in; bit-identical to
     scatter-then-:func:`paged_attention_reference` on the reference path
     (tier-1), one fused Pallas program on TPU (pool blocks aliased in
-    place — the standalone scatter disappears).  The kernel runs at the
-    pool's own head_dim: nothing is padded."""
+    place — the standalone scatter disappears).  The kernel reads the
+    pool's blocks as they lie in HBM: nothing is padded."""
     backend = jax.default_backend()
-    hd = q.shape[-1]
+    B, hd = q.shape[0], q.shape[-1]
     if use_pallas is None:
         use_pallas = backend == "tpu"
     if not use_pallas:
         at = (slot_blocks, slot_offsets) if layer is None \
             else (layer, slot_blocks, slot_offsets)
-        k_pool = k_pool.at[at].set(k_new)
-        v_pool = v_pool.at[at].set(v_new)
+        k_pool = k_pool.at[at].set(k_new.reshape(B, -1))
+        v_pool = v_pool.at[at].set(v_new.reshape(B, -1))
         a = paged_attention_reference(
             q, _layer_of(k_pool, layer), _layer_of(v_pool, layer),
             block_tables, context_lens,
@@ -510,9 +565,9 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens=None, *,
     """Dispatch: Pallas kernel on TPU, gather reference elsewhere (the
     interpreted kernel is for tests).  Same signature/shape/raggedness
     contract as :func:`paged_attention_reference`, plus ``layer``: None
-    for one layer's (num_blocks, BS, H, hd) pool slices, ``i`` for the
-    stacked (L, num_blocks, BS, H, hd) pool read in place at layer i.
-    The kernel reads the pools where they are, at their own head_dim:
+    for one layer's (num_blocks, BS, H*hd) pool slices, ``i`` for the
+    stacked (L, num_blocks, BS, H*hd) pool read in place at layer i.
+    The kernel reads the pools where they are, in the layout they have:
     nothing pool-sized is padded, sliced or copied by this function."""
     backend = jax.default_backend()
     if use_pallas is None:

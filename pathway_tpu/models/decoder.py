@@ -548,10 +548,11 @@ def paged_prefill(params: dict, cfg: DecoderConfig, token_ids: jax.Array,
     blocks named by ``block_tables``.
 
     token_ids: (B, T) with T a multiple of the pool block size;
-    k_pool/v_pool: (n_layers, num_blocks, block_size, H, hd) donated pool
-    arrays; block_tables: (B, T // block_size) int32 — rows padded with the
-    null block 0, whose garbage contents are never attended to (masked by
-    context length) and are overwritten slot-by-slot as decoding proceeds.
+    k_pool/v_pool: (n_layers, num_blocks, block_size, H * hd) donated pool
+    arrays (BlockPool's shape); block_tables: (B, T // block_size) int32 —
+    rows padded with the null block 0, whose garbage contents are never
+    attended to (masked by context length) and are overwritten slot-by-slot
+    as decoding proceeds.
     Returns ``(logits, k_pool, v_pool)``.
     """
     logits, cache = prefill(params, cfg, token_ids, n_valid, flash=flash,
@@ -559,12 +560,10 @@ def paged_prefill(params: dict, cfg: DecoderConfig, token_ids: jax.Array,
     B, T = token_ids.shape
     BS = k_pool.shape[2]
     nb = T // BS
-    hd = cfg.d_model // cfg.n_heads
     k_new = jnp.stack([c["k"] for c in cache])  # (L, B, T, H[/tp], hd)
     v_new = jnp.stack([c["v"] for c in cache])
-    H = k_new.shape[3]  # per-shard head count under tp_axis
-    k_blocks = k_new.reshape(cfg.n_layers, B, nb, BS, H, hd)
-    v_blocks = v_new.reshape(cfg.n_layers, B, nb, BS, H, hd)
+    k_blocks = k_new.reshape(cfg.n_layers, B, nb, BS, -1)
+    v_blocks = v_new.reshape(cfg.n_layers, B, nb, BS, -1)
     k_pool = k_pool.at[:, block_tables].set(k_blocks)
     v_pool = v_pool.at[:, block_tables].set(v_blocks)
     return logits, k_pool, v_pool
@@ -624,8 +623,10 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, k_pool: jax.Array,
                 layer=li, use_pallas=True,
             )
         else:
-            k_pool = k_pool.at[li, slot_blocks, slot_offsets].set(k1[:, 0])
-            v_pool = v_pool.at[li, slot_blocks, slot_offsets].set(v1[:, 0])
+            k_pool = k_pool.at[li, slot_blocks, slot_offsets].set(
+                k1.reshape(B, -1))
+            v_pool = v_pool.at[li, slot_blocks, slot_offsets].set(
+                v1.reshape(B, -1))
             a = paged_attention_reference(
                 q, k_pool[li], v_pool[li], block_tables, context_lens
             )
@@ -643,23 +644,24 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, k_pool: jax.Array,
 def _write_rows(k_pool, v_pool, layer: int, slot_blocks, slot_offsets,
                 k_rows, v_rows):
     """``pool[layer, slot_blocks[t], slot_offsets[t]] = rows[t]`` for every
-    packed token t, as in-place row updates in token order.  Beside the
-    attention kernel an XLA scatter wants the pool in a layout of its
-    own, and on the chip the program then converted both whole pools
-    back and forth around every layer (PERF.md, PR 21); an update of one
-    row keeps whatever layout the pool has.  Padding tokens all write the
-    null block's first slot, which nothing reads."""
+    packed token t, as in-place row updates in token order: one
+    contiguous (H*hd,) row a token.  Beside the attention kernel an XLA
+    scatter wants the pool in a layout of its own, and on the chip the
+    program then converted both whole pools back and forth around every
+    layer (PERF.md, PR 21); an update of one row keeps whatever layout
+    the pool has.  Padding tokens all write the null block's first slot,
+    which nothing reads.  k_rows/v_rows: (T, H, hd)."""
+    T = k_rows.shape[0]
+    k_rows = k_rows.reshape(T, 1, 1, 1, -1).astype(k_pool.dtype)
+    v_rows = v_rows.reshape(T, 1, 1, 1, -1).astype(v_pool.dtype)
+
     def body(t, pools):
         kp, vp = pools
-        at = (layer, slot_blocks[t], slot_offsets[t], 0, 0)
-        return (
-            jax.lax.dynamic_update_slice(
-                kp, k_rows[t][None, None, None].astype(kp.dtype), at),
-            jax.lax.dynamic_update_slice(
-                vp, v_rows[t][None, None, None].astype(vp.dtype), at),
-        )
+        at = (layer, slot_blocks[t], slot_offsets[t], 0)
+        return (jax.lax.dynamic_update_slice(kp, k_rows[t], at),
+                jax.lax.dynamic_update_slice(vp, v_rows[t], at))
 
-    return jax.lax.fori_loop(0, k_rows.shape[0], body, (k_pool, v_pool))
+    return jax.lax.fori_loop(0, T, body, (k_pool, v_pool))
 
 
 def paged_mixed_step(params: dict, cfg: DecoderConfig, k_pool: jax.Array,
@@ -745,8 +747,10 @@ def paged_mixed_step(params: dict, cfg: DecoderConfig, k_pool: jax.Array,
                 use_pallas=True,
             )
         else:
-            k_pool = k_pool.at[li, slot_blocks, slot_offsets].set(k1)
-            v_pool = v_pool.at[li, slot_blocks, slot_offsets].set(v1)
+            k_pool = k_pool.at[li, slot_blocks, slot_offsets].set(
+                k1.reshape(T, -1))
+            v_pool = v_pool.at[li, slot_blocks, slot_offsets].set(
+                v1.reshape(T, -1))
             a_rows = paged_attention_reference(
                 q_rows, k_pool[li], v_pool[li], row_tables,
                 start_pos=row_start, n_valid=row_nvalid,

@@ -96,8 +96,9 @@ def shard_params(params, mesh: Mesh):
 # replicated (positions are gathered per token inside shard_map) and
 # shards the column-parallel BIASES alongside their weights.
 
-# [n_layers, num_blocks, block_size, n_kv_heads, head_dim]: heads over tp
-KV_POOL_PSPEC = P(None, None, None, "tp", None)
+# [n_layers, num_blocks, block_size, n_kv_heads * head_dim]: heads over tp
+# (the fused axis is head-major: a shard's slice is its own heads, whole)
+KV_POOL_PSPEC = P(None, None, None, "tp")
 
 # [n_layers, max_slots, n_heads, d_key, d_value]: the Round-16 SSD
 # recurrent-state array (kvcache/statecache.py) — heads over tp, like

@@ -11,6 +11,12 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
         os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
     ).strip()
 
+# pyarrow 25's default (mimalloc) pool segfaults in `pq.read_table` in most
+# fresh processes that also hold jax (tests/test_deltalake_mysql.py as a
+# worker's first file: 4 of 6 runs; with the system pool 0 of 6), so whether
+# tier-1 passed hung on which worker xdist gave that file to
+os.environ.setdefault("ARROW_DEFAULT_MEMORY_POOL", "system")
+
 # tests must not depend on what an earlier run compiled: compile counts
 # are asserted, and a persistent-cache hit is not a compile
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"

@@ -58,7 +58,8 @@ def test_pallas_engine_at_head_dim_64_runs_kernel_on_the_pool_in_place():
     kw = dict(num_blocks=16, block_size=8, max_batch_size=2, chain_steps=4)
     eng = PagedDecodeEngine(cfg, params, attn="pallas", name="t_hd64", **kw)
     assert eng.attn == "pallas"
-    assert eng.pool.k.shape[-1] == 64  # the pool's own head_dim
+    # heads fused on the minor axis, at the pool's own head_dim
+    assert eng.pool.k.shape[-1] == cfg.n_heads * 64
     ref = PagedDecodeEngine(cfg, params, attn="reference", name="t_hd64_ref",
                             **kw)
     reqs = [([3, 4, 5, 6, 7], 6), ([9, 8, 7], 6)]

@@ -15,7 +15,8 @@ import pytest
 
 # GPT-2-large through the engine's own geometry on one 16 GB chip
 # (chip_smoke.py prints it): 36 layers, 20 heads of 64, 16 rows, 1025
-# blocks of 16.  The kernels take the stacked pool of all layers.
+# blocks of 16.  The kernels take the stacked pool of all layers, in
+# BlockPool's shape: (L, NBLK, BS, heads * HD).
 L, H, HD, B, BS, NBLK, NB = 36, 20, 64, 16, 16, 1025, 64
 CHUNK = 2 * BS  # the mixed step's prefill chunk
 
@@ -76,22 +77,42 @@ def _compiled_kernel(fn, *args, **jit_kwargs):
     return compiled
 
 
+def _pool_copies(compiled, pool_dims):
+    """The compiled program's ``copy`` ops whose result is pool-sized: a
+    pool that XLA keeps in another layout than the kernel reads shows
+    here, twice on the way in and twice on the way out."""
+    dims = ",".join(str(d) for d in pool_dims)
+    return [ln.strip()[:120] for ln in compiled.as_text().splitlines()
+            if " copy(" in ln and f"[{dims}]" in ln.split(" copy(")[0]]
+
+
+def _args(shape, heads, C):
+    """q rows and the stacked pool of ``heads`` heads, with the index
+    arrays both kernels take."""
+    import jax.numpy as jnp
+
+    i32 = jnp.int32
+    pool = shape((L, NBLK, BS, heads * HD), jnp.bfloat16)
+    q = shape((B, C, heads, HD), jnp.bfloat16)
+    idx = (shape((1,), i32), shape((B, NB), i32), shape((B,), i32),
+           shape((B,), i32))
+    return q, pool, idx
+
+
 @pytest.mark.parametrize("C", [1, CHUNK], ids=["decode", "prefill_chunk"])
 @pytest.mark.parametrize("heads", [H, H // 4], ids=["tp1", "tp4_shard"])
 def test_paged_ragged_kernel_compiles_at_gpt2_large(shape, C, heads):
-    import jax.numpy as jnp
-
     pa = _paged()
-    i32 = jnp.int32
-    _compiled_kernel(
+    q, pool, idx = _args(shape, heads, C)
+    compiled = _compiled_kernel(
         lambda q, k, v, li, bt, c0, cl: pa._paged_ragged_fn(
             q, k, v, li, bt, c0, cl, d_true=HD),
-        shape((B, C, heads, HD), jnp.bfloat16),
-        shape((L, NBLK, BS, heads, HD), jnp.bfloat16),
-        shape((L, NBLK, BS, heads, HD), jnp.bfloat16),
-        shape((1,), i32), shape((B, NB), i32), shape((B,), i32),
-        shape((B,), i32),
+        q, pool, pool, *idx,
     )
+    # tp = 4: 5 x 64 = 320 lanes a shard do not fill whole tiles and the
+    # copies come back (PERF.md, open questions); printed, not asserted
+    print(f"heads={heads} C={C}: pool-sized copies "
+          f"{len(_pool_copies(compiled, pool.shape))}")
 
 
 @pytest.mark.parametrize("heads", [H, H // 4], ids=["tp1", "tp4_shard"])
@@ -99,19 +120,94 @@ def test_paged_append_kernel_compiles_at_gpt2_large(shape, heads):
     import jax.numpy as jnp
 
     pa = _paged()
-    i32 = jnp.int32
-    pool = shape((L, NBLK, BS, heads, HD), jnp.bfloat16)
-    _compiled_kernel(
+    q, pool, idx = _args(shape, heads, 1)
+    new = shape((B, heads, HD), jnp.bfloat16)
+    compiled = _compiled_kernel(
         lambda q, k1, v1, k, v, li, bt, c0, cl, so: pa._paged_append_fn(
             q, k1, v1, k, v, li, bt, c0, cl, so, d_true=HD),
-        shape((B, 1, heads, HD), jnp.bfloat16),
-        shape((B, heads, HD), jnp.bfloat16),
-        shape((B, heads, HD), jnp.bfloat16),
-        pool, pool,
-        shape((1,), i32), shape((B, NB), i32), shape((B,), i32),
-        shape((B,), i32), shape((B,), i32),
+        q, new, new, pool, pool, *idx, idx[-1],
         donate_argnums=(3, 4),
     )
+    print(f"heads={heads}: pool-sized copies "
+          f"{len(_pool_copies(compiled, pool.shape))}")
+
+
+def _mixed_like(q, k1, v1, kp, vp, sb, so, bt, c0, cl):
+    """What a mixed step does to the pool, over two layers: every packed
+    token's row written in place, then the ragged kernel over the stacked
+    pool."""
+    import jax.numpy as jnp
+
+    from pathway_tpu.models.decoder import _write_rows
+
+    pa = _paged()
+    out = []
+    for li in range(2):
+        kp, vp = _write_rows(kp, vp, li, sb, so, k1, v1)
+        out.append(pa._paged_ragged_fn(
+            q, kp, vp, jnp.full((1,), li, jnp.int32), bt, c0, cl,
+            d_true=HD))
+    return out[0] + out[1], kp, vp
+
+
+def _chain_like(q, k1, v1, kp, vp, bt, c0, cl, so):
+    """What a chained decode does to the pool: the append kernel over
+    two layers inside a two-step ``lax.scan`` that carries the pools."""
+    import jax
+    import jax.numpy as jnp
+
+    pa = _paged()
+
+    def body(carry, _):
+        kp, vp, acc = carry
+        for li in range(2):
+            a, kp, vp = pa._paged_append_fn(
+                q, k1, v1, kp, vp, jnp.full((1,), li, jnp.int32), bt, c0,
+                cl, so, d_true=HD)
+            acc = acc + a
+        return (kp, vp, acc), None
+
+    (kp, vp, acc), _ = jax.lax.scan(
+        body, (kp, vp, jnp.zeros_like(q)), None, length=2)
+    return acc, kp, vp
+
+
+@pytest.mark.parametrize("program", ["mixed", "chained", "cow_copy"])
+def test_step_programs_keep_the_pool_where_it_is(shape, program):
+    """The pool in BlockPool's shape goes through a step-shaped program
+    without a change of layout: its entry layout is row-major (what the
+    kernels read), no pool-sized copy is compiled, and the program's
+    temporaries stay small.  With heads and head_dim as separate minor
+    axes each such program held four pool-sized copies and 7.26 GB of
+    temporaries (PERF.md, PR 26)."""
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.kvcache.block_pool import _cow_copy_fn
+
+    i32 = jnp.int32
+    T = B + CHUNK
+    q, pool, (_li, bt, c0, cl) = _args(
+        shape, H, CHUNK if program == "mixed" else 1)
+    if program == "mixed":
+        rows = shape((T, H, HD), jnp.bfloat16)
+        fn, pools_at = _mixed_like, (3, 4)
+        args = (q, rows, rows, pool, pool, shape((T,), i32),
+                shape((T,), i32), bt, c0, cl)
+    elif program == "chained":
+        new = shape((B, H, HD), jnp.bfloat16)
+        fn, pools_at = _chain_like, (3, 4)
+        args = (q, new, new, pool, pool, bt, c0, cl, c0)
+    else:
+        fn, pools_at = _cow_copy_fn, (0,)
+        args = (pool, shape((), i32), shape((), i32))
+    compiled = jax.jit(fn, donate_argnums=pools_at).lower(*args).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == (program != "cow_copy")
+    layouts = compiled.input_formats[0]
+    for i in pools_at:
+        assert layouts[i].layout.major_to_minor == (0, 1, 2, 3), layouts[i]
+    assert _pool_copies(compiled, pool.shape) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 @pytest.mark.parametrize("rows", [16384, 131072])

@@ -362,14 +362,15 @@ def test_profile_diff_rows_and_cli(tmp_path):
 
 
 def _append_case(seed=0, B=3, H=2, hd=128, NB=4, BS=4):
-    """A decode-step-shaped case: slot at the context tail."""
+    """A decode-step-shaped case: slot at the context tail; pools in
+    BlockPool's per-layer shape, heads fused on the minor axis."""
     rng = np.random.default_rng(seed)
     nb_total = 1 + B * NB  # block 0 is the null block
     q = rng.normal(size=(B, 1, H, hd)).astype(np.float32)
     k_new = rng.normal(size=(B, H, hd)).astype(np.float32)
     v_new = rng.normal(size=(B, H, hd)).astype(np.float32)
-    k_pool = rng.normal(size=(nb_total, BS, H, hd)).astype(np.float32)
-    v_pool = rng.normal(size=(nb_total, BS, H, hd)).astype(np.float32)
+    k_pool = rng.normal(size=(nb_total, BS, H * hd)).astype(np.float32)
+    v_pool = rng.normal(size=(nb_total, BS, H * hd)).astype(np.float32)
     bt = np.zeros((B, NB), np.int32)
     cl = np.array([3, BS + 1, 2 * BS], np.int32)[:B]
     for b in range(B):
@@ -390,15 +391,17 @@ def test_paged_append_attend_reference_bit_identity():
     q, k1, v1, kp, vp, bt, cl, sb, so = _append_case()
     a, ko, vo = paged_append_attend(q, k1, v1, kp, vp, bt, cl, sb, so,
                                     use_pallas=False)
-    kp2 = kp.at[sb, so].set(k1)
-    vp2 = vp.at[sb, so].set(v1)
+    kp2 = kp.at[sb, so].set(k1.reshape(k1.shape[0], -1))
+    vp2 = vp.at[sb, so].set(v1.reshape(v1.shape[0], -1))
     want = paged_attention_reference(q, kp2, vp2, bt, cl)
     assert (np.asarray(a) == np.asarray(want)).all()
     assert (np.asarray(ko) == np.asarray(kp2)).all()
     assert (np.asarray(vo) == np.asarray(vp2)).all()
 
 
-def test_paged_append_attend_kernel_interpret():
+@pytest.mark.parametrize("H,hd", [(2, 128), (20, 64)],
+                         ids=["toy", "gpt2_large_heads"])
+def test_paged_append_attend_kernel_interpret(H, hd):
     """The Pallas kernel (interpret mode on CPU) matches the reference
     to fp tolerance, with the new token's K/V landed in the slot block
     through the in-place pool alias."""
@@ -406,13 +409,15 @@ def test_paged_append_attend_kernel_interpret():
         paged_append_attend, paged_attention_reference,
     )
 
-    q, k1, v1, kp, vp, bt, cl, sb, so = _append_case()
+    q, k1, v1, kp, vp, bt, cl, sb, so = _append_case(H=H, hd=hd)
     kp_np, vp_np = np.asarray(kp), np.asarray(vp)
+    k1, v1 = k1.reshape(k1.shape[0], -1), v1.reshape(v1.shape[0], -1)
     want = paged_attention_reference(
         q, kp.at[sb, so].set(k1), vp.at[sb, so].set(v1), bt, cl
     )
-    a, ko, vo = paged_append_attend(q, k1, v1, kp, vp, bt, cl, sb, so,
-                                    use_pallas=True, interpret=True)
+    a, ko, vo = paged_append_attend(
+        q, k1.reshape(-1, H, hd), v1.reshape(-1, H, hd), kp, vp, bt, cl, sb,
+        so, use_pallas=True, interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
     sb_np, so_np = np.asarray(sb), np.asarray(so)

@@ -384,8 +384,8 @@ def test_zero_length_context_fails_loudly():
 
     rng = np.random.default_rng(1)
     q = jnp.asarray(rng.standard_normal((2, 1, 2, 4)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((4, 4, 2, 4)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((4, 4, 2, 4)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((4, 4, 2 * 4)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((4, 4, 2 * 4)), jnp.float32)
     bt = jnp.asarray([[1, 2], [3, 0]], jnp.int32)
     with pytest.raises(ValueError, match="context_lens >= n_queries"):
         paged_attention_reference(q, kp, vp, bt, jnp.asarray([0, 3]))
@@ -404,26 +404,32 @@ def test_zero_length_context_fails_loudly():
     assert np.isfinite(np.asarray(out)).all()
 
 
-@pytest.mark.slow
-def test_ragged_kernel_matches_reference_interpreted():
-    """The length-aware multi-query kernel (interpret mode on CPU — slow)
-    must agree with the gather reference on every VALID query column."""
+@pytest.mark.parametrize(
+    "C,H,hd,sp,nv",
+    [(4, 2, 16, [17, 4, 0], [4, 2, 1]),
+     (32, 20, 64, [0, 5, 31], [32, 11, 1])],
+    ids=["toy", "gpt2_large_chunk"],
+)
+def test_ragged_kernel_matches_reference_interpreted(C, H, hd, sp, nv):
+    """The length-aware multi-query kernel (interpret mode on CPU) must
+    agree with the gather reference on every VALID query column, over a
+    pool in BlockPool's shape (heads fused on the minor axis)."""
     from pathway_tpu.kvcache.paged_attention import (
         paged_attention, paged_attention_reference,
     )
 
     rng = np.random.default_rng(5)
-    B, C, H, hd, BS, NBLK = 3, 4, 2, 16, 8, 12
+    B, BS, NBLK = 3, 8, 12
     q = jnp.asarray(rng.standard_normal((B, C, H, hd)), jnp.float32)
-    k_pool = jnp.asarray(rng.standard_normal((NBLK, BS, H, hd)), jnp.float32)
-    v_pool = jnp.asarray(rng.standard_normal((NBLK, BS, H, hd)), jnp.float32)
+    k_pool = jnp.asarray(rng.standard_normal((NBLK, BS, H * hd)), jnp.float32)
+    v_pool = jnp.asarray(rng.standard_normal((NBLK, BS, H * hd)), jnp.float32)
     tables = jnp.asarray(
         [[1, 2, 3, 4], [5, 6, 0, 0], [7, 8, 9, 10]], jnp.int32
     )
-    # ragged: a full chunk deep in its sequence, a partial tail chunk,
-    # and a fresh 1-token decode-style row
-    sp = jnp.asarray([17, 4, 0], jnp.int32)
-    nv = jnp.asarray([4, 2, 1], jnp.int32)
+    # ragged: a full chunk deep in its sequence (toy) or at its start
+    # (chunk width), a partial tail chunk, and a 1-token decode-style row
+    sp = jnp.asarray(sp, jnp.int32)
+    nv = jnp.asarray(nv, jnp.int32)
     want = paged_attention_reference(
         q, k_pool, v_pool, tables, start_pos=sp, n_valid=nv
     )

@@ -85,19 +85,18 @@ def test_tp8_identity_mixed_lengths_and_sharded_pool(params):
     ]
     eng1 = _engine(params, 1, "t_tp_id1")
     eng8 = _engine(params, 8, "t_tp_id8")
-    # the pool is GENUINELY sharded: NamedSharding on the head axis, 8
-    # devices, each shard holding n_kv_heads/8 heads of every block
+    # the pool is GENUINELY sharded: NamedSharding on the fused
+    # heads x head_dim axis (head-major), 8 devices, each shard holding
+    # n_kv_heads/8 whole heads of every block
     def _head_sharded(spec):
-        # trailing Nones are normalized away, so compare padded
-        padded = tuple(spec) + (None,) * (5 - len(tuple(spec)))
-        return padded == (None, None, None, "tp", None)
+        return tuple(spec) == (None, None, None, "tp")
 
     for arr in (eng8.pool.k, eng8.pool.v):
         assert len(arr.sharding.device_set) == 8
         assert _head_sharded(arr.sharding.spec)
         shard_shape = arr.addressable_shards[0].data.shape
-        assert shard_shape[3] == _CFG.n_heads // 8
-        assert arr.shape[3] == _CFG.n_heads
+        assert shard_shape[3] == _CFG.d_model // 8
+        assert arr.shape[3] == _CFG.d_model
     got1 = eng1.generate_batch([(p, 8) for p in prompts])
     got8 = eng8.generate_batch([(p, 8) for p in prompts])
     assert got8 == got1
