@@ -58,6 +58,7 @@ managed-resource framing follows arxiv 2603.09555.
 from .backend import CacheBackend, UnsupportedCacheOp, make_backend
 from .block_pool import BlockPool, PoolExhausted, SequenceState
 from .engine import EngineHungError, PagedDecodeEngine, resolve_tp
+from .hybrid import HybridCache
 from .paged_attention import paged_attention, paged_attention_reference
 from .prefix_cache import PrefixCache
 from .speculative import (Drafter, DraftModelDrafter, NGramDrafter,
@@ -75,6 +76,7 @@ __all__ = [
     "BlockPool",
     "CacheBackend",
     "EngineHungError",
+    "HybridCache",
     "PoolExhausted",
     "SequenceState",
     "PrefixCache",

@@ -144,6 +144,17 @@ class CacheBackend(abc.ABC):
     def retire(self) -> None:
         """Unregister from metrics; default no-op."""
 
+    # -- per-row state beside the block tables -----------------------------
+    def row_extras(self, seq_ids, n_rows: int) -> tuple:
+        """Further ``(n_rows,)`` arrays a step program takes after the
+        paged ones, one entry a batch row in ``seq_ids``' order (a hybrid
+        cache: the rows' conv slots).  None by default."""
+        return ()
+
+    def after_sync(self) -> None:
+        """Every program dispatched so far has finished: fold what the
+        programs counted on the device into the stats.  Default no-op."""
+
 
 _BACKENDS: dict[str, Callable] = {}
 
@@ -156,7 +167,9 @@ def make_backend(kind: str, **kwargs) -> CacheBackend:
     """Construct a cache backend by kind — the seam engines build (and
     restart-rebuild) their cache through.  ``"paged"`` →
     :class:`~pathway_tpu.kvcache.block_pool.BlockPool`; ``"state"`` →
-    :class:`~pathway_tpu.kvcache.statecache.StateCache`."""
+    :class:`~pathway_tpu.kvcache.statecache.StateCache`; ``"hybrid"`` →
+    :class:`~pathway_tpu.kvcache.hybrid.HybridCache` (K/V blocks for the
+    attention layers and a conv slot, one sequence)."""
     if kind not in _BACKENDS:
         # lazy registration avoids import cycles: block_pool/statecache
         # import nothing from here at module scope except the ABC
@@ -168,9 +181,14 @@ def make_backend(kind: str, **kwargs) -> CacheBackend:
             from .statecache import StateCache
 
             register_backend("state", StateCache)
+        elif kind == "hybrid":
+            from .hybrid import HybridCache
+
+            register_backend("hybrid", HybridCache)
         else:
             raise ValueError(
                 f"unknown cache backend {kind!r}; "
-                f"registered: {sorted(_BACKENDS)} + builtin: paged, state"
+                f"registered: {sorted(_BACKENDS)} + builtin: paged, state, "
+                "hybrid"
             )
     return _BACKENDS[kind](**kwargs)

@@ -451,6 +451,16 @@ class BlockPool(CacheBackend):
         self.k = _tier_scatter(self.k, idx, jnp.asarray(payload["k"]))
         self.v = _tier_scatter(self.v, idx, jnp.asarray(payload["v"]))
 
+    # -- what the engine's step programs take and give back ----------------
+    def device_state(self) -> tuple:
+        """The donated device arrays a step program takes after the
+        parameters, in order."""
+        return (self.k, self.v)
+
+    def set_device_state(self, k, v) -> None:
+        """What the program returned after its ids, in the same order."""
+        self.k, self.v = k, v
+
     # -- preemption --------------------------------------------------------
     def preempt(self, *, exclude: set | frozenset = frozenset()
                 ) -> SequenceState | None:

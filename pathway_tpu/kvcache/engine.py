@@ -439,11 +439,22 @@ class PagedDecodeEngine:
         if attn is None:
             attn = "pallas" if jax.default_backend() == "tpu" else "reference"
         self.attn = attn
+        # the block family (models/families.py), read from the
+        # configuration: it says which math a round runs and what state a
+        # sequence keeps; what it cannot do yet it refuses here, by name
+        from ..models.families import step_family
+
+        self.family = step_family(cfg)
+        self.family.unsupported(
+            tp=tp, quantize=quantize, speculative=speculative,
+            session_store=session_store, chunked_prefill=chunked_prefill)
         # Round-9 tensor parallelism: tp > 1 lays the K/V pool out over a
         # (dp=1, tp) mesh (n_kv_heads/tp per shard — N x aggregate KV HBM)
         # and shard_maps every step program; tp == 1 keeps the EXACT
         # single-device round-8 programs (no mesh, no shard_map wrapper)
-        self.tp = resolve_tp(cfg, tp)
+        # (an unasked tp takes every local chip; a family without sharded
+        # programs has refused an asked one above and runs on one)
+        self.tp = resolve_tp(cfg, tp) if self.family.tensor_parallel else 1
         self.mesh = None
         if self.tp > 1:
             from ..parallel.mesh import tp_mesh
@@ -459,16 +470,12 @@ class PagedDecodeEngine:
         # checkpoint reproduces it — and its tokens — exactly.
         self.quantize = quantize
         self.base_params = params
-        from ..models.decoder import plan_decode_params
-
-        plan = plan_decode_params(cfg, params, tp=self.tp,
-                                  quantize=quantize)
+        plan = self.family.plan(cfg, params, tp=self.tp, quantize=quantize)
         if self.tp > 1:
             from ..parallel.mesh import shard_decoder_params
 
             plan = shard_decoder_params(plan, self.mesh)
         self.params = plan
-        head_dim = cfg.d_model // cfg.n_heads
         # Round-14 pre-flight HBM fit (obs/memory.py): params + KV pool +
         # step-temp watermark must fit the budget BEFORE any allocation —
         # an unfittable (num_blocks, chain_steps, max_batch) is rejected
@@ -554,12 +561,17 @@ class PagedDecodeEngine:
         # contract, with BlockPool as its paged implementation.
         self._pool_kwargs = dict(
             num_blocks=num_blocks, block_size=block_size,
-            n_layers=cfg.n_layers, n_heads=cfg.n_heads, head_dim=head_dim,
             dtype=_resolve_dtype(cfg.dtype), name=name, mesh=self.mesh,
+            **self.family.cache_kwargs(cfg, self.max_batch_size),
         )
-        self._prefix_sharing = bool(prefix_sharing)
-        self.pool = make_backend("paged", **self._pool_kwargs)
-        self.prefix = PrefixCache(self.pool) if prefix_sharing else None
+        self.pool = make_backend(self.family.cache_kind, **self._pool_kwargs)
+        # a cache that cannot share blocks (the hybrid one: a shared block
+        # would skip the tokens that build the conv state) runs without a
+        # prefix cache, whatever was asked for
+        self._prefix_sharing = bool(prefix_sharing) \
+            and self.pool.supports_prefix
+        self.prefix = PrefixCache(self.pool) if self._prefix_sharing \
+            else None
         # watchdog + supervised restart (Round-13): a dispatch blocked
         # past watchdog_timeout_s raises EngineHungError; any engine
         # failure with restart budget left rebuilds the pool and
@@ -652,77 +664,10 @@ class PagedDecodeEngine:
         # Per-run state (reset by _run_loop); the engine lock serializes
         # runs, so one map on self is safe
         self._inflight_prefix: dict = {}
-        _cfg = cfg
-        _attn = self.attn
-        _mesh = self.mesh
-
-        # device-side sampling: every step/prefill wrapper argmaxes INSIDE
-        # the jitted program, so only [B] int32 ids (not [B, vocab]
-        # logits) cross the device->host boundary per round.  Under tp the
-        # shard_map variants return ids directly — greedy sampling is
-        # fused into the sharded vocab head as an exact two-stage argmax
-        # (decoder._head_out), so the full [B, vocab] logits are never
-        # materialized on any device either.
-        def _step_fn(p, k_pool, v_pool, token, positions, bt, sb, so):
-            from ..models.decoder import paged_decode_step, paged_decode_step_tp
-
-            if _mesh is not None:
-                return paged_decode_step_tp(
-                    p, _cfg, _mesh, k_pool, v_pool, token, positions, bt,
-                    sb, so, attn=_attn,
-                )
-            logits, k_pool, v_pool = paged_decode_step(
-                p, _cfg, k_pool, v_pool, token, positions, bt, sb, so,
-                attn=_attn,
-            )
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), \
-                k_pool, v_pool
-
-        def _mixed_fn(p, k_pool, v_pool, tokens, positions, row_tables,
-                      row_start, row_nvalid, row_token_idx, tok_row,
-                      tok_col, sb, so, logit_idx):
-            from ..models.decoder import paged_mixed_step, paged_mixed_step_tp
-
-            if _mesh is not None:
-                return paged_mixed_step_tp(
-                    p, _cfg, _mesh, k_pool, v_pool, tokens, positions,
-                    row_tables, row_start, row_nvalid, row_token_idx,
-                    tok_row, tok_col, sb, so, logit_idx, attn=_attn,
-                )
-            logits, k_pool, v_pool = paged_mixed_step(
-                p, _cfg, k_pool, v_pool, tokens, positions, row_tables,
-                row_start, row_nvalid, row_token_idx, tok_row, tok_col,
-                sb, so, logit_idx, attn=_attn,
-            )
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), \
-                k_pool, v_pool
-
-        def _chained_fn(p, k_pool, v_pool, token, positions, bt, sb, so):
-            from ..models.decoder import (paged_chained_decode,
-                                          paged_chained_decode_tp)
-
-            if _mesh is not None:
-                return paged_chained_decode_tp(
-                    p, _cfg, _mesh, k_pool, v_pool, token, positions, bt,
-                    sb, so, attn=_attn,
-                )
-            return paged_chained_decode(
-                p, _cfg, k_pool, v_pool, token, positions, bt, sb, so,
-                attn=_attn,
-            )
-
-        def _prefill_fn(p, token_ids, n_valid, k_pool, v_pool, bt):
-            from ..models.decoder import paged_prefill, paged_prefill_tp
-
-            if _mesh is not None:
-                return paged_prefill_tp(
-                    p, _cfg, _mesh, token_ids, n_valid, k_pool, v_pool, bt
-                )
-            logits, k_pool, v_pool = paged_prefill(
-                p, _cfg, token_ids, n_valid, k_pool, v_pool, bt
-            )
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), \
-                k_pool, v_pool
+        # the greedy step programs are the family's; every one argmaxes
+        # INSIDE the jitted program, so only [B] int32 ids (not [B, vocab]
+        # logits) cross the device->host boundary per round
+        progs = self.family.programs(cfg, self.attn, self.mesh)
 
         # pools donated: every step/prefill consumes them in place.  Two
         # static shapes cover the whole workload in chunked mode — the
@@ -740,21 +685,20 @@ class PagedDecodeEngine:
         # names so the observatory ranks/rooflines the two weight paths
         # separately and CompileWatch pins each variant's compile count
         sfx = self._prog_suffix
-        self._step = profiled_jit(
-            f"pw.decode_step{sfx}", _step_fn, donate_argnums=(1, 2)
-        )
-        self._mixed = profiled_jit(
-            f"pw.mixed_step{sfx}", _mixed_fn, donate_argnums=(1, 2)
-        )
+
+        def program(name: str, kind: str):
+            if kind not in progs:
+                return None
+            fn, donated = progs[kind]
+            return profiled_jit(f"{name}{sfx}", fn, donate_argnums=donated)
+
+        self._step = program("pw.decode_step", "step")
+        self._mixed = program("pw.mixed_step", "mixed")
         # the chained program's (B, chain_steps) shape is static, so the
         # whole multi-step hot loop is ONE additional compile on top of
         # the round-8 pair (K=1 rounds reuse the plain step program)
-        self._chained = profiled_jit(
-            f"pw.chained_decode{sfx}", _chained_fn, donate_argnums=(1, 2)
-        )
-        self._prefill = profiled_jit(
-            f"pw.prefill{sfx}", _prefill_fn, donate_argnums=(3, 4)
-        )
+        self._chained = program("pw.chained_decode", "chained")
+        self._prefill = program("pw.prefill", "prefill")
         # Round-18 speculative decoding (kvcache/speculative.py): a
         # drafter proposes up to K tokens per row, ONE ragged verify
         # dispatch checks them all, and the greedy accept rule keeps the
@@ -933,6 +877,15 @@ class PagedDecodeEngine:
             donate_argnums=(1, 2),
         )
         return self._verify
+
+    def _call(self, prog, dev: tuple):
+        """Run a greedy step program on the cache's device arrays and put
+        back what it returns after its ids (the donated arrays, and for a
+        hybrid cache the device's counters)."""
+        pool = self.pool
+        ids, *state = prog(self.params, *pool.device_state(), *dev)
+        pool.set_device_state(*state)
+        return ids
 
     def _record_dispatch(self, prog, t_disp, t_end, items: int) -> None:
         """Attribute one dispatch->sync window to ``prog``'s registry
@@ -1191,7 +1144,8 @@ class PagedDecodeEngine:
         old_pool.retire()
         try:
             self.pool = None
-            self.pool = make_backend("paged", **self._pool_kwargs)
+            self.pool = make_backend(self.family.cache_kind,
+                                     **self._pool_kwargs)
         except BaseException:
             # keep a pool object attached: the terminal path still reads
             # .stats (degrade accounting) and frees sequences through it
@@ -1415,6 +1369,7 @@ class PagedDecodeEngine:
             )
             self._t_dispatch = None
         self._t_device_idle = now
+        self.pool.after_sync()
 
     def _note_dispatch(self, kind: str = "step") -> None:
         now = time.perf_counter()
@@ -1505,6 +1460,11 @@ class PagedDecodeEngine:
             # zero-token request: the dense path returns nothing, so must we
             deliver(req)
             return "done"
+        if self.family.greedy_only and req.sampling is not None:
+            deliver(req, ValueError(
+                f"the {self.family.name} block family decodes greedily: "
+                "it has no sampled step programs"))
+            return "failed"
         tokens = req.prompt + req.emitted
         limit = self.max_seq_tokens
         remaining = req.max_new - len(req.emitted)
@@ -2073,7 +2033,8 @@ class PagedDecodeEngine:
             )
             ph.set(kind="chain", rows=len(acts), tokens=sum(kreal),
                    budget=B * K, waiting=0)
-        host = (token, positions, bt, sb, so)
+            extras = self._row_extras([a.seq_id for a in acts], ph)
+        host = (token, positions, bt, sb, so) + extras
         faults.fire("engine.dispatch.chain")
         self._note_dispatch("chain")
         t_disp = self._t_dispatch
@@ -2082,7 +2043,7 @@ class PagedDecodeEngine:
             else self._sampled_programs()["chained"]
         with self._phase("pw.chain_dispatch" if samp is None
                          else "pw.chain_dispatch_sampled"):
-            ids, pool.k, pool.v = prog(self.params, pool.k, pool.v, *dev)
+            ids = self._call(prog, dev)
         try:
             # start the device->host copy NOW so it overlaps the chain's
             # tail and the host's bookkeeping; np.asarray later just
@@ -2199,6 +2160,15 @@ class PagedDecodeEngine:
                 return True
             inflight = nxt
 
+    def _row_extras(self, seq_ids: list, ph) -> tuple:
+        """The cache's own per-row arrays of a step, in row order (a
+        hybrid cache: the rows' conv slots, noted on ``pw.round.build`` as
+        ``conv_rows``)."""
+        extras = self.pool.row_extras(seq_ids, self.max_batch_size)
+        if extras:
+            ph.set(conv_rows=len(seq_ids))
+        return extras
+
     def _build_decode(self, reserved, ph) -> tuple:
         """The numpy arrays of one 1-token-per-row step (inside the
         caller's ``pw.round.build``): ``(host arrays, sampled?)``."""
@@ -2222,7 +2192,8 @@ class PagedDecodeEngine:
         )
         ph.set(kind="step", rows=len(reserved), tokens=len(reserved),
                budget=B, waiting=0)
-        host = (token, positions, bt, sb, so)
+        host = (token, positions, bt, sb, so) + self._row_extras(
+            [act.seq_id for act, _s in reserved], ph)
         return (host if samp is None else host + samp), samp is not None
 
     def _decode_round(self, step, reserved, running, deliver) -> None:
@@ -2234,9 +2205,7 @@ class PagedDecodeEngine:
         prog = self._sampled_programs()["step"] if sampled else self._step
         with self._phase("pw.decode_step_sampled" if sampled
                          else "pw.decode_step"):
-            ids, self.pool.k, self.pool.v = prog(
-                self.params, self.pool.k, self.pool.v, *dev,
-            )
+            ids = self._call(prog, dev)
         ids = self._sync_host(ids)
         with self._phase("pw.round.deliver") as ph:
             t_sync1 = time.perf_counter()
@@ -2376,7 +2345,8 @@ class PagedDecodeEngine:
         ph.set(kind="mixed", rows=len(rows), tokens=t, budget=T,
                waiting=len(waiting))
         host = (tokens, positions, row_tables, row_start, row_nvalid,
-                row_token_idx, tok_row, tok_col, sb, so, logit_idx)
+                row_token_idx, tok_row, tok_col, sb, so, logit_idx) \
+            + self._row_extras([act.seq_id for act, _r, _f in rows], ph)
         return (host if samp is None else host + samp), samp is not None, \
             rows, t
 
@@ -2394,9 +2364,7 @@ class PagedDecodeEngine:
         prog = self._sampled_programs()["mixed"] if sampled else self._mixed
         with self._phase("pw.mixed_step_sampled" if sampled
                          else "pw.mixed_step"):
-            ids, self.pool.k, self.pool.v = prog(
-                self.params, self.pool.k, self.pool.v, *dev,
-            )
+            ids = self._call(prog, dev)
         ids = self._sync_host(ids)
         with self._phase("pw.round.deliver") as ph:
             t_sync1 = time.perf_counter()

@@ -130,7 +130,8 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     """Gather-based ragged paged attention.
 
     q: (B, C, H, hd) — C consecutive query tokens per row (C=1 decode);
-    k_pool/v_pool: (num_blocks, block_size, H * hd);
+    k_pool/v_pool: (num_blocks, block_size, Hkv * hd), H a multiple of
+    Hkv: query head ``i`` reads K/V head ``i // (H // Hkv)``;
     block_tables: (B, NB) int32, padded with the null block;
     context_lens: (B,) int32 — the LAST query column's context (position
     of the last query + 1); earlier columns attend to one token less
@@ -148,8 +149,11 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     c0, cl_last = _query_context(C, context_lens, start_pos, n_valid)
     # per-(row, column) context: min(c0 + c, cl_last)
     ctx = jnp.minimum(c0[:, None] + jnp.arange(C)[None, :], cl_last[:, None])
-    k = k_pool[block_tables].reshape(B, NB * BS, H, hd)
-    v = v_pool[block_tables].reshape(B, NB * BS, H, hd)
+    k = k_pool[block_tables].reshape(B, NB * BS, -1, hd)
+    v = v_pool[block_tables].reshape(B, NB * BS, -1, hd)
+    if k.shape[2] != H:  # grouped queries: each K/V head serves H/Hkv
+        k = jnp.repeat(k, H // k.shape[2], axis=2)
+        v = jnp.repeat(v, H // v.shape[2], axis=2)
     # decode_step's exact math: same einsum strings, mask, f32 softmax
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(hd)
     valid = (
@@ -204,21 +208,29 @@ def _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, *, C: int, G: int,
 
 
 def _attend_block(j, c0, ctx, kb, vb, qm_ref, m_ref, l_ref, acc_ref, *,
-                  block_size: int, scale: float, C: int, G: int, hd: int):
+                  block_size: int, scale: float, rep: int, C: int, G: int,
+                  hd: int):
     """One visible K/V block's online-softmax update, shared by both
     kernels.  kb/vb: (BS, H*hd) VALUES — the pool's block as it lies in
     HBM.  Per group of G heads: scores (G*C, BS) of the group's stacked
     query rows against the group's lanes of K, each row's own softmax
     recurrence (m, l in f32), and ``p @ v`` over the group's lanes of V
     into acc (G*C, G*hd) f32 — of which row ``i*C + c`` is read only in
-    head i's lanes (:func:`_write_out`)."""
+    head i's lanes (:func:`_write_out`).  ``rep`` > 1 (grouped queries):
+    the ``rep`` query heads of a K/V head ride as ``rep`` neighbouring
+    columns of it, so of the C columns here column ``c`` is query column
+    ``c // rep``."""
     R, W = G * C, G * hd
     rows_i = jax.lax.broadcasted_iota(jnp.int32, (R, block_size), 0)
     k_pos = j * block_size + jax.lax.broadcasted_iota(
         jnp.int32, (R, block_size), 1
     )
     # column c attends to min(c0 + c, ctx) tokens; row i*C + c is column c
-    col_ctx = jnp.minimum(c0 + (rows_i % C if C > 1 else 0), ctx)
+    if C == rep:  # one query column a row (decode)
+        col = 0
+    else:
+        col = rows_i % C if rep == 1 else (rows_i % C) // rep
+    col_ctx = jnp.minimum(c0 + col, ctx)
     valid = k_pos < col_ctx
     for g in range(qm_ref.shape[0] // R):
         rows = slice(g * R, (g + 1) * R)
@@ -264,7 +276,7 @@ def _write_out(o_ref, l_ref, acc_ref, *, C: int, G: int, hd: int):
 
 def _paged_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
                   qm_ref, m_ref, l_ref, acc_ref, *, block_size: int,
-                  scale: float, **geom):
+                  scale: float, rep: int, **geom):
     """Grid: (B, NB) — blocks innermost, so (m, l, acc) scratch carries the
     online softmax across one sequence's blocks.  Blocks: q and o
     (C, H*hd); k/v (block_size, H*hd) — the physical block of layer
@@ -287,7 +299,8 @@ def _paged_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(j <= jlast)  # skip blocks wholly past the context
     def _visible():
         _attend_block(j, c0, ctx, k_ref[:], v_ref[:], qm_ref, m_ref, l_ref,
-                      acc_ref, block_size=block_size, scale=scale, **geom)
+                      acc_ref, block_size=block_size, scale=scale, rep=rep,
+                      **geom)
 
     # write at the row's LAST VALID block, not the grid edge: later grid
     # steps touch nothing, and the (per-row) output block flushes when
@@ -306,22 +319,47 @@ def _softmax_scratch(H: int, C: int, hd: int, G: int, dtype):
     ]
 
 
+def _fold_queries(q, D: int):
+    """q (B, C, H, hd) -> ((B, C * rep, D) rows for the kernels, rep), D =
+    Hkv * hd the pool's minor axis.  With as many query heads as K/V heads
+    this is a reshape.  With ``rep`` query heads a K/V head they become
+    ``rep`` neighbouring query columns of that K/V head (column
+    ``c * rep + r`` is head ``kv * rep + r`` of query column ``c``), so the
+    kernels see Hkv heads and a wider row of queries."""
+    B, C, H, hd = q.shape
+    rep = H * hd // D
+    if rep == 1:
+        return q.reshape(B, C, D), 1
+    q = q.reshape(B, C, H // rep, rep, hd).transpose(0, 1, 3, 2, 4)
+    return q.reshape(B, C * rep, D), rep
+
+
+def _unfold_queries(o, q_shape, rep: int):
+    B, C, H, hd = q_shape
+    if rep == 1:
+        return o.reshape(B, C, H, hd)
+    o = o.reshape(B, C, rep, H // rep, hd).transpose(0, 1, 3, 2, 4)
+    return o.reshape(B, C, H, hd)
+
+
 def _paged_ragged_fn(q, k_pool, v_pool, layer, block_tables, c0, cl, *,
                      d_true: int, interpret: bool = False):
-    """q: (B, C, H, hd); pools (L, num_blocks, BS, H*hd) — ALL layers'
+    """q: (B, C, H, hd); pools (L, num_blocks, BS, Hkv*hd) — ALL layers'
     stacked pool, read in place at ``layer`` ((1,) int32): the block index
     maps carry the layer, so no layer is ever sliced out of the pool.  A
     block is (BS, H*hd): a whole number of the chip's tiles when H*hd is
     a multiple of 128, so nothing is lane-padded and the pool's layout in
     HBM is the one the kernel reads; c0/cl: (B,) per-row column-0 /
     last-column context lengths."""
-    B, C, H, hd = q.shape
+    B, hd = q.shape[0], q.shape[3]
     BS, D = k_pool.shape[2:]
     NB = block_tables.shape[1]
+    qf, rep = _fold_queries(q, D)
+    C, H = qf.shape[1], D // hd
     G = _heads_per_group(H, hd, C)
     kernel = functools.partial(
         _paged_kernel, block_size=BS, scale=1.0 / np.sqrt(d_true),
-        C=C, G=G, hd=hd,
+        rep=rep, C=C, G=G, hd=hd,
     )
 
     def _kv_map(b, j, li, bt, c0, cl):
@@ -347,8 +385,8 @@ def _paged_ragged_fn(q, k_pool, v_pool, layer, block_tables, c0, cl, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, C, D), q.dtype),
         interpret=interpret,
-    )(layer, block_tables, c0, cl, q.reshape(B, C, D), k_pool, v_pool)
-    return out.reshape(B, C, H, hd)
+    )(layer, block_tables, c0, cl, qf, k_pool, v_pool)
+    return _unfold_queries(out, q.shape, rep)
 
 
 def _make_paged_ragged():
@@ -369,7 +407,8 @@ _paged_ragged = _make_paged_ragged()
 
 def _append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, q_ref, k1_ref,
                    v1_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref, qm_ref, m_ref,
-                   l_ref, acc_ref, *, block_size: int, scale: float, **geom):
+                   l_ref, acc_ref, *, block_size: int, scale: float, rep: int,
+                   **geom):
     """Round-17 fused append+attend (decode, C=1): the incoming token's
     K/V rides into the kernel as a (1, H*hd) operand, is patched into the
     tail block IN REGISTER for the attention math, and is flushed back
@@ -403,7 +442,8 @@ def _append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, q_ref, k1_ref,
         last = j == jlast
         _attend_block(j, c0, ctx, _patched(k_ref, k1_ref, last),
                       _patched(v_ref, v1_ref, last), qm_ref, m_ref, l_ref,
-                      acc_ref, block_size=block_size, scale=scale, **geom)
+                      acc_ref, block_size=block_size, scale=scale, rep=rep,
+                      **geom)
 
     @pl.when(j == jlast)
     def _final():
@@ -417,21 +457,23 @@ def _append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, q_ref, k1_ref,
 def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, layer, block_tables,
                      c0, cl, slot_offsets, *, d_true: int,
                      interpret: bool = False):
-    """q: (B, 1, H, hd); k_new/v_new: (B, H, hd); pools
-    (L, num_blocks, BS, H*hd) — ALL layers' stacked pool, returned
+    """q: (B, 1, H, hd); k_new/v_new: (B, Hkv, hd); pools
+    (L, num_blocks, BS, Hkv*hd) — ALL layers' stacked pool, returned
     UPDATED at ``layer`` ((1,) int32), aliased in place on TPU: one tail
     block per row is written, nothing else of the pool is touched or
     copied.  Contract: the slot is the tail of the attended context
     (``slot_blocks[b] == block_tables[b, (cl[b]-1)//BS]`` and
     ``slot_offsets[b] == (cl[b]-1) % BS``) — the decode append the
     engine constructs by definition."""
-    B, C, H, hd = q.shape
+    B, hd = q.shape[0], q.shape[3]
     BS, D = k_pool.shape[2:]
     NB = block_tables.shape[1]
+    qf, rep = _fold_queries(q, D)
+    C, H = qf.shape[1], D // hd
     G = _heads_per_group(H, hd, C)
     kernel = functools.partial(
         _append_kernel, block_size=BS, scale=1.0 / np.sqrt(d_true),
-        C=C, G=G, hd=hd,
+        rep=rep, C=C, G=G, hd=hd,
     )
 
     def _kv_map(b, j, li, bt, c0, cl, so):
@@ -473,9 +515,9 @@ def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, layer, block_tables,
         ],
         input_output_aliases={8: 1, 9: 2},
         interpret=interpret,
-    )(layer, block_tables, c0, cl, slot_offsets, q.reshape(B, C, D),
+    )(layer, block_tables, c0, cl, slot_offsets, qf,
       k_new.reshape(B, 1, D), v_new.reshape(B, 1, D), k_pool, v_pool)
-    return o.reshape(B, C, H, hd), k_pool, v_pool
+    return _unfold_queries(o, q.shape, rep), k_pool, v_pool
 
 
 def _make_paged_append():

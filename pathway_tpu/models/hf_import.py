@@ -145,6 +145,126 @@ def config_from_gpt2(hf_config):
     )
 
 
+def config_from_lfm2_moe(hf_config, *, max_len: int | None = None,
+                         dtype="auto"):
+    """``lfm2_moe`` config (LiquidAI/LFM2-8B-A1B's ``config.json`` keys) ->
+    :class:`~pathway_tpu.models.lfm2.Lfm2Config`.  ``layer_types`` is read
+    as published and decides the depth; ``max_len`` caps the served
+    context below ``max_position_embeddings`` (rotary positions: any cap
+    is exact).  The head is tied to the embedding unless
+    ``tie_word_embeddings`` (or LFM2's ``tie_embedding``) says otherwise;
+    the published config gives neither key."""
+    from .lfm2 import Lfm2Config
+
+    def get(name, default=None):
+        return getattr(hf_config, name, default)
+
+    if get("model_type") != "lfm2_moe":
+        raise ValueError(
+            f"expected an lfm2_moe config, got model_type="
+            f"{get('model_type')!r}")
+    if get("conv_bias", False):
+        raise ValueError("conv_bias=True is not written down here")
+    layer_types = tuple(hf_config.layer_types)
+    n_layers = get("num_hidden_layers", len(layer_types))
+    if n_layers != len(layer_types):
+        raise ValueError(
+            f"num_hidden_layers={n_layers} but layer_types names "
+            f"{len(layer_types)} layers")
+    positions = int(hf_config.max_position_embeddings)
+    tied = get("tie_word_embeddings", get("tie_embedding", True))
+    return Lfm2Config(
+        vocab_size=hf_config.vocab_size,
+        d_model=hf_config.hidden_size,
+        n_heads=hf_config.num_attention_heads,
+        n_kv_heads=hf_config.num_key_value_heads,
+        d_ff=hf_config.intermediate_size,
+        d_ff_expert=hf_config.moe_intermediate_size,
+        n_experts=hf_config.num_experts,
+        top_k=hf_config.num_experts_per_tok,
+        n_dense_layers=hf_config.num_dense_layers,
+        layer_types=layer_types,
+        conv_kernel=int(get("conv_L_cache", 3)),
+        rope_theta=float(get("rope_theta", 1e6)),
+        norm_eps=float(get("norm_eps", 1e-5)),
+        max_len=min(positions, int(max_len)) if max_len else positions,
+        dtype=dtype,
+        norm_topk_prob=bool(get("norm_topk_prob", True)),
+        use_expert_bias=bool(get("use_expert_bias", True)),
+        routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
+        tie_embedding=bool(tied),
+    )
+
+
+def params_from_lfm2_state_dict(state: dict[str, Any], cfg) -> dict:
+    """Map a (torch) LFM2-family state dict onto
+    :mod:`pathway_tpu.models.lfm2`'s parameter pytree, in ``cfg``'s dtype.
+    Linear weights transpose (torch stores out x in); the depthwise conv's
+    ``(d, 1, 3)`` taps become ``(d, 3)``.  Dense feed-forwards are
+    ``feed_forward.w1/w2/w3``; an expert layer is read as
+    ``feed_forward.gate``, ``feed_forward.expert_bias`` and
+    ``feed_forward.experts.<e>.w1/w2/w3`` (the names of the ``lfm2_moe``
+    checkpoints; the installed ``transformers`` has only the dense
+    family, which is what the parity test holds this to)."""
+    import jax.numpy as jnp
+
+    from .encoder import _resolve_dtype
+    from .lfm2 import ATTENTION
+
+    dtype = _resolve_dtype(cfg.dtype)
+
+    def get(name: str) -> np.ndarray:
+        for prefix in ("", "model."):
+            if prefix + name in state:
+                v = state[prefix + name]
+                return np.asarray(v.detach().cpu().float().numpy()
+                                  if hasattr(v, "detach") else v)
+        raise KeyError(name)
+
+    def arr(x, keep_f32=False):
+        return jnp.asarray(x, jnp.float32 if keep_f32 else dtype)
+
+    def lin(name: str):
+        return arr(get(name + ".weight").T)
+
+    params: dict = {"embed": arr(get("embed_tokens.weight")),
+                    "norm_out": arr(get("embedding_norm.weight")),
+                    "layers": []}
+    if not cfg.tie_embedding:
+        params["head"] = arr(np.asarray(state["lm_head.weight"]).T)
+    for li, kind in enumerate(cfg.layer_types):
+        pre = f"layers.{li}."
+        lay = {"norm_op": arr(get(pre + "operator_norm.weight")),
+               "norm_ffn": arr(get(pre + "ffn_norm.weight"))}
+        if kind == ATTENTION:
+            at = pre + "self_attn."
+            lay.update(wq=lin(at + "q_proj"), wk=lin(at + "k_proj"),
+                       wv=lin(at + "v_proj"), wo=lin(at + "out_proj"),
+                       q_norm=arr(get(at + "q_layernorm.weight")),
+                       k_norm=arr(get(at + "k_layernorm.weight")))
+        else:
+            cv = pre + "conv."
+            lay.update(w_in=lin(cv + "in_proj"), w_out=lin(cv + "out_proj"),
+                       conv_w=arr(get(cv + "conv.weight")[:, 0, :]))
+        ff = pre + "feed_forward."
+        if li < cfg.n_dense_layers:
+            lay.update(w1=lin(ff + "w1"), w3=lin(ff + "w3"),
+                       w2=lin(ff + "w2"))
+        else:
+            def experts(w):
+                return arr(np.stack([
+                    get(f"{ff}experts.{e}.{w}.weight").T
+                    for e in range(cfg.n_experts)]))
+
+            lay.update(wg=lin(ff + "gate"), w1=experts("w1"),
+                       w3=experts("w3"), w2=experts("w2"))
+            if cfg.use_expert_bias:
+                lay["expert_bias"] = arr(get(ff + "expert_bias"),
+                                         keep_f32=True)
+        params["layers"].append(lay)
+    return params
+
+
 def params_from_gpt2_state_dict(state: dict[str, Any], cfg) -> dict:
     """Map a (torch) GPT-2 state dict onto the decoder's param pytree.
 

@@ -10,7 +10,9 @@ alternative named:
 
 - **params**: the decoder weights, per tensor-parallel shard;
 - **KV pool**: BlockPool's stacked K/V arrays — the same per-shard
-  formula as PR 4's ``shard_hbm_bytes`` gauge;
+  formula as PR 4's ``shard_hbm_bytes`` gauge; for a hybrid block family
+  over the attention layers and K/V heads only, with the conv slot arena
+  beside it (``conv_bytes``);
 - **step temps**: the transient working set of the largest step
   program.  When the program registry (obs/profiler.py) already holds
   a MEASURED ``memory_analysis()`` temp watermark for the engine's
@@ -88,6 +90,8 @@ def _params_bytes(cfg, params, tp: int, itemsize: int) -> int:
             return int(total // max(tp, 1))
         except Exception:  # noqa: BLE001 - fall through to analytic
             pass
+    if hasattr(cfg, "param_count"):  # a family that counts its own
+        return int(cfg.param_count() * itemsize // max(tp, 1))
     d, v, ff, ln = cfg.d_model, cfg.vocab_size, cfg.d_ff, cfg.n_layers
     n = v * d + cfg.max_len * d + ln * (4 * d * d + 2 * d * ff + 9 * d) \
         + 2 * d
@@ -98,9 +102,28 @@ def kv_pool_bytes(cfg, *, num_blocks: int, block_size: int, tp: int,
                   itemsize: int) -> int:
     """K + V bytes held by EACH shard — BlockPool.per_shard_bytes
     computed from the configuration before the pool exists."""
+    layers, kv_heads, hd = _kv_geometry(cfg)
+    heads = max(kv_heads // max(tp, 1), 1)
+    return 2 * layers * num_blocks * block_size * heads * hd * itemsize
+
+
+def _kv_geometry(cfg) -> tuple:
+    """(layers that keep K/V, K/V heads, head_dim): all layers and all
+    heads for the plain decoder; a hybrid family keeps K/V in its attention
+    layers only, and fewer K/V heads than query heads."""
     hd = cfg.d_model // cfg.n_heads
-    heads = max(cfg.n_heads // max(tp, 1), 1)
-    return 2 * cfg.n_layers * num_blocks * block_size * heads * hd * itemsize
+    attn_layers = getattr(cfg, "attn_layers", None)
+    return (cfg.n_layers if attn_layers is None else len(attn_layers),
+            getattr(cfg, "n_kv_heads", cfg.n_heads), hd)
+
+
+def conv_arena_bytes(cfg, *, max_batch_size: int, itemsize: int) -> int:
+    """The conv slot arena of a hybrid family (kvcache/hybrid.py): two
+    carried vectors a conv layer for every batch row and the null slot.
+    0 for a family without conv layers."""
+    conv_layers = getattr(cfg, "conv_layers", ())
+    return len(conv_layers) * (max_batch_size + 1) * 2 * cfg.d_model \
+        * itemsize
 
 
 def _temp_bytes(cfg, *, num_blocks: int, block_size: int,
@@ -129,6 +152,11 @@ def _temp_bytes(cfg, *, num_blocks: int, block_size: int,
         if reference_attn else 0
     )
     acts = 6 * T * max(d, cfg.d_ff) * itemsize  # packed stream residuals
+    if getattr(cfg, "n_experts", 0):
+        # routed pairs laid out by expert in whole tiles of 16 rows: the
+        # gathered inputs, the experts' hidden rows and their outputs
+        rows = T * cfg.top_k + 16 * cfg.n_experts
+        acts += rows * (2 * d + cfg.d_ff_expert) * itemsize
     logits = B * vocab * 4  # f32 head output
     chain = B * max(chain_steps, 1) * 4 * 2  # [B, K] ids carry + stack
     return int(gather + acts + logits + chain)
@@ -157,11 +185,15 @@ class HbmPlan:
     # of the paged K/V pool formula — the number the constant-memory
     # capacity headline is computed from
     state_bytes_per_seq: int | None = None
+    # a hybrid family's conv slot arena (0 elsewhere): fixed by the batch,
+    # not by num_blocks
+    conv_bytes: int = 0
     _replan: "object" = dataclasses.field(default=None, repr=False)
 
     @property
     def total_bytes(self) -> int:
-        return self.params_bytes + self.kv_bytes + self.temp_bytes
+        return self.params_bytes + self.kv_bytes + self.conv_bytes \
+            + self.temp_bytes
 
     @property
     def fits(self) -> bool:
@@ -201,8 +233,8 @@ class HbmPlan:
         if self.budget_bytes is None:
             return self.num_blocks
         per_block = max(self.per_block_bytes, 1)
-        nb = (self.budget_bytes - self.params_bytes - self.temp_bytes) \
-            // per_block
+        nb = (self.budget_bytes - self.params_bytes - self.conv_bytes
+              - self.temp_bytes) // per_block
         nb = min(int(nb), self.num_blocks)
         while nb >= 2 and not self.with_(num_blocks=nb).fits:
             nb -= max(nb // 8, 1)
@@ -245,7 +277,8 @@ class HbmPlan:
             f"engine configuration cannot fit HBM: params "
             f"{self.params_bytes / mb:.1f}MB + KV pool "
             f"{self.kv_bytes / mb:.1f}MB ({self.num_blocks} blocks x "
-            f"{self.block_size} tokens, tp={self.tp}) + step temps "
+            f"{self.block_size} tokens, tp={self.tp}) + conv arena "
+            f"{self.conv_bytes / mb:.1f}MB + step temps "
             f"{self.temp_bytes / mb:.1f}MB ({self.temp_source}) = "
             f"{self.total_bytes / mb:.1f}MB > HBM budget "
             f"{self.budget_bytes / mb:.1f}MB ({self.budget_source}); "
@@ -256,6 +289,7 @@ class HbmPlan:
         return {
             "params_bytes": self.params_bytes,
             "kv_bytes": self.kv_bytes,
+            "conv_bytes": self.conv_bytes,
             "temp_bytes": self.temp_bytes,
             "temp_source": self.temp_source,
             "total_bytes": self.total_bytes,
@@ -350,6 +384,8 @@ def hbm_plan(cfg, *, num_blocks: int, block_size: int,
             max_batch_size=int(max_batch_size),
             chain_steps=int(chain_steps), prefill_chunk=pchunk, tp=tp,
             state_bytes_per_seq=state_bytes_per_seq,
+            conv_bytes=conv_arena_bytes(
+                cfg, max_batch_size=int(max_batch_size), itemsize=itemsize),
         )
         plan._replan = _build
         return plan

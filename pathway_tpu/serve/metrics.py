@@ -171,6 +171,12 @@ class KVCacheStats:
       /spec_rounds = accepted tokens per dispatch, the headline)
     - ``pathway_kv_spec_rounds_total{pool}``    counter (verify
       dispatches)
+    - ``pathway_kv_conv_slots_in_use{pool}`` / ``..._total{pool}`` gauges
+      (hybrid caches: sequences holding a conv slot, and the arena's size)
+    - ``pathway_kv_moe_routed_pairs_total{pool}`` counter ((token, expert)
+      pairs the step programs routed, counted on the device) and
+      ``pathway_kv_moe_tokens_per_expert_total{pool,expert}`` (the same,
+      by expert, summed over the expert layers)
     - ``pathway_kv_shard_hbm_bytes{pool,shard}``     gauge (Round-9: K+V
       HBM held by each tensor-parallel shard)
     - ``pathway_kv_shard_blocks_in_use{pool,shard}`` gauge (block
@@ -227,6 +233,28 @@ class KVCacheStats:
         from collections import deque as _deque
 
         self.recent_ttfts = _deque(maxlen=256)
+        # hybrid caches (kvcache/hybrid.py): the conv slot arena, and what
+        # the step programs counted on the device - (token, expert) pairs
+        # routed, and tokens per expert summed over the expert layers
+        self.conv_slots_total = 0
+        self._conv_slots_in_use_fn = None
+        self.moe_routed_pairs = 0
+        self.moe_tokens_per_expert: list[int] = []
+        self.moe_fullest_expert_tokens = 0  # sum over programs of the max
+
+    @property
+    def conv_slots_in_use(self) -> int:
+        fn = self._conv_slots_in_use_fn
+        return int(fn()) if fn is not None else 0
+
+    def record_moe(self, counts) -> None:
+        with self._lock:
+            if len(self.moe_tokens_per_expert) != len(counts):
+                self.moe_tokens_per_expert = [0] * len(counts)
+            for e, n in enumerate(counts):
+                self.moe_tokens_per_expert[e] += int(n)
+            self.moe_routed_pairs += int(sum(int(n) for n in counts))
+            self.moe_fullest_expert_tokens += int(max(counts))
 
     def record_prefix_hit(self, n: int = 1) -> None:
         with self._lock:
@@ -414,6 +442,11 @@ class KVCacheStats:
                 "engine_recovery_s_sum": self.engine_recovery_s_sum,
                 "last_engine_recovery_s": self.last_engine_recovery_s,
                 "engine_degraded": self.engine_degraded,
+                "conv_slots_in_use": self.conv_slots_in_use,
+                "conv_slots_total": self.conv_slots_total,
+                "moe_routed_pairs": self.moe_routed_pairs,
+                "moe_tokens_per_expert": list(self.moe_tokens_per_expert),
+                "moe_fullest_expert_tokens": self.moe_fullest_expert_tokens,
             }
 
 
@@ -961,6 +994,10 @@ def _render_kv_lines() -> list[str]:
         "# TYPE pathway_kv_engine_restart_seconds_total counter",
         "# TYPE pathway_kv_engine_recovery_seconds_total counter",
         "# TYPE pathway_kv_engine_degraded_total counter",
+        "# TYPE pathway_kv_conv_slots_in_use gauge",
+        "# TYPE pathway_kv_conv_slots_total gauge",
+        "# TYPE pathway_kv_moe_routed_pairs_total counter",
+        "# TYPE pathway_kv_moe_tokens_per_expert_total counter",
     ]
     for s in stats:
         snap = s.snapshot()
@@ -1110,6 +1147,17 @@ def _render_kv_lines() -> list[str]:
             f"pathway_kv_engine_degraded_total{{{lbl}}} "
             f"{snap['engine_degraded']}"
         )
+        if snap["conv_slots_total"]:  # a hybrid cache (kvcache/hybrid.py)
+            lines.append(f"pathway_kv_conv_slots_in_use{{{lbl}}} "
+                         f"{snap['conv_slots_in_use']}")
+            lines.append(f"pathway_kv_conv_slots_total{{{lbl}}} "
+                         f"{snap['conv_slots_total']}")
+            lines.append(f"pathway_kv_moe_routed_pairs_total{{{lbl}}} "
+                         f"{snap['moe_routed_pairs']}")
+            for e, n in enumerate(snap["moe_tokens_per_expert"]):
+                lines.append(
+                    f'pathway_kv_moe_tokens_per_expert_total{{{lbl},'
+                    f'expert="{e}"}} {n}')
     return lines
 
 

@@ -239,3 +239,98 @@ def test_flash_attention_kernel_compiles(shape, bh, t, dtype):
         x, x, x, causal=True, t_valid=t, d_true=64
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- the lfm2_moe block family (LFM2-8B-A1B's published widths) ---------------
+# 2048 wide, 32 query heads over 8 K/V heads of 64, 32 experts of 1792
+# top-4, conv 3, vocab 65,536; the engine's geometry for the benchmark's
+# cut: 16 rows, 2,049 blocks of 16, tables of 128, chains of 16.
+
+
+def _lfm2_programs(shape, depth):
+    """(cfg, parameter shapes, pool, arena, the three step programs with
+    their index arguments) at ``depth`` layers of the published pattern
+    after one leading dense layer."""
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.models import lfm2
+
+    pattern = ("conv", "full_attention", "conv", "conv", "conv") \
+        + ("full_attention", "conv", "conv", "conv") * 2
+    cfg = lfm2.Lfm2Config(n_dense_layers=1, layer_types=pattern[:depth],
+                          max_len=2048, dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(
+        lambda: lfm2.init_lfm2_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(lambda s: shape(s.shape, s.dtype), shapes)
+    rows, chunk, tables, blocks, chain = 16, 32, 128, 2049, 16
+    T, i32 = rows + chunk, jnp.int32
+    pool = shape((len(cfg.attn_layers), blocks, BS,
+                  cfg.n_kv_heads * cfg.head_dim), jnp.bfloat16)
+    arena = shape((len(cfg.conv_layers), rows + 1, 2, cfg.d_model),
+                  jnp.bfloat16)
+
+    def vec(*dims):
+        return shape(dims, i32)
+
+    step = (vec(rows), vec(rows), vec(rows, tables))
+    programs = {
+        "mixed": (lfm2.hybrid_mixed_step, (
+            vec(T), vec(T), vec(rows, tables), vec(rows), vec(rows),
+            vec(rows, chunk), vec(T), vec(T), vec(T), vec(T), vec(rows),
+            vec(rows))),
+        "decode": (lfm2.hybrid_decode_step,
+                   step + (vec(rows), vec(rows), vec(rows))),
+        "chained": (lfm2.hybrid_chained_decode,
+                    step + (vec(rows, chain), vec(rows, chain), vec(rows))),
+    }
+    return cfg, params, pool, arena, programs
+
+
+@pytest.mark.parametrize("program", ["mixed", "decode", "chained"])
+def test_lfm2_step_programs_keep_the_pool_where_it_is(shape, program,
+                                                      monkeypatch):
+    """The hybrid family's three step programs at the published widths,
+    two periods of the layer pattern deep: the kernels are in the compiled
+    text (three attention calls, two grouped matmuls an expert layer), the
+    K/V pool enters row-major and is never copied whole, the temporaries
+    are a few MB.  The step functions ask the backend whether to
+    interpret their kernels; here they are told it is the chip."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, params, pool, arena, programs = _lfm2_programs(shape, depth=9)
+    fn, args = programs[program]
+    compiled = jax.jit(
+        lambda p, k, v, c, *a: fn(p, cfg, k, v, c, *a, attn="pallas"),
+        donate_argnums=(1, 2, 3),
+    ).lower(params, pool, pool, arena, *args).compile()
+    n_moe = cfg.n_layers - cfg.n_dense_layers
+    assert compiled.as_text().count("tpu_custom_call") \
+        == len(cfg.attn_layers) + 2 * n_moe
+    layouts = compiled.input_formats[0]
+    for i in (1, 2):
+        assert layouts[i].layout.major_to_minor == (0, 1, 2, 3), layouts[i]
+    assert _pool_copies(compiled, pool.shape) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("pairs", [64, 192], ids=["decode", "mixed"])
+def test_grouped_matmul_kernel_compiles_at_the_published_experts(shape,
+                                                                 pairs):
+    """32 experts of 3 x 2048 x 1792 in bf16, the routed pairs of a decode
+    step (16 x 4) and of a mixed step (48 x 4), in whole tiles of 16."""
+    import jax.numpy as jnp
+
+    from pathway_tpu.ops import moe
+
+    E, D, F = 32, 2048, 1792
+    tiles = moe.n_tiles(pairs, E)
+    bf16 = jnp.bfloat16
+    compiled = _compiled_kernel(
+        moe._moe_gmm_fn, shape((tiles * moe.TM, D), bf16),
+        shape((E, D, F), bf16), shape((E, D, F), bf16),
+        shape((E, F, D), bf16), shape((tiles,), jnp.int32),
+        shape((1,), jnp.int32))
+    assert compiled.as_text().count("tpu_custom_call") == 2
+    assert "_moe_gmm_fn" in compiled.as_text()

@@ -100,3 +100,105 @@ def test_hf_encoder_end_to_end(tmp_path):
     v = enc.embed("hello world")
     assert v.shape == (32,)
     assert abs(float(np.linalg.norm(v)) - 1.0) < 1e-3
+
+
+def test_lfm2_mixers_match_transformers():
+    """The gated short conv, grouped-query attention with q/k norms and
+    rotate-half rotary, RMSNorm and SwiGLU of the ``lfm2`` block family
+    against ``transformers``' Lfm2 (the dense sibling of ``lfm2_moe``: the
+    installed version has no expert variant): the plain f32 reference on
+    the imported weights gives torch's logits, and so does the program's
+    mixed step fed the prompt in runs of 5."""
+    import importlib
+    import types
+
+    import jax.numpy as jnp
+    from transformers import Lfm2Config, Lfm2ForCausalLM
+
+    from pathway_tpu.models import hf_import
+
+    torch.manual_seed(0)
+    kinds = ["conv", "full_attention", "conv", "conv"]
+    hf = Lfm2Config(vocab_size=180, hidden_size=32, intermediate_size=64,
+                    num_hidden_layers=4, num_attention_heads=4,
+                    num_key_value_heads=2, max_position_embeddings=64,
+                    layer_types=kinds, block_auto_adjust_ff_dim=False,
+                    attn_implementation="eager")
+    model = Lfm2ForCausalLM(hf).eval()
+    published = types.SimpleNamespace(
+        model_type="lfm2_moe", vocab_size=180, hidden_size=32,
+        intermediate_size=64, moe_intermediate_size=16, num_experts=4,
+        num_experts_per_tok=2, num_dense_layers=4, num_hidden_layers=4,
+        num_attention_heads=4, num_key_value_heads=2, layer_types=kinds,
+        max_position_embeddings=64, norm_eps=hf.norm_eps,
+        rope_theta=hf.rope_theta, conv_L_cache=3, conv_bias=False)
+    cfg = hf_import.config_from_lfm2_moe(published, dtype=jnp.float32)
+    params = hf_import.params_from_lfm2_state_dict(model.state_dict(), cfg)
+    ids = np.random.default_rng(0).integers(0, 180, (2, 17))
+    with torch.no_grad():
+        want = model(input_ids=torch.tensor(ids)).logits.numpy()
+    ref = importlib.import_module("benchmark.reference.lfm2_moe_f32")
+    from benchmark.systems.serve_lfm2 import decoder_shape
+
+    rows, cols = np.divmod(np.arange(2 * 17), 17)
+    got, _margin = ref.logits_at(params, decoder_shape(cfg, 0), ids, rows,
+                                 cols)
+    assert np.abs(np.asarray(got).reshape(2, 17, -1) - want).max() < 2e-4
+    # the program: one row, the first prompt in runs of 5 tokens
+    from .utils import lfm2_feed
+
+    runs, _state = lfm2_feed(cfg, params, ids[0].tolist(), 5)
+    assert [n for n, _l in runs] == [5, 10, 15, 17]
+    for n, logits in runs:
+        assert np.abs(logits - want[0, n - 1]).max() < 2e-4
+
+
+@pytest.mark.parametrize("case", ["published", "cut_to_13", "untied",
+                                  "depth_mismatch", "wrong_family"])
+def test_lfm2_moe_config_import(case):
+    """``config_from_lfm2_moe`` on LiquidAI/LFM2-8B-A1B's published keys,
+    on the benchmark's cut of them, and on configs it must refuse."""
+    import types
+
+    from pathway_tpu.models import hf_import
+
+    kinds = ["conv", "conv", "full_attention", "conv", "conv", "conv",
+             "full_attention", "conv", "conv", "conv", "full_attention",
+             "conv", "conv", "conv", "full_attention", "conv", "conv",
+             "conv", "full_attention", "conv", "conv", "full_attention",
+             "conv", "conv"]
+    pub = dict(
+        model_type="lfm2_moe", conv_L_cache=3, conv_bias=False,
+        hidden_size=2048, intermediate_size=7168, layer_types=kinds,
+        max_position_embeddings=128000, moe_intermediate_size=1792,
+        norm_eps=1e-05, norm_topk_prob=True, num_attention_heads=32,
+        num_dense_layers=2, num_experts=32, num_experts_per_tok=4,
+        num_hidden_layers=24, num_key_value_heads=8, rope_theta=1000000,
+        routed_scaling_factor=1, use_expert_bias=True, vocab_size=65536)
+    if case == "cut_to_13":
+        pub.update(num_hidden_layers=13, num_dense_layers=1,
+                   layer_types=kinds[1:14])
+    elif case == "untied":
+        pub["tie_word_embeddings"] = False
+    elif case == "depth_mismatch":
+        pub["num_hidden_layers"] = 23
+    elif case == "wrong_family":
+        pub["model_type"] = "gpt2"
+    if case in ("depth_mismatch", "wrong_family"):
+        with pytest.raises(ValueError):
+            hf_import.config_from_lfm2_moe(types.SimpleNamespace(**pub))
+        return
+    cfg = hf_import.config_from_lfm2_moe(types.SimpleNamespace(**pub),
+                                         max_len=2048)
+    assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_ff,
+            cfg.d_ff_expert, cfg.n_experts, cfg.top_k, cfg.vocab_size) \
+        == (2048, 32, 8, 7168, 1792, 32, 4, 65536)
+    assert cfg.rope_theta == 1e6 and cfg.norm_eps == 1e-5
+    assert cfg.max_len == 2048 and cfg.family == "lfm2"
+    assert cfg.tie_embedding == (case != "untied")
+    if case == "cut_to_13":
+        assert cfg.n_layers == 13 and cfg.attn_layers == (1, 5, 9)
+        assert len(cfg.conv_layers) == 10 and cfg.n_dense_layers == 1
+        assert 4.60e9 < cfg.param_count() < 4.61e9  # 9.2 GB in bf16
+    else:
+        assert cfg.n_layers == 24 and len(cfg.attn_layers) == 6

@@ -211,9 +211,46 @@ def test_counter_tracks_in_flight_recorder_dump(params):
 # -- HBM ledger + pre-flight fit -------------------------------------------
 
 
-def test_hbm_plan_kv_term_matches_block_pool(params):
+def _lfm2_published():
+    """LFM2-8B-A1B's published widths, layers 1 to 13 (the benchmark's
+    cut): one dense conv layer, then (attention, conv, conv, conv) x 3."""
+    from pathway_tpu.models.lfm2 import ATTENTION as A, CONV as C, Lfm2Config
+
+    return Lfm2Config(n_dense_layers=1, max_len=2048, dtype="bfloat16",
+                      layer_types=(C, A, C, C, C, A, C, C, C, A, C, C, C))
+
+
+@pytest.mark.parametrize("family", ["decoder", "lfm2"])
+def test_hbm_plan_kv_term_matches_block_pool(params, family):
+    from pathway_tpu.kvcache.backend import make_backend
     from pathway_tpu.kvcache.block_pool import BlockPool
 
+    if family == "lfm2":
+        # a hybrid family: K/V over the attention layers and K/V heads
+        # only, the conv arena beside it, expert leaves at their own width
+        import jax.numpy as jnp
+
+        cfg = _lfm2_published()
+        plan = obs_memory.hbm_plan(
+            cfg, num_blocks=64, block_size=16, max_batch_size=16,
+            chain_steps=16, dtype=jnp.bfloat16,
+        )
+        pool = make_backend(
+            "hybrid", num_blocks=64, block_size=16, n_layers=3, n_heads=8,
+            head_dim=64, dtype=jnp.bfloat16, name="t_prof_hybrid",
+            conv_layers=10, conv_width=2048, conv_slots=16,
+        )
+        assert plan.per_block_bytes == 3 * 2 * 16 * 512 * 2 == 98304
+        assert plan.conv_bytes == pool.conv_bytes == 10 * 17 * 2 * 2048 * 2
+        assert plan.kv_bytes + plan.conv_bytes == pool.per_shard_bytes
+        # 4,605M parameters in bf16, the experts 12 x 32 x 3 x 2048 x 1792
+        experts = 12 * 32 * 3 * 2048 * 1792
+        assert plan.params_bytes == cfg.param_count() * 2
+        assert 0.9 < experts * 2 / plan.params_bytes < 0.93
+        assert 9.1e9 < plan.params_bytes < 9.3e9
+        assert plan.total_bytes == plan.params_bytes + plan.kv_bytes \
+            + plan.conv_bytes + plan.temp_bytes
+        return
     plan = obs_memory.hbm_plan(
         _CFG, num_blocks=64, block_size=8, max_batch_size=4,
         chain_steps=8, dtype=np.float32, params=params,
@@ -224,6 +261,7 @@ def test_hbm_plan_kv_term_matches_block_pool(params):
         name="t_prof_pool",
     )
     assert plan.kv_bytes == pool.per_shard_bytes
+    assert plan.conv_bytes == 0
     # exact params term from the live pytree
     leaves = jax.tree_util.tree_leaves(params)
     assert plan.params_bytes == sum(

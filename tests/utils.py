@@ -358,3 +358,45 @@ class CompileWatch:
             "(no provenance available — wrap the entry point with "
             "obs.profiler.profiled_jit to name it)"
         )
+
+
+def lfm2_feed(cfg, params, prompt, chunk, *, conv0=None, slot=1,
+              block_size=8):
+    """``prompt`` through ``models.lfm2.hybrid_mixed_step`` in runs of
+    ``chunk`` tokens, one row, packed as the engine packs a chunk row (f32
+    cache, gather path).  Returns ``[(tokens fed so far, the last fed
+    position's logits)]`` a run, and the row's conv slot afterwards."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pathway_tpu.models import lfm2
+
+    bs = block_size
+    nb = -(-len(prompt) // bs)
+    k = jnp.zeros((len(cfg.attn_layers), nb + 1, bs,
+                   cfg.n_kv_heads * cfg.head_dim), jnp.float32)
+    conv = jnp.zeros((len(cfg.conv_layers), slot + 1, 2, cfg.d_model),
+                     jnp.float32) if conv0 is None else conv0
+    state = (k, jnp.zeros_like(k), conv)
+    table = np.arange(1, nb + 1, dtype=np.int32)[None, :]
+    step = jax.jit(lambda *a: lfm2.hybrid_mixed_step(params, cfg, *a))
+
+    def i32(x):
+        return jnp.asarray(np.asarray(x, np.int32))
+
+    out = []
+    for s in range(0, len(prompt), chunk):
+        run = list(prompt[s: s + chunk])
+        nv, pad = len(run), chunk - len(run)
+        pos = list(range(s, s + nv))
+        logits, *state = step(
+            *state, i32(run + [0] * pad), i32(pos + [0] * pad), i32(table),
+            i32([s]), i32([nv]), i32([list(range(nv)) + [nv - 1] * pad]),
+            i32([0] * chunk), i32(list(range(nv)) + [0] * pad),
+            i32([table[0, p // bs] for p in pos] + [0] * pad),
+            i32([p % bs for p in pos] + [0] * pad), i32([nv - 1]),
+            i32([slot]))
+        state = state[:3]
+        out.append((s + nv, np.asarray(logits[0])))
+    return out, np.asarray(state[2][:, slot])
