@@ -70,6 +70,7 @@ import numpy as np
 from .. import faults, obs
 from .backend import make_backend
 from .block_pool import BlockPool, PoolExhausted  # noqa: F401 - re-export
+from .paged_attention import span_blocks
 from .prefix_cache import PrefixCache
 
 
@@ -616,6 +617,10 @@ class PagedDecodeEngine:
             max_blocks_per_seq = -(-min(cfg.max_len, cap) // bs)
         self.max_blocks_per_seq = int(max_blocks_per_seq)
         self.max_seq_tokens = min(self.max_blocks_per_seq * bs, cfg.max_len)
+        # keys one grid step of the paged kernels spans (a lane tile: eight
+        # blocks of 16), for ``kv_key_lanes`` on ``pw.round.build``
+        self._span_keys = bs * span_blocks(
+            bs, self.max_blocks_per_seq, self.pool.k.shape[-1] // self.tp)
         # prefill buckets: block-aligned, capped at what one table can span.
         # The cap itself must round DOWN to a block multiple — rounding a
         # bucket up past a non-aligned max_seq_tokens (cfg.max_len not a
@@ -2033,6 +2038,9 @@ class PagedDecodeEngine:
             )
             ph.set(kind="chain", rows=len(acts), tokens=sum(kreal),
                    budget=B * K, waiting=0)
+            self._note_keys(ph, [int(positions[i]) + 1 + t
+                                 for i, k in enumerate(kreal)
+                                 for t in range(k)])
             extras = self._row_extras([a.seq_id for a in acts], ph)
         host = (token, positions, bt, sb, so) + extras
         faults.fire("engine.dispatch.chain")
@@ -2160,6 +2168,18 @@ class PagedDecodeEngine:
                 return True
             inflight = nxt
 
+    def _note_keys(self, ph, contexts) -> None:
+        """What a round's calls of the paged kernels attend, on
+        ``pw.round.build`` and in the pool's counters: ``kv_keys``, the
+        live rows' context lengths summed (a chain's rows once a step),
+        and ``kv_key_lanes``, the key lanes their live grid steps span
+        (every context rounded up to whole spans)."""
+        span = self._span_keys
+        keys = sum(contexts)
+        lanes = sum(-(-c // span) for c in contexts) * span
+        ph.set(kv_keys=keys, kv_key_lanes=lanes)
+        self.pool.stats.record_attended_keys(keys, lanes)
+
     def _row_extras(self, seq_ids: list, ph) -> tuple:
         """The cache's own per-row arrays of a step, in row order (a
         hybrid cache: the rows' conv slots, noted on ``pw.round.build`` as
@@ -2192,6 +2212,7 @@ class PagedDecodeEngine:
         )
         ph.set(kind="step", rows=len(reserved), tokens=len(reserved),
                budget=B, waiting=0)
+        self._note_keys(ph, [int(p) + 1 for p in positions[:len(reserved)]])
         host = (token, positions, bt, sb, so) + self._row_extras(
             [act.seq_id for act, _s in reserved], ph)
         return (host if samp is None else host + samp), samp is not None
@@ -2344,6 +2365,8 @@ class PagedDecodeEngine:
                 a.req.n_rounds += 1
         ph.set(kind="mixed", rows=len(rows), tokens=t, budget=T,
                waiting=len(waiting))
+        self._note_keys(ph, [int(c) for c in
+                             row_start[:row] + row_nvalid[:row]])
         host = (tokens, positions, row_tables, row_start, row_nvalid,
                 row_token_idx, tok_row, tok_col, sb, so, logit_idx) \
             + self._row_extras([act.seq_id for act, _r, _f in rows], ph)

@@ -9,25 +9,38 @@ Two tiers with one contract:
   length, the logits are bit-identical to the dense batch-1 decode — the
   token-identity guarantee tests/test_kvcache.py pins.
 - a Pallas TPU kernel (Ragged-Paged-Attention shape, arxiv 2604.15464):
-  the block table rides in scalar-prefetch SMEM so each grid step DMAs
-  one physical KV block straight into VMEM — the (B, L, H, D) gathered
-  copy the reference path materializes in HBM never exists.  Online
-  softmax is carried in VMEM scratch across the (sequential, innermost)
-  block dimension, same (m, l, acc) recurrence as ops/attention_pallas.py.
+  the block table rides in scalar-prefetch SMEM and each grid step
+  gathers one SPAN of physical KV blocks straight into VMEM - the
+  (B, L, H, D) gathered copy the reference path materializes in HBM never
+  exists.  Online softmax is carried in VMEM scratch across the
+  (sequential, innermost) span dimension, same (m, l, acc) recurrence as
+  ops/attention_pallas.py.
+
+The span grid (PR 28): the allocator's block is 16 tokens, and a grid
+step that attended one block filled 16 of the 128 lanes of every register
+its scores, mask, ``exp`` and row sums occupy, and 16 of the MXU's
+columns.  A step attends ``K = 128 // block_size`` blocks (a lane tile of
+keys; :func:`span_blocks`), so the grid is ``(B, ceil(NB / K))`` and a
+step over 128 keys issues the operations a step over 16 did.  The pools
+stay whole in HBM (one operand each: the fused append aliases them, and
+XLA copies a pool that is also passed as further operands) and the kernels
+gather a span themselves through the table, two buffers deep
+(:func:`_next_span`).  Where a block's lanes are no whole tiles Mosaic
+cannot slice the pool for a copy: K = 1, the block comes through its block
+spec.
 
 Round-8 raggedness (the fused mixed decode/prefill step):
 
-- every row carries ``C >= 1`` query tokens at CONSECUTIVE positions —
+- every row carries ``C >= 1`` query tokens at CONSECUTIVE positions -
   decode rows use C=1, prefill-chunk rows up to the chunk width.  Query
   column ``c`` of row ``b`` attends to ``start_pos[b] + c + 1`` tokens
   (its own position included), clamped at the row's true context
   ``start_pos[b] + n_valid[b]`` for padding columns past ``n_valid``.
-- the grid is length-aware: blocks past a row's context are neither
-  DMA'd (the scalar-prefetched index map clamps to the row's last valid
-  block, and Pallas elides the copy when the block index repeats) nor
-  computed (``@pl.when`` guards), and the output is written at the
-  row's LAST VALID block instead of the grid edge — a 1-block row in a
-  64-block table costs one block of work, not 64.
+- the grid is length-aware: spans past a row's context are neither
+  copied (no copy is started for a dead grid step) nor computed
+  (``@pl.when`` guards), and the output is written at the row's LAST
+  VALID span instead of the grid edge - a 1-block row in a 64-block
+  table costs one step of work, not eight.
 
 Contract: every row must attend to AT LEAST one token
 (``context_lens >= C`` in the consecutive form, ``start_pos >= 0`` and
@@ -70,6 +83,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e9
+_LANES = 128  # keys one grid step attends: a lane tile of scores
 
 
 def _query_context(C: int, context_lens, start_pos, n_valid):
@@ -207,24 +221,25 @@ def _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, *, C: int, G: int,
             qm_ref.dtype)
 
 
-def _attend_block(j, c0, ctx, kb, vb, qm_ref, m_ref, l_ref, acc_ref, *,
-                  block_size: int, scale: float, rep: int, C: int, G: int,
-                  hd: int):
-    """One visible K/V block's online-softmax update, shared by both
-    kernels.  kb/vb: (BS, H*hd) VALUES — the pool's block as it lies in
-    HBM.  Per group of G heads: scores (G*C, BS) of the group's stacked
-    query rows against the group's lanes of K, each row's own softmax
+def _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref, acc_ref,
+                 *, scale: float, rep: int, C: int, G: int, hd: int):
+    """One visible span's online-softmax update, shared by both kernels:
+    the ``span`` keys (a lane tile's worth: K blocks of the pool) that grid
+    step ``j`` attends, so that scores, mask, ``exp`` and row sums fill the
+    lanes of their registers and ``p @ v`` contracts over a whole tile.
+    ``kbuf[slot]`` / ``vbuf[slot]``: the span's (span, H*hd) K / V - the
+    pool's blocks as they lie in HBM, one under the other.
+    Per group of G heads: scores (G*C, span) of the group's stacked query
+    rows against the group's lanes of K, each row's own softmax
     recurrence (m, l in f32), and ``p @ v`` over the group's lanes of V
-    into acc (G*C, G*hd) f32 — of which row ``i*C + c`` is read only in
+    into acc (G*C, G*hd) f32 - of which row ``i*C + c`` is read only in
     head i's lanes (:func:`_write_out`).  ``rep`` > 1 (grouped queries):
     the ``rep`` query heads of a K/V head ride as ``rep`` neighbouring
     columns of it, so of the C columns here column ``c`` is query column
     ``c // rep``."""
-    R, W = G * C, G * hd
-    rows_i = jax.lax.broadcasted_iota(jnp.int32, (R, block_size), 0)
-    k_pos = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (R, block_size), 1
-    )
+    R, W, span = G * C, G * hd, kbuf.shape[1]
+    rows_i = jax.lax.broadcasted_iota(jnp.int32, (R, span), 0)
+    k_pos = j * span + jax.lax.broadcasted_iota(jnp.int32, (R, span), 1)
     # column c attends to min(c0 + c, ctx) tokens; row i*C + c is column c
     if C == rep:  # one query column a row (decode)
         col = 0
@@ -236,10 +251,10 @@ def _attend_block(j, c0, ctx, kb, vb, qm_ref, m_ref, l_ref, acc_ref, *,
         rows = slice(g * R, (g + 1) * R)
         lanes = slice(g * W, (g + 1) * W)
         s = jax.lax.dot_general(
-            qm_ref[rows], kb[:, lanes],
+            qm_ref[rows], kbuf[slot, :, lanes],
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale  # (R, BS)
+        ) * scale  # (R, span)
         s = jnp.where(valid, s, _NEG)
         m_prev = m_ref[rows, :1]  # (R, 1)
         m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -251,8 +266,9 @@ def _attend_block(j, c0, ctx, kb, vb, qm_ref, m_ref, l_ref, acc_ref, *,
             l_ref[rows, :1] * corr + jnp.sum(p, axis=1, keepdims=True),
             (R, l_ref.shape[1]),
         )
+        vb = vbuf[slot, :, lanes]
         acc_ref[rows] = acc_ref[rows] * corr + jax.lax.dot_general(
-            p.astype(vb.dtype), vb[:, lanes],
+            p.astype(vb.dtype), vb,
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -274,17 +290,87 @@ def _write_out(o_ref, l_ref, acc_ref, *, C: int, G: int, hd: int):
         o_ref[:, g * W:(g + 1) * W] = o.astype(o_ref.dtype)
 
 
-def _paged_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
-                  qm_ref, m_ref, l_ref, acc_ref, *, block_size: int,
-                  scale: float, rep: int, **geom):
-    """Grid: (B, NB) — blocks innermost, so (m, l, acc) scratch carries the
-    online softmax across one sequence's blocks.  Blocks: q and o
-    (C, H*hd); k/v (block_size, H*hd) — the physical block of layer
-    ``li`` that the scalar-prefetched table maps grid step j to (``li_ref``
-    is only read by the index maps).  Blocks past the row's
-    context (``j > jlast``) are dead: the index map pins their DMA to the
-    last valid block (Pallas elides the repeated copy) and every
-    ``@pl.when`` below is false, so they cost nothing."""
+def _span_copies(pools, bufs, sem, slot, block_of, *, K: int,
+                 block_size: int):
+    """The 2K async copies that fill buffer ``slot`` with one span: block
+    ``block_of(i)`` = (layer, physical block) of each pool into rows
+    ``[i*BS, (i+1)*BS)`` of its buffer, all on the slot's one semaphore."""
+    return [
+        pltpu.make_async_copy(
+            pool.at[block_of(i)],
+            buf.at[slot, pl.ds(i * block_size, block_size)], sem.at[slot])
+        for i in range(K) for pool, buf in zip(pools, bufs)
+    ]
+
+
+def _next_span(b, j, jlast, li_ref, bt_ref, cl_ref, pools, bufs, sem, n_ref,
+               *, K: int, block_size: int):
+    """The pool's blocks of this live grid step (b, j <= jlast, the row's
+    last live span), in VMEM: returns the buffer slot that holds span j
+    of row b.  The pools stay in HBM
+    (one operand each, so the fused append can alias them) and the kernel
+    gathers a span's K blocks itself through the scalar-prefetched block
+    table, two buffers deep: the copies of the NEXT live span - span j+1
+    of this row, or span 0 of the next row - start before this one's are
+    waited for, so they run under this step's math; dead grid steps start
+    and wait for nothing.  ``n_ref`` (SMEM) counts the live steps so far:
+    its parity is the slot.  Blocks past the row's last one repeat it:
+    every span is 2K copies, started and waited for alike, and no row of a
+    buffer is left unwritten (it could hold NaNs that 0 * v keeps); their
+    keys are masked by the context length, as a block's own dead keys are.
+
+    K == 1 (:func:`span_blocks`: lanes that are no whole tiles, which the
+    kernel's copies cannot slice, or a block that fills the lanes alone):
+    ``pools`` are the step's one block of each pool, brought by its block
+    spec as Pallas pipelines it; it goes into slot 0, so that what follows
+    reads one place."""
+    if K == 1:
+        for pool, buf in zip(pools, bufs):
+            buf[0] = pool[:]
+        return 0
+    B = cl_ref.shape[0]
+    li = li_ref[0]
+
+    def copies(slot, row, span):
+        last = (cl_ref[row] - 1) // block_size
+        return _span_copies(
+            pools, bufs, sem, slot,
+            lambda i: (li, bt_ref[row, jnp.minimum(span * K + i, last)]),
+            K=K, block_size=block_size)
+
+    @pl.when((b == 0) & (j == 0))
+    def _first():
+        n_ref[0] = 0
+        for c in copies(0, 0, 0):
+            c.start()
+
+    n = n_ref[0]
+    slot = n % 2
+    more = j < jlast
+
+    @pl.when(more | (b + 1 < B))
+    def _prefetch():
+        row = jnp.where(more, b, jnp.minimum(b + 1, B - 1))
+        for c in copies(1 - slot, row, jnp.where(more, j + 1, 0)):
+            c.start()
+
+    for c in _span_copies(pools, bufs, sem, slot, lambda i: (0, 0), K=K,
+                          block_size=block_size):
+        c.wait()  # only the semaphore and the size matter to a wait
+    n_ref[0] = n + 1
+    return slot
+
+
+def _paged_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, k_in, v_in, o_ref,
+                  kbuf, vbuf, sem, n_ref, qm_ref, m_ref, l_ref, acc_ref, *,
+                  K: int, block_size: int, scale: float, rep: int, **geom):
+    """Grid: (B, NS) - spans innermost, so (m, l, acc) scratch carries the
+    online softmax across one sequence's spans.  Blocks: q and o
+    (C, H*hd); the pools whole, in HBM, of which :func:`_next_span` brings
+    grid step j's K blocks of layer ``li`` into ``kbuf`` / ``vbuf``
+    ((2, K*block_size, H*hd)).  Spans past the row's context
+    (``j > jlast``) are dead: every ``@pl.when`` below is false and no
+    copy is started, so they cost an empty grid step."""
     b = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -294,15 +380,17 @@ def _paged_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
 
     c0 = c0_ref[b]       # column 0's context length
     ctx = cl_ref[b]      # the row's full context (last valid column's)
-    jlast = (ctx - 1) // block_size  # last block holding attended tokens
+    jlast = (ctx - 1) // (K * block_size)  # last span with attended tokens
 
-    @pl.when(j <= jlast)  # skip blocks wholly past the context
+    @pl.when(j <= jlast)  # skip spans wholly past the context
     def _visible():
-        _attend_block(j, c0, ctx, k_ref[:], v_ref[:], qm_ref, m_ref, l_ref,
-                      acc_ref, block_size=block_size, scale=scale, rep=rep,
-                      **geom)
+        slot = _next_span(b, j, jlast, li_ref, bt_ref, cl_ref, (k_in, v_in),
+                          (kbuf, vbuf), sem, n_ref, K=K,
+                          block_size=block_size)
+        _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref,
+                     acc_ref, scale=scale, rep=rep, **geom)
 
-    # write at the row's LAST VALID block, not the grid edge: later grid
+    # write at the row's LAST VALID span, not the grid edge: later grid
     # steps touch nothing, and the (per-row) output block flushes when
     # the grid leaves row b
     @pl.when(j == jlast)
@@ -310,8 +398,13 @@ def _paged_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
         _write_out(o_ref, l_ref, acc_ref, **geom)
 
 
-def _softmax_scratch(H: int, C: int, hd: int, G: int, dtype):
+def _scratch(K: int, BS: int, D: int, pool_dtype, H: int, C: int, hd: int,
+             G: int, dtype):
     return [
+        pltpu.VMEM((2, K * BS, D), pool_dtype),    # kbuf: this span, next
+        pltpu.VMEM((2, K * BS, D), pool_dtype),    # vbuf
+        pltpu.SemaphoreType.DMA((2,)),             # one a buffer slot
+        pltpu.SMEM((1,), jnp.int32),               # live steps so far
         pltpu.VMEM((H * C, G * hd), dtype),        # qm: grouped queries
         pltpu.VMEM((H * C, 128), jnp.float32),     # m
         pltpu.VMEM((H * C, 128), jnp.float32),     # l
@@ -342,43 +435,58 @@ def _unfold_queries(o, q_shape, rep: int):
     return o.reshape(B, C, H, hd)
 
 
+def span_blocks(block_size: int, table_blocks: int, lanes: int) -> int:
+    """K: how many blocks of the pool one grid step of the kernels attends
+    - as many as fill the 128 key lanes of the score registers (eight
+    blocks of 16), never more than a row's table holds; one where a
+    block's ``lanes`` (heads * head_dim) are no whole tiles (five heads of
+    64 a shard): Mosaic refuses to slice such a pool for a copy."""
+    if lanes % _LANES:
+        return 1
+    return max(1, min(_LANES // block_size, table_blocks))
+
+
+def _pool_spec(K: int, BS: int, D: int):
+    """How a pool reaches the kernels: whole, in HBM, for the kernel's own
+    gather of K blocks a step; at K == 1 a block a step (clamped to the
+    row's last block, so that a dead step's copy is elided)."""
+    if K > 1:
+        return pl.BlockSpec(memory_space=pl.ANY)
+    return pl.BlockSpec(
+        (None, None, BS, D),
+        lambda b, j, li, bt, c0, cl, *_: (
+            li[0], bt[b, jnp.minimum(j, (cl[b] - 1) // BS)], 0, 0))
+
+
 def _paged_ragged_fn(q, k_pool, v_pool, layer, block_tables, c0, cl, *,
                      d_true: int, interpret: bool = False):
-    """q: (B, C, H, hd); pools (L, num_blocks, BS, Hkv*hd) — ALL layers'
-    stacked pool, read in place at ``layer`` ((1,) int32): the block index
-    maps carry the layer, so no layer is ever sliced out of the pool.  A
+    """q: (B, C, H, hd); pools (L, num_blocks, BS, Hkv*hd) - ALL layers'
+    stacked pool, read in place at ``layer`` ((1,) int32): the kernel's own
+    copies carry the layer, so no layer is ever sliced out of the pool.  A
     block is (BS, H*hd): a whole number of the chip's tiles when H*hd is
     a multiple of 128, so nothing is lane-padded and the pool's layout in
-    HBM is the one the kernel reads; c0/cl: (B,) per-row column-0 /
-    last-column context lengths."""
+    HBM is the one the kernel reads; a grid step attends K of them
+    (:func:`span_blocks`); c0/cl: (B,) per-row column-0 / last-column
+    context lengths."""
     B, hd = q.shape[0], q.shape[3]
     BS, D = k_pool.shape[2:]
     NB = block_tables.shape[1]
+    K = span_blocks(BS, NB, D)
     qf, rep = _fold_queries(q, D)
     C, H = qf.shape[1], D // hd
     G = _heads_per_group(H, hd, C)
     kernel = functools.partial(
-        _paged_kernel, block_size=BS, scale=1.0 / np.sqrt(d_true),
+        _paged_kernel, K=K, block_size=BS, scale=1.0 / np.sqrt(d_true),
         rep=rep, C=C, G=G, hd=hd,
     )
-
-    def _kv_map(b, j, li, bt, c0, cl):
-        # ragged grid: clamp dead steps to the row's last valid block so
-        # their DMA is elided (same index as the previous step)
-        return (li[0], bt[b, jnp.minimum(j, (cl[b] - 1) // BS)], 0, 0)
-
+    row = pl.BlockSpec((None, C, D), lambda b, j, *_: (b, 0, 0))
+    pool = _pool_spec(K, BS, D)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,  # layer, block_tables, c0, cl
-        grid=(B, NB),
-        in_specs=[
-            pl.BlockSpec((None, C, D),
-                         lambda b, j, li, bt, c0, cl: (b, 0, 0)),
-            pl.BlockSpec((None, None, BS, D), _kv_map),
-            pl.BlockSpec((None, None, BS, D), _kv_map),
-        ],
-        out_specs=pl.BlockSpec((None, C, D),
-                               lambda b, j, li, bt, c0, cl: (b, 0, 0)),
-        scratch_shapes=_softmax_scratch(H, C, hd, G, q.dtype),
+        grid=(B, -(-NB // K)),
+        in_specs=[row, pool, pool],
+        out_specs=row,
+        scratch_shapes=_scratch(K, BS, D, k_pool.dtype, H, C, hd, G, q.dtype),
     )
     out = pl.pallas_call(
         kernel,
@@ -406,17 +514,19 @@ _paged_ragged = _make_paged_ragged()
 
 
 def _append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, q_ref, k1_ref,
-                   v1_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref, qm_ref, m_ref,
-                   l_ref, acc_ref, *, block_size: int, scale: float, rep: int,
-                   **geom):
+                   v1_ref, k_in, v_in, o_ref, ko_ref, vo_ref, kbuf, vbuf,
+                   sem, n_ref, qm_ref, m_ref, l_ref, acc_ref, *, K: int,
+                   block_size: int, scale: float, rep: int, **geom):
     """Round-17 fused append+attend (decode, C=1): the incoming token's
     K/V rides into the kernel as a (1, H*hd) operand, is patched into the
-    tail block IN REGISTER for the attention math, and is flushed back
-    to the pool through the aliased pool outputs — the standalone
-    scatter program the unfused path runs before attention disappears.
-    Pool out-blocks map every grid step of row ``b`` to the row's slot
-    block, so exactly ONE block per pool per row is written (at
-    ``j == jlast``), the same write set as the scatter.  Same grid /
+    tail block IN VMEM for the attention math (the HBM copy the kernel
+    gathered predates the append), and is flushed back to the pool
+    through the aliased pool outputs - the standalone scatter program the
+    unfused path runs before attention disappears.  The tail block is
+    block ``(ctx-1) // block_size % K`` of the row's last span.  Pool
+    out-blocks map every grid step of row ``b`` to the row's slot block,
+    so exactly ONE block per pool per row is written (at ``j == jlast``),
+    the same write set as the scatter.  Same grid / gather /
     online-softmax recurrence as :func:`_paged_kernel`."""
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -427,30 +537,34 @@ def _append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, q_ref, k1_ref,
 
     c0 = c0_ref[b]
     ctx = cl_ref[b]
-    so = so_ref[b]
-    jlast = (ctx - 1) // block_size  # the append lands in this block
-
-    def _patched(raw_ref, new_ref, last):
-        # tail block with the new token's row substituted (the HBM copy
-        # the input DMA'd predates the append)
-        sel = (jax.lax.broadcasted_iota(jnp.int32, (block_size, 1), 0)
-               == so) & last
-        return jnp.where(sel, new_ref[:], raw_ref[:])
+    jlast = (ctx - 1) // (K * block_size)  # the append lands in this span
 
     @pl.when(j <= jlast)
     def _visible():
-        last = j == jlast
-        _attend_block(j, c0, ctx, _patched(k_ref, k1_ref, last),
-                      _patched(v_ref, v1_ref, last), qm_ref, m_ref, l_ref,
-                      acc_ref, block_size=block_size, scale=scale, rep=rep,
-                      **geom)
+        slot = _next_span(b, j, jlast, li_ref, bt_ref, cl_ref, (k_in, v_in),
+                          (kbuf, vbuf), sem, n_ref, K=K,
+                          block_size=block_size)
+
+        @pl.when(j == jlast)
+        def _append():
+            # the append itself: full tail block (input content + new
+            # row) through the aliased pool output - flushed once per row
+            tail = pl.ds(pl.multiple_of(
+                (ctx - 1) // block_size % K * block_size, block_size),
+                block_size)
+            new_row = jax.lax.broadcasted_iota(
+                jnp.int32, (block_size, 1), 0) == so_ref[b]
+            for buf, new_ref, out_ref in ((kbuf, k1_ref, ko_ref),
+                                          (vbuf, v1_ref, vo_ref)):
+                blk = jnp.where(new_row, new_ref[:], buf[slot, tail, :])
+                buf[slot, tail, :] = blk
+                out_ref[:] = blk.astype(out_ref.dtype)
+
+        _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref,
+                     acc_ref, scale=scale, rep=rep, **geom)
 
     @pl.when(j == jlast)
     def _final():
-        # the append itself: full tail block (input content + new row)
-        # through the aliased pool output — flushed once per row
-        ko_ref[:] = _patched(k_ref, k1_ref, True).astype(ko_ref.dtype)
-        vo_ref[:] = _patched(v_ref, v1_ref, True).astype(vo_ref.dtype)
         _write_out(o_ref, l_ref, acc_ref, **geom)
 
 
@@ -458,50 +572,43 @@ def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, layer, block_tables,
                      c0, cl, slot_offsets, *, d_true: int,
                      interpret: bool = False):
     """q: (B, 1, H, hd); k_new/v_new: (B, Hkv, hd); pools
-    (L, num_blocks, BS, Hkv*hd) — ALL layers' stacked pool, returned
+    (L, num_blocks, BS, Hkv*hd) - ALL layers' stacked pool, returned
     UPDATED at ``layer`` ((1,) int32), aliased in place on TPU: one tail
     block per row is written, nothing else of the pool is touched or
     copied.  Contract: the slot is the tail of the attended context
     (``slot_blocks[b] == block_tables[b, (cl[b]-1)//BS]`` and
-    ``slot_offsets[b] == (cl[b]-1) % BS``) — the decode append the
+    ``slot_offsets[b] == (cl[b]-1) % BS``) - the decode append the
     engine constructs by definition."""
     B, hd = q.shape[0], q.shape[3]
     BS, D = k_pool.shape[2:]
     NB = block_tables.shape[1]
+    K = span_blocks(BS, NB, D)
     qf, rep = _fold_queries(q, D)
     C, H = qf.shape[1], D // hd
     G = _heads_per_group(H, hd, C)
     kernel = functools.partial(
-        _append_kernel, block_size=BS, scale=1.0 / np.sqrt(d_true),
+        _append_kernel, K=K, block_size=BS, scale=1.0 / np.sqrt(d_true),
         rep=rep, C=C, G=G, hd=hd,
     )
 
-    def _kv_map(b, j, li, bt, c0, cl, so):
-        return (li[0], bt[b, jnp.minimum(j, (cl[b] - 1) // BS)], 0, 0)
+    def _row(rows):
+        return pl.BlockSpec((None, rows, D), lambda b, j, *_: (b, 0, 0))
 
     def _slot_map(b, j, li, bt, c0, cl, so):
         # constant per row: the pool out-block IS the row's slot block
         return (li[0], bt[b, (cl[b] - 1) // BS], 0, 0)
 
-    def _row(*tail):
-        return lambda b, j, li, bt, c0, cl, so: (b,) + tail
-
+    pool = _pool_spec(K, BS, D)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,  # layer, block_tables, c0, cl, slot_offsets
-        grid=(B, NB),
-        in_specs=[
-            pl.BlockSpec((None, C, D), _row(0, 0)),
-            pl.BlockSpec((None, 1, D), _row(0, 0)),
-            pl.BlockSpec((None, 1, D), _row(0, 0)),
-            pl.BlockSpec((None, None, BS, D), _kv_map),
-            pl.BlockSpec((None, None, BS, D), _kv_map),
-        ],
+        grid=(B, -(-NB // K)),
+        in_specs=[_row(C), _row(1), _row(1), pool, pool],
         out_specs=[
-            pl.BlockSpec((None, C, D), _row(0, 0)),
+            _row(C),
             pl.BlockSpec((None, None, BS, D), _slot_map),
             pl.BlockSpec((None, None, BS, D), _slot_map),
         ],
-        scratch_shapes=_softmax_scratch(H, C, hd, G, q.dtype),
+        scratch_shapes=_scratch(K, BS, D, k_pool.dtype, H, C, hd, G, q.dtype),
     )
     # alias indices count the scalar-prefetch operands: pools are operands
     # 8/9 of (layer, bt, c0, cl, so, q, k_new, v_new, k_pool, v_pool)

@@ -159,6 +159,12 @@ class KVCacheStats:
       ``pathway_kv_mixed_tokens_budget_total{pool}`` counters (packed
       tokens the mixed dispatches carried over the ``mixed_tokens`` they
       had room for; used/budget = how full the ragged step runs)
+    - ``pathway_kv_attended_keys_total{pool}`` /
+      ``pathway_kv_attended_key_lanes_total{pool}`` counters (keys the
+      paged kernels' rows attended - their context lengths, a chain's
+      rows once a step - over the key lanes their live grid steps spanned,
+      every context rounded up to whole spans of 128; keys/lanes = how
+      full the kernels' score registers run)
     - ``pathway_kv_spec_proposed_total{pool}``  counter (Round-18: draft
       tokens proposed into verify dispatches)
     - ``pathway_kv_spec_accepted_total{pool}``  counter (draft tokens the
@@ -212,6 +218,8 @@ class KVCacheStats:
         self.round_s: dict[str, float] = {}
         self.mixed_tokens_used = 0
         self.mixed_tokens_budget = 0
+        self.kv_keys = 0
+        self.kv_key_lanes = 0
         # Round-18 speculative decoding: proposed/accepted/rejected draft
         # tokens, total verify-emitted tokens and verify dispatches
         self.spec_proposed = 0
@@ -331,6 +339,13 @@ class KVCacheStats:
             self.mixed_tokens_used += used
             self.mixed_tokens_budget += budget
 
+    def record_attended_keys(self, keys: int, lanes: int) -> None:
+        """Keys one round's paged-kernel rows attended, and the key lanes
+        their live grid steps spanned."""
+        with self._lock:
+            self.kv_keys += keys
+            self.kv_key_lanes += lanes
+
     def record_engine_restart(self, rebuild_seconds: float) -> None:
         """One supervised engine restart (pool rebuild time only; the
         failure -> first-recovered-token window lands separately via
@@ -429,6 +444,8 @@ class KVCacheStats:
                 "round_s": dict(self.round_s),
                 "mixed_tokens_used": self.mixed_tokens_used,
                 "mixed_tokens_budget": self.mixed_tokens_budget,
+                "kv_keys": self.kv_keys,
+                "kv_key_lanes": self.kv_key_lanes,
                 "spec_proposed": self.spec_proposed,
                 "spec_accepted": self.spec_accepted,
                 "spec_rejected": self.spec_rejected,
@@ -984,6 +1001,8 @@ def _render_kv_lines() -> list[str]:
         "# TYPE pathway_kv_round_seconds_total counter",
         "# TYPE pathway_kv_mixed_tokens_used_total counter",
         "# TYPE pathway_kv_mixed_tokens_budget_total counter",
+        "# TYPE pathway_kv_attended_keys_total counter",
+        "# TYPE pathway_kv_attended_key_lanes_total counter",
         "# TYPE pathway_kv_spec_proposed_total counter",
         "# TYPE pathway_kv_spec_accepted_total counter",
         "# TYPE pathway_kv_spec_rejected_total counter",
@@ -1103,6 +1122,13 @@ def _render_kv_lines() -> list[str]:
         lines.append(
             f"pathway_kv_mixed_tokens_budget_total{{{lbl}}} "
             f"{snap['mixed_tokens_budget']}"
+        )
+        lines.append(
+            f"pathway_kv_attended_keys_total{{{lbl}}} {snap['kv_keys']}"
+        )
+        lines.append(
+            f"pathway_kv_attended_key_lanes_total{{{lbl}}} "
+            f"{snap['kv_key_lanes']}"
         )
         # Round-18 speculative decoding: draft proposal/acceptance flow
         lines.append(

@@ -132,6 +132,36 @@ def test_paged_append_kernel_compiles_at_gpt2_large(shape, heads):
           f"{len(_pool_copies(compiled, pool.shape))}")
 
 
+def test_paged_kernels_compile_at_lfm2_spans(shape):
+    """Both paged kernels at LFM2-8B-A1B's geometry (32 query heads over 8
+    K/V heads of 64, tables of 128 blocks, three attention layers): a grid
+    step attends a span of eight blocks that the kernel gathers itself
+    from the pool in HBM, the query heads of a K/V head folded into the
+    query rows (128 a chunk of 32, 4 a decode row)."""
+    import jax.numpy as jnp
+
+    pa = _paged()
+    kv, rep, tables, blocks, i32 = 8, 4, 128, 2049, jnp.int32
+    assert pa.span_blocks(BS, tables, kv * HD) == 128 // BS
+    pool = shape((3, blocks, BS, kv * HD), jnp.bfloat16)
+    idx = (shape((1,), i32), shape((B, tables), i32), shape((B,), i32),
+           shape((B,), i32))
+    ragged = _compiled_kernel(
+        lambda q, k, v, li, bt, c0, cl: pa._paged_ragged_fn(
+            q, k, v, li, bt, c0, cl, d_true=HD),
+        shape((B, CHUNK, kv * rep, HD), jnp.bfloat16), pool, pool, *idx,
+    )
+    new = shape((B, kv, HD), jnp.bfloat16)
+    append = _compiled_kernel(
+        lambda q, k1, v1, k, v, li, bt, c0, cl, so: pa._paged_append_fn(
+            q, k1, v1, k, v, li, bt, c0, cl, so, d_true=HD),
+        shape((B, 1, kv * rep, HD), jnp.bfloat16), new, new, pool, pool,
+        *idx, idx[-1], donate_argnums=(3, 4),
+    )
+    for compiled in (ragged, append):
+        assert _pool_copies(compiled, pool.shape) == []
+
+
 def _mixed_like(q, k1, v1, kp, vp, sb, so, bt, c0, cl):
     """What a mixed step does to the pool, over two layers: every packed
     token's row written in place, then the ragged kernel over the stacked

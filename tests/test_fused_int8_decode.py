@@ -361,10 +361,11 @@ def test_profile_diff_rows_and_cli(tmp_path):
 # -- fused append+attend op --------------------------------------------------
 
 
-def _append_case(seed=0, B=3, H=2, hd=128, NB=4, BS=4):
+def _append_case(seed=0, B=3, H=2, hd=128, NB=4, BS=4, cl=None):
     """A decode-step-shaped case: slot at the context tail; pools in
     BlockPool's per-layer shape, heads fused on the minor axis."""
     rng = np.random.default_rng(seed)
+    B = B if cl is None else len(cl)
     nb_total = 1 + B * NB  # block 0 is the null block
     q = rng.normal(size=(B, 1, H, hd)).astype(np.float32)
     k_new = rng.normal(size=(B, H, hd)).astype(np.float32)
@@ -372,7 +373,7 @@ def _append_case(seed=0, B=3, H=2, hd=128, NB=4, BS=4):
     k_pool = rng.normal(size=(nb_total, BS, H * hd)).astype(np.float32)
     v_pool = rng.normal(size=(nb_total, BS, H * hd)).astype(np.float32)
     bt = np.zeros((B, NB), np.int32)
-    cl = np.array([3, BS + 1, 2 * BS], np.int32)[:B]
+    cl = np.array([3, BS + 1, 2 * BS] if cl is None else cl, np.int32)[:B]
     for b in range(B):
         used = -(-int(cl[b]) // BS)
         bt[b, :used] = 1 + b * NB + np.arange(used)
@@ -399,17 +400,26 @@ def test_paged_append_attend_reference_bit_identity():
     assert (np.asarray(vo) == np.asarray(vp2)).all()
 
 
-@pytest.mark.parametrize("H,hd", [(2, 128), (20, 64)],
-                         ids=["toy", "gpt2_large_heads"])
-def test_paged_append_attend_kernel_interpret(H, hd):
+# "spans*": blocks of 16 in tables of 20, so a grid step attends a span of
+# 128 keys (eight blocks) and the table is 2.5 spans: the appended token is
+# a row's first, a block's last, inside a span, a span's last key, the
+# first key of the next span, and the table's last.
+@pytest.mark.parametrize("H,hd,geometry", [
+    (2, 128, {}),
+    (20, 64, {}),
+    (2, 128, dict(NB=20, BS=16, cl=[1, 16, 200, 128, 129, 320])),
+    (20, 64, dict(NB=20, BS=16, cl=[16, 320, 129])),
+], ids=["toy", "gpt2_large_heads", "spans", "spans_gpt2_large_heads"])
+def test_paged_append_attend_kernel_interpret(H, hd, geometry):
     """The Pallas kernel (interpret mode on CPU) matches the reference
     to fp tolerance, with the new token's K/V landed in the slot block
-    through the in-place pool alias."""
+    through the in-place pool alias: the pool differs from its input in
+    exactly one block a row, the tail."""
     from pathway_tpu.kvcache.paged_attention import (
         paged_append_attend, paged_attention_reference,
     )
 
-    q, k1, v1, kp, vp, bt, cl, sb, so = _append_case(H=H, hd=hd)
+    q, k1, v1, kp, vp, bt, cl, sb, so = _append_case(H=H, hd=hd, **geometry)
     kp_np, vp_np = np.asarray(kp), np.asarray(vp)
     k1, v1 = k1.reshape(k1.shape[0], -1), v1.reshape(v1.shape[0], -1)
     want = paged_attention_reference(
@@ -424,10 +434,12 @@ def test_paged_append_attend_kernel_interpret(H, hd):
     ko_np, vo_np = np.asarray(ko), np.asarray(vo)
     np.testing.assert_array_equal(ko_np[sb_np, so_np], np.asarray(k1))
     np.testing.assert_array_equal(vo_np[sb_np, so_np], np.asarray(v1))
-    # untouched blocks pass through unchanged
-    mask = np.ones(kp_np.shape[0], bool)
-    mask[sb_np] = False
-    np.testing.assert_array_equal(ko_np[mask], kp_np[mask])
+    # untouched blocks pass through unchanged, and so do the tail blocks'
+    # other rows
+    for new, old in ((ko_np, kp_np), (vo_np, vp_np)):
+        changed = np.argwhere(new != old)
+        assert sorted(set(map(tuple, changed[:, :2]))) \
+            == sorted(zip(sb_np.tolist(), so_np.tolist()))
 
 
 # -- generate(fused="auto") reads the measured tier prior --------------------

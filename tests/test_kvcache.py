@@ -501,9 +501,36 @@ def test_single_oversized_request_fails_cleanly(params):
     assert eng.pool.blocks_in_use == 0
 
 
-@pytest.mark.parametrize("H,hd", [(2, 16), (20, 64)],
-                         ids=["toy", "gpt2_large_heads"])
-def test_pallas_kernel_matches_reference_interpreted(H, hd):
+def _decode_case(H, hd, BS, NB, lens):
+    """Decode rows (C=1) over a pool in BlockPool's shape, heads fused on
+    the minor axis: every row its own blocks, the null block behind."""
+    rng = np.random.default_rng(5)
+    B = len(lens)
+    q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), jnp.float32)
+    pool = (1 + B * NB, BS, H * hd)
+    k_pool = jnp.asarray(rng.standard_normal(pool), jnp.float32)
+    v_pool = jnp.asarray(rng.standard_normal(pool), jnp.float32)
+    tables = np.zeros((B, NB), np.int32)
+    for b, n in enumerate(lens):
+        used = -(-n // BS)
+        tables[b, :used] = 1 + b * NB + rng.permutation(NB)[:used]
+    return q, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(lens, jnp.int32)
+
+
+# A grid step of the kernels attends a span of 128 keys (eight blocks of
+# 16) where a block's lanes are whole tiles.  Tables of 20 blocks are 2.5
+# spans: contexts of one token, one block, ending inside a span, at a
+# span's edge, and at the table's end; a one-block row beside a full one.
+@pytest.mark.parametrize("H,hd,BS,NB,lens", [
+    (2, 16, 8, 3, [20, 9, 24]),
+    (20, 64, 8, 3, [20, 9, 24]),
+    (2, 64, 16, 20, [1, 16, 200, 128, 256, 320]),
+    (20, 64, 16, 20, [16, 320, 129]),
+    (2, 64, 16, 8, [128, 5]),
+    (2, 64, 16, 5, [80, 17, 64]),
+], ids=["toy", "gpt2_large_heads", "spans", "spans_gpt2_large_heads",
+        "one_span_table", "table_shorter_than_a_span"])
+def test_pallas_kernel_matches_reference_interpreted(H, hd, BS, NB, lens):
     """The TPU kernel path (interpret mode on CPU) must agree with the
     gather reference to f32 tolerance: decode rows (C=1) over a pool in
     BlockPool's shape, heads fused on the minor axis."""
@@ -511,15 +538,7 @@ def test_pallas_kernel_matches_reference_interpreted(H, hd):
         paged_attention, paged_attention_reference,
     )
 
-    rng = np.random.default_rng(5)
-    B, BS, NBLK = 3, 8, 12
-    q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), jnp.float32)
-    k_pool = jnp.asarray(rng.standard_normal((NBLK, BS, H * hd)), jnp.float32)
-    v_pool = jnp.asarray(rng.standard_normal((NBLK, BS, H * hd)), jnp.float32)
-    tables = jnp.asarray(
-        [[1, 2, 3], [4, 5, 0], [6, 7, 8]], jnp.int32
-    )
-    lens = jnp.asarray([20, 9, 24], jnp.int32)
+    q, k_pool, v_pool, tables, lens = _decode_case(H, hd, BS, NB, lens)
     want = paged_attention_reference(q, k_pool, v_pool, tables, lens)
     got = paged_attention(
         q, k_pool, v_pool, tables, lens, use_pallas=True, interpret=True

@@ -288,23 +288,37 @@ def test_grouped_matmul_kernel_matches_its_reference(case):
     assert (np.asarray(n_tok) == counts).all()
 
 
+# "spans": K/V heads whose lanes are a whole tile, so a grid step of the
+# kernels attends 128 keys (eight blocks of 16) of a table of 20 blocks (2.5
+# spans): contexts of one block, ending inside a span, at a span's edge (the
+# chunk's last column / the appended token is the span's last key) and at
+# the table's end.
+@pytest.mark.parametrize("KV,HD,BS,NB,last", [
+    (2, 16, 8, 4, [18, 10, 26]),
+    (2, 64, 16, 20, [16, 200, 128, 320, 257]),
+], ids=["toy", "spans"])
 @pytest.mark.parametrize("C", [1, 4], ids=["decode", "chunk"])
-def test_paged_kernels_group_query_heads(C):
+def test_paged_kernels_group_query_heads(C, KV, HD, BS, NB, last):
     """Four query heads over two K/V heads in both paged kernels (the
-    interpreted kernels against the gather reference)."""
+    interpreted kernels against the gather reference); the fused append
+    changes exactly one block a row, the tail."""
     import jax
     import jax.numpy as jnp
 
     pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
-    L, NBLK, BS, KV, REP, HD, B = 2, 12, 8, 2, 2, 16, 3
+    L, REP, B = 2, 2, len(last)
     ks = jax.random.split(jax.random.PRNGKey(1), 6)
-    kp = jax.random.normal(ks[0], (L, NBLK, BS, KV * HD))
-    vp = jax.random.normal(ks[1], (L, NBLK, BS, KV * HD))
-    bt = jnp.asarray([[1, 2, 3, 0], [4, 5, 0, 0], [6, 7, 8, 9]], jnp.int32)
+    kp = jax.random.normal(ks[0], (L, 1 + B * NB, BS, KV * HD))
+    vp = jax.random.normal(ks[1], (L, 1 + B * NB, BS, KV * HD))
+    bt = np.zeros((B, NB), np.int32)
+    for b, n in enumerate(last):  # the row's own blocks, the null behind
+        bt[b, :-(-n // BS)] = 1 + b * NB + np.arange(-(-n // BS))
+    bt = jnp.asarray(bt)
     q = jax.random.normal(ks[2], (B, C, KV * REP, HD))
-    last = jnp.asarray([18, 10, 26], jnp.int32)  # the last column's context
+    last = jnp.asarray(last, jnp.int32)  # the last column's context
     if C > 1:
-        start, nv = last - C, jnp.asarray([C, C - 1, C], jnp.int32)
+        start = last - C
+        nv = jnp.full((B,), C, jnp.int32).at[1].set(C - 1)
         want = pa.paged_attention_reference(q, kp[1], vp[1], bt,
                                             start_pos=start, n_valid=nv)
         got = pa.paged_attention(q, kp, vp, bt, start_pos=start, n_valid=nv,
@@ -317,11 +331,15 @@ def test_paged_kernels_group_query_heads(C):
     sb, so = bt[jnp.arange(B), (last - 1) // BS], (last - 1) % BS
     a0, k0, v0 = pa.paged_append_attend(q, k1, v1, kp, vp, bt, last, sb, so,
                                         layer=0, use_pallas=False)
+    before = np.asarray(kp), np.asarray(vp)  # the kernel's call donates them
     a1, k_, v_ = pa.paged_append_attend(q, k1, v1, kp, vp, bt, last, sb, so,
                                         layer=0, use_pallas=True,
                                         interpret=True)
     assert float(jnp.abs(a0 - a1).max()) < 1e-5
     assert bool((k0 == k_).all()) and bool((v0 == v_).all())
+    for new, old in zip((k_, v_), before):
+        changed = np.argwhere((np.asarray(new) != old).any(axis=(2, 3)))
+        assert changed.tolist() == [[0, int(b)] for b in sorted(sb)]
 
 
 # -- the engine: batching, preemption, restart, exhaustion --------------------

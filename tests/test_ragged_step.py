@@ -404,13 +404,22 @@ def test_zero_length_context_fails_loudly():
     assert np.isfinite(np.asarray(out)).all()
 
 
+# The last three: spans of 128 keys (eight blocks of 16) over tables of 20
+# blocks (2.5 spans) - chunks that end inside a span, exactly at a span's
+# edge and at the table's end, a one-token row beside them, rows whose
+# valid queries stop short of the chunk (``n_valid`` < C).
 @pytest.mark.parametrize(
-    "C,H,hd,sp,nv",
-    [(4, 2, 16, [17, 4, 0], [4, 2, 1]),
-     (32, 20, 64, [0, 5, 31], [32, 11, 1])],
-    ids=["toy", "gpt2_large_chunk"],
+    "C,H,hd,BS,NB,sp,nv",
+    [(4, 2, 16, 8, 4, [17, 4, 0], [4, 2, 1]),
+     (32, 20, 64, 8, 4, [0, 5, 31], [32, 11, 1]),
+     (4, 2, 64, 16, 20, [126, 0, 250, 316, 124], [4, 1, 3, 4, 4]),
+     (32, 2, 64, 16, 20, [96, 288, 0, 130], [32, 32, 7, 1]),
+     (32, 20, 64, 16, 20, [96, 300], [32, 11])],
+    ids=["toy", "gpt2_large_chunk", "spans", "spans_chunk",
+         "spans_gpt2_large_chunk"],
 )
-def test_ragged_kernel_matches_reference_interpreted(C, H, hd, sp, nv):
+def test_ragged_kernel_matches_reference_interpreted(C, H, hd, BS, NB, sp,
+                                                     nv):
     """The length-aware multi-query kernel (interpret mode on CPU) must
     agree with the gather reference on every VALID query column, over a
     pool in BlockPool's shape (heads fused on the minor axis)."""
@@ -419,15 +428,19 @@ def test_ragged_kernel_matches_reference_interpreted(C, H, hd, sp, nv):
     )
 
     rng = np.random.default_rng(5)
-    B, BS, NBLK = 3, 8, 12
+    B = len(sp)
     q = jnp.asarray(rng.standard_normal((B, C, H, hd)), jnp.float32)
-    k_pool = jnp.asarray(rng.standard_normal((NBLK, BS, H * hd)), jnp.float32)
-    v_pool = jnp.asarray(rng.standard_normal((NBLK, BS, H * hd)), jnp.float32)
-    tables = jnp.asarray(
-        [[1, 2, 3, 4], [5, 6, 0, 0], [7, 8, 9, 10]], jnp.int32
-    )
-    # ragged: a full chunk deep in its sequence (toy) or at its start
-    # (chunk width), a partial tail chunk, and a 1-token decode-style row
+    pool = (1 + B * NB, BS, H * hd)
+    k_pool = jnp.asarray(rng.standard_normal(pool), jnp.float32)
+    v_pool = jnp.asarray(rng.standard_normal(pool), jnp.float32)
+    # every row its own blocks as far as its context reaches, the null
+    # block behind; ragged: a full chunk deep in its sequence or at its
+    # start, a partial tail chunk, and a 1-token decode-style row
+    tables = np.zeros((B, NB), np.int32)
+    for b in range(B):
+        used = -(-(sp[b] + nv[b]) // BS)
+        tables[b, :used] = 1 + b * NB + rng.permutation(NB)[:used]
+    tables = jnp.asarray(tables)
     sp = jnp.asarray(sp, jnp.int32)
     nv = jnp.asarray(nv, jnp.int32)
     want = paged_attention_reference(

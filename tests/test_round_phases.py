@@ -172,6 +172,8 @@ def test_round_phases_tile_the_engine_thread(wide_params, path, kw, n_new):
         if s.name == "pw.round.build":
             assert {"rows", "tokens", "budget", "waiting"} <= set(s.attrs)
             assert s.attrs["tokens"] <= s.attrs["budget"]
+            if s.attrs["rows"]:  # the round calls a paged kernel
+                assert 0 < s.attrs["kv_keys"] <= s.attrs["kv_key_lanes"]
         elif s.name == "pw.round.sync":
             assert abs(s.attrs["perf_ns"] * 1e-9 - s.t0) < 1e-3
         elif s.name == "pw.round.h2d":
@@ -191,6 +193,38 @@ def test_round_phases_tile_the_engine_thread(wide_params, path, kw, n_new):
         == snap["mixed_tokens_budget"] - before["mixed_tokens_budget"]
     assert sum(s.attrs["tokens"] for s in mixed) \
         == snap["mixed_tokens_used"] - before["mixed_tokens_used"]
+
+
+@pytest.mark.parametrize("wide", [False, True], ids=["toy", "whole_tiles"])
+@pytest.mark.parametrize("chain_steps", [1, 4], ids=["step", "chain"])
+def test_build_counts_the_keys_its_rows_attend(params, wide_params,
+                                               chain_steps, wide):
+    """``kv_keys`` / ``kv_key_lanes`` on ``pw.round.build``: one request
+    alone, so every round has one live row and the sums are known - a
+    prompt of 21 tokens in chunks of 8 attends 8 + 16 + 21 keys, then
+    every decode step (alone or in a chain) the context it has reached.
+    The lanes round each context up to whole spans: a block of 4 where the
+    pool's lanes are no whole tiles (the toy's 64), 128 keys where they
+    are (eight heads of 64)."""
+    P, n_new = 21, 9
+    cfg, par = (_WIDE, wide_params) if wide else (_CFG, params)
+    eng = _engine(par, f"t_keys_{chain_steps}_{wide}", cfg=cfg,
+                  chain_steps=chain_steps)
+    span = 128 if wide else 4
+    assert eng._span_keys == span
+    eng.generate_batch([(p, n_new) for p in _prompts((P,))])
+    builds = [s for s in obs.recorder().snapshot()
+              if s.name == "pw.round.build" and s.attrs.get("rows")]
+    contexts = [8, 16, P] + [P + i for i in range(1, n_new)]
+    assert sum(s.attrs["kv_keys"] for s in builds) == sum(contexts)
+    assert sum(s.attrs["kv_key_lanes"] for s in builds) \
+        == sum(-(-c // span) * span for c in contexts)
+    assert {s.attrs["kind"] for s in builds} \
+        == {"mixed", "chain" if chain_steps > 1 else "step"}
+    snap = eng.pool.stats.snapshot()
+    assert snap["kv_keys"] == sum(contexts)
+    assert snap["kv_key_lanes"] == sum(s.attrs["kv_key_lanes"]
+                                       for s in builds)
 
 
 def test_round_counters_are_on_metrics(params):
@@ -213,6 +247,11 @@ def test_round_counters_are_on_metrics(params):
     assert f"pathway_kv_mixed_tokens_budget_total{{{lbl}}} " \
         f"{snap['mixed_steps'] * eng.mixed_tokens}" in lines
     assert 0 < snap["mixed_tokens_used"] <= snap["mixed_tokens_budget"]
+    assert f"pathway_kv_attended_keys_total{{{lbl}}} " \
+        f"{snap['kv_keys']}" in lines
+    assert f"pathway_kv_attended_key_lanes_total{{{lbl}}} " \
+        f"{snap['kv_key_lanes']}" in lines
+    assert 0 < snap["kv_keys"] <= snap["kv_key_lanes"]
     assert any(x.startswith(f"pathway_kv_host_gap_seconds_total{{{lbl}}}")
                for x in lines)
 
