@@ -527,126 +527,6 @@ def bench_fleet() -> dict:
     return out
 
 
-def bench_ssd() -> dict:
-    """Round-16 constant-memory decode rows (SOFT self-history gates):
-
-    - ``live_sessions_at_fixed_hbm_vs_paged``: the ``hbm_plan``-computed
-      capacity headline — at one fixed HBM budget, live sequences the
-      state backend holds (budget / state_bytes_per_seq) over what the
-      paged pool holds at the same per-session context.  The acceptance
-      floor (>= 4x at 128-token sessions) is pinned in
-      tests/test_statecache.py; the bench commits the measured ratio.
-    - ``decode_tokens_per_s``: greedy chained-decode throughput through
-      ``StateDecodeEngine`` (same harness shape as the paged rows).
-    - ``session_resume_ms_p99``: host-tier suspend/resume round-trip
-      across real conversation turns — measured at SHORT (~128-token)
-      and LONG (~2k-token) session contexts separately; the state is a
-      fixed-size buffer, so the two must agree within noise
-      (``session_resume_ctx_ratio`` records long/short).
-
-    Any section degrades to an error note instead of failing the
-    bench."""
-    out: dict = {}
-    try:
-        import jax as _jax
-        import numpy as _np
-
-        from pathway_tpu.kvcache.statecache import StateDecodeEngine
-        from pathway_tpu.kvcache.tiering import SessionStore
-        from pathway_tpu.models.decoder import (
-            DecoderConfig as _DC, init_decoder_params as _init,
-        )
-        from pathway_tpu.obs.memory import hbm_plan as _hbm_plan
-
-        cfg = _DC(vocab_size=256, d_model=64, n_layers=2, n_heads=4,
-                  d_ff=128, max_len=128)
-        params = _init(cfg, _jax.random.PRNGKey(0))
-        rng = _np.random.default_rng(16)
-        # ---- capacity headline: state vs paged at one HBM budget ------
-        budget = 64 * 1024 * 1024
-        session_tokens = 128
-        block_size = 4
-        paged_plan = _hbm_plan(
-            cfg, num_blocks=128, block_size=block_size, max_batch_size=8,
-            chain_steps=4, params=params, budget_bytes=budget,
-            reference_attn=False,
-        )
-        eng = StateDecodeEngine(
-            cfg, params, name="bench_ssd", max_slots=64, max_batch_size=8,
-            prefill_chunk=16, chain_steps=8,
-        )
-        sbps = int(eng.hbm_plan.state_bytes_per_seq)
-        state_plan = _hbm_plan(
-            cfg, num_blocks=eng.pool.max_slots, block_size=block_size,
-            max_batch_size=8, chain_steps=8, params=params,
-            budget_bytes=budget, reference_attn=False,
-            state_bytes_per_seq=sbps,
-        )
-        cache_budget = (budget - state_plan.params_bytes
-                        - state_plan.temp_bytes)
-        state_sessions = cache_budget // sbps
-        blocks_per_session = -(-session_tokens // block_size)
-        paged_blocks = (budget - paged_plan.params_bytes
-                        - paged_plan.temp_bytes) \
-            // max(paged_plan.per_block_bytes, 1)
-        paged_sessions = paged_blocks // blocks_per_session
-        out["state_bytes_per_seq"] = sbps
-        out["session_tokens"] = session_tokens
-        out["live_sessions_state"] = int(state_sessions)
-        out["live_sessions_paged"] = int(paged_sessions)
-        out["live_sessions_at_fixed_hbm_vs_paged"] = round(
-            state_sessions / max(paged_sessions, 1), 1
-        )
-        # ---- chained greedy decode throughput -------------------------
-        reqs = [(list(rng.integers(1, 256, size=6)), 32) for _ in range(8)]
-        eng.generate_batch([(list(p), n) for p, n in reqs])  # warm
-        t0 = time.perf_counter()
-        got = eng.generate_batch([(list(p), n) for p, n in reqs])
-        el = time.perf_counter() - t0
-        out["decode_tokens_per_s"] = round(
-            sum(len(g) for g in got) / el, 1
-        )
-        # ---- resume latency vs context length -------------------------
-        # resume copies ONE fixed-size state buffer, so a 2k-token
-        # session must resume as fast as a 128-token one
-        def _resume_p99(ctx_tokens: int) -> float:
-            store = SessionStore()
-            seng = StateDecodeEngine(
-                cfg, params, name=f"bench_ssd_sess{ctx_tokens}",
-                max_slots=8, max_batch_size=4, prefill_chunk=16,
-                chain_steps=8, session_store=store,
-            )
-            # warm on a throwaway store: the first suspend/resume pays
-            # the pw.state_suspend/resume compile, not the copy
-            wp = list(rng.integers(1, 256, size=16))
-            wsess = {"session": f"ssd-warm-{ctx_tokens}"}
-            wt = seng.generate_batch([(wp, 4, dict(wsess))])[0]
-            seng.generate_batch([(wp + wt + [3], 4, dict(wsess))])
-            store = SessionStore()
-            seng.session_store = store
-            for i in range(4):
-                p = list(rng.integers(1, 256, size=ctx_tokens - 16))
-                sess = {"session": f"ssd-sess-{ctx_tokens}-{i}"}
-                t1 = seng.generate_batch([(p, 8, dict(sess))])[0]
-                seng.generate_batch([(p + t1 + [3], 8, dict(sess))])
-            st = store.stats()
-            out[f"session_resumes_ctx{ctx_tokens}"] = st["resumes"]
-            return float(st["resume_ms_p99"])
-
-        short_p99 = _resume_p99(128)
-        long_p99 = _resume_p99(2048)
-        out["session_resume_ms_p99"] = round(max(short_p99, long_p99), 2)
-        out["session_resume_ms_p99_ctx128"] = round(short_p99, 2)
-        out["session_resume_ms_p99_ctx2048"] = round(long_p99, 2)
-        if short_p99 > 0:
-            out["session_resume_ctx_ratio"] = round(
-                long_p99 / short_p99, 2
-            )
-    except Exception as exc:  # noqa: BLE001 - never cost the headline
-        out["ssd_error"] = f"{type(exc).__name__}: {exc}"[:300]
-    return out
-
-
 def bench_parallel(n_rows_per_file: int = 50_000, n_files: int = 16) -> dict:
     """Measured multi-process scaling of the engine data plane.  On a
     single-core host this honestly reports <= 1x (processes time-slice one
@@ -1628,17 +1508,12 @@ def bench_generation() -> dict:
     # injected mid-decode (poll_inflight).  TTFT is recorded by the engine
     # per REQUEST (arrival at the engine -> first token; the stats
     # histogram's recent-observation ring), so the percentiles cover the
-    # whole workload — the round-7 whole-bucket path serializes one
-    # O(bucket^2) prefill dispatch per admission, which is exactly what
-    # the tail exposes.  decode stall = max gap between consecutive
+    # whole workload.  decode stall = max gap between consecutive
     # DECODE-ADVANCING dispatch completions (_step/_mixed spies) in the
     # window straddling the injection: every in-flight decoder emits one
-    # token per such dispatch in both modes, so that cadence IS
-    # inter-token latency — the dense path's admission prefill shows up
-    # as one long gap (poll timestamps would NOT work: _loop_body stops
-    # polling while the batch is full).  Same pool geometry both modes
-    # (the round-7 batched-bench config); the ISSUE-3 acceptance gate is
-    # p99 >= 2x.
+    # token per such dispatch, so that cadence IS inter-token latency
+    # (poll timestamps would NOT work: _loop_body stops polling while the
+    # batch is full).  The round-7 batched-bench pool geometry.
     ttft_fields = {}
     try:
         from pathway_tpu.kvcache.engine import PagedDecodeEngine as _PDE
@@ -1653,11 +1528,11 @@ def bench_generation() -> dict:
             " ".join(f"L w{i % 311}" for i in range(96))
         )[:96]
 
-        def _mixed_workload(chunked: bool, reps: int = 3):
+        def _mixed_workload(reps: int = 3):
             eng = _PDE(
                 cfg, lm.params, num_blocks=96, block_size=16,
                 max_batch_size=8, max_blocks_per_seq=7, seq_buckets=(112,),
-                prefix_sharing=False, chunked_prefill=chunked,
+                prefix_sharing=False,
                 # budget sized to the expected arrival: the whole 96-token
                 # prompt rides ONE ragged dispatch alongside the decoders
                 prefill_chunk=96,
@@ -1666,10 +1541,9 @@ def bench_generation() -> dict:
                 # decode token per dispatch (a round-10 chain would also
                 # compile its program inside the timed window)
                 chain_steps=1, speculative="off",
-                name=f"bench_ttft_{'chunked' if chunked else 'dense'}",
+                name="bench_ttft_chunked",
             )
-            # warm every shape this workload hits (mixed + decode + the
-            # legacy prefill bucket)
+            # warm every shape this workload hits (mixed + decode)
             eng.generate_batch(
                 [(long_prompt, 2)] + [(p, 2) for p in short_prompts]
             )
@@ -1735,8 +1609,7 @@ def bench_generation() -> dict:
                 "stall": max(stalls) if stalls else None,
             }
 
-        chunked_r = _mixed_workload(True)
-        dense_r = _mixed_workload(False)
+        chunked_r = _mixed_workload()
         if chunked_r:
             ttft_fields["ttft_ms_p50"] = round(chunked_r["p50"] * 1e3, 1)
             ttft_fields["ttft_ms_p99"] = round(chunked_r["p99"] * 1e3, 1)
@@ -1744,23 +1617,6 @@ def bench_generation() -> dict:
                 ttft_fields["decode_stall_ms_during_long_prefill"] = round(
                     chunked_r["stall"] * 1e3, 1
                 )
-        if dense_r:
-            ttft_fields["ttft_ms_p50_dense_prefill"] = round(
-                dense_r["p50"] * 1e3, 1
-            )
-            ttft_fields["ttft_ms_p99_dense_prefill"] = round(
-                dense_r["p99"] * 1e3, 1
-            )
-            if dense_r["stall"] is not None:
-                ttft_fields["decode_stall_ms_dense_prefill"] = round(
-                    dense_r["stall"] * 1e3, 1
-                )
-        if chunked_r and dense_r:
-            # the ISSUE-3 acceptance ratio: long-arrival tail latency,
-            # whole-bucket path over chunked path (>= 2x required)
-            ttft_fields["ttft_p99_speedup_vs_dense"] = round(
-                dense_r["p99"] / max(chunked_r["p99"], 1e-9), 2
-            )
 
         # ---- round-18 under-load A/B: the SAME mixed workload (7 short
         # decoders + a long-prompt arrival injected mid-decode) with
@@ -2255,23 +2111,6 @@ _HISTORY_BESTS = {
     "fleet.sessions_resident_at_fixed_hbm": (
         "max",
         lambda p: (p.get("fleet") or {}).get("sessions_resident_at_fixed_hbm"),
-    ),
-    # round-16 constant-memory decode rows (SOFT — deliberately NOT in
-    # _GATED_METRICS): the hbm_plan capacity ratio is a computed ledger
-    # row (its >= 4x floor is a test assertion, not a bench gate), and
-    # the throughput/resume rows accumulate self-history like the other
-    # serving rows
-    "ssd.live_sessions_at_fixed_hbm_vs_paged": (
-        "max",
-        lambda p: (p.get("ssd") or {}).get(
-            "live_sessions_at_fixed_hbm_vs_paged"
-        ),
-    ),
-    "ssd.decode_tokens_per_s": (
-        "max", lambda p: (p.get("ssd") or {}).get("decode_tokens_per_s"),
-    ),
-    "ssd.session_resume_ms_p99": (
-        "min", lambda p: (p.get("ssd") or {}).get("session_resume_ms_p99"),
     ),
     # round-18 speculative-decode rows (SOFT — deliberately NOT in
     # _GATED_METRICS): accept rate is workload-dependent, so these
@@ -2881,9 +2720,6 @@ def main() -> None:
     _stage("fleet")
     fleet = bench_fleet()
     _PARTIAL["fleet"] = fleet
-    _stage("ssd")
-    ssd = bench_ssd()
-    _PARTIAL["ssd"] = ssd
 
     # round-14 device cost observatory roll-up: total compile wall,
     # distinct device programs, redundant compiles, and the persisted
@@ -2938,11 +2774,6 @@ def main() -> None:
         # replica-kill MTTR, session-tier resume p99 and the HBM-ledger
         # residency row (soft self-history gates; see bench_fleet)
         "fleet": fleet,
-        # round-16 constant-memory decode rows: the hbm_plan-computed
-        # live-session capacity ratio vs the paged pool, SSD chained
-        # decode throughput, and context-independent session resume p99
-        # (soft self-history gates; see bench_ssd)
-        "ssd": ssd,
         "n_docs": n_docs,
         "embed_dim": enc.dimensions,
         "backend": backend,

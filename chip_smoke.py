@@ -259,8 +259,8 @@ def program_table(engine, expect_kernel: bool, since: float) -> list[dict]:
 
     from pathway_tpu.obs import profiler
 
-    wrappers = {id(w) for w in (engine._step, engine._mixed, engine._chained,
-                                engine._prefill)}
+    wrappers = {id(w) for w in (engine._step, engine._mixed,
+                                engine._chained)}
     # what one device's program sees of the pool (a quarter under tp=4)
     shard_shape = engine.pool.k.addressable_shards[0].data.shape
     rows = []
@@ -294,7 +294,7 @@ def program_table(engine, expect_kernel: bool, since: float) -> list[dict]:
                                 if mine else None),
         }
         rows.append(row)
-        if expect_kernel and rec.program != "pw.prefill":
+        if expect_kernel:
             require(row["kernel"] == "present",
                     'attn="pallas" but the compiled step program holds no '
                     "kernel", **row)
@@ -1092,8 +1092,6 @@ def phase_tp(sz: dict, seed: int, rehearse: bool) -> None:
                         "a device does not hold about a quarter of pool + "
                         "weights", held=held, quarter=quarter)
             for row in table:
-                if row["program"] == "pw.prefill":
-                    continue
                 # a psum after each row-parallel projection (two a layer)
                 # and the gather of the two-stage argmax
                 require(row["all_reduce"] >= 2 * cfg.n_layers

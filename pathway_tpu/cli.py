@@ -332,9 +332,8 @@ def plan_command(*, as_json: bool = False, calibrate: bool = False,
 
 def _program_family(name: str) -> str:
     """Family key for the profile rollup: ``pw.<plane>_<op>`` programs
-    group by plane (``pw.ssd_chained_decode`` -> ``pw.ssd``,
-    ``pw.state_suspend`` -> ``pw.state``, ``pw.chained_decode`` ->
-    ``pw.chained``); anything else groups under its leading dotted
+    group by plane (``pw.chained_decode`` -> ``pw.chained``,
+    ``pw.mixed_step_sampled`` -> ``pw.mixed``); anything else groups under its leading dotted
     component.  Round-18: ``_draft``-marked drafter programs fold into
     the family they draft FOR (``pw.prefill_draft`` -> ``pw.prefill``) —
     the rollup answers "what does this plane cost", and a drafter's
@@ -352,7 +351,7 @@ def format_profile_table(data: dict) -> str:
     """The ranked per-program device cost table (Round-14): one row per
     (program, bucket), ordered by total dispatch seconds — the "which
     kernel to fuse first" view of ``/debug/profile``.  Round-16 appends
-    a per-family rollup (``pw.ssd``, ``pw.paged``, ...) so a whole
+    a per-family rollup (``pw.mixed``, ``pw.chained``, ...) so a whole
     decode plane's device share reads off one line."""
     cols = ("program", "disp", "ms p50", "share", "GFLOP", "MB", "AI",
             "MFU", "bound", "compiles", "compile s")

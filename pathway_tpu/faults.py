@@ -24,8 +24,8 @@ Fault points wired in this round (call sites in parentheses):
 ``engine.dispatch.chain`` the Nth chained decode dispatch
                           (kvcache/engine.py); ``raise`` models a failing
                           device program
-``engine.dispatch.step``  / ``engine.dispatch.mixed`` /
-``engine.dispatch.prefill``  the other dispatch kinds, same semantics
+``engine.dispatch.step``  / ``engine.dispatch.mixed``: the other
+                          dispatch kinds, same semantics
 ``engine.dispatch.verify``  the Round-18 speculative verify dispatch,
                           same semantics as the other dispatch kinds
 ``engine.draft``          the speculative draft phase, BEFORE proposals

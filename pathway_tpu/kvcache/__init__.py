@@ -30,16 +30,12 @@ reduction — no replicated [B, vocab] gather ever materializes).
 ``PagedDecodeEngine(tp=...)``; tp=1 degenerates to the exact
 single-device programs.
 
-Round-16 (ARCHITECTURE.md "Round-16: Constant-memory decode and the
-cache-backend contract") extracts the engine<->cache contract into
-backend.py (``CacheBackend`` + ``make_backend``; BlockPool is its paged
-implementation, behavior-identical) and adds a second implementation:
-statecache.py — ``StateCache`` slots hold the SSD/linear-attention
-decoder's fixed-size recurrent states (models/decoder.py ``ssd_*``), so
-per-sequence HBM and session suspend/resume cost are CONSTANT in
-context length; ``StateDecodeEngine`` serves them with the paged
-engine's exact surface (continuous batching, chained decode, watchdog
-restart, tiering, fleet failover).
+The engine<->cache contract is backend.py (``CacheBackend`` +
+``make_backend``), with two kinds behind it: ``"paged"`` (block_pool.py
+``BlockPool``: K/V blocks for every layer) and ``"hybrid"`` (hybrid.py
+``HybridCache``: K/V blocks for a model's attention layers and a conv
+slot a sequence beside them).  Which kind an engine builds, and which
+step programs it runs, its block family says (models/families.py).
 
 Round-18 (ARCHITECTURE.md "Round-18: Speculative decoding") breaks the
 step's serial token dependence: a cheap drafter (speculative.py — a
@@ -63,7 +59,6 @@ from .paged_attention import paged_attention, paged_attention_reference
 from .prefix_cache import PrefixCache
 from .speculative import (Drafter, DraftModelDrafter, NGramDrafter,
                           SpecController, SpecResourceError)
-from .statecache import StateCache, StateDecodeEngine
 from .tiering import SessionStore
 
 __all__ = [
@@ -81,8 +76,6 @@ __all__ = [
     "SequenceState",
     "PrefixCache",
     "PagedDecodeEngine",
-    "StateCache",
-    "StateDecodeEngine",
     "UnsupportedCacheOp",
     "make_backend",
     "resolve_tp",

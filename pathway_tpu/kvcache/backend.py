@@ -1,47 +1,51 @@
-"""The engine↔cache contract (Round-16).
+"""The engine↔cache contract.
 
-Until this round the decode engines programmed directly against
-:class:`~pathway_tpu.kvcache.block_pool.BlockPool` — the paged layout
-was the only cache scheme, so the contract between "engine" (admission,
-scheduling, restart, sessions) and "cache" (how a sequence's decode
-state lives in HBM) existed only implicitly, as the set of BlockPool
-methods the engine happened to call.  ROADMAP item 4's constant-memory
-decode family needs a SECOND scheme — a fixed-size recurrent state per
-sequence (statecache.py) — so the contract becomes explicit here.
+The engine (engine.py: admission, rounds, restart, sessions) programs
+against :class:`CacheBackend`, not against a layout; how a sequence's
+decode state lives in HBM is the backend's.  Two kinds serve cells:
 
-:class:`CacheBackend` is that contract.  A backend owns:
+- ``"paged"`` — :class:`~pathway_tpu.kvcache.block_pool.BlockPool`: K/V
+  blocks for every layer, addressed through per-sequence block tables;
+  prefix sharing, copy-on-write fork, preemption, host tiering.
+- ``"hybrid"`` — :class:`~pathway_tpu.kvcache.hybrid.HybridCache`: the
+  same K/V blocks for a model's attention layers and, beside them, one
+  conv slot a sequence for its short-conv layers.  Preemption only: a
+  shared, forked or resumed block would skip the tokens that build the
+  conv state.
+
+A backend owns:
 
 - **slot lifecycle**: ``allocate`` / ``extend_slots`` / ``append_slot``
-  / ``free_sequence`` — how a sequence claims device memory.  For the
-  paged backend slots are KV blocks and extension is real growth; for
-  the state backend a "slot" is the sequence's single fixed-size state
-  row and extension past allocation is a no-op by construction.
-- **byte accounting**: ``per_shard_bytes`` (what the backend pins in
+  / ``free_sequence`` — how a sequence claims device memory (K/V blocks
+  that grow with the context; for the hybrid kind a conv slot with them,
+  claimed and released in the same call);
+- **device state**: ``device_state()`` / ``set_device_state(...)`` — the
+  arrays a step program takes after the params and gives back (donated),
+  and ``row_extras`` — further per-row host arrays the programs take
+  (the hybrid kind: the rows' conv slots);
+- **byte accounting**: ``per_shard_bytes`` — what the backend pins in
   each tensor-parallel shard's HBM, the number ``obs/memory.py
-  hbm_plan`` charges) and ``state_bytes_per_seq`` (the per-sequence
-  footprint — block-count-dependent for paged, a constant for state).
+  hbm_plan`` charges;
 - **suspend/resume**: ``suspend_host`` / ``resume_host`` — the
   device↔host copies behind
   :class:`~pathway_tpu.kvcache.tiering.SessionStore`.  The payload is
-  backend-opaque; the store only charges its byte size and keys it by
-  session.  The paged payload grows with context (power-of-two padded
-  block gathers); the state payload is ONE fixed-size array, which is
-  what makes session resume O(1) in context length.
+  backend-opaque; the store only charges its byte size (power-of-two
+  padded block gathers for the paged kind) and keys it by session;
 - **invariants**: ``check_invariants`` — the backend-specific
-  consistency sweep (refcount conservation for paged; slot-bitmap
-  conservation for state).  Engine-owned invariants (admission
-  ordering, emit counts, watchdog state) stay in the engine and are NOT
-  part of this contract.
+  consistency sweep (refcount conservation; for the hybrid kind also
+  slot conservation).  Engine-owned invariants (admission ordering,
+  emit counts, watchdog state) stay in the engine and are NOT part of
+  this contract.
 
 Backend-optional capabilities — prefix sharing, copy-on-write ``fork``,
 preemption-by-eviction — are declared via ``supports_*`` flags and
-raise :class:`UnsupportedCacheOp` by default; the paged engine consults
-the flags before relying on them.
+raise :class:`UnsupportedCacheOp` where absent; the engine consults the
+flags before relying on them.
 
-``make_backend(kind, ...)`` is the construction seam: engines build
-their cache through it (and REBUILD through it on supervised restart),
-so tests can run the existing paged identity suite through the
-extracted interface unchanged.
+``make_backend(kind, ...)`` is the construction seam: the engine builds
+its cache through it (and REBUILDS through it on supervised restart)
+with the geometry its block family names (models/families.py
+``cache_kind`` / ``cache_kwargs``).
 """
 
 from __future__ import annotations
@@ -59,7 +63,7 @@ class CacheBackend(abc.ABC):
     """Abstract engine↔cache contract.  See the module docstring for
     which side owns which invariant."""
 
-    #: "paged" | "state" | ... — the factory key and metrics family
+    #: "paged" | "hybrid" — the factory key
     cache_kind: str = "abstract"
     #: optional capabilities the paged engine consults
     supports_fork: bool = False
@@ -77,8 +81,7 @@ class CacheBackend(abc.ABC):
     @abc.abstractmethod
     def extend_slots(self, seq_id, k: int) -> list[int]:
         """Grow the sequence by ``k`` decode slots, atomically; returns
-        the slot ids (paged: new block ids; state: the fixed slot,
-        repeated — growth is free)."""
+        the ``(block, offset)`` slots the next ``k`` tokens land in."""
 
     def append_slot(self, seq_id) -> int:
         return self.extend_slots(seq_id, 1)[0]
@@ -101,15 +104,6 @@ class CacheBackend(abc.ABC):
     @abc.abstractmethod
     def per_shard_bytes(self) -> int:
         """Bytes this backend pins in EACH tensor-parallel shard's HBM."""
-
-    def state_bytes_per_seq(self, n_tokens: int) -> int:
-        """Device bytes one sequence of ``n_tokens`` occupies (global
-        across shards).  Paged: grows with the block span.  State: a
-        constant — the property the capacity headline is computed
-        from."""
-        raise UnsupportedCacheOp(
-            f"{type(self).__name__} does not account per-sequence bytes"
-        )
 
     # -- suspend / resume (tiering.SessionStore) ---------------------------
     @abc.abstractmethod
@@ -166,21 +160,16 @@ def register_backend(kind: str, factory: Callable) -> None:
 def make_backend(kind: str, **kwargs) -> CacheBackend:
     """Construct a cache backend by kind — the seam engines build (and
     restart-rebuild) their cache through.  ``"paged"`` →
-    :class:`~pathway_tpu.kvcache.block_pool.BlockPool`; ``"state"`` →
-    :class:`~pathway_tpu.kvcache.statecache.StateCache`; ``"hybrid"`` →
+    :class:`~pathway_tpu.kvcache.block_pool.BlockPool`; ``"hybrid"`` →
     :class:`~pathway_tpu.kvcache.hybrid.HybridCache` (K/V blocks for the
     attention layers and a conv slot, one sequence)."""
     if kind not in _BACKENDS:
-        # lazy registration avoids import cycles: block_pool/statecache
+        # lazy registration avoids import cycles: block_pool/hybrid
         # import nothing from here at module scope except the ABC
         if kind == "paged":
             from .block_pool import BlockPool
 
             register_backend("paged", BlockPool)
-        elif kind == "state":
-            from .statecache import StateCache
-
-            register_backend("state", StateCache)
         elif kind == "hybrid":
             from .hybrid import HybridCache
 
@@ -188,7 +177,6 @@ def make_backend(kind: str, **kwargs) -> CacheBackend:
         else:
             raise ValueError(
                 f"unknown cache backend {kind!r}; "
-                f"registered: {sorted(_BACKENDS)} + builtin: paged, state, "
-                "hybrid"
+                f"registered: {sorted(_BACKENDS)} + builtin: paged, hybrid"
             )
     return _BACKENDS[kind](**kwargs)

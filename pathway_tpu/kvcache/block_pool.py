@@ -200,16 +200,6 @@ class BlockPool(CacheBackend):
         total = int(self.k.size) + int(self.v.size)
         return total * self.k.dtype.itemsize // self.tp
 
-    def state_bytes_per_seq(self, n_tokens: int) -> int:
-        """GLOBAL device bytes one ``n_tokens`` sequence occupies: its
-        block span times the per-block K/V bytes summed across shards
-        (a block id means the same head-split block on every shard)."""
-        per_block = (
-            (int(self.k.size) + int(self.v.size))
-            * self.k.dtype.itemsize // self.num_blocks
-        )
-        return self.blocks_for(max(int(n_tokens), 1)) * per_block
-
     @property
     def num_free(self) -> int:
         return len(self._free)

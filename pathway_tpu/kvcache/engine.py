@@ -10,19 +10,18 @@ decodes MANY sequences per device step:
   re-stored AND re-computed — chunked prefill starts after them), fresh
   blocks are allocated for the remainder, and the prompt then streams
   through the RAGGED fused step in block-aligned chunks
-  (:func:`~pathway_tpu.models.decoder.paged_mixed_step`): each engine
+  (the block family's ``mixed`` program, models/families.py): each engine
   step carries the in-flight decode rows (1 token each) plus one
   ``prefill_chunk``-token chunk per admitting sequence in ONE dispatch,
   so a 1k-token arrival never stalls running decodes behind a
   monolithic whole-bucket prefill (head-of-line blocking at step
-  boundaries).  ``chunked_prefill=False`` restores the Round-7
-  whole-bucket admission prefill (the bench baseline);
+  boundaries);
 - decode: every running sequence advances one token per dispatch with
   per-sequence positions/block tables (the dense path's
   one-scalar-position design is what forced batch 1).  Rounds with no
   chunk in flight dispatch the cheap 1-token-per-row program; rounds
   with admissions dispatch the mixed program — two static shapes total,
-  compiled once each (no per-bucket prefill ladder in chunked mode);
+  compiled once each (no per-bucket prefill ladder);
 - device-side sampling: greedy argmax runs INSIDE the jitted step; only
   ``[B]`` int32 token ids cross the device->host boundary per round
   (the done-mask is a host compare on those ids), shrinking the
@@ -72,6 +71,12 @@ from .backend import make_backend
 from .block_pool import BlockPool, PoolExhausted  # noqa: F401 - re-export
 from .paged_attention import span_blocks
 from .prefix_cache import PrefixCache
+
+
+# the registry names of a family's step programs (obs/profiler.py; the
+# ``_sampled`` / ``_i8`` suffixes are added to these)
+_PROGRAM_NAMES = {"step": "pw.decode_step", "mixed": "pw.mixed_step",
+                  "chained": "pw.chained_decode"}
 
 
 class EngineHungError(RuntimeError):
@@ -337,8 +342,7 @@ class _Active:
         self.seq_id = seq_id
         self.req = req
         # chunked-prefill state: `tokens` is the full (trimmed) prompt
-        # still being streamed in; None once prefill completes (or for
-        # the legacy whole-bucket path, from the start)
+        # still being streamed in; None once prefill completes
         self.tokens: list[int] | None = None
         # the trimmed token list this sequence was admitted with — kept
         # past prefill completion (unlike `tokens`) so session suspension
@@ -361,7 +365,7 @@ class _Active:
 def build_engine(cfg, params, fallback_msg: str, logger_name: str,
                  engine_cls=None, **kwargs):
     """Construct a decode engine (:class:`PagedDecodeEngine` by default,
-    or ``engine_cls`` — e.g. kvcache.statecache.StateDecodeEngine).
+    or ``engine_cls``).
 
     The serial tier behind the callers (JaxDecoderLM.paged_engine,
     Int8DecoderHost.paged_engine) is the CPU's: on a CPU backend an
@@ -421,7 +425,7 @@ class PagedDecodeEngine:
                  max_batch_size: int | None = None,
                  seq_buckets=(64, 256, 1024),
                  prefix_sharing: bool = True, stop_token: int | None = None,
-                 attn: str | None = None, chunked_prefill: bool = True,
+                 attn: str | None = None,
                  prefill_chunk: int | None = None, tp: int | None = None,
                  chain_steps: int | None = None,
                  quantize: str | None = None,
@@ -448,7 +452,7 @@ class PagedDecodeEngine:
         self.family = step_family(cfg)
         self.family.unsupported(
             tp=tp, quantize=quantize, speculative=speculative,
-            session_store=session_store, chunked_prefill=chunked_prefill)
+            session_store=session_store)
         # Round-9 tensor parallelism: tp > 1 lays the K/V pool out over a
         # (dp=1, tp) mesh (n_kv_heads/tp per shard — N x aggregate KV HBM)
         # and shard_maps every step program; tp == 1 keeps the EXACT
@@ -596,8 +600,8 @@ class PagedDecodeEngine:
         self.degrade_fn = degrade_fn
         # Round-15 KV session tiering (kvcache/tiering.py SessionStore):
         # requests carrying a `session` id suspend their blocks to host
-        # RAM at completion and resume by re-scatter at the next turn.
-        # Chunked-prefill mode only (resume rides the chunk divert rule).
+        # RAM at completion and resume by re-scatter at the next turn
+        # (resume rides the chunk divert rule).
         self.session_store = session_store
         # Round-15 sampled program variants — built LAZILY on the first
         # sampled request (_sampled_programs), so a greedy-only workload
@@ -621,16 +625,14 @@ class PagedDecodeEngine:
         # blocks of 16), for ``kv_key_lanes`` on ``pw.round.build``
         self._span_keys = bs * span_blocks(
             bs, self.max_blocks_per_seq, self.pool.k.shape[-1] // self.tp)
-        # prefill buckets: block-aligned, capped at what one table can span.
-        # The cap itself must round DOWN to a block multiple — rounding a
-        # bucket up past a non-aligned max_seq_tokens (cfg.max_len not a
-        # multiple of block_size) would break paged_prefill's reshape
+        # prompt buckets: block-aligned, capped at what one table can
+        # span (the cap rounded DOWN to a block multiple); the largest
+        # entry caps a prompt
         bucket_cap = max((self.max_seq_tokens // bs) * bs, bs)
         buckets = sorted({
             min(-(-b // bs) * bs, bucket_cap) for b in seq_buckets
         })
         self.seq_buckets = buckets or [bucket_cap]
-        self.chunked_prefill = bool(chunked_prefill)
         # chunk width: block-aligned (so chunk writes cover whole blocks
         # except the prompt's tail), default two blocks per step — small
         # enough that an arrival adds bounded latency to in-flight
@@ -669,41 +671,20 @@ class PagedDecodeEngine:
         # Per-run state (reset by _run_loop); the engine lock serializes
         # runs, so one map on self is safe
         self._inflight_prefix: dict = {}
-        # the greedy step programs are the family's; every one argmaxes
-        # INSIDE the jitted program, so only [B] int32 ids (not [B, vocab]
-        # logits) cross the device->host boundary per round
-        progs = self.family.programs(cfg, self.attn, self.mesh)
-
-        # pools donated: every step/prefill consumes them in place.  Two
-        # static shapes cover the whole workload in chunked mode — the
-        # (B,) decode program and the (B, prefill_chunk) mixed program —
-        # so a bucket-ladder workload compiles exactly twice (pinned by
-        # tests/test_ragged_step.py's recompile guard); the legacy
-        # whole-bucket prefill specializes per (1, bucket) as before.
-        # Round-14: every program registers in the device cost
-        # observatory — compile wall/provenance at first lowering,
-        # FLOPs/bytes introspection, and the dispatch->sync windows the
-        # sync sites below attribute per program (obs/profiler.py)
-        from ..obs.profiler import profiled_jit
-
-        # Round-17: int8 engines register under distinct ``_i8`` program
-        # names so the observatory ranks/rooflines the two weight paths
-        # separately and CompileWatch pins each variant's compile count
-        sfx = self._prog_suffix
-
-        def program(name: str, kind: str):
-            if kind not in progs:
-                return None
-            fn, donated = progs[kind]
-            return profiled_jit(f"{name}{sfx}", fn, donate_argnums=donated)
-
-        self._step = program("pw.decode_step", "step")
-        self._mixed = program("pw.mixed_step", "mixed")
-        # the chained program's (B, chain_steps) shape is static, so the
-        # whole multi-step hot loop is ONE additional compile on top of
-        # the round-8 pair (K=1 rounds reuse the plain step program)
-        self._chained = program("pw.chained_decode", "chained")
-        self._prefill = program("pw.prefill", "prefill")
+        # the step programs are the family's (models/families.py); every
+        # one samples INSIDE the jitted program, so only [B] int32 ids (not
+        # [B, vocab] logits) cross the device->host boundary per round.
+        # Two static shapes cover the whole workload — the (B,) decode
+        # program and the (B, prefill_chunk) mixed program — so a
+        # bucket-ladder workload compiles exactly twice (pinned by
+        # tests/test_ragged_step.py's recompile guard); the chained
+        # program's (B, chain_steps) shape is static too, so the whole
+        # multi-step hot loop is ONE additional compile (K=1 rounds reuse
+        # the plain step program)
+        greedy = self._programs(sampled=False)
+        self._step = greedy["step"]
+        self._mixed = greedy["mixed"]
+        self._chained = greedy["chained"]
         # Round-18 speculative decoding (kvcache/speculative.py): a
         # drafter proposes up to K tokens per row, ONE ragged verify
         # dispatch checks them all, and the greedy accept rule keeps the
@@ -721,104 +702,36 @@ class PagedDecodeEngine:
     def _prog_suffix(self) -> str:
         return "_i8" if self.quantize == "int8" else ""
 
-    # -- Round-15: device-side temperature/top-k/top-p sampling ------------
+    # -- the step programs: jitted here, named by the family -----------------
+    def _programs(self, sampled: bool) -> dict:
+        """One table of the family's step programs (``step``, ``mixed``,
+        ``chained``), jitted with the cache's arrays donated: every step
+        consumes them in place.  Round-14: every program registers in the
+        device cost observatory — compile wall/provenance at first
+        lowering, FLOPs/bytes introspection, and the dispatch->sync
+        windows the sync sites attribute per program (obs/profiler.py).
+        Round-17: int8 engines register under distinct ``_i8`` names, so
+        the observatory ranks the two weight paths separately and
+        CompileWatch pins each variant's compile count."""
+        from ..obs.profiler import profiled_jit
+
+        sfx = ("_sampled" if sampled else "") + self._prog_suffix
+        table = self.family.programs(self.cfg, self.attn, self.mesh,
+                                     sampled=sampled)
+        return {
+            kind: profiled_jit(f"{_PROGRAM_NAMES[kind]}{sfx}", fn,
+                               donate_argnums=donated)
+            for kind, (fn, donated) in table.items()
+        }
+
     def _sampled_programs(self) -> dict:
-        """The pw.*_sampled jitted programs, built on FIRST use.  Each
-        wraps its greedy twin's step math with the sampling head
-        (models/decoder.py) and takes five extra (B,) arrays:
+        """The pw.*_sampled programs (Round-15), built on FIRST use: each
+        takes five (B,) arrays after its greedy twin's —
         temperature/top_k/top_p/seed/emit-index.  Greedy-only workloads
         never call this, so the sampled variants are the ONLY programs
         sampling adds — the zero-extra-compiles pin of the round."""
-        if self._sampled is not None:
-            return self._sampled
-        from ..obs.profiler import profiled_jit
-
-        _cfg, _attn, _mesh = self.cfg, self.attn, self.mesh
-
-        def _step_fn(p, k_pool, v_pool, token, positions, bt, sb, so,
-                     temp, tk, tpp, seed, emit):
-            from ..models.decoder import (paged_decode_step_sampled,
-                                          paged_decode_step_sampled_tp)
-
-            if _mesh is not None:
-                return paged_decode_step_sampled_tp(
-                    p, _cfg, _mesh, k_pool, v_pool, token, positions, bt,
-                    sb, so, temp, tk, tpp, seed, emit, attn=_attn,
-                )
-            return paged_decode_step_sampled(
-                p, _cfg, k_pool, v_pool, token, positions, bt, sb, so,
-                temp, tk, tpp, seed, emit, attn=_attn,
-            )
-
-        def _mixed_fn(p, k_pool, v_pool, tokens, positions, row_tables,
-                      row_start, row_nvalid, row_token_idx, tok_row,
-                      tok_col, sb, so, logit_idx, temp, tk, tpp, seed,
-                      emit):
-            from ..models.decoder import (paged_mixed_step_sampled,
-                                          paged_mixed_step_sampled_tp)
-
-            if _mesh is not None:
-                return paged_mixed_step_sampled_tp(
-                    p, _cfg, _mesh, k_pool, v_pool, tokens, positions,
-                    row_tables, row_start, row_nvalid, row_token_idx,
-                    tok_row, tok_col, sb, so, logit_idx, temp, tk, tpp,
-                    seed, emit, attn=_attn,
-                )
-            return paged_mixed_step_sampled(
-                p, _cfg, k_pool, v_pool, tokens, positions, row_tables,
-                row_start, row_nvalid, row_token_idx, tok_row, tok_col,
-                sb, so, logit_idx, temp, tk, tpp, seed, emit, attn=_attn,
-            )
-
-        def _chained_fn(p, k_pool, v_pool, token, positions, bt, sb, so,
-                        temp, tk, tpp, seed, emit0):
-            from ..models.decoder import (paged_chained_decode_sampled,
-                                          paged_chained_decode_sampled_tp)
-
-            if _mesh is not None:
-                return paged_chained_decode_sampled_tp(
-                    p, _cfg, _mesh, k_pool, v_pool, token, positions, bt,
-                    sb, so, temp, tk, tpp, seed, emit0, attn=_attn,
-                )
-            return paged_chained_decode_sampled(
-                p, _cfg, k_pool, v_pool, token, positions, bt, sb, so,
-                temp, tk, tpp, seed, emit0, attn=_attn,
-            )
-
-        def _prefill_fn(p, token_ids, n_valid, k_pool, v_pool, bt,
-                        temp, tk, tpp, seed, emit):
-            from ..models.decoder import (paged_prefill_sampled,
-                                          paged_prefill_sampled_tp)
-
-            if _mesh is not None:
-                return paged_prefill_sampled_tp(
-                    p, _cfg, _mesh, token_ids, n_valid, k_pool, v_pool,
-                    bt, temp, tk, tpp, seed, emit,
-                )
-            return paged_prefill_sampled(
-                p, _cfg, token_ids, n_valid, k_pool, v_pool, bt, temp,
-                tk, tpp, seed, emit,
-            )
-
-        sfx = self._prog_suffix
-        self._sampled = {
-            "step": profiled_jit(
-                f"pw.decode_step_sampled{sfx}", _step_fn,
-                donate_argnums=(1, 2),
-            ),
-            "mixed": profiled_jit(
-                f"pw.mixed_step_sampled{sfx}", _mixed_fn,
-                donate_argnums=(1, 2),
-            ),
-            "chained": profiled_jit(
-                f"pw.chained_decode_sampled{sfx}", _chained_fn,
-                donate_argnums=(1, 2),
-            ),
-            "prefill": profiled_jit(
-                f"pw.prefill_sampled{sfx}", _prefill_fn,
-                donate_argnums=(3, 4),
-            ),
-        }
+        if self._sampled is None:
+            self._sampled = self._programs(sampled=True)
         return self._sampled
 
     def _sampling_arrays(self, entries, B: int):
@@ -845,42 +758,21 @@ class PagedDecodeEngine:
     # -- Round-18: speculative verify program ------------------------------
     def _verify_program(self):
         """The jitted verify program, built on FIRST speculative use: the
-        EXACT ragged mixed-step math with a FLATTENED ``(B*C,)`` logit
-        head — one argmax per packed query position instead of one per
-        row, so the host can compare every draft token against the
+        family's ragged ``mixed`` program given a FLATTENED ``(B*C,)``
+        logit index — one argmax per packed query position instead of one
+        per row, so the host can compare every draft token against the
         target model's own next-token choice.  Shapes are static
         (``T = B * (k+1)`` tokens, ``C = k+1`` queries/row, ``B =
         max_batch_size``), so the program compiles exactly once per
         engine — the zero-recompile pin of the round."""
-        if self._verify is not None:
-            return self._verify
-        from ..obs.profiler import profiled_jit
+        if self._verify is None:
+            from ..obs.profiler import profiled_jit
 
-        _cfg, _attn, _mesh = self.cfg, self.attn, self.mesh
-
-        def _verify_fn(p, k_pool, v_pool, tokens, positions, row_tables,
-                       row_start, row_nvalid, row_token_idx, tok_row,
-                       tok_col, sb, so, logit_idx):
-            from ..models.decoder import paged_mixed_step, paged_mixed_step_tp
-
-            if _mesh is not None:
-                return paged_mixed_step_tp(
-                    p, _cfg, _mesh, k_pool, v_pool, tokens, positions,
-                    row_tables, row_start, row_nvalid, row_token_idx,
-                    tok_row, tok_col, sb, so, logit_idx, attn=_attn,
-                )
-            logits, k_pool, v_pool = paged_mixed_step(
-                p, _cfg, k_pool, v_pool, tokens, positions, row_tables,
-                row_start, row_nvalid, row_token_idx, tok_row, tok_col,
-                sb, so, logit_idx, attn=_attn,
-            )
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), \
-                k_pool, v_pool
-
-        self._verify = profiled_jit(
-            f"pw.verify_step{self._prog_suffix}", _verify_fn,
-            donate_argnums=(1, 2),
-        )
+            fn, donated = self.family.programs(
+                self.cfg, self.attn, self.mesh)["mixed"]
+            self._verify = profiled_jit(
+                f"pw.verify_step{self._prog_suffix}", fn,
+                donate_argnums=donated)
         return self._verify
 
     def _call(self, prog, dev: tuple):
@@ -1452,15 +1344,14 @@ class PagedDecodeEngine:
 
     # -- admission ---------------------------------------------------------
     def _try_admit(self, req: _Request, running, pending, deliver) -> str:
-        """Allocate (and in legacy mode prefill) one request.  Returns
-        "admitted", "done" (finished at its first token — legacy mode
-        only), "failed" (undecodable — delivered as an error), or "wait"
-        (pool full while other sequences run).
+        """Allocate one request.  Returns "admitted", "done" (a request
+        for no tokens), "failed" (undecodable — delivered as an error), or
+        "wait" (pool full while other sequences run).
 
-        Chunked mode allocates the sequence's blocks and queues the
-        prompt for streaming through the ragged mixed step — NO device
-        work happens at admission, so an arrival can never stall the
-        in-flight batch here."""
+        Admission allocates the sequence's blocks and queues the prompt
+        for streaming through the ragged mixed step — NO device work
+        happens here, so an arrival can never stall the in-flight
+        batch."""
         if req.max_new - len(req.emitted) <= 0:
             # zero-token request: the dense path returns nothing, so must we
             deliver(req)
@@ -1486,7 +1377,7 @@ class PagedDecodeEngine:
         n = len(tokens)
         self._seq_counter += 1
         seq_id = self._seq_counter
-        # Round-15 session tiering (chunked mode only): a session-tagged
+        # Round-15 session tiering: a session-tagged
         # request resumes its suspended K/V from the host tier instead of
         # going through the prefix cache — sessions are PRIVATE
         # continuity (one conversation's history), not shared prefixes,
@@ -1494,8 +1385,7 @@ class PagedDecodeEngine:
         # writer gates) is deliberately bypassed for them
         sess_entry = None
         use_session = (
-            self.chunked_prefill and req.session is not None
-            and self.session_store is not None
+            req.session is not None and self.session_store is not None
         )
         if use_session:
             sess_entry = self.session_store.match(req.session, tokens)
@@ -1508,37 +1398,34 @@ class PagedDecodeEngine:
             if self.prefix is not None and not use_session:
                 # sharing is safe even when it covers EVERY prompt block:
                 # full blocks are never decode-write targets (appends open
-                # a fresh block at the boundary) and shared blocks are
-                # excluded from the prefill scatter below.  Only the first
-                # match records hit/miss stats — eviction retries re-match
-                # the same admission
-                shared, keys = self.prefix.match(
-                    tokens,
-                    record=(attempt == 0 and not self.chunked_prefill),
-                )
-                if self.chunked_prefill:
-                    # extend the match into blocks an IN-FLIGHT chunked
-                    # prefill is still writing: the physical sharing (and
-                    # compute skip) starts NOW; our chunks gate on the
-                    # writer's progress.  One writer only — chaining
-                    # across writers would need a multi-way gate for
-                    # marginal benefit
-                    for key in keys[len(shared):]:
-                        ent = self._inflight_prefix.get(key)
-                        if ent is None or (
-                            writer is not None and ent[0] is not writer
-                        ):
-                            break
-                        writer = ent[0]
-                        shared.append(ent[1])
-                    if attempt == 0:
-                        hits = len(shared)
-                        if hits:
-                            self.pool.stats.record_prefix_hit(hits)
-                        if len(keys) - hits:
-                            self.pool.stats.record_prefix_miss(
-                                len(keys) - hits
-                            )
+                # a fresh block at the boundary) and chunk writes for the
+                # shared positions are diverted to the null block.  Only
+                # the first match records hit/miss stats (below, in-flight
+                # blocks included) — eviction retries re-match the same
+                # admission
+                shared, keys = self.prefix.match(tokens, record=False)
+                # extend the match into blocks an IN-FLIGHT chunked
+                # prefill is still writing: the physical sharing (and
+                # compute skip) starts NOW; our chunks gate on the
+                # writer's progress.  One writer only — chaining
+                # across writers would need a multi-way gate for
+                # marginal benefit
+                for key in keys[len(shared):]:
+                    ent = self._inflight_prefix.get(key)
+                    if ent is None or (
+                        writer is not None and ent[0] is not writer
+                    ):
+                        break
+                    writer = ent[0]
+                    shared.append(ent[1])
+                if attempt == 0:
+                    hits = len(shared)
+                    if hits:
+                        self.pool.stats.record_prefix_hit(hits)
+                    if len(keys) - hits:
+                        self.pool.stats.record_prefix_miss(
+                            len(keys) - hits
+                        )
             attempt += 1
             try:
                 state = self.pool.allocate(
@@ -1562,127 +1449,49 @@ class PagedDecodeEngine:
                         f"{n}-token sequence"
                     ))
                     return "failed"
-        if self.chunked_prefill:
-            act = _Active(seq_id, req)
-            act.tokens = tokens
-            act.admitted = tokens
-            if use_session:
-                resident = 0
-                if sess_entry is not None:
-                    resident = self.session_store.resume_into(
-                        self.pool, sess_entry, state.block_ids
-                    )
-                # resumed positions ride the chunk divert rule exactly
-                # like prefix-shared blocks: their K/V is already
-                # resident, so chunk writes for pos < n_diverted go to
-                # the null block — but the prompt's LAST token always
-                # recomputes to produce the next-token logits
-                act.n_filled = min(resident, n - 1)
-                act.n_diverted = resident
-                req.note_admitted(time.perf_counter())
-                running.append(act)
-                return "admitted"
-            # prefix-shared leading blocks need no recompute: their K/V
-            # is already (or will be, gated on the writer) resident, so
-            # chunking starts after them — the compute saving the
-            # Round-7 whole-bucket prefill could not take — but at least
-            # the prompt's LAST token must run to produce the
-            # next-token logits
-            shared_tokens = len(shared) * self.pool.block_size
-            act.n_filled = min(shared_tokens, n - 1)
-            act.n_diverted = shared_tokens
-            act.wait_writer = writer
-            # cache registration happens only when the last chunk lands
-            # (K/V written); until then our OWN unshared full blocks go
-            # into the in-flight map so same-round arrivals can share
-            # them under the progress gate
-            act.prefix_keys = keys
-            if self.prefix is not None:
-                for key, blk in zip(keys[len(shared):],
-                                    state.block_ids[len(shared):len(keys)]):
-                    self._inflight_prefix.setdefault(key, (act, blk))
+        act = _Active(seq_id, req)
+        act.tokens = tokens
+        act.admitted = tokens
+        if use_session:
+            resident = 0
+            if sess_entry is not None:
+                resident = self.session_store.resume_into(
+                    self.pool, sess_entry, state.block_ids
+                )
+            # resumed positions ride the chunk divert rule exactly
+            # like prefix-shared blocks: their K/V is already
+            # resident, so chunk writes for pos < n_diverted go to
+            # the null block — but the prompt's LAST token always
+            # recomputes to produce the next-token logits
+            act.n_filled = min(resident, n - 1)
+            act.n_diverted = resident
             req.note_admitted(time.perf_counter())
             running.append(act)
             return "admitted"
-        # -- legacy whole-bucket prefill (chunked_prefill=False) ----------
-        try:
-            bucket = next(b for b in self.seq_buckets if b >= n)
-            nb = bucket // self.pool.block_size
-            buf = np.zeros((1, bucket), np.int32)
-            buf[0, :n] = tokens
-            # prefix-shared leading blocks already hold the right K/V:
-            # divert their scatter slots to the null block instead of
-            # rewriting them — a live sequence may be attending through
-            # those blocks RIGHT NOW, and a rewrite from a different
-            # length bucket is not bit-identical on kernels that switch
-            # algorithm by length (flash vs dense), which would silently
-            # perturb its remaining decode
-            scatter_bt = self.pool.block_table(seq_id, nb)
-            scatter_bt[: len(shared)] = 0
-            host = (buf, np.asarray([n], np.int32), scatter_bt[None, :])
-            if req.sampling is not None:
-                # first token's emit index is len(emitted): a restart /
-                # failover re-admission resumes the seed schedule exactly
-                # where the dead engine left off
-                tv, kv, pv, sv = req.sampling
-                host += (np.asarray([tv], np.float32),
-                         np.asarray([kv], np.int32),
-                         np.asarray([pv], np.float32),
-                         np.asarray([sv], np.int32),
-                         np.asarray([len(req.emitted)], np.int32))
-            faults.fire("engine.dispatch.prefill")
-            self._note_dispatch("prefill")
-            t_disp_pf = self._t_dispatch
-            # this path dispatches from INSIDE admission: its h2d, program
-            # and sync phases nest in pw.round.admit, and the request is
-            # admitted and given its one chunk at the same instant
-            req.note_admitted(t_disp_pf)
-            req.note_chunk(t_disp_pf)
-            tok_d, n_d, bt_d, *samp_d = self._h2d(host)
-            if req.sampling is None:
-                prog_pf = self._prefill
-                with self._phase("pw.prefill"):
-                    ids, self.pool.k, self.pool.v = prog_pf(
-                        self.params, tok_d, n_d, self.pool.k, self.pool.v,
-                        bt_d,
-                    )
-            else:
-                prog_pf = self._sampled_programs()["prefill"]
-                with self._phase("pw.prefill_sampled"):
-                    ids, self.pool.k, self.pool.v = prog_pf(
-                        self.params, tok_d, n_d, self.pool.k, self.pool.v,
-                        bt_d, *samp_d,
-                    )
-            # the sync stays INSIDE the failure cleanup: a hung/failed
-            # sync (watchdog) with no restart budget must not leak the
-            # just-prefilled blocks for the engine's lifetime
-            first_id = int(self._sync_host(ids)[0])
-            self._record_dispatch(prog_pf, t_disp_pf,
-                                  time.perf_counter(), items=n)
-            if self.prefix is not None:
-                # zip inside insert() truncates to the full-block keys, so
-                # a partial tail block (the live decode-write target) is
-                # never registered
-                self.prefix.insert(keys, state.block_ids)
-        except BaseException:
-            # the sequence is not yet in `running`, so _run_loop's failure
-            # cleanup cannot see it — free here or its blocks leak for the
-            # engine's (process-long) lifetime
-            self.pool.free_sequence(seq_id)
-            raise
-        self._note_sync()
-        self._emit(req, first_id)
-        act = _Active(seq_id, req)
-        if self._is_done(req, seq_id):
-            self.pool.free_sequence(seq_id)
-            deliver(req)
-            return "done"
+        # prefix-shared leading blocks need no recompute: their K/V
+        # is already (or will be, gated on the writer) resident, so
+        # chunking starts after them, but at least the prompt's LAST
+        # token must run to produce the next-token logits
+        shared_tokens = len(shared) * self.pool.block_size
+        act.n_filled = min(shared_tokens, n - 1)
+        act.n_diverted = shared_tokens
+        act.wait_writer = writer
+        # cache registration happens only when the last chunk lands
+        # (K/V written); until then our OWN unshared full blocks go
+        # into the in-flight map so same-round arrivals can share
+        # them under the progress gate
+        act.prefix_keys = keys
+        if self.prefix is not None:
+            for key, blk in zip(keys[len(shared):],
+                                state.block_ids[len(shared):len(keys)]):
+                self._inflight_prefix.setdefault(key, (act, blk))
+        req.note_admitted(time.perf_counter())
         running.append(act)
         return "admitted"
 
     def _release_seq(self, act: _Active) -> None:
         """Completion-time release of a finished sequence's blocks.  A
-        session-tagged request (chunked mode, session_store attached)
+        session-tagged request (session_store attached)
         SUSPENDS instead: its context K/V — the admitted tokens plus
         every emitted-and-fed-back token — is copied to the host tier so
         the session's next turn resumes by re-scatter rather than
@@ -2322,9 +2131,8 @@ class PagedDecodeEngine:
             blocks = np.asarray(seq.block_ids, np.int32)
             # prefix-shared leading blocks already hold the right K/V:
             # divert their writes to the null block — a live sequence may
-            # be attending through them right now (same rule as the
-            # legacy whole-bucket scatter); the gather still READS the
-            # shared blocks' resident bytes through the table
+            # be attending through them right now; the gather still READS
+            # the shared blocks' resident bytes through the table
             sb[t:t + nv] = np.where(pos < act.n_diverted, 0,
                                     blocks[pos // bs])
             so[t:t + nv] = pos % bs

@@ -76,8 +76,7 @@ class _SessionEntry:
     def __init__(self, session_id, tokens, payload, nbytes):
         self.session_id = session_id
         self.tokens = tokens  # the context tokens the stored state covers
-        # backend-opaque host state (paged: padded K/V block gathers;
-        # state backend: one fixed-size recurrent-state array)
+        # backend-opaque host state (paged: padded K/V block gathers)
         self.payload = payload
         # the REAL host buffer size, padding included — Round-16 fix:
         # charging the logical block bytes of a padded gather's view

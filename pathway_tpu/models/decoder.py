@@ -441,8 +441,7 @@ def forward_logits(params: dict, cfg: DecoderConfig, token_ids: jax.Array) -> ja
 
 
 def prefill(params: dict, cfg: DecoderConfig, token_ids: jax.Array,
-            n_valid: jax.Array, *, flash: bool | None = None,
-            tp_axis: str | None = None, head_fn=None):
+            n_valid: jax.Array, *, flash: bool | None = None):
     """Full-context forward over the (padded) prompt, emitting the KV cache
     and the logits at position n_valid-1 (the next-token distribution).
 
@@ -459,7 +458,7 @@ def prefill(params: dict, cfg: DecoderConfig, token_ids: jax.Array,
     hd = cfg.d_model // cfg.n_heads
     if flash is None:
         flash = jax.default_backend() == "tpu" and T >= 256
-    x = _embed_rows(params["embed"].astype(dtype), token_ids, tp_axis)
+    x = params["embed"].astype(dtype)[token_ids]
     x = x + params["pos_embed"].astype(dtype)[:T][None, :, :]
     eps = cfg.ln_eps
     act = _act_fn(cfg)
@@ -483,18 +482,15 @@ def prefill(params: dict, cfg: DecoderConfig, token_ids: jax.Array,
                 scores.astype(jnp.float32), axis=-1
             ).astype(h.dtype)
             a = jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(B, T, -1)
-        x = x + _row_proj(layer, a, "wo", "bo", tp_axis)
+        x = x + _proj_p(layer, a, "wo", "bo")
         h = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"], eps)
         ff = act(_proj_p(layer, h, "w_up", "b_up"))
-        x = x + _row_proj(layer, ff, "w_down", "b_down", tp_axis)
+        x = x + _proj_p(layer, ff, "w_down", "b_down")
     x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"], eps)
     last = jnp.take_along_axis(
         x, (n_valid - 1)[:, None, None].astype(jnp.int32), axis=1
     )[:, 0, :]
-    out = (_head_out if head_fn is None else head_fn)(
-        _head_weight(params), last, tp_axis
-    )
-    return out, cache
+    return _head_logits(_head_weight(params), last), cache
 
 
 def decode_step(params: dict, cfg: DecoderConfig, cache: list[dict],
@@ -535,38 +531,6 @@ def decode_step(params: dict, cfg: DecoderConfig, cache: list[dict],
     x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"], eps)
     logits = (x[:, 0, :] @ params["embed"].astype(x.dtype).T).astype(jnp.float32)
     return logits, new_cache
-
-
-def paged_prefill(params: dict, cfg: DecoderConfig, token_ids: jax.Array,
-                  n_valid: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                  block_tables: jax.Array, *, flash: bool | None = None,
-                  tp_axis: str | None = None, head_fn=None):
-    """Prefill through the paged KV cache (kvcache/block_pool.py).
-
-    Runs the exact dense :func:`prefill` (so prompt logits are bit-identical
-    to the batch-1 path), then scatters the per-layer K/V into the pool
-    blocks named by ``block_tables``.
-
-    token_ids: (B, T) with T a multiple of the pool block size;
-    k_pool/v_pool: (n_layers, num_blocks, block_size, H * hd) donated pool
-    arrays (BlockPool's shape); block_tables: (B, T // block_size) int32 —
-    rows padded with the null block 0, whose garbage contents are never
-    attended to (masked by context length) and are overwritten slot-by-slot
-    as decoding proceeds.
-    Returns ``(logits, k_pool, v_pool)``.
-    """
-    logits, cache = prefill(params, cfg, token_ids, n_valid, flash=flash,
-                            tp_axis=tp_axis, head_fn=head_fn)
-    B, T = token_ids.shape
-    BS = k_pool.shape[2]
-    nb = T // BS
-    k_new = jnp.stack([c["k"] for c in cache])  # (L, B, T, H[/tp], hd)
-    v_new = jnp.stack([c["v"] for c in cache])
-    k_blocks = k_new.reshape(cfg.n_layers, B, nb, BS, -1)
-    v_blocks = v_new.reshape(cfg.n_layers, B, nb, BS, -1)
-    k_pool = k_pool.at[:, block_tables].set(k_blocks)
-    v_pool = v_pool.at[:, block_tables].set(v_blocks)
-    return logits, k_pool, v_pool
 
 
 def paged_decode_step(params: dict, cfg: DecoderConfig, k_pool: jax.Array,
@@ -773,8 +737,8 @@ def paged_chained_decode(params: dict, cfg: DecoderConfig, k_pool: jax.Array,
                          positions: jax.Array, block_tables: jax.Array,
                          slot_blocks: jax.Array, slot_offsets: jax.Array, *,
                          attn: str = "reference",
-                         tp_axis: str | None = None):
-    """K greedy decode steps in ONE device program (Round-10).
+                         tp_axis: str | None = None, head_at=None):
+    """K decode steps in ONE device program (Round-10).
 
     :func:`paged_decode_step` is the loop BODY: a ``lax.scan`` feeds step
     t's argmaxed ids into step t+1 and scatters each step's K/V into the
@@ -800,6 +764,10 @@ def paged_chained_decode(params: dict, cfg: DecoderConfig, k_pool: jax.Array,
     scatter lands before step t+1's gather reads it (scan order), the
     per-step math is :func:`paged_decode_step` itself, and greedy
     sampling is the same argmax (two-stage under tp, see _head_out).
+
+    ``head_at(t)`` (sampled rows) gives step t's ``head_fn``: the keys of
+    a row's n-th emitted token depend only on (seed, n), however budgets,
+    preemption, restart or failover cut the chain (_row_sample_keys).
     """
     K = slot_blocks.shape[1]
     maxp = cfg.max_len - 1
@@ -814,8 +782,9 @@ def paged_chained_decode(params: dict, cfg: DecoderConfig, k_pool: jax.Array,
         out, kp, vp = paged_decode_step(
             params, cfg, kp, vp, tok, pos, block_tables, sb, so,
             attn=attn, tp_axis=tp_axis,
+            head_fn=None if head_at is None else head_at(t),
         )
-        ids = out if tp_axis is not None \
+        ids = out if tp_axis is not None or head_at is not None \
             else jnp.argmax(out, axis=-1).astype(jnp.int32)
         return (ids, kp, vp), ids
 
@@ -824,6 +793,27 @@ def paged_chained_decode(params: dict, cfg: DecoderConfig, k_pool: jax.Array,
         (slot_blocks.T, slot_offsets.T, jnp.arange(K, dtype=jnp.int32)),
     )
     return ids.T, k_pool, v_pool  # (B, K)
+
+
+def _tp_shard_map(fn, mesh, params, k_pool, v_pool, *host):
+    """Run a paged step program over ``mesh``'s tp axis (Round-9): params
+    by decoder rules (QKV column-parallel, output projections row-parallel
+    with one psum), the two K/V pools on the head axis, every host-built
+    array after them replicated; gives (replicated ids, *sharded pools).
+    ``params``/pools must be laid out by
+    ``parallel.mesh.shard_decoder_params`` / ``kv_pool_sharding``."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.mesh import KV_POOL_PSPEC, decoder_param_specs
+
+    pools = (KV_POOL_PSPEC, KV_POOL_PSPEC)
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=(decoder_param_specs(params),) + pools
+        + (P(),) * len(host),
+        out_specs=(P(),) + pools,
+        check_vma=False,
+    )(params, k_pool, v_pool, *host)
 
 
 # -- draft-model proposals (Round-18 speculative decoding) -------------------
@@ -865,788 +855,6 @@ def draft_propose(params: dict, cfg: DecoderConfig, token_ids: jax.Array,
         jnp.arange(k, dtype=jnp.int32),
     )
     return ids.T  # (B, k)
-
-
-# -- sampled program variants (Round-15) -------------------------------------
-#
-# Each wraps its greedy twin with the sampling head; the step math (and
-# therefore the logits, and therefore the greedy rows' output) is shared
-# code, not a copy.  The engine builds these as SEPARATE jitted programs
-# (pw.*_sampled) lazily, so greedy-only workloads never compile them.
-
-
-def paged_decode_step_sampled(params: dict, cfg: DecoderConfig,
-                              k_pool: jax.Array, v_pool: jax.Array,
-                              token: jax.Array, positions: jax.Array,
-                              block_tables: jax.Array, slot_blocks: jax.Array,
-                              slot_offsets: jax.Array, temperature: jax.Array,
-                              top_k: jax.Array, top_p: jax.Array,
-                              seed: jax.Array, emit_idx: jax.Array, *,
-                              attn: str = "reference",
-                              tp_axis: str | None = None):
-    """:func:`paged_decode_step` with per-row sampling: extra (B,) arrays
-    temperature (f32), top_k (int32, <=0 disables), top_p (f32, 1.0
-    disables), seed (int32, the request's fixed seed) and emit_idx (int32,
-    the absolute index of the token this step emits for the row).  Returns
-    ``(ids, k_pool, v_pool)`` with ids (B,) int32 in BOTH the single-device
-    and tp forms (logits never leave the program)."""
-    head = _sampling_head(temperature, top_k, top_p,
-                          _row_sample_keys(seed, emit_idx))
-    return paged_decode_step(
-        params, cfg, k_pool, v_pool, token, positions, block_tables,
-        slot_blocks, slot_offsets, attn=attn, tp_axis=tp_axis, head_fn=head,
-    )
-
-
-def paged_mixed_step_sampled(params: dict, cfg: DecoderConfig,
-                             k_pool: jax.Array, v_pool: jax.Array,
-                             tokens: jax.Array, positions: jax.Array,
-                             row_tables: jax.Array, row_start: jax.Array,
-                             row_nvalid: jax.Array, row_token_idx: jax.Array,
-                             tok_row: jax.Array, tok_col: jax.Array,
-                             slot_blocks: jax.Array, slot_offsets: jax.Array,
-                             logit_idx: jax.Array, temperature: jax.Array,
-                             top_k: jax.Array, top_p: jax.Array,
-                             seed: jax.Array, emit_idx: jax.Array, *,
-                             attn: str = "reference",
-                             tp_axis: str | None = None):
-    """:func:`paged_mixed_step` with per-row sampling (see
-    :func:`paged_decode_step_sampled` for the extra arrays; mid-prefill
-    rows' sampled ids are garbage the engine ignores, exactly like their
-    greedy logits).  Returns ``(ids, k_pool, v_pool)``, ids (B,) int32."""
-    head = _sampling_head(temperature, top_k, top_p,
-                          _row_sample_keys(seed, emit_idx))
-    return paged_mixed_step(
-        params, cfg, k_pool, v_pool, tokens, positions, row_tables,
-        row_start, row_nvalid, row_token_idx, tok_row, tok_col, slot_blocks,
-        slot_offsets, logit_idx, attn=attn, tp_axis=tp_axis, head_fn=head,
-    )
-
-
-def paged_chained_decode_sampled(params: dict, cfg: DecoderConfig,
-                                 k_pool: jax.Array, v_pool: jax.Array,
-                                 token: jax.Array, positions: jax.Array,
-                                 block_tables: jax.Array,
-                                 slot_blocks: jax.Array,
-                                 slot_offsets: jax.Array,
-                                 temperature: jax.Array, top_k: jax.Array,
-                                 top_p: jax.Array, seed: jax.Array,
-                                 emit0: jax.Array, *,
-                                 attn: str = "reference",
-                                 tp_axis: str | None = None):
-    """:func:`paged_chained_decode` with per-row sampling carried through
-    the scan: the per-row seed-derived base keys ride the scan CARRY
-    (device-resident for the whole chain, like the token ids), and step t
-    folds them with ``emit0 + t`` — so the noise for a row's n-th emitted
-    token depends only on (seed, n) regardless of how the chain was cut by
-    budgets, preemption, restart or failover.  ``emit0``: (B,) int32, the
-    absolute emit index of each row's step-0 token."""
-    K = slot_blocks.shape[1]
-    maxp = cfg.max_len - 1
-    base_keys = jax.vmap(
-        lambda s: jax.random.fold_in(jax.random.PRNGKey(0), s)
-    )(seed)
-
-    def body(carry, xs):
-        tok, kp, vp, keys = carry
-        sb, so, t = xs
-        pos = jnp.minimum(positions + t, maxp)
-        step_keys = jax.vmap(jax.random.fold_in)(keys, emit0 + t)
-        head = _sampling_head(temperature, top_k, top_p, step_keys)
-        ids, kp, vp = paged_decode_step(
-            params, cfg, kp, vp, tok, pos, block_tables, sb, so,
-            attn=attn, tp_axis=tp_axis, head_fn=head,
-        )
-        return (ids, kp, vp, keys), ids
-
-    (_last, k_pool, v_pool, _keys), ids = jax.lax.scan(
-        body, (token.astype(jnp.int32), k_pool, v_pool, base_keys),
-        (slot_blocks.T, slot_offsets.T, jnp.arange(K, dtype=jnp.int32)),
-    )
-    return ids.T, k_pool, v_pool  # (B, K)
-
-
-def paged_prefill_sampled(params: dict, cfg: DecoderConfig,
-                          token_ids: jax.Array, n_valid: jax.Array,
-                          k_pool: jax.Array, v_pool: jax.Array,
-                          block_tables: jax.Array, temperature: jax.Array,
-                          top_k: jax.Array, top_p: jax.Array,
-                          seed: jax.Array, emit_idx: jax.Array, *,
-                          flash: bool | None = None,
-                          tp_axis: str | None = None):
-    """:func:`paged_prefill` with first-token sampling fused in.
-    ``emit_idx`` is 0 for a fresh prompt but NOT after preemption or
-    restart re-admission, where the recompute prefill covers
-    prompt + emitted and its next token is emit index len(emitted).
-    Returns ``(ids, k_pool, v_pool)``, ids (B,) int32."""
-    head = _sampling_head(
-        temperature, top_k, top_p, _row_sample_keys(seed, emit_idx)
-    )
-    return paged_prefill(
-        params, cfg, token_ids, n_valid, k_pool, v_pool, block_tables,
-        flash=flash, tp_axis=tp_axis, head_fn=head,
-    )
-
-
-# -- shard_map wrappers: the tensor-parallel serving path (Round-9) ----------
-
-
-def _tp_shard_map(fn, mesh, params, n_pool: int, n_rep: int):
-    """shard_map a paged step: params by decoder rules, ``n_pool`` K/V pool
-    arrays on the head axis, ``n_rep`` replicated host-built index arrays;
-    outputs are (replicated sampled ids, *sharded pools)."""
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel.mesh import KV_POOL_PSPEC, decoder_param_specs
-
-    return jax.shard_map(
-        fn, mesh=mesh,
-        in_specs=(decoder_param_specs(params),)
-        + (KV_POOL_PSPEC,) * n_pool + (P(),) * n_rep,
-        out_specs=(P(),) + (KV_POOL_PSPEC,) * n_pool,
-        check_vma=False,
-    )
-
-
-def paged_decode_step_tp(params: dict, cfg: DecoderConfig, mesh,
-                         k_pool: jax.Array, v_pool: jax.Array,
-                         token: jax.Array, positions: jax.Array,
-                         block_tables: jax.Array, slot_blocks: jax.Array,
-                         slot_offsets: jax.Array, *,
-                         attn: str = "reference"):
-    """:func:`paged_decode_step` sharded over ``mesh``'s tp axis: each
-    shard scatters/gathers its n_kv_heads/tp slice of the pool and runs
-    the same ragged attention on fewer heads; QKV is column-parallel, the
-    output projection row-parallel with one psum, and greedy sampling is
-    fused into the sharded vocab head (an exact two-stage argmax — see
-    :func:`_head_out`), so the first return value is the (B,) int32
-    sampled ids, NOT logits: the full [B, vocab] array never exists on
-    any device.  ``params``/pools must be laid out by
-    ``parallel.mesh.shard_decoder_params`` / ``kv_pool_sharding``."""
-
-    def fn(p, k_pool, v_pool, token, positions, bt, sb, so):
-        return paged_decode_step(
-            p, cfg, k_pool, v_pool, token, positions, bt, sb, so,
-            attn=attn, tp_axis="tp",
-        )
-
-    return _tp_shard_map(fn, mesh, params, 2, 5)(
-        params, k_pool, v_pool, token, positions, block_tables,
-        slot_blocks, slot_offsets,
-    )
-
-
-def paged_mixed_step_tp(params: dict, cfg: DecoderConfig, mesh,
-                        k_pool: jax.Array, v_pool: jax.Array,
-                        tokens: jax.Array, positions: jax.Array,
-                        row_tables: jax.Array, row_start: jax.Array,
-                        row_nvalid: jax.Array, row_token_idx: jax.Array,
-                        tok_row: jax.Array, tok_col: jax.Array,
-                        slot_blocks: jax.Array, slot_offsets: jax.Array,
-                        logit_idx: jax.Array, *, attn: str = "reference"):
-    """:func:`paged_mixed_step` over the tp mesh — same collective
-    placement as :func:`paged_decode_step_tp` (the packed FFN/projection
-    stream is column/row-parallel, attention per shard on its heads)."""
-
-    def fn(p, k_pool, v_pool, *rest):
-        return paged_mixed_step(
-            p, cfg, k_pool, v_pool, *rest, attn=attn, tp_axis="tp"
-        )
-
-    return _tp_shard_map(fn, mesh, params, 2, 11)(
-        params, k_pool, v_pool, tokens, positions, row_tables, row_start,
-        row_nvalid, row_token_idx, tok_row, tok_col, slot_blocks,
-        slot_offsets, logit_idx,
-    )
-
-
-def paged_chained_decode_tp(params: dict, cfg: DecoderConfig, mesh,
-                            k_pool: jax.Array, v_pool: jax.Array,
-                            token: jax.Array, positions: jax.Array,
-                            block_tables: jax.Array, slot_blocks: jax.Array,
-                            slot_offsets: jax.Array, *,
-                            attn: str = "reference"):
-    """:func:`paged_chained_decode` over the tp mesh.  The chain adds
-    ZERO collectives beyond the per-step set: the scan runs per shard
-    (each shard chains its own n_kv_heads/tp pool slice), and the only
-    cross-shard traffic per step is the existing one-psum-per-row-
-    parallel-projection plus the two-stage argmax — whose (B,) ids ARE
-    the replicated scan carry every shard feeds its next step."""
-
-    def fn(p, k_pool, v_pool, token, positions, bt, sb, so):
-        return paged_chained_decode(
-            p, cfg, k_pool, v_pool, token, positions, bt, sb, so,
-            attn=attn, tp_axis="tp",
-        )
-
-    return _tp_shard_map(fn, mesh, params, 2, 5)(
-        params, k_pool, v_pool, token, positions, block_tables,
-        slot_blocks, slot_offsets,
-    )
-
-
-def paged_prefill_tp(params: dict, cfg: DecoderConfig, mesh,
-                     token_ids: jax.Array, n_valid: jax.Array,
-                     k_pool: jax.Array, v_pool: jax.Array,
-                     block_tables: jax.Array, *, flash: bool | None = None):
-    """:func:`paged_prefill` over the tp mesh: the dense prefill runs with
-    per-shard heads (same kernel, fewer heads) and each shard scatters its
-    own K/V slice into its pool shard."""
-
-    def fn(p, k_pool, v_pool, token_ids, n_valid, bt):
-        return paged_prefill(
-            p, cfg, token_ids, n_valid, k_pool, v_pool, bt,
-            flash=flash, tp_axis="tp",
-        )
-
-    return _tp_shard_map(fn, mesh, params, 2, 3)(
-        params, k_pool, v_pool, token_ids, n_valid, block_tables,
-    )
-
-
-def paged_decode_step_sampled_tp(params: dict, cfg: DecoderConfig, mesh,
-                                 k_pool: jax.Array, v_pool: jax.Array,
-                                 token: jax.Array, positions: jax.Array,
-                                 block_tables: jax.Array,
-                                 slot_blocks: jax.Array,
-                                 slot_offsets: jax.Array,
-                                 temperature: jax.Array, top_k: jax.Array,
-                                 top_p: jax.Array, seed: jax.Array,
-                                 emit_idx: jax.Array, *,
-                                 attn: str = "reference"):
-    """:func:`paged_decode_step_sampled` over the tp mesh — the sampling
-    arrays ride as replicated inputs; the head all_gathers the sharded
-    logits row (see :func:`_sampling_head`) and the sampled (B,) ids are
-    identical on every shard, matching the replicated out_spec."""
-
-    def fn(p, k_pool, v_pool, *rest):
-        return paged_decode_step_sampled(
-            p, cfg, k_pool, v_pool, *rest, attn=attn, tp_axis="tp"
-        )
-
-    return _tp_shard_map(fn, mesh, params, 2, 10)(
-        params, k_pool, v_pool, token, positions, block_tables,
-        slot_blocks, slot_offsets, temperature, top_k, top_p, seed, emit_idx,
-    )
-
-
-def paged_mixed_step_sampled_tp(params: dict, cfg: DecoderConfig, mesh,
-                                k_pool: jax.Array, v_pool: jax.Array,
-                                tokens: jax.Array, positions: jax.Array,
-                                row_tables: jax.Array, row_start: jax.Array,
-                                row_nvalid: jax.Array,
-                                row_token_idx: jax.Array, tok_row: jax.Array,
-                                tok_col: jax.Array, slot_blocks: jax.Array,
-                                slot_offsets: jax.Array, logit_idx: jax.Array,
-                                temperature: jax.Array, top_k: jax.Array,
-                                top_p: jax.Array, seed: jax.Array,
-                                emit_idx: jax.Array, *,
-                                attn: str = "reference"):
-    """:func:`paged_mixed_step_sampled` over the tp mesh."""
-
-    def fn(p, k_pool, v_pool, *rest):
-        return paged_mixed_step_sampled(
-            p, cfg, k_pool, v_pool, *rest, attn=attn, tp_axis="tp"
-        )
-
-    return _tp_shard_map(fn, mesh, params, 2, 16)(
-        params, k_pool, v_pool, tokens, positions, row_tables, row_start,
-        row_nvalid, row_token_idx, tok_row, tok_col, slot_blocks,
-        slot_offsets, logit_idx, temperature, top_k, top_p, seed, emit_idx,
-    )
-
-
-def paged_chained_decode_sampled_tp(params: dict, cfg: DecoderConfig, mesh,
-                                    k_pool: jax.Array, v_pool: jax.Array,
-                                    token: jax.Array, positions: jax.Array,
-                                    block_tables: jax.Array,
-                                    slot_blocks: jax.Array,
-                                    slot_offsets: jax.Array,
-                                    temperature: jax.Array, top_k: jax.Array,
-                                    top_p: jax.Array, seed: jax.Array,
-                                    emit0: jax.Array, *,
-                                    attn: str = "reference"):
-    """:func:`paged_chained_decode_sampled` over the tp mesh — the scan
-    runs per shard with the replicated sampled ids as carry, exactly like
-    the greedy chain; the per-step logits gather is the only added
-    collective."""
-
-    def fn(p, k_pool, v_pool, *rest):
-        return paged_chained_decode_sampled(
-            p, cfg, k_pool, v_pool, *rest, attn=attn, tp_axis="tp"
-        )
-
-    return _tp_shard_map(fn, mesh, params, 2, 10)(
-        params, k_pool, v_pool, token, positions, block_tables,
-        slot_blocks, slot_offsets, temperature, top_k, top_p, seed, emit0,
-    )
-
-
-def paged_prefill_sampled_tp(params: dict, cfg: DecoderConfig, mesh,
-                             token_ids: jax.Array, n_valid: jax.Array,
-                             k_pool: jax.Array, v_pool: jax.Array,
-                             block_tables: jax.Array, temperature: jax.Array,
-                             top_k: jax.Array, top_p: jax.Array,
-                             seed: jax.Array, emit_idx: jax.Array, *,
-                             flash: bool | None = None):
-    """:func:`paged_prefill_sampled` over the tp mesh."""
-
-    def fn(p, k_pool, v_pool, token_ids, n_valid, bt, temperature, top_k,
-           top_p, seed, emit_idx):
-        return paged_prefill_sampled(
-            p, cfg, token_ids, n_valid, k_pool, v_pool, bt, temperature,
-            top_k, top_p, seed, emit_idx, flash=flash, tp_axis="tp",
-        )
-
-    return _tp_shard_map(fn, mesh, params, 2, 8)(
-        params, k_pool, v_pool, token_ids, n_valid, block_tables,
-        temperature, top_k, top_p, seed, emit_idx,
-    )
-
-
-# -- SSD / gated linear-attention decoder (Round-16) -------------------------
-#
-# A second model family whose per-sequence decode state is a FIXED-SIZE
-# tensor instead of a growing KV span ("Compiler-First State Space
-# Duality and Portable O(1) Autoregressive Caching", arxiv 2603.09555).
-# Each attention block is replaced by a gated linear-attention / SSD
-# recurrence over per-head matrix states S in R^{hd x hd}:
-#
-#     a_t = exp(-softplus(x_t @ w_a + b_a))        per-head decay in (0,1)
-#     S_t = a_t * S_{t-1} + k_t^T v_t
-#     y_t = (q_t / sqrt(hd)) . S_t
-#
-# The SAME math runs in two forms — the state-space duality:
-#
-# - CHUNK-PARALLEL (prefill): a C-token chunk computes all its outputs
-#   with masked matmuls over cumulative log-decays plus one inter-chunk
-#   term against the carried state, then folds the chunk into the state
-#   in closed form.  Prompts stream through fixed-width chunks exactly
-#   like the paged engine's chunked prefill.
-# - RECURRENT (decode): one token updates the state in O(hd^2) per head
-#   — constant memory, constant latency, no context-length term at all.
-#
-# Everything else — embeddings, layer norms, Megatron column/row
-# projections with one psum, the two-stage argmax vocab head, the
-# (seed, emit-index) sampling key schedule — is shared with the paged
-# path, so tp sharding and token-identity guarantees carry over.  The
-# SSD path uses NO positional embedding: order is encoded by the decay
-# recurrence itself, which is what makes the state a complete,
-# fixed-size summary (suspend/resume copies ONE array per layer).
-#
-# The recurrent state is stored in a stacked per-shard array
-# [n_layers, max_slots, n_heads(/tp), hd, hd] managed by
-# kvcache/statecache.py; slot 0 is the designated garbage sink for
-# padding rows, mirroring the paged pool's null block.
-
-
-def ssd_augment_params(params: dict, cfg: DecoderConfig,
-                       seed: int = 0) -> dict:
-    """Graft per-layer SSD decay projections (``w_a``: (D, H), ``b_a``:
-    (H,)) onto an existing dense decoder pytree — every other weight
-    (embed, QKV, output/FFN projections, layer norms) is reused as-is,
-    so one checkpoint serves both the paged-attention and SSD engines.
-    ``b_a`` spreads head decay rates from slow (~0.95/token) to fast
-    (~0.27/token); ``w_a`` adds small input-dependent gating."""
-    rng = jax.random.PRNGKey(seed)
-    D, H = cfg.d_model, cfg.n_heads
-    out = dict(params)
-    layers = []
-    for layer in params["layers"]:
-        rng, sub = jax.random.split(rng)
-        new = dict(layer)
-        new["w_a"] = (0.02 * jax.random.normal(sub, (D, H))).astype(
-            jnp.float32
-        )
-        new["b_a"] = jnp.linspace(-3.0, 1.0, H, dtype=jnp.float32)
-        layers.append(new)
-    out["layers"] = layers
-    return out
-
-
-def _ssd_decay(layer, h, valid=None):
-    """Per-head log decay ``log a = -softplus(h @ w_a + b_a)`` <= 0.
-    ``valid`` masks padding tokens to log a = 0 (a = 1): an invalid
-    token neither decays nor feeds the state, so a partially filled
-    tail chunk folds exactly like its valid prefix alone."""
-    la = -jax.nn.softplus(
-        h @ layer["w_a"].astype(h.dtype) + layer["b_a"].astype(h.dtype)
-    )
-    if valid is not None:
-        la = la * valid[..., None].astype(la.dtype)
-    return la
-
-
-def _ssd_layer_chunk(layer, h, s0, hd: int, valid):
-    """Chunk-parallel (duality) form over one C-token chunk.
-
-    h: (B, C, D) post-ln stream; s0: (B, H, hd, hd) carried state;
-    valid: (B, C) bool.  Returns ``(y, s1)`` with y (B, C, H, hd).
-
-    Intra-chunk outputs use the masked decay matrix
-    ``W[t, s] = exp(L_t - L_s)`` (s <= t, L the inclusive cumulative
-    log decay); the carried state contributes ``exp(L_t) * q_t . s0``;
-    the chunk folds into ``s1 = exp(L_C) s0 + sum_s exp(L_C - L_s)
-    k_s^T v_s``.  Padding tokens carry log a = 0 and k = 0, so they are
-    exact no-ops on both outputs and state."""
-    from .encoder import _proj
-
-    B, C, _D = h.shape
-    q = _proj(layer, h, "wq", "bq").reshape(B, C, -1, hd) / np.sqrt(hd)
-    k = _proj(layer, h, "wk", "bk").reshape(B, C, -1, hd)
-    v = _proj(layer, h, "wv", "bv").reshape(B, C, -1, hd)
-    k = jnp.where(valid[:, :, None, None], k, 0)
-    la = _ssd_decay(layer, h, valid)           # (B, C, H)
-    lc = jnp.cumsum(la, axis=1)                # inclusive: L_t
-    dec = lc[:, :, None, :] - lc[:, None, :, :]  # (B, t, s, H)
-    causal = jnp.tril(jnp.ones((C, C), bool))
-    w = jnp.where(causal[None, :, :, None], jnp.exp(dec), 0).astype(h.dtype)
-    att = jnp.einsum("bthd,bshd->btsh", q, k)
-    y = jnp.einsum("btsh,bshd->bthd", att * w, v)
-    y = y + jnp.exp(lc)[..., None].astype(h.dtype) * jnp.einsum(
-        "bthd,bhde->bthe", q, s0
-    )
-    w_fold = jnp.exp(lc[:, -1:, :] - lc).astype(h.dtype)  # (B, C, H)
-    s1 = jnp.exp(lc[:, -1])[..., None, None].astype(h.dtype) * s0 \
-        + jnp.einsum("bsh,bshd,bshe->bhde", w_fold, k, v)
-    return y, s1
-
-
-def _ssd_layer_step(layer, h, s0, hd: int):
-    """Recurrent form: one token, O(hd^2) per head, no context term.
-    h: (B, D); s0: (B, H, hd, hd).  Returns ``(y, s1)``, y (B, H, hd).
-    Equals the C=1 chunk form exactly (same einsums, no mask)."""
-    from .encoder import _proj
-
-    B = h.shape[0]
-    q = _proj(layer, h, "wq", "bq").reshape(B, -1, hd) / np.sqrt(hd)
-    k = _proj(layer, h, "wk", "bk").reshape(B, -1, hd)
-    v = _proj(layer, h, "wv", "bv").reshape(B, -1, hd)
-    a = jnp.exp(_ssd_decay(layer, h))          # (B, H)
-    s1 = a[..., None, None].astype(h.dtype) * s0 \
-        + jnp.einsum("bhd,bhe->bhde", k, v)
-    y = jnp.einsum("bhd,bhde->bhe", q, s1)
-    return y, s1
-
-
-def _ssd_forward_step(params: dict, cfg: DecoderConfig, s, token,
-                      tp_axis, head_fn):
-    """One recurrent token through every layer.  ``s``: the gathered
-    per-row state stack (L, B, H[/tp], hd, hd) — device-resident carry
-    in the chained scan.  Returns ``(out, s_new)``."""
-    dtype = _resolve_dtype(cfg.dtype)
-    from .encoder import _proj
-
-    B = token.shape[0]
-    hd = cfg.d_model // cfg.n_heads
-    eps = cfg.ln_eps
-    act = _act_fn(cfg)
-    x = _embed_rows(params["embed"].astype(dtype), token, tp_axis)  # (B, D)
-    new = []
-    for li, layer in enumerate(params["layers"]):
-        h = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"], eps)
-        y, s1 = _ssd_layer_step(layer, h, s[li], hd)
-        x = x + _row_proj(layer, y.reshape(B, -1), "wo", "bo", tp_axis)
-        h = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"], eps)
-        ff = act(_proj(layer, h, "w_up", "b_up"))
-        x = x + _row_proj(layer, ff, "w_down", "b_down", tp_axis)
-        new.append(s1)
-    x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"], eps)
-    out = (_head_out if head_fn is None else head_fn)(
-        params["embed"], x, tp_axis
-    )
-    return out, jnp.stack(new)
-
-
-def ssd_mixed_step(params: dict, cfg: DecoderConfig, state: jax.Array,
-                   tokens: jax.Array, n_valid: jax.Array,
-                   row_slots: jax.Array, *, tp_axis: str | None = None,
-                   head_fn=None):
-    """One chunk-parallel SSD step over a batch of token RUNS — the
-    state engine's mixed prefill+decode program (chunked prefill
-    streams through the same per-round token budget as the paged
-    engine's ragged step; a decode row is simply a run of one token).
-
-    tokens: (B, C) int32 — each row's next C tokens, zero-padded;
-    n_valid: (B,) int32 — valid tokens per row (0 = idle padding row:
-    an exact no-op on its slot); row_slots: (B,) int32 slot ids in the
-    stacked state array (idle rows point at the null slot 0);
-    state: (L, S, H[/tp], hd, hd), donated.
-    Returns ``(out, state)`` — out is the next-token result at each
-    row's LAST valid token: (B, V) f32 logits single-device, (B,)
-    int32 greedily sampled ids under ``tp_axis`` (:func:`_head_out`),
-    or ``head_fn``'s result."""
-    dtype = _resolve_dtype(cfg.dtype)
-    from .encoder import _proj
-
-    B, C = tokens.shape
-    hd = cfg.d_model // cfg.n_heads
-    eps = cfg.ln_eps
-    act = _act_fn(cfg)
-    valid = jnp.arange(C, dtype=jnp.int32)[None, :] < n_valid[:, None]
-    x = _embed_rows(params["embed"].astype(dtype), tokens, tp_axis)
-    new = []
-    for li, layer in enumerate(params["layers"]):
-        s0 = state[li, row_slots]               # (B, H, hd, hd)
-        h = _layer_norm(x, layer["ln1_scale"], layer["ln1_bias"], eps)
-        y, s1 = _ssd_layer_chunk(layer, h, s0, hd, valid)
-        x = x + _row_proj(layer, y.reshape(B, C, -1), "wo", "bo", tp_axis)
-        h = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"], eps)
-        ff = act(_proj(layer, h, "w_up", "b_up"))
-        x = x + _row_proj(layer, ff, "w_down", "b_down", tp_axis)
-        new.append(s1)
-    # duplicate null-slot targets among idle rows are a benign race:
-    # slot 0 is the designated garbage sink, like the pool's block 0
-    state = state.at[:, row_slots].set(jnp.stack(new))
-    x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"], eps)
-    sel = jnp.take_along_axis(
-        x, jnp.maximum(n_valid - 1, 0)[:, None, None].astype(jnp.int32),
-        axis=1,
-    )[:, 0]
-    out = (_head_out if head_fn is None else head_fn)(
-        params["embed"], sel, tp_axis
-    )
-    return out, state
-
-
-def ssd_decode_step(params: dict, cfg: DecoderConfig, state: jax.Array,
-                    token: jax.Array, row_slots: jax.Array, *,
-                    tp_axis: str | None = None, head_fn=None):
-    """One batched recurrent decode token: gather each row's fixed-size
-    state, update, scatter back.  token/row_slots: (B,) int32; state
-    donated.  Returns ``(out, state)`` (out as in
-    :func:`ssd_mixed_step`)."""
-    s = state[:, row_slots]                     # (L, B, H, hd, hd)
-    out, s = _ssd_forward_step(params, cfg, s, token, tp_axis, head_fn)
-    return out, state.at[:, row_slots].set(s)
-
-
-def ssd_chained_decode(params: dict, cfg: DecoderConfig, state: jax.Array,
-                       token: jax.Array, row_slots: jax.Array,
-                       steps: jax.Array, rem: jax.Array,
-                       stop_tok: jax.Array, *,
-                       tp_axis: str | None = None):
-    """K greedy recurrent steps in ONE device program: the per-row
-    state stack rides the ``lax.scan`` carry next to the sampled ids,
-    gathered once before and scattered once after the chain — zero host
-    round trips in between, and (unlike the paged chain) zero slot
-    bookkeeping: the state neither grows nor moves.
-
-    ``steps``: (K,) int32 arange (its length is the chain length);
-    ``rem``: (B,) int32 per-row step budget; ``stop_tok``: () int32 EOS
-    id (-1 for none).  A row past its budget or EOS FREEZES in-scan:
-    its state stops updating and its id repeats — the paged chain's
-    surplus steps land in the null block, but a recurrent state has no
-    null to absorb them, so the mask is what keeps a finished row's
-    state equal to context + emitted[:-1] (the suspend-coverage rule).
-    Host-side truncation of the returned (B, K) ids is unchanged."""
-    s = state[:, row_slots]
-    B = token.shape[0]
-
-    def body(carry, t):
-        tok, s, nprod, stopped = carry
-        out, s_new = _ssd_forward_step(params, cfg, s, tok, tp_axis, None)
-        ids = out if tp_axis is not None \
-            else jnp.argmax(out, axis=-1).astype(jnp.int32)
-        active = jnp.logical_and(~stopped, nprod < rem)
-        s = jnp.where(active[None, :, None, None, None], s_new, s)
-        ids = jnp.where(active, ids, tok)
-        nprod = nprod + active.astype(jnp.int32)
-        stopped = jnp.logical_or(stopped, active & (ids == stop_tok))
-        return (ids, s, nprod, stopped), ids
-
-    init = (token.astype(jnp.int32), s, jnp.zeros(B, jnp.int32),
-            jnp.zeros(B, bool))
-    (_last, s, _np, _st), ids = jax.lax.scan(body, init, steps)
-    return ids.T, state.at[:, row_slots].set(s)
-
-
-def ssd_mixed_step_sampled(params: dict, cfg: DecoderConfig,
-                           state: jax.Array, tokens: jax.Array,
-                           n_valid: jax.Array, row_slots: jax.Array,
-                           temperature: jax.Array, top_k: jax.Array,
-                           top_p: jax.Array, seed: jax.Array,
-                           emit_idx: jax.Array, *,
-                           tp_axis: str | None = None):
-    """:func:`ssd_mixed_step` with per-row sampling (the same
-    (seed, emit-index) key schedule as the paged programs, so restart /
-    failover replay is bit-identical).  Returns ``(ids, state)``."""
-    head = _sampling_head(temperature, top_k, top_p,
-                          _row_sample_keys(seed, emit_idx))
-    return ssd_mixed_step(
-        params, cfg, state, tokens, n_valid, row_slots,
-        tp_axis=tp_axis, head_fn=head,
-    )
-
-
-def ssd_decode_step_sampled(params: dict, cfg: DecoderConfig,
-                            state: jax.Array, token: jax.Array,
-                            row_slots: jax.Array, temperature: jax.Array,
-                            top_k: jax.Array, top_p: jax.Array,
-                            seed: jax.Array, emit_idx: jax.Array, *,
-                            tp_axis: str | None = None):
-    """:func:`ssd_decode_step` with per-row sampling."""
-    head = _sampling_head(temperature, top_k, top_p,
-                          _row_sample_keys(seed, emit_idx))
-    return ssd_decode_step(
-        params, cfg, state, token, row_slots, tp_axis=tp_axis, head_fn=head,
-    )
-
-
-def ssd_chained_decode_sampled(params: dict, cfg: DecoderConfig,
-                               state: jax.Array, token: jax.Array,
-                               row_slots: jax.Array, steps: jax.Array,
-                               rem: jax.Array, stop_tok: jax.Array,
-                               temperature: jax.Array, top_k: jax.Array,
-                               top_p: jax.Array, seed: jax.Array,
-                               emit0: jax.Array, *,
-                               tp_axis: str | None = None):
-    """:func:`ssd_chained_decode` with sampling carried through the
-    scan — base keys ride the carry, step t folds ``emit0 + t``,
-    exactly the paged chained schedule (a row's active steps are a
-    prefix of the chain, so step index == tokens produced and the key
-    schedule matches K single sampled steps bit-for-bit)."""
-    s = state[:, row_slots]
-    B = token.shape[0]
-    base_keys = jax.vmap(
-        lambda sd: jax.random.fold_in(jax.random.PRNGKey(0), sd)
-    )(seed)
-
-    def body(carry, t):
-        tok, s, keys, nprod, stopped = carry
-        step_keys = jax.vmap(jax.random.fold_in)(keys, emit0 + t)
-        head = _sampling_head(temperature, top_k, top_p, step_keys)
-        ids, s_new = _ssd_forward_step(params, cfg, s, tok, tp_axis, head)
-        active = jnp.logical_and(~stopped, nprod < rem)
-        s = jnp.where(active[None, :, None, None, None], s_new, s)
-        ids = jnp.where(active, ids, tok)
-        nprod = nprod + active.astype(jnp.int32)
-        stopped = jnp.logical_or(stopped, active & (ids == stop_tok))
-        return (ids, s, keys, nprod, stopped), ids
-
-    init = (token.astype(jnp.int32), s, base_keys,
-            jnp.zeros(B, jnp.int32), jnp.zeros(B, bool))
-    (_last, s, _k, _np, _st), ids = jax.lax.scan(body, init, steps)
-    return ids.T, state.at[:, row_slots].set(s)
-
-
-def _tp_shard_map_ssd(fn, mesh, params, n_rep: int):
-    """shard_map an SSD step: params by decoder rules (w_a/b_a shard
-    with the heads), ONE state array on its head axis, ``n_rep``
-    replicated host-built arrays; outputs (replicated ids, sharded
-    state)."""
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel.mesh import SSD_STATE_PSPEC, decoder_param_specs
-
-    return jax.shard_map(
-        fn, mesh=mesh,
-        in_specs=(decoder_param_specs(params), SSD_STATE_PSPEC)
-        + (P(),) * n_rep,
-        out_specs=(P(), SSD_STATE_PSPEC),
-        check_vma=False,
-    )
-
-
-def ssd_mixed_step_tp(params: dict, cfg: DecoderConfig, mesh,
-                      state: jax.Array, tokens: jax.Array,
-                      n_valid: jax.Array, row_slots: jax.Array):
-    """:func:`ssd_mixed_step` sharded over ``mesh``'s tp axis: each
-    shard runs its n_heads/tp heads' recurrence on its slice of the
-    state array; the collective set per layer is identical to the paged
-    path (one psum per row-parallel projection, two-stage argmax
-    head)."""
-
-    def fn(p, state, tokens, n_valid, row_slots):
-        return ssd_mixed_step(
-            p, cfg, state, tokens, n_valid, row_slots, tp_axis="tp"
-        )
-
-    return _tp_shard_map_ssd(fn, mesh, params, 3)(
-        params, state, tokens, n_valid, row_slots
-    )
-
-
-def ssd_decode_step_tp(params: dict, cfg: DecoderConfig, mesh,
-                       state: jax.Array, token: jax.Array,
-                       row_slots: jax.Array):
-    """:func:`ssd_decode_step` over the tp mesh."""
-
-    def fn(p, state, token, row_slots):
-        return ssd_decode_step(p, cfg, state, token, row_slots,
-                               tp_axis="tp")
-
-    return _tp_shard_map_ssd(fn, mesh, params, 2)(
-        params, state, token, row_slots
-    )
-
-
-def ssd_chained_decode_tp(params: dict, cfg: DecoderConfig, mesh,
-                          state: jax.Array, token: jax.Array,
-                          row_slots: jax.Array, steps: jax.Array,
-                          rem: jax.Array, stop_tok: jax.Array):
-    """:func:`ssd_chained_decode` over the tp mesh — the replicated
-    (B,) ids are the scan carry on every shard, like the paged chain."""
-
-    def fn(p, state, *rest):
-        return ssd_chained_decode(p, cfg, state, *rest, tp_axis="tp")
-
-    return _tp_shard_map_ssd(fn, mesh, params, 5)(
-        params, state, token, row_slots, steps, rem, stop_tok
-    )
-
-
-def ssd_mixed_step_sampled_tp(params: dict, cfg: DecoderConfig, mesh,
-                              state: jax.Array, tokens: jax.Array,
-                              n_valid: jax.Array, row_slots: jax.Array,
-                              temperature: jax.Array, top_k: jax.Array,
-                              top_p: jax.Array, seed: jax.Array,
-                              emit_idx: jax.Array):
-    """:func:`ssd_mixed_step_sampled` over the tp mesh."""
-
-    def fn(p, state, *rest):
-        return ssd_mixed_step_sampled(p, cfg, state, *rest, tp_axis="tp")
-
-    return _tp_shard_map_ssd(fn, mesh, params, 8)(
-        params, state, tokens, n_valid, row_slots, temperature, top_k,
-        top_p, seed, emit_idx,
-    )
-
-
-def ssd_decode_step_sampled_tp(params: dict, cfg: DecoderConfig, mesh,
-                               state: jax.Array, token: jax.Array,
-                               row_slots: jax.Array,
-                               temperature: jax.Array, top_k: jax.Array,
-                               top_p: jax.Array, seed: jax.Array,
-                               emit_idx: jax.Array):
-    """:func:`ssd_decode_step_sampled` over the tp mesh."""
-
-    def fn(p, state, *rest):
-        return ssd_decode_step_sampled(p, cfg, state, *rest, tp_axis="tp")
-
-    return _tp_shard_map_ssd(fn, mesh, params, 7)(
-        params, state, token, row_slots, temperature, top_k, top_p, seed,
-        emit_idx,
-    )
-
-
-def ssd_chained_decode_sampled_tp(params: dict, cfg: DecoderConfig, mesh,
-                                  state: jax.Array, token: jax.Array,
-                                  row_slots: jax.Array, steps: jax.Array,
-                                  rem: jax.Array, stop_tok: jax.Array,
-                                  temperature: jax.Array,
-                                  top_k: jax.Array, top_p: jax.Array,
-                                  seed: jax.Array, emit0: jax.Array):
-    """:func:`ssd_chained_decode_sampled` over the tp mesh."""
-
-    def fn(p, state, *rest):
-        return ssd_chained_decode_sampled(p, cfg, state, *rest,
-                                          tp_axis="tp")
-
-    return _tp_shard_map_ssd(fn, mesh, params, 10)(
-        params, state, token, row_slots, steps, rem, stop_tok,
-        temperature, top_k, top_p, seed, emit0,
-    )
 
 
 def generate_tokens_fused(params: dict, cfg: DecoderConfig,

@@ -10,16 +10,22 @@ and gives the engine:
 - ``cache_kind`` and ``cache_kwargs(cfg, max_batch_size)``: the cache
   backend of :func:`pathway_tpu.kvcache.backend.make_backend` and its
   geometry (the K/V pool's layers and heads are the family's to say);
-- ``programs(cfg, attn, mesh)``: the greedy step programs ``step``,
-  ``mixed``, ``chained`` (and ``prefill`` where the family has a
-  whole-bucket one), each ``(params, *cache arrays, *host arrays) ->
+- ``programs(cfg, attn, mesh, sampled=False)``: the table of step
+  programs ``step``, ``mixed``, ``chained`` as ``(function, donated
+  argument numbers)``, each ``(params, *cache arrays, *host arrays) ->
   (ids, *cache arrays[, device counters])`` with the cache arrays donated;
+  ``sampled=True`` gives the table for rounds with sampled rows, whose
+  functions take five further ``(B,)`` arrays (temperature, top_k, top_p,
+  seed, emit index).  The engine jits what it is given, the verify
+  program of speculative rounds being ``mixed`` once more;
 - ``unsupported(...)``: what to refuse at construction, by name (the one
   place a family refuses); ``greedy_only``: a sampled request fails alone,
-  typed; ``tensor_parallel``: whether an unasked ``tp`` may take every
-  local chip.
+  typed, and the sampled table is never asked for; ``tensor_parallel``:
+  whether an unasked ``tp`` may take every local chip.
 
-The function names ``_step_fn`` / ``_mixed_fn`` / ``_chained_fn`` are the
+A family is the only place that names a model's programs: the engine
+imports neither models/decoder.py nor models/lfm2.py.  The function
+names ``_step_fn`` / ``_mixed_fn`` / ``_chained_fn`` are the
 device trace's (``jit__mixed_fn`` on ``XLA Modules``): the benchmark's
 readers find the programs by them, for every family alike.
 """
@@ -35,8 +41,8 @@ def _ids(logits):
 
 class DecoderFamily:
     """models/decoder.py: LayerNorm, learned positions, full multi-head
-    attention, GELU; sampled, tensor-parallel, int8 and speculative
-    variants are the engine's own."""
+    attention, GELU; greedy and sampled rows, one device or a tp mesh,
+    f32/bf16 or an int8 plan (the plan's keys say which)."""
 
     name = "decoder"
     cache_kind = "paged"
@@ -59,64 +65,79 @@ class DecoderFamily:
         return None
 
     @staticmethod
-    def programs(cfg, attn: str, mesh) -> dict:
-        # device-side sampling: every wrapper argmaxes INSIDE the jitted
-        # program, so only [B] int32 ids (not [B, vocab] logits) cross the
-        # device->host boundary per round.  Under tp the shard_map variants
-        # return ids directly (an exact two-stage argmax over the sharded
-        # vocab head, decoder._head_out)
+    def programs(cfg, attn: str, mesh, sampled: bool = False) -> dict:
+        # every program samples INSIDE the jitted program, so only [B]
+        # int32 ids (not [B, vocab] logits) cross the device->host
+        # boundary per round.  The three paged functions of
+        # models/decoder.py are the math; the two things a program adds to
+        # them are put on here, once each: the sampling head and the
+        # shard map
         from . import decoder as d
 
-        def _step_fn(p, k_pool, v_pool, token, positions, bt, sb, so):
-            if mesh is not None:
-                return d.paged_decode_step_tp(
-                    p, cfg, mesh, k_pool, v_pool, token, positions, bt,
-                    sb, so, attn=attn)
-            logits, k_pool, v_pool = d.paged_decode_step(
-                p, cfg, k_pool, v_pool, token, positions, bt, sb, so,
-                attn=attn)
-            return _ids(logits), k_pool, v_pool
+        tp_axis = None if mesh is None else "tp"
+        # one device and greedy rows: the paged function gives logits;
+        # under tp its vocab head has argmaxed already (an exact two-stage
+        # argmax over the sharded head, decoder._head_out), and a sampling
+        # head gives ids
+        logits_out = tp_axis is None and not sampled
 
-        def _mixed_fn(p, k_pool, v_pool, tokens, positions, row_tables,
-                      row_start, row_nvalid, row_token_idx, tok_row,
-                      tok_col, sb, so, logit_idx):
-            if mesh is not None:
-                return d.paged_mixed_step_tp(
-                    p, cfg, mesh, k_pool, v_pool, tokens, positions,
-                    row_tables, row_start, row_nvalid, row_token_idx,
-                    tok_row, tok_col, sb, so, logit_idx, attn=attn)
-            logits, k_pool, v_pool = d.paged_mixed_step(
-                p, cfg, k_pool, v_pool, tokens, positions, row_tables,
-                row_start, row_nvalid, row_token_idx, tok_row, tok_col,
-                sb, so, logit_idx, attn=attn)
-            return _ids(logits), k_pool, v_pool
+        def head_at(samp, t=0):
+            """The vocab head of a sampled table's rows at the ``t``-th
+            token a dispatch emits, from the five (B,) arrays a sampled
+            program takes after its greedy twin's: temperature (f32),
+            top_k (int32, <= 0 disables), top_p (f32, 1.0 disables), seed
+            (int32, the request's) and the absolute index of the token a
+            row emits first.  Temperature-0 rows take the exact argmax."""
+            if not sampled:
+                return None
+            temp, top_k, top_p, seed, emit = samp
+            return d._sampling_head(temp, top_k, top_p,
+                                    d._row_sample_keys(seed, emit + t))
 
-        def _chained_fn(p, k_pool, v_pool, token, positions, bt, sb, so):
-            if mesh is not None:
-                return d.paged_chained_decode_tp(
-                    p, cfg, mesh, k_pool, v_pool, token, positions, bt,
-                    sb, so, attn=attn)
-            return d.paged_chained_decode(
-                p, cfg, k_pool, v_pool, token, positions, bt, sb, so,
-                attn=attn)
+        def over_mesh(body, *operands):
+            if mesh is None:
+                return body(*operands)
+            return d._tp_shard_map(body, mesh, *operands)
 
-        def _prefill_fn(p, token_ids, n_valid, k_pool, v_pool, bt):
-            if mesh is not None:
-                return d.paged_prefill_tp(
-                    p, cfg, mesh, token_ids, n_valid, k_pool, v_pool, bt)
-            logits, k_pool, v_pool = d.paged_prefill(
-                p, cfg, token_ids, n_valid, k_pool, v_pool, bt)
-            return _ids(logits), k_pool, v_pool
+        def _step_fn(p, k_pool, v_pool, *host):
+            def body(p, k_pool, v_pool, token, positions, bt, sb, so, *samp):
+                out, k_pool, v_pool = d.paged_decode_step(
+                    p, cfg, k_pool, v_pool, token, positions, bt, sb, so,
+                    attn=attn, tp_axis=tp_axis, head_fn=head_at(samp))
+                return (_ids(out) if logits_out else out), k_pool, v_pool
+
+            return over_mesh(body, p, k_pool, v_pool, *host)
+
+        def _mixed_fn(p, k_pool, v_pool, *host):
+            def body(p, k_pool, v_pool, tokens, positions, row_tables,
+                     row_start, row_nvalid, row_token_idx, tok_row, tok_col,
+                     sb, so, logit_idx, *samp):
+                out, k_pool, v_pool = d.paged_mixed_step(
+                    p, cfg, k_pool, v_pool, tokens, positions, row_tables,
+                    row_start, row_nvalid, row_token_idx, tok_row, tok_col,
+                    sb, so, logit_idx, attn=attn, tp_axis=tp_axis,
+                    head_fn=head_at(samp))
+                return (_ids(out) if logits_out else out), k_pool, v_pool
+
+            return over_mesh(body, p, k_pool, v_pool, *host)
+
+        def _chained_fn(p, k_pool, v_pool, *host):
+            def body(p, k_pool, v_pool, token, positions, bt, sb, so, *samp):
+                return d.paged_chained_decode(
+                    p, cfg, k_pool, v_pool, token, positions, bt, sb, so,
+                    attn=attn, tp_axis=tp_axis,
+                    head_at=(lambda t: head_at(samp, t)) if sampled
+                    else None)
+
+            return over_mesh(body, p, k_pool, v_pool, *host)
 
         return {"step": (_step_fn, (1, 2)), "mixed": (_mixed_fn, (1, 2)),
-                "chained": (_chained_fn, (1, 2)),
-                "prefill": (_prefill_fn, (3, 4))}
+                "chained": (_chained_fn, (1, 2))}
 
 
 class Lfm2Family:
     """models/lfm2.py: conv and grouped-query attention mixers, SwiGLU and
-    routed experts, on the hybrid cache.  Greedy on one device; the
-    chunked mixed step is its only prefill."""
+    routed experts, on the hybrid cache.  Greedy on one device."""
 
     name = "lfm2"
     cache_kind = "hybrid"
@@ -137,8 +158,7 @@ class Lfm2Family:
                 "conv_width": cfg.d_model, "conv_slots": max_batch_size}
 
     @staticmethod
-    def unsupported(*, tp, quantize, speculative, session_store,
-                    chunked_prefill) -> None:
+    def unsupported(*, tp, quantize, speculative, session_store) -> None:
         missing = [what for what, asked in (
             ("tensor parallelism (tp > 1): the conv arena and the expert "
              "weights have no sharded layout", tp is not None and tp > 1),
@@ -148,8 +168,6 @@ class Lfm2Family:
              "the conv state back", speculative not in (None, False)),
             ("host tiering (session_store): a resumed block skips the "
              "tokens that build the conv state", session_store is not None),
-            ("whole-bucket prefill (chunked_prefill=False): this family "
-             "prefills through the mixed step only", not chunked_prefill),
         ) if asked]
         if missing:
             raise ValueError(
@@ -157,7 +175,10 @@ class Lfm2Family:
                 + "; ".join(missing))
 
     @staticmethod
-    def programs(cfg, attn: str, mesh) -> dict:
+    def programs(cfg, attn: str, mesh, sampled: bool = False) -> dict:
+        if sampled:
+            raise ValueError("the lfm2 block family decodes greedily: it "
+                             "has no sampled step programs")
         from . import lfm2 as m
 
         def _step_fn(p, k_pool, v_pool, conv, token, positions, bt, sb, so,

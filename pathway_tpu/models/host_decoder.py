@@ -51,7 +51,6 @@ class Int8DecoderHost:
         # JAX-side engine from the same weights (serving_executor(paged=True))
         self._jax_params = params
         self._paged_engine = None
-        self._state_engine = None
         # clamp: positions beyond max_len have no positional embedding
         self.cap = min(int(cache_capacity or cfg.max_len), cfg.max_len)
         f32 = np.float32
@@ -225,46 +224,7 @@ class Int8DecoderHost:
                 self._paged_engine = engine
         return self._paged_engine or None
 
-    def state_engine(self, **kwargs):
-        """The constant-memory SSD decode engine
-        (kvcache/statecache.py) built from this host's weights, lazily
-        constructed; None when it cannot be built.  The engine grafts
-        the SSD mixing params (``ssd_augment_params``) onto the same
-        checkpoint, so one host serves either cache backend."""
-        if self._state_engine is not None:
-            cached_kwargs = getattr(self, "_state_engine_kwargs", None)
-            if kwargs and self._state_engine and kwargs != cached_kwargs:
-                import logging
-
-                logging.getLogger(__name__).warning(
-                    "state_engine(%r) ignored: engine already built with "
-                    "%r — the shared instance is returned unchanged",
-                    kwargs, cached_kwargs,
-                )
-        if self._state_engine is None:
-            self._state_engine_kwargs = dict(kwargs)
-            from ..kvcache.engine import build_engine
-            from ..kvcache.statecache import StateDecodeEngine
-
-            kwargs.setdefault("name", "host_decoder_state")
-            # same degrade path as paged_engine: stranded requests hand
-            # off to this host's serial int8 tier with emitted kept
-            kwargs.setdefault(
-                "degrade_fn",
-                lambda prompt, n_remaining, emitted: self.generate(
-                    list(prompt) + list(emitted), n_remaining
-                ),
-            )
-            engine = build_engine(
-                self.cfg, self._jax_params,
-                "serving falls back to serialized batch-1 decode",
-                __name__, engine_cls=StateDecodeEngine, **kwargs,
-            )
-            self._state_engine = engine if engine is not None else False
-        return self._state_engine or None
-
-    def serving_executor(self, *, cache: str = "paged",
-                         paged: bool | None = None,
+    def serving_executor(self, *, paged: bool | None = None,
                          max_batch_size: int | None = None,
                          tp: int | None = None,
                          chain_steps: int | None = None,
@@ -282,8 +242,7 @@ class Int8DecoderHost:
         stream their prompts through the ragged fused step in chunks
         (no whole-bucket prefill stalling in-flight decodes; N
         same-round arrivals ride one dispatch) and sampling runs
-        device-side — pass ``chunked_prefill=False`` through
-        :meth:`paged_engine` kwargs for the round-7 behavior.
+        device-side.
 
         ``tp=`` (Round-9) shards the paged engine over the local device
         mesh — the KV block pool's head axis and every step program split
@@ -333,33 +292,20 @@ class Int8DecoderHost:
         measured ``pw.spec_tier`` prior for this backend, and a
         ``Drafter``/``SpecController`` instance (kvcache/speculative.py,
         e.g. a small draft model) is used directly.  Default (None):
-        off.
-
-        ``cache=`` (Round-16) selects the cache backend behind the
-        executor: ``"paged"`` (default) is the block-pool KV tier above;
-        ``"state"`` routes through :meth:`state_engine` — the
-        SSD/linear-attention decoder whose per-sequence HBM is constant
-        in context length (kvcache/statecache.py).  The state tier is an
-        explicit choice, so an unbuildable engine raises instead of
-        silently degrading."""
-        if cache not in ("paged", "state"):
-            raise ValueError(
-                f"cache={cache!r}: expected 'paged' or 'state'"
-            )
+        off."""
         sched = getattr(self, "_serve_executor", None)
         if sched is not None and not sched._closed:
             if paged is not None or max_batch_size is not None \
                     or tp is not None or chain_steps is not None \
-                    or quantize is not None or speculative is not None \
-                    or cache != "paged":
+                    or quantize is not None or speculative is not None:
                 import logging
 
                 logging.getLogger(__name__).warning(
-                    "serving_executor(cache=%r, paged=%r, max_batch_size=%r,"
+                    "serving_executor(paged=%r, max_batch_size=%r,"
                     " tp=%r, chain_steps=%r, quantize=%r, speculative=%r) "
                     "ignored: the shared executor already exists; shut it "
                     "down first to rebuild with different settings",
-                    cache, paged, max_batch_size, tp, chain_steps, quantize,
+                    paged, max_batch_size, tp, chain_steps, quantize,
                     speculative,
                 )
             return sched
@@ -369,11 +315,11 @@ class Int8DecoderHost:
         kwargs.setdefault("max_queue", 64)
         linger = kwargs.pop("batch_linger_ms", None)
         engine = None
-        if cache == "paged" and paged is False and self._paged_engine is None:
+        if paged is False and self._paged_engine is None:
             # explicit opt-out frees the f32 weight pin for good
             self._paged_engine = False
             self._jax_params = None
-        if cache == "state" or paged or paged is None:
+        if paged or paged is None:
             engine_kwargs = {}
             if max_batch_size is not None:
                 engine_kwargs["max_batch_size"] = max_batch_size
@@ -383,20 +329,14 @@ class Int8DecoderHost:
                 engine_kwargs["chain_steps"] = chain_steps
             if quantize is not None:
                 engine_kwargs["quantize"] = quantize
-            if speculative is not None and cache == "paged":
+            if speculative is not None:
                 engine_kwargs["speculative"] = speculative
-            if cache == "state":
-                engine = self.state_engine(**engine_kwargs)
-                if engine is None:
-                    raise RuntimeError("cache='state' but the state engine "
-                                       "is unavailable (see log)")
-            else:
-                engine = self.paged_engine(**engine_kwargs)
+            engine = self.paged_engine(**engine_kwargs)
             if engine is None and paged:
                 raise RuntimeError("paged=True but the KV engine is "
                                    "unavailable (see log)")
         if engine is not None:
-            if paged is None and cache == "paged":
+            if paged is None:
                 import logging
 
                 logging.getLogger(__name__).info(

@@ -180,11 +180,6 @@ class HbmPlan:
     chain_steps: int
     prefill_chunk: int
     tp: int
-    # Round-16 state backend: when set, the cache term is num_blocks
-    # SLOTS x this constant (per sequence, global across shards) instead
-    # of the paged K/V pool formula — the number the constant-memory
-    # capacity headline is computed from
-    state_bytes_per_seq: int | None = None
     # a hybrid family's conv slot arena (0 elsewhere): fixed by the batch,
     # not by num_blocks
     conv_bytes: int = 0
@@ -301,7 +296,6 @@ class HbmPlan:
             "max_batch_size": self.max_batch_size,
             "chain_steps": self.chain_steps,
             "tp": self.tp,
-            "state_bytes_per_seq": self.state_bytes_per_seq,
         }
 
 
@@ -309,23 +303,14 @@ def hbm_plan(cfg, *, num_blocks: int, block_size: int,
              max_batch_size: int = 8, chain_steps: int = 8,
              prefill_chunk: int | None = None, tp: int = 1, dtype=None,
              params=None, budget_bytes: int | None = None,
-             reference_attn: bool = True,
-             state_bytes_per_seq: int | None = None) -> HbmPlan:
+             reference_attn: bool = True) -> HbmPlan:
     """Build the HBM ledger for one engine configuration.
 
     ``params`` (the live pytree) makes the weights term exact;
     ``dtype`` defaults to float32.  The temp watermark prefers a
     MEASURED ``memory_analysis()`` value from the program registry when
     one is already cached (a warmed engine re-planning), else the
-    analytic estimate.
-
-    ``state_bytes_per_seq`` (Round-16) switches the cache term to the
-    constant-memory state backend: ``num_blocks`` is then the SLOT
-    count and the cache charge is ``num_blocks x state_bytes_per_seq /
-    tp`` per shard — context length does not appear, which is the whole
-    point.  Every fit-check helper (``fits_with``,
-    ``max_fitting_num_blocks``, ``largest_fitting``) works unchanged
-    because the term stays linear in ``num_blocks``."""
+    analytic estimate."""
     import numpy as np
 
     itemsize = _dtype_itemsize(dtype) if dtype is not None \
@@ -358,14 +343,9 @@ def hbm_plan(cfg, *, num_blocks: int, block_size: int,
     def _build(*, num_blocks: int, chain_steps: int,
                max_batch_size: int) -> HbmPlan:
         measured = _measured_temp(num_blocks)
-        if state_bytes_per_seq is not None:
-            # state backend: per-shard charge for num_blocks SLOTS of
-            # the fixed per-sequence state (sharded on the head axis)
-            kv = num_blocks * int(state_bytes_per_seq) // max(tp, 1)
-        else:
-            kv = kv_pool_bytes(cfg, num_blocks=num_blocks,
-                               block_size=int(block_size), tp=tp,
-                               itemsize=itemsize)
+        kv = kv_pool_bytes(cfg, num_blocks=num_blocks,
+                           block_size=int(block_size), tp=tp,
+                           itemsize=itemsize)
         analytic = _temp_bytes(
             cfg, num_blocks=num_blocks, block_size=int(block_size),
             max_batch_size=max_batch_size, chain_steps=chain_steps,
@@ -383,7 +363,6 @@ def hbm_plan(cfg, *, num_blocks: int, block_size: int,
             block_size=int(block_size),
             max_batch_size=int(max_batch_size),
             chain_steps=int(chain_steps), prefill_chunk=pchunk, tp=tp,
-            state_bytes_per_seq=state_bytes_per_seq,
             conv_bytes=conv_arena_bytes(
                 cfg, max_batch_size=int(max_batch_size), itemsize=itemsize),
         )

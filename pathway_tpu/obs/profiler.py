@@ -381,8 +381,8 @@ class ProgramRegistry:
             if key is not None:
                 rec = self._records.get(key)
             else:
-                # multi-bucket wrapper (the legacy per-bucket prefill):
-                # aggregate under a program-level pseudo bucket
+                # a wrapper called at several shapes: aggregate under a
+                # program-level pseudo bucket
                 rec = self._records.get((program, ("*",)))
                 if rec is None:
                     rec = self._records[(program, ("*",))] = ProgramRecord(

@@ -100,18 +100,9 @@ def shard_params(params, mesh: Mesh):
 # (the fused axis is head-major: a shard's slice is its own heads, whole)
 KV_POOL_PSPEC = P(None, None, None, "tp")
 
-# [n_layers, max_slots, n_heads, d_key, d_value]: the Round-16 SSD
-# recurrent-state array (kvcache/statecache.py) — heads over tp, like
-# the KV pool (each shard carries its heads' fixed-size states)
-SSD_STATE_PSPEC = P(None, None, "tp", None, None)
-
 
 def kv_pool_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, KV_POOL_PSPEC)
-
-
-def ssd_state_sharding(mesh: Mesh) -> NamedSharding:
-    return NamedSharding(mesh, SSD_STATE_PSPEC)
 
 
 def tp_mesh(tp: int) -> Mesh:
@@ -191,11 +182,9 @@ def decoder_param_sharding_rules(path: tuple[str, ...],
         return P(None, "tp")
     if name == "bqkv":
         return P("tp")
-    # w_a/b_a: the SSD decay projection (Round-16) — one scalar gate per
-    # HEAD, so it shards column-parallel with the heads like wq
-    if name in ("wq", "wk", "wv", "w_up", "w_gate", "w_a"):
+    if name in ("wq", "wk", "wv", "w_up", "w_gate"):
         return P(None, "tp")
-    if name in ("bq", "bk", "bv", "b_up", "b_gate", "b_a"):
+    if name in ("bq", "bk", "bv", "b_up", "b_gate"):
         return P("tp")
     if name in ("wo", "w_down"):
         return P("tp", None)

@@ -69,15 +69,7 @@ class ReplicaFleet:
     Engine keyword arguments (``num_blocks``, ``block_size``,
     ``watchdog_timeout_s``, ``max_restarts``, ``tp``, ...) pass through
     to every replica; ``degrade_fn`` (if given) becomes the LAST-resort
-    tier, consulted only when the whole fleet is dead.
-
-    ``cache="state"`` (Round-16) builds every replica as a
-    :class:`~pathway_tpu.kvcache.statecache.StateDecodeEngine` — the
-    constant-memory SSD tier — instead of the paged KV engine.  Routing,
-    failover and the session tier are unchanged: the state cache keeps a
-    ``block_size`` attribute so prefix-affinity digests still chunk
-    prompts identically, and suspend buffers flow through the same
-    :class:`~pathway_tpu.kvcache.tiering.SessionStore`."""
+    tier, consulted only when the whole fleet is dead."""
 
     def __init__(self, cfg, params, *, replicas: int = 2,
                  name: str = "fleet", session_store=None,
@@ -85,19 +77,9 @@ class ReplicaFleet:
                  failover_timeout_s: float = 120.0,
                  scheduler_kwargs: dict | None = None,
                  degrade_fn: Callable | None = None,
-                 cache: str = "paged",
                  **engine_kwargs):
         from ..kvcache.engine import PagedDecodeEngine
 
-        if cache == "state":
-            from ..kvcache.statecache import StateDecodeEngine
-            engine_cls = StateDecodeEngine
-        elif cache == "paged":
-            engine_cls = PagedDecodeEngine
-        else:
-            raise ValueError(
-                f"cache={cache!r}: expected 'paged' or 'state'"
-            )
         if int(replicas) < 1:
             raise ValueError("a fleet needs at least one replica")
         self.name = name
@@ -116,7 +98,7 @@ class ReplicaFleet:
         sched_kw.setdefault("max_batch_size",
                             int(engine_kwargs.get("max_batch_size", 8)))
         for i in range(int(replicas)):
-            engine = engine_cls(
+            engine = PagedDecodeEngine(
                 cfg, params, name=f"{name}_r{i}",
                 session_store=session_store,
                 degrade_fn=self._make_handoff(i), **engine_kwargs,

@@ -560,57 +560,8 @@ class FleetStats:
         return snap
 
 
-class StateCacheStats:
-    """Round-16 constant-memory cache counters (``pathway_state_*``):
-    slot occupancy plus suspend/resume traffic for one
-    kvcache/statecache.py StateCache.  Engine-generic counters (TTFT,
-    chains, restarts, host gap) stay on the shared
-    :class:`KVCacheStats` block — this family carries only what is
-    specific to the fixed-size-state backend."""
-
-    def __init__(self, name: str, slots_in_use_fn=None,
-                 slots_total: int = 0, state_bytes_per_seq: int = 0):
-        self.name = name
-        self._slots_in_use_fn = slots_in_use_fn
-        self.slots_total = slots_total
-        self.state_bytes_per_seq = state_bytes_per_seq
-        self.suspends = 0
-        self.resumes = 0
-        self._lock = threading.Lock()
-
-    @property
-    def slots_in_use(self) -> int:
-        if self._slots_in_use_fn is None:
-            return 0
-        try:
-            return int(self._slots_in_use_fn())
-        except Exception:
-            return 0
-
-    def record_suspend(self, n: int = 1) -> None:
-        with self._lock:
-            self.suspends += n
-
-    def record_resume(self, n: int = 1) -> None:
-        with self._lock:
-            self.resumes += n
-
-    def snapshot(self) -> dict:
-        with self._lock:
-            snap = {
-                "name": self.name,
-                "slots_total": self.slots_total,
-                "state_bytes_per_seq": self.state_bytes_per_seq,
-                "suspends": self.suspends,
-                "resumes": self.resumes,
-            }
-        snap["slots_in_use"] = self.slots_in_use
-        return snap
-
-
 _registry: dict[str, ServeStats] = {}
 _kv_registry: dict[str, KVCacheStats] = {}
-_state_registry: dict[str, StateCacheStats] = {}
 _fleet_registry: dict[str, FleetStats] = {}
 # SessionStore host tiers (kvcache/tiering.py) keyed by store name; the
 # store registers itself so pathway_kv_tier_* lines exist with or
@@ -652,29 +603,6 @@ def kv_stats(name: str, blocks_in_use_fn=None, blocks_total: int | None = None,
                 stats.shards = shards
             if shard_hbm_bytes is not None:
                 stats.shard_hbm_bytes = shard_hbm_bytes
-        return stats
-
-
-def state_stats(name: str, slots_in_use_fn=None,
-                slots_total: int | None = None,
-                state_bytes_per_seq: int | None = None) -> StateCacheStats:
-    """Get-or-create the state-cache stats block for `name` (same
-    contract as :func:`kv_stats`: counters stay monotonic across cache
-    rebuilds — a restarted engine's fresh StateCache re-attaches)."""
-    with _registry_lock:
-        stats = _state_registry.get(name)
-        if stats is None:
-            stats = _state_registry[name] = StateCacheStats(
-                name, slots_in_use_fn, slots_total or 0,
-                state_bytes_per_seq or 0,
-            )
-        else:
-            if slots_in_use_fn is not None:
-                stats._slots_in_use_fn = slots_in_use_fn
-            if slots_total is not None:
-                stats.slots_total = slots_total
-            if state_bytes_per_seq is not None:
-                stats.state_bytes_per_seq = state_bytes_per_seq
         return stats
 
 
@@ -720,11 +648,6 @@ def all_kv_stats() -> list[KVCacheStats]:
         return list(_kv_registry.values())
 
 
-def all_state_stats() -> list[StateCacheStats]:
-    with _registry_lock:
-        return list(_state_registry.values())
-
-
 def all_fleet_stats() -> list[FleetStats]:
     with _registry_lock:
         return list(_fleet_registry.values())
@@ -740,7 +663,6 @@ def reset_registry() -> None:
     with _registry_lock:
         _registry.clear()
         _kv_registry.clear()
-        _state_registry.clear()
         _fleet_registry.clear()
         _tier_registry.clear()
 
@@ -760,9 +682,8 @@ def render_prometheus_lines() -> list[str]:
     """Prometheus text-format lines, appended to MetricsServer.render()."""
     stats = all_stats()
     if not stats:
-        return (_render_kv_lines() + _render_state_lines()
-                + _render_fleet_lines() + _render_tier_lines()
-                + _render_xla_lines())
+        return (_render_kv_lines() + _render_fleet_lines()
+                + _render_tier_lines() + _render_xla_lines())
     lines = [
         "# TYPE pathway_serve_queue_depth gauge",
         "# TYPE pathway_serve_admitted_total counter",
@@ -804,44 +725,9 @@ def render_prometheus_lines() -> list[str]:
             f"{snap['time_in_queue_s']:.6f}"
         )
     lines.extend(_render_kv_lines())
-    lines.extend(_render_state_lines())
     lines.extend(_render_fleet_lines())
     lines.extend(_render_tier_lines())
     lines.extend(_render_xla_lines())
-    return lines
-
-
-def _render_state_lines() -> list[str]:
-    """Round-16 constant-memory cache lines (``pathway_state_*``)."""
-    stats = all_state_stats()
-    if not stats:
-        return []
-    lines = [
-        "# TYPE pathway_state_slots_in_use gauge",
-        "# TYPE pathway_state_slots_total gauge",
-        "# TYPE pathway_state_bytes_per_seq gauge",
-        "# TYPE pathway_state_suspends_total counter",
-        "# TYPE pathway_state_resumes_total counter",
-    ]
-    for s in stats:
-        snap = s.snapshot()
-        lbl = f'cache="{s.name}"'
-        lines.append(
-            f"pathway_state_slots_in_use{{{lbl}}} {snap['slots_in_use']}"
-        )
-        lines.append(
-            f"pathway_state_slots_total{{{lbl}}} {snap['slots_total']}"
-        )
-        lines.append(
-            f"pathway_state_bytes_per_seq{{{lbl}}} "
-            f"{snap['state_bytes_per_seq']}"
-        )
-        lines.append(
-            f"pathway_state_suspends_total{{{lbl}}} {snap['suspends']}"
-        )
-        lines.append(
-            f"pathway_state_resumes_total{{{lbl}}} {snap['resumes']}"
-        )
     return lines
 
 
@@ -1255,18 +1141,6 @@ def otlp_points(now_ns: str) -> list[dict]:
                         shard_attr,
                     ],
                 })
-    for s in all_state_stats():
-        snap = s.snapshot()
-        for key in ("slots_in_use", "slots_total", "state_bytes_per_seq",
-                    "suspends", "resumes"):
-            points.append({
-                "asInt": str(snap[key]),
-                "timeUnixNano": now_ns,
-                "attributes": [
-                    {"key": "cache", "value": {"stringValue": s.name}},
-                    {"key": "counter", "value": {"stringValue": key}},
-                ],
-            })
     for s in all_fleet_stats():
         snap = s.snapshot()
         for key in ("replicas", "live", "replica_deaths", "recovery_count",

@@ -88,12 +88,6 @@ class DashboardServer:
                 "fleets": [
                     s.snapshot() for s in serve_metrics.all_fleet_stats()
                 ],
-                # round-16: constant-memory state caches (SSD decode
-                # tier) — slots in use, per-seq bytes, suspend/resume
-                # counters, next to the kv table
-                "states": [
-                    s.snapshot() for s in serve_metrics.all_state_stats()
-                ],
                 "stores": [],
             }
             for store in serve_metrics.all_session_stores():

@@ -1,0 +1,439 @@
+"""The engine<->cache contract (kvcache/backend.py) and the family seam
+(models/families.py), over the two kinds of cache that serve cells:
+
+- ``paged``: BlockPool, K/V blocks for every layer;
+- ``hybrid``: HybridCache, K/V blocks for the attention layers and a conv
+  slot a sequence beside them.
+
+Each contract test runs over both kinds through ``make_backend``, the seam
+the engine builds (and, on a supervised restart, rebuilds) its cache
+through.  The family tests hold what the engine and the trace readers take
+from ``programs()``: the three kinds of step program, which arguments are
+donated, and the function names the device trace carries
+(``jit__mixed_fn`` on ``XLA Modules``: a rename would silence a metric).
+"""
+
+import ast
+import random
+from collections import deque
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from pathway_tpu.kvcache import (
+    BlockPool, CacheBackend, HybridCache, PagedDecodeEngine, PoolExhausted,
+    SessionStore, UnsupportedCacheOp, make_backend,
+)
+from pathway_tpu.models.decoder import DecoderConfig, init_decoder_params
+from pathway_tpu.models.families import step_family
+from pathway_tpu.serve import metrics as serve_metrics
+
+KINDS = ("paged", "hybrid")
+_GEOM = dict(num_blocks=24, block_size=4, n_layers=2, n_heads=2, head_dim=8)
+_CONV = dict(conv_layers=3, conv_width=16, conv_slots=5)
+
+_CFG = DecoderConfig(
+    vocab_size=64, d_model=64, n_layers=2, n_heads=8, d_ff=128, max_len=128
+)
+_HD = _CFG.d_model // _CFG.n_heads
+
+
+def _make(kind, name, **over):
+    kw = dict(_GEOM, name=name, **(_CONV if kind == "hybrid" else {}))
+    kw.update(over)
+    return make_backend(kind, **kw)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_decoder_params(_CFG, jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module")
+def lfm2():
+    from pathway_tpu.models.lfm2 import Lfm2Config, init_lfm2_params
+
+    a, c = "full_attention", "conv"
+    cfg = Lfm2Config(vocab_size=257, d_model=64, n_heads=4, n_kv_heads=2,
+                     d_ff=128, d_ff_expert=32, n_experts=8, top_k=2,
+                     n_dense_layers=1, layer_types=(c, a, c, c, a),
+                     max_len=256, dtype=jnp.float32)
+    return cfg, init_lfm2_params(cfg, jax.random.PRNGKey(0))
+
+
+def _engine(kind, params, lfm2, name, **kw):
+    geom = dict(num_blocks=64, block_size=4, max_batch_size=4,
+                prefill_chunk=8, chain_steps=4)
+    geom.update(kw)
+    if kind == "paged":
+        return PagedDecodeEngine(_CFG, params, name=name, **geom)
+    cfg, lparams = lfm2
+    return PagedDecodeEngine(cfg, lparams, name=name, attn="reference",
+                             **geom)
+
+
+def _nbytes(arrays) -> int:
+    return sum(int(a.size) * a.dtype.itemsize for a in arrays)
+
+
+def _cache_arrays(kind) -> int:
+    """K and V pools, and the hybrid kind's conv arena."""
+    return 3 if kind == "hybrid" else 2
+
+
+# -- the contract, over both kinds ---------------------------------------------
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_lifecycle_fuzz_holds_the_invariants_after_every_operation(kind):
+    pool = _make(kind, f"t_cb_fuzz_{kind}")
+    assert isinstance(pool, CacheBackend) and pool.cache_kind == kind
+    rng = random.Random(0xB10C + len(kind))
+    live: list[int] = []
+    next_id = 1
+    counts = {"allocate": 0, "extend": 0, "free": 0, "preempt": 0,
+              "exhausted": 0}
+    for _step in range(400):
+        op = rng.random()
+        before = (pool.num_free, len(pool.sequences()))
+        try:
+            if op < 0.35 or not live:
+                pool.allocate(next_id, rng.randint(0, 18),
+                              priority=rng.randint(0, 2))
+                live.append(next_id)
+                next_id += 1
+                counts["allocate"] += 1
+            elif op < 0.70:
+                sid = rng.choice(live)
+                k = rng.randint(1, 6)
+                n0 = pool.sequence(sid).n_tokens
+                slots = pool.extend_slots(sid, k)
+                assert len(slots) == k
+                assert pool.sequence(sid).n_tokens == n0 + k
+                # the slots are the sequence's own blocks, never the null one
+                assert {b for b, _o in slots} <= set(
+                    pool.sequence(sid).block_ids) - {0}
+                counts["extend"] += 1
+            elif op < 0.90:
+                sid = live.pop(rng.randrange(len(live)))
+                pool.free_sequence(sid)
+                counts["free"] += 1
+            else:
+                victim = pool.preempt()
+                assert victim is not None
+                live.remove(victim.seq_id)
+                counts["preempt"] += 1
+        except PoolExhausted:
+            # no partial side effect: neither a block nor a sequence moved
+            assert (pool.num_free, len(pool.sequences())) == before
+            counts["exhausted"] += 1
+            victim = pool.preempt()
+            if victim is not None:
+                live.remove(victim.seq_id)
+        pool.check_invariants()
+        assert sorted(s.seq_id for s in pool.sequences()) == sorted(live)
+    assert all(counts.values()), counts
+    for sid in live:
+        pool.free_sequence(sid)
+    pool.check_invariants()
+    assert pool.num_free == pool.num_blocks - 1
+    if kind == "hybrid":
+        assert pool.slots_in_use == 0
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_optional_operations_work_or_refuse_as_the_flags_say(kind):
+    pool = _make(kind, f"t_cb_flags_{kind}")
+    parent = pool.allocate(1, 9)
+    assert pool.supports_preemption
+    assert pool.supports_fork == pool.supports_prefix == (kind == "paged")
+    if pool.supports_fork:
+        child = pool.fork(1, 2)
+        assert child.block_ids == parent.block_ids
+    else:
+        with pytest.raises(UnsupportedCacheOp, match="fork"):
+            pool.fork(1, 2)
+    if pool.supports_prefix:
+        # the two full blocks of the parent, shared
+        st = pool.allocate(3, 9, shared_blocks=parent.block_ids[:2])
+        assert st.block_ids[:2] == parent.block_ids[:2]
+    else:
+        with pytest.raises(UnsupportedCacheOp, match="shared blocks"):
+            pool.allocate(3, 9, shared_blocks=parent.block_ids[:2])
+    n_live = len(pool.sequences())
+    victim = pool.preempt()
+    assert victim is not None and len(pool.sequences()) == n_live - 1
+    pool.check_invariants()
+    with pytest.raises(ValueError, match="unknown cache backend"):
+        make_backend("state")
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_per_shard_bytes_are_the_bytes_of_the_device_state(kind):
+    pool = _make(kind, f"t_cb_bytes_{kind}", dtype=jnp.bfloat16)
+    state = pool.device_state()
+    assert len(state) == _cache_arrays(kind)
+    assert all(a.dtype == jnp.bfloat16 for a in state)
+    assert pool.per_shard_bytes == _nbytes(state)
+    # what /metrics reports a shard to hold
+    assert pool.stats.shard_hbm_bytes == pool.per_shard_bytes
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_restart_rebuilds_the_cache_through_the_seam(kind, params, lfm2):
+    eng = _engine(kind, params, lfm2, f"t_cb_restart_{kind}")
+    eng.generate_batch([([5, 9, 20, 3, 7, 11, 2], 6), ([41, 2, 8], 6)])
+    old = eng.pool
+    shapes = [(a.shape, a.dtype) for a in old.device_state()]
+    assert all(np.asarray(a).any() for a in old.device_state())
+    eng._restart([], deque(), "Boom", "a test's restart", 1)
+    new = eng.pool
+    assert new is not old and type(new) is type(old)
+    assert new.cache_kind == kind == eng.family.cache_kind
+    # the parent's geometry, and nothing of its contents
+    assert [(a.shape, a.dtype) for a in new.device_state()] == shapes
+    assert not any(np.asarray(a).any() for a in new.device_state())
+    assert (new.num_blocks, new.block_size) \
+        == (old.num_blocks, old.block_size)
+    assert new.sequences() == [] and new.num_free == new.num_blocks - 1
+    new.check_invariants()
+    # the counters stay with the name, across the rebuild
+    assert new.name == old.name and new.stats is old.stats
+    assert new.stats.snapshot()["engine_restarts"] == 1
+    # and the seam gives the same cache to anyone holding the same kwargs
+    twin = make_backend(kind, **{**eng._pool_kwargs,
+                                 "name": f"t_cb_restart_twin_{kind}"})
+    assert [(a.shape, a.dtype) for a in twin.device_state()] == shapes
+    assert twin.per_shard_bytes == new.per_shard_bytes
+    # the rebuilt engine serves
+    out = eng.generate_batch([([5, 9, 20, 3, 7, 11, 2], 6)])
+    assert len(out[0]) == 6
+
+
+def _gauge(lines, metric: str, pool_name: str):
+    want = f'{metric}{{pool="{pool_name}"}} '
+    got = [ln for ln in lines if ln.startswith(want)]
+    return [float(ln[len(want):]) for ln in got]
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_retire_hands_the_name_and_its_gauges_to_the_next_cache(kind):
+    # a retired cache gives up its /metrics name at once: the cache built
+    # after it under the same name owns the gauges (no "#1" twin), and the
+    # counters stay monotonic across the two
+    name = f"t_cb_retire_{kind}"
+    first = _make(kind, name)
+    first.allocate(1, 9)
+    first.preempt()
+    first.allocate(2, 9)
+    lines = serve_metrics.render_prometheus_lines()
+    assert _gauge(lines, "pathway_kv_blocks_in_use", name) == [3.0]
+    if kind == "hybrid":
+        assert _gauge(lines, "pathway_kv_conv_slots_in_use", name) == [1.0]
+    unretired = _make(kind, name)
+    assert unretired.name == name + "#1"
+    unretired.retire()
+    first.retire()
+    second = _make(kind, name)
+    assert second.name == name and second.stats is first.stats
+    lines = serve_metrics.render_prometheus_lines()
+    assert _gauge(lines, "pathway_kv_blocks_in_use", name) == [0.0]
+    assert _gauge(lines, "pathway_kv_preemptions_total", name) == [1.0]
+    if kind == "hybrid":
+        assert _gauge(lines, "pathway_kv_conv_slots_in_use", name) == [0.0]
+        assert _gauge(lines, "pathway_kv_conv_slots_total", name) \
+            == [float(_CONV["conv_slots"])]
+    second.retire()
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_host_tiering_round_trips_or_refuses_by_name(kind):
+    pool = _make(kind, f"t_cb_tier_{kind}")
+    st = pool.allocate(1, 11)
+    if kind == "hybrid":
+        with pytest.raises(UnsupportedCacheOp, match="host tiering"):
+            pool.suspend_host(1, list(range(11)))
+        with pytest.raises(UnsupportedCacheOp, match="host tiering"):
+            pool.resume_host({}, st.block_ids)
+        # a refusal takes nothing from the sequence
+        assert pool.sequence(1).block_ids == st.block_ids
+        pool.check_invariants()
+        return
+    rng = np.random.default_rng(5)
+    k = rng.standard_normal(pool.k.shape).astype(np.float32)
+    v = rng.standard_normal(pool.v.shape).astype(np.float32)
+    pool.set_device_state(jnp.asarray(k), jnp.asarray(v))
+    blocks = list(st.block_ids)
+    payload, nbytes = pool.suspend_host(1, list(range(11)))
+    assert pool.sequences() == [] and pool.num_free == pool.num_blocks - 1
+    assert nbytes == payload["k"].nbytes + payload["v"].nbytes
+    # other sequences take the freed blocks first: the resumed one lands
+    # elsewhere and must read the same bytes
+    pool.allocate(2, 7)
+    back = pool.allocate(3, 11)
+    assert back.block_ids != blocks
+    pool.resume_host(payload, back.block_ids)
+    for arr, src in ((pool.k, k), (pool.v, v)):
+        np.testing.assert_array_equal(
+            np.asarray(arr)[:, back.block_ids], src[:, blocks])
+    pool.check_invariants()
+
+
+# -- kept from the suite of the engine that went --------------------------------
+
+
+def test_paged_engine_builds_pool_through_make_backend(params):
+    eng = PagedDecodeEngine(
+        _CFG, params, num_blocks=64, block_size=4, max_batch_size=4,
+        seq_buckets=(16, 32, 64), prefill_chunk=8, name="t_seam_engine",
+    )
+    assert isinstance(eng.pool, CacheBackend)
+    assert isinstance(eng.pool, BlockPool)
+    assert eng.pool.cache_kind == "paged"
+
+
+def test_blockpool_parity_through_backend_interface():
+    # the SAME behavior whether BlockPool is constructed directly or
+    # through the make_backend seam: allocation layout, suspend payload
+    # bytes, invariants
+    kw = dict(num_blocks=32, block_size=4, n_layers=_CFG.n_layers,
+              n_heads=_CFG.n_heads, head_dim=_HD)
+    direct = BlockPool(name="t_seam_direct", **kw)
+    seamed = make_backend("paged", name="t_seam_made", **kw)
+    assert type(seamed) is BlockPool
+    for pool in (direct, seamed):
+        st = pool.allocate(0, 11)
+        assert len(st.block_ids) == pool.blocks_for(11)
+    assert (direct.sequence(0).block_ids
+            == seamed.sequence(0).block_ids)
+    p_direct, b_direct = direct.suspend_host(0, list(range(11)))
+    p_seamed, b_seamed = seamed.suspend_host(0, list(range(11)))
+    assert b_direct == b_seamed
+    np.testing.assert_array_equal(p_direct["k"], p_seamed["k"])
+    direct.check_invariants()
+    seamed.check_invariants()
+    with pytest.raises(ValueError, match="unknown cache backend"):
+        make_backend("bogus")
+
+
+def test_session_store_charges_real_buffer_bytes():
+    store = SessionStore()
+    # 11 tokens -> 3 blocks, padded gather width 4 — the charge is the
+    # PADDED buffer (k + v), not the logical 3-block span
+    pool = BlockPool(num_blocks=32, block_size=4, n_layers=2, n_heads=4,
+                     head_dim=8, name="t_charge_paged")
+    pool.allocate(0, 11)
+    per_block = 2 * 4 * 4 * 8 * 4  # L * bs * H * hd * itemsize
+    store.suspend("pg", pool, 0, list(range(11)))
+    ent = store.match("pg", list(range(11)))
+    assert ent is not None
+    assert ent.nbytes == 2 * 4 * per_block  # k+v, padded 3 -> 4 blocks
+    assert ent.payload["k"].nbytes == 4 * per_block
+    assert store.host_bytes >= ent.nbytes
+
+
+# -- a conv slot is not cleared between sequences -------------------------------
+
+
+def test_a_reused_conv_slot_gives_what_a_fresh_engine_gives(params, lfm2):
+    # one row, one slot: every request takes the slot the one before it
+    # left, with that sequence's last two conv inputs still in it
+    reqs = [([9, 4, 250, 17, 33, 8, 101, 64, 5], 7), ([77, 12], 9),
+            ([201, 5, 5, 90, 13, 44, 2, 150, 31, 6, 18], 5)]
+    eng = _engine("hybrid", params, lfm2, "t_cb_slot_reuse",
+                  max_batch_size=1)
+    assert isinstance(eng.pool, HybridCache) and eng.pool.conv_slots == 1
+    got = []
+    for prompt, n in reqs:
+        got.append(eng.generate(prompt, n))
+        # the sequence is gone, what it wrote into the slot is not
+        assert eng.pool.slots_in_use == 0
+        assert np.asarray(eng.pool.conv[:, 1]).any()
+    for i, (prompt, n) in enumerate(reqs):
+        fresh = _engine("hybrid", params, lfm2, f"t_cb_slot_fresh{i}",
+                        max_batch_size=1)
+        assert fresh.generate(prompt, n) == got[i]
+
+
+# -- the family's table of step programs ----------------------------------------
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_family_names_its_three_programs_for_the_trace(kind, sampled,
+                                                         params, lfm2):
+    cfg = _CFG if kind == "paged" else lfm2[0]
+    family = step_family(cfg)
+    assert family.cache_kind == kind
+    if family.greedy_only and sampled:
+        # never asked for by the engine (a sampled request fails alone at
+        # admission); asked anyway, the family refuses by name
+        with pytest.raises(ValueError, match="lfm2 .* decodes greedily"):
+            family.programs(cfg, "reference", None, sampled=True)
+        return
+    table = family.programs(cfg, "reference", None, sampled=sampled)
+    assert sorted(table) == ["chained", "mixed", "step"]
+    # exactly the cache's arrays are donated: they follow the params
+    n = _cache_arrays(kind)
+    for fn, donated in table.values():
+        assert tuple(donated) == tuple(range(1, n + 1))
+    # the device trace names a program by its function: jit__mixed_fn and
+    # jit__chained_fn are what mixed_step_ms / decode_step_ms search for
+    assert {k: fn.__name__ for k, (fn, _d) in table.items()} == {
+        "step": "_step_fn", "mixed": "_mixed_fn", "chained": "_chained_fn"}
+    # and the engine registers them under the names the round spans carry
+    eng = _engine(kind, params, lfm2,
+                  f"t_cb_table_{kind}_{'s' if sampled else 'g'}")
+    progs = eng._sampled_programs() if sampled else {
+        "step": eng._step, "mixed": eng._mixed, "chained": eng._chained}
+    sfx = "_sampled" if sampled else ""
+    assert {k: p.program for k, p in progs.items()} == {
+        "step": "pw.decode_step" + sfx, "mixed": "pw.mixed_step" + sfx,
+        "chained": "pw.chained_decode" + sfx}
+    assert len(eng.pool.device_state()) == n
+
+
+def test_the_verify_program_is_the_familys_mixed_program(params, lfm2):
+    eng = _engine("paged", params, lfm2, "t_cb_verify", speculative="ngram")
+    assert eng._verify is None
+    verify = eng._verify_program()
+    assert verify.program == "pw.verify_step"
+    assert verify is eng._verify_program()
+    assert verify is not eng._mixed
+    assert verify._jit.__wrapped__.__name__ == "_mixed_fn"
+
+
+def _imported_modules(path: str, package: str) -> set[str]:
+    """Absolute names of everything ``path`` imports, at any depth of the
+    file (module level, functions, methods)."""
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    parts = package.split(".")
+    found = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            found.update(a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            base = parts[:len(parts) - node.level + 1] if node.level else []
+            mod = ".".join(base + ([node.module] if node.module else []))
+            found.add(mod)
+            found.update(f"{mod}.{a.name}" for a in node.names)
+    return found
+
+
+def test_the_engine_imports_no_models_math():
+    # kvcache/engine.py -> models/families.py -> models/decoder.py |
+    # models/lfm2.py: the engine reaches a model's programs through its
+    # family and no other way
+    import pathway_tpu.kvcache.engine as engine_mod
+
+    mods = _imported_modules(engine_mod.__file__, "pathway_tpu.kvcache")
+    assert "pathway_tpu.models.families" in mods
+    models = {m for m in mods if m.startswith("pathway_tpu.models")}
+    assert not {m for m in models
+                if m.split(".")[2] in ("decoder", "lfm2")}, models
+    with open(engine_mod.__file__) as f:
+        src = f.read()
+    assert "models.decoder" not in src and "models.lfm2" not in src

@@ -434,27 +434,6 @@ def test_engine_failure_releases_inflight_waiters(params):
     assert eng.pool.blocks_in_use == 0
 
 
-def test_prefill_failure_does_not_leak_blocks(params):
-    # chunked_prefill=False: the legacy whole-bucket admission prefill is
-    # the only path that dispatches from INSIDE _try_admit (the chunked
-    # analog — a mid-prefill mixed-step failure — is pinned in
-    # tests/test_ragged_step.py)
-    eng = PagedDecodeEngine(
-        _CFG, params, num_blocks=16, block_size=8, max_batch_size=2,
-        seq_buckets=(16,), chunked_prefill=False, name="t_pfail",
-    )
-
-    def bad_prefill(*_a, **_k):
-        raise RuntimeError("prefill exploded")
-
-    eng._prefill = bad_prefill
-    # the failing sequence is not yet in `running`: its freshly allocated
-    # blocks must be freed on the way out, not leak for the engine's life
-    with pytest.raises(RuntimeError, match="prefill exploded"):
-        eng.generate_batch([([1, 2, 3], 4)])
-    assert eng.pool.blocks_in_use == 0
-
-
 def test_nonaligned_max_len_buckets(params):
     # cfg.max_len=60 is NOT a multiple of block_size=8: buckets must
     # round DOWN to 56, and a long prompt trims to the bucket

@@ -486,7 +486,6 @@ def test_hbm_plan_equals_the_live_bytes(cfg, params):
     ({"quantize": "int8"}, "quantize='int8'"),
     ({"speculative": "ngram"}, "speculative drafting"),
     ({"session_store": object()}, "host tiering"),
-    ({"chunked_prefill": False}, "whole-bucket prefill"),
 ])
 def test_unsupported_engine_options_are_refused_by_name(cfg, params, kwargs,
                                                         names):

@@ -73,7 +73,7 @@ def test_chunked_identity_mixed_lengths_and_partial_tail(params):
         _CFG, params, num_blocks=96, block_size=4, max_batch_size=4,
         seq_buckets=(16, 32, 64), prefill_chunk=8, name="t_r8_identity",
     )
-    assert eng.chunked_prefill and eng.prefill_chunk == 8
+    assert eng.prefill_chunk == 8
     rng = np.random.default_rng(7)
     lengths = [3, 5, 8, 11, 16, 17, 27, 31]
     prompts = [
@@ -92,22 +92,20 @@ def test_chunked_identity_mixed_lengths_and_partial_tail(params):
     )
 
 
-def test_chunked_matches_legacy_whole_bucket_path(params):
+def test_chunked_matches_dense_at_the_default_chunk(params):
+    # the engine's own chunk width (two blocks of 8)
     rng = np.random.default_rng(13)
     prompts = [
         [int(t) for t in rng.integers(0, _CFG.vocab_size, size=n)]
         for n in (6, 13, 21, 30)
     ]
-    outs = {}
-    for chunked in (True, False):
-        eng = PagedDecodeEngine(
-            _CFG, params, num_blocks=96, block_size=8, max_batch_size=4,
-            seq_buckets=(16, 32, 64), chunked_prefill=chunked,
-            name=f"t_r8_cmp_{chunked}",
-        )
-        outs[chunked] = eng.generate_batch([(p, 6) for p in prompts])
-    assert outs[True] == outs[False]
-    assert outs[True] == [_dense_greedy(params, p, 6) for p in prompts]
+    eng = PagedDecodeEngine(
+        _CFG, params, num_blocks=96, block_size=8, max_batch_size=4,
+        seq_buckets=(16, 32, 64), name="t_r8_cmp",
+    )
+    assert eng.prefill_chunk == 16
+    out = eng.generate_batch([(p, 6) for p in prompts])
+    assert out == [_dense_greedy(params, p, 6) for p in prompts]
 
 
 def test_chunked_identity_under_shared_prefixes_same_round(params):

@@ -329,29 +329,6 @@ def test_request_lifecycle_sums_to_recorded_ttft(params, preempt):
     assert sorted(recent) == pytest.approx(sorted(ttfts), abs=1e-9)
 
 
-def test_state_engine_shares_the_lifecycle(params):
-    """The constant-memory engine borrows the helpers, so its requests
-    carry the same lifecycle and its runs the shared phases."""
-    from pathway_tpu.kvcache.statecache import StateDecodeEngine
-
-    eng = StateDecodeEngine(_CFG, params, max_slots=8, max_batch_size=4,
-                            prefill_chunk=8, chain_steps=4,
-                            name="t_life_state")
-    out = eng.generate_batch([(p, 9) for p in _prompts((20, 5, 11))])
-    assert [len(o) for o in out] == [9, 9, 9]
-    spans = obs.recorder().snapshot()
-    assert not [s for s in spans if s.name == "engine.decode_step"]
-    for by in _lifecycle(spans).values():
-        (root,) = by["engine.request"]
-        parts = [by[n][0] for n in ("engine.pending", "engine.prefill_wait",
-                                    "engine.prefill")]
-        assert sum(s.t1 - s.t0 for s in parts) == pytest.approx(
-            root.attrs["ttft_s"], abs=1e-3)
-        assert len(by["engine.decode"]) == 1
-    names = {s.name for s in spans}
-    assert {"pw.round.admit", "pw.round.sync", "pw.round.deliver"} <= names
-
-
 # -- the hoist changed no token ---------------------------------------------
 
 
@@ -368,7 +345,6 @@ def _hoist_runs(params, name, **kw):
 @pytest.mark.parametrize("name,kw", [
     ("chained", {"chain_steps": 8}),
     ("step", {"chain_steps": 1}),
-    ("legacy", {"chunked_prefill": False, "chain_steps": 8}),
 ])
 def test_tokens_identical_before_and_after_the_h2d_hoist(params, name, kw):
     with open(os.path.join(os.path.dirname(__file__),

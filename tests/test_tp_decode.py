@@ -5,8 +5,9 @@ Pins the tentpole guarantees on the tier-1 virtual 8-device mesh
 
 - greedy output on the tp=8 mesh is TOKEN-IDENTICAL to tp=1 (and to the
   round-7/8 dense reference) across mixed lengths, partial tail chunks,
-  shared prefixes, preemption-recompute, and the legacy whole-bucket
-  prefill path;
+  shared prefixes and preemption-recompute; fixed-seed SAMPLED requests
+  give the same tokens at tp=8 as at tp=1, through mixed, step and
+  chained rounds;
 - the pool's K/V arrays are GENUINELY sharded — asserted on
   ``.sharding`` and the addressable shard shapes, not just array shape;
 - tp=1 degenerates to the exact single-device path: no mesh, no
@@ -140,19 +141,35 @@ def test_tp8_identity_across_preemption_recompute(params):
     assert outs[8] == outs[1]
 
 
-def test_tp8_identity_legacy_whole_bucket_prefill(params):
-    # chunked_prefill=False exercises the shard_mapped paged_prefill
-    rng = np.random.default_rng(13)
+@pytest.mark.parametrize("chain_steps,kinds", [
+    (1, {"pw.mixed_step_sampled", "pw.decode_step_sampled"}),
+    (4, {"pw.mixed_step_sampled", "pw.chained_decode_sampled"}),
+], ids=["step", "chained"])
+def test_tp8_sampled_identity_through_every_round_kind(params, chain_steps,
+                                                       kinds):
+    # the sampling head gathers the sharded logits row, so a fixed seed
+    # draws the same token on the mesh as on one device; greedy rows
+    # riding the sampled programs (temperature 0) keep the exact argmax
+    rng = np.random.default_rng(29)
     prompts = [
         [int(t) for t in rng.integers(0, _CFG.vocab_size, size=n)]
-        for n in (6, 13, 21, 30)
+        for n in (5, 13, 21, 9)
     ]
+    reqs = [(p, 10, {"sampling": (0.9, 6, 0.9, 700 + i)})
+            for i, p in enumerate(prompts[:3])] + [(prompts[3], 10)]
     outs = {}
     for tp in (1, 8):
-        eng = _engine(params, tp, f"t_tp_lg{tp}", block_size=8,
-                      chunked_prefill=False)
-        outs[tp] = eng.generate_batch([(p, 6) for p in prompts])
+        eng = _engine(params, tp, f"t_tp_smp{chain_steps}_{tp}",
+                      chain_steps=chain_steps)
+        assert eng._sampled is None
+        outs[tp] = eng.generate_batch(reqs)
+        assert {prog.program for prog in eng._sampled.values()
+                if prog.calls} == kinds
     assert outs[8] == outs[1]
+    # sampling really drew: not the greedy continuation
+    greedy = [_dense_greedy(params, p, 10) for p in prompts]
+    assert outs[1][3] == greedy[3]
+    assert outs[1][:3] != greedy[:3]
 
 
 # -- tp=1 degeneration / validation ------------------------------------------
