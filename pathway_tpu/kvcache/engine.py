@@ -1989,6 +1989,15 @@ class PagedDecodeEngine:
         ph.set(kv_keys=keys, kv_key_lanes=lanes)
         self.pool.stats.record_attended_keys(keys, lanes)
 
+    def _note_write_blocks(self, ph, slot_blocks) -> None:
+        """``kv_write_blocks`` on a mixed round's ``pw.round.build`` and in
+        the pool's counters: the distinct pool blocks the round's tokens
+        land in (the null block once, where tokens are diverted to it),
+        which is what the K/V writer moves a layer and pool."""
+        blocks = int(np.unique(slot_blocks).size)
+        ph.set(kv_write_blocks=blocks)
+        self.pool.stats.record_write_blocks(blocks)
+
     def _row_extras(self, seq_ids: list, ph) -> tuple:
         """The cache's own per-row arrays of a step, in row order (a
         hybrid cache: the rows' conv slots, noted on ``pw.round.build`` as
@@ -2175,6 +2184,7 @@ class PagedDecodeEngine:
                waiting=len(waiting))
         self._note_keys(ph, [int(c) for c in
                              row_start[:row] + row_nvalid[:row]])
+        self._note_write_blocks(ph, sb[:t])
         host = (tokens, positions, row_tables, row_start, row_nvalid,
                 row_token_idx, tok_row, tok_col, sb, so, logit_idx) \
             + self._row_extras([act.seq_id for act, _r, _f in rows], ph)

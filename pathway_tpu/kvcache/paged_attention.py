@@ -29,6 +29,14 @@ gather a span themselves through the table, two buffers deep
 cannot slice the pool for a copy: K = 1, the block comes through its block
 spec.
 
+The K/V writer (PR 30): a mixed step's new rows reach the pool through
+:func:`paged_write_rows`, one kernel call a layer for both pools, in whole
+blocks: the grid runs over the packed tokens, a token's block comes and
+goes through one aliased block spec, and the tokens of a run patch the
+block while it is resident.  (The decode step appends inside its attention
+kernel; a mixed step cannot, because a row may attend what another row
+writes in the same step.)
+
 Round-8 raggedness (the fused mixed decode/prefill step):
 
 - every row carries ``C >= 1`` query tokens at CONSECUTIVE positions -
@@ -642,6 +650,67 @@ def _make_paged_append():
 _paged_append = _make_paged_append()
 
 
+def _write_kernel(li_ref, sb_ref, so_ref, k1_ref, v1_ref, k_in, v_in, ko_ref,
+                  vo_ref):
+    """Grid: (T,) - the packed tokens in stream order.  Token t's pool
+    blocks are ``(layer, slot_blocks[t])`` on the way in and, aliased, on
+    the way out.  Consecutive tokens of a run land in one block, and a
+    block whose index does not change between grid steps is neither
+    fetched again nor flushed: the first token of a block copies it from
+    the input and patches its own row, the later ones patch the resident
+    output block, which goes back to HBM whole when the stream moves on."""
+    t = pl.program_id(0)
+    first = (t == 0) | (sb_ref[t] != sb_ref[jnp.maximum(t - 1, 0)])
+    new_row = jax.lax.broadcasted_iota(
+        jnp.int32, (ko_ref.shape[0], 1), 0) == so_ref[t]
+    for new_ref, in_ref, out_ref in ((k1_ref, k_in, ko_ref),
+                                     (v1_ref, v_in, vo_ref)):
+        @pl.when(first)
+        def _fresh():
+            out_ref[:] = jnp.where(new_row, new_ref[:], in_ref[:])
+
+        @pl.when(jnp.logical_not(first))
+        def _resident():
+            out_ref[:] = jnp.where(new_row, new_ref[:], out_ref[:])
+
+
+def _paged_write_fn(k_rows, v_rows, k_pool, v_pool, layer, slot_blocks,
+                    slot_offsets, *, interpret: bool = False):
+    """k_rows/v_rows: (T, Hkv*hd) in the pools' dtype; pools
+    (L, num_blocks, BS, Hkv*hd) - ALL layers' stacked pool, returned
+    UPDATED at ``layer`` ((1,) int32), each pool ONE operand aliased in
+    place: only the blocks the stream names are written, as whole
+    (BS, Hkv*hd) blocks - the unit the chip moves without help (one bf16
+    row is half a packed sublane) - and nothing of the pool is copied or
+    changes its layout.  The block's shape is the pool's own: lanes that
+    are no whole tiles (a tp shard) come through the same block spec."""
+    T, D = k_rows.shape
+    BS = k_pool.shape[2]
+    row = pl.BlockSpec((None, 1, D), lambda t, *_: (t, 0, 0))
+    block = pl.BlockSpec((None, None, BS, D),
+                         lambda t, li, sb, so: (li[0], sb[t], 0, 0))
+    # alias indices count the scalar-prefetch operands: pools are operands
+    # 5/6 of (layer, sb, so, k_rows, v_rows, k_pool, v_pool)
+    return pl.pallas_call(
+        _write_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # layer, slot_blocks, slot_offsets
+            grid=(T,),
+            in_specs=[row, row, block, block],
+            out_specs=[block, block],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
+        input_output_aliases={5: 0, 6: 1},
+        interpret=interpret,
+    )(layer, slot_blocks, slot_offsets, k_rows.reshape(T, 1, D),
+      v_rows.reshape(T, 1, D), k_pool, v_pool)
+
+
+_paged_write = jax.jit(_paged_write_fn, static_argnames=("interpret",),
+                       donate_argnums=(2, 3))
+
+
 def _stacked(k_pool, v_pool, layer):
     """Resolve the two pool conventions to (stacked pools, (1,) int32
     layer): ``layer=None`` means one layer's (num_blocks, BS, H*hd)
@@ -655,6 +724,51 @@ def _stacked(k_pool, v_pool, layer):
 def _layer_of(pool, layer):
     """One layer's (num_blocks, BS, H*hd) pool, for the gather reference."""
     return pool if layer is None else pool[layer]
+
+
+def paged_write_rows(k_pool, v_pool, slot_blocks, slot_offsets, k_rows,
+                     v_rows, *, layer=None, use_pallas: bool | None = None,
+                     interpret: bool | None = None):
+    """``pool[layer, slot_blocks[t], slot_offsets[t]] = rows[t]`` for every
+    token t of a packed stream: how a mixed step's new K/V reaches the
+    pool, one call a layer for both pools.
+
+    k_rows/v_rows: (T, Hkv, hd); slot_blocks/slot_offsets: (T,) int32;
+    pools as in :func:`paged_attention` (``layer=None`` one layer's
+    slices, ``layer=i`` the stacked pool updated IN PLACE at layer i).
+    Returns ``(k_pool, v_pool)``.  On the reference path an
+    ``.at[].set``; the kernel (:func:`_paged_write_fn`) writes whole blocks
+    instead of rows - beside the attention kernels an XLA scatter wants
+    the pool in a layout of its own and converts both pools around every
+    layer (PERF.md, PR 21), and a loop of one-row updates costs a
+    microsecond a row and pool (PR 30).
+
+    The write set is the scatter's: the other rows of a touched block keep
+    their bits, untouched blocks are not written, and the returned pools
+    carry every token's row, so an attention call that takes them gathers
+    what the SAME step wrote.  The engine's contract makes blocks
+    independent of each other: the tokens of a block are neighbours in the
+    stream, and no two rows of a step write the same real block
+    (``PagedDecodeEngine._build_mixed``).  Only the null block 0 - padding
+    and tokens diverted from a shared prefix - is revisited out of order;
+    it ends up holding any of their rows, and nothing reads it."""
+    T = k_rows.shape[0]
+    k_rows = k_rows.reshape(T, -1).astype(k_pool.dtype)
+    v_rows = v_rows.reshape(T, -1).astype(v_pool.dtype)
+    backend = jax.default_backend()
+    if use_pallas is None:
+        use_pallas = backend == "tpu"
+    if not use_pallas:
+        at = (slot_blocks, slot_offsets) if layer is None \
+            else (layer, slot_blocks, slot_offsets)
+        return k_pool.at[at].set(k_rows), v_pool.at[at].set(v_rows)
+    kk, vv, li = _stacked(k_pool, v_pool, layer)
+    kk, vv = _paged_write(
+        k_rows, v_rows, kk, vv, li, jnp.asarray(slot_blocks, jnp.int32),
+        jnp.asarray(slot_offsets, jnp.int32),
+        interpret=(backend != "tpu") if interpret is None else interpret,
+    )
+    return (kk[0], vv[0]) if layer is None else (kk, vv)
 
 
 def paged_append_attend(q, k_new, v_new, k_pool, v_pool, block_tables,
@@ -680,14 +794,13 @@ def paged_append_attend(q, k_new, v_new, k_pool, v_pool, block_tables,
     place — the standalone scatter disappears).  The kernel reads the
     pool's blocks as they lie in HBM: nothing is padded."""
     backend = jax.default_backend()
-    B, hd = q.shape[0], q.shape[-1]
+    hd = q.shape[-1]
     if use_pallas is None:
         use_pallas = backend == "tpu"
     if not use_pallas:
-        at = (slot_blocks, slot_offsets) if layer is None \
-            else (layer, slot_blocks, slot_offsets)
-        k_pool = k_pool.at[at].set(k_new.reshape(B, -1))
-        v_pool = v_pool.at[at].set(v_new.reshape(B, -1))
+        k_pool, v_pool = paged_write_rows(
+            k_pool, v_pool, slot_blocks, slot_offsets, k_new, v_new,
+            layer=layer, use_pallas=False)
         a = paged_attention_reference(
             q, _layer_of(k_pool, layer), _layer_of(v_pool, layer),
             block_tables, context_lens,

@@ -605,29 +605,6 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, k_pool: jax.Array,
     return out, k_pool, v_pool
 
 
-def _write_rows(k_pool, v_pool, layer: int, slot_blocks, slot_offsets,
-                k_rows, v_rows):
-    """``pool[layer, slot_blocks[t], slot_offsets[t]] = rows[t]`` for every
-    packed token t, as in-place row updates in token order: one
-    contiguous (H*hd,) row a token.  Beside the attention kernel an XLA
-    scatter wants the pool in a layout of its own, and on the chip the
-    program then converted both whole pools back and forth around every
-    layer (PERF.md, PR 21); an update of one row keeps whatever layout
-    the pool has.  Padding tokens all write the null block's first slot,
-    which nothing reads.  k_rows/v_rows: (T, H, hd)."""
-    T = k_rows.shape[0]
-    k_rows = k_rows.reshape(T, 1, 1, 1, -1).astype(k_pool.dtype)
-    v_rows = v_rows.reshape(T, 1, 1, 1, -1).astype(v_pool.dtype)
-
-    def body(t, pools):
-        kp, vp = pools
-        at = (layer, slot_blocks[t], slot_offsets[t], 0)
-        return (jax.lax.dynamic_update_slice(kp, k_rows[t], at),
-                jax.lax.dynamic_update_slice(vp, v_rows[t], at))
-
-    return jax.lax.fori_loop(0, T, body, (k_pool, v_pool))
-
-
 def paged_mixed_step(params: dict, cfg: DecoderConfig, k_pool: jax.Array,
                      v_pool: jax.Array, tokens: jax.Array,
                      positions: jax.Array, row_tables: jax.Array,
@@ -657,8 +634,10 @@ def paged_mixed_step(params: dict, cfg: DecoderConfig, k_pool: jax.Array,
       packed-form per-token gather would move the row's whole context
       T times per layer.
 
-    Per layer, all T tokens' K/V is scattered into the pool slots FIRST,
-    then attention reads back masked to ``row_start + c + 1`` per query
+    Per layer, all T tokens' K/V enters the pool slots FIRST
+    (``paged_write_rows``: one kernel call a layer that writes both pools
+    block by block, in place), then attention reads back masked to
+    ``row_start + c + 1`` per query
     column — a chunk token therefore sees every earlier chunk, the same
     dispatch's earlier tokens of its own run, and itself: exactly the
     causal set the dense prefill masks to.  The per-layer math mirrors
@@ -681,10 +660,10 @@ def paged_mixed_step(params: dict, cfg: DecoderConfig, k_pool: jax.Array,
     Under ``tp_axis`` the first element is the greedily sampled (B,)
     int32 ids instead (_head_out).
     """
-    from ..kvcache.paged_attention import (paged_attention,
-                                           paged_attention_reference)
+    from ..kvcache.paged_attention import paged_attention, paged_write_rows
 
     dtype = _resolve_dtype(cfg.dtype)
+    kernels = attn == "pallas"
     T = tokens.shape[0]
     hd = cfg.d_model // cfg.n_heads
     # padding tokens may carry position 0 already; clamp defensively so a
@@ -701,24 +680,17 @@ def paged_mixed_step(params: dict, cfg: DecoderConfig, k_pool: jax.Array,
         k1 = k1.reshape(T, -1, hd)
         v1 = v1.reshape(T, -1, hd)
         q_rows = q[row_token_idx]  # (B, C, H[/tp], hd)
-        if attn == "pallas":
-            k_pool, v_pool = _write_rows(
-                k_pool, v_pool, li, slot_blocks, slot_offsets, k1, v1
-            )
-            a_rows = paged_attention(
-                q_rows, k_pool, v_pool, row_tables,
-                start_pos=row_start, n_valid=row_nvalid, layer=li,
-                use_pallas=True,
-            )
-        else:
-            k_pool = k_pool.at[li, slot_blocks, slot_offsets].set(
-                k1.reshape(T, -1))
-            v_pool = v_pool.at[li, slot_blocks, slot_offsets].set(
-                v1.reshape(T, -1))
-            a_rows = paged_attention_reference(
-                q_rows, k_pool[li], v_pool[li], row_tables,
-                start_pos=row_start, n_valid=row_nvalid,
-            )
+        # every token's row lands before any row's attention gathers
+        # (the returned pools carry the dependence): a reader of a shared
+        # prefix may attend what its writer fills in this same step
+        k_pool, v_pool = paged_write_rows(
+            k_pool, v_pool, slot_blocks, slot_offsets, k1, v1, layer=li,
+            use_pallas=kernels,
+        )
+        a_rows = paged_attention(
+            q_rows, k_pool, v_pool, row_tables, start_pos=row_start,
+            n_valid=row_nvalid, layer=li, use_pallas=kernels,
+        )
         a = a_rows[tok_row, tok_col]  # back to the packed (T, H[/tp], hd)
         x = x + _row_proj(layer, a.reshape(T, -1), "wo", "bo", tp_axis)
         h = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"], eps)

@@ -226,10 +226,8 @@ def _forward(params: dict, cfg: Lfm2Config, k_pool, v_pool, conv, tokens,
     the attention layers take the fused append+attend kernel.  Returns
     ``(logits (B, V) f32, k_pool, v_pool, conv, counts (E,))``."""
     from ..kvcache.paged_attention import (paged_append_attend,
-                                           paged_attention,
-                                           paged_attention_reference)
+                                           paged_attention, paged_write_rows)
     from ..ops.moe import expert_ffn
-    from .decoder import _write_rows
 
     T = tokens.shape[0]
     hd, eps = cfg.head_dim, cfg.norm_eps
@@ -259,22 +257,15 @@ def _forward(params: dict, cfg: Lfm2Config, k_pool, v_pool, conv, tokens,
                     row_start + 1, slot_blocks, slot_offsets, layer=ai,
                     use_pallas=True)
                 a = a[:, 0]
-            elif kernels:
-                k_pool, v_pool = _write_rows(k_pool, v_pool, ai, slot_blocks,
-                                             slot_offsets, k1, v1)
+            else:
+                # all rows land before any row's attention gathers
+                k_pool, v_pool = paged_write_rows(
+                    k_pool, v_pool, slot_blocks, slot_offsets, k1, v1,
+                    layer=ai, use_pallas=kernels)
                 a = paged_attention(
                     q[row_token_idx], k_pool, v_pool, row_tables,
                     start_pos=row_start, n_valid=row_nvalid, layer=ai,
-                    use_pallas=True)[tok_row, tok_col]
-            else:
-                k_pool = k_pool.at[ai, slot_blocks, slot_offsets].set(
-                    k1.reshape(T, -1).astype(k_pool.dtype))
-                v_pool = v_pool.at[ai, slot_blocks, slot_offsets].set(
-                    v1.reshape(T, -1).astype(v_pool.dtype))
-                a = paged_attention_reference(
-                    q[row_token_idx], k_pool[ai], v_pool[ai], row_tables,
-                    start_pos=row_start, n_valid=row_nvalid,
-                )[tok_row, tok_col]
+                    use_pallas=kernels)[tok_row, tok_col]
             x = x + a.reshape(T, -1).astype(dtype) @ lay["wo"]
             ai += 1
         else:

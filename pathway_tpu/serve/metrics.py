@@ -165,6 +165,9 @@ class KVCacheStats:
       rows once a step - over the key lanes their live grid steps spanned,
       every context rounded up to whole spans of 128; keys/lanes = how
       full the kernels' score registers run)
+    - ``pathway_kv_write_blocks_total{pool}``  counter (distinct pool
+      blocks the mixed steps' tokens landed in: what the K/V writer moves
+      a layer and pool, against ``mixed_tokens_used`` rows)
     - ``pathway_kv_spec_proposed_total{pool}``  counter (Round-18: draft
       tokens proposed into verify dispatches)
     - ``pathway_kv_spec_accepted_total{pool}``  counter (draft tokens the
@@ -220,6 +223,7 @@ class KVCacheStats:
         self.mixed_tokens_budget = 0
         self.kv_keys = 0
         self.kv_key_lanes = 0
+        self.kv_write_blocks = 0
         # Round-18 speculative decoding: proposed/accepted/rejected draft
         # tokens, total verify-emitted tokens and verify dispatches
         self.spec_proposed = 0
@@ -346,6 +350,11 @@ class KVCacheStats:
             self.kv_keys += keys
             self.kv_key_lanes += lanes
 
+    def record_write_blocks(self, blocks: int) -> None:
+        """Distinct pool blocks one mixed step's tokens landed in."""
+        with self._lock:
+            self.kv_write_blocks += blocks
+
     def record_engine_restart(self, rebuild_seconds: float) -> None:
         """One supervised engine restart (pool rebuild time only; the
         failure -> first-recovered-token window lands separately via
@@ -446,6 +455,7 @@ class KVCacheStats:
                 "mixed_tokens_budget": self.mixed_tokens_budget,
                 "kv_keys": self.kv_keys,
                 "kv_key_lanes": self.kv_key_lanes,
+                "kv_write_blocks": self.kv_write_blocks,
                 "spec_proposed": self.spec_proposed,
                 "spec_accepted": self.spec_accepted,
                 "spec_rejected": self.spec_rejected,
@@ -889,6 +899,7 @@ def _render_kv_lines() -> list[str]:
         "# TYPE pathway_kv_mixed_tokens_budget_total counter",
         "# TYPE pathway_kv_attended_keys_total counter",
         "# TYPE pathway_kv_attended_key_lanes_total counter",
+        "# TYPE pathway_kv_write_blocks_total counter",
         "# TYPE pathway_kv_spec_proposed_total counter",
         "# TYPE pathway_kv_spec_accepted_total counter",
         "# TYPE pathway_kv_spec_rejected_total counter",
@@ -1015,6 +1026,10 @@ def _render_kv_lines() -> list[str]:
         lines.append(
             f"pathway_kv_attended_key_lanes_total{{{lbl}}} "
             f"{snap['kv_key_lanes']}"
+        )
+        lines.append(
+            f"pathway_kv_write_blocks_total{{{lbl}}} "
+            f"{snap['kv_write_blocks']}"
         )
         # Round-18 speculative decoding: draft proposal/acceptance flow
         lines.append(

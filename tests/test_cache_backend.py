@@ -14,7 +14,10 @@ donated, and the function names the device trace carries
 """
 
 import ast
+import json
+import os
 import random
+import re
 from collections import deque
 
 import jax
@@ -70,8 +73,8 @@ def _engine(kind, params, lfm2, name, **kw):
     if kind == "paged":
         return PagedDecodeEngine(_CFG, params, name=name, **geom)
     cfg, lparams = lfm2
-    return PagedDecodeEngine(cfg, lparams, name=name, attn="reference",
-                             **geom)
+    geom.setdefault("attn", "reference")
+    return PagedDecodeEngine(cfg, lparams, name=name, **geom)
 
 
 def _nbytes(arrays) -> int:
@@ -393,6 +396,42 @@ def test_a_family_names_its_three_programs_for_the_trace(kind, sampled,
         "step": "pw.decode_step" + sfx, "mixed": "pw.mixed_step" + sfx,
         "chained": "pw.chained_decode" + sfx}
     assert len(eng.pool.device_state()) == n
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_mixed_program_names_its_paged_kernels(kind, params, lfm2):
+    """The device trace names a kernel's events by the jitted function
+    that holds the call: ``paged_attn_roofline`` /
+    ``paged_attn_gqa_roofline`` read ``^_paged_(append|ragged)_fn`` and
+    ``kv_write_ms`` reads the mixed step's K/V writer, which the
+    rooflines must not count.  Each is one function of the lowered
+    program however many layers call it."""
+    eng = _engine(kind, params, lfm2, f"t_cb_kernels_{kind}", attn="pallas")
+    mixed, texts = eng._mixed, []
+
+    def lowering_mixed(*args):
+        if not texts:
+            texts.append(mixed.lower(*args).as_text())
+        return mixed(*args)
+
+    eng._mixed = lowering_mixed
+    eng.generate(list(range(1, 12)), 2)
+    funcs = re.findall(r"func\.func \w+ @(\w+)\(", texts[0])
+    kernels = sorted(f for f in funcs if f.startswith("_paged_"))
+    assert kernels == ["_paged_ragged_fn", "_paged_write_fn"]
+    metrics = os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "metrics")
+    reads = {}
+    for metric in ("kv_write_ms", "paged_attn_roofline",
+                   "paged_attn_gqa_roofline"):
+        with open(os.path.join(metrics, metric + ".json")) as f:
+            pattern = json.load(f)["pattern"]
+        reads[metric] = [k for k in kernels + ["_paged_append_fn"]
+                         if re.match(pattern, k + ".7")]
+    assert reads == {
+        "kv_write_ms": ["_paged_write_fn"],
+        "paged_attn_roofline": ["_paged_ragged_fn", "_paged_append_fn"],
+        "paged_attn_gqa_roofline": ["_paged_ragged_fn", "_paged_append_fn"]}
 
 
 def test_the_verify_program_is_the_familys_mixed_program(params, lfm2):

@@ -162,18 +162,42 @@ def test_paged_kernels_compile_at_lfm2_spans(shape):
         assert _pool_copies(compiled, pool.shape) == []
 
 
-def _mixed_like(q, k1, v1, kp, vp, sb, so, bt, c0, cl):
-    """What a mixed step does to the pool, over two layers: every packed
-    token's row written in place, then the ragged kernel over the stacked
-    pool."""
+@pytest.mark.parametrize(
+    "layers,blocks,heads",
+    [(L, NBLK, H), (3, 2049, 8), (L, NBLK, H // 4)],
+    ids=["gpt2_large", "lfm2", "tp4_shard"])
+def test_paged_write_kernel_compiles(shape, layers, blocks, heads):
+    """The mixed step's K/V writer alone: 48 packed tokens' rows into the
+    stacked pool of GPT-2-large (1280 lanes), of LFM2-8B-A1B's three
+    attention layers (512) and of a tp=4 shard (320: no whole tiles, the
+    block spec spans the dims), both pools aliased in place."""
     import jax.numpy as jnp
 
-    from pathway_tpu.models.decoder import _write_rows
+    pa = _paged()
+    T, i32 = B + CHUNK, jnp.int32
+    pool = shape((layers, blocks, BS, heads * HD), jnp.bfloat16)
+    rows = shape((T, heads * HD), jnp.bfloat16)
+    compiled = _compiled_kernel(
+        pa._paged_write_fn, rows, rows, pool, pool, shape((1,), i32),
+        shape((T,), i32), shape((T,), i32), donate_argnums=(2, 3))
+    copies = _pool_copies(compiled, pool.shape)
+    print(f"heads={heads}: pool-sized copies {len(copies)}")
+    if heads * HD % 128 == 0:
+        assert copies == []
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def _mixed_like(q, k1, v1, kp, vp, sb, so, bt, c0, cl):
+    """What a mixed step does to the pool, over two layers: every packed
+    token's row written block by block by the writer kernel, then the
+    ragged kernel over the stacked pool."""
+    import jax.numpy as jnp
 
     pa = _paged()
     out = []
     for li in range(2):
-        kp, vp = _write_rows(kp, vp, li, sb, so, k1, v1)
+        kp, vp = pa.paged_write_rows(kp, vp, sb, so, k1, v1, layer=li,
+                                     use_pallas=True, interpret=False)
         out.append(pa._paged_ragged_fn(
             q, kp, vp, jnp.full((1,), li, jnp.int32), bt, c0, cl,
             d_true=HD))
@@ -336,8 +360,9 @@ def test_lfm2_step_programs_keep_the_pool_where_it_is(shape, program,
         donate_argnums=(1, 2, 3),
     ).lower(params, pool, pool, arena, *args).compile()
     n_moe = cfg.n_layers - cfg.n_dense_layers
-    assert compiled.as_text().count("tpu_custom_call") \
-        == len(cfg.attn_layers) + 2 * n_moe
+    # a mixed step's attention layer is two calls: the writer, the kernel
+    n_attn = len(cfg.attn_layers) * (2 if program == "mixed" else 1)
+    assert compiled.as_text().count("tpu_custom_call") == n_attn + 2 * n_moe
     layouts = compiled.input_formats[0]
     for i in (1, 2):
         assert layouts[i].layout.major_to_minor == (0, 1, 2, 3), layouts[i]

@@ -108,15 +108,19 @@ def test_chunked_matches_dense_at_the_default_chunk(params):
     assert out == [_dense_greedy(params, p, 6) for p in prompts]
 
 
-def test_chunked_identity_under_shared_prefixes_same_round(params):
+@pytest.mark.parametrize("attn", ["reference", "pallas"])
+def test_chunked_identity_under_shared_prefixes_same_round(params, attn):
     # every prompt shares a two-block header and ALL are admitted in the
     # same round: later arrivals must map the first writer's IN-FLIGHT
     # blocks (lockstep gate) — physical sharing from round one, token
     # output untouched.  One prompt equals the header exactly (the
-    # fully-shared case recomputes only its final token)
+    # fully-shared case recomputes only its final token).  ``pallas``: the
+    # programs the chip runs, interpreted - a reader attends what the
+    # writer kernel put into the pool in the SAME dispatch
     eng = PagedDecodeEngine(
         _CFG, params, num_blocks=96, block_size=8, max_batch_size=8,
-        seq_buckets=(32, 64), prefill_chunk=16, name="t_r8_prefix",
+        seq_buckets=(32, 64), prefill_chunk=16, attn=attn,
+        name=f"t_r8_prefix_{attn}",
     )
     header = [11] * 8 + [13] * 8
     prompts = [header + [20 + i, 30 + i] for i in range(5)] + [list(header)]
@@ -454,6 +458,80 @@ def test_ragged_kernel_matches_reference_interpreted(C, H, hd, BS, NB, sp,
                 np.asarray(got)[b, c], np.asarray(want)[b, c],
                 rtol=2e-5, atol=2e-5,
             )
+
+
+# -- the mixed step's K/V writer ---------------------------------------------
+
+# Streams as ``_build_mixed`` packs them, (block, offset) a token over
+# blocks of 16: decode rows first (one token, a block of their own), then
+# chunk runs; 0 is the null block (padding, tokens diverted from a shared
+# prefix), the only block a stream comes back to.
+def _run(blocks, start, n):
+    """A chunk run: ``n`` positions from ``start``, through ``blocks``
+    (the row's table from the block that holds ``start`` on)."""
+    return [(blocks[p // 16 - start // 16], p % 16)
+            for p in range(start, start + n)]
+
+
+_STREAMS = {
+    # three decode rows, a run that starts mid-block and crosses one
+    # boundary, a run that crosses two, padding at the tail
+    "decode_then_runs": [(3, 4), (5, 15), (9, 0)] + _run([7, 8], 13, 9)
+    + _run([11, 12, 13], 12, 22) + [(0, 0)] * 4,
+    # a run that ends its block exactly, then a second chunk the budget
+    # cut to five tokens, no padding
+    "block_end_and_budget_cut": [(2, 7)] + _run([4, 6], 16, 16)
+    + _run([10, 14], 30, 5),
+    # a reader of a shared prefix: its first tokens go to the null block
+    # between the writer's run and its own real ones, a decode row before
+    "diverted_between_real": [(3, 1)] + _run([5, 6], 0, 20)
+    + [(0, p) for p in range(6)] + _run([8, 9], 6, 12) + [(0, 0)] * 3,
+    # every token a decode row: no block holds two
+    "decode_only": [(b, (5 * b) % 16) for b in (1, 4, 2, 13, 7, 6)]
+    + [(0, 0)] * 2,
+}
+
+
+@pytest.mark.parametrize("stream", list(_STREAMS))
+@pytest.mark.parametrize("H,hd", [(20, 64), (8, 64), (5, 64)],
+                         ids=["d1280", "d512", "d320_tp_shard"])
+def test_write_kernel_matches_the_scatter_bit_for_bit(H, hd, stream):
+    """The writer kernel (interpreted) against ``.at[layer, sb, so].set``
+    on a bf16 pool in BlockPool's shape: every packed token's row where
+    the scatter puts it, every other row of a touched block and every
+    untouched block with the bits it had, the other layers untouched;
+    the null block may hold any of the rows sent to it."""
+    from pathway_tpu.kvcache.paged_attention import paged_write_rows
+
+    rng = np.random.default_rng(7)
+    L, NBLK, BS, layer = 3, 15, 16, 1
+    sb, so = (jnp.asarray(x, jnp.int32) for x in zip(*_STREAMS[stream]))
+    T = sb.shape[0]
+    real = np.asarray(sb) > 0
+    assert len(set(zip(np.asarray(sb)[real], np.asarray(so)[real]))) \
+        == real.sum()  # the engine's contract: no real slot twice
+
+    def draw(*dims):
+        return jnp.asarray(rng.standard_normal(dims), jnp.bfloat16)
+
+    k_pool, v_pool = draw(L, NBLK, BS, H * hd), draw(L, NBLK, BS, H * hd)
+    k1, v1 = draw(T, H, hd), draw(T, H, hd)
+    want = paged_write_rows(k_pool, v_pool, sb, so, k1, v1, layer=layer,
+                            use_pallas=False)
+    # the kernel's entry point donates its pools: hand it copies
+    got = paged_write_rows(jnp.array(k_pool), jnp.array(v_pool), sb, so, k1,
+                           v1, layer=layer, use_pallas=True, interpret=True)
+    for before, w, g, rows in zip((k_pool, v_pool), want, got, (k1, v1)):
+        before, w, g = (np.asarray(x).view(np.uint16) for x in (before, w, g))
+        assert (g[:, 1:] == w[:, 1:]).all()
+        assert (g[[0, 2]] == before[[0, 2]]).all()
+        rows = np.asarray(rows).view(np.uint16).reshape(T, -1)
+        assert (g[layer, np.asarray(sb)[real], np.asarray(so)[real]]
+                == rows[real]).all()
+        # the null block: each slot its old row or one sent to block 0
+        sent = rows[~real]
+        for r, old in zip(g[layer, 0], before[layer, 0]):
+            assert (r == old).all() or (sent == r).all(axis=1).any()
 
 
 # -- continuous batching: arrivals never stall in-flight decodes -------------

@@ -227,6 +227,32 @@ def test_build_counts_the_keys_its_rows_attend(params, wide_params,
                                        for s in builds)
 
 
+@pytest.mark.parametrize("shared", [False, True], ids=["alone", "shared"])
+def test_build_counts_the_blocks_its_tokens_land_in(params, shared):
+    """``kv_write_blocks`` on the ``pw.round.build`` of mixed rounds: the
+    distinct pool blocks the K/V writer moves a layer.  A prompt of 21
+    tokens in chunks of 8 over blocks of 4 lands in 2 + 2 + 2 blocks
+    (the last chunk's five tokens reach into a second one).  A second
+    prompt that shares the first 20 tokens and comes a run later maps the
+    five resident blocks and streams position 20 alone: one block."""
+    eng = _engine(params, f"t_wblocks_{shared}")
+    prompt = _prompts((21,))[0]
+    eng.generate_batch([(prompt, 2)])
+    want = 6
+    if shared:
+        eng.generate_batch([(prompt[:20] + [(prompt[20] + 1) % 64], 2)])
+        want += 1
+    builds = [s for s in obs.recorder().snapshot()
+              if s.name == "pw.round.build"]
+    mixed = [s for s in builds if s.attrs["kind"] == "mixed"]
+    assert all("kv_write_blocks" not in s.attrs for s in builds
+               if s.attrs["kind"] != "mixed")
+    assert all(1 <= s.attrs["kv_write_blocks"] <= s.attrs["tokens"]
+               for s in mixed)
+    assert sum(s.attrs["kv_write_blocks"] for s in mixed) == want
+    assert eng.pool.stats.snapshot()["kv_write_blocks"] == want
+
+
 def test_round_counters_are_on_metrics(params):
     """The operator's view of the same seconds: /metrics carries the
     round's time by phase and the mixed steps' fill, beside the host gap."""
@@ -252,6 +278,9 @@ def test_round_counters_are_on_metrics(params):
     assert f"pathway_kv_attended_key_lanes_total{{{lbl}}} " \
         f"{snap['kv_key_lanes']}" in lines
     assert 0 < snap["kv_keys"] <= snap["kv_key_lanes"]
+    assert f"pathway_kv_write_blocks_total{{{lbl}}} " \
+        f"{snap['kv_write_blocks']}" in lines
+    assert 0 < snap["kv_write_blocks"] <= snap["mixed_tokens_used"]
     assert any(x.startswith(f"pathway_kv_host_gap_seconds_total{{{lbl}}}")
                for x in lines)
 
