@@ -31,10 +31,12 @@ reduction — no replicated [B, vocab] gather ever materializes).
 single-device programs.
 
 The engine<->cache contract is backend.py (``CacheBackend`` +
-``make_backend``), with two kinds behind it: ``"paged"`` (block_pool.py
-``BlockPool``: K/V blocks for every layer) and ``"hybrid"`` (hybrid.py
+``make_backend``), with three kinds behind it: ``"paged"`` (block_pool.py
+``BlockPool``: K/V blocks for every layer), ``"hybrid"`` (hybrid.py
 ``HybridCache``: K/V blocks for a model's attention layers and a conv
-slot a sequence beside them).  Which kind an engine builds, and which
+slot a sequence beside them) and ``"windowed"`` (windowed.py
+``WindowedCache``: K/V blocks for a model's full-attention layers and a
+second pool, freed behind the window, for its sliding-window layers).  Which kind an engine builds, and which
 step programs it runs, its block family says (models/families.py).
 
 Round-18 (ARCHITECTURE.md "Round-18: Speculative decoding") breaks the
@@ -60,6 +62,7 @@ from .prefix_cache import PrefixCache
 from .speculative import (Drafter, DraftModelDrafter, NGramDrafter,
                           SpecController, SpecResourceError)
 from .tiering import SessionStore
+from .windowed import WindowedCache
 
 __all__ = [
     "Drafter",
@@ -77,6 +80,7 @@ __all__ = [
     "PrefixCache",
     "PagedDecodeEngine",
     "UnsupportedCacheOp",
+    "WindowedCache",
     "make_backend",
     "resolve_tp",
     "paged_attention",

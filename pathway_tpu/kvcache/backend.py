@@ -2,7 +2,7 @@
 
 The engine (engine.py: admission, rounds, restart, sessions) programs
 against :class:`CacheBackend`, not against a layout; how a sequence's
-decode state lives in HBM is the backend's.  Two kinds serve cells:
+decode state lives in HBM is the backend's.  Three kinds serve cells:
 
 - ``"paged"`` — :class:`~pathway_tpu.kvcache.block_pool.BlockPool`: K/V
   blocks for every layer, addressed through per-sequence block tables;
@@ -12,6 +12,12 @@ decode state lives in HBM is the backend's.  Two kinds serve cells:
   conv slot a sequence for its short-conv layers.  Preemption only: a
   shared, forked or resumed block would skip the tokens that build the
   conv state.
+- ``"windowed"`` — :class:`~pathway_tpu.kvcache.windowed.WindowedCache`:
+  the K/V blocks of a model's full-attention layers and, in a second pool
+  with a block table of its own, those of its sliding-window layers, whose
+  blocks go back to their free list once they lie behind the window.
+  Preemption only: a shared block could be freed behind one sequence's
+  window while another still reads it.
 
 A backend owns:
 
@@ -22,7 +28,8 @@ A backend owns:
 - **device state**: ``device_state()`` / ``set_device_state(...)`` — the
   arrays a step program takes after the params and gives back (donated),
   and ``row_extras`` — further per-row host arrays the programs take
-  (the hybrid kind: the rows' conv slots);
+  (the hybrid kind: the rows' conv slots; the windowed kind: the rows'
+  window tables);
 - **byte accounting**: ``per_shard_bytes`` — what the backend pins in
   each tensor-parallel shard's HBM, the number ``obs/memory.py
   hbm_plan`` charges;
@@ -63,8 +70,11 @@ class CacheBackend(abc.ABC):
     """Abstract engine↔cache contract.  See the module docstring for
     which side owns which invariant."""
 
-    #: "paged" | "hybrid" — the factory key
+    #: "paged" | "hybrid" | "windowed" — the factory key
     cache_kind: str = "abstract"
+    #: positions a sliding-window layer's query sees (itself included);
+    #: None: every layer keeps every key
+    window: int | None = None
     #: optional capabilities the paged engine consults
     supports_fork: bool = False
     supports_prefix: bool = False
@@ -139,15 +149,47 @@ class CacheBackend(abc.ABC):
         """Unregister from metrics; default no-op."""
 
     # -- per-row state beside the block tables -----------------------------
-    def row_extras(self, seq_ids, n_rows: int) -> tuple:
-        """Further ``(n_rows,)`` arrays a step program takes after the
+    def row_extras(self, seq_ids, n_rows: int, table_blocks: int = 0) -> tuple:
+        """Further ``(n_rows, ...)`` arrays a step program takes after the
         paged ones, one entry a batch row in ``seq_ids``' order (a hybrid
-        cache: the rows' conv slots).  None by default."""
+        cache: the rows' conv slots; a windowed cache: the rows' window
+        tables, ``table_blocks`` wide as the block tables are).  None by
+        default."""
         return ()
+
+    def reserve_chunk(self, seq_id, end: int) -> None:
+        """A round is about to compute the sequence's prompt positions
+        below ``end``.  ``allocate`` has claimed whatever grows with the
+        whole prompt; a cache that claims by progress instead (a windowed
+        cache's window blocks) does so here.  Default no-op."""
 
     def after_sync(self) -> None:
         """Every program dispatched so far has finished: fold what the
         programs counted on the device into the stats.  Default no-op."""
+
+
+class ExpertCounts:
+    """The tokens each expert received, counted by the step programs on
+    the device (``int32[n_experts]`` a program): kept as they come back,
+    read after the next sync (``moe_routed_pairs`` /
+    ``moe_tokens_per_expert`` of the cache's stats).  For the caches of
+    families with expert layers."""
+
+    _expert_counts: tuple = ()  # the programs' counts not yet read back
+
+    def keep_expert_counts(self, counts) -> None:
+        try:
+            counts.copy_to_host_async()
+        except Exception:  # noqa: BLE001 - optional fast path (CPU arrays)
+            pass
+        self._expert_counts = (*self._expert_counts, counts)
+
+    def fold_expert_counts(self) -> None:
+        import numpy as np
+
+        pending, self._expert_counts = self._expert_counts, ()
+        for counts in pending:
+            self.stats.record_moe(np.asarray(counts))
 
 
 _BACKENDS: dict[str, Callable] = {}
@@ -162,7 +204,9 @@ def make_backend(kind: str, **kwargs) -> CacheBackend:
     restart-rebuild) their cache through.  ``"paged"`` →
     :class:`~pathway_tpu.kvcache.block_pool.BlockPool`; ``"hybrid"`` →
     :class:`~pathway_tpu.kvcache.hybrid.HybridCache` (K/V blocks for the
-    attention layers and a conv slot, one sequence)."""
+    attention layers and a conv slot, one sequence); ``"windowed"`` →
+    :class:`~pathway_tpu.kvcache.windowed.WindowedCache` (a second pool and
+    table for the sliding-window layers)."""
     if kind not in _BACKENDS:
         # lazy registration avoids import cycles: block_pool/hybrid
         # import nothing from here at module scope except the ABC
@@ -174,9 +218,14 @@ def make_backend(kind: str, **kwargs) -> CacheBackend:
             from .hybrid import HybridCache
 
             register_backend("hybrid", HybridCache)
+        elif kind == "windowed":
+            from .windowed import WindowedCache
+
+            register_backend("windowed", WindowedCache)
         else:
             raise ValueError(
                 f"unknown cache backend {kind!r}; "
-                f"registered: {sorted(_BACKENDS)} + builtin: paged, hybrid"
+                f"registered: {sorted(_BACKENDS)} + builtin: paged, hybrid, "
+                "windowed"
             )
     return _BACKENDS[kind](**kwargs)

@@ -558,6 +558,41 @@ class PagedDecodeEngine:
                 self.hbm_plan = self.hbm_plan.with_(num_blocks=clamped)
             else:
                 raise ValueError(self.hbm_plan.reject_message())
+        # the shapes of a round, before the cache is built: a cache may
+        # size itself by the most one round adds to a sequence
+        bs = int(block_size)
+        cap = min((num_blocks - 1) * bs, cfg.max_len)
+        if max_blocks_per_seq is None:
+            max_blocks_per_seq = -(-min(cfg.max_len, cap) // bs)
+        self.max_blocks_per_seq = int(max_blocks_per_seq)
+        self.max_seq_tokens = min(self.max_blocks_per_seq * bs, cfg.max_len)
+        # prompt buckets: block-aligned, capped at what one table can
+        # span (the cap rounded DOWN to a block multiple); the largest
+        # entry caps a prompt
+        bucket_cap = max((self.max_seq_tokens // bs) * bs, bs)
+        buckets = sorted({
+            min(-(-b // bs) * bs, bucket_cap) for b in seq_buckets
+        })
+        self.seq_buckets = buckets or [bucket_cap]
+        # chunk width: block-aligned (so chunk writes cover whole blocks
+        # except the prompt's tail), default two blocks per step — small
+        # enough that an arrival adds bounded latency to in-flight
+        # decodes, large enough to amortize the dispatch
+        if prefill_chunk is None:
+            prefill_chunk = 2 * bs
+        self.prefill_chunk = max(bs, min(-(-int(prefill_chunk) // bs) * bs,
+                                         bucket_cap))
+        # packed token budget of one ragged dispatch: every decode row
+        # costs one token, the rest is chunk headroom — so the mixed
+        # program's cost scales with B + chunk, never B x chunk
+        self.mixed_tokens = self.max_batch_size + self.prefill_chunk
+        # Round-10 device-resident multi-step decode: when the queue is
+        # quiet (no pending admissions, no mid-prefill chunks) the engine
+        # chains up to `chain_steps` greedy steps into ONE dispatch and
+        # syncs once per chain on a [B, K] ids array — K adapts back to 1
+        # the moment arrivals or preemption are pending, so TTFT and the
+        # step-boundary admission semantics are unchanged
+        self.chain_steps = max(1, int(chain_steps))
         # Round-13 failure domain: the pool's constructor args are kept so
         # a supervised restart can rebuild it from scratch (a failed or
         # hung dispatch may have consumed the donated K/V arrays).
@@ -567,9 +602,15 @@ class PagedDecodeEngine:
         self._pool_kwargs = dict(
             num_blocks=num_blocks, block_size=block_size,
             dtype=_resolve_dtype(cfg.dtype), name=name, mesh=self.mesh,
-            **self.family.cache_kwargs(cfg, self.max_batch_size),
+            **self.family.cache_kwargs(
+                cfg, self.max_batch_size,
+                max(self.prefill_chunk, self.chain_steps)),
         )
         self.pool = make_backend(self.family.cache_kind, **self._pool_kwargs)
+        # keys one grid step of the paged kernels spans (a lane tile: eight
+        # blocks of 16), for ``kv_key_lanes`` on ``pw.round.build``
+        self._span_keys = bs * span_blocks(
+            bs, self.max_blocks_per_seq, self.pool.k.shape[-1] // self.tp)
         # a cache that cannot share blocks (the hybrid one: a shared block
         # would skip the tokens that build the conv state) runs without a
         # prefix cache, whatever was asked for
@@ -615,43 +656,6 @@ class PagedDecodeEngine:
         # cleared by the first token emitted after the restart — the
         # failure -> first-recovered-token MTTR the bench reports
         self._t_failure: float | None = None
-        bs = self.pool.block_size
-        cap = min((num_blocks - 1) * bs, cfg.max_len)
-        if max_blocks_per_seq is None:
-            max_blocks_per_seq = -(-min(cfg.max_len, cap) // bs)
-        self.max_blocks_per_seq = int(max_blocks_per_seq)
-        self.max_seq_tokens = min(self.max_blocks_per_seq * bs, cfg.max_len)
-        # keys one grid step of the paged kernels spans (a lane tile: eight
-        # blocks of 16), for ``kv_key_lanes`` on ``pw.round.build``
-        self._span_keys = bs * span_blocks(
-            bs, self.max_blocks_per_seq, self.pool.k.shape[-1] // self.tp)
-        # prompt buckets: block-aligned, capped at what one table can
-        # span (the cap rounded DOWN to a block multiple); the largest
-        # entry caps a prompt
-        bucket_cap = max((self.max_seq_tokens // bs) * bs, bs)
-        buckets = sorted({
-            min(-(-b // bs) * bs, bucket_cap) for b in seq_buckets
-        })
-        self.seq_buckets = buckets or [bucket_cap]
-        # chunk width: block-aligned (so chunk writes cover whole blocks
-        # except the prompt's tail), default two blocks per step — small
-        # enough that an arrival adds bounded latency to in-flight
-        # decodes, large enough to amortize the dispatch
-        if prefill_chunk is None:
-            prefill_chunk = 2 * bs
-        self.prefill_chunk = max(bs, min(-(-int(prefill_chunk) // bs) * bs,
-                                         bucket_cap))
-        # packed token budget of one ragged dispatch: every decode row
-        # costs one token, the rest is chunk headroom — so the mixed
-        # program's cost scales with B + chunk, never B x chunk
-        self.mixed_tokens = self.max_batch_size + self.prefill_chunk
-        # Round-10 device-resident multi-step decode: when the queue is
-        # quiet (no pending admissions, no mid-prefill chunks) the engine
-        # chains up to `chain_steps` greedy steps into ONE dispatch and
-        # syncs once per chain on a [B, K] ids array — K adapts back to 1
-        # the moment arrivals or preemption are pending, so TTFT and the
-        # step-boundary admission semantics are unchanged
-        self.chain_steps = max(1, int(chain_steps))
         # host-gap accounting: perf_counter of the last device->host sync
         # (the device has nothing queued past it) — the next dispatch
         # closes the window and records it (see _note_sync/_note_dispatch).
@@ -1977,17 +1981,28 @@ class PagedDecodeEngine:
                 return True
             inflight = nxt
 
-    def _note_keys(self, ph, contexts) -> None:
+    def _note_keys(self, ph, contexts, queries=None) -> None:
         """What a round's calls of the paged kernels attend, on
         ``pw.round.build`` and in the pool's counters: ``kv_keys``, the
         live rows' context lengths summed (a chain's rows once a step),
         and ``kv_key_lanes``, the key lanes their live grid steps span
-        (every context rounded up to whole spans)."""
+        (every context rounded up to whole spans).  With a windowed cache
+        those are the full layers'; for a sliding-window layer
+        ``kv_window_keys``, the keys the rows' queries see there
+        (``queries``: a row's query columns, default one: the window of
+        its first column to its last key), and ``kv_window_ctx_keys``,
+        what they would see with no window (``kv_keys`` again)."""
         span = self._span_keys
         keys = sum(contexts)
         lanes = sum(-(-c // span) for c in contexts) * span
         ph.set(kv_keys=keys, kv_key_lanes=lanes)
         self.pool.stats.record_attended_keys(keys, lanes)
+        window = self.pool.window
+        if window is not None:
+            seen = sum(min(c, window + q - 1) for c, q in zip(
+                contexts, queries or [1] * len(contexts)))
+            ph.set(kv_window_keys=seen, kv_window_ctx_keys=keys)
+            self.pool.stats.record_window_keys(seen, keys)
 
     def _note_write_blocks(self, ph, slot_blocks) -> None:
         """``kv_write_blocks`` on a mixed round's ``pw.round.build`` and in
@@ -2001,9 +2016,10 @@ class PagedDecodeEngine:
     def _row_extras(self, seq_ids: list, ph) -> tuple:
         """The cache's own per-row arrays of a step, in row order (a
         hybrid cache: the rows' conv slots, noted on ``pw.round.build`` as
-        ``conv_rows``)."""
-        extras = self.pool.row_extras(seq_ids, self.max_batch_size)
-        if extras:
+        ``conv_rows``; a windowed cache: the rows' window tables)."""
+        extras = self.pool.row_extras(seq_ids, self.max_batch_size,
+                                      self.max_blocks_per_seq)
+        if extras and getattr(self.pool, "conv_slots", 0):
             ph.set(conv_rows=len(seq_ids))
         return extras
 
@@ -2134,6 +2150,7 @@ class PagedDecodeEngine:
                     if e <= s:
                         continue  # no safe progress: writer lags a round
             nv = e - s
+            self.pool.reserve_chunk(act.seq_id, e)
             pos = np.arange(s, e)
             tokens[t:t + nv] = act.tokens[s:e]
             positions[t:t + nv] = pos
@@ -2183,7 +2200,8 @@ class PagedDecodeEngine:
         ph.set(kind="mixed", rows=len(rows), tokens=t, budget=T,
                waiting=len(waiting))
         self._note_keys(ph, [int(c) for c in
-                             row_start[:row] + row_nvalid[:row]])
+                             row_start[:row] + row_nvalid[:row]],
+                        [int(q) for q in row_nvalid[:row]])
         self._note_write_blocks(ph, sb[:t])
         host = (tokens, positions, row_tables, row_start, row_nvalid,
                 row_token_idx, tok_row, tok_col, sb, so, logit_idx) \

@@ -28,11 +28,11 @@ import weakref
 import jax.numpy as jnp
 import numpy as np
 
-from .backend import UnsupportedCacheOp
+from .backend import ExpertCounts, UnsupportedCacheOp
 from .block_pool import BlockPool, PoolExhausted, SequenceState
 
 
-class HybridCache(BlockPool):
+class HybridCache(ExpertCounts, BlockPool):
     cache_kind = "hybrid"
     supports_fork = False
     supports_prefix = False
@@ -48,7 +48,6 @@ class HybridCache(BlockPool):
             pool_kwargs.get("dtype", jnp.float32))
         self._free_slots: list[int] = list(range(self.conv_slots, 0, -1))
         self._slot_of: dict[int, int] = {}
-        self._counts: list = []  # per-expert token counts not yet read back
         super().__init__(**pool_kwargs)
         wref = weakref.ref(self)
         self.stats.conv_slots_total = self.conv_slots
@@ -108,13 +107,9 @@ class HybridCache(BlockPool):
 
     def set_device_state(self, k, v, conv, counts) -> None:
         self.k, self.v, self.conv = k, v, conv
-        try:
-            counts.copy_to_host_async()
-        except Exception:  # noqa: BLE001 - optional fast path (CPU arrays)
-            pass
-        self._counts.append(counts)
+        self.keep_expert_counts(counts)
 
-    def row_extras(self, seq_ids, n_rows: int) -> tuple:
+    def row_extras(self, seq_ids, n_rows: int, table_blocks: int = 0) -> tuple:
         """(n_rows,) int32: each row's conv slot, the null slot for the
         rows past ``seq_ids``."""
         slots = np.zeros(n_rows, np.int32)
@@ -125,9 +120,7 @@ class HybridCache(BlockPool):
     def after_sync(self) -> None:
         """The tokens-per-expert counts of the programs that have finished
         (``moe_routed_pairs`` / ``moe_tokens_per_expert``)."""
-        pending, self._counts = self._counts, []
-        for counts in pending:
-            self.stats.record_moe(np.asarray(counts))
+        self.fold_expert_counts()
 
     # -- verification ------------------------------------------------------
     def check_invariants(self, external_refs=None) -> None:

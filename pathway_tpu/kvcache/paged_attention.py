@@ -37,6 +37,21 @@ block while it is resident.  (The decode step appends inside its attention
 kernel; a mixed step cannot, because a row may attend what another row
 writes in the same step.)
 
+The window (PR 31): both attention kernels and the gather reference take
+a static ``window`` (a sliding-window layer's width; None: full attention,
+and then nothing below is traced - the kernels are the ones of before).  A
+query column of context ``n`` sees the keys ``n - window <= j < n``
+(:func:`_attend_span`'s mask), and a span wholly behind the window of a
+row's FIRST column is dead as a span past the row's context is: no copy
+started, nothing computed, and the double buffer's "next live span" skips
+it (:func:`_first_span`, :func:`_next_span`).  A table's entries behind the
+window may point at the null block (kvcache/windowed.py frees those
+blocks); what a live span still gathers of them is masked.  Where a head's
+folded query rows are many (eight query heads a K/V head at a chunk of
+256: 2,048 rows), a span's update runs in tiles of :data:`_ROW_TILE` rows
+and skips the tiles past a row's valid columns (:func:`_row_tile`), and the
+call asks for the VMEM its scratch needs (:func:`_vmem_limit`).
+
 Round-8 raggedness (the fused mixed decode/prefill step):
 
 - every row carries ``C >= 1`` query tokens at CONSECUTIVE positions -
@@ -92,6 +107,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e9
 _LANES = 128  # keys one grid step attends: a lane tile of scores
+_ROW_TILE = 256  # query rows an update takes where a head has more
 
 
 def _query_context(C: int, context_lens, start_pos, n_valid):
@@ -148,7 +164,7 @@ def _require_positive_context(C: int, context_lens, start_pos, n_valid):
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables,
                               context_lens=None, *, start_pos=None,
-                              n_valid=None):
+                              n_valid=None, window: int | None = None):
     """Gather-based ragged paged attention.
 
     q: (B, C, H, hd) — C consecutive query tokens per row (C=1 decode);
@@ -161,6 +177,8 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     rows: column ``c`` attends to ``start_pos + min(c, n_valid-1) + 1``
     tokens (padding columns past ``n_valid`` clamp to the last valid
     query's context — their output is garbage the caller masks).
+    ``window`` (a sliding-window layer): a query at position ``p`` sees
+    the keys at ``p - window < j <= p`` only.
     Returns (B, C, H, hd).
     """
     B, C = q.shape[:2]
@@ -178,9 +196,11 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
         v = jnp.repeat(v, H // v.shape[2], axis=2)
     # decode_step's exact math: same einsum strings, mask, f32 softmax
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(hd)
-    valid = (
-        jnp.arange(NB * BS)[None, None, :] < ctx[:, :, None]
-    )[:, None, :, :]
+    k_pos = jnp.arange(NB * BS)[None, None, :]
+    valid = k_pos < ctx[:, :, None]
+    if window is not None:
+        valid = valid & (k_pos >= ctx[:, :, None] - window)
+    valid = valid[:, None, :, :]
     scores = jnp.where(valid, scores, _NEG)
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -229,8 +249,20 @@ def _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, *, C: int, G: int,
             qm_ref.dtype)
 
 
+def _row_tile(R: int, G: int) -> int:
+    """Query rows one update of :func:`_attend_span` takes.  All of a
+    group's rows, unless the group is one head with many folded query
+    columns (eight query heads a K/V head at a chunk of 256: 2,048 rows):
+    then tiles of :data:`_ROW_TILE`, so that a row with few live columns
+    (a decode row of a mixed step) pays for its first tile alone."""
+    if G == 1 and R > _ROW_TILE and R % _ROW_TILE == 0:
+        return _ROW_TILE
+    return R
+
+
 def _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref, acc_ref,
-                 *, scale: float, rep: int, C: int, G: int, hd: int):
+                 *, scale: float, rep: int, C: int, G: int, hd: int,
+                 window: int | None = None):
     """One visible span's online-softmax update, shared by both kernels:
     the ``span`` keys (a lane tile's worth: K blocks of the pool) that grid
     step ``j`` attends, so that scores, mask, ``exp`` and row sums fill the
@@ -244,27 +276,37 @@ def _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref, acc_ref,
     head i's lanes (:func:`_write_out`).  ``rep`` > 1 (grouped queries):
     the ``rep`` query heads of a K/V head ride as ``rep`` neighbouring
     columns of it, so of the C columns here column ``c`` is query column
-    ``c // rep``."""
+    ``c // rep``.  ``window``: a column of context ``n`` (position
+    ``n - 1``) sees the keys at ``n - window <= j < n`` only."""
     R, W, span = G * C, G * hd, kbuf.shape[1]
-    rows_i = jax.lax.broadcasted_iota(jnp.int32, (R, span), 0)
-    k_pos = j * span + jax.lax.broadcasted_iota(jnp.int32, (R, span), 1)
-    # column c attends to min(c0 + c, ctx) tokens; row i*C + c is column c
-    if C == rep:  # one query column a row (decode)
-        col = 0
-    else:
-        col = rows_i % C if rep == 1 else (rows_i % C) // rep
-    col_ctx = jnp.minimum(c0 + col, ctx)
-    valid = k_pos < col_ctx
-    for g in range(qm_ref.shape[0] // R):
-        rows = slice(g * R, (g + 1) * R)
-        lanes = slice(g * W, (g + 1) * W)
+    tile = _row_tile(R, G)
+
+    def mask(r0: int, n: int):
+        rows_i = jax.lax.broadcasted_iota(jnp.int32, (n, span), 0)
+        if r0:
+            rows_i = rows_i + r0
+        k_pos = j * span + jax.lax.broadcasted_iota(jnp.int32, (n, span), 1)
+        # column c attends to min(c0 + c, ctx) tokens; row i*C + c is
+        # column c
+        if C == rep:  # one query column a row (decode)
+            col = 0
+        else:
+            col = rows_i % C if rep == 1 else (rows_i % C) // rep
+        col_ctx = jnp.minimum(c0 + col, ctx)
+        valid = k_pos < col_ctx
+        if window is not None:
+            valid = valid & (k_pos >= col_ctx - window)
+        return valid
+
+    def update(rows, lanes, valid):
+        n = valid.shape[0]
         s = jax.lax.dot_general(
             qm_ref[rows], kbuf[slot, :, lanes],
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale  # (R, span)
+        ) * scale  # (n, span)
         s = jnp.where(valid, s, _NEG)
-        m_prev = m_ref[rows, :1]  # (R, 1)
+        m_prev = m_ref[rows, :1]  # (n, 1)
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)
@@ -272,7 +314,7 @@ def _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref, acc_ref,
         corr = jnp.exp(m_prev - m_new)
         l_ref[rows] = jnp.broadcast_to(
             l_ref[rows, :1] * corr + jnp.sum(p, axis=1, keepdims=True),
-            (R, l_ref.shape[1]),
+            (n, l_ref.shape[1]),
         )
         vb = vbuf[slot, :, lanes]
         acc_ref[rows] = acc_ref[rows] * corr + jax.lax.dot_general(
@@ -280,7 +322,25 @@ def _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref, acc_ref,
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        m_ref[rows] = jnp.broadcast_to(m_new, (R, m_ref.shape[1]))
+        m_ref[rows] = jnp.broadcast_to(m_new, (n, m_ref.shape[1]))
+
+    if tile == R:
+        valid = mask(0, R)
+        for g in range(qm_ref.shape[0] // R):
+            update(slice(g * R, (g + 1) * R), slice(g * W, (g + 1) * W),
+                   valid)
+        return
+    # one head a group, its folded query columns in tiles: the columns past
+    # the row's valid ones are padding whose output the caller drops, so a
+    # tile wholly past them is skipped (its rows keep acc = l = 0)
+    live = (ctx - c0 + 1) * rep
+    for r0 in range(0, R, tile):
+        @pl.when(r0 < live)
+        def _tile(r0=r0):
+            valid = mask(r0, tile)
+            for g in range(qm_ref.shape[0] // R):
+                update(slice(g * R + r0, g * R + r0 + tile),
+                       slice(g * W, (g + 1) * W), valid)
 
 
 def _write_out(o_ref, l_ref, acc_ref, *, C: int, G: int, hd: int):
@@ -311,11 +371,18 @@ def _span_copies(pools, bufs, sem, slot, block_of, *, K: int,
     ]
 
 
-def _next_span(b, j, jlast, li_ref, bt_ref, cl_ref, pools, bufs, sem, n_ref,
-               *, K: int, block_size: int):
+def _first_span(c0, window: int, span: int):
+    """The first span a row's queries see keys in under a window: the span
+    of the first key its first column (context ``c0``) still sees; the
+    spans before it are dead, as the spans past the context are."""
+    return jnp.maximum(c0 - window, 0) // span
+
+
+def _next_span(b, j, jlast, li_ref, bt_ref, c0_ref, cl_ref, pools, bufs, sem,
+               n_ref, *, K: int, block_size: int, window: int | None = None):
     """The pool's blocks of this live grid step (b, j <= jlast, the row's
-    last live span), in VMEM: returns the buffer slot that holds span j
-    of row b.  The pools stay in HBM
+    last live span; with a ``window``, j >= the row's first live span
+    too), in VMEM: returns the buffer slot that holds span j of row b.  The pools stay in HBM
     (one operand each, so the fused append can alias them) and the kernel
     gathers a span's K blocks itself through the scalar-prefetched block
     table, two buffers deep: the copies of the NEXT live span - span j+1
@@ -339,6 +406,11 @@ def _next_span(b, j, jlast, li_ref, bt_ref, cl_ref, pools, bufs, sem, n_ref,
     B = cl_ref.shape[0]
     li = li_ref[0]
 
+    def first(row):
+        if window is None:
+            return 0
+        return _first_span(c0_ref[row], window, K * block_size)
+
     def copies(slot, row, span):
         last = (cl_ref[row] - 1) // block_size
         return _span_copies(
@@ -346,10 +418,10 @@ def _next_span(b, j, jlast, li_ref, bt_ref, cl_ref, pools, bufs, sem, n_ref,
             lambda i: (li, bt_ref[row, jnp.minimum(span * K + i, last)]),
             K=K, block_size=block_size)
 
-    @pl.when((b == 0) & (j == 0))
+    @pl.when((b == 0) & (j == first(0)))
     def _first():
         n_ref[0] = 0
-        for c in copies(0, 0, 0):
+        for c in copies(0, 0, first(0)):
             c.start()
 
     n = n_ref[0]
@@ -359,7 +431,7 @@ def _next_span(b, j, jlast, li_ref, bt_ref, cl_ref, pools, bufs, sem, n_ref,
     @pl.when(more | (b + 1 < B))
     def _prefetch():
         row = jnp.where(more, b, jnp.minimum(b + 1, B - 1))
-        for c in copies(1 - slot, row, jnp.where(more, j + 1, 0)):
+        for c in copies(1 - slot, row, jnp.where(more, j + 1, first(row))):
             c.start()
 
     for c in _span_copies(pools, bufs, sem, slot, lambda i: (0, 0), K=K,
@@ -369,16 +441,25 @@ def _next_span(b, j, jlast, li_ref, bt_ref, cl_ref, pools, bufs, sem, n_ref,
     return slot
 
 
+def _live_span(j, c0, jlast, window: int | None, span: int):
+    live = j <= jlast
+    if window is not None:
+        live = live & (j >= _first_span(c0, window, span))
+    return live
+
+
 def _paged_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, k_in, v_in, o_ref,
                   kbuf, vbuf, sem, n_ref, qm_ref, m_ref, l_ref, acc_ref, *,
-                  K: int, block_size: int, scale: float, rep: int, **geom):
+                  K: int, block_size: int, scale: float, rep: int,
+                  window: int | None = None, **geom):
     """Grid: (B, NS) - spans innermost, so (m, l, acc) scratch carries the
     online softmax across one sequence's spans.  Blocks: q and o
     (C, H*hd); the pools whole, in HBM, of which :func:`_next_span` brings
     grid step j's K blocks of layer ``li`` into ``kbuf`` / ``vbuf``
     ((2, K*block_size, H*hd)).  Spans past the row's context
-    (``j > jlast``) are dead: every ``@pl.when`` below is false and no
-    copy is started, so they cost an empty grid step."""
+    (``j > jlast``) and, with a ``window``, spans wholly behind the row's
+    first column's window are dead: every ``@pl.when`` below is false and
+    no copy is started, so they cost an empty grid step."""
     b = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -390,13 +471,14 @@ def _paged_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, k_in, v_in, o_ref,
     ctx = cl_ref[b]      # the row's full context (last valid column's)
     jlast = (ctx - 1) // (K * block_size)  # last span with attended tokens
 
-    @pl.when(j <= jlast)  # skip spans wholly past the context
+    # skip spans wholly past the context, or wholly behind the window
+    @pl.when(_live_span(j, c0, jlast, window, K * block_size))
     def _visible():
-        slot = _next_span(b, j, jlast, li_ref, bt_ref, cl_ref, (k_in, v_in),
-                          (kbuf, vbuf), sem, n_ref, K=K,
-                          block_size=block_size)
+        slot = _next_span(b, j, jlast, li_ref, bt_ref, c0_ref, cl_ref,
+                          (k_in, v_in), (kbuf, vbuf), sem, n_ref, K=K,
+                          block_size=block_size, window=window)
         _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref,
-                     acc_ref, scale=scale, rep=rep, **geom)
+                     acc_ref, scale=scale, rep=rep, window=window, **geom)
 
     # write at the row's LAST VALID span, not the grid edge: later grid
     # steps touch nothing, and the (per-row) output block flushes when
@@ -454,20 +536,44 @@ def span_blocks(block_size: int, table_blocks: int, lanes: int) -> int:
     return max(1, min(_LANES // block_size, table_blocks))
 
 
-def _pool_spec(K: int, BS: int, D: int):
+def _pool_spec(K: int, BS: int, D: int, window: int | None = None):
     """How a pool reaches the kernels: whole, in HBM, for the kernel's own
     gather of K blocks a step; at K == 1 a block a step (clamped to the
-    row's last block, so that a dead step's copy is elided)."""
+    row's last block and, with a window, to its first live one, so that a
+    dead step's copy is elided)."""
     if K > 1:
         return pl.BlockSpec(memory_space=pl.ANY)
+    if window is None:
+        return pl.BlockSpec(
+            (None, None, BS, D),
+            lambda b, j, li, bt, c0, cl, *_: (
+                li[0], bt[b, jnp.minimum(j, (cl[b] - 1) // BS)], 0, 0))
     return pl.BlockSpec(
         (None, None, BS, D),
         lambda b, j, li, bt, c0, cl, *_: (
-            li[0], bt[b, jnp.minimum(j, (cl[b] - 1) // BS)], 0, 0))
+            li[0], bt[b, jnp.clip(j, _first_span(c0[b], window, BS),
+                                  (cl[b] - 1) // BS)], 0, 0))
+
+
+def _vmem_limit(K: int, BS: int, D: int, pool_dtype, H: int, C: int, hd: int,
+                G: int, dtype) -> dict:
+    """``compiler_params`` of a kernel call: none while the kernel's
+    scratch and its double-buffered query and output blocks fit the
+    compiler's own scoped limit with room to spare (every geometry before
+    eight folded query heads at a chunk of 256), else a limit that holds
+    them and the scores of a row tile."""
+    need = 2 * 2 * K * BS * D * jnp.dtype(pool_dtype).itemsize \
+        + H * C * G * hd * (jnp.dtype(dtype).itemsize + 4) \
+        + 2 * H * C * 128 * 4 + 2 * 2 * C * D * jnp.dtype(dtype).itemsize
+    if need <= 10 * 2 ** 20:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=int(min(need + 24 * 2 ** 20, 100 * 2 ** 20)))}
 
 
 def _paged_ragged_fn(q, k_pool, v_pool, layer, block_tables, c0, cl, *,
-                     d_true: int, interpret: bool = False):
+                     d_true: int, interpret: bool = False,
+                     window: int | None = None):
     """q: (B, C, H, hd); pools (L, num_blocks, BS, Hkv*hd) - ALL layers'
     stacked pool, read in place at ``layer`` ((1,) int32): the kernel's own
     copies carry the layer, so no layer is ever sliced out of the pool.  A
@@ -475,7 +581,8 @@ def _paged_ragged_fn(q, k_pool, v_pool, layer, block_tables, c0, cl, *,
     a multiple of 128, so nothing is lane-padded and the pool's layout in
     HBM is the one the kernel reads; a grid step attends K of them
     (:func:`span_blocks`); c0/cl: (B,) per-row column-0 / last-column
-    context lengths."""
+    context lengths; ``window``: a sliding-window layer's width (static),
+    None for full attention."""
     B, hd = q.shape[0], q.shape[3]
     BS, D = k_pool.shape[2:]
     NB = block_tables.shape[1]
@@ -486,9 +593,10 @@ def _paged_ragged_fn(q, k_pool, v_pool, layer, block_tables, c0, cl, *,
     kernel = functools.partial(
         _paged_kernel, K=K, block_size=BS, scale=1.0 / np.sqrt(d_true),
         rep=rep, C=C, G=G, hd=hd,
+        **({} if window is None else {"window": int(window)}),
     )
     row = pl.BlockSpec((None, C, D), lambda b, j, *_: (b, 0, 0))
-    pool = _pool_spec(K, BS, D)
+    pool = _pool_spec(K, BS, D, window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,  # layer, block_tables, c0, cl
         grid=(B, -(-NB // K)),
@@ -501,6 +609,7 @@ def _paged_ragged_fn(q, k_pool, v_pool, layer, block_tables, c0, cl, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, C, D), q.dtype),
         interpret=interpret,
+        **_vmem_limit(K, BS, D, k_pool.dtype, H, C, hd, G, q.dtype),
     )(layer, block_tables, c0, cl, qf, k_pool, v_pool)
     return _unfold_queries(out, q.shape, rep)
 
@@ -509,7 +618,7 @@ def _make_paged_ragged():
     """Jit the standalone kernel entry point through the device cost
     observatory (Round-14); falls back to a plain jit while the obs
     package is still importing (circular-import window)."""
-    kwargs = dict(static_argnames=("d_true", "interpret"))
+    kwargs = dict(static_argnames=("d_true", "interpret", "window"))
     try:
         from ..obs.profiler import profiled_jit
 
@@ -524,7 +633,8 @@ _paged_ragged = _make_paged_ragged()
 def _append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, q_ref, k1_ref,
                    v1_ref, k_in, v_in, o_ref, ko_ref, vo_ref, kbuf, vbuf,
                    sem, n_ref, qm_ref, m_ref, l_ref, acc_ref, *, K: int,
-                   block_size: int, scale: float, rep: int, **geom):
+                   block_size: int, scale: float, rep: int,
+                   window: int | None = None, **geom):
     """Round-17 fused append+attend (decode, C=1): the incoming token's
     K/V rides into the kernel as a (1, H*hd) operand, is patched into the
     tail block IN VMEM for the attention math (the HBM copy the kernel
@@ -547,11 +657,11 @@ def _append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, q_ref, k1_ref,
     ctx = cl_ref[b]
     jlast = (ctx - 1) // (K * block_size)  # the append lands in this span
 
-    @pl.when(j <= jlast)
+    @pl.when(_live_span(j, c0, jlast, window, K * block_size))
     def _visible():
-        slot = _next_span(b, j, jlast, li_ref, bt_ref, cl_ref, (k_in, v_in),
-                          (kbuf, vbuf), sem, n_ref, K=K,
-                          block_size=block_size)
+        slot = _next_span(b, j, jlast, li_ref, bt_ref, c0_ref, cl_ref,
+                          (k_in, v_in), (kbuf, vbuf), sem, n_ref, K=K,
+                          block_size=block_size, window=window)
 
         @pl.when(j == jlast)
         def _append():
@@ -569,7 +679,7 @@ def _append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, q_ref, k1_ref,
                 out_ref[:] = blk.astype(out_ref.dtype)
 
         _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref,
-                     acc_ref, scale=scale, rep=rep, **geom)
+                     acc_ref, scale=scale, rep=rep, window=window, **geom)
 
     @pl.when(j == jlast)
     def _final():
@@ -578,7 +688,7 @@ def _append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, q_ref, k1_ref,
 
 def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, layer, block_tables,
                      c0, cl, slot_offsets, *, d_true: int,
-                     interpret: bool = False):
+                     interpret: bool = False, window: int | None = None):
     """q: (B, 1, H, hd); k_new/v_new: (B, Hkv, hd); pools
     (L, num_blocks, BS, Hkv*hd) - ALL layers' stacked pool, returned
     UPDATED at ``layer`` ((1,) int32), aliased in place on TPU: one tail
@@ -597,6 +707,7 @@ def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, layer, block_tables,
     kernel = functools.partial(
         _append_kernel, K=K, block_size=BS, scale=1.0 / np.sqrt(d_true),
         rep=rep, C=C, G=G, hd=hd,
+        **({} if window is None else {"window": int(window)}),
     )
 
     def _row(rows):
@@ -606,7 +717,7 @@ def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, layer, block_tables,
         # constant per row: the pool out-block IS the row's slot block
         return (li[0], bt[b, (cl[b] - 1) // BS], 0, 0)
 
-    pool = _pool_spec(K, BS, D)
+    pool = _pool_spec(K, BS, D, window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,  # layer, block_tables, c0, cl, slot_offsets
         grid=(B, -(-NB // K)),
@@ -636,7 +747,7 @@ def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, layer, block_tables,
 
 
 def _make_paged_append():
-    kwargs = dict(static_argnames=("d_true", "interpret"),
+    kwargs = dict(static_argnames=("d_true", "interpret", "window"),
                   donate_argnums=(3, 4))
     try:
         from ..obs.profiler import profiled_jit
@@ -774,7 +885,8 @@ def paged_write_rows(k_pool, v_pool, slot_blocks, slot_offsets, k_rows,
 def paged_append_attend(q, k_new, v_new, k_pool, v_pool, block_tables,
                         context_lens, slot_blocks, slot_offsets, *,
                         layer=None, use_pallas: bool | None = None,
-                        interpret: bool | None = None):
+                        interpret: bool | None = None,
+                        window: int | None = None):
     """Fused decode append+attend over one layer of the pool: scatter
     the incoming token's K/V at ``(slot_blocks, slot_offsets)`` and
     attend through ``block_tables`` in a single program.
@@ -803,7 +915,7 @@ def paged_append_attend(q, k_new, v_new, k_pool, v_pool, block_tables,
             layer=layer, use_pallas=False)
         a = paged_attention_reference(
             q, _layer_of(k_pool, layer), _layer_of(v_pool, layer),
-            block_tables, context_lens,
+            block_tables, context_lens, window=window,
         )
         return a, k_pool, v_pool
     _require_positive_context(1, context_lens, None, None)
@@ -816,6 +928,7 @@ def paged_append_attend(q, k_new, v_new, k_pool, v_pool, block_tables,
         jnp.asarray(slot_offsets, jnp.int32),
         d_true=hd,
         interpret=(backend != "tpu") if interpret is None else interpret,
+        **({} if window is None else {"window": int(window)}),
     )
     return (a, kk[0], vv[0]) if layer is None else (a, kk, vv)
 
@@ -823,7 +936,8 @@ def paged_append_attend(q, k_new, v_new, k_pool, v_pool, block_tables,
 def paged_attention(q, k_pool, v_pool, block_tables, context_lens=None, *,
                     start_pos=None, n_valid=None, layer=None,
                     use_pallas: bool | None = None,
-                    interpret: bool | None = None):
+                    interpret: bool | None = None,
+                    window: int | None = None):
     """Dispatch: Pallas kernel on TPU, gather reference elsewhere (the
     interpreted kernel is for tests).  Same signature/shape/raggedness
     contract as :func:`paged_attention_reference`, plus ``layer``: None
@@ -838,7 +952,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens=None, *,
         return paged_attention_reference(
             q, _layer_of(k_pool, layer), _layer_of(v_pool, layer),
             block_tables, context_lens,
-            start_pos=start_pos, n_valid=n_valid,
+            start_pos=start_pos, n_valid=n_valid, window=window,
         )
     B, C, H, hd = q.shape
     _require_positive_context(C, context_lens, start_pos, n_valid)
@@ -850,4 +964,5 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens=None, *,
         c0.astype(jnp.int32), cl_last.astype(jnp.int32),
         d_true=hd,
         interpret=(backend != "tpu") if interpret is None else interpret,
+        **({} if window is None else {"window": int(window)}),
     )

@@ -7,9 +7,11 @@ keeps, is the model's.  A family is read from the configuration
 and gives the engine:
 
 - ``plan(cfg, params, tp, quantize)``: the pytree it dispatches with;
-- ``cache_kind`` and ``cache_kwargs(cfg, max_batch_size)``: the cache
-  backend of :func:`pathway_tpu.kvcache.backend.make_backend` and its
-  geometry (the K/V pool's layers and heads are the family's to say);
+- ``cache_kind`` and ``cache_kwargs(cfg, max_batch_size, round_tokens)``:
+  the cache backend of :func:`pathway_tpu.kvcache.backend.make_backend`
+  and its geometry (the K/V pool's layers and heads are the family's to
+  say; ``round_tokens``: the most one round adds to a sequence, which
+  sizes a windowed cache's second pool);
 - ``programs(cfg, attn, mesh, sampled=False)``: the table of step
   programs ``step``, ``mixed``, ``chained`` as ``(function, donated
   argument numbers)``, each ``(params, *cache arrays, *host arrays) ->
@@ -24,7 +26,7 @@ and gives the engine:
   whether an unasked ``tp`` may take every local chip.
 
 A family is the only place that names a model's programs: the engine
-imports neither models/decoder.py nor models/lfm2.py.  The function
+imports none of models/decoder.py, models/lfm2.py, models/afmoe.py.  The function
 names ``_step_fn`` / ``_mixed_fn`` / ``_chained_fn`` are the
 device trace's (``jit__mixed_fn`` on ``XLA Modules``): the benchmark's
 readers find the programs by them, for every family alike.
@@ -56,7 +58,7 @@ class DecoderFamily:
         return plan_decode_params(cfg, params, tp=tp, quantize=quantize)
 
     @staticmethod
-    def cache_kwargs(cfg, max_batch_size: int) -> dict:
+    def cache_kwargs(cfg, max_batch_size: int, round_tokens: int) -> dict:
         return {"n_layers": cfg.n_layers, "n_heads": cfg.n_heads,
                 "head_dim": cfg.d_model // cfg.n_heads}
 
@@ -151,7 +153,7 @@ class Lfm2Family:
         return plan_params(cfg, params)
 
     @staticmethod
-    def cache_kwargs(cfg, max_batch_size: int) -> dict:
+    def cache_kwargs(cfg, max_batch_size: int, round_tokens: int) -> dict:
         return {"n_layers": len(cfg.attn_layers), "n_heads": cfg.n_kv_heads,
                 "head_dim": cfg.head_dim,
                 "conv_layers": len(cfg.conv_layers),
@@ -207,7 +209,86 @@ class Lfm2Family:
                 "chained": (_chained_fn, (1, 2, 3))}
 
 
-_FAMILIES = {f.name: f for f in (DecoderFamily, Lfm2Family)}
+class AfmoeFamily:
+    """models/afmoe.py: sliding-window and full attention layers with a
+    gated output, sandwich norms, SwiGLU and routed experts beside a shared
+    one, on the windowed cache.  Greedy on one device.  Beyond what
+    :meth:`unsupported` refuses, the cache kind runs without a prefix cache
+    whatever was asked for (a shared block may be freed behind one
+    sequence's window while another still reads it), and a sampled request
+    fails alone (``greedy_only``)."""
+
+    name = "afmoe"
+    cache_kind = "windowed"
+    greedy_only = True
+    tensor_parallel = False
+
+    @staticmethod
+    def plan(cfg, params, *, tp: int, quantize):
+        from .afmoe import plan_params
+
+        return plan_params(cfg, params)
+
+    @staticmethod
+    def cache_kwargs(cfg, max_batch_size: int, round_tokens: int) -> dict:
+        return {"n_layers": len(cfg.full_layers), "n_heads": cfg.n_kv_heads,
+                "head_dim": cfg.head_dim, "window": cfg.sliding_window,
+                "window_layers": len(cfg.window_layers),
+                "round_tokens": round_tokens, "max_seqs": max_batch_size}
+
+    @staticmethod
+    def unsupported(*, tp, quantize, speculative, session_store) -> None:
+        missing = [what for what, asked in (
+            ("tensor parallelism (tp > 1): the window pool and the expert "
+             "weights have no sharded layout", tp is not None and tp > 1),
+            (f"quantize={quantize!r}: no quantized plan of the expert "
+             "weights", quantize is not None),
+            ("speculative drafting: a rejected draft's slots cannot be "
+             "rolled back behind the window", speculative not in (None, False)),
+            ("host tiering (session_store): a suspended sequence has lost "
+             "the window layers' keys behind its window",
+             session_store is not None),
+        ) if asked]
+        if missing:
+            raise ValueError(
+                "the afmoe block family does not support "
+                + "; ".join(missing))
+
+    @staticmethod
+    def programs(cfg, attn: str, mesh, sampled: bool = False) -> dict:
+        if sampled:
+            raise ValueError("the afmoe block family decodes greedily: it "
+                             "has no sampled step programs")
+        from . import afmoe as m
+
+        def _step_fn(p, k_pool, v_pool, kw_pool, vw_pool, token, positions,
+                     bt, sb, so, wt):
+            logits, *state = m.windowed_decode_step(
+                p, cfg, k_pool, v_pool, kw_pool, vw_pool, token, positions,
+                bt, sb, so, wt, attn=attn)
+            return (m.greedy_ids(logits), *state)
+
+        def _mixed_fn(p, k_pool, v_pool, kw_pool, vw_pool, tokens, positions,
+                      row_tables, row_start, row_nvalid, row_token_idx,
+                      tok_row, tok_col, sb, so, logit_idx, wt):
+            logits, *state = m.windowed_mixed_step(
+                p, cfg, k_pool, v_pool, kw_pool, vw_pool, tokens, positions,
+                row_tables, row_start, row_nvalid, row_token_idx, tok_row,
+                tok_col, sb, so, logit_idx, wt, attn=attn)
+            return (m.greedy_ids(logits), *state)
+
+        def _chained_fn(p, k_pool, v_pool, kw_pool, vw_pool, token,
+                        positions, bt, sb, so, wt):
+            return m.windowed_chained_decode(
+                p, cfg, k_pool, v_pool, kw_pool, vw_pool, token, positions,
+                bt, sb, so, wt, attn=attn)
+
+        donated = (1, 2, 3, 4)
+        return {"step": (_step_fn, donated), "mixed": (_mixed_fn, donated),
+                "chained": (_chained_fn, donated)}
+
+
+_FAMILIES = {f.name: f for f in (DecoderFamily, Lfm2Family, AfmoeFamily)}
 
 
 def step_family(cfg):

@@ -196,6 +196,69 @@ def config_from_lfm2_moe(hf_config, *, max_len: int | None = None,
     )
 
 
+def config_from_afmoe(hf_config, *, max_len: int | None = None,
+                      dtype="auto"):
+    """``afmoe`` config (arcee-ai/Trinity-Mini's ``config.json`` keys) ->
+    :class:`~pathway_tpu.models.afmoe.AfmoeConfig`.  ``layer_types`` is
+    read as published and decides the depth; ``max_len`` caps the served
+    context below ``max_position_embeddings`` (rotary on the window layers,
+    no positions on the full ones: any cap is exact).  What the keys can
+    say and this family has not written down is refused: a score function
+    other than sigmoid, a group limit on the router (``n_group`` /
+    ``topk_group`` above 1), rotary scaling, a tied head, another
+    activation than SiLU."""
+    from .afmoe import AfmoeConfig
+
+    def get(name, default=None):
+        return getattr(hf_config, name, default)
+
+    if get("model_type") != "afmoe":
+        raise ValueError(
+            f"expected an afmoe config, got model_type="
+            f"{get('model_type')!r}")
+    refused = [what for what, bad in (
+        ("score_func other than sigmoid", get("score_func", "sigmoid")
+         != "sigmoid"),
+        ("a group limit on the router (n_group / topk_group > 1)",
+         max(get("n_group", 1) or 1, get("topk_group", 1) or 1) > 1),
+        ("rope_scaling", get("rope_scaling") is not None),
+        ("tie_word_embeddings", bool(get("tie_word_embeddings", False))),
+        ("hidden_act other than silu", get("hidden_act", "silu") != "silu"),
+    ) if bad]
+    if refused:
+        raise ValueError("afmoe: not written down here: " + "; ".join(refused))
+    layer_types = tuple(hf_config.layer_types)
+    n_layers = get("num_hidden_layers", len(layer_types))
+    if n_layers != len(layer_types):
+        raise ValueError(
+            f"num_hidden_layers={n_layers} but layer_types names "
+            f"{len(layer_types)} layers")
+    positions = int(hf_config.max_position_embeddings)
+    return AfmoeConfig(
+        vocab_size=hf_config.vocab_size,
+        d_model=hf_config.hidden_size,
+        n_heads=hf_config.num_attention_heads,
+        n_kv_heads=hf_config.num_key_value_heads,
+        head_dim=int(get("head_dim", hf_config.hidden_size
+                         // hf_config.num_attention_heads)),
+        d_ff=hf_config.intermediate_size,
+        d_ff_expert=hf_config.moe_intermediate_size,
+        n_experts=hf_config.num_experts,
+        top_k=hf_config.num_experts_per_tok,
+        n_shared_experts=int(get("num_shared_experts", 1)),
+        n_dense_layers=hf_config.num_dense_layers,
+        layer_types=layer_types,
+        sliding_window=int(hf_config.sliding_window),
+        rope_theta=float(get("rope_theta", 1e4)),
+        norm_eps=float(get("rms_norm_eps", 1e-5)),
+        max_len=min(positions, int(max_len)) if max_len else positions,
+        dtype=dtype,
+        route_norm=bool(get("route_norm", True)),
+        route_scale=float(get("route_scale", 1.0)),
+        mup_enabled=bool(get("mup_enabled", False)),
+    )
+
+
 def params_from_lfm2_state_dict(state: dict[str, Any], cfg) -> dict:
     """Map a (torch) LFM2-family state dict onto
     :mod:`pathway_tpu.models.lfm2`'s parameter pytree, in ``cfg``'s dtype.

@@ -12,7 +12,9 @@ alternative named:
 - **KV pool**: BlockPool's stacked K/V arrays — the same per-shard
   formula as PR 4's ``shard_hbm_bytes`` gauge; for a hybrid block family
   over the attention layers and K/V heads only, with the conv slot arena
-  beside it (``conv_bytes``);
+  beside it (``conv_bytes``); for a family with sliding-window layers over
+  its full-attention layers only, with the window layers' pool, sized by
+  the batch and the window, beside it (``window_bytes``);
 - **step temps**: the transient working set of the largest step
   program.  When the program registry (obs/profiler.py) already holds
   a MEASURED ``memory_analysis()`` temp watermark for the engine's
@@ -108,12 +110,15 @@ def kv_pool_bytes(cfg, *, num_blocks: int, block_size: int, tp: int,
 
 
 def _kv_geometry(cfg) -> tuple:
-    """(layers that keep K/V, K/V heads, head_dim): all layers and all
-    heads for the plain decoder; a hybrid family keeps K/V in its attention
-    layers only, and fewer K/V heads than query heads."""
-    hd = cfg.d_model // cfg.n_heads
-    attn_layers = getattr(cfg, "attn_layers", None)
-    return (cfg.n_layers if attn_layers is None else len(attn_layers),
+    """(layers whose K/V the block pool keeps, K/V heads, head_dim): all
+    layers and all heads for the plain decoder; a hybrid family keeps K/V
+    in its attention layers only, a family with sliding-window layers
+    keeps its full-attention layers' there (the window layers' pool:
+    :func:`window_pool_bytes`); both have fewer K/V heads than query
+    heads."""
+    hd = getattr(cfg, "head_dim", cfg.d_model // cfg.n_heads)
+    pooled = getattr(cfg, "attn_layers", getattr(cfg, "full_layers", None))
+    return (cfg.n_layers if pooled is None else len(pooled),
             getattr(cfg, "n_kv_heads", cfg.n_heads), hd)
 
 
@@ -124,6 +129,23 @@ def conv_arena_bytes(cfg, *, max_batch_size: int, itemsize: int) -> int:
     conv_layers = getattr(cfg, "conv_layers", ())
     return len(conv_layers) * (max_batch_size + 1) * 2 * cfg.d_model \
         * itemsize
+
+
+def window_pool_bytes(cfg, *, max_batch_size: int, block_size: int,
+                      round_tokens: int, itemsize: int) -> int:
+    """The sliding-window layers' K/V pool of a windowed family
+    (kvcache/windowed.py): sized exactly by the batch, the window and the
+    most a round adds to a sequence, not by ``num_blocks``.  0 for a
+    family without window layers."""
+    layers = len(getattr(cfg, "window_layers", ()))
+    if not layers:
+        return 0
+    from ..kvcache.windowed import window_pool_blocks
+
+    _l, kv_heads, hd = _kv_geometry(cfg)
+    blocks = window_pool_blocks(cfg.sliding_window, round_tokens, block_size,
+                                max_batch_size)
+    return 2 * layers * blocks * block_size * kv_heads * hd * itemsize
 
 
 def _temp_bytes(cfg, *, num_blocks: int, block_size: int,
@@ -137,7 +159,7 @@ def _temp_bytes(cfg, *, num_blocks: int, block_size: int,
     C = max(prefill_chunk, 1)
     T = B + C
     d = cfg.d_model
-    hd = d // cfg.n_heads
+    hd = getattr(cfg, "head_dim", d // cfg.n_heads)
     heads = max(cfg.n_heads // max(tp, 1), 1)
     vocab = cfg.vocab_size // max(tp, 1)
     # a sequence's table can span at most the pool (minus the null block)
@@ -152,6 +174,9 @@ def _temp_bytes(cfg, *, num_blocks: int, block_size: int,
         if reference_attn else 0
     )
     acts = 6 * T * max(d, cfg.d_ff) * itemsize  # packed stream residuals
+    # the rows' queries gathered (B, C, H, hd) for the ragged kernel, its
+    # output the same, each with a folded copy
+    acts += 4 * B * C * heads * hd * itemsize
     if getattr(cfg, "n_experts", 0):
         # routed pairs laid out by expert in whole tiles of 16 rows: the
         # gathered inputs, the experts' hidden rows and their outputs
@@ -183,12 +208,15 @@ class HbmPlan:
     # a hybrid family's conv slot arena (0 elsewhere): fixed by the batch,
     # not by num_blocks
     conv_bytes: int = 0
+    # a windowed family's pool of the sliding-window layers (0 elsewhere):
+    # fixed by the batch and the window, not by num_blocks
+    window_bytes: int = 0
     _replan: "object" = dataclasses.field(default=None, repr=False)
 
     @property
     def total_bytes(self) -> int:
         return self.params_bytes + self.kv_bytes + self.conv_bytes \
-            + self.temp_bytes
+            + self.window_bytes + self.temp_bytes
 
     @property
     def fits(self) -> bool:
@@ -229,7 +257,7 @@ class HbmPlan:
             return self.num_blocks
         per_block = max(self.per_block_bytes, 1)
         nb = (self.budget_bytes - self.params_bytes - self.conv_bytes
-              - self.temp_bytes) // per_block
+              - self.window_bytes - self.temp_bytes) // per_block
         nb = min(int(nb), self.num_blocks)
         while nb >= 2 and not self.with_(num_blocks=nb).fits:
             nb -= max(nb // 8, 1)
@@ -273,7 +301,8 @@ class HbmPlan:
             f"{self.params_bytes / mb:.1f}MB + KV pool "
             f"{self.kv_bytes / mb:.1f}MB ({self.num_blocks} blocks x "
             f"{self.block_size} tokens, tp={self.tp}) + conv arena "
-            f"{self.conv_bytes / mb:.1f}MB + step temps "
+            f"{self.conv_bytes / mb:.1f}MB + window pool "
+            f"{self.window_bytes / mb:.1f}MB + step temps "
             f"{self.temp_bytes / mb:.1f}MB ({self.temp_source}) = "
             f"{self.total_bytes / mb:.1f}MB > HBM budget "
             f"{self.budget_bytes / mb:.1f}MB ({self.budget_source}); "
@@ -285,6 +314,7 @@ class HbmPlan:
             "params_bytes": self.params_bytes,
             "kv_bytes": self.kv_bytes,
             "conv_bytes": self.conv_bytes,
+            "window_bytes": self.window_bytes,
             "temp_bytes": self.temp_bytes,
             "temp_source": self.temp_source,
             "total_bytes": self.total_bytes,
@@ -365,6 +395,12 @@ def hbm_plan(cfg, *, num_blocks: int, block_size: int,
             chain_steps=int(chain_steps), prefill_chunk=pchunk, tp=tp,
             conv_bytes=conv_arena_bytes(
                 cfg, max_batch_size=int(max_batch_size), itemsize=itemsize),
+            window_bytes=window_pool_bytes(
+                cfg, max_batch_size=int(max_batch_size),
+                block_size=int(block_size),
+                round_tokens=max(-(-pchunk // int(block_size))
+                                 * int(block_size), int(chain_steps)),
+                itemsize=itemsize),
         )
         plan._replan = _build
         return plan

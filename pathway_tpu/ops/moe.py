@@ -1,9 +1,10 @@
 """Sparse expert layer: the router, routed pairs grouped by expert, and the
 grouped matmul over the tokens each expert received.
 
-A step carries few tokens (a mixed step 48, a decode step 16) and many
-experts (32 of 3 x 2048 x 1792), so the layer is bound by the bytes of the
-expert weights it touches, not by its FLOPs.  The layout follows from
+A step carries few tokens (a mixed step 48 to 272, a decode step 16) and
+many experts (32 of 3 x 2048 x 1792, or 128 of 3 x 2048 x 1024), so the
+layer is bound by the bytes of the expert weights it touches, not by its
+FLOPs.  The layout follows from
 that:
 
 - :func:`route` scores every token against every expert (sigmoid, f32),
@@ -36,11 +37,12 @@ TM = 16  # rows a tile: one packed bf16 sublane tile
 
 
 def route(h, wg, bias, *, top_k: int, norm_topk: bool = True,
-          scale: float = 1.0):
+          scale: float = 1.0, renorm_eps: float = 1e-6):
     """h (T, D), wg (D, E), bias (E,) or None -> (experts (T, k) int32,
     weights (T, k) f32, scores (T, E) f32).  Scores in f32 at the highest
     matmul precision: the choice is a comparison of neighbours.  ``bias``
-    moves the choice only; the weights are the chosen experts' scores."""
+    moves the choice only; the weights are the chosen experts' scores,
+    over their sum + ``renorm_eps`` where ``norm_topk``."""
     s = jax.nn.sigmoid(jnp.dot(
         h.astype(jnp.float32), wg.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
@@ -48,7 +50,7 @@ def route(h, wg, bias, *, top_k: int, norm_topk: bool = True,
     _, idx = jax.lax.top_k(sel, top_k)
     w = jnp.take_along_axis(s, idx, axis=1)
     if norm_topk:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + renorm_eps)
     return idx.astype(jnp.int32), w * scale, s
 
 
@@ -207,7 +209,7 @@ def grouped_matmul(x, w1, w3, w2, tile_expert, n_live, *, tm: int = TM,
 
 
 def expert_ffn(h, layer: dict, valid, *, top_k: int, norm_topk: bool = True,
-               scale: float = 1.0, h_route=None,
+               scale: float = 1.0, renorm_eps: float = 1e-6, h_route=None,
                use_pallas: bool | None = None):
     """One expert layer over a packed stream.  h (T, D) normed input in
     the experts' dtype, ``h_route`` the same before it was rounded to that
@@ -219,7 +221,7 @@ def expert_ffn(h, layer: dict, valid, *, top_k: int, norm_topk: bool = True,
     experts, weights, _s = route(h if h_route is None else h_route,
                                  layer["wg"], layer.get("expert_bias"),
                                  top_k=top_k, norm_topk=norm_topk,
-                                 scale=scale)
+                                 scale=scale, renorm_eps=renorm_eps)
     g = group_rows(experts, valid, E)
     y = grouped_matmul(h[g["row_token"]], layer["w1"], layer["w3"],
                        layer["w2"], g["tile_expert"], g["n_live"],

@@ -182,6 +182,14 @@ class KVCacheStats:
       dispatches)
     - ``pathway_kv_conv_slots_in_use{pool}`` / ``..._total{pool}`` gauges
       (hybrid caches: sequences holding a conv slot, and the arena's size)
+    - ``pathway_kv_window_blocks_in_use{pool}`` / ``..._total{pool}`` gauges
+      (windowed caches: blocks of the sliding-window layers' pool held, and
+      its size), ``pathway_kv_window_blocks_allocated_total{pool}`` /
+      ``pathway_kv_window_blocks_freed_total{pool}`` counters (freed:
+      behind a window or with their sequence; freed/allocated = the share
+      that went back), ``pathway_kv_window_keys_total{pool}`` /
+      ``pathway_kv_window_ctx_keys_total{pool}`` counters (keys a window
+      layer's rows attended, over what they would attend with no window)
     - ``pathway_kv_moe_routed_pairs_total{pool}`` counter ((token, expert)
       pairs the step programs routed, counted on the device) and
       ``pathway_kv_moe_tokens_per_expert_total{pool,expert}`` (the same,
@@ -253,11 +261,37 @@ class KVCacheStats:
         self.moe_routed_pairs = 0
         self.moe_tokens_per_expert: list[int] = []
         self.moe_fullest_expert_tokens = 0  # sum over programs of the max
+        # windowed caches (kvcache/windowed.py): the sliding-window layers'
+        # pool, and the keys those layers attend a layer
+        self.window_blocks_total = 0
+        self._window_blocks_in_use_fn = None
+        self.kv_window_blocks_allocated = 0
+        self.kv_window_blocks_freed = 0
+        self.kv_window_keys = 0
+        self.kv_window_ctx_keys = 0
 
     @property
     def conv_slots_in_use(self) -> int:
         fn = self._conv_slots_in_use_fn
         return int(fn()) if fn is not None else 0
+
+    @property
+    def window_blocks_in_use(self) -> int:
+        fn = self._window_blocks_in_use_fn
+        return int(fn()) if fn is not None else 0
+
+    def record_window_blocks(self, allocated: int = 0, freed: int = 0
+                             ) -> None:
+        with self._lock:
+            self.kv_window_blocks_allocated += allocated
+            self.kv_window_blocks_freed += freed
+
+    def record_window_keys(self, keys: int, ctx_keys: int) -> None:
+        """Keys one round's rows attended in a sliding-window layer, and
+        the keys they would attend there with no window."""
+        with self._lock:
+            self.kv_window_keys += keys
+            self.kv_window_ctx_keys += ctx_keys
 
     def record_moe(self, counts) -> None:
         with self._lock:
@@ -474,6 +508,12 @@ class KVCacheStats:
                 "moe_routed_pairs": self.moe_routed_pairs,
                 "moe_tokens_per_expert": list(self.moe_tokens_per_expert),
                 "moe_fullest_expert_tokens": self.moe_fullest_expert_tokens,
+                "window_blocks_in_use": self.window_blocks_in_use,
+                "window_blocks_total": self.window_blocks_total,
+                "kv_window_blocks_allocated": self.kv_window_blocks_allocated,
+                "kv_window_blocks_freed": self.kv_window_blocks_freed,
+                "kv_window_keys": self.kv_window_keys,
+                "kv_window_ctx_keys": self.kv_window_ctx_keys,
             }
 
 
@@ -914,6 +954,12 @@ def _render_kv_lines() -> list[str]:
         "# TYPE pathway_kv_conv_slots_total gauge",
         "# TYPE pathway_kv_moe_routed_pairs_total counter",
         "# TYPE pathway_kv_moe_tokens_per_expert_total counter",
+        "# TYPE pathway_kv_window_blocks_in_use gauge",
+        "# TYPE pathway_kv_window_blocks_total gauge",
+        "# TYPE pathway_kv_window_blocks_allocated_total counter",
+        "# TYPE pathway_kv_window_blocks_freed_total counter",
+        "# TYPE pathway_kv_window_keys_total counter",
+        "# TYPE pathway_kv_window_ctx_keys_total counter",
     ]
     for s in stats:
         snap = s.snapshot()
@@ -1079,6 +1125,15 @@ def _render_kv_lines() -> list[str]:
                          f"{snap['conv_slots_in_use']}")
             lines.append(f"pathway_kv_conv_slots_total{{{lbl}}} "
                          f"{snap['conv_slots_total']}")
+        if snap["window_blocks_total"]:  # a windowed cache
+            for key in ("window_blocks_in_use", "window_blocks_total"):
+                lines.append(f"pathway_kv_{key}{{{lbl}}} {snap[key]}")
+            for key in ("window_blocks_allocated", "window_blocks_freed",
+                        "window_keys", "window_ctx_keys"):
+                lines.append(f"pathway_kv_{key}_total{{{lbl}}} "
+                             f"{snap['kv_' + key]}")
+        if snap["conv_slots_total"] or snap["window_blocks_total"]:
+            # the caches of the families with expert layers
             lines.append(f"pathway_kv_moe_routed_pairs_total{{{lbl}}} "
                          f"{snap['moe_routed_pairs']}")
             for e, n in enumerate(snap["moe_tokens_per_expert"]):

@@ -1,11 +1,14 @@
 """The engine<->cache contract (kvcache/backend.py) and the family seam
-(models/families.py), over the two kinds of cache that serve cells:
+(models/families.py), over the three kinds of cache that serve cells:
 
 - ``paged``: BlockPool, K/V blocks for every layer;
 - ``hybrid``: HybridCache, K/V blocks for the attention layers and a conv
-  slot a sequence beside them.
+  slot a sequence beside them;
+- ``windowed``: WindowedCache, K/V blocks for the full-attention layers
+  and a second pool and table for the sliding-window layers, whose blocks
+  are freed behind the window.
 
-Each contract test runs over both kinds through ``make_backend``, the seam
+Each contract test runs over every kind through ``make_backend``, the seam
 the engine builds (and, on a supervised restart, rebuilds) its cache
 through.  The family tests hold what the engine and the trace readers take
 from ``programs()``: the three kinds of step program, which arguments are
@@ -33,9 +36,11 @@ from pathway_tpu.models.decoder import DecoderConfig, init_decoder_params
 from pathway_tpu.models.families import step_family
 from pathway_tpu.serve import metrics as serve_metrics
 
-KINDS = ("paged", "hybrid")
+KINDS = ("paged", "hybrid", "windowed")
 _GEOM = dict(num_blocks=24, block_size=4, n_layers=2, n_heads=2, head_dim=8)
 _CONV = dict(conv_layers=3, conv_width=16, conv_slots=5)
+_WINDOW = dict(window=10, window_layers=3, round_tokens=6, max_seqs=5)
+_EXTRA = {"paged": {}, "hybrid": _CONV, "windowed": _WINDOW}
 
 _CFG = DecoderConfig(
     vocab_size=64, d_model=64, n_layers=2, n_heads=8, d_ff=128, max_len=128
@@ -44,7 +49,7 @@ _HD = _CFG.d_model // _CFG.n_heads
 
 
 def _make(kind, name, **over):
-    kw = dict(_GEOM, name=name, **(_CONV if kind == "hybrid" else {}))
+    kw = dict(_GEOM, name=name, **_EXTRA[kind])
     kw.update(over)
     return make_backend(kind, **kw)
 
@@ -55,7 +60,7 @@ def params():
 
 
 @pytest.fixture(scope="module")
-def lfm2():
+def lfm2_only():
     from pathway_tpu.models.lfm2 import Lfm2Config, init_lfm2_params
 
     a, c = "full_attention", "conv"
@@ -66,15 +71,33 @@ def lfm2():
     return cfg, init_lfm2_params(cfg, jax.random.PRNGKey(0))
 
 
+@pytest.fixture(scope="module")
+def afmoe():
+    from pathway_tpu.models.afmoe import AfmoeConfig, init_afmoe_params
+
+    s, f = "sliding_attention", "full_attention"
+    cfg = AfmoeConfig(vocab_size=257, d_model=64, n_heads=8, n_kv_heads=2,
+                      head_dim=128, d_ff=96, d_ff_expert=32, n_experts=8,
+                      top_k=2, n_dense_layers=1, layer_types=(s, f, s),
+                      sliding_window=8, max_len=256, dtype=jnp.float32)
+    return cfg, init_afmoe_params(cfg, jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module")
+def lfm2(lfm2_only, afmoe):
+    """The two families that bring a cache of their own, by its kind."""
+    return {"hybrid": lfm2_only, "windowed": afmoe}
+
+
 def _engine(kind, params, lfm2, name, **kw):
     geom = dict(num_blocks=64, block_size=4, max_batch_size=4,
                 prefill_chunk=8, chain_steps=4)
     geom.update(kw)
     if kind == "paged":
         return PagedDecodeEngine(_CFG, params, name=name, **geom)
-    cfg, lparams = lfm2
+    cfg, fparams = lfm2[kind]
     geom.setdefault("attn", "reference")
-    return PagedDecodeEngine(cfg, lparams, name=name, **geom)
+    return PagedDecodeEngine(cfg, fparams, name=name, **geom)
 
 
 def _nbytes(arrays) -> int:
@@ -82,8 +105,9 @@ def _nbytes(arrays) -> int:
 
 
 def _cache_arrays(kind) -> int:
-    """K and V pools, and the hybrid kind's conv arena."""
-    return 3 if kind == "hybrid" else 2
+    """K and V pools, and the hybrid kind's conv arena or the windowed
+    kind's second pool pair."""
+    return {"paged": 2, "hybrid": 3, "windowed": 4}[kind]
 
 
 # -- the contract, over both kinds ---------------------------------------------
@@ -135,6 +159,7 @@ def test_lifecycle_fuzz_holds_the_invariants_after_every_operation(kind):
             victim = pool.preempt()
             if victim is not None:
                 live.remove(victim.seq_id)
+        pool.after_sync()  # a windowed cache frees behind the window here
         pool.check_invariants()
         assert sorted(s.seq_id for s in pool.sequences()) == sorted(live)
     assert all(counts.values()), counts
@@ -144,6 +169,11 @@ def test_lifecycle_fuzz_holds_the_invariants_after_every_operation(kind):
     assert pool.num_free == pool.num_blocks - 1
     if kind == "hybrid":
         assert pool.slots_in_use == 0
+    if kind == "windowed":
+        assert pool.window_blocks_in_use == 0
+        snap = pool.stats.snapshot()
+        assert snap["kv_window_blocks_allocated"] \
+            == snap["kv_window_blocks_freed"] > 0
 
 
 @pytest.mark.parametrize("kind", KINDS)
@@ -235,6 +265,10 @@ def test_retire_hands_the_name_and_its_gauges_to_the_next_cache(kind):
     assert _gauge(lines, "pathway_kv_blocks_in_use", name) == [3.0]
     if kind == "hybrid":
         assert _gauge(lines, "pathway_kv_conv_slots_in_use", name) == [1.0]
+    if kind == "windowed":
+        first.reserve_chunk(2, 9)
+        lines = serve_metrics.render_prometheus_lines()
+        assert _gauge(lines, "pathway_kv_window_blocks_in_use", name) == [3.0]
     unretired = _make(kind, name)
     assert unretired.name == name + "#1"
     unretired.retire()
@@ -248,6 +282,10 @@ def test_retire_hands_the_name_and_its_gauges_to_the_next_cache(kind):
         assert _gauge(lines, "pathway_kv_conv_slots_in_use", name) == [0.0]
         assert _gauge(lines, "pathway_kv_conv_slots_total", name) \
             == [float(_CONV["conv_slots"])]
+    if kind == "windowed":
+        assert _gauge(lines, "pathway_kv_window_blocks_in_use", name) == [0.0]
+        assert _gauge(lines, "pathway_kv_window_blocks_total", name) \
+            == [float(second.window_blocks - 1)]
     second.retire()
 
 
@@ -255,7 +293,7 @@ def test_retire_hands_the_name_and_its_gauges_to_the_next_cache(kind):
 def test_host_tiering_round_trips_or_refuses_by_name(kind):
     pool = _make(kind, f"t_cb_tier_{kind}")
     st = pool.allocate(1, 11)
-    if kind == "hybrid":
+    if kind != "paged":
         with pytest.raises(UnsupportedCacheOp, match="host tiering"):
             pool.suspend_host(1, list(range(11)))
         with pytest.raises(UnsupportedCacheOp, match="host tiering"):
@@ -367,13 +405,14 @@ def test_a_reused_conv_slot_gives_what_a_fresh_engine_gives(params, lfm2):
 @pytest.mark.parametrize("kind", KINDS)
 def test_a_family_names_its_three_programs_for_the_trace(kind, sampled,
                                                          params, lfm2):
-    cfg = _CFG if kind == "paged" else lfm2[0]
+    cfg = _CFG if kind == "paged" else lfm2[kind][0]
     family = step_family(cfg)
     assert family.cache_kind == kind
     if family.greedy_only and sampled:
         # never asked for by the engine (a sampled request fails alone at
         # admission); asked anyway, the family refuses by name
-        with pytest.raises(ValueError, match="lfm2 .* decodes greedily"):
+        with pytest.raises(ValueError,
+                           match=family.name + " .* decodes greedily"):
             family.programs(cfg, "reference", None, sampled=True)
         return
     table = family.programs(cfg, "reference", None, sampled=sampled)
@@ -417,13 +456,19 @@ def test_a_mixed_program_names_its_paged_kernels(kind, params, lfm2):
     eng._mixed = lowering_mixed
     eng.generate(list(range(1, 12)), 2)
     funcs = re.findall(r"func\.func \w+ @(\w+)\(", texts[0])
-    kernels = sorted(f for f in funcs if f.startswith("_paged_"))
+    found = [f for f in funcs if f.startswith("_paged_")]
+    # one function a pool pair: the windowed kind's second pair has shapes
+    # (and a window) of its own, and its functions a numbered name that
+    # the readers' patterns match as they match the first
+    assert len(found) == (4 if kind == "windowed" else 2)
+    kernels = sorted({re.sub(r"_\d+$", "", f) for f in found})
     assert kernels == ["_paged_ragged_fn", "_paged_write_fn"]
+    assert all(re.match(r"^_paged_(ragged|write)_fn", f) for f in found)
     metrics = os.path.join(os.path.dirname(__file__), "..", "benchmark",
                            "metrics")
     reads = {}
     for metric in ("kv_write_ms", "paged_attn_roofline",
-                   "paged_attn_gqa_roofline"):
+                   "paged_attn_gqa_roofline", "paged_attn_swa_roofline"):
         with open(os.path.join(metrics, metric + ".json")) as f:
             pattern = json.load(f)["pattern"]
         reads[metric] = [k for k in kernels + ["_paged_append_fn"]
@@ -431,7 +476,8 @@ def test_a_mixed_program_names_its_paged_kernels(kind, params, lfm2):
     assert reads == {
         "kv_write_ms": ["_paged_write_fn"],
         "paged_attn_roofline": ["_paged_ragged_fn", "_paged_append_fn"],
-        "paged_attn_gqa_roofline": ["_paged_ragged_fn", "_paged_append_fn"]}
+        "paged_attn_gqa_roofline": ["_paged_ragged_fn", "_paged_append_fn"],
+        "paged_attn_swa_roofline": ["_paged_ragged_fn", "_paged_append_fn"]}
 
 
 def test_the_verify_program_is_the_familys_mixed_program(params, lfm2):
@@ -464,15 +510,16 @@ def _imported_modules(path: str, package: str) -> set[str]:
 
 def test_the_engine_imports_no_models_math():
     # kvcache/engine.py -> models/families.py -> models/decoder.py |
-    # models/lfm2.py: the engine reaches a model's programs through its
-    # family and no other way
+    # models/lfm2.py | models/afmoe.py: the engine reaches a model's
+    # programs through its family and no other way
     import pathway_tpu.kvcache.engine as engine_mod
 
     mods = _imported_modules(engine_mod.__file__, "pathway_tpu.kvcache")
     assert "pathway_tpu.models.families" in mods
     models = {m for m in mods if m.startswith("pathway_tpu.models")}
     assert not {m for m in models
-                if m.split(".")[2] in ("decoder", "lfm2")}, models
+                if m.split(".")[2] in ("decoder", "lfm2", "afmoe")}, models
     with open(engine_mod.__file__) as f:
         src = f.read()
-    assert "models.decoder" not in src and "models.lfm2" not in src
+    assert not any("models." + m in src for m in ("decoder", "lfm2", "afmoe"))
+    assert "afmoe" not in src  # no keyword, no branch names the family

@@ -389,3 +389,110 @@ def test_grouped_matmul_kernel_compiles_at_the_published_experts(shape,
         shape((1,), jnp.int32))
     assert compiled.as_text().count("tpu_custom_call") == 2
     assert "_moe_gmm_fn" in compiled.as_text()
+
+
+# -- the afmoe block family (Trinity-Mini's published widths) -----------------
+# 2048 wide, 32 query heads over 4 K/V heads of 128, 128 experts of 1024
+# top-8 beside a shared one, a sliding window of 2,048, vocab 200,192; the
+# engine's geometry for the benchmark's cut: 16 rows, a chunk of 256, tables
+# of 512, 8,193 full blocks and 16 x 146 + 1 window blocks of 16.
+
+
+@pytest.mark.parametrize("window", [None, 2048], ids=["full", "window"])
+def test_paged_kernels_compile_at_trinity_spans(shape, window):
+    """Both paged kernels at Trinity-Mini's geometry: a head is a whole
+    lane tile, the eight query heads of a K/V head fold into 2,048 query
+    rows a chunk of 256 (in tiles of 256, under a VMEM limit the call asks
+    for), and with a window the spans behind it are dead grid steps."""
+    import jax.numpy as jnp
+
+    pa = _paged()
+    kv, rep, hd, chunk, tables, i32 = 4, 8, 128, 256, 512, jnp.int32
+    blocks = 8193 if window is None else 16 * 146 + 1
+    assert pa.span_blocks(BS, tables, kv * hd) == 128 // BS
+    assert pa._row_tile(chunk * rep, pa._heads_per_group(kv, hd, chunk * rep)
+                        ) == 256
+    pool = shape((2 if window is None else 6, blocks, BS, kv * hd),
+                 jnp.bfloat16)
+    idx = (shape((1,), i32), shape((B, tables), i32), shape((B,), i32),
+           shape((B,), i32))
+    ragged = _compiled_kernel(
+        lambda q, k, v, li, bt, c0, cl: pa._paged_ragged_fn(
+            q, k, v, li, bt, c0, cl, d_true=hd, window=window),
+        shape((B, chunk, kv * rep, hd), jnp.bfloat16), pool, pool, *idx,
+    )
+    new = shape((B, kv, hd), jnp.bfloat16)
+    append = _compiled_kernel(
+        lambda q, k1, v1, k, v, li, bt, c0, cl, so: pa._paged_append_fn(
+            q, k1, v1, k, v, li, bt, c0, cl, so, d_true=hd, window=window),
+        shape((B, 1, kv * rep, hd), jnp.bfloat16), new, new, pool, pool,
+        *idx, idx[-1], donate_argnums=(3, 4),
+    )
+    for compiled in (ragged, append):
+        assert _pool_copies(compiled, pool.shape) == []
+
+
+def test_afmoe_mixed_step_compiles_at_the_published_widths(shape,
+                                                           monkeypatch):
+    """The windowed family's mixed step at the published widths, three
+    layers deep (sliding dense, sliding experts, full experts): the kernels
+    are in the compiled text (a writer and an attention call a layer, two
+    grouped matmuls an expert layer), both pool pairs enter row-major and
+    neither is copied whole, the temporaries stay under 128 MB."""
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.kvcache.windowed import window_pool_blocks
+    from pathway_tpu.models import afmoe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    s, f = afmoe.SLIDING, afmoe.FULL
+    cfg = afmoe.AfmoeConfig(n_dense_layers=1, layer_types=(s, s, f),
+                            max_len=8192, dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(
+        lambda: afmoe.init_afmoe_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(lambda a: shape(a.shape, a.dtype), shapes)
+    rows, chunk, tables, i32 = 16, 256, 512, jnp.int32
+    T, D = rows + chunk, cfg.n_kv_heads * cfg.head_dim
+    pool = shape((1, 8193, BS, D), jnp.bfloat16)
+    wpool = shape((2, window_pool_blocks(cfg.sliding_window, chunk, BS, rows),
+                   BS, D), jnp.bfloat16)
+    assert wpool.shape[1] == 16 * 146 + 1
+
+    def vec(*dims):
+        return shape(dims, i32)
+
+    args = (vec(T), vec(T), vec(rows, tables), vec(rows), vec(rows),
+            vec(rows, chunk), vec(T), vec(T), vec(T), vec(T), vec(rows),
+            vec(rows, tables))
+    compiled = jax.jit(
+        lambda p, k, v, kw, vw, *a: afmoe.windowed_mixed_step(
+            p, cfg, k, v, kw, vw, *a, attn="pallas"),
+        donate_argnums=(1, 2, 3, 4),
+    ).lower(params, pool, pool, wpool, wpool, *args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2 * 3 + 2 * 2
+    layouts = compiled.input_formats[0]
+    for i in (1, 2, 3, 4):
+        assert layouts[i].layout.major_to_minor == (0, 1, 2, 3), layouts[i]
+    assert _pool_copies(compiled, pool.shape) == []
+    assert _pool_copies(compiled, wpool.shape) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
+
+
+def test_grouped_matmul_kernel_compiles_at_128_experts(shape):
+    """128 experts of 3 x 2048 x 1024 in bf16 and the routed pairs of a
+    mixed step of 272 tokens top-8, in whole tiles of 16."""
+    import jax.numpy as jnp
+
+    from pathway_tpu.ops import moe
+
+    E, D, F, pairs = 128, 2048, 1024, 272 * 8
+    tiles = moe.n_tiles(pairs, E)
+    assert tiles == 264
+    bf16 = jnp.bfloat16
+    compiled = _compiled_kernel(
+        moe._moe_gmm_fn, shape((tiles * moe.TM, D), bf16),
+        shape((E, D, F), bf16), shape((E, D, F), bf16),
+        shape((E, F, D), bf16), shape((tiles,), jnp.int32),
+        shape((1,), jnp.int32))
+    assert compiled.as_text().count("tpu_custom_call") == 2
