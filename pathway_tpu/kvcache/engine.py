@@ -69,6 +69,7 @@ import numpy as np
 from .. import faults, obs
 from .backend import make_backend
 from .block_pool import BlockPool, PoolExhausted  # noqa: F401 - re-export
+from .packing import RoundLayout
 from .paged_attention import span_blocks
 from .prefix_cache import PrefixCache
 
@@ -685,6 +686,9 @@ class PagedDecodeEngine:
         # program's (B, chain_steps) shape is static too, so the whole
         # multi-step hot loop is ONE additional compile (K=1 rounds reuse
         # the plain step program)
+        # (kind, sampled) -> the layout of that program's packed operand
+        # (_layout): a round's arrays cross in one buffer, one transfer
+        self._layouts: dict = {}
         greedy = self._programs(sampled=False)
         self._step = greedy["step"]
         self._mixed = greedy["mixed"]
@@ -723,10 +727,21 @@ class PagedDecodeEngine:
         table = self.family.programs(self.cfg, self.attn, self.mesh,
                                      sampled=sampled)
         return {
-            kind: profiled_jit(f"{_PROGRAM_NAMES[kind]}{sfx}", fn,
+            kind: profiled_jit(f"{_PROGRAM_NAMES[kind]}{sfx}",
+                               self._layout(kind, sampled).program(fn),
                                donate_argnums=donated)
             for kind, (fn, donated) in table.items()
         }
+
+    def _layout(self, kind: str, sampled: bool = False) -> RoundLayout:
+        """The layout of one step program's packed operand: each jitted
+        program takes ``(params, *cache arrays, packed)`` and cuts the
+        family's operands out of ``packed`` (kvcache/packing.py).  Fixed
+        by the program's first round (:meth:`_h2d`)."""
+        layout = self._layouts.get((kind, sampled))
+        if layout is None:
+            layout = self._layouts[(kind, sampled)] = RoundLayout()
+        return layout
 
     def _sampled_programs(self) -> dict:
         """The pw.*_sampled programs (Round-15), built on FIRST use: each
@@ -775,16 +790,17 @@ class PagedDecodeEngine:
             fn, donated = self.family.programs(
                 self.cfg, self.attn, self.mesh)["mixed"]
             self._verify = profiled_jit(
-                f"pw.verify_step{self._prog_suffix}", fn,
+                f"pw.verify_step{self._prog_suffix}",
+                self._layout("verify").program(fn),
                 donate_argnums=donated)
         return self._verify
 
-    def _call(self, prog, dev: tuple):
-        """Run a greedy step program on the cache's device arrays and put
-        back what it returns after its ids (the donated arrays, and for a
-        hybrid cache the device's counters)."""
+    def _call(self, prog, packed):
+        """Run a step program on the cache's device arrays and the round's
+        packed operand, and put back what it returns after its ids (the
+        donated arrays, and for a hybrid cache the device's counters)."""
         pool = self.pool
-        ids, *state = prog(self.params, *pool.device_state(), *dev)
+        ids, *state = prog(self.params, *pool.device_state(), packed)
         pool.set_device_state(*state)
         return ids
 
@@ -1324,12 +1340,17 @@ class PagedDecodeEngine:
         key = name[9:] if name.startswith("pw.round.") else "dispatch"
         return _RoundPhase(self.pool.stats, key, name, self._run_ctx, attrs)
 
-    def _h2d(self, host: tuple) -> tuple:
-        """The step's numpy arrays to the device, one transfer each, in
-        order (``pw.round.h2d``)."""
+    def _h2d(self, kind: str, sampled: bool, host: tuple):
+        """The step's numpy arrays to the device in ONE transfer, packed
+        by the layout of the program they are for (``pw.round.h2d``:
+        ``arrays`` the arrays a round is made of, ``transfers`` what
+        crossed)."""
+        self.pool.stats.record_h2d(len(host), 1)
         with self._phase("pw.round.h2d", arrays=len(host),
-                         bytes=sum(a.nbytes for a in host)):
-            return tuple(jnp.asarray(a) for a in host)
+                         transfers=1) as ph:
+            packed = self._layout(kind, sampled).pack(host)
+            ph.set(bytes=packed.nbytes)
+            return jnp.asarray(packed)
 
     def _sync_host(self, dev_array) -> np.ndarray:
         """Device->host sync (``pw.round.sync``), watchdog-bounded when
@@ -1699,10 +1720,10 @@ class PagedDecodeEngine:
         faults.fire("engine.dispatch.verify")
         self._note_dispatch("verify")
         t_disp = self._t_dispatch
-        dev = self._h2d(host)
+        dev = self._h2d("verify", False, host)
         prog = self._verify_program()
         with self._phase("pw.verify_step"):
-            ids, pool.k, pool.v = prog(self.params, pool.k, pool.v, *dev)
+            ids = self._call(prog, dev)
         ids = self._sync_host(ids)
         with self._phase("pw.round.deliver") as ph:
             t_sync1 = time.perf_counter()
@@ -1859,7 +1880,8 @@ class PagedDecodeEngine:
         faults.fire("engine.dispatch.chain")
         self._note_dispatch("chain")
         t_disp = self._t_dispatch
-        dev = self._h2d(host if samp is None else host + samp)
+        dev = self._h2d("chained", samp is not None,
+                        host if samp is None else host + samp)
         prog = self._chained if samp is None \
             else self._sampled_programs()["chained"]
         with self._phase("pw.chain_dispatch" if samp is None
@@ -2056,7 +2078,7 @@ class PagedDecodeEngine:
         faults.fire("engine.dispatch.step")
         self._note_dispatch("step")
         t_disp = self._t_dispatch
-        dev = self._h2d(host)
+        dev = self._h2d("step", sampled, host)
         prog = self._sampled_programs()["step"] if sampled else self._step
         with self._phase("pw.decode_step_sampled" if sampled
                          else "pw.decode_step"):
@@ -2219,7 +2241,7 @@ class PagedDecodeEngine:
         for act, _row, filled in rows:
             if filled >= 0:
                 act.req.note_chunk(t_disp)
-        dev = self._h2d(host)
+        dev = self._h2d("mixed", sampled, host)
         prog = self._sampled_programs()["mixed"] if sampled else self._mixed
         with self._phase("pw.mixed_step_sampled" if sampled
                          else "pw.mixed_step"):

@@ -155,6 +155,10 @@ class KVCacheStats:
       ``dispatch`` (the program call's enqueue), ``sync``, ``deliver`` —
       the ``pw.round.*`` phases of kvcache/engine.py; their sum is the
       engine thread's time, and everything but ``sync`` is host work)
+    - ``pathway_kv_h2d_arrays_total{pool}`` /
+      ``pathway_kv_h2d_transfers_total{pool}`` counters (step arrays the
+      dispatches were made of over the host-to-device transfers they
+      crossed in: one a dispatch, kvcache/packing.py)
     - ``pathway_kv_mixed_tokens_used_total{pool}`` /
       ``pathway_kv_mixed_tokens_budget_total{pool}`` counters (packed
       tokens the mixed dispatches carried over the ``mixed_tokens`` they
@@ -227,6 +231,8 @@ class KVCacheStats:
         self.chain_emitted = 0
         self.host_gap_s = 0.0
         self.round_s: dict[str, float] = {}
+        self.h2d_arrays = 0
+        self.h2d_transfers = 0
         self.mixed_tokens_used = 0
         self.mixed_tokens_budget = 0
         self.kv_keys = 0
@@ -371,6 +377,13 @@ class KVCacheStats:
         with self._lock:
             self.round_s[phase] = self.round_s.get(phase, 0.0) + seconds
 
+    def record_h2d(self, arrays: int, transfers: int) -> None:
+        """Step arrays one dispatch was made of, and the host-to-device
+        transfers they crossed in."""
+        with self._lock:
+            self.h2d_arrays += arrays
+            self.h2d_transfers += transfers
+
     def record_mixed_tokens(self, used: int, budget: int) -> None:
         """Packed tokens one mixed dispatch carried, of its budget."""
         with self._lock:
@@ -485,6 +498,8 @@ class KVCacheStats:
                 "chain_occupancy": self.chain_occupancy,
                 "host_gap_s": self.host_gap_s,
                 "round_s": dict(self.round_s),
+                "h2d_arrays": self.h2d_arrays,
+                "h2d_transfers": self.h2d_transfers,
                 "mixed_tokens_used": self.mixed_tokens_used,
                 "mixed_tokens_budget": self.mixed_tokens_budget,
                 "kv_keys": self.kv_keys,
@@ -935,6 +950,8 @@ def _render_kv_lines() -> list[str]:
         "# TYPE pathway_kv_chain_occupancy gauge",
         "# TYPE pathway_kv_host_gap_seconds_total counter",
         "# TYPE pathway_kv_round_seconds_total counter",
+        "# TYPE pathway_kv_h2d_arrays_total counter",
+        "# TYPE pathway_kv_h2d_transfers_total counter",
         "# TYPE pathway_kv_mixed_tokens_used_total counter",
         "# TYPE pathway_kv_mixed_tokens_budget_total counter",
         "# TYPE pathway_kv_attended_keys_total counter",
@@ -1058,6 +1075,13 @@ def _render_kv_lines() -> list[str]:
                 f'pathway_kv_round_seconds_total{{{lbl},phase="{phase}"}} '
                 f"{seconds:.6f}"
             )
+        lines.append(
+            f"pathway_kv_h2d_arrays_total{{{lbl}}} {snap['h2d_arrays']}"
+        )
+        lines.append(
+            f"pathway_kv_h2d_transfers_total{{{lbl}}} "
+            f"{snap['h2d_transfers']}"
+        )
         lines.append(
             f"pathway_kv_mixed_tokens_used_total{{{lbl}}} "
             f"{snap['mixed_tokens_used']}"

@@ -437,6 +437,38 @@ def test_a_family_names_its_three_programs_for_the_trace(kind, sampled,
     assert len(eng.pool.device_state()) == n
 
 
+def _lowered_program(eng, attr: str, n_new: int) -> str:
+    """The StableHLO of the step program ``eng.<attr>`` as the engine
+    calls it: on ``(params, *cache arrays, packed)``."""
+    prog, texts = getattr(eng, attr), []
+
+    def lowering(*args):
+        if not texts:
+            assert len(args) == 2 + len(eng.pool.device_state())
+            assert args[-1].dtype == np.int32 and args[-1].ndim == 1
+            texts.append(prog.lower(*args).as_text())
+        return prog(*args)
+
+    setattr(eng, attr, lowering)
+    eng.generate(list(range(1, 12)), n_new)
+    return texts[0]
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_chained_program_names_itself_and_its_kernel(kind, params, lfm2):
+    """The chained program behind its packed operand: the module is still
+    ``jit__chained_fn`` (what ``decode_step_ms`` and the MFUs' decode
+    events search ``XLA Modules`` for) and its one kernel a pool pair is
+    ``_paged_append_fn``, which the attention rooflines read."""
+    eng = _engine(kind, params, lfm2, f"t_cb_chain_{kind}", attn="pallas")
+    text = _lowered_program(eng, "_chained", 8)
+    assert re.search(r"module @(\w+)", text).group(1) == "jit__chained_fn"
+    funcs = re.findall(r"func\.func \w+ @(\w+)\(", text)
+    found = [f for f in funcs if f.startswith("_paged_")]
+    assert len(found) == (2 if kind == "windowed" else 1)
+    assert {re.sub(r"_\d+$", "", f) for f in found} == {"_paged_append_fn"}
+
+
 @pytest.mark.parametrize("kind", KINDS)
 def test_a_mixed_program_names_its_paged_kernels(kind, params, lfm2):
     """The device trace names a kernel's events by the jitted function
@@ -444,18 +476,13 @@ def test_a_mixed_program_names_its_paged_kernels(kind, params, lfm2):
     ``paged_attn_gqa_roofline`` read ``^_paged_(append|ragged)_fn`` and
     ``kv_write_ms`` reads the mixed step's K/V writer, which the
     rooflines must not count.  Each is one function of the lowered
-    program however many layers call it."""
+    program however many layers call it.  The program itself, behind its
+    packed operand, is still ``jit__mixed_fn``: ``mixed_step_ms`` and the
+    MFUs find it on ``XLA Modules`` by that name."""
     eng = _engine(kind, params, lfm2, f"t_cb_kernels_{kind}", attn="pallas")
-    mixed, texts = eng._mixed, []
-
-    def lowering_mixed(*args):
-        if not texts:
-            texts.append(mixed.lower(*args).as_text())
-        return mixed(*args)
-
-    eng._mixed = lowering_mixed
-    eng.generate(list(range(1, 12)), 2)
-    funcs = re.findall(r"func\.func \w+ @(\w+)\(", texts[0])
+    text = _lowered_program(eng, "_mixed", 2)
+    assert re.search(r"module @(\w+)", text).group(1) == "jit__mixed_fn"
+    funcs = re.findall(r"func\.func \w+ @(\w+)\(", text)
     found = [f for f in funcs if f.startswith("_paged_")]
     # one function a pool pair: the windowed kind's second pair has shapes
     # (and a window) of its own, and its functions a numbered name that
@@ -466,6 +493,8 @@ def test_a_mixed_program_names_its_paged_kernels(kind, params, lfm2):
     assert all(re.match(r"^_paged_(ragged|write)_fn", f) for f in found)
     metrics = os.path.join(os.path.dirname(__file__), "..", "benchmark",
                            "metrics")
+    with open(os.path.join(metrics, "mixed_step_ms.json")) as f:
+        assert re.search(json.load(f)["pattern"], "jit__mixed_fn(123)")
     reads = {}
     for metric in ("kv_write_ms", "paged_attn_roofline",
                    "paged_attn_gqa_roofline", "paged_attn_swa_roofline"):

@@ -496,3 +496,123 @@ def test_grouped_matmul_kernel_compiles_at_128_experts(shape):
         shape((E, F, D), bf16), shape((tiles,), jnp.int32),
         shape((1,), jnp.int32))
     assert compiled.as_text().count("tpu_custom_call") == 2
+
+
+# -- the step programs behind their packed operand (PR 32) --------------------
+# The engine hands a jitted step program (params, *cache arrays, packed):
+# the round's index arrays in one int32 buffer (kvcache/packing.py).  Here
+# each family's own table of programs, wrapped as the engine wraps it, at
+# the published widths and a few layers deep.
+
+
+def _family_case(shape, family):
+    """(programs table, parameter shapes, cache arrays, the mixed and the
+    chained program's index arrays, custom calls of a mixed step)."""
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.models import families
+
+    i32, rows, chain = jnp.int32, B, 16
+
+    def vec(*dims):
+        return shape(dims, i32)
+
+    def index_arrays(T, chunk, tables, extras):
+        return {
+            "mixed": (vec(T), vec(T), vec(rows, tables), vec(rows),
+                      vec(rows), vec(rows, chunk), vec(T), vec(T), vec(T),
+                      vec(T), vec(rows)) + extras,
+            "chained": (vec(rows), vec(rows), vec(rows, tables),
+                        vec(rows, chain), vec(rows, chain)) + extras}
+
+    def planned(fam, cfg, init):
+        shapes = jax.eval_shape(lambda: fam.plan(
+            cfg, init(cfg, jax.random.PRNGKey(0)), tp=1, quantize=None))
+        return jax.tree_util.tree_map(lambda s: shape(s.shape, s.dtype),
+                                      shapes)
+
+    if family == "decoder":
+        from pathway_tpu.models import decoder
+
+        fam = families.DecoderFamily
+        cfg = decoder.DecoderConfig(vocab_size=50257, d_model=H * HD,
+                                    n_layers=2, n_heads=H, d_ff=4 * H * HD,
+                                    max_len=1024, dtype=jnp.bfloat16)
+        params = planned(fam, cfg, lambda c, k: decoder.init_decoder_params(
+            c, k))
+        pool = shape((2, NBLK, BS, H * HD), jnp.bfloat16)
+        return (fam.programs(cfg, "pallas", None), params, (pool, pool),
+                index_arrays(B + CHUNK, CHUNK, NB, ()), {"mixed": 2 * 2,
+                                                         "chained": 2})
+    if family == "lfm2":
+        cfg, _raw, pool, arena, _p = _lfm2_programs(shape, depth=5)
+        from pathway_tpu.models import lfm2
+
+        fam = families.Lfm2Family
+        params = planned(fam, cfg, lfm2.init_lfm2_params)
+        n_moe = cfg.n_layers - cfg.n_dense_layers
+        n_attn = len(cfg.attn_layers)
+        return (fam.programs(cfg, "pallas", None), params,
+                (pool, pool, arena),
+                index_arrays(rows + 32, 32, 128, (vec(rows),)),
+                {"mixed": 2 * n_attn + 2 * n_moe,
+                 "chained": n_attn + 2 * n_moe})
+    from pathway_tpu.kvcache.windowed import window_pool_blocks
+    from pathway_tpu.models import afmoe
+
+    fam = families.AfmoeFamily
+    s, f = afmoe.SLIDING, afmoe.FULL
+    cfg = afmoe.AfmoeConfig(n_dense_layers=1, layer_types=(s, s, f),
+                            max_len=8192, dtype=jnp.bfloat16)
+    params = planned(fam, cfg, afmoe.init_afmoe_params)
+    chunk, tables, D = 256, 512, cfg.n_kv_heads * cfg.head_dim
+    pool = shape((1, 8193, BS, D), jnp.bfloat16)
+    wpool = shape((2, window_pool_blocks(cfg.sliding_window, chunk, BS, rows),
+                   BS, D), jnp.bfloat16)
+    return (fam.programs(cfg, "pallas", None), params,
+            (pool, pool, wpool, wpool),
+            index_arrays(rows + chunk, chunk, tables, (vec(rows, tables),)),
+            {"mixed": 2 * 3 + 2 * 2, "chained": 3 + 2 * 2})
+
+
+@pytest.mark.parametrize("program", ["mixed", "chained"])
+@pytest.mark.parametrize("family", ["decoder", "lfm2", "afmoe"])
+def test_packed_step_programs_lower_under_their_names(shape, monkeypatch,
+                                                      family, program):
+    """A family's mixed and chained programs as the engine jits them, on
+    one packed index operand: the module is still ``jit__mixed_fn`` /
+    ``jit__chained_fn`` and the kernels' functions ``_paged_*_fn`` (the
+    names the benchmark's readers search the device trace for), every
+    kernel is in the compiled text, the cache's arrays are donated and
+    enter row-major, and no pool is copied whole."""
+    import math
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.kvcache.packing import RoundLayout
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    table, params, state, host, calls = _family_case(shape, family)
+    fn, donated = table[program]
+    assert tuple(donated) == tuple(range(1, len(state) + 1))
+    layout = RoundLayout(host[program])
+    packed = shape((layout.size,), jnp.int32)
+    assert layout.size == sum(math.prod(a.shape) for a in host[program])
+    lowered = jax.jit(layout.program(fn), donate_argnums=donated).lower(
+        params, *state, packed)
+    text = lowered.as_text()
+    assert re.search(r"module @(\w+)", text).group(1) == f"jit__{program}_fn"
+    kernels = {re.sub(r"_\d+$", "", f) for f in re.findall(
+        r"func\.func \w+ @(_paged_\w+)\(", text)}
+    assert kernels == ({"_paged_ragged_fn", "_paged_write_fn"}
+                       if program == "mixed" else {"_paged_append_fn"})
+    compiled = lowered.compile()
+    assert compiled.as_text().count("tpu_custom_call") == calls[program]
+    layouts = compiled.input_formats[0]
+    for i in donated:
+        assert layouts[i].layout.major_to_minor == (0, 1, 2, 3), layouts[i]
+    for pool in {s.shape for s in state}:
+        assert _pool_copies(compiled, pool) == []

@@ -196,6 +196,7 @@ def test_round_phases_tile_the_engine_thread(wide_params, path, kw, n_new):
                 assert 0 < s.attrs["kv_keys"] <= s.attrs["kv_key_lanes"]
         elif s.name == "pw.round.h2d":
             assert s.attrs["arrays"] in (5, 11) and s.attrs["bytes"] > 0
+            assert s.attrs["transfers"] == 1
     if path == "chain":
         # chain N's callbacks run AFTER chain N+1 went out: a deliver
         # follows a chain dispatch before the next sync, truthfully
@@ -301,6 +302,161 @@ def test_round_counters_are_on_metrics(params):
     assert 0 < snap["kv_write_blocks"] <= snap["mixed_tokens_used"]
     assert any(x.startswith(f"pathway_kv_host_gap_seconds_total{{{lbl}}}")
                for x in lines)
+    # one transfer a dispatch, whatever it is made of (kvcache/packing.py)
+    h2d = [s for s in obs.recorder().snapshot() if s.name == "pw.round.h2d"]
+    assert snap["h2d_transfers"] == len(h2d) > 0
+    assert snap["h2d_arrays"] == sum(s.attrs["arrays"] for s in h2d) \
+        >= 5 * len(h2d)
+    assert f"pathway_kv_h2d_arrays_total{{{lbl}}} " \
+        f"{snap['h2d_arrays']}" in lines
+    assert f"pathway_kv_h2d_transfers_total{{{lbl}}} " \
+        f"{snap['h2d_transfers']}" in lines
+
+
+# -- one transfer a dispatch: the packed operand ------------------------------
+
+_B, _NB, _C, _K = 4, 16, 8, 4  # rows, table blocks, chunk, chain steps
+
+
+def _round_arrays(kind, cache, sampled, rng):
+    """Arrays of the shapes and dtypes the engine's builders make for one
+    round of ``kind`` (``_build_decode``, ``_dispatch_chain``,
+    ``_build_mixed``, ``_build_verify``), with the cache's row extras and
+    the five sampling arrays, filled with whatever bits."""
+    T = {"mixed": _B + _C, "verify": _B * _C}.get(kind)
+    shapes = {
+        "step": [(_B,), (_B,), (_B, _NB), (_B,), (_B,)],
+        "chained": [(_B,), (_B,), (_B, _NB), (_B, _K), (_B, _K)],
+    }.get(kind) or [(T,), (T,), (_B, _NB), (_B,), (_B,), (_B, _C), (T,),
+                    (T,), (T,), (T,), (T if kind == "verify" else _B,)]
+    shapes = shapes + {"decoder": [], "hybrid": [(_B,)],
+                       "windowed": [(_B, _NB)]}[cache]
+    out = [rng.integers(-2**31, 2**31, size=s, dtype=np.int64)
+           .astype(np.int32) for s in shapes]
+    if sampled:
+        # temperature and top_p are float32 and ride as their bits: a
+        # denormal, a negative zero and a NaN payload come back as sent
+        temp = np.array([0.8, -0.0, 1e-45, 0.0], np.float32)
+        top_p = rng.integers(0, 2**31, size=_B).astype(np.int32) \
+            .view(np.float32)
+        ints = [rng.integers(0, 2**31, size=_B).astype(np.int32)
+                for _ in range(3)]
+        out += [temp, ints[0], top_p, ints[1], ints[2]]
+    return out
+
+
+@pytest.mark.parametrize("cache", ["decoder", "hybrid", "windowed"])
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("kind", ["step", "mixed", "chained", "verify"])
+def test_packed_operand_unpacks_bit_exact(kind, sampled, cache):
+    """What the builders make crosses in one int32 buffer and the jitted
+    program cuts back exactly the arrays its function takes: shapes,
+    dtypes and every bit, the float sampling arrays included."""
+    from pathway_tpu.kvcache.packing import RoundLayout
+
+    rng = np.random.default_rng(32)
+    host = _round_arrays(kind, cache, sampled, rng)
+    n = {"step": 5, "chained": 5}.get(kind, 11) \
+        + (cache != "decoder") + 5 * sampled
+    assert len(host) == n
+    layout = RoundLayout()
+    packed = layout.pack(host)
+    assert packed.dtype == np.int32 and packed.ndim == 1
+    assert packed.nbytes == sum(a.nbytes for a in host) == 4 * layout.size
+
+    def _echo_fn(params, pool, *arrays):
+        return arrays, pool
+
+    prog = layout.program(_echo_fn)
+    assert prog.__name__ == "_echo_fn"
+    got, _pool = jax.jit(prog, donate_argnums=(1,))(
+        None, np.zeros(3, np.float32), packed)
+    assert len(got) == len(host)
+    for g, a in zip(got, host):
+        g = np.asarray(g)
+        assert g.shape == a.shape and g.dtype == a.dtype
+        assert np.array_equal(g.view(np.int32), a.view(np.int32))
+    # a second round of the same shapes goes through the same layout, in
+    # a buffer of its own
+    again = layout.pack(_round_arrays(kind, cache, sampled, rng))
+    assert again.shape == packed.shape and again is not packed
+    assert not np.shares_memory(again, packed)
+
+
+@pytest.mark.parametrize("how", ["shape", "dtype", "count"])
+def test_a_round_that_departs_from_the_layout_raises(how):
+    """The compiled program's slices are the first round's: a later round
+    whose arrays differ raises, the layout is not laid out anew."""
+    from pathway_tpu.kvcache.packing import RoundLayout
+
+    rng = np.random.default_rng(33)
+    host = _round_arrays("mixed", "windowed", True, rng)
+    layout = RoundLayout()
+    layout.pack(host)
+    fields = layout.fields
+    if how == "shape":
+        # the same words in all, another shape: the table transposed
+        host[-6] = np.ascontiguousarray(host[-6].T)
+    elif how == "dtype":
+        host[-5] = host[-5].view(np.int32)  # temperature as integers
+    else:
+        host = host[:-1]
+    with pytest.raises(ValueError, match="depart from the program's layout"):
+        layout.pack(host)
+    assert layout.fields is fields
+    with pytest.raises(TypeError, match="word for word"):
+        RoundLayout().pack([np.zeros(4, np.int64)])
+    with pytest.raises(RuntimeError, match="no layout yet"):
+        RoundLayout().unpack(np.zeros(4, np.int32))
+
+
+def test_an_engine_round_of_another_shape_raises(params):
+    """The same through the engine: its rounds pack by the layout of the
+    program they call, fixed at the program's first round."""
+    eng = _engine(params, "t_layout_departs")
+    eng.generate_batch([(p, 3) for p in _prompts((9, 5))])
+    layout = eng._layout("mixed")
+    assert [f[0] for f in layout.fields][:3] == [(12,), (12,), (4, eng.max_blocks_per_seq)]
+    assert eng._layout("mixed") is layout
+    assert layout is not eng._layout("mixed", sampled=True)
+    cached = eng.pool.blocks_in_use  # the prefix cache's, of the first run
+    eng.mixed_tokens += 1  # a later round builds longer token arrays
+    from pathway_tpu.serve.admission import EngineFailedError
+
+    with pytest.raises(EngineFailedError,
+                       match="depart from the program's layout"):
+        eng.generate_batch([(p, 3) for p in _prompts((7,), seed=2)])
+    assert eng._layout("mixed").fields is layout.fields
+    assert eng.pool.blocks_in_use == cached
+
+
+def test_the_benchmark_reads_arrays_per_transfer(params):
+    """``h2d_arrays_per_transfer`` (benchmark/metrics) through its reader
+    on this run's recorder: the counters' ratio, between a chain's five
+    arrays and a mixed round's eleven; spans without ``transfers`` (the
+    program before the packed operand) give no reading."""
+    import time
+    import types
+
+    from benchmark.readers import span_stat
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "metrics", "h2d_arrays_per_transfer.json")) as f:
+        spec = json.load(f)
+    eng = _engine(params, "t_h2d_metric")
+    t0 = time.perf_counter()
+    eng.generate_batch([(p, 12) for p in _prompts((20, 9, 13))])
+    window = (t0, time.perf_counter())
+    ring = obs.recorder().snapshot()  # nothing evicted: all that was kept
+    value = span_stat.from_ring(spec, ring, len(ring), window)
+    snap = eng.pool.stats.snapshot()
+    assert value == pytest.approx(snap["h2d_arrays"] / snap["h2d_transfers"])
+    assert 5 < value < 11
+    before = [types.SimpleNamespace(
+        name=s.name, t0=s.t0, t1=s.t1, trace_id=s.trace_id,
+        attrs={k: v for k, v in (s.attrs or {}).items() if k != "transfers"})
+        for s in ring]
+    assert span_stat.from_ring(spec, before, len(before), window) is None
 
 
 # -- a request's lifecycle ----------------------------------------------------
