@@ -31,12 +31,15 @@ reduction — no replicated [B, vocab] gather ever materializes).
 single-device programs.
 
 The engine<->cache contract is backend.py (``CacheBackend`` +
-``make_backend``), with three kinds behind it: ``"paged"`` (block_pool.py
+``make_backend``), with four kinds behind it: ``"paged"`` (block_pool.py
 ``BlockPool``: K/V blocks for every layer), ``"hybrid"`` (hybrid.py
 ``HybridCache``: K/V blocks for a model's attention layers and a conv
-slot a sequence beside them) and ``"windowed"`` (windowed.py
+slot a sequence beside them), ``"windowed"`` (windowed.py
 ``WindowedCache``: K/V blocks for a model's full-attention layers and a
-second pool, freed behind the window, for its sliding-window layers).  Which kind an engine builds, and which
+second pool, freed behind the window, for its sliding-window layers) and
+``"latent_state"`` (hybrid.py ``StateCache``: a latent pool of one array
+for a model's latent-attention layers and, under one slot a sequence, the
+conv inputs and the f32 matrix states of its delta-rule layers).  Which kind an engine builds, and which
 step programs it runs, its block family says (models/families.py).
 
 Round-18 (ARCHITECTURE.md "Round-18: Speculative decoding") breaks the
@@ -56,7 +59,7 @@ managed-resource framing follows arxiv 2603.09555.
 from .backend import CacheBackend, UnsupportedCacheOp, make_backend
 from .block_pool import BlockPool, PoolExhausted, SequenceState
 from .engine import EngineHungError, PagedDecodeEngine, resolve_tp
-from .hybrid import HybridCache
+from .hybrid import HybridCache, StateCache
 from .paged_attention import paged_attention, paged_attention_reference
 from .prefix_cache import PrefixCache
 from .speculative import (Drafter, DraftModelDrafter, NGramDrafter,
@@ -75,6 +78,7 @@ __all__ = [
     "CacheBackend",
     "EngineHungError",
     "HybridCache",
+    "StateCache",
     "PoolExhausted",
     "SequenceState",
     "PrefixCache",

@@ -2,7 +2,7 @@
 
 The engine (engine.py: admission, rounds, restart, sessions) programs
 against :class:`CacheBackend`, not against a layout; how a sequence's
-decode state lives in HBM is the backend's.  Three kinds serve cells:
+decode state lives in HBM is the backend's.  Four kinds serve cells:
 
 - ``"paged"`` — :class:`~pathway_tpu.kvcache.block_pool.BlockPool`: K/V
   blocks for every layer, addressed through per-sequence block tables;
@@ -12,6 +12,11 @@ decode state lives in HBM is the backend's.  Three kinds serve cells:
   conv slot a sequence for its short-conv layers.  Preemption only: a
   shared, forked or resumed block would skip the tokens that build the
   conv state.
+- ``"latent_state"`` — :class:`~pathway_tpu.kvcache.hybrid.StateCache`:
+  a latent pool (one array: a token's stored row is key and value both)
+  for a model's latent-attention layers and, in the slot arena, a matrix
+  state a head beside the carried conv inputs for its delta-rule layers.
+  Preemption only, as the hybrid kind.
 - ``"windowed"`` — :class:`~pathway_tpu.kvcache.windowed.WindowedCache`:
   the K/V blocks of a model's full-attention layers and, in a second pool
   with a block table of its own, those of its sliding-window layers, whose
@@ -70,7 +75,7 @@ class CacheBackend(abc.ABC):
     """Abstract engine↔cache contract.  See the module docstring for
     which side owns which invariant."""
 
-    #: "paged" | "hybrid" | "windowed" — the factory key
+    #: "paged" | "hybrid" | "windowed" | "latent_state" — the factory key
     cache_kind: str = "abstract"
     #: positions a sliding-window layer's query sees (itself included);
     #: None: every layer keeps every key
@@ -222,10 +227,14 @@ def make_backend(kind: str, **kwargs) -> CacheBackend:
             from .windowed import WindowedCache
 
             register_backend("windowed", WindowedCache)
+        elif kind == "latent_state":
+            from .hybrid import StateCache
+
+            register_backend("latent_state", StateCache)
         else:
             raise ValueError(
                 f"unknown cache backend {kind!r}; "
                 f"registered: {sorted(_BACKENDS)} + builtin: paged, hybrid, "
-                "windowed"
+                "windowed, latent_state"
             )
     return _BACKENDS[kind](**kwargs)

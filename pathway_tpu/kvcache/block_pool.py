@@ -106,6 +106,9 @@ class BlockPool(CacheBackend):
     (backend.py)."""
 
     cache_kind = "paged"
+    #: a second pool array for the values; a latent pool's stored row is key
+    #: and value both (``v`` is then None)
+    value_pool = True
     supports_fork = True
     supports_prefix = True
     supports_preemption = True
@@ -148,10 +151,10 @@ class BlockPool(CacheBackend):
                 lambda: jnp.zeros(shape, dtype), out_shardings=sharding
             )
             self.k = zeros()
-            self.v = zeros()
+            self.v = zeros() if self.value_pool else None
         else:
             self.k = jnp.zeros(shape, dtype)
-            self.v = jnp.zeros(shape, dtype)
+            self.v = jnp.zeros(shape, dtype) if self.value_pool else None
         # block 0 reserved: never allocated, target of padded writes
         self._free: list[int] = list(range(num_blocks - 1, 0, -1))
         self._ref = np.zeros(num_blocks, np.int32)
@@ -197,7 +200,7 @@ class BlockPool(CacheBackend):
     @property
     def per_shard_bytes(self) -> int:
         """K + V HBM held by EACH shard (the whole pool when tp=1)."""
-        total = int(self.k.size) + int(self.v.size)
+        total = int(self.k.size) * (2 if self.value_pool else 1)
         return total * self.k.dtype.itemsize // self.tp
 
     @property

@@ -1875,6 +1875,7 @@ class PagedDecodeEngine:
             self._note_keys(ph, [int(positions[i]) + 1 + t
                                  for i, k in enumerate(kreal)
                                  for t in range(k)])
+            self._note_state(ph, len(acts), step_rows=sum(kreal))
             extras = self._row_extras([a.seq_id for a in acts], ph)
         host = (token, positions, bt, sb, so) + extras
         faults.fire("engine.dispatch.chain")
@@ -2045,6 +2046,22 @@ class PagedDecodeEngine:
             ph.set(conv_rows=len(seq_ids))
         return extras
 
+    def _note_state(self, ph, rows: int, *, chunk_tokens: int = 0,
+                    step_rows: int = 0, resets: int = 0) -> None:
+        """With a cache of matrix-state slots (kvcache/hybrid.py
+        StateCache), on ``pw.round.build``: ``state_rows``, the rows that
+        carry a state slot; ``kda_chunk_tokens``, the tokens that go through
+        the chunked scan (rows of two tokens or more); ``kda_step_rows``,
+        the rows that go through the one-token recurrence (a chain's rows
+        once a step); and in the pool's counters ``kda_state_resets``, the
+        first chunks that start a slot's state from zero."""
+        if getattr(self.pool, "state", None) is None:
+            return
+        ph.set(state_rows=rows, kda_chunk_tokens=chunk_tokens,
+               kda_step_rows=step_rows)
+        if resets:
+            self.pool.stats.record_state_resets(resets)
+
     def _build_decode(self, reserved, ph) -> tuple:
         """The numpy arrays of one 1-token-per-row step (inside the
         caller's ``pw.round.build``): ``(host arrays, sampled?)``."""
@@ -2069,6 +2086,7 @@ class PagedDecodeEngine:
         ph.set(kind="step", rows=len(reserved), tokens=len(reserved),
                budget=B, waiting=0)
         self._note_keys(ph, [int(p) + 1 for p in positions[:len(reserved)]])
+        self._note_state(ph, len(reserved), step_rows=len(reserved))
         host = (token, positions, bt, sb, so) + self._row_extras(
             [act.seq_id for act, _s in reserved], ph)
         return (host if samp is None else host + samp), samp is not None
@@ -2225,6 +2243,11 @@ class PagedDecodeEngine:
                              row_start[:row] + row_nvalid[:row]],
                         [int(q) for q in row_nvalid[:row]])
         self._note_write_blocks(ph, sb[:t])
+        runs = row_nvalid[:row]
+        self._note_state(
+            ph, row, chunk_tokens=int(runs[runs >= 2].sum()),
+            step_rows=int((runs == 1).sum()),
+            resets=int(((runs >= 2) & (row_start[:row] == 0)).sum()))
         host = (tokens, positions, row_tables, row_start, row_nvalid,
                 row_token_idx, tok_row, tok_col, sb, so, logit_idx) \
             + self._row_extras([act.seq_id for act, _r, _f in rows], ph)

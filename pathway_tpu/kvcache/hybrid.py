@@ -19,6 +19,19 @@ earlier sequence left in a slot is never read.
 Prefix sharing, fork and host tiering are off: a shared or resumed block
 would skip the tokens that build the conv state (it would take a snapshot
 of the state at every block boundary; ROADMAP B4).
+
+:class:`StateCache` (PR 33) is the same arena with a second kind of slot
+under the same index: a matrix state a head, ``(state layers, slots + 1,
+heads, dk, dv)`` in f32, for the layers of a linear-attention family that
+mix tokens by a delta rule (models/kimi_linear.py), beside a conv slot of
+the carried inputs of their short convolutions; and with a LATENT pool in
+the place of K and V: one array ``(latent layers, blocks, block size,
+lanes)`` whose row is key and value both (``v`` is None).  Unlike a conv
+slot a matrix state is summed into, so what an earlier sequence left in a
+slot must not be read: the step programs start a sequence's first chunk
+from zero themselves (``row_start == 0``; no host-side clear, which would
+cost a dispatch), a chunk's padded tokens leave the state as it was, and a
+preempted sequence rebuilds it by recompute like any other.
 """
 
 from __future__ import annotations
@@ -38,14 +51,14 @@ class HybridCache(ExpertCounts, BlockPool):
     supports_prefix = False
 
     def __init__(self, *, conv_layers: int, conv_width: int,
-                 conv_slots: int, **pool_kwargs):
+                 conv_slots: int, conv_taps: int = 2, **pool_kwargs):
         if conv_slots < 1:
             raise ValueError("conv_slots must be >= 1 (slot 0 is reserved)")
         self.conv_slots = int(conv_slots)
         # before the pool registers its stats: per_shard_bytes reads it
         self.conv = jnp.zeros(
-            (int(conv_layers), self.conv_slots + 1, 2, int(conv_width)),
-            pool_kwargs.get("dtype", jnp.float32))
+            (int(conv_layers), self.conv_slots + 1, int(conv_taps),
+             int(conv_width)), pool_kwargs.get("dtype", jnp.float32))
         self._free_slots: list[int] = list(range(self.conv_slots, 0, -1))
         self._slot_of: dict[int, int] = {}
         super().__init__(**pool_kwargs)
@@ -135,3 +148,48 @@ class HybridCache(ExpertCounts, BlockPool):
                 "a conv slot is held twice or held and free")
             assert sorted(held + free) == list(range(1, self.conv_slots + 1)), (
                 "held and free conv slots do not partition the arena")
+
+
+class StateCache(HybridCache):
+    """A latent pool, and one slot a sequence in two arenas: the carried
+    conv inputs and the matrix states of the delta-rule layers."""
+
+    cache_kind = "latent_state"
+    value_pool = False
+
+    def __init__(self, *, state_heads: int, state_dk: int, state_dv: int,
+                 conv_layers: int, conv_slots: int, **kwargs):
+        # before the pool registers its stats: per_shard_bytes reads it
+        self.state = jnp.zeros(
+            (int(conv_layers), int(conv_slots) + 1, int(state_heads),
+             int(state_dk), int(state_dv)), jnp.float32)
+        super().__init__(conv_layers=conv_layers, conv_slots=conv_slots,
+                         **kwargs)
+        wref = weakref.ref(self)
+        self.stats.state_slots_total = self.conv_slots
+        self.stats._state_slots_in_use_fn = lambda: (
+            0 if wref() is None else wref().slots_in_use)
+
+    @property
+    def state_bytes(self) -> int:
+        return int(self.state.size) * self.state.dtype.itemsize
+
+    @property
+    def per_shard_bytes(self) -> int:
+        return super().per_shard_bytes + self.state_bytes
+
+    def device_state(self) -> tuple:
+        return (self.k, self.conv, self.state)
+
+    def set_device_state(self, k, conv, state, counts) -> None:
+        self.k, self.conv, self.state = k, conv, state
+        self.keep_expert_counts(counts)
+
+    def after_sync(self) -> None:
+        """The programs' device counters: tokens per held expert, and last
+        the pairs routed to experts held elsewhere."""
+        pending, self._expert_counts = self._expert_counts, ()
+        for counts in pending:
+            counts = np.asarray(counts)
+            self.stats.record_moe(counts[:-1])
+            self.stats.record_pairs_elsewhere(int(counts[-1]))

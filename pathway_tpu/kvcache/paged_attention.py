@@ -822,6 +822,357 @@ _paged_write = jax.jit(_paged_write_fn, static_argnames=("interpret",),
                        donate_argnums=(2, 3))
 
 
+# -- the latent pool (PR 33): key and value are one stored row ---------------
+#
+# Multi-head latent attention in its absorbed form: a token leaves one row
+# in the cache, ``[c (kv_lora_rank) ; k_r (qk_rope_head_dim) ; 0]`` padded to
+# whole lane tiles (``W`` lanes: 512 + 64 + 64 of padding at the published
+# widths), every query head attends that one row (the key is the whole row,
+# the value the same row: the lanes past ``c`` come out as the scores' mix of
+# ``k_r`` and of zeros, and the caller drops them), and the per-head halves
+# of ``W_kv_b`` are applied to the queries before and to the mix after.  To
+# the kernels that is grouped-query attention with ONE K/V head of ``W``
+# lanes and all query heads folded on it as neighbouring columns
+# (:func:`_fold_queries`), on a pool that is one array: the kernels below
+# are the paged ones (:func:`_next_span`, :func:`_attend_span`: the same
+# span grid, gather and online softmax) with one pool operand, one span
+# buffer and one aliased output.  Their jitted functions are named
+# ``_paged_latent_fn`` / ``_paged_latent_append_fn`` /
+# ``_paged_latent_write_fn`` for the device trace.
+
+_LATENT_COLS = 64  # query columns a kernel row takes of a longer chunk
+
+
+def _latent_scratch(K: int, BS: int, W: int, pool_dtype, R: int, dtype):
+    return [
+        pltpu.VMEM((2, K * BS, W), pool_dtype),    # the span, and the next
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.SMEM((1,), jnp.int32),               # live steps so far
+        pltpu.VMEM((R, W), dtype),                 # qm: the folded queries
+        pltpu.VMEM((R, 128), jnp.float32),         # m
+        pltpu.VMEM((R, 128), jnp.float32),         # l
+        pltpu.VMEM((R, W), jnp.float32),           # acc
+    ]
+
+
+def _latent_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, c_in, o_ref, cbuf,
+                   sem, n_ref, qm_ref, m_ref, l_ref, acc_ref, *, K: int,
+                   block_size: int, scale: float, rep: int, **geom):
+    """:func:`_paged_kernel` on the latent pool: one pool, whose span is
+    key and value both."""
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, **geom)
+
+    c0 = c0_ref[b]
+    ctx = cl_ref[b]
+    jlast = (ctx - 1) // (K * block_size)
+
+    @pl.when(j <= jlast)
+    def _visible():
+        slot = _next_span(b, j, jlast, li_ref, bt_ref, c0_ref, cl_ref,
+                          (c_in,), (cbuf,), sem, n_ref, K=K,
+                          block_size=block_size)
+        _attend_span(j, c0, ctx, cbuf, cbuf, slot, qm_ref, m_ref, l_ref,
+                     acc_ref, scale=scale, rep=rep, **geom)
+
+    @pl.when(j == jlast)
+    def _final():
+        _write_out(o_ref, l_ref, acc_ref, **geom)
+
+
+def _paged_latent_fn(q, pool, layer, block_tables, c0, cl, *, scale: float,
+                     interpret: bool = False):
+    """q (B, C, H, W) the absorbed queries, ``W`` the pool's lanes; pool
+    (L, num_blocks, BS, W), all latent layers' rows, read in place at
+    ``layer`` ((1,) int32).  Returns (B, C, H, W): every head's mix of the
+    rows it sees, of which the caller keeps the lanes of ``c``."""
+    B = q.shape[0]
+    BS, W = pool.shape[2:]
+    NB = block_tables.shape[1]
+    K = span_blocks(BS, NB, W)
+    qf, rep = _fold_queries(q, W)
+    R = qf.shape[1]
+    kernel = functools.partial(_latent_kernel, K=K, block_size=BS,
+                               scale=scale, rep=rep, C=R, G=1, hd=W)
+    row = pl.BlockSpec((None, R, W), lambda b, j, *_: (b, 0, 0))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,  # layer, block_tables, c0, cl
+            grid=(B, -(-NB // K)),
+            in_specs=[row, _pool_spec(K, BS, W)],
+            out_specs=row,
+            scratch_shapes=_latent_scratch(K, BS, W, pool.dtype, R, q.dtype),
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, R, W), q.dtype),
+        interpret=interpret,
+        **_vmem_limit(K, BS, W, pool.dtype, 1, R, W, 1, q.dtype),
+    )(layer, block_tables, c0, cl, qf, pool)
+    return _unfold_queries(out, q.shape, rep)
+
+
+_paged_latent = jax.jit(_paged_latent_fn,
+                        static_argnames=("scale", "interpret"))
+
+
+def _latent_append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, q_ref,
+                          new_ref, c_in, o_ref, co_ref, cbuf, sem, n_ref,
+                          qm_ref, m_ref, l_ref, acc_ref, *, K: int,
+                          block_size: int, scale: float, rep: int, **geom):
+    """:func:`_append_kernel` on the latent pool: the token's new row is
+    patched into the tail block in VMEM, attended with the rest, and
+    flushed through the one aliased pool output."""
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, **geom)
+
+    c0 = c0_ref[b]
+    ctx = cl_ref[b]
+    jlast = (ctx - 1) // (K * block_size)
+
+    @pl.when(j <= jlast)
+    def _visible():
+        slot = _next_span(b, j, jlast, li_ref, bt_ref, c0_ref, cl_ref,
+                          (c_in,), (cbuf,), sem, n_ref, K=K,
+                          block_size=block_size)
+
+        @pl.when(j == jlast)
+        def _append():
+            tail = pl.ds(pl.multiple_of(
+                (ctx - 1) // block_size % K * block_size, block_size),
+                block_size)
+            new_row = jax.lax.broadcasted_iota(
+                jnp.int32, (block_size, 1), 0) == so_ref[b]
+            blk = jnp.where(new_row, new_ref[:], cbuf[slot, tail, :])
+            cbuf[slot, tail, :] = blk
+            co_ref[:] = blk.astype(co_ref.dtype)
+
+        _attend_span(j, c0, ctx, cbuf, cbuf, slot, qm_ref, m_ref, l_ref,
+                     acc_ref, scale=scale, rep=rep, **geom)
+
+    @pl.when(j == jlast)
+    def _final():
+        _write_out(o_ref, l_ref, acc_ref, **geom)
+
+
+def _paged_latent_append_fn(q, row_new, pool, layer, block_tables, c0, cl,
+                            slot_offsets, *, scale: float,
+                            interpret: bool = False):
+    """q (B, 1, H, W); row_new (B, W) the tokens' rows; pool
+    (L, num_blocks, BS, W) returned UPDATED at ``layer``, aliased in place:
+    one tail block a row is written.  The slot is the tail of the attended
+    context, as :func:`_paged_append_fn` requires."""
+    B = q.shape[0]
+    BS, W = pool.shape[2:]
+    NB = block_tables.shape[1]
+    K = span_blocks(BS, NB, W)
+    qf, rep = _fold_queries(q, W)
+    R = qf.shape[1]
+    kernel = functools.partial(_latent_append_kernel, K=K, block_size=BS,
+                               scale=scale, rep=rep, C=R, G=1, hd=W)
+
+    def _row(rows):
+        return pl.BlockSpec((None, rows, W), lambda b, j, *_: (b, 0, 0))
+
+    def _slot_map(b, j, li, bt, c0, cl, so):
+        return (li[0], bt[b, (cl[b] - 1) // BS], 0, 0)
+
+    # alias indices count the scalar-prefetch operands: the pool is operand
+    # 7 of (layer, bt, c0, cl, so, q, row_new, pool)
+    o, pool = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,  # layer, block_tables, c0, cl, offsets
+            grid=(B, -(-NB // K)),
+            in_specs=[_row(R), _row(1), _pool_spec(K, BS, W)],
+            out_specs=[_row(R),
+                       pl.BlockSpec((None, None, BS, W), _slot_map)],
+            scratch_shapes=_latent_scratch(K, BS, W, pool.dtype, R, q.dtype),
+        ),
+        out_shape=[jax.ShapeDtypeStruct((B, R, W), q.dtype),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        input_output_aliases={7: 1},
+        interpret=interpret,
+    )(layer, block_tables, c0, cl, slot_offsets, qf,
+      row_new.reshape(B, 1, W), pool)
+    return _unfold_queries(o, q.shape, rep), pool
+
+
+_paged_latent_append = jax.jit(
+    _paged_latent_append_fn, static_argnames=("scale", "interpret"),
+    donate_argnums=(2,))
+
+
+def _latent_write_kernel(li_ref, sb_ref, so_ref, new_ref, c_in, co_ref):
+    """:func:`_write_kernel` on one pool."""
+    t = pl.program_id(0)
+    first = (t == 0) | (sb_ref[t] != sb_ref[jnp.maximum(t - 1, 0)])
+    new_row = jax.lax.broadcasted_iota(
+        jnp.int32, (co_ref.shape[0], 1), 0) == so_ref[t]
+
+    @pl.when(first)
+    def _fresh():
+        co_ref[:] = jnp.where(new_row, new_ref[:], c_in[:])
+
+    @pl.when(jnp.logical_not(first))
+    def _resident():
+        co_ref[:] = jnp.where(new_row, new_ref[:], co_ref[:])
+
+
+def _paged_latent_write_fn(rows, pool, layer, slot_blocks, slot_offsets, *,
+                           interpret: bool = False):
+    """rows (T, W) in the pool's dtype; pool (L, num_blocks, BS, W)
+    returned UPDATED at ``layer``, whole blocks in place, as
+    :func:`_paged_write_fn` writes its two."""
+    T, W = rows.shape
+    BS = pool.shape[2]
+    row = pl.BlockSpec((None, 1, W), lambda t, *_: (t, 0, 0))
+    block = pl.BlockSpec((None, None, BS, W),
+                         lambda t, li, sb, so: (li[0], sb[t], 0, 0))
+    # the pool is operand 4 of (layer, sb, so, rows, pool)
+    return pl.pallas_call(
+        _latent_write_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # layer, slot_blocks, slot_offsets
+            grid=(T,),
+            in_specs=[row, block],
+            out_specs=block,
+        ),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={4: 0},
+        interpret=interpret,
+    )(layer, slot_blocks, slot_offsets, rows.reshape(T, 1, W), pool)
+
+
+_paged_latent_write = jax.jit(
+    _paged_latent_write_fn, static_argnames=("interpret",),
+    donate_argnums=(1,))
+
+
+def latent_attention_reference(q, pool, block_tables, context_lens=None, *,
+                               start_pos=None, n_valid=None, scale: float):
+    """Gather-based latent attention: q (B, C, H, W); pool (num_blocks, BS,
+    W), one layer's rows; the raggedness contract of
+    :func:`paged_attention_reference`.  Returns (B, C, H, W)."""
+    B, C = q.shape[:2]
+    _require_positive_context(C, context_lens, start_pos, n_valid)
+    NB, BS = block_tables.shape[1], pool.shape[1]
+    c0, cl_last = _query_context(C, context_lens, start_pos, n_valid)
+    ctx = jnp.minimum(c0[:, None] + jnp.arange(C)[None, :], cl_last[:, None])
+    rows = pool[block_tables].reshape(B, NB * BS, -1)
+    scores = jnp.einsum("bqhw,bkw->bhqk", q, rows) * scale
+    valid = (jnp.arange(NB * BS)[None, None, :] < ctx[:, :, None])[:, None]
+    scores = jnp.where(valid, scores, _NEG)
+    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkw->bqhw", probs, rows)
+
+
+def _latent_layer(pool, layer):
+    if layer is None:
+        return pool[None], jnp.zeros((1,), jnp.int32)
+    return pool, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def latent_write_rows(pool, slot_blocks, slot_offsets, rows, *, layer=None,
+                      use_pallas: bool | None = None,
+                      interpret: bool | None = None):
+    """``pool[layer, slot_blocks[t], slot_offsets[t]] = rows[t]``: how a
+    mixed step's new latent rows reach the pool (:func:`paged_write_rows`
+    for one pool).  rows (T, W)."""
+    rows = rows.astype(pool.dtype)
+    backend = jax.default_backend()
+    if use_pallas is None:
+        use_pallas = backend == "tpu"
+    if not use_pallas:
+        at = (slot_blocks, slot_offsets) if layer is None \
+            else (layer, slot_blocks, slot_offsets)
+        return pool.at[at].set(rows)
+    pp, li = _latent_layer(pool, layer)
+    pp = _paged_latent_write(
+        rows, pp, li, jnp.asarray(slot_blocks, jnp.int32),
+        jnp.asarray(slot_offsets, jnp.int32),
+        interpret=(backend != "tpu") if interpret is None else interpret)
+    return pp[0] if layer is None else pp
+
+
+def latent_append_attend(q, row_new, pool, block_tables, context_lens,
+                         slot_blocks, slot_offsets, *, scale: float,
+                         layer=None, use_pallas: bool | None = None,
+                         interpret: bool | None = None):
+    """Fused decode append+attend on the latent pool
+    (:func:`paged_append_attend` for one pool): q (B, 1, H, W), row_new
+    (B, W).  Returns ``(mix (B, 1, H, W), pool)``."""
+    backend = jax.default_backend()
+    if use_pallas is None:
+        use_pallas = backend == "tpu"
+    if not use_pallas:
+        pool = latent_write_rows(pool, slot_blocks, slot_offsets, row_new,
+                                 layer=layer, use_pallas=False)
+        a = latent_attention_reference(
+            q, _layer_of(pool, layer), block_tables, context_lens,
+            scale=scale)
+        return a, pool
+    _require_positive_context(1, context_lens, None, None)
+    c0, cl_last = _query_context(1, context_lens, None, None)
+    pp, li = _latent_layer(pool, layer)
+    a, pp = _paged_latent_append(
+        q, row_new.astype(pool.dtype), pp, li,
+        jnp.asarray(block_tables, jnp.int32), c0.astype(jnp.int32),
+        cl_last.astype(jnp.int32), jnp.asarray(slot_offsets, jnp.int32),
+        scale=float(scale),
+        interpret=(backend != "tpu") if interpret is None else interpret)
+    return a, (pp[0] if layer is None else pp)
+
+
+def latent_attention(q, pool, block_tables, context_lens=None, *,
+                     start_pos=None, n_valid=None, scale: float, layer=None,
+                     use_pallas: bool | None = None,
+                     interpret: bool | None = None):
+    """Ragged latent attention (:func:`paged_attention` for the latent
+    pool).  A chunk's rows are handed to the kernel in pieces of
+    :data:`_LATENT_COLS` query columns, each a kernel row of its own over
+    the same table (all heads of a column are folded on the one stored row:
+    32 heads x 256 columns would be 8,192 query rows in VMEM); a piece past
+    a row's valid columns attends one key and is dropped."""
+    backend = jax.default_backend()
+    if use_pallas is None:
+        use_pallas = backend == "tpu"
+    if not use_pallas:
+        return latent_attention_reference(
+            q, _layer_of(pool, layer), block_tables, context_lens,
+            start_pos=start_pos, n_valid=n_valid, scale=scale)
+    B, C, H, W = q.shape
+    _require_positive_context(C, context_lens, start_pos, n_valid)
+    c0, cl = _query_context(C, context_lens, start_pos, n_valid)
+    tables = jnp.asarray(block_tables, jnp.int32)
+    n = C // _LATENT_COLS if C > _LATENT_COLS and C % _LATENT_COLS == 0 else 1
+    if n > 1:
+        # piece i of a row: its columns from i * _LATENT_COLS on; one past
+        # the row's valid columns attends one key
+        first = c0[:, None] \
+            + jnp.arange(n, dtype=jnp.int32)[None, :] * _LATENT_COLS
+        live = first <= cl[:, None]
+        c0 = jnp.where(live, first, 1).reshape(B * n)
+        cl = jnp.where(live, jnp.minimum(cl[:, None],
+                                         first + _LATENT_COLS - 1),
+                       1).reshape(B * n)
+        q = q.reshape(B * n, _LATENT_COLS, H, W)
+        tables = jnp.repeat(tables, n, axis=0)
+    pp, li = _latent_layer(pool, layer)
+    out = _paged_latent(
+        q, pp, li, tables, c0.astype(jnp.int32), cl.astype(jnp.int32),
+        scale=float(scale),
+        interpret=(backend != "tpu") if interpret is None else interpret)
+    return out.reshape(B, C, H, W)
+
+
 def _stacked(k_pool, v_pool, layer):
     """Resolve the two pool conventions to (stacked pools, (1,) int32
     layer): ``layer=None`` means one layer's (num_blocks, BS, H*hd)

@@ -26,7 +26,8 @@ and gives the engine:
   whether an unasked ``tp`` may take every local chip.
 
 A family is the only place that names a model's programs: the engine
-imports none of models/decoder.py, models/lfm2.py, models/afmoe.py.  The function
+imports none of models/decoder.py, models/lfm2.py, models/afmoe.py,
+models/kimi_linear.py.  The function
 names ``_step_fn`` / ``_mixed_fn`` / ``_chained_fn`` are the
 device trace's (``jit__mixed_fn`` on ``XLA Modules``): the benchmark's
 readers find the programs by them, for every family alike.
@@ -39,6 +40,15 @@ import jax.numpy as jnp
 
 def _ids(logits):
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def _refuse(family: str, asked: tuple) -> None:
+    """``asked``: (what is missing and why, whether it was asked for)
+    pairs; one ``ValueError`` that names the family and every one asked."""
+    missing = [what for what, wanted in asked if wanted]
+    if missing:
+        raise ValueError(f"the {family} block family does not support "
+                         + "; ".join(missing))
 
 
 class DecoderFamily:
@@ -161,7 +171,7 @@ class Lfm2Family:
 
     @staticmethod
     def unsupported(*, tp, quantize, speculative, session_store) -> None:
-        missing = [what for what, asked in (
+        _refuse("lfm2", (
             ("tensor parallelism (tp > 1): the conv arena and the expert "
              "weights have no sharded layout", tp is not None and tp > 1),
             (f"quantize={quantize!r}: no quantized plan of the expert "
@@ -170,11 +180,7 @@ class Lfm2Family:
              "the conv state back", speculative not in (None, False)),
             ("host tiering (session_store): a resumed block skips the "
              "tokens that build the conv state", session_store is not None),
-        ) if asked]
-        if missing:
-            raise ValueError(
-                "the lfm2 block family does not support "
-                + "; ".join(missing))
+        ))
 
     @staticmethod
     def programs(cfg, attn: str, mesh, sampled: bool = False) -> dict:
@@ -238,7 +244,7 @@ class AfmoeFamily:
 
     @staticmethod
     def unsupported(*, tp, quantize, speculative, session_store) -> None:
-        missing = [what for what, asked in (
+        _refuse("afmoe", (
             ("tensor parallelism (tp > 1): the window pool and the expert "
              "weights have no sharded layout", tp is not None and tp > 1),
             (f"quantize={quantize!r}: no quantized plan of the expert "
@@ -248,11 +254,7 @@ class AfmoeFamily:
             ("host tiering (session_store): a suspended sequence has lost "
              "the window layers' keys behind its window",
              session_store is not None),
-        ) if asked]
-        if missing:
-            raise ValueError(
-                "the afmoe block family does not support "
-                + "; ".join(missing))
+        ))
 
     @staticmethod
     def programs(cfg, attn: str, mesh, sampled: bool = False) -> dict:
@@ -288,7 +290,88 @@ class AfmoeFamily:
                 "chained": (_chained_fn, donated)}
 
 
-_FAMILIES = {f.name: f for f in (DecoderFamily, Lfm2Family, AfmoeFamily)}
+class KimiLinearFamily:
+    """models/kimi_linear.py: Kimi Delta Attention and latent attention
+    layers, SwiGLU and routed experts (all of them, or the share a chip of
+    an expert-parallel deployment holds) beside a shared one, on the
+    latent-and-state cache.  Greedy on one device.  Beyond what
+    :meth:`unsupported` refuses, the cache kind runs without a prefix cache
+    and without fork whatever was asked for (a shared block would skip the
+    tokens that build the matrix state), and a sampled request fails alone
+    (``greedy_only``)."""
+
+    name = "kimi_linear"
+    cache_kind = "latent_state"
+    greedy_only = True
+    tensor_parallel = False
+
+    @staticmethod
+    def plan(cfg, params, *, tp: int, quantize):
+        from .kimi_linear import plan_params
+
+        return plan_params(cfg, params)
+
+    @staticmethod
+    def cache_kwargs(cfg, max_batch_size: int, round_tokens: int) -> dict:
+        return {"n_layers": len(cfg.mla_layers), "n_heads": 1,
+                "head_dim": cfg.latent_lanes,
+                "conv_layers": len(cfg.kda_layers),
+                "conv_width": 3 * cfg.kda_width,
+                "conv_taps": cfg.conv_kernel - 1,
+                "conv_slots": max_batch_size, "state_heads": cfg.n_heads,
+                "state_dk": cfg.kda_head_dim, "state_dv": cfg.kda_head_dim}
+
+    @staticmethod
+    def unsupported(*, tp, quantize, speculative, session_store) -> None:
+        _refuse("kimi_linear", (
+            ("tensor parallelism (tp > 1): the state arena, the latent pool "
+             "and the held experts have no sharded layout and no exchange",
+             tp is not None and tp > 1),
+            (f"quantize={quantize!r}: no quantized plan of the expert "
+             "weights", quantize is not None),
+            ("speculative drafting: a rejected draft would have to roll "
+             "the matrix state back", speculative not in (None, False)),
+            ("host tiering (session_store): a resumed block skips the "
+             "tokens that build the matrix state (it would take a snapshot "
+             "of the state at every block boundary, as prefix sharing and "
+             "fork would)", session_store is not None),
+        ))
+
+    @staticmethod
+    def programs(cfg, attn: str, mesh, sampled: bool = False) -> dict:
+        if sampled:
+            raise ValueError("the kimi_linear block family decodes greedily: "
+                             "it has no sampled step programs")
+        from . import kimi_linear as m
+
+        def _step_fn(p, pool, conv, state, token, positions, bt, sb, so,
+                     slots):
+            logits, *cache = m.state_decode_step(
+                p, cfg, pool, conv, state, token, positions, bt, sb, so,
+                slots, attn=attn)
+            return (m.greedy_ids(logits), *cache)
+
+        def _mixed_fn(p, pool, conv, state, tokens, positions, row_tables,
+                      row_start, row_nvalid, row_token_idx, tok_row, tok_col,
+                      sb, so, logit_idx, slots):
+            logits, *cache = m.state_mixed_step(
+                p, cfg, pool, conv, state, tokens, positions, row_tables,
+                row_start, row_nvalid, row_token_idx, tok_row, tok_col, sb,
+                so, logit_idx, slots, attn=attn)
+            return (m.greedy_ids(logits), *cache)
+
+        def _chained_fn(p, pool, conv, state, token, positions, bt, sb, so,
+                        slots):
+            return m.state_chained_decode(
+                p, cfg, pool, conv, state, token, positions, bt, sb, so,
+                slots, attn=attn)
+
+        return {"step": (_step_fn, (1, 2, 3)), "mixed": (_mixed_fn, (1, 2, 3)),
+                "chained": (_chained_fn, (1, 2, 3))}
+
+
+_FAMILIES = {f.name: f for f in (DecoderFamily, Lfm2Family, AfmoeFamily,
+                                 KimiLinearFamily)}
 
 
 def step_family(cfg):

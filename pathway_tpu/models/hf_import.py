@@ -259,6 +259,101 @@ def config_from_afmoe(hf_config, *, max_len: int | None = None,
     )
 
 
+def config_from_kimi_linear(hf_config, *, max_len: int | None = None,
+                            dtype="auto", router_experts: int | None = None,
+                            first_expert: int = 0):
+    """``kimi_linear`` config (moonshotai/Kimi-Linear-48B-A3B-Instruct's
+    ``config.json`` keys) ->
+    :class:`~pathway_tpu.models.kimi_linear.KimiLinearConfig`.
+    ``linear_attn_config`` (a mapping or an object) names the ``kda_layers``
+    and the ``full_attn_layers`` by their 1-based numbers, the KDA heads,
+    their size and the conv's taps; the layers up to ``num_hidden_layers``
+    are taken.  ``max_len`` caps the served context below
+    ``model_max_length`` (no positions anywhere: any cap is exact).
+
+    ``router_experts``: the router's published width where ``num_experts``
+    counts the experts this share HOLDS (one chip of an expert-parallel
+    deployment: experts ``first_expert .. first_expert + num_experts``).
+
+    What the keys can say and this family has not written down is refused:
+    a router activation other than sigmoid, a group limit on the router,
+    a low-rank query (``q_lora_rank``), rotary on the latent layers
+    (``mla_use_nope`` false), rotary scaling, a tied head, another
+    activation than SiLU, an expert layer in a part of the layers only
+    (``moe_layer_freq``), multi-token prediction layers."""
+    from .kimi_linear import KDA, MLA, KimiLinearConfig
+
+    def get(name, default=None):
+        return getattr(hf_config, name, default)
+
+    if get("model_type") != "kimi_linear":
+        raise ValueError(
+            f"expected a kimi_linear config, got model_type="
+            f"{get('model_type')!r}")
+    refused = [what for what, bad in (
+        ("moe_router_activation_func other than sigmoid",
+         get("moe_router_activation_func", "sigmoid") != "sigmoid"),
+        ("a group limit on the router (num_expert_group / topk_group > 1)",
+         max(get("num_expert_group", 1) or 1, get("topk_group", 1) or 1) > 1),
+        ("q_lora_rank", get("q_lora_rank") is not None),
+        ("rotary on the latent layers (mla_use_nope false)",
+         not get("mla_use_nope", True)),
+        ("rope_scaling", get("rope_scaling") is not None),
+        ("tie_word_embeddings", bool(get("tie_word_embeddings", False))),
+        ("hidden_act other than silu", get("hidden_act", "silu") != "silu"),
+        ("moe_layer_freq other than 1", get("moe_layer_freq", 1) != 1),
+        ("num_nextn_predict_layers", bool(get("num_nextn_predict_layers", 0))),
+        ("num_key_value_heads other than num_attention_heads",
+         get("num_key_value_heads", hf_config.num_attention_heads)
+         != hf_config.num_attention_heads),
+    ) if bad]
+    if refused:
+        raise ValueError(
+            "kimi_linear: not written down here: " + "; ".join(refused))
+    lin = hf_config.linear_attn_config
+    if not isinstance(lin, dict):
+        lin = vars(lin)
+    if lin["num_heads"] != hf_config.num_attention_heads:
+        raise ValueError(
+            "kimi_linear: KDA and latent layers with different head counts "
+            "are not written down here")
+    n_layers = int(hf_config.num_hidden_layers)
+    kinds = {int(i): KDA for i in lin["kda_layers"]}
+    kinds.update({int(i): MLA for i in lin["full_attn_layers"]})
+    missing = [i for i in range(1, n_layers + 1) if i not in kinds]
+    if missing:
+        raise ValueError(
+            f"linear_attn_config names no kind for layer(s) {missing}")
+    positions = int(get("model_max_length", 0)
+                    or get("max_position_embeddings"))
+    held = int(hf_config.num_experts)
+    return KimiLinearConfig(
+        vocab_size=hf_config.vocab_size,
+        d_model=hf_config.hidden_size,
+        n_heads=hf_config.num_attention_heads,
+        kda_head_dim=int(lin["head_dim"]),
+        conv_kernel=int(lin["short_conv_kernel_size"]),
+        kv_lora_rank=int(hf_config.kv_lora_rank),
+        qk_nope_head_dim=int(hf_config.qk_nope_head_dim),
+        qk_rope_head_dim=int(hf_config.qk_rope_head_dim),
+        v_head_dim=int(hf_config.v_head_dim),
+        d_ff=hf_config.intermediate_size,
+        d_ff_expert=hf_config.moe_intermediate_size,
+        n_experts=held if router_experts is None else int(router_experts),
+        n_held_experts=None if router_experts is None else held,
+        first_expert=int(first_expert),
+        top_k=hf_config.num_experts_per_token,
+        n_shared_experts=int(get("num_shared_experts", 1)),
+        n_dense_layers=int(get("first_k_dense_replace", 0)),
+        layer_types=tuple(kinds[i] for i in range(1, n_layers + 1)),
+        norm_eps=float(get("rms_norm_eps", 1e-5)),
+        max_len=min(positions, int(max_len)) if max_len else positions,
+        dtype=dtype,
+        route_norm=bool(get("moe_renormalize", True)),
+        route_scale=float(get("routed_scaling_factor", 1.0)),
+    )
+
+
 def params_from_lfm2_state_dict(state: dict[str, Any], cfg) -> dict:
     """Map a (torch) LFM2-family state dict onto
     :mod:`pathway_tpu.models.lfm2`'s parameter pytree, in ``cfg``'s dtype.

@@ -14,7 +14,10 @@ alternative named:
   over the attention layers and K/V heads only, with the conv slot arena
   beside it (``conv_bytes``); for a family with sliding-window layers over
   its full-attention layers only, with the window layers' pool, sized by
-  the batch and the window, beside it (``window_bytes``);
+  the batch and the window, beside it (``window_bytes``); for a family with
+  latent-attention and delta-rule layers the latent pool (one array, in the
+  latent layers only), with the conv inputs (``conv_bytes``) and the matrix
+  states (``state_bytes``, f32) of the slot arena beside it;
 - **step temps**: the transient working set of the largest step
   program.  When the program registry (obs/profiler.py) already holds
   a MEASURED ``memory_analysis()`` temp watermark for the engine's
@@ -104,6 +107,11 @@ def kv_pool_bytes(cfg, *, num_blocks: int, block_size: int, tp: int,
                   itemsize: int) -> int:
     """K + V bytes held by EACH shard — BlockPool.per_shard_bytes
     computed from the configuration before the pool exists."""
+    if hasattr(cfg, "latent_lanes"):
+        # a latent pool (kvcache/hybrid.py StateCache): one array, a stored
+        # row key and value both, in the latent-attention layers only
+        return len(cfg.mla_layers) * num_blocks * block_size \
+            * cfg.latent_lanes * itemsize
     layers, kv_heads, hd = _kv_geometry(cfg)
     heads = max(kv_heads // max(tp, 1), 1)
     return 2 * layers * num_blocks * block_size * heads * hd * itemsize
@@ -126,9 +134,26 @@ def conv_arena_bytes(cfg, *, max_batch_size: int, itemsize: int) -> int:
     """The conv slot arena of a hybrid family (kvcache/hybrid.py): two
     carried vectors a conv layer for every batch row and the null slot.
     0 for a family without conv layers."""
+    if hasattr(cfg, "kda_layers"):
+        # the carried inputs of the three short convolutions of a
+        # delta-rule layer (kvcache/hybrid.py StateCache)
+        return len(cfg.kda_layers) * (max_batch_size + 1) \
+            * (cfg.conv_kernel - 1) * 3 * cfg.kda_width * itemsize
     conv_layers = getattr(cfg, "conv_layers", ())
     return len(conv_layers) * (max_batch_size + 1) * 2 * cfg.d_model \
         * itemsize
+
+
+def state_arena_bytes(cfg, *, max_batch_size: int) -> int:
+    """The matrix-state arena of a family with delta-rule layers
+    (kvcache/hybrid.py StateCache): ``heads x dk x dv`` in f32 a layer for
+    every batch row and the null slot.  0 for a family without such
+    layers."""
+    layers = len(getattr(cfg, "kda_layers", ()))
+    if not layers:
+        return 0
+    return layers * (max_batch_size + 1) * cfg.n_heads \
+        * cfg.kda_head_dim * cfg.kda_head_dim * 4
 
 
 def window_pool_bytes(cfg, *, max_batch_size: int, block_size: int,
@@ -159,7 +184,9 @@ def _temp_bytes(cfg, *, num_blocks: int, block_size: int,
     C = max(prefill_chunk, 1)
     T = B + C
     d = cfg.d_model
-    hd = getattr(cfg, "head_dim", d // cfg.n_heads)
+    # a latent family folds every head on a stored row of latent_lanes
+    hd = getattr(cfg, "latent_lanes", None) \
+        or getattr(cfg, "head_dim", d // cfg.n_heads)
     heads = max(cfg.n_heads // max(tp, 1), 1)
     vocab = cfg.vocab_size // max(tp, 1)
     # a sequence's table can span at most the pool (minus the null block)
@@ -177,6 +204,11 @@ def _temp_bytes(cfg, *, num_blocks: int, block_size: int,
     # the rows' queries gathered (B, C, H, hd) for the ragged kernel, its
     # output the same, each with a folded copy
     acts += 4 * B * C * heads * hd * itemsize
+    if hasattr(cfg, "kda_layers"):
+        # the chunked scan's work items: five operands and the output,
+        # every row wasting less than one item of kda_chunk tokens
+        items = T // cfg.kda_chunk + B
+        acts += 6 * items * cfg.kda_chunk * cfg.kda_width * 4
     if getattr(cfg, "n_experts", 0):
         # routed pairs laid out by expert in whole tiles of 16 rows: the
         # gathered inputs, the experts' hidden rows and their outputs
@@ -211,12 +243,15 @@ class HbmPlan:
     # a windowed family's pool of the sliding-window layers (0 elsewhere):
     # fixed by the batch and the window, not by num_blocks
     window_bytes: int = 0
+    # a delta-rule family's matrix-state arena (0 elsewhere): fixed by the
+    # batch, not by num_blocks
+    state_bytes: int = 0
     _replan: "object" = dataclasses.field(default=None, repr=False)
 
     @property
     def total_bytes(self) -> int:
         return self.params_bytes + self.kv_bytes + self.conv_bytes \
-            + self.window_bytes + self.temp_bytes
+            + self.window_bytes + self.state_bytes + self.temp_bytes
 
     @property
     def fits(self) -> bool:
@@ -257,7 +292,8 @@ class HbmPlan:
             return self.num_blocks
         per_block = max(self.per_block_bytes, 1)
         nb = (self.budget_bytes - self.params_bytes - self.conv_bytes
-              - self.window_bytes - self.temp_bytes) // per_block
+              - self.window_bytes - self.state_bytes
+              - self.temp_bytes) // per_block
         nb = min(int(nb), self.num_blocks)
         while nb >= 2 and not self.with_(num_blocks=nb).fits:
             nb -= max(nb // 8, 1)
@@ -302,7 +338,8 @@ class HbmPlan:
             f"{self.kv_bytes / mb:.1f}MB ({self.num_blocks} blocks x "
             f"{self.block_size} tokens, tp={self.tp}) + conv arena "
             f"{self.conv_bytes / mb:.1f}MB + window pool "
-            f"{self.window_bytes / mb:.1f}MB + step temps "
+            f"{self.window_bytes / mb:.1f}MB + state arena "
+            f"{self.state_bytes / mb:.1f}MB + step temps "
             f"{self.temp_bytes / mb:.1f}MB ({self.temp_source}) = "
             f"{self.total_bytes / mb:.1f}MB > HBM budget "
             f"{self.budget_bytes / mb:.1f}MB ({self.budget_source}); "
@@ -315,6 +352,7 @@ class HbmPlan:
             "kv_bytes": self.kv_bytes,
             "conv_bytes": self.conv_bytes,
             "window_bytes": self.window_bytes,
+            "state_bytes": self.state_bytes,
             "temp_bytes": self.temp_bytes,
             "temp_source": self.temp_source,
             "total_bytes": self.total_bytes,
@@ -401,6 +439,8 @@ def hbm_plan(cfg, *, num_blocks: int, block_size: int,
                 round_tokens=max(-(-pchunk // int(block_size))
                                  * int(block_size), int(chain_steps)),
                 itemsize=itemsize),
+            state_bytes=state_arena_bytes(
+                cfg, max_batch_size=int(max_batch_size)),
         )
         plan._replan = _build
         return plan

@@ -210,22 +210,42 @@ def grouped_matmul(x, w1, w3, w2, tile_expert, n_live, *, tm: int = TM,
 
 def expert_ffn(h, layer: dict, valid, *, top_k: int, norm_topk: bool = True,
                scale: float = 1.0, renorm_eps: float = 1e-6, h_route=None,
-               use_pallas: bool | None = None):
+               use_pallas: bool | None = None, first_expert=None):
     """One expert layer over a packed stream.  h (T, D) normed input in
     the experts' dtype, ``h_route`` the same before it was rounded to that
     dtype (f32; default h: the router then sees what the experts see);
     ``layer``: ``wg`` (D, E), ``expert_bias`` (E,) or absent, ``w1`` /
     ``w3`` (E, D, F), ``w2`` (E, F, D); valid (T,) bool.  Returns
-    ``(sum_e w_e expert_e(h) (T, D), counts (E,) int32)``."""
+    ``(sum_e w_e expert_e(h) (T, D), counts (E,) int32)``.
+
+    ``first_expert`` (an int): the layer is one share of an expert-parallel
+    deployment and holds the experts ``first_expert .. first_expert + held``
+    only, ``held`` the leading axis of ``w1``.  The router keeps its ``E``
+    outputs and its ``top_k``; a pair routed to an expert held elsewhere
+    takes no row, touches no expert and adds nothing (its part of the sum
+    is the other shares', which no code here stands in for).  Returns
+    ``(the held experts' part (T, D), counts (held,) int32, pairs routed
+    elsewhere () int32)``."""
     E = layer["wg"].shape[1]
     experts, weights, _s = route(h if h_route is None else h_route,
                                  layer["wg"], layer.get("expert_bias"),
                                  top_k=top_k, norm_topk=norm_topk,
                                  scale=scale, renorm_eps=renorm_eps)
-    g = group_rows(experts, valid, E)
+    held = E
+    if first_expert is not None:
+        held = layer["w1"].shape[0]
+        local = experts - first_expert
+        mine = (local >= 0) & (local < held)
+        experts = jnp.where(mine, local, held)  # held: no expert's group
+    g = group_rows(experts, valid, held)
     y = grouped_matmul(h[g["row_token"]], layer["w1"], layer["w3"],
                        layer["w2"], g["tile_expert"], g["n_live"],
                        use_pallas=use_pallas)
-    pairs = jnp.where(valid[:, None, None], y[g["pair_row"]], 0)
+    here = valid[:, None, None] if first_expert is None \
+        else (valid[:, None] & mine)[:, :, None]
+    pairs = jnp.where(here, y[g["pair_row"]], 0)
     out = jnp.sum(pairs.astype(jnp.float32) * weights[:, :, None], axis=1)
-    return out.astype(h.dtype), g["counts"]
+    if first_expert is None:
+        return out.astype(h.dtype), g["counts"]
+    elsewhere = jnp.sum(valid[:, None] & ~mine).astype(jnp.int32)
+    return out.astype(h.dtype), g["counts"], elsewhere

@@ -186,6 +186,12 @@ class KVCacheStats:
       dispatches)
     - ``pathway_kv_conv_slots_in_use{pool}`` / ``..._total{pool}`` gauges
       (hybrid caches: sequences holding a conv slot, and the arena's size)
+    - ``pathway_kv_state_slots_in_use{pool}`` / ``..._total{pool}`` gauges
+      (state caches: sequences holding a matrix-state slot, and the arena's
+      size), ``pathway_kv_kda_state_resets_total{pool}`` (first chunks that
+      started a slot's state from zero) and
+      ``pathway_kv_moe_pairs_elsewhere_total{pool}`` ((token, expert) pairs
+      the router sent to experts another share holds) counters
     - ``pathway_kv_window_blocks_in_use{pool}`` / ``..._total{pool}`` gauges
       (windowed caches: blocks of the sliding-window layers' pool held, and
       its size), ``pathway_kv_window_blocks_allocated_total{pool}`` /
@@ -267,6 +273,13 @@ class KVCacheStats:
         self.moe_routed_pairs = 0
         self.moe_tokens_per_expert: list[int] = []
         self.moe_fullest_expert_tokens = 0  # sum over programs of the max
+        # state caches (kvcache/hybrid.py StateCache): the matrix-state
+        # slots beside the conv ones, the first chunks that started a state
+        # from zero, and the pairs routed to experts held elsewhere
+        self.state_slots_total = 0
+        self._state_slots_in_use_fn = None
+        self.kda_state_resets = 0
+        self.moe_pairs_elsewhere = 0
         # windowed caches (kvcache/windowed.py): the sliding-window layers'
         # pool, and the keys those layers attend a layer
         self.window_blocks_total = 0
@@ -280,6 +293,19 @@ class KVCacheStats:
     def conv_slots_in_use(self) -> int:
         fn = self._conv_slots_in_use_fn
         return int(fn()) if fn is not None else 0
+
+    @property
+    def state_slots_in_use(self) -> int:
+        fn = self._state_slots_in_use_fn
+        return int(fn()) if fn is not None else 0
+
+    def record_state_resets(self, n: int) -> None:
+        with self._lock:
+            self.kda_state_resets += n
+
+    def record_pairs_elsewhere(self, n: int) -> None:
+        with self._lock:
+            self.moe_pairs_elsewhere += n
 
     @property
     def window_blocks_in_use(self) -> int:
@@ -523,6 +549,10 @@ class KVCacheStats:
                 "moe_routed_pairs": self.moe_routed_pairs,
                 "moe_tokens_per_expert": list(self.moe_tokens_per_expert),
                 "moe_fullest_expert_tokens": self.moe_fullest_expert_tokens,
+                "state_slots_in_use": self.state_slots_in_use,
+                "state_slots_total": self.state_slots_total,
+                "kda_state_resets": self.kda_state_resets,
+                "moe_pairs_elsewhere": self.moe_pairs_elsewhere,
                 "window_blocks_in_use": self.window_blocks_in_use,
                 "window_blocks_total": self.window_blocks_total,
                 "kv_window_blocks_allocated": self.kv_window_blocks_allocated,
@@ -971,6 +1001,10 @@ def _render_kv_lines() -> list[str]:
         "# TYPE pathway_kv_conv_slots_total gauge",
         "# TYPE pathway_kv_moe_routed_pairs_total counter",
         "# TYPE pathway_kv_moe_tokens_per_expert_total counter",
+        "# TYPE pathway_kv_state_slots_in_use gauge",
+        "# TYPE pathway_kv_state_slots_total gauge",
+        "# TYPE pathway_kv_kda_state_resets_total counter",
+        "# TYPE pathway_kv_moe_pairs_elsewhere_total counter",
         "# TYPE pathway_kv_window_blocks_in_use gauge",
         "# TYPE pathway_kv_window_blocks_total gauge",
         "# TYPE pathway_kv_window_blocks_allocated_total counter",
@@ -1149,6 +1183,11 @@ def _render_kv_lines() -> list[str]:
                          f"{snap['conv_slots_in_use']}")
             lines.append(f"pathway_kv_conv_slots_total{{{lbl}}} "
                          f"{snap['conv_slots_total']}")
+        if snap["state_slots_total"]:  # a state cache
+            for key in ("state_slots_in_use", "state_slots_total"):
+                lines.append(f"pathway_kv_{key}{{{lbl}}} {snap[key]}")
+            for key in ("kda_state_resets", "moe_pairs_elsewhere"):
+                lines.append(f"pathway_kv_{key}_total{{{lbl}}} {snap[key]}")
         if snap["window_blocks_total"]:  # a windowed cache
             for key in ("window_blocks_in_use", "window_blocks_total"):
                 lines.append(f"pathway_kv_{key}{{{lbl}}} {snap[key]}")

@@ -1,12 +1,15 @@
 """The engine<->cache contract (kvcache/backend.py) and the family seam
-(models/families.py), over the three kinds of cache that serve cells:
+(models/families.py), over the four kinds of cache that serve cells:
 
 - ``paged``: BlockPool, K/V blocks for every layer;
 - ``hybrid``: HybridCache, K/V blocks for the attention layers and a conv
   slot a sequence beside them;
 - ``windowed``: WindowedCache, K/V blocks for the full-attention layers
   and a second pool and table for the sliding-window layers, whose blocks
-  are freed behind the window.
+  are freed behind the window;
+- ``latent_state``: StateCache, a latent pool of one array (a stored row is
+  key and value both) and, under one slot a sequence, the conv inputs and
+  the f32 matrix states of the delta-rule layers.
 
 Each contract test runs over every kind through ``make_backend``, the seam
 the engine builds (and, on a supervised restart, rebuilds) its cache
@@ -36,11 +39,15 @@ from pathway_tpu.models.decoder import DecoderConfig, init_decoder_params
 from pathway_tpu.models.families import step_family
 from pathway_tpu.serve import metrics as serve_metrics
 
-KINDS = ("paged", "hybrid", "windowed")
+KINDS = ("paged", "hybrid", "windowed", "latent_state")
+PAGED_KERNEL_KINDS = KINDS[:3]  # the kinds whose kernels are _paged_*_fn
+SLOTTED = ("hybrid", "latent_state")
 _GEOM = dict(num_blocks=24, block_size=4, n_layers=2, n_heads=2, head_dim=8)
 _CONV = dict(conv_layers=3, conv_width=16, conv_slots=5)
 _WINDOW = dict(window=10, window_layers=3, round_tokens=6, max_seqs=5)
-_EXTRA = {"paged": {}, "hybrid": _CONV, "windowed": _WINDOW}
+_STATE = dict(_CONV, conv_taps=3, state_heads=2, state_dk=8, state_dv=8)
+_EXTRA = {"paged": {}, "hybrid": _CONV, "windowed": _WINDOW,
+          "latent_state": _STATE}
 
 _CFG = DecoderConfig(
     vocab_size=64, d_model=64, n_layers=2, n_heads=8, d_ff=128, max_len=128
@@ -84,9 +91,25 @@ def afmoe():
 
 
 @pytest.fixture(scope="module")
-def lfm2(lfm2_only, afmoe):
-    """The two families that bring a cache of their own, by its kind."""
-    return {"hybrid": lfm2_only, "windowed": afmoe}
+def kimi():
+    from pathway_tpu.models.kimi_linear import (KimiLinearConfig,
+                                                init_kimi_linear_params)
+
+    k, m = "kda", "mla"
+    cfg = KimiLinearConfig(vocab_size=257, d_model=64, n_heads=4,
+                           kda_head_dim=16, kv_lora_rank=32,
+                           qk_nope_head_dim=16, qk_rope_head_dim=8,
+                           v_head_dim=16, d_ff=96, d_ff_expert=32,
+                           n_experts=8, top_k=2, n_dense_layers=1,
+                           layer_types=(k, m, k), max_len=256,
+                           dtype=jnp.float32, kda_chunk=8)
+    return cfg, init_kimi_linear_params(cfg, jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module")
+def lfm2(lfm2_only, afmoe, kimi):
+    """The three families that bring a cache of their own, by its kind."""
+    return {"hybrid": lfm2_only, "windowed": afmoe, "latent_state": kimi}
 
 
 def _engine(kind, params, lfm2, name, **kw):
@@ -106,8 +129,9 @@ def _nbytes(arrays) -> int:
 
 def _cache_arrays(kind) -> int:
     """K and V pools, and the hybrid kind's conv arena or the windowed
-    kind's second pool pair."""
-    return {"paged": 2, "hybrid": 3, "windowed": 4}[kind]
+    kind's second pool pair; the latent pool (one array) and the state
+    kind's two arenas."""
+    return {"paged": 2, "hybrid": 3, "windowed": 4, "latent_state": 3}[kind]
 
 
 # -- the contract, over both kinds ---------------------------------------------
@@ -167,7 +191,7 @@ def test_lifecycle_fuzz_holds_the_invariants_after_every_operation(kind):
         pool.free_sequence(sid)
     pool.check_invariants()
     assert pool.num_free == pool.num_blocks - 1
-    if kind == "hybrid":
+    if kind in SLOTTED:
         assert pool.slots_in_use == 0
     if kind == "windowed":
         assert pool.window_blocks_in_use == 0
@@ -208,7 +232,9 @@ def test_per_shard_bytes_are_the_bytes_of_the_device_state(kind):
     pool = _make(kind, f"t_cb_bytes_{kind}", dtype=jnp.bfloat16)
     state = pool.device_state()
     assert len(state) == _cache_arrays(kind)
-    assert all(a.dtype == jnp.bfloat16 for a in state)
+    # the matrix states are f32 whatever the cache's dtype
+    assert [a.dtype for a in state] == [jnp.bfloat16] * (len(state) - 1) + [
+        jnp.float32 if kind == "latent_state" else jnp.bfloat16]
     assert pool.per_shard_bytes == _nbytes(state)
     # what /metrics reports a shard to hold
     assert pool.stats.shard_hbm_bytes == pool.per_shard_bytes
@@ -263,8 +289,10 @@ def test_retire_hands_the_name_and_its_gauges_to_the_next_cache(kind):
     first.allocate(2, 9)
     lines = serve_metrics.render_prometheus_lines()
     assert _gauge(lines, "pathway_kv_blocks_in_use", name) == [3.0]
-    if kind == "hybrid":
+    if kind in SLOTTED:
         assert _gauge(lines, "pathway_kv_conv_slots_in_use", name) == [1.0]
+    if kind == "latent_state":
+        assert _gauge(lines, "pathway_kv_state_slots_in_use", name) == [1.0]
     if kind == "windowed":
         first.reserve_chunk(2, 9)
         lines = serve_metrics.render_prometheus_lines()
@@ -278,9 +306,12 @@ def test_retire_hands_the_name_and_its_gauges_to_the_next_cache(kind):
     lines = serve_metrics.render_prometheus_lines()
     assert _gauge(lines, "pathway_kv_blocks_in_use", name) == [0.0]
     assert _gauge(lines, "pathway_kv_preemptions_total", name) == [1.0]
-    if kind == "hybrid":
+    if kind in SLOTTED:
         assert _gauge(lines, "pathway_kv_conv_slots_in_use", name) == [0.0]
         assert _gauge(lines, "pathway_kv_conv_slots_total", name) \
+            == [float(_CONV["conv_slots"])]
+    if kind == "latent_state":
+        assert _gauge(lines, "pathway_kv_state_slots_total", name) \
             == [float(_CONV["conv_slots"])]
     if kind == "windowed":
         assert _gauge(lines, "pathway_kv_window_blocks_in_use", name) == [0.0]
@@ -454,7 +485,7 @@ def _lowered_program(eng, attr: str, n_new: int) -> str:
     return texts[0]
 
 
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", PAGED_KERNEL_KINDS)
 def test_a_chained_program_names_itself_and_its_kernel(kind, params, lfm2):
     """The chained program behind its packed operand: the module is still
     ``jit__chained_fn`` (what ``decode_step_ms`` and the MFUs' decode
@@ -469,7 +500,7 @@ def test_a_chained_program_names_itself_and_its_kernel(kind, params, lfm2):
     assert {re.sub(r"_\d+$", "", f) for f in found} == {"_paged_append_fn"}
 
 
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", PAGED_KERNEL_KINDS)
 def test_a_mixed_program_names_its_paged_kernels(kind, params, lfm2):
     """The device trace names a kernel's events by the jitted function
     that holds the call: ``paged_attn_roofline`` /

@@ -616,3 +616,160 @@ def test_packed_step_programs_lower_under_their_names(shape, monkeypatch,
         assert layouts[i].layout.major_to_minor == (0, 1, 2, 3), layouts[i]
     for pool in {s.shape for s in state}:
         assert _pool_copies(compiled, pool) == []
+
+
+# -- the kimi_linear block family (Kimi-Linear-48B-A3B's published widths) ----
+
+
+def _kimi_arena(shape, layers=10, slots=17):
+    import jax.numpy as jnp
+
+    return shape((layers, slots, 32, 128, 128), jnp.float32)
+
+
+def test_kda_kernels_compile_at_the_published_widths(shape):
+    """The chunk kernel (20 work items of 128 tokens: a mixed step of 528
+    tokens in 16 rows; 32 heads of 128, eight a grid step; products in f32
+    at the highest precision, one with its left operand transposed) and the
+    step kernel (16 rows, a slot of 32 x 128 x 128 f32 a grid step), the
+    arena aliased in place: nothing arena-sized is a temporary."""
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.ops import kda
+
+    bf, i32 = jnp.bfloat16, jnp.int32
+    NW, n, W = kda.n_items(528, 16), kda.CHUNK, 32 * 128
+    assert (NW, n) == (20, 128)
+    tok = shape((NW, n, W), bf)
+    chunk = _compiled_kernel(
+        kda._kda_chunk_fn, tok, tok, tok, tok, shape((NW, n, W), jnp.float32),
+        _kimi_arena(shape), shape((1,), i32), shape((NW,), i32),
+        shape((NW,), i32), donate_argnums=(5,))
+    col = shape((16, 128, 32), jnp.float32)
+    step = _compiled_kernel(
+        kda._kda_step_fn, col, col, col, col, shape((16, 32, 128), bf),
+        _kimi_arena(shape), shape((1,), i32), shape((16,), i32),
+        shape((16,), i32), donate_argnums=(5,))
+    for compiled in (chunk, step):
+        assert compiled.as_text().count("tpu_custom_call") == 1
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_latent_kernels_compile_at_the_published_widths(shape):
+    """The latent pool's three kernels at 640 lanes a stored row (576 and
+    64 of padding), 32 query heads folded on it: the ragged kernel on pieces
+    of 64 query columns (2,048 folded rows, as Trinity's eight heads at a
+    chunk of 256; 128 pieces at the cell's chunk of 512), the fused append
+    at one column, the writer; the pool is
+    one operand and is never copied."""
+    import jax.numpy as jnp
+
+    pa = _paged()
+    bf, i32 = jnp.bfloat16, jnp.int32
+    pool = shape((3, 8193, BS, 640), bf)
+    pieces = 16 * 512 // pa._LATENT_COLS
+    import functools
+
+    scaled = functools.partial
+    ragged = _compiled_kernel(
+        scaled(pa._paged_latent_fn, scale=192 ** -0.5),
+        shape((pieces, pa._LATENT_COLS, 32, 640), bf),
+        pool, shape((1,), i32), shape((pieces, 512), i32),
+        shape((pieces,), i32), shape((pieces,), i32))
+    append = _compiled_kernel(
+        scaled(pa._paged_latent_append_fn, scale=192 ** -0.5),
+        shape((16, 1, 32, 640), bf),
+        shape((16, 640), bf), pool, shape((1,), i32), shape((16, 512), i32),
+        shape((16,), i32), shape((16,), i32), shape((16,), i32),
+        donate_argnums=(2,))
+    write = _compiled_kernel(
+        pa._paged_latent_write_fn, shape((528, 640), bf), pool,
+        shape((1,), i32), shape((528,), i32), shape((528,), i32),
+        donate_argnums=(1,))
+    for compiled in (ragged, append, write):
+        assert compiled.as_text().count("tpu_custom_call") == 1
+        assert _pool_copies(compiled, pool.shape) == []
+
+
+def _kimi_case(shape):
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.models import families, kimi_linear as m
+
+    fam = families.KimiLinearFamily
+    cfg = m.KimiLinearConfig(n_held_experts=64, max_len=8192,
+                             layer_types=(m.KDA, m.KDA, m.MLA),
+                             dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(lambda: fam.plan(
+        cfg, m.init_kimi_linear_params(cfg, jax.random.PRNGKey(0)), tp=1,
+        quantize=None))
+    params = jax.tree_util.tree_map(lambda s: shape(s.shape, s.dtype), shapes)
+    assert params["layers"][1]["w1"].shape == (64, 2304, 1024)
+    assert params["layers"][1]["wg"].shape == (2304, 256)
+    assert params["layers"][2]["w_kb"].shape == (32, 128, 512)
+    state = (shape((1, 8193, BS, cfg.latent_lanes), jnp.bfloat16),
+             shape((2, 17, 3, 3 * cfg.kda_width), jnp.bfloat16),
+             _kimi_arena(shape, layers=2))
+    return fam, cfg, params, state
+
+
+@pytest.mark.parametrize("program", ["mixed", "chained"])
+def test_kimi_packed_step_programs_lower_under_their_names(shape,
+                                                           monkeypatch,
+                                                           program):
+    """The family's mixed and chained programs as the engine jits them at
+    the published widths, three layers deep (kda dense, kda experts, latent
+    experts; 64 of 256 experts held): the module is ``jit__mixed_fn`` /
+    ``jit__chained_fn``, the kernels' functions carry the names the
+    benchmark's readers search the device trace for, every kernel is in the
+    compiled text (a mixed step: step and chunk kernels a KDA layer, writer
+    and attention a latent layer, two grouped matmuls an expert layer), the
+    three cache arrays are donated, the latent pool and the state arena
+    enter row-major and are not copied (the conv inputs, 1.3 MB a layer, are
+    XLA's to lay out)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.kvcache.packing import RoundLayout
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fam, cfg, params, state = _kimi_case(shape)
+    i32, rows, chunk, tables = jnp.int32, 16, 512, 512
+
+    def vec(*dims):
+        return shape(dims, i32)
+
+    T = rows + chunk
+    host = {"mixed": (vec(T), vec(T), vec(rows, tables), vec(rows),
+                      vec(rows), vec(rows, chunk), vec(T), vec(T), vec(T),
+                      vec(T), vec(rows), vec(rows)),
+            "chained": (vec(rows), vec(rows), vec(rows, tables),
+                        vec(rows, 16), vec(rows, 16), vec(rows))}[program]
+    fn, donated = fam.programs(cfg, "pallas", None)[program]
+    assert tuple(donated) == (1, 2, 3)
+    layout = RoundLayout(host)
+    lowered = jax.jit(layout.program(fn), donate_argnums=donated).lower(
+        params, *state, shape((layout.size,), i32))
+    text = lowered.as_text()
+    assert re.search(r"module @(\w+)", text).group(1) == f"jit__{program}_fn"
+    kernels = {re.sub(r"_\d+$", "", f) for f in re.findall(
+        r"func\.func \w+ @(_(?:paged|kda|moe)_\w+)\(", text)}
+    assert kernels == ({"_paged_latent_fn", "_paged_latent_write_fn",
+                        "_kda_chunk_fn", "_kda_step_fn", "_moe_gmm_fn"}
+                       if program == "mixed" else
+                       {"_paged_latent_append_fn", "_kda_step_fn",
+                        "_moe_gmm_fn"})
+    compiled = lowered.compile()
+    assert compiled.as_text().count("tpu_custom_call") == (
+        2 * 2 + 2 + 2 * 2 if program == "mixed" else 2 + 1 + 2 * 2)
+    layouts = compiled.input_formats[0]
+    for i, rank in ((1, 4), (3, 5)):
+        assert layouts[i].layout.major_to_minor == tuple(range(rank)), \
+            layouts[i]
+    for pool in (state[0].shape, state[2].shape):
+        assert _pool_copies(compiled, pool) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 800 << 20
