@@ -15,7 +15,11 @@ decodes MANY sequences per device step:
   ``prefill_chunk``-token chunk per admitting sequence in ONE dispatch,
   so a 1k-token arrival never stalls running decodes behind a
   monolithic whole-bucket prefill (head-of-line blocking at step
-  boundaries);
+  boundaries); ``prefill_chunk`` left unset is chosen with the other
+  shapes (obs/memory.choose_engine_config): as wide as the HBM ledger,
+  the prompt cap and the chip's ridge allow, since a step streams every
+  weight whatever it carries; two blocks where no budget or no device
+  roof resolves (the CPU);
 - decode: every running sequence advances one token per dispatch with
   per-sequence positions/block tables (the dense path's
   one-scalar-position design is what forced batch 1).  Rounds with no
@@ -509,17 +513,19 @@ class PagedDecodeEngine:
             reference_attn=(self.attn != "pallas"),
             prefill_chunk=prefill_chunk, num_blocks=num_blocks,
             block_size=block_size, max_batch_size=max_batch_size,
-            chain_steps=chain_steps,
+            chain_steps=chain_steps, seq_buckets=seq_buckets,
         )
         num_blocks = auto["num_blocks"]
         block_size = auto["block_size"]
         chain_steps = auto["chain_steps"]
+        prefill_chunk = auto["prefill_chunk"]
         self.max_batch_size = int(auto["max_batch_size"])
         self.auto_config = {
             "chosen": auto["chosen"], "source": auto["source"],
             "num_blocks": num_blocks, "block_size": block_size,
             "max_batch_size": self.max_batch_size,
-            "chain_steps": chain_steps, "quantize": quantize,
+            "chain_steps": chain_steps,
+            "chunk_source": auto["chunk_source"], "quantize": quantize,
         }
         # re-constructibility guarantee: the ledger below is built FRESH
         # from the resolved shapes (not reused from the chooser), so the
@@ -534,7 +540,8 @@ class PagedDecodeEngine:
             budget_bytes=hbm_budget_bytes,
             reference_attn=(self.attn != "pallas"),
         )
-        if auto["chosen"] and self.hbm_plan.budget_bytes is not None:
+        if set(auto["chosen"]) - {"prefill_chunk"} \
+                and self.hbm_plan.budget_bytes is not None:
             assert self.hbm_plan.fits, (
                 "auto-chosen engine config must re-construct as fitting: "
                 + self.hbm_plan.reject_message()
@@ -576,17 +583,22 @@ class PagedDecodeEngine:
         })
         self.seq_buckets = buckets or [bucket_cap]
         # chunk width: block-aligned (so chunk writes cover whole blocks
-        # except the prompt's tail), default two blocks per step — small
-        # enough that an arrival adds bounded latency to in-flight
-        # decodes, large enough to amortize the dispatch
-        if prefill_chunk is None:
-            prefill_chunk = 2 * bs
+        # except the prompt's tail).  Left unset it was chosen above
+        # (obs/memory.choose_engine_config) as wide as the ledger, the
+        # prompt cap and the chip's ridge allow: a step streams every
+        # weight whatever it carries, so its tokens ride on bytes paid
+        # anyway, and a decode row that rides it waits that one longer
+        # step in place of several short ones; two blocks where no budget
+        # or no device roof resolves (the CPU)
         self.prefill_chunk = max(bs, min(-(-int(prefill_chunk) // bs) * bs,
                                          bucket_cap))
         # packed token budget of one ragged dispatch: every decode row
         # costs one token, the rest is chunk headroom — so the mixed
         # program's cost scales with B + chunk, never B x chunk
         self.mixed_tokens = self.max_batch_size + self.prefill_chunk
+        # (what runs: the chosen or given chunk in whole blocks, no wider
+        # than the largest bucket)
+        self.auto_config["prefill_chunk"] = self.prefill_chunk
         # Round-10 device-resident multi-step decode: when the queue is
         # quiet (no pending admissions, no mid-prefill chunks) the engine
         # chains up to `chain_steps` greedy steps into ONE dispatch and
@@ -2237,8 +2249,11 @@ class PagedDecodeEngine:
             if a.req.t_chunk0 is not None:
                 a.req.n_skipped += 1
                 a.req.n_rounds += 1
+        # chunk_rows / chunk_tokens: the rows that carry a prompt chunk and
+        # their tokens (the rest of `tokens` is one a decode row)
         ph.set(kind="mixed", rows=len(rows), tokens=t, budget=T,
-               waiting=len(waiting))
+               waiting=len(waiting), chunk_rows=len(chunked),
+               chunk_tokens=t - (len(rows) - len(chunked)))
         self._note_keys(ph, [int(c) for c in
                              row_start[:row] + row_nvalid[:row]],
                         [int(q) for q in row_nvalid[:row]])

@@ -363,6 +363,7 @@ class HbmPlan:
             "block_size": self.block_size,
             "max_batch_size": self.max_batch_size,
             "chain_steps": self.chain_steps,
+            "prefill_chunk": self.prefill_chunk,
             "tp": self.tp,
         }
 
@@ -461,6 +462,59 @@ ENGINE_DEFAULTS = {
 
 _BATCH_LADDER = (16, 8, 4, 2, 1)
 _CHAIN_LADDER = (16, 8, 4, 1)
+# prefill chunk rungs (tokens; rounded up to whole blocks).  The top and
+# the ridge share below are fixed from one sweep of 64 / 128 / 256 / 512 in
+# serve_closed16 and lfm2_closed16 (PERF.md section 6, PR 34)
+_CHUNK_LADDER = (512, 256, 128, 64, 32)
+# the share of the step's streaming time its matmuls may fill: at 1.0 a
+# step of max_batch_size + chunk tokens sits on the chip's ridge
+_CHUNK_RIDGE_SHARE = 1.0
+
+
+def resolve_roof(explicit: dict | None = None) -> tuple[dict | None, str]:
+    """(``{"peak": FLOP/s, "membw": bytes/s}`` | None, source): the roof a
+    prefill chunk is sized against.  An explicit one (a described chip),
+    else on a TPU backend the published row of the device's kind
+    (``obs/profiler._roofline``); a kind that is not listed, and every
+    other backend, resolve none: the CPU's roof is a probe, and nothing is
+    measured at engine build."""
+    if explicit:
+        return {"peak": float(explicit["peak"]),
+                "membw": float(explicit["membw"])}, "explicit"
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return None, "none"
+    from .profiler import _roofline
+
+    roof = _roofline()
+    if roof["peak"] and roof["membw"]:
+        return {"peak": roof["peak"], "membw": roof["membw"]}, roof["source"]
+    return None, roof["source"]
+
+
+def step_flops_per_token(cfg, params, *, tp: int = 1) -> int:
+    """Matmul FLOPs one token costs a step, per shard, from the decode
+    plan's own leaves as the ledger bills them: 2 an element of every leaf
+    of two axes or more; a routed expert layer (three axes, as many
+    leading entries as the configuration holds experts) at the share of
+    its experts a token is routed to.  Without the pytree, twice the
+    ledger's parameter count."""
+    if params is None:
+        return 2 * _params_bytes(cfg, None, tp, 1)  # a byte a parameter
+    import jax
+
+    held = getattr(cfg, "n_experts", 0)
+    share = getattr(cfg, "top_k", 0) / max(
+        getattr(cfg, "router_experts", held), 1)
+    flops = 0.0
+    for leaf in jax.tree_util.tree_leaves(params):
+        shape = getattr(leaf, "shape", ())
+        if len(shape) < 2:
+            continue
+        routed = held and len(shape) == 3 and shape[0] == held
+        flops += 2.0 * leaf.size * (share if routed else 1.0)
+    return int(flops // max(tp, 1))
 
 
 def choose_engine_config(cfg, *, params=None, tp: int = 1, dtype=None,
@@ -470,11 +524,12 @@ def choose_engine_config(cfg, *, params=None, tp: int = 1, dtype=None,
                          num_blocks: int | None = None,
                          block_size: int | None = None,
                          max_batch_size: int | None = None,
-                         chain_steps: int | None = None) -> dict:
+                         chain_steps: int | None = None,
+                         seq_buckets=None, roof: dict | None = None) -> dict:
     """Pick the engine shapes the caller left as ``None`` from HBM-ledger
     what-ifs (:meth:`HbmPlan.fits_with`) instead of hand-set defaults
     (Round-17).  Explicit values are honored verbatim — only the Nones
-    are chosen.  The rule, in order:
+    are chosen.  The rule, in order, of the five shapes:
 
     - ``block_size``: the pool granularity every kernel/chunk rule is
       tiled for — not a fit question; 16 unless overridden.
@@ -486,30 +541,56 @@ def choose_engine_config(cfg, *, params=None, tp: int = 1, dtype=None,
     - ``num_blocks``: full coverage — every batch row able to span
       ``cfg.max_len`` (plus the null block) — when that fits, else the
       ledger's ``max_fitting_num_blocks`` at the chosen batch/chain.
+    - ``prefill_chunk``: chosen LAST, at the three shapes above and a
+      two-block chunk, so that a wider chunk never costs a block of the
+      pool or a rung of batch or chain.  The widest rung of (512, 256,
+      128, 64, 32), in whole blocks, for which (1) the ledger still fits,
+      with the mixed program's temporaries and a windowed family's window
+      pool at that chunk, and the pool at full coverage; (2) the rung is
+      no wider than the prompt cap (the largest of ``seq_buckets``, or
+      ``cfg.max_len``); (3) the step stays on the bytes side of the chip's
+      ridge: ``(max_batch_size + rung) x`` :func:`step_flops_per_token`
+      over the roof's peak is no more than the bytes a step streams (the
+      ledger's ``params_bytes``) over its bandwidth, so the chunk rides on
+      weights the step reads anyway, and a plan of fewer bytes (int8,
+      bf16) gets a narrower chunk than an f32 one of the same shapes.
+      Where no budget or no roof resolves (:func:`resolve_roof`: the CPU),
+      or no rung passes, two blocks, reported as a default.
 
     With no budget resolvable the ladder has no signal and the choice
     falls back to :data:`ENGINE_DEFAULTS` (reported as such).
 
-    Returns a dict of the four resolved ints plus ``plan`` (a FRESH
+    Returns a dict of the five resolved ints plus ``plan`` (a FRESH
     ledger built from the final values — the re-constructibility
     guarantee: anyone re-running ``hbm_plan`` with these numbers gets
-    the same fitting verdict), ``chosen`` (which names were auto-picked)
-    and ``source``.  Raises ``ValueError`` when a budget resolves but no
-    configuration fits, mirroring the construction rejection path."""
+    the same fitting verdict), ``chosen`` (which names were auto-picked),
+    ``source`` and ``chunk_source`` (how the chunk was arrived at).  Raises
+    ``ValueError`` when a budget resolves but no configuration fits,
+    mirroring the construction rejection path."""
     chosen = [name for name, v in (
         ("num_blocks", num_blocks), ("block_size", block_size),
         ("max_batch_size", max_batch_size), ("chain_steps", chain_steps),
+        ("prefill_chunk", prefill_chunk),
     ) if v is None]
     bs = int(block_size) if block_size else ENGINE_DEFAULTS["block_size"]
     budget, budget_source = resolve_budget(budget_bytes)
 
-    def ledger(nb: int, k: int, b: int) -> HbmPlan:
+    def ledger(nb: int, k: int, b: int, chunk: int | None = None) -> HbmPlan:
         return hbm_plan(
             cfg, num_blocks=nb, block_size=bs, max_batch_size=b,
-            chain_steps=k, prefill_chunk=prefill_chunk, tp=tp,
+            chain_steps=k, prefill_chunk=chunk or prefill_chunk, tp=tp,
             dtype=dtype, params=params, budget_bytes=budget_bytes,
             reference_attn=reference_attn,
         )
+
+    def result(plan: HbmPlan, chunk_source: str, source: str) -> dict:
+        return {
+            "num_blocks": plan.num_blocks, "block_size": bs,
+            "max_batch_size": plan.max_batch_size,
+            "chain_steps": plan.chain_steps,
+            "prefill_chunk": plan.prefill_chunk, "plan": plan,
+            "chosen": chosen, "source": source, "chunk_source": chunk_source,
+        }
 
     if budget is None:
         nb = int(num_blocks) if num_blocks else \
@@ -518,11 +599,11 @@ def choose_engine_config(cfg, *, params=None, tp: int = 1, dtype=None,
             ENGINE_DEFAULTS["max_batch_size"]
         k = max(1, int(chain_steps) if chain_steps else
                 ENGINE_DEFAULTS["chain_steps"])
-        return {
-            "num_blocks": nb, "block_size": bs, "max_batch_size": b,
-            "chain_steps": k, "plan": ledger(nb, k, b), "chosen": chosen,
-            "source": "defaults (no HBM budget resolved)",
-        }
+        return result(
+            ledger(nb, k, b),  # an unset chunk is two blocks there
+            "explicit" if prefill_chunk
+            else "default: two blocks (no HBM budget resolved)",
+            "defaults (no HBM budget resolved)")
 
     blocks_per_seq = -(-cfg.max_len // bs)
     min_nb = blocks_per_seq + 1  # one full-length sequence + null block
@@ -536,26 +617,68 @@ def choose_engine_config(cfg, *, params=None, tp: int = 1, dtype=None,
             (k for k in _CHAIN_LADDER if ledger(min_nb, k, b).fits), 1
         )
     k = max(1, int(chain_steps))
+    covered = True
     if num_blocks is None:
         want = b * blocks_per_seq + 1
         probe = ledger(want, k, b)
         if probe.fits:
             num_blocks = want
         else:
+            covered = False
             num_blocks = probe.max_fitting_num_blocks()
             if num_blocks is None or num_blocks < 2:
                 raise ValueError(probe.reject_message())
     nb = int(num_blocks)
-    final = ledger(nb, k, b)
-    if chosen and not final.fits:
+    if prefill_chunk:
+        chunk, chunk_source = int(prefill_chunk), "explicit"
+    else:
+        chunk, chunk_source = _choose_chunk(
+            cfg, ledger(nb, k, b), lambda c: ledger(nb, k, b, c).fits,
+            params=params, covered=covered, seq_buckets=seq_buckets,
+            roof=roof)
+    final = ledger(nb, k, b, chunk)
+    if set(chosen) - {"prefill_chunk"} and not final.fits:
         # an auto-chosen shape must never need the clamp/reject path —
         # the what-ifs above already proved it against the same ledger
+        # (a chosen chunk alone decides nothing: it is two blocks, as an
+        # unset one always was, unless the ledger fits at a wider rung)
         raise AssertionError(
             "auto-chosen engine config failed its own re-constructed "
             "fit check: " + final.reject_message()
         )
-    return {
-        "num_blocks": nb, "block_size": bs, "max_batch_size": b,
-        "chain_steps": k, "plan": final, "chosen": chosen,
-        "source": f"hbm_plan.fits_with what-ifs ({budget_source})",
-    }
+    return result(final, chunk_source,
+                  f"hbm_plan.fits_with what-ifs ({budget_source})")
+
+
+def _choose_chunk(cfg, plan: HbmPlan, fits, *, params, covered: bool,
+                  seq_buckets, roof: dict | None) -> tuple[int, str]:
+    """The ``prefill_chunk`` rule of :func:`choose_engine_config` at the
+    shapes of ``plan`` (built at a two-block chunk): ``(tokens, how)``.
+    ``fits(chunk)``: the ledger's verdict at these shapes and that chunk;
+    ``covered``: the pool spans every row's full length."""
+    bs = plan.block_size
+    two_blocks = 2 * bs
+    roof, roof_source = resolve_roof(roof)
+    if roof is None:
+        return two_blocks, f"default: two blocks (no device roof: " \
+            f"{roof_source})"
+    if not covered:
+        return two_blocks, "default: two blocks (the pool is short of " \
+            "full coverage: no bytes to spare for a wider step)"
+    cap = min(max(seq_buckets), cfg.max_len) if seq_buckets \
+        else cfg.max_len
+    token_s = step_flops_per_token(cfg, params, tp=plan.tp) / roof["peak"]
+    stream_s = _CHUNK_RIDGE_SHARE * plan.params_bytes / roof["membw"]
+    for rung in _CHUNK_LADDER:
+        rung = -(-rung // bs) * bs
+        if rung <= two_blocks:
+            break
+        if rung <= cap and fits(rung) \
+                and (plan.max_batch_size + rung) * token_s <= stream_s:
+            return rung, (
+                f"ridge: {plan.max_batch_size} + {rung} tokens x "
+                f"{token_s * 1e6:.2f} us of matmuls <= "
+                f"{stream_s * 1e3:.2f} ms of streamed weights "
+                f"({roof_source}); prompt cap {cap}; ledger fits")
+    return two_blocks, "default: two blocks (no wider rung passes the " \
+        "ledger, the prompt cap and the ridge)"

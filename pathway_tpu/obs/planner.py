@@ -388,9 +388,9 @@ def plan(*, cfg=None, db=None, current_processes: int | None = None,
                     why=str(res.get("source")),
                 ))
             p.add(Decision(
-                knob="prefill_chunk",
-                value=2 * int(res["block_size"]), source="default",
-                why="2 x block_size (engine admission tiling rule)",
+                knob="prefill_chunk", value=res["prefill_chunk"],
+                source="default" if res["chunk_source"].startswith("default")
+                else src, why=res["chunk_source"],
             ))
         except Exception as exc:  # noqa: BLE001 - an unfittable config
             p.add(Decision(                      # is a reported decision

@@ -10,6 +10,7 @@ the worker that is given the file loads the TPU library.
 """
 
 import importlib
+import math
 
 import pytest
 
@@ -505,9 +506,11 @@ def test_grouped_matmul_kernel_compiles_at_128_experts(shape):
 # the published widths and a few layers deep.
 
 
-def _family_case(shape, family):
+def _family_case(shape, family, chunk=None):
     """(programs table, parameter shapes, cache arrays, the mixed and the
-    chained program's index arrays, custom calls of a mixed step)."""
+    chained program's index arrays, custom calls of a mixed step);
+    ``chunk``: the decoder's or the lfm2 family's prefill chunk (two blocks
+    unless given)."""
     import jax
     import jax.numpy as jnp
 
@@ -542,8 +545,9 @@ def _family_case(shape, family):
         params = planned(fam, cfg, lambda c, k: decoder.init_decoder_params(
             c, k))
         pool = shape((2, NBLK, BS, H * HD), jnp.bfloat16)
+        chunk = chunk or CHUNK
         return (fam.programs(cfg, "pallas", None), params, (pool, pool),
-                index_arrays(B + CHUNK, CHUNK, NB, ()), {"mixed": 2 * 2,
+                index_arrays(B + chunk, chunk, NB, ()), {"mixed": 2 * 2,
                                                          "chained": 2})
     if family == "lfm2":
         cfg, _raw, pool, arena, _p = _lfm2_programs(shape, depth=5)
@@ -555,7 +559,8 @@ def _family_case(shape, family):
         n_attn = len(cfg.attn_layers)
         return (fam.programs(cfg, "pallas", None), params,
                 (pool, pool, arena),
-                index_arrays(rows + 32, 32, 128, (vec(rows),)),
+                index_arrays(rows + (chunk or 32), chunk or 32, 128,
+                             (vec(rows),)),
                 {"mixed": 2 * n_attn + 2 * n_moe,
                  "chained": n_attn + 2 * n_moe})
     from pathway_tpu.kvcache.windowed import window_pool_blocks
@@ -616,6 +621,104 @@ def test_packed_step_programs_lower_under_their_names(shape, monkeypatch,
         assert layouts[i].layout.major_to_minor == (0, 1, 2, 3), layouts[i]
     for pool in {s.shape for s in state}:
         assert _pool_copies(compiled, pool) == []
+
+
+# -- the chunk the engine chooses for itself (PR 34) --------------------------
+# obs/memory.choose_engine_config sizes the prefill chunk from the plan's
+# bytes, the ledger and the chip's roof; here the rung it gives each of the
+# two geometries whose cells leave the chunk to it, under the described
+# v5e's published roof and budget, through the ragged kernel and the mixed
+# program.
+
+
+def _chosen_chunk(family):
+    """(the rung of the rule for the family's whole model, K/V heads,
+    query heads a K/V head, blocks a table, pool blocks, attention
+    layers)."""
+    from pathway_tpu.obs import memory
+
+    from .utils import described_decode_plan
+
+    cfg, plan, dtype, kw = described_decode_plan(
+        "gpt2_large_f32" if family == "decoder" else "lfm2")
+    res = memory.choose_engine_config(
+        cfg, params=plan, dtype=dtype, budget_bytes=int(15.02 * 2 ** 30),
+        reference_attn=False, roof={"peak": 197e12, "membw": 819e9},
+        seq_buckets=(64, 256, 1024), **kw)
+    assert "prefill_chunk" in res["chosen"]
+    assert res["chunk_source"].startswith("ridge"), res["chunk_source"]
+    assert res["max_batch_size"] == B
+    geometry = (H, 1, NB, NBLK, L) if family == "decoder" else (
+        cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, 128, 2049,
+        len(cfg.attn_layers))
+    return (res["prefill_chunk"],) + geometry
+
+
+@pytest.mark.parametrize("family", ["decoder", "lfm2"])
+def test_ragged_kernel_compiles_at_the_chosen_chunk(shape, family):
+    """``_paged_ragged_fn`` at the rung the rule gives GPT-2-large (20
+    heads of 64) and LFM2-8B-A1B (32 query heads over 8 K/V heads of 64:
+    four query heads folded on a K/V head's rows).  The compiler holds the
+    kernel to the VMEM its call asks for (:func:`_vmem_limit`), which has
+    to cover the scratch the kernel declares and stay under the chip's
+    128 MiB."""
+    import jax.numpy as jnp
+
+    pa = _paged()
+    chunk, kv, rep, tables, blocks, layers = _chosen_chunk(family)
+    i32 = jnp.int32
+    pool = shape((layers, blocks, BS, kv * HD), jnp.bfloat16)
+    idx = (shape((1,), i32), shape((B, tables), i32), shape((B,), i32),
+           shape((B,), i32))
+    compiled = _compiled_kernel(
+        lambda q, k, v, li, bt, c0, cl: pa._paged_ragged_fn(
+            q, k, v, li, bt, c0, cl, d_true=HD),
+        shape((B, chunk, kv * rep, HD), jnp.bfloat16), pool, pool, *idx,
+    )
+    assert _pool_copies(compiled, pool.shape) == []
+    C = chunk * rep
+    G = pa._heads_per_group(kv, HD, C)
+    K = pa.span_blocks(BS, tables, kv * HD)
+    scratch = sum(
+        math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
+        for s in pa._scratch(K, BS, kv * HD, jnp.bfloat16, kv, C, HD, G,
+                             jnp.bfloat16) if hasattr(s, "dtype")
+        and len(s.shape) > 1)
+    asked = pa._vmem_limit(K, BS, kv * HD, jnp.bfloat16, kv, C, HD, G,
+                           jnp.bfloat16)
+    limit = asked["compiler_params"].vmem_limit_bytes if asked \
+        else 16 * 2 ** 20  # the compiler's own scoped limit
+    print(f"{family}: chunk {chunk}, {C * G} rows a group, scratch "
+          f"{scratch / 2 ** 20:.1f} MiB, limit {limit / 2 ** 20:.0f} MiB")
+    assert scratch < limit <= 100 * 2 ** 20
+
+
+@pytest.mark.parametrize("family", ["decoder", "lfm2"])
+def test_mixed_program_compiles_at_the_chosen_chunk(shape, monkeypatch,
+                                                    family):
+    """The family's mixed program as the engine jits it, ``max_batch_size
+    + chunk`` packed tokens wide at the rung the rule gives the whole
+    model, a few layers deep: every kernel is in the compiled text, no
+    pool is copied whole, and the temporaries stay a small part of what
+    the ledger bills a step of that width."""
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.kvcache.packing import RoundLayout
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    chunk = _chosen_chunk(family)[0]
+    table, params, state, host, calls = _family_case(shape, family, chunk)
+    fn, donated = table["mixed"]
+    layout = RoundLayout(host["mixed"])
+    compiled = jax.jit(layout.program(fn), donate_argnums=donated).lower(
+        params, *state, shape((layout.size,), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == calls["mixed"]
+    for pool in {s.shape for s in state}:
+        assert _pool_copies(compiled, pool) == []
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"{family}: chunk {chunk}, temporaries {temp / 2 ** 20:.1f} MiB")
+    assert temp < 512 << 20
 
 
 # -- the kimi_linear block family (Kimi-Linear-48B-A3B's published widths) ----

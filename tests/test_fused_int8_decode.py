@@ -238,8 +238,12 @@ def test_autoconfig_defaults_without_budget(params, monkeypatch):
                             name="t_r17_auto_def")
     ac = eng.auto_config
     assert set(ac["chosen"]) == {"num_blocks", "block_size",
-                                 "max_batch_size", "chain_steps"}
+                                 "max_batch_size", "chain_steps",
+                                 "prefill_chunk"}
     assert "defaults" in ac["source"]
+    # no budget, no roof: two blocks, reported as a default
+    assert ac["prefill_chunk"] == eng.prefill_chunk == 2 * ac["block_size"]
+    assert ac["chunk_source"].startswith("default")
     for k, v in obs_memory.ENGINE_DEFAULTS.items():
         assert ac[k] == v, (k, ac)
 
@@ -259,7 +263,8 @@ def test_autoconfig_budget_ladder_and_reconstruct(params, monkeypatch):
         _CFG, params, seq_buckets=(16, 32, 64),
         num_blocks=ac["num_blocks"], block_size=ac["block_size"],
         max_batch_size=ac["max_batch_size"],
-        chain_steps=ac["chain_steps"], name="t_r17_auto_redo",
+        chain_steps=ac["chain_steps"], prefill_chunk=ac["prefill_chunk"],
+        name="t_r17_auto_redo",
     )
     assert redo.auto_config["chosen"] == []
     assert redo.hbm_plan.fits, redo.hbm_plan.reject_message()
@@ -270,9 +275,128 @@ def test_autoconfig_budget_ladder_and_reconstruct(params, monkeypatch):
     tiny = PagedDecodeEngine(_CFG, params, seq_buckets=(16, 32, 64),
                              num_blocks=24, block_size=4,
                              max_batch_size=2, chain_steps=4,
+                             prefill_chunk=12,
                              name="t_r17_auto_explicit")
     assert tiny.auto_config["chosen"] == []
     assert tiny.pool.num_blocks == 24
+    assert tiny.prefill_chunk == tiny.hbm_plan.prefill_chunk == 12
+    # a budget but no device roof (the CPU): the chunk stays two blocks,
+    # chosen and reported as a default, and the ledger carries it
+    assert "prefill_chunk" in ac["chosen"]
+    assert eng.prefill_chunk == eng.hbm_plan.prefill_chunk == 32
+    assert ac["chunk_source"].startswith("default: two blocks (no device")
+
+
+# -- the fifth chosen shape: prefill_chunk ------------------------------------
+
+# a described v5e: the published roof (obs/profiler.TPU_PEAKS) and the
+# budget its memory_stats report
+_V5E = {"peak": 197e12, "membw": 819e9}
+_V5E_HBM = int(15.02 * 2 ** 30)
+
+
+def _choose(name, **kw):
+    from .utils import described_decode_plan
+
+    cfg, plan, dtype, extra = described_decode_plan(name)
+    kw = {"budget_bytes": _V5E_HBM, "roof": _V5E,
+          "seq_buckets": (64, 256, 1024), **extra, **kw}
+    return cfg, plan, dtype, obs_memory.choose_engine_config(
+        cfg, params=plan, dtype=dtype, reference_attn=False, **kw)
+
+
+@pytest.mark.parametrize("name", ["gpt2_large_f32", "gpt2_large_int8",
+                                  "lfm2"])
+def test_chunk_is_chosen_from_ledger_cap_and_ridge(name):
+    """Under a described v5e roof and budget the chunk is a rung of the
+    ladder, listed as chosen with its reckoning; it never lowers the three
+    shapes chosen before it; and the ledger re-constructed from the chosen
+    numbers carries the chunk and fits."""
+    cfg, plan, dtype, res = _choose(name)
+    assert res["prefill_chunk"] in obs_memory._CHUNK_LADDER
+    assert "prefill_chunk" in res["chosen"]
+    assert res["chunk_source"].startswith("ridge"), res["chunk_source"]
+    _c, _p, _d, narrow = _choose(name, prefill_chunk=32)
+    assert "prefill_chunk" not in narrow["chosen"]
+    for shape in ("num_blocks", "max_batch_size", "chain_steps"):
+        assert res[shape] == narrow[shape], (shape, res, narrow)
+    assert res["num_blocks"] == res["max_batch_size"] \
+        * (cfg.max_len // 16) + 1  # full coverage
+    redo = obs_memory.hbm_plan(
+        cfg, num_blocks=res["num_blocks"], block_size=res["block_size"],
+        max_batch_size=res["max_batch_size"],
+        chain_steps=res["chain_steps"],
+        prefill_chunk=res["prefill_chunk"], dtype=dtype, params=plan,
+        budget_bytes=_V5E_HBM, reference_attn=False)
+    assert redo.prefill_chunk == res["prefill_chunk"]
+    assert redo.as_dict()["prefill_chunk"] == res["prefill_chunk"]
+    assert redo.fits and redo.total_bytes == res["plan"].total_bytes
+    # a wider step keeps more temporaries: the ledger bills them
+    assert redo.temp_bytes >= narrow["plan"].temp_bytes
+
+
+def test_chunk_follows_the_bytes_a_step_streams():
+    """Same shapes, fewer bytes, a narrower-or-equal chunk (no test of
+    ``quantize``: the int8 plan's leaves are a byte wide); LFM2's 9 GB of
+    experts against ~1 GFLOP a token allow at least GPT-2's rung."""
+    f32 = _choose("gpt2_large_f32")[3]
+    i8 = _choose("gpt2_large_int8")[3]
+    moe = _choose("lfm2")[3]
+    assert f32["plan"].params_bytes > 2 * i8["plan"].params_bytes
+    assert f32["prefill_chunk"] >= i8["prefill_chunk"] > 0
+    assert moe["prefill_chunk"] >= f32["prefill_chunk"]
+    # the routed experts count at top_k / n_experts of their FLOPs
+    cfg, plan, _d, _r = _choose("lfm2")
+    dense = 2 * sum(w.size for w in jax.tree_util.tree_leaves(plan)
+                    if w.ndim >= 2)
+    assert obs_memory.step_flops_per_token(cfg, plan) < 0.3 * dense
+
+
+@pytest.mark.parametrize("case", ["explicit", "no_budget", "no_roof",
+                                  "prompt_cap", "tight_ledger",
+                                  "short_pool"])
+def test_chunk_rule_edges(case, monkeypatch):
+    monkeypatch.delenv("PW_HBM_BUDGET_BYTES", raising=False)
+    from .utils import described_decode_plan
+
+    cfg, plan, dtype, _x = described_decode_plan("gpt2_large_f32")
+    kw = dict(params=plan, dtype=dtype, reference_attn=False,
+              budget_bytes=_V5E_HBM, roof=_V5E, seq_buckets=(64, 256, 1024))
+    if case == "explicit":  # honoured verbatim, not listed, not a rung
+        res = obs_memory.choose_engine_config(cfg, prefill_chunk=48, **kw)
+        assert res["prefill_chunk"] == res["plan"].prefill_chunk == 48
+        assert "prefill_chunk" not in res["chosen"]
+        assert res["chunk_source"] == "explicit"
+        return
+    if case == "no_budget":
+        kw["budget_bytes"] = None
+    elif case == "no_roof":  # this backend resolves none
+        kw["roof"] = None
+    elif case == "prompt_cap":
+        kw["seq_buckets"] = (64,)
+    elif case == "tight_ledger":
+        # room for the two-block configuration at full coverage and 40 MB
+        base = obs_memory.choose_engine_config(cfg, prefill_chunk=32, **kw)
+        kw["budget_bytes"] = base["plan"].total_bytes + 40 * 2 ** 20
+    elif case == "short_pool":
+        base = obs_memory.choose_engine_config(cfg, prefill_chunk=32, **kw)
+        kw["budget_bytes"] = base["plan"].total_bytes - 2 ** 30
+    res = obs_memory.choose_engine_config(cfg, **kw)
+    assert "prefill_chunk" in res["chosen"]
+    assert res["plan"].prefill_chunk == res["prefill_chunk"]
+    if case in ("no_budget", "no_roof", "short_pool"):
+        assert res["prefill_chunk"] == 32
+        assert res["chunk_source"].startswith("default"), res["chunk_source"]
+    elif case == "prompt_cap":
+        assert res["prefill_chunk"] == 64
+    else:
+        wide = obs_memory.choose_engine_config(
+            cfg, **dict(kw, budget_bytes=_V5E_HBM))
+        assert 32 <= res["prefill_chunk"] < wide["prefill_chunk"]
+        assert res["num_blocks"] == wide["num_blocks"]
+        assert res["plan"].fits
+    if case == "short_pool":  # the pool was clamped: nothing to spare
+        assert res["num_blocks"] < 16 * 64 + 1
 
 
 def test_hbm_plan_bills_int8_at_true_byte_width(params):

@@ -108,6 +108,54 @@ def test_chunked_matches_dense_at_the_default_chunk(params):
     assert out == [_dense_greedy(params, p, 6) for p in prompts]
 
 
+@pytest.mark.parametrize("chunk", [None, 32, 64])
+def test_chunk_width_regroups_positions_and_is_counted(params, chunk):
+    """A chunk only regroups a prompt's positions: served at the engine's
+    own two blocks (no budget, no roof on the CPU), at 32 and at the
+    widest rung of the chooser's ladder that the prompt cap (64) allows,
+    the greedy tokens are the dense path's; and the mixed rounds'
+    ``pw.round.build`` spans count the rows that carried a chunk and
+    their tokens, which sum to the prompts' tokens."""
+    from pathway_tpu import obs
+    from pathway_tpu.obs import memory as obs_memory
+
+    eng = PagedDecodeEngine(
+        _CFG, params, num_blocks=96, block_size=8, max_batch_size=4,
+        seq_buckets=(16, 32, 64), prefix_sharing=False,
+        prefill_chunk=chunk, name=f"t_r34_chunk_{chunk}",
+    )
+    if chunk is None:
+        assert eng.prefill_chunk == 16
+        assert "prefill_chunk" in eng.auto_config["chosen"]
+        assert eng.auto_config["chunk_source"].startswith("default")
+    else:
+        assert chunk in obs_memory._CHUNK_LADDER
+        assert eng.prefill_chunk == chunk <= eng.seq_buckets[-1]
+        assert "prefill_chunk" not in eng.auto_config["chosen"]
+    assert eng.auto_config["prefill_chunk"] == eng.prefill_chunk
+    assert eng.mixed_tokens == eng.max_batch_size + eng.prefill_chunk
+    rng = np.random.default_rng(34)
+    lengths = [5, 17, 33, 40, 61, 64]
+    prompts = [[int(t) for t in rng.integers(0, _CFG.vocab_size, size=n)]
+               for n in lengths]
+    rec = obs.recorder()
+    n0 = rec.n_recorded
+    got = eng.generate_batch([(p, 5) for p in prompts])
+    assert got == [_dense_greedy(params, p, 5) for p in prompts]
+    assert rec.n_recorded - n0 < rec.capacity  # nothing of it evicted
+    builds = [s.attrs for s in rec.snapshot()
+              if s.name == "pw.round.build" and s.trace_id == eng._run_ctx[0]
+              and s.attrs.get("kind") == "mixed"]
+    assert builds and all(
+        0 < a["chunk_rows"] <= a["rows"]
+        and a["tokens"] == a["chunk_tokens"] + a["rows"] - a["chunk_rows"]
+        and a["chunk_tokens"] <= a["chunk_rows"] * eng.prefill_chunk
+        for a in builds)
+    assert sum(a["chunk_tokens"] for a in builds) == sum(lengths)
+    assert sum(a["chunk_rows"] for a in builds) >= sum(
+        -(-n // eng.prefill_chunk) for n in lengths)
+
+
 @pytest.mark.parametrize("attn", ["reference", "pallas"])
 def test_chunked_identity_under_shared_prefixes_same_round(params, attn):
     # every prompt shares a two-block header and ALL are admitted in the

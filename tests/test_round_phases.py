@@ -459,6 +459,39 @@ def test_the_benchmark_reads_arrays_per_transfer(params):
     assert span_stat.from_ring(spec, before, len(before), window) is None
 
 
+def test_the_benchmark_reads_chunk_row_tokens(params):
+    """``chunk_row_tokens`` (benchmark/metrics) through its reader on this
+    run's recorder: prompt tokens a chunk row over the mixed rounds, no
+    more than the engine's chunk; spans without ``chunk_rows`` (the
+    program before it counted them) give no reading."""
+    import time
+    import types
+
+    from benchmark.readers import span_stat
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "metrics", "chunk_row_tokens.json")) as f:
+        spec = json.load(f)
+    eng = _engine(params, "t_chunk_metric")
+    lengths = (20, 9, 13)
+    t0 = time.perf_counter()
+    eng.generate_batch([(p, 4) for p in _prompts(lengths)])
+    window = (t0, time.perf_counter())
+    ring = obs.recorder().snapshot()
+    value = span_stat.from_ring(spec, ring, len(ring), window)
+    rows = sum(s.attrs["chunk_rows"] for s in ring
+               if s.name == "pw.round.build"
+               and s.attrs.get("kind") == "mixed")
+    assert value == pytest.approx(sum(lengths) / rows)
+    assert 1 <= value <= eng.prefill_chunk
+    before = [types.SimpleNamespace(
+        name=s.name, t0=s.t0, t1=s.t1, trace_id=s.trace_id,
+        attrs={k: v for k, v in (s.attrs or {}).items()
+               if k not in ("chunk_rows", "chunk_tokens")})
+        for s in ring]
+    assert span_stat.from_ring(spec, before, len(before), window) is None
+
+
 # -- a request's lifecycle ----------------------------------------------------
 
 

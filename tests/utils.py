@@ -400,3 +400,46 @@ def lfm2_feed(cfg, params, prompt, chunk, *, conv0=None, slot=1,
         state = state[:3]
         out.append((s + nv, np.asarray(logits[0])))
     return out, np.asarray(state[2][:, slot])
+
+
+def described_decode_plan(name: str):
+    """``(cfg, decode plan as shapes, dtype, engine keywords)`` of the
+    benchmark's two short-prompt configurations, nothing allocated:
+    ``gpt2_large_f32`` / ``gpt2_large_int8`` (the f32 plan as the chip
+    keeps it, the int8-resident one) and ``lfm2`` (LFM2-8B-A1B's published
+    layers 1-13 in bf16, batch 16 as its cell asks)."""
+    import jax
+    import jax.numpy as jnp
+
+    if name.startswith("gpt2_large"):
+        from pathway_tpu.models import decoder
+
+        cfg = decoder.DecoderConfig(
+            vocab_size=50257, d_model=1280, n_layers=36, n_heads=20,
+            d_ff=5120, max_len=1024, dtype="bfloat16")
+        kw = {"quantize": "int8", "native": True} \
+            if name.endswith("int8") else {"head_t": False}
+        init, cast, extra = decoder.init_decoder_params, None, {}
+
+        def plan(params):
+            return decoder.plan_decode_params(cfg, params, **kw)
+    else:
+        from pathway_tpu.models import lfm2
+
+        pattern = ("conv", "full_attention", "conv", "conv", "conv") \
+            + ("full_attention", "conv", "conv", "conv") * 2
+        cfg = lfm2.Lfm2Config(n_dense_layers=1, layer_types=pattern,
+                              max_len=2048, dtype="bfloat16")
+        init, cast, extra = lfm2.init_lfm2_params, jnp.bfloat16, \
+            {"max_batch_size": 16}
+
+        def plan(params):
+            return lfm2.plan_params(cfg, params)
+
+    def build():
+        params = init(cfg, jax.random.PRNGKey(0))
+        if cast is not None:
+            params = jax.tree_util.tree_map(lambda w: w.astype(cast), params)
+        return plan(params)
+
+    return cfg, jax.eval_shape(build), jnp.bfloat16, extra
