@@ -961,6 +961,35 @@ def bench_retrieval_quality() -> dict:
     }
 
 
+# the engine thread's host phases between two program calls
+_HOST_PHASES = ("pw.round.deliver", "pw.round.admit", "pw.round.build",
+                "pw.round.h2d")
+
+
+def _spans_s(spans, w0: float, w1: float, prefixes, kinds=None) -> float:
+    """Seconds of ``[w0, w1]`` under the recorder's spans whose name
+    starts with one of ``prefixes`` (and, with ``kinds``, whose ``kind``
+    attribute is one of them: the engine's ``pw.round.sync`` /
+    ``pw.round.d2h`` say which program they wait for)."""
+    tot = 0.0
+    for s in spans:
+        if s.t1 is None or s.t1 <= w0 or s.t0 >= w1:
+            continue
+        if kinds is not None and (s.attrs or {}).get("kind") not in kinds:
+            continue
+        if any(s.name.startswith(p) for p in prefixes):
+            tot += min(s.t1, w1) - max(s.t0, w0)
+    return tot
+
+
+def _program_s(phase_s, kinds, *calls) -> float:
+    """From a program call to its ids on the host, for the programs of
+    ``kinds``: the call spans, and the sync and readback of those kinds.
+    ``phase_s`` is a window's :func:`_spans_s`."""
+    return phase_s(*calls) + phase_s("pw.round.sync", "pw.round.d2h",
+                                     kinds=kinds)
+
+
 def bench_generation() -> dict:
     """KV-cached decoding + adaptive-RAG serving (BASELINE config #4).
 
@@ -1191,14 +1220,8 @@ def bench_generation() -> dict:
             w0, w1 = best_window
             spans = _obs.recorder().snapshot()
 
-            def _phase_s(*prefixes):
-                tot = 0.0
-                for s in spans:
-                    if s.t1 is None or s.t1 <= w0 or s.t0 >= w1:
-                        continue
-                    if any(s.name.startswith(p) for p in prefixes):
-                        tot += min(s.t1, w1) - max(s.t0, w0)
-                return tot
+            def _phase_s(*prefixes, kinds=None):
+                return _spans_s(spans, w0, w1, prefixes, kinds)
 
             wall = max(w1 - w0, 1e-9)
             # round-14: per-PROGRAM share of the same window from the
@@ -1221,23 +1244,25 @@ def bench_generation() -> dict:
             chained_fields["decode_phase_fracs"] = {
                 # scheduler queue wait (0 for this direct-call workload)
                 "queue": round(_phase_s("serve.queue") / wall, 4),
-                # re-admission prefill dispatches inside the timed window
-                "prefill": round(_phase_s(
-                    "engine.device.mixed", "engine.device.prefill"
-                ) / wall, 4),
-                # decode device-busy (dispatch -> sync return)
-                "device": round(_phase_s(
-                    "engine.device.chain", "engine.device.step",
-                    "engine.device.verify"
+                # re-admission prefill dispatches inside the timed window:
+                # the mixed program's call, the wait for it, the readback
+                "prefill": round(_program_s(
+                    _phase_s, ("mixed",), "pw.mixed_step") / wall, 4),
+                # decode device-busy (program call -> ids on the host)
+                "device": round(_program_s(
+                    _phase_s, ("chain", "step", "verify"),
+                    "pw.chain_dispatch", "pw.decode_step", "pw.verify_step"
                 ) / wall, 4),
                 # speculative draft cost (0 here — this row is pinned
                 # speculative="off"; the spec row reports its own fracs)
                 "draft": round(_phase_s("engine.draft") / wall, 4),
-                # host blocked collecting the [B, K] ids (subset of
+                # host blocked until the [B, K] ids are ready (subset of
                 # device-busy — reported separately, not additive)
                 "sync": round(_phase_s("pw.round.sync") / wall, 4),
-                # host bookkeeping on the critical path (device idle)
-                "host": round(_phase_s("engine.host_gap") / wall, 4),
+                # the host's own phases between two program calls; on the
+                # double-buffered path part of them runs under the device
+                # (decode_host_gap_frac counts the critical path alone)
+                "host": round(_phase_s(*_HOST_PHASES) / wall, 4),
             }
         # ---- recorder overhead A/B on the SAME workload: chained decode
         # with the flight recorder disabled vs the always-on number above
@@ -1361,28 +1386,23 @@ def bench_generation() -> dict:
             )
         if spec_window is not None:
             # draft-vs-verify attribution of the timed window from the
-            # always-on flight recorder (engine.draft / engine.device.
-            # verify spans) — what the drafting itself cost
+            # always-on flight recorder (engine.draft, and the verify
+            # round's program call, sync and readback) — what the
+            # drafting itself cost
             sw0, sw1 = spec_window
             sspans = _obs.recorder().snapshot()
 
-            def _spec_phase_s(*prefixes):
-                tot = 0.0
-                for s in sspans:
-                    if s.t1 is None or s.t1 <= sw0 or s.t0 >= sw1:
-                        continue
-                    if any(s.name.startswith(p) for p in prefixes):
-                        tot += min(s.t1, sw1) - max(s.t0, sw0)
-                return tot
+            def _spec_phase_s(*prefixes, kinds=None):
+                return _spans_s(sspans, sw0, sw1, prefixes, kinds)
 
             swall = max(sw1 - sw0, 1e-9)
             chained_fields["speculative_phase_fracs"] = {
                 "draft": round(_spec_phase_s("engine.draft") / swall, 4),
-                "verify_device": round(
-                    _spec_phase_s("engine.device.verify") / swall, 4
-                ),
+                "verify_device": round(_program_s(
+                    _spec_phase_s, ("verify",), "pw.verify_step"
+                ) / swall, 4),
                 "sync": round(_spec_phase_s("pw.round.sync") / swall, 4),
-                "host": round(_spec_phase_s("engine.host_gap") / swall, 4),
+                "host": round(_spec_phase_s(*_HOST_PHASES) / swall, 4),
             }
         # the measured (drafter, k) verdict lands in the cost store under
         # this backend's fingerprint — speculative="auto" reads the

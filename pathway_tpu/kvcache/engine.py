@@ -94,12 +94,13 @@ class EngineHungError(RuntimeError):
 class _WatchdogSync:
     """Deadline-bounded device->host sync.
 
-    A blocked ``np.asarray(device_array)`` cannot be interrupted from
-    Python, so the pull runs on a persistent helper thread and the
-    engine thread waits with a timeout.  On expiry the helper is
-    ORPHANED (it parks on the wedged pull; daemon, so it never blocks
-    exit) and the next sync spawns a fresh one — the restarted engine's
-    new pool makes the wedged program's eventual result irrelevant."""
+    A blocked ``block_until_ready()`` or ``np.asarray(device_array)``
+    cannot be interrupted from Python, so both waits of a sync run on a
+    persistent helper thread and the engine thread waits with a timeout.
+    On expiry the helper is ORPHANED (it parks on the wedged wait;
+    daemon, so it never blocks exit) and the next sync spawns a fresh one
+    — the restarted engine's new pool makes the wedged program's eventual
+    result irrelevant."""
 
     def __init__(self, name: str = "pw-engine-watchdog"):
         self._name = name
@@ -671,10 +672,7 @@ class PagedDecodeEngine:
         self._t_failure: float | None = None
         # host-gap accounting: perf_counter of the last device->host sync
         # (the device has nothing queued past it) — the next dispatch
-        # closes the window and records it (see _note_sync/_note_dispatch).
-        # Round-11 generalizes the pair into device-busy vs host-gap SPANS
-        # on the engine-run trace: _note_dispatch opens the device window
-        # (closing any host gap), _note_sync closes it
+        # closes the window and counts it (see _note_sync/_note_dispatch)
         self._t_device_idle: float | None = None
         self._t_dispatch: float | None = None
         self._dispatch_kind = "step"
@@ -1282,30 +1280,25 @@ class PagedDecodeEngine:
     def _note_sync(self) -> None:
         """A device->host sync just returned with nothing queued behind
         it: the device is idle until the next dispatch.  Every dispatch
-        site calls :meth:`_note_dispatch` to close (and record) the
+        site calls :meth:`_note_dispatch` to close (and count) the
         window, so ``pathway_kv_host_gap_seconds_total`` measures exactly
-        the host-on-critical-path time the device spends waiting — on the
+        the host-on-critical-path time the device spends waiting; on the
         double-buffered chained path the bookkeeping that runs AFTER the
-        next dispatch is correctly excluded.  Round-11: the dispatch->sync
-        window additionally lands as an ``engine.device.<kind>`` span on
-        the engine-run trace (device-busy), the sync->dispatch window as
-        ``engine.host_gap`` — the two halves of every engine round."""
-        now = time.perf_counter()
-        if self._t_dispatch is not None:
-            obs.record_span(
-                "engine.device." + self._dispatch_kind,
-                self._t_dispatch, now, ctx=self._run_ctx,
-            )
-            self._t_dispatch = None
-        self._t_device_idle = now
+        next dispatch is correctly excluded.  The counter is all there
+        is: the round's phases (``pw.round.deliver`` + ``admit`` +
+        ``build`` between the two calls, ``h2d`` + the program call +
+        ``sync`` + ``d2h`` from dispatch to here) are the spans."""
+        self._t_device_idle = time.perf_counter()
         self.pool.after_sync()
 
     def _note_dispatch(self, kind: str = "step") -> None:
+        """A dispatch begins: closes the host-gap window :meth:`_note_sync`
+        opened, and keeps the instant (``_t_dispatch``: the prefill-chunk
+        and chain spans start there) and the program's ``kind`` (what the
+        round's ``pw.round.sync`` / ``pw.round.d2h`` will say)."""
         now = time.perf_counter()
         if self._t_device_idle is not None:
             self.pool.stats.record_host_gap(now - self._t_device_idle)
-            obs.record_span("engine.host_gap", self._t_device_idle, now,
-                            ctx=self._run_ctx)
             self._t_device_idle = None
         self._t_dispatch = now
         self._dispatch_kind = kind
@@ -1365,19 +1358,34 @@ class PagedDecodeEngine:
             return jnp.asarray(packed)
 
     def _sync_host(self, dev_array) -> np.ndarray:
-        """Device->host sync (``pw.round.sync``), watchdog-bounded when
-        configured.  The `engine.sync` fault point lives INSIDE the pull
-        so a chaos `hang` wedges exactly where a stuck device program
-        would.  ``perf_ns`` is the clock anchor: this clock's reading at
-        the annotation's start on a device trace's clock."""
-        def pull():
-            faults.fire("engine.sync")
-            return np.asarray(dev_array)
+        """Device->host sync as two sibling phases on the engine thread,
+        each watchdog-bounded when configured.  ``pw.round.sync`` lasts
+        from the program call's return until the result is ready ON THE
+        DEVICE: the launch, the program's own device time and the wake-up.
+        The `engine.sync` fault point lives inside it, so a chaos `hang`
+        wedges exactly where a stuck device program would; ``perf_ns`` is
+        the clock anchor (this clock's reading at the annotation's start
+        on a device trace's clock).  ``pw.round.d2h`` is the pull of the
+        ready result into numpy: the readback's tail on a mixed or step
+        round, near nothing on the chained path, whose copy started at
+        dispatch.  Both say the ``kind`` of the program they wait for."""
+        kind = self._dispatch_kind
 
-        with self._phase("pw.round.sync", perf_ns=time.perf_counter_ns()):
-            if self._watchdog is None:
-                return pull()
-            return self._watchdog.run(pull, self.watchdog_timeout_s)
+        def ready():
+            faults.fire("engine.sync")
+            dev_array.block_until_ready()
+
+        with self._phase("pw.round.sync", perf_ns=time.perf_counter_ns(),
+                         kind=kind):
+            self._bounded(ready)
+        with self._phase("pw.round.d2h", kind=kind, bytes=dev_array.nbytes):
+            return self._bounded(lambda: np.asarray(dev_array))
+
+    def _bounded(self, wait: Callable):
+        """``wait()``, under the watchdog's deadline when one is set."""
+        if self._watchdog is None:
+            return wait()
+        return self._watchdog.run(wait, self.watchdog_timeout_s)
 
     # -- admission ---------------------------------------------------------
     def _try_admit(self, req: _Request, running, pending, deliver) -> str:
@@ -1962,7 +1970,7 @@ class PagedDecodeEngine:
                 ph.set(admitted=0, pending=len(pending))
             acts, kreal, ids_dev, t_disp, prog = inflight
             # ONE sync per K-token chain: the host-blocked-on-device
-            # window (a subset of the device-busy span _note_sync closes)
+            # window, then the pull of ids already on their way
             ids_np = self._sync_host(ids_dev)
             with self._phase("pw.round.deliver") as ph:
                 t_sync1 = time.perf_counter()

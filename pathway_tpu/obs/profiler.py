@@ -621,7 +621,7 @@ class ProfiledFunction:
     def record_dispatch(self, duration_s: float, *, t_end: float | None = None,
                         items: int | None = None) -> None:
         """Attribute one dispatch->sync window to this program (the
-        engine calls this where its ``_note_sync`` closes the window).
+        engine calls this after its ``pw.round.d2h`` returned).
         ``t_end`` is the window's perf_counter end so window queries
         (``window_fracs``) line up with the flight recorder.  Windows
         overlapping a compile are dropped — they measure XLA, not the

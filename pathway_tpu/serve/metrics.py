@@ -152,9 +152,11 @@ class KVCacheStats:
       double-buffered overlap is working)
     - ``pathway_kv_round_seconds_total{pool,phase}`` counter (engine-thread
       seconds by phase of a round: ``admit``, ``build``, ``h2d``,
-      ``dispatch`` (the program call's enqueue), ``sync``, ``deliver`` —
-      the ``pw.round.*`` phases of kvcache/engine.py; their sum is the
-      engine thread's time, and everything but ``sync`` is host work)
+      ``dispatch`` (the program call's enqueue), ``sync`` (until the
+      result is ready on the device), ``d2h`` (its pull into numpy),
+      ``deliver`` — the ``pw.round.*`` phases of kvcache/engine.py; their
+      sum is the engine thread's time, and everything but ``sync`` and
+      ``d2h`` is host work)
     - ``pathway_kv_h2d_arrays_total{pool}`` /
       ``pathway_kv_h2d_transfers_total{pool}`` counters (step arrays the
       dispatches were made of over the host-to-device transfers they

@@ -275,14 +275,19 @@ def test_chained_request_span_tree_admission_to_delivery(params):
         # admission, chunked prefill, and the chain windows it rode
         assert {"engine.admission", "engine.prefill_chunk",
                 "engine.chain"} <= kids
-    # the engine-run trace carries the device-busy/host-gap/sync split
+    # the engine-run trace carries the round's phases: the chain's call,
+    # the wait for it and the [B, K] ids' collect, the host's own work
     run = next(s for s in spans if s.name == "engine.run")
     run_names = {
         s.name for s in spans if s.trace_id == run.trace_id
     }
-    assert "engine.device.chain" in run_names  # chain dispatch->sync
-    assert "pw.round.sync" in run_names        # the [B, K] ids collect
-    assert "engine.host_gap" in run_names      # host-on-critical-path
+    assert "pw.chain_dispatch" in run_names    # the chain's program call
+    assert {"pw.round.sync", "pw.round.d2h"} <= run_names
+    assert {"pw.round.deliver", "pw.round.admit",
+            "pw.round.build"} <= run_names     # host-on-critical-path
+    # which say it once: beside its root the run's trace holds the
+    # phases alone, no second pair of spans that repeats them
+    assert {n.split(".")[0] for n in run_names - {"engine.run"}} == {"pw"}
     # two requests, distinct traces
     assert len({r.trace_id for r in reqs}) == 2
     # and the whole thing dumps as valid Chrome trace JSON

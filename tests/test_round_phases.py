@@ -33,7 +33,7 @@ _CFG = DecoderConfig(
 _WIDE = DecoderConfig(
     vocab_size=64, d_model=512, n_layers=4, n_heads=8, d_ff=1024, max_len=128
 )
-_PHASES = ("admit", "build", "h2d", "sync", "deliver")
+_PHASES = ("admit", "build", "h2d", "sync", "d2h", "deliver")
 
 
 @pytest.fixture(scope="module")
@@ -126,6 +126,30 @@ def test_phase_enters_the_real_annotation_class():
 # -- the phases of a run ----------------------------------------------------
 
 
+def _every_sync_has_its_d2h(phases, kinds):
+    """The sync is two sibling phases: every ``pw.round.sync`` (the wait
+    until the result is ready on the device) is followed at once by
+    exactly one ``pw.round.d2h`` (its pull into numpy) of the same
+    ``kind``, the kind of the program call before them, and there is no
+    ``d2h`` but these; every kind of ``kinds`` is seen.  No duration is
+    asserted: a CPU array has no copy to wait for."""
+    of_call = {"pw.mixed_step": "mixed", "pw.decode_step": "step",
+               "pw.chain_dispatch": "chain", "pw.verify_step": "verify"}
+    syncs = [i for i, s in enumerate(phases) if s.name == "pw.round.sync"]
+    assert syncs and len(syncs) == sum(
+        s.name == "pw.round.d2h" for s in phases)
+    seen = set()
+    for i in syncs:
+        sync, d2h = phases[i], phases[i + 1]
+        assert d2h.name == "pw.round.d2h", d2h.name
+        assert d2h.attrs["kind"] == sync.attrs["kind"]
+        assert d2h.attrs["bytes"] > 0
+        call = next(s for s in reversed(phases[:i]) if s.name in of_call)
+        assert of_call[call.name] == sync.attrs["kind"]
+        seen.add(sync.attrs["kind"])
+    assert kinds <= seen <= set(of_call.values()), seen
+
+
 @pytest.mark.parametrize("path,kw,n_new", [
     # prompts of 3 chunks, one token each: mixed rounds and nothing else
     ("mixed", {"chain_steps": 8}, 1),
@@ -188,6 +212,7 @@ def test_round_phases_tile_the_engine_thread(wide_params, path, kw, n_new):
                 <= anchor <= s.t0 + 1e-6, (prev.name, anchor, s.t0)
             lead.append(s.t0 - anchor)
     assert sorted(lead)[len(lead) // 2] < 1e-3, sorted(lead)[-3:]
+    _every_sync_has_its_d2h(phases, {path, "mixed"})
     for s in phases:
         if s.name == "pw.round.build":
             assert {"rows", "tokens", "budget", "waiting"} <= set(s.attrs)
@@ -212,6 +237,48 @@ def test_round_phases_tile_the_engine_thread(wide_params, path, kw, n_new):
         == snap["mixed_tokens_budget"] - before["mixed_tokens_budget"]
     assert sum(s.attrs["tokens"] for s in mixed) \
         == snap["mixed_tokens_used"] - before["mixed_tokens_used"]
+
+
+def test_sync_and_d2h_under_the_watchdog(params):
+    """With a watchdog both waits run on its helper thread, and both
+    phases are still entered on the engine thread, in the same order; the
+    `engine.sync` fault point fires inside ``pw.round.sync``, so a hang
+    there is the watchdog's to catch and ``pw.round.d2h`` is not
+    reached."""
+    from pathway_tpu import faults
+    from pathway_tpu.serve.admission import EngineFailedError
+
+    eng = _engine(params, "t_phases_watchdog", watchdog_timeout_s=30.0,
+                  chain_steps=4)
+    reqs = [(p, 9) for p in _prompts((20, 9, 13))]
+    want = _engine(params, "t_phases_no_watchdog",
+                   chain_steps=4).generate_batch(list(reqs))
+    obs.recorder().clear()
+    assert eng.generate_batch(list(reqs)) == want
+    spans = obs.recorder().snapshot()
+    (run,) = [s for s in spans if s.name == "engine.run"]
+    phases = sorted((s for s in spans if s.trace_id == run.trace_id
+                     and s.name.startswith("pw.")), key=lambda s: s.t0)
+    assert {s.tid for s in phases} == {run.tid}
+    _every_sync_has_its_d2h(phases, {"mixed", "chain"})
+    for a, b in zip(phases, phases[1:]):
+        assert b.t0 >= a.t1, (a.name, b.name)
+    # a wedge at the fault point: the sync's phase closes with the
+    # watchdog's error, and no readback follows it
+    hung = _engine(params, "t_phases_hung", watchdog_timeout_s=0.3,
+                   max_restarts=0, chain_steps=4)
+    obs.recorder().clear()
+    faults.install("engine.sync", "hang", nth=1, arg_ms=1500)
+    try:
+        with pytest.raises(EngineFailedError, match="watchdog deadline"):
+            hung.generate_batch(list(reqs))
+    finally:
+        faults.clear()
+    names = [s.name for s in sorted(obs.recorder().snapshot(),
+                                    key=lambda s: s.t0)
+             if s.name.startswith("pw.round.")]
+    assert names.count("pw.round.sync") == 1 and names[-1] == "pw.round.sync"
+    assert "pw.round.d2h" not in names
 
 
 @pytest.mark.parametrize("wide", [False, True], ids=["toy", "whole_tiles"])
