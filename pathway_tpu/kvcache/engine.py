@@ -74,7 +74,7 @@ from .. import faults, obs
 from .backend import make_backend
 from .block_pool import BlockPool, PoolExhausted  # noqa: F401 - re-export
 from .packing import RoundLayout
-from .paged_attention import span_blocks
+from .paged_attention import query_tile_columns, span_blocks
 from .prefix_cache import PrefixCache
 
 
@@ -623,8 +623,16 @@ class PagedDecodeEngine:
         self.pool = make_backend(self.family.cache_kind, **self._pool_kwargs)
         # keys one grid step of the paged kernels spans (a lane tile: eight
         # blocks of 16), for ``kv_key_lanes`` on ``pw.round.build``
-        self._span_keys = bs * span_blocks(
-            bs, self.max_blocks_per_seq, self.pool.k.shape[-1] // self.tp)
+        lanes = self.pool.k.shape[-1] // self.tp
+        self._span_keys = bs * span_blocks(bs, self.max_blocks_per_seq, lanes)
+        # the query columns the live tiles of a mixed step's row cover, by
+        # its valid columns 0 .. chunk (the kernels' own tile rule, on a
+        # shard's query heads and pool lanes; a latent pool, which has no V,
+        # is attended in pieces), for ``kv_query_tile_cols``
+        self._query_tile_cols = query_tile_columns(
+            np.arange(self.prefill_chunk + 1), self.prefill_chunk,
+            cfg.n_heads // self.tp, self._pool_kwargs["head_dim"], lanes,
+            self._pool_kwargs["dtype"], latent=self.pool.v is None)
         # a cache that cannot share blocks (the hybrid one: a shared block
         # would skip the tokens that build the conv state) runs without a
         # prefix cache, whatever was asked for
@@ -2047,6 +2055,17 @@ class PagedDecodeEngine:
             ph.set(kv_window_keys=seen, kv_window_ctx_keys=keys)
             self.pool.stats.record_window_keys(seen, keys)
 
+    def _note_query_cols(self, ph, row_nvalid) -> None:
+        """What a mixed round's calls of the ragged kernels run, on
+        ``pw.round.build``: ``kv_query_cols``, the live query columns of
+        ALL the step's rows (an idle row has one: the kernel runs it), and
+        ``kv_query_tile_cols``, the columns their live tiles cover
+        (:func:`query_tile_columns`).  A decode step's or a chain's rows are
+        one column and one tile each: nothing to count."""
+        ph.set(kv_query_cols=int(row_nvalid.sum()),
+               kv_query_tile_cols=int(
+                   self._query_tile_cols[row_nvalid].sum()))
+
     def _note_write_blocks(self, ph, slot_blocks) -> None:
         """``kv_write_blocks`` on a mixed round's ``pw.round.build`` and in
         the pool's counters: the distinct pool blocks the round's tokens
@@ -2265,6 +2284,7 @@ class PagedDecodeEngine:
         self._note_keys(ph, [int(c) for c in
                              row_start[:row] + row_nvalid[:row]],
                         [int(q) for q in row_nvalid[:row]])
+        self._note_query_cols(ph, row_nvalid)
         self._note_write_blocks(ph, sb[:t])
         runs = row_nvalid[:row]
         self._note_state(

@@ -48,9 +48,37 @@ it (:func:`_first_span`, :func:`_next_span`).  A table's entries behind the
 window may point at the null block (kvcache/windowed.py frees those
 blocks); what a live span still gathers of them is masked.  Where a head's
 folded query rows are many (eight query heads a K/V head at a chunk of
-256: 2,048 rows), a span's update runs in tiles of :data:`_ROW_TILE` rows
-and skips the tiles past a row's valid columns (:func:`_row_tile`), and the
-call asks for the VMEM its scratch needs (:func:`_vmem_limit`).
+256: 2,048 rows), the call asks for the VMEM its scratch needs
+(:func:`_vmem_limit`).
+
+The query-column tiles (PR 37): a mixed step hands the ragged kernels
+sixteen rows at the chunk's width, of which about two carry a chunk, five
+one decoded token and nine nothing (an idle row: context 1 on the null
+block), and a row used to pay for the chunk's width whatever it held
+(only one head a group ran in row tiles, and only a span's update).  Now
+a row pays for its LIVE query columns (``cl - c0 + 1``, from the
+scalar-prefetched ``c0`` / ``cl``: no new operand), in every head grouping
+and in all three per-row pieces: the layout and reset of
+:func:`_start_row`, a span's update in :func:`_attend_span`, the division
+and write of :func:`_write_out`.  The tile is a rule of shapes
+(:func:`_col_tiles`: the heads a group, the folded columns, ``rep``, the
+query dtype's sublane tile; no argument, no model's name): a row of no
+more than ``first`` live folded columns (8 at two heads a group, a sublane
+tile at one: a decode row, an idle row, a chunk's short tail) runs one tile
+of ``first`` columns; a longer one runs the :data:`_ROW_TILE`-row tiles its
+live columns reach into, in ONE loop (:func:`_live_tiles`), so that the
+kernel's code does not grow with the chunk.  An idle row is not skipped:
+it costs its first tile over one span, and the double buffer's order of
+copies stays what it was.  The columns past a row's live tiles are not
+computed; the output block is written as zeros there (padding no caller
+reads: the step programs gather ``a_rows[tok_row, tok_col]``).  One query
+column a row (``C == rep``: the append kernels of the chains and the decode
+step) is ONE tile, by the same code (``body(0, C)``, static): those kernels
+trace to the jaxpr they traced to before.  So is a width that is no whole
+sublane tiles of the query dtype (a verify round's ``k + 1`` columns: 40
+folded ones at eight query heads a K/V head), which runs as it ran before.
+The engine counts how full the tiles run from the same rule
+(:func:`query_tile_columns`: ``kv_query_tile_cols`` on ``pw.round.build``).
 
 Round-8 raggedness (the fused mixed decode/prefill step):
 
@@ -64,6 +92,11 @@ Round-8 raggedness (the fused mixed decode/prefill step):
   (``@pl.when`` guards), and the output is written at the row's LAST
   VALID span instead of the grid edge - a 1-block row in a 64-block
   table costs one step of work, not eight.
+- a row is width-aware too (PR 37, above): a live span's work is the
+  row's live column tiles, not the chunk's ``C`` columns - a decode row
+  beside a chunk row pays one tile of 8 or 16 folded columns a span, the
+  columns past ``n_valid`` in a live tile are computed with a clamped
+  context and dropped by the caller, the tiles past them are not computed.
 
 Contract: every row must attend to AT LEAST one token
 (``context_lens >= C`` in the consecutive form, ``start_pos >= 0`` and
@@ -107,7 +140,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e9
 _LANES = 128  # keys one grid step attends: a lane tile of scores
-_ROW_TILE = 256  # query rows an update takes where a head has more
+_ROW_TILE = 256  # query rows (heads a group x columns) a column tile takes
 
 
 def _query_context(C: int, context_lens, start_pos, n_valid):
@@ -221,77 +254,250 @@ def _heads_per_group(H: int, hd: int, C: int) -> int:
 
 def _own_lanes(R: int, W: int, C: int, hd: int):
     """(R, W) bool: lane belongs to the head of its row (row i*C + c of
-    a group is head i, whose lanes are [i*hd, (i+1)*hd))."""
+    a group, or of a column tile of ``C`` columns, is head i, whose lanes
+    are [i*hd, (i+1)*hd))."""
     return (jax.lax.broadcasted_iota(jnp.int32, (R, W), 0) // C
             == jax.lax.broadcasted_iota(jnp.int32, (R, W), 1) // hd)
 
 
-def _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, *, C: int, G: int,
-               hd: int):
+def _sublanes(dtype) -> int:
+    """Rows of one sublane tile of ``dtype``: 8 of f32, 16 of bf16."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def _col_tiles(G: int, C: int, rep: int, dtype) -> tuple | None:
+    """The column tiles a group's ``C`` folded query columns lie in:
+    ``(first, tile)`` columns, or None where the row is ONE tile (the
+    group's ``G * C`` rows as one update, head after head).  A rule of
+    shapes alone:
+
+    - one query column a row (``C == rep``: the append kernels of the chains
+      and the decode step) is one tile;
+    - ``tile`` columns are :data:`_ROW_TILE` rows of the group (128 columns
+      at two heads a group, 256 at one), for the MXU; a row with more than
+      ``first`` live columns runs the ``tile``-wide tiles its live columns
+      reach into, in a loop;
+    - ``first`` is the smallest tile the layout allows: every head's piece
+      whole f32 sublane tiles (the softmax state is f32), the tile's rows
+      whole sublane tiles of the query dtype, whole query columns (``rep``
+      folded ones each): 8 columns at two heads a group, 16 folded columns
+      at one.  A row with no more live columns than that (a decode row, an
+      idle row of a mixed step, a chunk's short tail) runs that tile alone.
+
+    A width that is no whole sublane tiles of the query dtype (a verify
+    round's ``k + 1`` columns: a tile's queries come in whole sublane tiles,
+    which must lie inside the block) or that ``tile`` does not divide is one
+    tile."""
+    sub = _sublanes(dtype)
+    first = math.lcm(8, sub // math.gcd(sub, G), rep)
+    unit = math.lcm(first, sub)
+    tile = min(max(_ROW_TILE // G // unit, 1) * unit, C)
+    if C == rep or C % sub or C % tile or first >= tile:
+        return None
+    return first, tile
+
+
+def _wide_tiles(live, first: int, tile: int):
+    """How many ``tile``-wide tiles a row of ``live`` folded live columns
+    runs: those that start under ``live``, or none where the ``first`` tile
+    holds them all.  Operators alone, so that the kernels' traced scalars
+    (:func:`_live_tiles`) and the engine's arrays
+    (:func:`query_tile_columns`) walk ONE rule."""
+    return (live > first) * ((live + tile - 1) // tile)
+
+
+def _live_tiles(tiles: tuple | None, live, C: int, body) -> None:
+    """``body(t0, Tc)`` for the column tiles of a row of ``C`` folded
+    columns, ``live`` of them live (at least one): the ``first`` tile alone,
+    or the ``tile``-wide ones that start under ``live``, one loop whose body
+    does not grow with their number; without ``tiles`` the row is one tile,
+    ``body(0, C)``, and nothing else is traced.  A tile ``[t0, t0 + Tc)``
+    holds every group's rows of its columns, group after group, head i of a
+    group at ``i*Tc`` within the group's (:func:`_group`): the two modes lay
+    a row's first columns out differently, and every per-row piece of a
+    kernel (:func:`_start_row`, :func:`_attend_span`, :func:`_write_out`)
+    takes the mode from the same ``live``."""
+    if tiles is None:
+        body(0, C)
+        return
+    first, tile = tiles
+    wide = _wide_tiles(live, first, tile)
+    pl.when(wide == 0)(lambda: body(0, first))
+
+    def step(t, carry):
+        body(pl.multiple_of(t * tile, tile), tile)
+        return carry
+
+    jax.lax.fori_loop(0, wide, step, 0)
+
+
+def _each_group(n_groups: int, tiles: tuple | None, fn) -> None:
+    """``fn(g)`` for every group of heads.  One tile a row: group after
+    group, as the code stands.  A tiled row: in two turns of half the groups
+    each: within a turn the groups' updates stand side by side in the code
+    and are scheduled together (each is a chain of a matmul, a softmax step
+    and a matmul); the two turns are one loop.  A tiled kernel holds two
+    tile bodies (:func:`_live_tiles`) where a one-tile kernel holds one, and
+    the kernel is traced and lowered anew in every process whatever the
+    compile cache holds: with the turns it traces as many group bodies as
+    the one-tile kernel did, and the program's set-up time stays what it was.
+    A DEBT, not a design (ROADMAP A9 (7), "lower a step program once"): the
+    turns cost the overlap between groups (~1 ms a mixed round of
+    ``serve_closed16``), and an odd number of groups is unrolled, at twice
+    the one-tile kernel's bodies.  When a step program is lowered once a
+    process, unroll every group and drop the traced ``g`` of
+    :func:`_group`."""
+    if tiles is None or n_groups % 2:
+        for g in range(n_groups):
+            fn(g)
+        return
+    turn = n_groups // 2
+
+    def step(k, carry):
+        for i in range(turn):
+            fn(k * turn + i)
+        return carry
+
+    jax.lax.fori_loop(0, 2, step, 0)
+
+
+def _rows(start, n: int):
+    """``n`` rows from ``start``, a multiple of ``n`` (said where traced)."""
+    return pl.ds(start if isinstance(start, int)
+                 else pl.multiple_of(start, n), n)
+
+
+def _group(g, n_groups: int, G: int, W: int, t0, Tc: int) -> tuple:
+    """(rows, lanes) of group ``g`` in the tile of ``Tc`` columns at column
+    ``t0``: the scratch rows lie (tile, group, head, column in tile), so a
+    tile's rows of all groups are one slice (``_rows(n_groups * G * t0,
+    n_groups * G * Tc)``: what :func:`_start_row` resets) and a one-tile
+    row's are the whole scratch, group ``g`` at ``g * G * C``."""
+    n = G * Tc
+    lanes = slice(g * W, (g + 1) * W) if isinstance(g, int) \
+        else pl.ds(pl.multiple_of(g * W, W), W)
+    return _rows(n_groups * G * t0 + g * n, n), lanes
+
+
+def _live_columns(b, c0_ref, cl_ref, rep: int, tiles: tuple | None):
+    """Row ``b``'s live folded query columns (its valid columns, the last of
+    which attends ``cl`` keys and the first ``c0``, times ``rep``), where
+    its rows lie in column ``tiles``; None, and nothing traced, where the
+    row is one tile (those kernels stay the jaxpr they were)."""
+    if tiles is None:
+        return None
+    return (cl_ref[b] - c0_ref[b] + 1) * rep
+
+
+def query_tile_columns(n_valid, C: int, H: int, hd: int, D: int, dtype,
+                       latent: bool = False):
+    """The query columns the kernels' live tiles cover for rows of
+    ``n_valid`` live columns each (an idle row has one) of ``C``, ``H``
+    query heads of ``hd`` over a pool of ``D`` lanes, queries in ``dtype``
+    (``latent``: through :func:`latent_attention`, a row in pieces): the
+    rule of :func:`_col_tiles` as :func:`_live_tiles` runs it
+    (:func:`_wide_tiles`), for the engine's ``kv_query_tile_cols``.
+    (len(n_valid),) int64."""
+    n = np.asarray(n_valid, np.int64)
+    pieces = _latent_pieces(C) if latent else 1
+    C //= pieces
+    rep = H * hd // D
+    tiles = _col_tiles(_heads_per_group(D // hd, hd, C * rep), C * rep, rep,
+                       dtype)
+    # a piece's live columns; a piece past the row's valid ones runs one
+    live = np.clip(n[:, None] - C * np.arange(pieces)[None, :], 1, C) * rep
+    if tiles is None:
+        run = np.full_like(live, C * rep)
+    else:
+        first, tile = tiles
+        wide = _wide_tiles(live, first, tile)
+        run = np.where(wide == 0, first, wide * tile)
+    return run.sum(axis=1) // rep
+
+
+def _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, live=None, *, C: int,
+               G: int, hd: int, tiles: tuple | None = None):
     """First grid step of a batch row: reset the online softmax and lay
-    the row's queries out for the grouped matmuls.  ``qm_ref`` row
-    ``h*C + c`` holds query column c of head h in the lanes of h's place
-    in its group and zeros in the other heads' lanes, so that
-    ``qm[group rows] @ k[:, group lanes].T`` is every head's own scores
-    (a zero lane adds an exact 0 to the f32 sum)."""
-    m_ref[:] = jnp.full_like(m_ref, _NEG)
-    l_ref[:] = jnp.zeros_like(l_ref)
-    acc_ref[:] = jnp.zeros_like(acc_ref)
-    R, W = G * C, G * hd
-    own = _own_lanes(R, W, C, hd)
-    for g in range(qm_ref.shape[0] // R):
-        # in f32, whose tiles the mask shares (a bf16 select against an
-        # f32-tiled mask is a relayout Mosaic refuses); the way back is exact
-        qg = q_ref[:, g * W:(g + 1) * W].astype(jnp.float32)  # (C, W)
-        qg = jnp.broadcast_to(qg, (R, W)) if C == 1 \
-            else jnp.concatenate([qg] * G, axis=0)
-        qm_ref[g * R:(g + 1) * R, :] = jnp.where(own, qg, 0.0).astype(
-            qm_ref.dtype)
+    the row's queries out for the grouped matmuls, for the row's live
+    column tiles (:func:`_live_tiles`; one tile of ``C`` columns without
+    ``tiles``).  A tile's ``qm_ref`` row ``i*Tc + c`` of a group
+    (:func:`_group`) holds query column ``t0 + c`` of the group's head i in
+    the lanes of i's place in its group and zeros in the other heads' lanes,
+    so that ``qm[group rows] @ k[:, group lanes].T`` is every head's own
+    scores (a zero lane adds an exact 0 to the f32 sum).  The rows of the
+    tiles that are not live keep what they held, and nothing reads them."""
+    W, n_groups = G * hd, qm_ref.shape[0] // (G * C)
+    sub = _sublanes(q_ref.dtype)
 
+    def lay(t0, Tc: int):
+        n = G * Tc
+        tile = _rows(n_groups * G * t0, n_groups * n)  # every group's rows
+        m_ref[tile] = jnp.full((n_groups * n, m_ref.shape[1]), _NEG,
+                               m_ref.dtype)
+        l_ref[tile] = jnp.zeros((n_groups * n, l_ref.shape[1]), l_ref.dtype)
+        acc_ref[tile] = jnp.zeros((n_groups * n, W), acc_ref.dtype)
+        own = _own_lanes(n, W, Tc, hd)
+        # whole sublane tiles of the query dtype come in (they lie inside
+        # the block: _col_tiles); the first tile's columns are cut from them
+        cols = pl.ds(t0, min(-(-Tc // sub) * sub, C))
 
-def _row_tile(R: int, G: int) -> int:
-    """Query rows one update of :func:`_attend_span` takes.  All of a
-    group's rows, unless the group is one head with many folded query
-    columns (eight query heads a K/V head at a chunk of 256: 2,048 rows):
-    then tiles of :data:`_ROW_TILE`, so that a row with few live columns
-    (a decode row of a mixed step) pays for its first tile alone."""
-    if G == 1 and R > _ROW_TILE and R % _ROW_TILE == 0:
-        return _ROW_TILE
-    return R
+        def group(g):
+            rows, lanes = _group(g, n_groups, G, W, t0, Tc)
+            # in f32, whose tiles the mask shares (a bf16 select against an
+            # f32-tiled mask is a relayout Mosaic refuses); the way back is
+            # exact
+            qg = q_ref[cols, lanes].astype(jnp.float32)
+            if qg.shape[0] > Tc:
+                qg = qg[:Tc]
+            qg = jnp.broadcast_to(qg, (n, W)) if Tc == 1 \
+                else jnp.concatenate([qg] * G, axis=0)
+            qm_ref[rows, :] = jnp.where(own, qg, 0.0).astype(qm_ref.dtype)
+
+        _each_group(n_groups, tiles, group)
+
+    _live_tiles(tiles, live, C, lay)
 
 
 def _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref, acc_ref,
-                 *, scale: float, rep: int, C: int, G: int, hd: int,
-                 window: int | None = None):
+                 live=None, *, scale: float, rep: int, C: int, G: int,
+                 hd: int, window: int | None = None,
+                 tiles: tuple | None = None):
     """One visible span's online-softmax update, shared by both kernels:
     the ``span`` keys (a lane tile's worth: K blocks of the pool) that grid
     step ``j`` attends, so that scores, mask, ``exp`` and row sums fill the
     lanes of their registers and ``p @ v`` contracts over a whole tile.
     ``kbuf[slot]`` / ``vbuf[slot]``: the span's (span, H*hd) K / V - the
     pool's blocks as they lie in HBM, one under the other.
-    Per group of G heads: scores (G*C, span) of the group's stacked query
-    rows against the group's lanes of K, each row's own softmax
-    recurrence (m, l in f32), and ``p @ v`` over the group's lanes of V
-    into acc (G*C, G*hd) f32 - of which row ``i*C + c`` is read only in
-    head i's lanes (:func:`_write_out`).  ``rep`` > 1 (grouped queries):
-    the ``rep`` query heads of a K/V head ride as ``rep`` neighbouring
-    columns of it, so of the C columns here column ``c`` is query column
-    ``c // rep``.  ``window``: a column of context ``n`` (position
-    ``n - 1``) sees the keys at ``n - window <= j < n`` only."""
-    R, W, span = G * C, G * hd, kbuf.shape[1]
-    tile = _row_tile(R, G)
+    Per LIVE column tile of the row (:func:`_live_tiles`; one tile of ``C``
+    columns without ``tiles``) and group of G heads: scores (G*Tc, span) of
+    the group's stacked query rows against the group's lanes of K, each
+    row's own softmax recurrence (m, l in f32), and ``p @ v`` over the
+    group's lanes of V into acc (G*Tc, G*hd) f32 - of which row ``i*Tc + c``
+    is read only in head i's lanes (:func:`_write_out`).  So a row pays for
+    its live query columns and not for the chunk's width; the columns past
+    them are padding whose output the caller drops.  ``rep`` > 1 (grouped
+    queries): the ``rep`` query heads of a K/V head ride as ``rep``
+    neighbouring columns of it, so of the C columns here column ``c`` is
+    query column ``c // rep``.  ``window``: a column of context ``n``
+    (position ``n - 1``) sees the keys at ``n - window <= j < n`` only."""
+    W, span = G * hd, kbuf.shape[1]
+    n_groups = qm_ref.shape[0] // (G * C)
 
-    def mask(r0: int, n: int):
-        rows_i = jax.lax.broadcasted_iota(jnp.int32, (n, span), 0)
-        if r0:
-            rows_i = rows_i + r0
+    def mask(t0, Tc: int):
+        n = G * Tc
         k_pos = j * span + jax.lax.broadcasted_iota(jnp.int32, (n, span), 1)
-        # column c attends to min(c0 + c, ctx) tokens; row i*C + c is
-        # column c
+        # column c attends to min(c0 + c, ctx) tokens; row i*Tc + c of a
+        # group's tile is column t0 + c
         if C == rep:  # one query column a row (decode)
             col = 0
         else:
-            col = rows_i % C if rep == 1 else (rows_i % C) // rep
+            col = jax.lax.broadcasted_iota(jnp.int32, (n, span), 0)
+            if G > 1:
+                col = col % Tc
+            if not isinstance(t0, int) or t0:
+                col = col + t0
+            if rep > 1:
+                col = col // rep
         col_ctx = jnp.minimum(c0 + col, ctx)
         valid = k_pos < col_ctx
         if window is not None:
@@ -324,38 +530,40 @@ def _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref, acc_ref,
         )
         m_ref[rows] = jnp.broadcast_to(m_new, (n, m_ref.shape[1]))
 
-    if tile == R:
-        valid = mask(0, R)
-        for g in range(qm_ref.shape[0] // R):
-            update(slice(g * R, (g + 1) * R), slice(g * W, (g + 1) * W),
-                   valid)
-        return
-    # one head a group, its folded query columns in tiles: the columns past
-    # the row's valid ones are padding whose output the caller drops, so a
-    # tile wholly past them is skipped (its rows keep acc = l = 0)
-    live = (ctx - c0 + 1) * rep
-    for r0 in range(0, R, tile):
-        @pl.when(r0 < live)
-        def _tile(r0=r0):
-            valid = mask(r0, tile)
-            for g in range(qm_ref.shape[0] // R):
-                update(slice(g * R + r0, g * R + r0 + tile),
-                       slice(g * W, (g + 1) * W), valid)
+    def attend(t0, Tc: int):
+        valid = mask(t0, Tc)
+        _each_group(n_groups, tiles, lambda g: update(
+            *_group(g, n_groups, G, W, t0, Tc), valid))
+
+    _live_tiles(tiles, live, C, attend)
 
 
-def _write_out(o_ref, l_ref, acc_ref, *, C: int, G: int, hd: int):
-    """o (C, H*hd): each head's lanes from its own rows of acc / l."""
-    R, W = G * C, G * hd
-    own = _own_lanes(R, W, C, hd)
-    for g in range(acc_ref.shape[0] // R):
-        rows = slice(g * R, (g + 1) * R)
-        o = jnp.where(
-            own, acc_ref[rows] / jnp.maximum(l_ref[rows, :1], 1e-20), 0.0
-        )
-        # one head's rows are non-zero in a lane: the sum picks them
-        o = jnp.sum(o, axis=0, keepdims=True) if C == 1 \
-            else sum(o[i * C:(i + 1) * C] for i in range(G))
-        o_ref[:, g * W:(g + 1) * W] = o.astype(o_ref.dtype)
+def _write_out(o_ref, l_ref, acc_ref, live=None, *, C: int, G: int, hd: int,
+               tiles: tuple | None = None):
+    """o (C, H*hd): each head's lanes from its own rows of acc / l, for the
+    row's live column tiles (:func:`_live_tiles`); with ``tiles`` the
+    columns past them are written as zeros (padding the caller drops, which
+    has to be finite)."""
+    W, n_groups = G * hd, acc_ref.shape[0] // (G * C)
+    if tiles is not None:
+        o_ref[:] = jnp.zeros_like(o_ref)
+
+    def write(t0, Tc: int):
+        own = _own_lanes(G * Tc, W, Tc, hd)
+
+        def group(g):
+            rows, lanes = _group(g, n_groups, G, W, t0, Tc)
+            o = jnp.where(
+                own, acc_ref[rows] / jnp.maximum(l_ref[rows, :1], 1e-20), 0.0
+            )
+            # one head's rows are non-zero in a lane: the sum picks them
+            o = jnp.sum(o, axis=0, keepdims=True) if Tc == 1 \
+                else sum(o[i * Tc:(i + 1) * Tc] for i in range(G))
+            o_ref[pl.ds(t0, Tc), lanes] = o.astype(o_ref.dtype)
+
+        _each_group(n_groups, tiles, group)
+
+    _live_tiles(tiles, live, C, write)
 
 
 def _span_copies(pools, bufs, sem, slot, block_of, *, K: int,
@@ -462,10 +670,11 @@ def _paged_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, k_in, v_in, o_ref,
     no copy is started, so they cost an empty grid step."""
     b = pl.program_id(0)
     j = pl.program_id(1)
+    live = _live_columns(b, c0_ref, cl_ref, rep, geom["tiles"])
 
     @pl.when(j == 0)
     def _init():
-        _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, **geom)
+        _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, live, **geom)
 
     c0 = c0_ref[b]       # column 0's context length
     ctx = cl_ref[b]      # the row's full context (last valid column's)
@@ -478,14 +687,15 @@ def _paged_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, k_in, v_in, o_ref,
                           (k_in, v_in), (kbuf, vbuf), sem, n_ref, K=K,
                           block_size=block_size, window=window)
         _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref,
-                     acc_ref, scale=scale, rep=rep, window=window, **geom)
+                     acc_ref, live, scale=scale, rep=rep, window=window,
+                     **geom)
 
     # write at the row's LAST VALID span, not the grid edge: later grid
     # steps touch nothing, and the (per-row) output block flushes when
     # the grid leaves row b
     @pl.when(j == jlast)
     def _final():
-        _write_out(o_ref, l_ref, acc_ref, **geom)
+        _write_out(o_ref, l_ref, acc_ref, live, **geom)
 
 
 def _scratch(K: int, BS: int, D: int, pool_dtype, H: int, C: int, hd: int,
@@ -592,7 +802,7 @@ def _paged_ragged_fn(q, k_pool, v_pool, layer, block_tables, c0, cl, *,
     G = _heads_per_group(H, hd, C)
     kernel = functools.partial(
         _paged_kernel, K=K, block_size=BS, scale=1.0 / np.sqrt(d_true),
-        rep=rep, C=C, G=G, hd=hd,
+        rep=rep, C=C, G=G, hd=hd, tiles=_col_tiles(G, C, rep, q.dtype),
         **({} if window is None else {"window": int(window)}),
     )
     row = pl.BlockSpec((None, C, D), lambda b, j, *_: (b, 0, 0))
@@ -843,6 +1053,13 @@ _paged_write = jax.jit(_paged_write_fn, static_argnames=("interpret",),
 _LATENT_COLS = 64  # query columns a kernel row takes of a longer chunk
 
 
+def _latent_pieces(C: int) -> int:
+    """The kernel rows :func:`latent_attention` cuts a row of ``C`` query
+    columns into."""
+    return C // _LATENT_COLS if C > _LATENT_COLS and C % _LATENT_COLS == 0 \
+        else 1
+
+
 def _latent_scratch(K: int, BS: int, W: int, pool_dtype, R: int, dtype):
     return [
         pltpu.VMEM((2, K * BS, W), pool_dtype),    # the span, and the next
@@ -862,10 +1079,11 @@ def _latent_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, c_in, o_ref, cbuf,
     key and value both."""
     b = pl.program_id(0)
     j = pl.program_id(1)
+    live = _live_columns(b, c0_ref, cl_ref, rep, geom["tiles"])
 
     @pl.when(j == 0)
     def _init():
-        _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, **geom)
+        _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, live, **geom)
 
     c0 = c0_ref[b]
     ctx = cl_ref[b]
@@ -877,11 +1095,11 @@ def _latent_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, c_in, o_ref, cbuf,
                           (c_in,), (cbuf,), sem, n_ref, K=K,
                           block_size=block_size)
         _attend_span(j, c0, ctx, cbuf, cbuf, slot, qm_ref, m_ref, l_ref,
-                     acc_ref, scale=scale, rep=rep, **geom)
+                     acc_ref, live, scale=scale, rep=rep, **geom)
 
     @pl.when(j == jlast)
     def _final():
-        _write_out(o_ref, l_ref, acc_ref, **geom)
+        _write_out(o_ref, l_ref, acc_ref, live, **geom)
 
 
 def _paged_latent_fn(q, pool, layer, block_tables, c0, cl, *, scale: float,
@@ -897,7 +1115,8 @@ def _paged_latent_fn(q, pool, layer, block_tables, c0, cl, *, scale: float,
     qf, rep = _fold_queries(q, W)
     R = qf.shape[1]
     kernel = functools.partial(_latent_kernel, K=K, block_size=BS,
-                               scale=scale, rep=rep, C=R, G=1, hd=W)
+                               scale=scale, rep=rep, C=R, G=1, hd=W,
+                               tiles=_col_tiles(1, R, rep, q.dtype))
     row = pl.BlockSpec((None, R, W), lambda b, j, *_: (b, 0, 0))
     out = pl.pallas_call(
         kernel,
@@ -1152,7 +1371,7 @@ def latent_attention(q, pool, block_tables, context_lens=None, *,
     _require_positive_context(C, context_lens, start_pos, n_valid)
     c0, cl = _query_context(C, context_lens, start_pos, n_valid)
     tables = jnp.asarray(block_tables, jnp.int32)
-    n = C // _LATENT_COLS if C > _LATENT_COLS and C % _LATENT_COLS == 0 else 1
+    n = _latent_pieces(C)
     if n > 1:
         # piece i of a row: its columns from i * _LATENT_COLS on; one past
         # the row's valid columns attends one key

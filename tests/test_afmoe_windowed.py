@@ -474,7 +474,7 @@ def test_paged_kernels_mask_and_skip_behind_the_window(C, window):
     interpreted kernels against the gather reference, with a window that is
     a whole span of 128 keys and one that is not even whole blocks; rows
     that start inside the first span, behind one dead span and behind two.
-    64 query columns x 8 folded heads take the row tiles."""
+    64 query columns x 8 folded heads take two column tiles."""
     import jax.numpy as jnp
 
     pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
@@ -489,8 +489,10 @@ def test_paged_kernels_mask_and_skip_behind_the_window(C, window):
     q = jnp.asarray(rng.standard_normal((B, C, KV * rep, hd)), jnp.float32)
     start = np.array([0, 150, 300], np.int32)
     nv = np.array([C, max(C // 2, 1), 1], np.int32)
-    if C > 1:
-        assert pa._row_tile(C * rep, 1) == (256 if C == 64 else C * rep)
+    # one K/V head a group: a first tile of one query column's eight folded
+    # heads, wide tiles of 256 rows where they divide the folded columns
+    assert pa._col_tiles(1, C * rep, rep, jnp.float32) \
+        == {1: None, 4: (8, 32), 40: None, 64: (8, 256)}[C]
     want = pa.paged_attention_reference(q, kp, vp, bt, start_pos=start,
                                         n_valid=nv, window=window)
     got = pa.paged_attention(q, kp, vp, bt, start_pos=start, n_valid=nv,

@@ -403,16 +403,18 @@ def test_grouped_matmul_kernel_compiles_at_the_published_experts(shape,
 def test_paged_kernels_compile_at_trinity_spans(shape, window):
     """Both paged kernels at Trinity-Mini's geometry: a head is a whole
     lane tile, the eight query heads of a K/V head fold into 2,048 query
-    rows a chunk of 256 (in tiles of 256, under a VMEM limit the call asks
-    for), and with a window the spans behind it are dead grid steps."""
+    rows a chunk of 256 (in column tiles of 256 rows, under a VMEM limit the
+    call asks for), and with a window the spans behind it are dead grid steps."""
     import jax.numpy as jnp
 
     pa = _paged()
     kv, rep, hd, chunk, tables, i32 = 4, 8, 128, 256, 512, jnp.int32
     blocks = 8193 if window is None else 16 * 146 + 1
     assert pa.span_blocks(BS, tables, kv * hd) == 128 // BS
-    assert pa._row_tile(chunk * rep, pa._heads_per_group(kv, hd, chunk * rep)
-                        ) == 256
+    # one K/V head a group: a first tile of two query columns' folded heads
+    # (a sublane tile of bf16), wide ones of 256 rows, run in one loop
+    assert pa._heads_per_group(kv, hd, chunk * rep) == 1
+    assert pa._col_tiles(1, chunk * rep, rep, jnp.bfloat16) == (16, 256)
     pool = shape((2 if window is None else 6, blocks, BS, kv * hd),
                  jnp.bfloat16)
     idx = (shape((1,), i32), shape((B, tables), i32), shape((B,), i32),
@@ -679,6 +681,11 @@ def test_ragged_kernel_compiles_at_the_chosen_chunk(shape, family):
     C = chunk * rep
     G = pa._heads_per_group(kv, HD, C)
     K = pa.span_blocks(BS, tables, kv * HD)
+    # two heads of 64 a group: a first tile of 8 folded columns (16 rows: a
+    # sublane tile of bf16) and wide ones of 128 (256 rows), whatever the
+    # chunk: the loop over a row's live tiles is 2 or 16 tiles long
+    assert G == 2 and pa._col_tiles(G, C, rep, jnp.bfloat16) == (8, 128)
+    assert C // 128 == {"decoder": 2, "lfm2": 16}[family]
     scratch = sum(
         math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
         for s in pa._scratch(K, BS, kv * HD, jnp.bfloat16, kv, C, HD, G,
@@ -691,6 +698,37 @@ def test_ragged_kernel_compiles_at_the_chosen_chunk(shape, family):
     print(f"{family}: chunk {chunk}, {C * G} rows a group, scratch "
           f"{scratch / 2 ** 20:.1f} MiB, limit {limit / 2 ** 20:.0f} MiB")
     assert scratch < limit <= 100 * 2 ** 20
+
+
+@pytest.mark.parametrize(
+    "kv,rep,hd,window,k",
+    [(20, 1, 64, None, 4), (20, 1, 64, None, 23), (8, 4, 64, None, 4),
+     (4, 8, 128, 2048, 4), (4, 8, 128, None, 4)],
+    ids=["gpt2_k4", "gpt2_k23", "lfm2_k4", "trinity_window_k4",
+         "trinity_k4"])
+def test_ragged_kernel_compiles_at_verify_widths(shape, kv, rep, hd, window,
+                                                 k):
+    """A verify round of speculative decoding is the ragged kernel at
+    ``k + 1`` query columns: folded widths (5, 24, 20, 40) that are no whole
+    sublane tiles of bf16.  A tile's queries come in whole sublane tiles, so
+    such a row has to be one tile (``_col_tiles``: None): the compiler
+    refuses a read past the block."""
+    import jax.numpy as jnp
+
+    pa = _paged()
+    C, tables, i32 = (k + 1) * rep, 64, jnp.int32
+    assert pa._col_tiles(pa._heads_per_group(kv, hd, C), C, rep,
+                         jnp.bfloat16) is None
+    pool = shape((2, NBLK, BS, kv * hd), jnp.bfloat16)
+    idx = (shape((1,), i32), shape((B, tables), i32), shape((B,), i32),
+           shape((B,), i32))
+    kw = {} if window is None else {"window": window}
+    compiled = _compiled_kernel(
+        lambda q, kp, vp, li, bt, c0, cl: pa._paged_ragged_fn(
+            q, kp, vp, li, bt, c0, cl, d_true=hd, **kw),
+        shape((B, k + 1, kv * rep, hd), jnp.bfloat16), pool, pool, *idx,
+    )
+    assert _pool_copies(compiled, pool.shape) == []
 
 
 @pytest.mark.parametrize("family", ["decoder", "lfm2"])
@@ -772,6 +810,10 @@ def test_latent_kernels_compile_at_the_published_widths(shape):
     bf, i32 = jnp.bfloat16, jnp.int32
     pool = shape((3, 8193, BS, 640), bf)
     pieces = 16 * 512 // pa._LATENT_COLS
+    assert pa._latent_pieces(512) * 16 == pieces
+    # a piece's 64 x 32 folded rows: a first tile of one query column's 32
+    # heads, wide ones of 256 rows (eight query columns)
+    assert pa._col_tiles(1, pa._LATENT_COLS * 32, 32, bf) == (32, 256)
     import functools
 
     scaled = functools.partial

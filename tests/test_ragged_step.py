@@ -21,6 +21,7 @@ of NaNs) and the Round-8 metrics surface (prefill chunks, mixed-step
 occupancy, TTFT histogram).
 """
 
+import importlib
 import threading
 
 import jax
@@ -506,6 +507,299 @@ def test_ragged_kernel_matches_reference_interpreted(C, H, hd, BS, NB, sp,
                 np.asarray(got)[b, c], np.asarray(want)[b, c],
                 rtol=2e-5, atol=2e-5,
             )
+
+
+# -- the query-column tiles (PR 37) -------------------------------------------
+# A row of the ragged kernels pays for its live query columns: its rows lie
+# in column tiles, of which the live ones run (``_col_tiles``,
+# ``_live_tiles``).  The four cells' geometries scaled down, ONE batch a
+# case: an idle row (context 1 on the null block) at the head and in the
+# middle, a decode row deep in its sequence, a chunk's short tail inside
+# the first tile, a row whose live columns end inside a wide tile, one a
+# column past a wide tile's edge, and one that fills the chunk.
+_IDLE = (0, 1)  # start, valid columns; its table is the null block alone
+
+_TILE_CASES = {
+    # C, query heads, head_dim, rep, window, dtype, (first, tile) folded
+    "gpt2_g2_rep1": (256, 4, 64, 1, None, "float32", (8, 128)),
+    "gpt2_g2_rep1_bf16": (256, 2, 64, 1, None, "bfloat16", (8, 128)),
+    "lfm2_g2_rep4": (64, 16, 64, 4, None, "float32", (8, 128)),
+    "trinity_g1_rep8_window": (64, 8, 128, 8, 24, "float32", (8, 256)),
+    "trinity_g1_rep8_bf16": (64, 8, 128, 8, None, "bfloat16", (16, 256)),
+}
+
+
+def _tile_rows(C, rep, first, tile):
+    """(start, valid columns) a row, in query columns."""
+    first, tile = max(first // rep, 1), tile // rep
+    return [_IDLE, (200, 1), (40, first), (96, tile - 3), _IDLE,
+            (130, tile + 1), (30, C)]
+
+
+def _paged_case(rng, rows, C, H, hd, lanes, dtype, BS=16, NB=20):
+    B = len(rows)
+    q = jnp.asarray(rng.standard_normal((B, C, H, hd)), dtype)
+    tables = np.zeros((B, NB), np.int32)
+    for b, (start, n) in enumerate(rows):
+        if (start, n) != _IDLE:
+            used = -(-(start + n) // BS)
+            tables[b, :used] = 1 + b * NB + rng.permutation(NB)[:used]
+    pool = jnp.asarray(rng.standard_normal((1 + B * NB, BS, lanes)), dtype)
+    return q, pool, jnp.asarray(tables), \
+        jnp.asarray([r[0] for r in rows], jnp.int32), \
+        jnp.asarray([r[1] for r in rows], jnp.int32)
+
+
+def _assert_live_columns(got, want, rows, tile_cols, tol):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()  # the padding columns too
+    for b, (_start, n) in enumerate(rows):
+        np.testing.assert_allclose(got[b, :n], want[b, :n], rtol=tol,
+                                   atol=tol)
+        # past the row's live tiles nothing was computed: zeros
+        assert not got[b, int(tile_cols[b]):].any()
+
+
+@pytest.mark.parametrize("case", list(_TILE_CASES))
+def test_tiled_ragged_kernel_matches_reference_interpreted(case):
+    pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
+    C, H, hd, rep, window, dtype, tiles = _TILE_CASES[case]
+    dtype = jnp.dtype(dtype)
+    lanes = H // rep * hd
+    G = pa._heads_per_group(H // rep, hd, C * rep)
+    assert pa._col_tiles(G, C * rep, rep, dtype) == tiles
+    rows = _tile_rows(C, rep, *tiles)
+    rng = np.random.default_rng(11)
+    q, k_pool, tables, sp, nv = _paged_case(rng, rows, C, H, hd, lanes, dtype)
+    v_pool = jnp.asarray(rng.standard_normal(k_pool.shape), dtype)
+    kw = {} if window is None else {"window": window}
+    want = pa.paged_attention_reference(
+        q, k_pool, v_pool, tables, start_pos=sp, n_valid=nv, **kw)
+    got = pa.paged_attention(
+        q, k_pool, v_pool, tables, start_pos=sp, n_valid=nv,
+        use_pallas=True, interpret=True, **kw)
+    cols = pa.query_tile_columns(np.asarray(nv), C, H, hd, lanes, dtype)
+    assert [int(c) for c in cols] == [
+        tiles[0] // rep if n * rep <= tiles[0]
+        else -(-n * rep // tiles[1]) * tiles[1] // rep for _s, n in rows]
+    _assert_live_columns(got, want, rows, cols,
+                         3e-2 if dtype == jnp.bfloat16 else 2e-5)
+
+
+# A verify round of speculative decoding is the mixed program at ``k + 1``
+# query columns (5 at the default k): folded widths of 40 (Trinity), 20
+# (LFM2), 5 / 12 / 24 (GPT-2), which are no whole sublane tiles of bf16 and
+# seldom of f32.  Such a row is ONE tile (the interpreter does not refuse a
+# read past the block; the chip's compiler does, tests/test_chip_compile.py).
+_VERIFY_CASES = {
+    # query columns, query heads, head_dim, rep, dtype, (first, tile) | None
+    "trinity_k4_bf16": (5, 8, 128, 8, "bfloat16", None),
+    "trinity_k4_f32": (5, 8, 128, 8, "float32", (8, 40)),
+    "lfm2_k4_bf16": (5, 8, 64, 4, "bfloat16", None),
+    "lfm2_k4_f32": (5, 8, 64, 4, "float32", None),
+    "gpt2_k4_bf16": (5, 4, 64, 1, "bfloat16", None),
+    "gpt2_k11_bf16": (12, 4, 64, 1, "bfloat16", None),
+    "gpt2_k23_bf16": (24, 4, 64, 1, "bfloat16", None),
+    "gpt2_k23_f32": (24, 4, 64, 1, "float32", (8, 24)),
+}
+
+
+@pytest.mark.parametrize("case", list(_VERIFY_CASES))
+def test_ragged_kernel_matches_reference_at_verify_widths(case):
+    pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
+    C, H, hd, rep, dtype, tiles = _VERIFY_CASES[case]
+    dtype = jnp.dtype(dtype)
+    lanes = H // rep * hd
+    G = pa._heads_per_group(H // rep, hd, C * rep)
+    assert pa._col_tiles(G, C * rep, rep, dtype) == tiles
+    # a verify round's rows: idle, one proposal accepted so far, a row deep
+    # in its sequence with all k proposals, partial rows
+    rows = [_IDLE, (200, 1), (40, C), (96, 2), _IDLE, (130, C - 1), (3, C)]
+    rng = np.random.default_rng(13)
+    q, k_pool, tables, sp, nv = _paged_case(rng, rows, C, H, hd, lanes, dtype)
+    v_pool = jnp.asarray(rng.standard_normal(k_pool.shape), dtype)
+    want = pa.paged_attention_reference(
+        q, k_pool, v_pool, tables, start_pos=sp, n_valid=nv)
+    got = pa.paged_attention(
+        q, k_pool, v_pool, tables, start_pos=sp, n_valid=nv,
+        use_pallas=True, interpret=True)
+    cols = pa.query_tile_columns(np.asarray(nv), C, H, hd, lanes, dtype)
+    assert (cols >= np.asarray(nv)).all() and (cols <= C).all()
+    _assert_live_columns(got, want, rows, cols,
+                         3e-2 if dtype == jnp.bfloat16 else 2e-5)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "G,rep", [(2, 1), (2, 4), (1, 8), (1, 32), (5, 1)],
+    ids=["g2_rep1", "g2_rep4", "g1_rep8", "g1_rep32", "tp_shard_g5"])
+def test_col_tiles_keep_every_read_inside_the_block(G, rep, dtype):
+    """The rule of shapes over every width up to a chunk of 640 query
+    columns: where it tiles a row, every slice the kernels take lies inside
+    its block and starts where ``pl.multiple_of`` says it does."""
+    pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
+    dtype = jnp.dtype(dtype)
+    sub = pa._sublanes(dtype)
+    tiled = 0
+    for C in range(rep, 640 * rep + 1, rep):
+        tiles = pa._col_tiles(G, C, rep, dtype)
+        if C == rep:
+            assert tiles is None  # one query column a row: the decode path
+        if tiles is None:
+            continue
+        tiled += 1
+        first, tile = tiles
+        assert first < tile <= C and G * tile <= max(256, G * first * 2)
+        for Tc in (first, tile):
+            # the queries come in whole sublane tiles, inside the block
+            assert -(-Tc // sub) * sub <= C and Tc % rep == 0
+            # a tile's rows: whole f32 and query-dtype sublane tiles, at a
+            # multiple of their number; its columns divide the row
+            assert (G * Tc) % sub == 0 and Tc % 8 == 0 and C % Tc == 0
+        assert tile % sub == 0 or tile == C and C % sub == 0
+    assert tiled
+
+
+@pytest.mark.parametrize("C", [64, 128], ids=["one_piece", "two_pieces"])
+def test_tiled_latent_kernel_matches_reference_interpreted(C):
+    """The latent form: one stored row of 128 lanes is key and value, the
+    eight query heads folded on it (G = 1); a chunk of 128 goes in two
+    pieces of 64 query columns, each a kernel row with tiles of its own."""
+    pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
+    H, W, tiles = 8, 128, (8, 256)
+    piece = C // pa._latent_pieces(C)
+    assert pa._col_tiles(1, piece * H, H, jnp.float32) == tiles
+    rows = [_IDLE, (200, 1), (40, 1), (96, piece - 3), _IDLE,
+            (130, piece // 2 + 1), (30, C)]
+    rng = np.random.default_rng(12)
+    q, pool, tables, sp, nv = _paged_case(rng, rows, C, H, W, W, jnp.float32)
+    want = pa.latent_attention_reference(
+        q, pool, tables, start_pos=sp, n_valid=nv, scale=0.09)
+    got = pa.latent_attention(
+        q, pool, tables, start_pos=sp, n_valid=nv, scale=0.09,
+        use_pallas=True, interpret=True)
+    _assert_live_columns(got, want, rows, [C] * len(rows), 2e-5)
+    # a row's pieces: its live columns' tiles, one first tile a dead piece
+    cols = pa.query_tile_columns(np.asarray(nv), C, H, W, W, jnp.float32,
+                                 latent=True)
+    dead = (C // piece - 1) * (tiles[0] // H)
+    assert [int(c) for c in cols][:3] == [1 + dead] * 3
+    assert int(cols[-1]) == C
+
+
+@pytest.mark.parametrize(
+    "C,H,hd,D,dtype,latent,at",
+    [(256, 20, 64, 1280, "bfloat16", False, {1: 8, 8: 8, 9: 128, 133: 256}),
+     (512, 32, 64, 512, "bfloat16", False, {1: 2, 2: 2, 3: 32, 214: 224}),
+     (256, 32, 128, 512, "bfloat16", False, {1: 2, 3: 32, 33: 64}),
+     (512, 32, 640, 640, "bfloat16", True,
+      {1: 8, 2: 15, 64: 71, 65: 71, 512: 512}),
+     (32, 2, 64, 128, "float32", False, {1: 8, 9: 32}),
+     (1, 20, 64, 1280, "bfloat16", False, {1: 1}),
+     (1, 32, 64, 512, "bfloat16", False, {1: 1}),
+     (40, 8, 128, 128, "float32", False, {1: 40, 40: 40})],
+    ids=["gpt2_large_256", "lfm2_512", "trinity_256", "kimi_512", "toy_f32",
+         "decode_row", "decode_row_folded", "width_no_tile_divides"],
+)
+def test_query_tile_columns_walks_the_kernels_tiles(C, H, hd, D, dtype,
+                                                    latent, at):
+    """``query_tile_columns`` (the engine's ``kv_query_tile_cols``) at the
+    cells' published geometries: never under the live columns, never over
+    the chunk, the whole chunk for a full row, and the readings a walk of
+    ``_col_tiles`` by hand gives."""
+    pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
+    ns = np.arange(1, C + 1)
+    got = pa.query_tile_columns(ns, C, H, hd, D, jnp.dtype(dtype),
+                                latent=latent)
+    assert (got >= ns).all() and (got <= C).all() and int(got[-1]) == C
+    assert (np.diff(got) >= 0).all()
+    assert {n: int(got[n - 1]) for n in at} == at
+
+
+@pytest.mark.parametrize("rep,hd", [(1, 64), (4, 64), (8, 128)],
+                         ids=["g2_rep1", "g2_rep4", "g1_rep8"])
+def test_tiled_kernel_does_not_grow_with_the_tiles(rep, hd):
+    """The live tiles run in ONE loop: the kernel of a chunk four times as
+    wide (four times the tiles) traces to as many equations."""
+    pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
+
+    def n_eqns(C):
+        def count(jaxpr):
+            return sum(1 + sum(count(j) for j in jax.core.jaxprs_in_params(
+                e.params)) for e in jaxpr.eqns)
+
+        def S(shape, dtype=jnp.int32):
+            return jax.ShapeDtypeStruct(shape, dtype)
+
+        pool = S((1, 41, 16, 2 * hd), jnp.bfloat16)
+        closed = jax.make_jaxpr(
+            lambda *a: pa._paged_ragged_fn(*a, d_true=hd))(
+            S((4, C, 2 * rep, hd), jnp.bfloat16), pool, pool, S((1,)),
+            S((4, 20)), S((4,)), S((4,)))
+        return count(closed.jaxpr)
+
+    assert pa._col_tiles(128 // hd, 256 * rep, rep, jnp.bfloat16) is not None
+    assert n_eqns(256) == n_eqns(1024)
+
+
+# What the kernels at ONE query column a row (the decode step, the chains'
+# fused append) traced to at PR 37's parent, after dead-code elimination,
+# source positions removed: sha256 of the kernel's jaxpr, first 16 digits.
+# They change with JAX's printer; a kernel change that is meant to touch
+# the decode path prints the new ones in its failure.
+_PARENT_DECODE_KERNELS = {
+    "gpt2_append": "e33ec9f969e35854", "gpt2_ragged": "5f6ddc46bf3081cb",
+    "lfm2_append": "e2eb5a47d51a06a7", "lfm2_ragged": "8c167802855c7e00",
+    "trinity_append": "d5b261861bd195a2",
+    "trinity_ragged": "81e99515700f2c90",
+    "tp_shard_append": "10f429dbfe6f75c9",
+    "tp_shard_ragged": "3ae40abf0ce419af",
+    "latent_append": "3be7f08a2d0acd54",
+}
+
+
+@pytest.mark.parametrize("case", list(_PARENT_DECODE_KERNELS))
+def test_decode_kernels_trace_to_the_parents_jaxpr(case):
+    """``C == rep`` is one tile: the append kernels and the ragged kernel
+    at one query column must lower to the kernel they lowered to before
+    the column tiles."""
+    import hashlib
+    import re
+
+    from jax._src.interpreters import partial_eval as pe
+
+    pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
+
+    def S(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    B, BS, NB, bf = 4, 16, 20, jnp.bfloat16
+    idx = (S((1,)), S((B, NB)), S((B,)), S((B,)))
+    name, kind = case.rsplit("_", 1)
+    if name == "latent":
+        pool = S((2, 41, BS, 640), bf)
+        fn, args, kw = pa._paged_latent_append_fn, (
+            S((B, 1, 32, 640), bf), S((B, 640), bf), pool, *idx, idx[-1]), \
+            {"scale": 0.07}
+    else:
+        H, kv, hd, window = {
+            "gpt2": (20, 20, 64, None), "lfm2": (32, 8, 64, None),
+            "trinity": (32, 4, 128, 2048), "tp_shard": (5, 5, 64, None),
+        }[name]
+        pool = S((2, 41, BS, kv * hd), bf)
+        kw = {"d_true": hd, **({} if window is None else {"window": window})}
+        q, new = S((B, 1, H, hd), bf), S((B, kv, hd), bf)
+        fn, args = (pa._paged_append_fn, (q, new, new, pool, pool, *idx,
+                                          idx[-1])) if kind == "append" \
+            else (pa._paged_ragged_fn, (q, pool, pool, *idx))
+    closed = jax.make_jaxpr(lambda *a: fn(*a, **kw))(*args)
+    (call,) = [e for e in closed.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    kernel = call.params["jaxpr"]
+    kernel, _ = pe.dce_jaxpr(kernel, [True] * len(kernel.outvars))
+    text = re.sub(r" at [^ ]*:\d+", "", str(kernel))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == _PARENT_DECODE_KERNELS[case]
 
 
 # -- the mixed step's K/V writer ---------------------------------------------

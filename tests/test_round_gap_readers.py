@@ -187,8 +187,11 @@ def test_benchmark_json_names_the_four_in_every_cell():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = [w["name"] for w in bench["workloads"]]
-    tail = bench["per_layer"][-4:]
-    assert [m["name"] for m in tail] == list(NEW)
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    tail = [by_name[name] for name in NEW]
+    # later metrics follow them (PR 37: paged_query_tile_fill_pct)
+    at = bench["per_layer"].index(tail[0])
+    assert bench["per_layer"][at:at + 4] == tail
     for m in tail:
         assert m == {"name": m["name"], "unit": "ms", "better": "lower",
                      "source": "device_trace", "layer": "Step programs",

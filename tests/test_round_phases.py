@@ -559,6 +559,53 @@ def test_the_benchmark_reads_chunk_row_tokens(params):
     assert span_stat.from_ring(spec, before, len(before), window) is None
 
 
+def test_build_counts_the_query_columns_its_tiles_cover(wide_params):
+    """``kv_query_cols`` / ``kv_query_tile_cols`` on the ``pw.round.build``
+    of mixed rounds, against the kernel's own rule: eight heads of 64 at a
+    chunk of 64 in f32 lie in a first tile of 8 columns and a wide one of 64
+    (``_col_tiles``: 128 a wide tile where the chunk has them).  One request alone: a prompt of 70 tokens is a row of
+    64 live columns, then one of 6, each beside three idle rows of one; a
+    hand-built round of a decode row, an idle row, a chunk's 9 columns and
+    a full chunk; and the benchmark's reader of the two
+    (``paged_query_tile_fill_pct``)."""
+    import importlib
+    import time
+
+    from benchmark.readers import span_stat
+
+    pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
+    eng = _engine(wide_params, "t_qcols", cfg=_WIDE, prefill_chunk=64,
+                  seq_buckets=(64, 128))
+    assert pa._col_tiles(2, 64, 1, eng._pool_kwargs["dtype"]) == (8, 64)
+    t0 = time.perf_counter()
+    eng.generate_batch([(p, 3) for p in _prompts((70,))])
+    window = (t0, time.perf_counter())
+    ring = obs.recorder().snapshot()
+    builds = [s for s in ring if s.name == "pw.round.build"]
+    mixed = [s.attrs for s in builds if s.attrs["kind"] == "mixed"]
+    assert [(a["kv_query_cols"], a["kv_query_tile_cols"]) for a in mixed] \
+        == [(64 + 3, 64 + 3 * 8), (6 + 3, 8 + 3 * 8)]
+    assert all("kv_query_cols" not in s.attrs for s in builds
+               if s.attrs["kind"] != "mixed")
+
+    class _Ph:
+        def set(self, **attrs):
+            self.attrs = attrs
+
+    ph, rows = _Ph(), np.array([1, 1, 9, 64], np.int32)
+    eng._note_query_cols(ph, rows)
+    assert ph.attrs == {
+        "kv_query_cols": 75,
+        "kv_query_tile_cols": int(pa.query_tile_columns(
+            rows, 64, 8, 64, 512, eng._pool_kwargs["dtype"]).sum())}
+    assert ph.attrs["kv_query_tile_cols"] == 8 + 8 + 64 + 64
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "metrics", "paged_query_tile_fill_pct.json")) as f:
+        spec = json.load(f)
+    assert span_stat.from_ring(spec, ring, len(ring), window) \
+        == pytest.approx(100.0 * (67 + 9) / (88 + 32))
+
+
 # -- a request's lifecycle ----------------------------------------------------
 
 

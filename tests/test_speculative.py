@@ -125,6 +125,33 @@ def test_spec_identity_mixed_lengths(params):
     on.pool.check_invariants(external_refs=on.prefix.external_refs())
 
 
+def test_spec_identity_through_the_interpreted_kernels():
+    """A verify round is the mixed program at ``k + 1`` = 5 query columns:
+    through the programs the chip runs (two heads of 64, the Pallas kernels
+    interpreted) it emits what the chained engine emits.  Five columns are
+    no whole sublane tile: the ragged kernel takes such a row as one tile
+    (``paged_attention._col_tiles``)."""
+    cfg = DecoderConfig(vocab_size=64, d_model=128, n_layers=1, n_heads=2,
+                        d_ff=128, max_len=128)
+    params = init_decoder_params(cfg, jax.random.PRNGKey(1))
+    prompts = [p + p[:6] for p in _prompts((9, 14, 21))]  # n-grams to draft
+
+    def engine(name, speculative):
+        return PagedDecodeEngine(
+            cfg, params, speculative=speculative, name=name, num_blocks=64,
+            block_size=8, max_batch_size=4, seq_buckets=(32, 64),
+            prefill_chunk=8, chain_steps=4, attn="pallas")
+
+    on = engine("t_sp_pallas_on", "ngram")
+    assert on._spec.k + 1 == 5
+    got = on.generate_batch([(p, 9) for p in prompts])
+    assert got == engine("t_sp_pallas_off", "off").generate_batch(
+        [(p, 9) for p in prompts])
+    assert got == [_dense_greedy(params, p, 9, cfg=cfg) for p in prompts]
+    assert _spec_stats(on)["spec_rounds"] > 0
+    on.pool.check_invariants(external_refs=on.prefix.external_refs())
+
+
 def test_spec_identity_shared_prefixes(params):
     # rows sharing long prefixes: spec rounds run over prefix-cache-shared
     # block tables (COW on the write slots), and a SECOND pass drafts
