@@ -31,15 +31,18 @@ reduction — no replicated [B, vocab] gather ever materializes).
 single-device programs.
 
 The engine<->cache contract is backend.py (``CacheBackend`` +
-``make_backend``), with four kinds behind it: ``"paged"`` (block_pool.py
+``make_backend``), with five kinds behind it: ``"paged"`` (block_pool.py
 ``BlockPool``: K/V blocks for every layer), ``"hybrid"`` (hybrid.py
 ``HybridCache``: K/V blocks for a model's attention layers and a conv
 slot a sequence beside them), ``"windowed"`` (windowed.py
 ``WindowedCache``: K/V blocks for a model's full-attention layers and a
-second pool, freed behind the window, for its sliding-window layers) and
+second pool, freed behind the window, for its sliding-window layers),
 ``"latent_state"`` (hybrid.py ``StateCache``: a latent pool of one array
 for a model's latent-attention layers and, under one slot a sequence, the
-conv inputs and the f32 matrix states of its delta-rule layers).  Which kind an engine builds, and which
+conv inputs and the f32 matrix states of its delta-rule layers) and
+``"kv_state"`` (hybrid.py ``KVStateCache``: that state cache with a plain
+K/V pool, keys and values of a model's full-attention layers, where the
+latent pool stood).  Which kind an engine builds, and which
 step programs it runs, its block family says (models/families.py).
 
 Round-18 (ARCHITECTURE.md "Round-18: Speculative decoding") breaks the
@@ -59,7 +62,7 @@ managed-resource framing follows arxiv 2603.09555.
 from .backend import CacheBackend, UnsupportedCacheOp, make_backend
 from .block_pool import BlockPool, PoolExhausted, SequenceState
 from .engine import EngineHungError, PagedDecodeEngine, resolve_tp
-from .hybrid import HybridCache, StateCache
+from .hybrid import HybridCache, KVStateCache, StateCache
 from .paged_attention import paged_attention, paged_attention_reference
 from .prefix_cache import PrefixCache
 from .speculative import (Drafter, DraftModelDrafter, NGramDrafter,
@@ -78,6 +81,7 @@ __all__ = [
     "CacheBackend",
     "EngineHungError",
     "HybridCache",
+    "KVStateCache",
     "StateCache",
     "PoolExhausted",
     "SequenceState",

@@ -2,7 +2,7 @@
 
 The engine (engine.py: admission, rounds, restart, sessions) programs
 against :class:`CacheBackend`, not against a layout; how a sequence's
-decode state lives in HBM is the backend's.  Four kinds serve cells:
+decode state lives in HBM is the backend's.  Five kinds serve cells:
 
 - ``"paged"`` — :class:`~pathway_tpu.kvcache.block_pool.BlockPool`: K/V
   blocks for every layer, addressed through per-sequence block tables;
@@ -17,6 +17,11 @@ decode state lives in HBM is the backend's.  Four kinds serve cells:
   for a model's latent-attention layers and, in the slot arena, a matrix
   state a head beside the carried conv inputs for its delta-rule layers.
   Preemption only, as the hybrid kind.
+- ``"kv_state"`` — :class:`~pathway_tpu.kvcache.hybrid.KVStateCache`:
+  the state kind with a plain K/V pool in the latent pool's place: keys
+  AND values of a model's full-attention layers beside the conv slot and
+  the matrix state of its delta-rule layers, one slot and one block table a
+  sequence.  Preemption only, as the state kind.
 - ``"windowed"`` — :class:`~pathway_tpu.kvcache.windowed.WindowedCache`:
   the K/V blocks of a model's full-attention layers and, in a second pool
   with a block table of its own, those of its sliding-window layers, whose
@@ -75,7 +80,8 @@ class CacheBackend(abc.ABC):
     """Abstract engine↔cache contract.  See the module docstring for
     which side owns which invariant."""
 
-    #: "paged" | "hybrid" | "windowed" | "latent_state" — the factory key
+    #: "paged" | "hybrid" | "windowed" | "latent_state" | "kv_state" — the
+    #: factory key
     cache_kind: str = "abstract"
     #: positions a sliding-window layer's query sees (itself included);
     #: None: every layer keeps every key
@@ -174,11 +180,14 @@ class CacheBackend(abc.ABC):
 
 
 class ExpertCounts:
-    """The tokens each expert received, counted by the step programs on
-    the device (``int32[n_experts]`` a program): kept as they come back,
-    read after the next sync (``moe_routed_pairs`` /
-    ``moe_tokens_per_expert`` of the cache's stats).  For the caches of
-    families with expert layers."""
+    """What the step programs of a family with expert layers count on the
+    device, one ``int32`` vector a program (ops/moe.py ``expert_ffn``,
+    summed over the expert layers): the tokens each held
+    expert received, then ``COUNTER_TAIL`` (pairs routed to experts held
+    elsewhere, the grouped matmul's live row tiles, held experts with at
+    least one pair, the passes counted).  Kept as they come back, read
+    after the next sync (:meth:`fold_expert_counts`, the vector's one
+    reader) into the cache's stats."""
 
     _expert_counts: tuple = ()  # the programs' counts not yet read back
 
@@ -192,9 +201,13 @@ class ExpertCounts:
     def fold_expert_counts(self) -> None:
         import numpy as np
 
+        from ..ops.moe import COUNTER_TAIL
+
         pending, self._expert_counts = self._expert_counts, ()
+        n = len(COUNTER_TAIL)
         for counts in pending:
-            self.stats.record_moe(np.asarray(counts))
+            counts = np.asarray(counts)
+            self.stats.record_moe(counts[:-n], zip(COUNTER_TAIL, counts[-n:]))
 
 
 _BACKENDS: dict[str, Callable] = {}
@@ -211,7 +224,10 @@ def make_backend(kind: str, **kwargs) -> CacheBackend:
     :class:`~pathway_tpu.kvcache.hybrid.HybridCache` (K/V blocks for the
     attention layers and a conv slot, one sequence); ``"windowed"`` →
     :class:`~pathway_tpu.kvcache.windowed.WindowedCache` (a second pool and
-    table for the sliding-window layers)."""
+    table for the sliding-window layers); ``"latent_state"`` / ``"kv_state"``
+    → :class:`~pathway_tpu.kvcache.hybrid.StateCache` /
+    :class:`~pathway_tpu.kvcache.hybrid.KVStateCache` (a latent pool, or a
+    K/V pool, beside a conv slot and a matrix state a sequence)."""
     if kind not in _BACKENDS:
         # lazy registration avoids import cycles: block_pool/hybrid
         # import nothing from here at module scope except the ABC
@@ -231,10 +247,14 @@ def make_backend(kind: str, **kwargs) -> CacheBackend:
             from .hybrid import StateCache
 
             register_backend("latent_state", StateCache)
+        elif kind == "kv_state":
+            from .hybrid import KVStateCache
+
+            register_backend("kv_state", KVStateCache)
         else:
             raise ValueError(
                 f"unknown cache backend {kind!r}; "
                 f"registered: {sorted(_BACKENDS)} + builtin: paged, hybrid, "
-                "windowed, latent_state"
+                "windowed, latent_state, kv_state"
             )
     return _BACKENDS[kind](**kwargs)

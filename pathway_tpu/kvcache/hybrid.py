@@ -32,6 +32,13 @@ slot must not be read: the step programs start a sequence's first chunk
 from zero themselves (``row_start == 0``; no host-side clear, which would
 cost a dispatch), a chunk's padded tokens leave the state as it was, and a
 preempted sequence rebuilds it by recompute like any other.
+
+:class:`KVStateCache` (PR 38) is that state cache with ``value_pool`` on: a
+plain K/V pool (keys AND values of a family's full-attention layers,
+models/qwen3_next.py) where the latent pool stood, ``device_state() = (k,
+v, conv, state)``.  Everything else - blocks and slot together or not at
+all, free, preemption, ``row_extras``, ``after_sync``, the invariants - is
+the class's it inherits from.
 """
 
 from __future__ import annotations
@@ -179,17 +186,17 @@ class StateCache(HybridCache):
         return super().per_shard_bytes + self.state_bytes
 
     def device_state(self) -> tuple:
-        return (self.k, self.conv, self.state)
+        pools = (self.k, self.v) if self.value_pool else (self.k,)
+        return (*pools, self.conv, self.state)
 
-    def set_device_state(self, k, conv, state, counts) -> None:
-        self.k, self.conv, self.state = k, conv, state
+    def set_device_state(self, *arrays) -> None:
+        *pools, self.conv, self.state, counts = arrays
+        self.k, self.v = pools if self.value_pool else (pools[0], None)
         self.keep_expert_counts(counts)
 
-    def after_sync(self) -> None:
-        """The programs' device counters: tokens per held expert, and last
-        the pairs routed to experts held elsewhere."""
-        pending, self._expert_counts = self._expert_counts, ()
-        for counts in pending:
-            counts = np.asarray(counts)
-            self.stats.record_moe(counts[:-1])
-            self.stats.record_pairs_elsewhere(int(counts[-1]))
+
+class KVStateCache(StateCache):
+    """A K/V pool, and one slot a sequence in the two arenas."""
+
+    cache_kind = "kv_state"
+    value_pool = True

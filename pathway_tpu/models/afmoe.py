@@ -30,7 +30,8 @@ table of its own, whose blocks go back to their free list behind the
 window (:class:`pathway_tpu.kvcache.windowed.WindowedCache`).  Both tables
 are indexed by position, so a token's window slot is its window table's
 entry at the position its full slot has.  Every program also returns the
-tokens each expert received, summed over the expert layers.
+expert layers' counter vector, summed (the tokens each expert received,
+then :data:`pathway_tpu.ops.moe.COUNTER_TAIL`).
 
 Greedy, one device.  Parameters are used in the dtype they come in (the
 configuration's: bf16 on the chip); no f32 copy is kept or made.
@@ -183,10 +184,11 @@ def _forward(params: dict, cfg: AfmoeConfig, k_pool, v_pool, kw_pool, vw_pool,
     ``valid`` (T,) which tokens are real).  ``decode``: every row is one
     token at column 0, so the layers take the fused append+attend kernel.
     Returns ``(logits (B, V) f32, k_pool, v_pool, kw_pool, vw_pool,
-    counts (E,))``."""
+    counts (E + 3,):
+    ops/moe.py ``expert_ffn``)``."""
     from ..kvcache.paged_attention import (paged_append_attend,
                                            paged_attention, paged_write_rows)
-    from ..ops.moe import expert_ffn
+    from ..ops.moe import COUNTER_TAIL, expert_ffn
 
     T = tokens.shape[0]
     hd, eps, f32 = cfg.head_dim, cfg.norm_eps, jnp.float32
@@ -197,7 +199,7 @@ def _forward(params: dict, cfg: AfmoeConfig, k_pool, v_pool, kw_pool, vw_pool,
     x = params["embed"][tokens].astype(f32)                    # (T, D)
     if cfg.mup_enabled:
         x = x * np.float32(np.sqrt(cfg.d_model))
-    counts = jnp.zeros((cfg.n_experts,), jnp.int32)
+    counts = jnp.zeros((cfg.n_experts + len(COUNTER_TAIL),), jnp.int32)
     # a token's window slot: its window table's entry at its position; a
     # token the full pool sends to the null block (padding) goes there too
     win_blocks = jnp.where(
@@ -298,6 +300,8 @@ def windowed_chained_decode(params: dict, cfg: AfmoeConfig, k_pool, v_pool,
     tables hold the chain's blocks already and none is freed inside it),
     step t's ids feeding step t + 1.  Returns ``(ids (B, K), k_pool,
     v_pool, kw_pool, vw_pool, counts)``."""
+    from ..ops.moe import COUNTER_TAIL
+
     K = slot_blocks.shape[1]
     maxp = cfg.max_len - 1
 
@@ -312,7 +316,7 @@ def windowed_chained_decode(params: dict, cfg: AfmoeConfig, k_pool, v_pool,
         return (ids, kp, vp, kwp, vwp, cnt + n_tok), ids
 
     init = (token.astype(jnp.int32), k_pool, v_pool, kw_pool, vw_pool,
-            jnp.zeros((cfg.n_experts,), jnp.int32))
+            jnp.zeros((cfg.n_experts + len(COUNTER_TAIL),), jnp.int32))
     (_last, k_pool, v_pool, kw_pool, vw_pool, counts), ids = jax.lax.scan(
         body, init, (slot_blocks.T, slot_offsets.T,
                      jnp.arange(K, dtype=jnp.int32)))

@@ -27,7 +27,7 @@ and gives the engine:
 
 A family is the only place that names a model's programs: the engine
 imports none of models/decoder.py, models/lfm2.py, models/afmoe.py,
-models/kimi_linear.py.  The function
+models/kimi_linear.py, models/qwen3_next.py.  The function
 names ``_step_fn`` / ``_mixed_fn`` / ``_chained_fn`` are the
 device trace's (``jit__mixed_fn`` on ``XLA Modules``): the benchmark's
 readers find the programs by them, for every family alike.
@@ -322,6 +322,12 @@ class KimiLinearFamily:
                 "state_dk": cfg.kda_head_dim, "state_dv": cfg.kda_head_dim}
 
     @staticmethod
+    def scan_items(cfg) -> tuple:
+        """(tokens, f32 lanes a token) of a work item of the chunked scan:
+        what obs/memory.py bills the mixed step's temporaries by."""
+        return cfg.kda_chunk, cfg.kda_width
+
+    @staticmethod
     def unsupported(*, tp, quantize, speculative, session_store) -> None:
         _refuse("kimi_linear", (
             ("tensor parallelism (tp > 1): the state arena, the latent pool "
@@ -370,8 +376,96 @@ class KimiLinearFamily:
                 "chained": (_chained_fn, (1, 2, 3))}
 
 
+class Qwen3NextFamily:
+    """models/qwen3_next.py: gated DeltaNet (one decay a head) and gated
+    full-attention layers (rotary on a part of the head), a softmax router
+    over routed experts (all of them, or the share a chip of an
+    expert-parallel deployment holds) beside a sigmoid-gated shared one, on
+    the K/V-and-state cache.  Greedy on one device.  Beyond what
+    :meth:`unsupported` refuses, the cache kind runs without a prefix cache
+    and without fork whatever was asked for (a shared block would skip the
+    tokens that build the matrix state), and a sampled request fails alone
+    (``greedy_only``)."""
+
+    name = "qwen3_next"
+    cache_kind = "kv_state"
+    greedy_only = True
+    tensor_parallel = False
+
+    @staticmethod
+    def plan(cfg, params, *, tp: int, quantize):
+        from .qwen3_next import plan_params
+
+        return plan_params(cfg, params)
+
+    @staticmethod
+    def cache_kwargs(cfg, max_batch_size: int, round_tokens: int) -> dict:
+        return {"n_layers": len(cfg.full_layers), "n_heads": cfg.n_kv_heads,
+                "head_dim": cfg.head_dim,
+                "conv_layers": len(cfg.gdn_layers),
+                "conv_width": cfg.conv_width,
+                "conv_taps": cfg.conv_kernel - 1,
+                "conv_slots": max_batch_size,
+                "state_heads": cfg.gdn_value_heads,
+                "state_dk": cfg.gdn_key_dim, "state_dv": cfg.gdn_value_dim}
+
+    @staticmethod
+    def scan_items(cfg) -> tuple:
+        """As :meth:`KimiLinearFamily.scan_items`."""
+        return cfg.gdn_chunk, cfg.value_width
+
+    @staticmethod
+    def unsupported(*, tp, quantize, speculative, session_store) -> None:
+        _refuse("qwen3_next", (
+            ("tensor parallelism (tp > 1): the state arena, the K/V pool of "
+             "two heads and the held experts have no sharded layout and no "
+             "exchange", tp is not None and tp > 1),
+            (f"quantize={quantize!r}: no quantized plan of the expert "
+             "weights", quantize is not None),
+            ("speculative drafting: a rejected draft would have to roll "
+             "the matrix state back", speculative not in (None, False)),
+            ("host tiering (session_store): a resumed block skips the "
+             "tokens that build the matrix state (it would take a snapshot "
+             "of the state at every block boundary, as prefix sharing and "
+             "fork would)", session_store is not None),
+        ))
+
+    @staticmethod
+    def programs(cfg, attn: str, mesh, sampled: bool = False) -> dict:
+        if sampled:
+            raise ValueError("the qwen3_next block family decodes greedily: "
+                             "it has no sampled step programs")
+        from . import qwen3_next as m
+
+        def _step_fn(p, k_pool, v_pool, conv, state, token, positions, bt,
+                     sb, so, slots):
+            logits, *cache = m.kv_state_decode_step(
+                p, cfg, k_pool, v_pool, conv, state, token, positions, bt,
+                sb, so, slots, attn=attn)
+            return (m.greedy_ids(logits), *cache)
+
+        def _mixed_fn(p, k_pool, v_pool, conv, state, tokens, positions,
+                      row_tables, row_start, row_nvalid, row_token_idx,
+                      tok_row, tok_col, sb, so, logit_idx, slots):
+            logits, *cache = m.kv_state_mixed_step(
+                p, cfg, k_pool, v_pool, conv, state, tokens, positions,
+                row_tables, row_start, row_nvalid, row_token_idx, tok_row,
+                tok_col, sb, so, logit_idx, slots, attn=attn)
+            return (m.greedy_ids(logits), *cache)
+
+        def _chained_fn(p, k_pool, v_pool, conv, state, token, positions, bt,
+                        sb, so, slots):
+            return m.kv_state_chained_decode(
+                p, cfg, k_pool, v_pool, conv, state, token, positions, bt,
+                sb, so, slots, attn=attn)
+
+        donated = (1, 2, 3, 4)
+        return {"step": (_step_fn, donated), "mixed": (_mixed_fn, donated),
+                "chained": (_chained_fn, donated)}
+
+
 _FAMILIES = {f.name: f for f in (DecoderFamily, Lfm2Family, AfmoeFamily,
-                                 KimiLinearFamily)}
+                                 KimiLinearFamily, Qwen3NextFamily)}
 
 
 def step_family(cfg):

@@ -354,6 +354,91 @@ def config_from_kimi_linear(hf_config, *, max_len: int | None = None,
     )
 
 
+def config_from_qwen3_next(hf_config, *, max_len: int | None = None,
+                           dtype="auto", router_experts: int | None = None,
+                           first_expert: int = 0):
+    """``qwen3_next`` config (Qwen/Qwen3-Next-80B-A3B-Instruct's
+    ``config.json`` keys) ->
+    :class:`~pathway_tpu.models.qwen3_next.Qwen3NextConfig`.  Layer ``i``
+    (1-based) is full attention where ``i % full_attention_interval == 0``,
+    else gated DeltaNet; ``partial_rotary_factor`` of ``head_dim`` takes the
+    rotary; every layer has the expert block.  ``max_len`` caps the served
+    context below ``max_position_embeddings``.
+
+    ``router_experts``: the router's published width where ``num_experts``
+    counts the experts this share HOLDS (one chip of an expert-parallel
+    deployment: experts ``first_expert .. first_expert + num_experts``).
+
+    The published checkpoint lays ``W_qkvz`` and ``W_ba`` out interleaved
+    by key head (a key head's q, k, then its value heads' v, z; b, a
+    likewise) and ``W_q`` a head as ``[query ; gate]``; the programs take
+    ``wqkvz`` in the split form ``[q ; k ; v ; z]``, ``wba`` as ``[b ; a]``
+    and ``wq`` a head as published.
+
+    What the keys can say and this family has not written down is refused:
+    dense layers (``mlp_only_layers`` not empty, ``decoder_sparse_step``
+    other than 1), rotary scaling, a sliding window, a tied head, another
+    activation than SiLU, unnormalised router weights (``norm_topk_prob``
+    false), multi-token prediction layers."""
+    from .qwen3_next import FULL, GDN, Qwen3NextConfig
+
+    def get(name, default=None):
+        return getattr(hf_config, name, default)
+
+    if get("model_type") != "qwen3_next":
+        raise ValueError(
+            f"expected a qwen3_next config, got model_type="
+            f"{get('model_type')!r}")
+    refused = [what for what, bad in (
+        ("mlp_only_layers", bool(get("mlp_only_layers", []))),
+        ("decoder_sparse_step other than 1",
+         get("decoder_sparse_step", 1) != 1),
+        ("rope_scaling", get("rope_scaling") is not None),
+        ("use_sliding_window", bool(get("use_sliding_window", False))),
+        ("tie_word_embeddings", bool(get("tie_word_embeddings", False))),
+        ("hidden_act other than silu", get("hidden_act", "silu") != "silu"),
+        ("norm_topk_prob false", not get("norm_topk_prob", True)),
+        ("multi-token prediction layers (num_nextn_predict_layers / "
+         "mtp_num_hidden_layers)",
+         bool(get("num_nextn_predict_layers", 0)
+              or get("mtp_num_hidden_layers", 0))),
+    ) if bad]
+    if refused:
+        raise ValueError(
+            "qwen3_next: not written down here: " + "; ".join(refused))
+    n_layers = int(hf_config.num_hidden_layers)
+    every = int(get("full_attention_interval", 4))
+    hd = int(get("head_dim", hf_config.hidden_size
+                 // hf_config.num_attention_heads))
+    positions = int(hf_config.max_position_embeddings)
+    held = int(hf_config.num_experts)
+    return Qwen3NextConfig(
+        vocab_size=hf_config.vocab_size,
+        d_model=hf_config.hidden_size,
+        n_heads=hf_config.num_attention_heads,
+        n_kv_heads=hf_config.num_key_value_heads,
+        head_dim=hd,
+        rotary_dim=int(hd * float(get("partial_rotary_factor", 1.0))),
+        gdn_key_heads=int(hf_config.linear_num_key_heads),
+        gdn_value_heads=int(hf_config.linear_num_value_heads),
+        gdn_key_dim=int(hf_config.linear_key_head_dim),
+        gdn_value_dim=int(hf_config.linear_value_head_dim),
+        conv_kernel=int(hf_config.linear_conv_kernel_dim),
+        d_ff_expert=hf_config.moe_intermediate_size,
+        d_ff_shared=hf_config.shared_expert_intermediate_size,
+        n_experts=held if router_experts is None else int(router_experts),
+        n_held_experts=None if router_experts is None else held,
+        first_expert=int(first_expert),
+        top_k=hf_config.num_experts_per_tok,
+        layer_types=tuple(FULL if i % every == 0 else GDN
+                          for i in range(1, n_layers + 1)),
+        rope_theta=float(get("rope_theta", 1e7)),
+        norm_eps=float(get("rms_norm_eps", 1e-6)),
+        max_len=min(positions, int(max_len)) if max_len else positions,
+        dtype=dtype,
+    )
+
+
 def params_from_lfm2_state_dict(state: dict[str, Any], cfg) -> dict:
     """Map a (torch) LFM2-family state dict onto
     :mod:`pathway_tpu.models.lfm2`'s parameter pytree, in ``cfg``'s dtype.

@@ -39,8 +39,10 @@ programs.  The latent rows live in a pool of one array whose layer axis
 counts the ``mla`` layers, the ``kda`` layers' state and conv inputs in two
 arenas under one slot a sequence
 (:class:`pathway_tpu.kvcache.hybrid.StateCache`).  Every program also
-returns the tokens each held expert received, summed over the expert
-layers, and last the pairs routed to experts held elsewhere.
+returns the expert layers' counter vector, summed: the tokens each held
+expert received, then the pairs routed to experts held elsewhere, the grouped
+matmul's live row tiles and the held experts touched
+(:data:`pathway_tpu.ops.moe.COUNTER_TAIL`).
 
 Greedy, one device.  Parameters are used in the dtype they come in (the
 configuration's: bf16 on the chip); no f32 copy is kept or made.
@@ -364,12 +366,12 @@ def _forward(params: dict, cfg: KimiLinearConfig, pool, conv, state, tokens,
     (B,) the rows' arena slots, ``valid`` (T,) which tokens are real and
     ``row_live`` (B,) which rows are).  ``decode``: every row is one token
     at column 0.  Returns ``(logits (B, V) f32, pool, conv, state, counts
-    (held + 1,))``."""
+    (held + 3,): ops/moe.py ``expert_ffn``)``."""
     from ..kvcache.paged_attention import (latent_append_attend,
                                            latent_attention,
                                            latent_write_rows)
     from ..ops import kda
-    from ..ops.moe import expert_ffn
+    from ..ops.moe import COUNTER_TAIL, expert_ffn
 
     T = tokens.shape[0]
     H, eps, f32 = cfg.n_heads, cfg.norm_eps, jnp.float32
@@ -382,7 +384,7 @@ def _forward(params: dict, cfg: KimiLinearConfig, pool, conv, state, tokens,
     # the residual stream accumulates in f32; every matmul takes it normed
     # and rounded to the parameters' dtype, the router takes it unrounded
     x = params["embed"][tokens].astype(f32)                    # (T, D)
-    counts = jnp.zeros((cfg.held_experts + 1,), jnp.int32)
+    counts = jnp.zeros((cfg.held_experts + len(COUNTER_TAIL),), jnp.int32)
     slot_of_tok = row_slot[tok_row]
     row_first = row_token_idx[:, 0]
     row_fresh = _fresh_rows(row_start)
@@ -440,13 +442,12 @@ def _forward(params: dict, cfg: KimiLinearConfig, pool, conv, state, tokens,
             x = x + _swiglu(lay, h)
         else:
             # with a share: the held experts' part, and the pairs elsewhere
-            y, n_tok, *away = expert_ffn(
+            y, n_tok = expert_ffn(
                 h, lay, valid, h_route=h32, top_k=cfg.top_k,
                 norm_topk=cfg.route_norm, scale=cfg.route_scale,
                 renorm_eps=1e-20, use_pallas=kernels, first_expert=cfg.share)
-            away = away[0] if away else jnp.zeros((), jnp.int32)
             x = x + y.astype(f32) + _swiglu(lay["shared"], h).astype(f32)
-            counts = counts + jnp.concatenate([n_tok, away[None]])
+            counts = counts + n_tok
     sel = _rms(x[logit_idx], params["norm_out"], eps, dtype)   # (B, D)
     logits = jnp.dot(sel, params["head"], preferred_element_type=f32)
     return logits, pool, conv, state, counts
@@ -494,6 +495,8 @@ def state_chained_decode(params: dict, cfg: KimiLinearConfig, pool, conv,
     ``slot_offsets`` (B, K), the host's pre-extended slots), step t's ids
     feeding step t + 1, the state riding the scan.  Returns ``(ids (B, K),
     pool, conv, state, counts)``."""
+    from ..ops.moe import COUNTER_TAIL
+
     K = slot_blocks.shape[1]
     maxp = cfg.max_len - 1
 
@@ -507,7 +510,7 @@ def state_chained_decode(params: dict, cfg: KimiLinearConfig, pool, conv,
         return (ids, pl_, cv, st, cnt + n_tok), ids
 
     init = (token.astype(jnp.int32), pool, conv, state,
-            jnp.zeros((cfg.held_experts + 1,), jnp.int32))
+            jnp.zeros((cfg.held_experts + len(COUNTER_TAIL),), jnp.int32))
     (_last, pool, conv, state, counts), ids = jax.lax.scan(
         body, init, (slot_blocks.T, slot_offsets.T,
                      jnp.arange(K, dtype=jnp.int32)))

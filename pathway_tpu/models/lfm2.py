@@ -24,8 +24,9 @@ conv layers' carried vectors live in a slot arena ``(conv layers, slots,
 2, d_model)`` beside it (:class:`pathway_tpu.kvcache.hybrid.HybridCache`).
 A token at position ``p`` reads ``u_{p-1}`` / ``u_{p-2}`` only where those
 positions exist, so a slot needs no clearing between sequences.  Every
-program also returns the tokens each expert received, summed over the
-expert layers (``int32[n_experts]``).
+program also returns the expert layers' counter vector, summed
+(``int32[n_experts + 3]``: the tokens each expert received, then
+:data:`pathway_tpu.ops.moe.COUNTER_TAIL`).
 
 Greedy, one device.  Parameters are used in the dtype they come in (the
 configuration's: bf16 on the chip); no f32 copy is kept or made.
@@ -224,10 +225,11 @@ def _forward(params: dict, cfg: Lfm2Config, k_pool, v_pool, conv, tokens,
     ``row_slot`` (B,) the rows' arena slots and ``valid`` (T,) which
     tokens are real).  ``decode``: every row is one token at column 0, so
     the attention layers take the fused append+attend kernel.  Returns
-    ``(logits (B, V) f32, k_pool, v_pool, conv, counts (E,))``."""
+    ``(logits (B, V) f32, k_pool, v_pool, conv, counts (E + 3,):
+    ops/moe.py ``expert_ffn``)``."""
     from ..kvcache.paged_attention import (paged_append_attend,
                                            paged_attention, paged_write_rows)
-    from ..ops.moe import expert_ffn
+    from ..ops.moe import COUNTER_TAIL, expert_ffn
 
     T = tokens.shape[0]
     hd, eps = cfg.head_dim, cfg.norm_eps
@@ -237,7 +239,7 @@ def _forward(params: dict, cfg: Lfm2Config, k_pool, v_pool, conv, tokens,
     # bf16 sum loses the small branches); every matmul takes it normed and
     # rounded to the parameters' dtype, the router takes it unrounded
     x = params["embed"][tokens].astype(jnp.float32)           # (T, D)
-    counts = jnp.zeros((cfg.n_experts,), jnp.int32)
+    counts = jnp.zeros((cfg.n_experts + len(COUNTER_TAIL),), jnp.int32)
     # where the two vectors before a token come from: the stream for the
     # later tokens of a run, the row's slot for its first two
     slot_of_tok = row_slot[tok_row]
@@ -349,6 +351,8 @@ def hybrid_chained_decode(params: dict, cfg: Lfm2Config, k_pool, v_pool,
     ``slot_offsets`` (B, K), the host's pre-extended slots), step t's ids
     feeding step t + 1.  Returns ``(ids (B, K), k_pool, v_pool, conv,
     counts)``."""
+    from ..ops.moe import COUNTER_TAIL
+
     K = slot_blocks.shape[1]
     maxp = cfg.max_len - 1
 
@@ -362,7 +366,7 @@ def hybrid_chained_decode(params: dict, cfg: Lfm2Config, k_pool, v_pool,
         return (ids, kp, vp, cv, cnt + n_tok), ids
 
     init = (token.astype(jnp.int32), k_pool, v_pool, conv,
-            jnp.zeros((cfg.n_experts,), jnp.int32))
+            jnp.zeros((cfg.n_experts + len(COUNTER_TAIL),), jnp.int32))
     (_last, k_pool, v_pool, conv, counts), ids = jax.lax.scan(
         body, init, (slot_blocks.T, slot_offsets.T,
                      jnp.arange(K, dtype=jnp.int32)))

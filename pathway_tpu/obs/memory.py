@@ -130,18 +130,24 @@ def _kv_geometry(cfg) -> tuple:
             getattr(cfg, "n_kv_heads", cfg.n_heads), hd)
 
 
+def _arenas(cfg, max_batch_size: int) -> dict:
+    """What the family hands its cache (models/families.py
+    ``cache_kwargs``): the one place a slot arena's geometry is written."""
+    from ..models.families import step_family
+
+    return step_family(cfg).cache_kwargs(cfg, max_batch_size, 0)
+
+
 def conv_arena_bytes(cfg, *, max_batch_size: int, itemsize: int) -> int:
-    """The conv slot arena of a hybrid family (kvcache/hybrid.py): two
-    carried vectors a conv layer for every batch row and the null slot.
-    0 for a family without conv layers."""
-    if hasattr(cfg, "kda_layers"):
-        # the carried inputs of the three short convolutions of a
-        # delta-rule layer (kvcache/hybrid.py StateCache)
-        return len(cfg.kda_layers) * (max_batch_size + 1) \
-            * (cfg.conv_kernel - 1) * 3 * cfg.kda_width * itemsize
-    conv_layers = getattr(cfg, "conv_layers", ())
-    return len(conv_layers) * (max_batch_size + 1) * 2 * cfg.d_model \
-        * itemsize
+    """The conv slot arena of a hybrid family (kvcache/hybrid.py): the
+    carried inputs (``conv_taps`` vectors of ``conv_width``, two where the
+    family does not say) a conv layer for every batch row and the null
+    slot.  0 for a family without conv layers."""
+    kw = _arenas(cfg, max_batch_size)
+    if "conv_layers" not in kw:
+        return 0
+    return kw["conv_layers"] * (kw["conv_slots"] + 1) \
+        * kw.get("conv_taps", 2) * kw["conv_width"] * itemsize
 
 
 def state_arena_bytes(cfg, *, max_batch_size: int) -> int:
@@ -149,11 +155,11 @@ def state_arena_bytes(cfg, *, max_batch_size: int) -> int:
     (kvcache/hybrid.py StateCache): ``heads x dk x dv`` in f32 a layer for
     every batch row and the null slot.  0 for a family without such
     layers."""
-    layers = len(getattr(cfg, "kda_layers", ()))
-    if not layers:
+    kw = _arenas(cfg, max_batch_size)
+    if "state_heads" not in kw:
         return 0
-    return layers * (max_batch_size + 1) * cfg.n_heads \
-        * cfg.kda_head_dim * cfg.kda_head_dim * 4
+    return kw["conv_layers"] * (kw["conv_slots"] + 1) * kw["state_heads"] \
+        * kw["state_dk"] * kw["state_dv"] * 4
 
 
 def window_pool_bytes(cfg, *, max_batch_size: int, block_size: int,
@@ -200,15 +206,19 @@ def _temp_bytes(cfg, *, num_blocks: int, block_size: int,
         2 * B * ctx * heads * hd * itemsize + B * heads * C * ctx * 4
         if reference_attn else 0
     )
-    acts = 6 * T * max(d, cfg.d_ff) * itemsize  # packed stream residuals
+    # packed stream residuals (a family of expert layers only: no d_ff)
+    acts = 6 * T * max(d, getattr(cfg, "d_ff", 0)) * itemsize
     # the rows' queries gathered (B, C, H, hd) for the ragged kernel, its
     # output the same, each with a folded copy
     acts += 4 * B * C * heads * hd * itemsize
-    if hasattr(cfg, "kda_layers"):
+    from ..models.families import step_family
+
+    scan = getattr(step_family(cfg), "scan_items", None)
+    if scan is not None:
         # the chunked scan's work items: five operands and the output,
-        # every row wasting less than one item of kda_chunk tokens
-        items = T // cfg.kda_chunk + B
-        acts += 6 * items * cfg.kda_chunk * cfg.kda_width * 4
+        # every row wasting less than one item of the scan's chunk
+        chunk, lanes = scan(cfg)
+        acts += 6 * (T // chunk + B) * chunk * lanes * 4
     if getattr(cfg, "n_experts", 0):
         # routed pairs laid out by expert in whole tiles of 16 rows: the
         # gathered inputs, the experts' hidden rows and their outputs
@@ -504,9 +514,10 @@ def step_flops_per_token(cfg, params, *, tp: int = 1) -> int:
         return 2 * _params_bytes(cfg, None, tp, 1)  # a byte a parameter
     import jax
 
-    held = getattr(cfg, "n_experts", 0)
-    share = getattr(cfg, "top_k", 0) / max(
-        getattr(cfg, "router_experts", held), 1)
+    # the experts whose matrices the plan holds (a share of an
+    # expert-parallel deployment holds fewer than the router chooses among)
+    held = getattr(cfg, "held_experts", getattr(cfg, "n_experts", 0))
+    share = getattr(cfg, "top_k", 0) / max(getattr(cfg, "n_experts", held), 1)
     flops = 0.0
     for leaf in jax.tree_util.tree_leaves(params):
         shape = getattr(leaf, "shape", ())

@@ -43,6 +43,16 @@ same names: ``XLA Ops`` events ``_kda_chunk_fn`` / ``_kda_step_fn``):
 token: the recurrence; more: items) and :func:`kda_decode` is the
 recurrence alone.  Off the chip both run in plain ``jnp`` unless asked for
 the interpreted kernels.
+
+One decay a head (PR 38: a gated delta rule whose ``g`` is one number a
+head and token, models/qwen3_next.py) is the per-channel rule with a head's
+``dk`` channels equal, so the same two kernels compute it.  ``g`` then
+comes as ``(T, H)`` (no trailing channel axis), crosses HBM in that form
+((NW, n, H) f32 to the chunk kernel, (B, 1, H) to the step kernel) and is
+broadcast over the channels in VMEM: a head's column of the item's ``(n,
+H)`` block, picked by a masked lane sum because the head index is traced,
+laid along the ``dk`` lanes.  Nothing of this is traced where ``g`` comes a
+channel: those kernels are the jaxprs they were.
 """
 
 from __future__ import annotations
@@ -149,10 +159,10 @@ def _step_math(s, a_col, k_col, kb_col, q_col, vb_row):
 
 def kda_recurrence(q, k, kb, vb, g, s0):
     """q, k, kb, g (T, H, dk), vb (T, H, dv), s0 (H, dk, dv) f32 ->
-    (o (T, H, dv) f32, s (H, dk, dv))."""
+    (o (T, H, dv) f32, s (H, dk, dv)).  ``g`` (T, H): one decay a head."""
     def body(s, x):
         q1, k1, kb1, vb1, g1 = (y.astype(F32) for y in x)
-        s = s * jnp.exp(g1)[:, :, None]
+        s = s * jnp.exp(g1).reshape(g1.shape[0], -1, 1)  # (H, dk | 1, 1)
         u = vb1 - jnp.einsum("hkv,hk->hv", s, kb1, precision=_HI)
         s = s + k1[:, :, None] * u[:, None, :]
         return s, jnp.einsum("hkv,hk->hv", s, q1, precision=_HI)
@@ -166,9 +176,19 @@ def kda_recurrence(q, k, kb, vb, g, s0):
 _LIVE, _FRESH, _FIRST = 1, 2, 4
 
 
+def _head_decay(g_ref, head, dk: int):
+    """One decay a head: the item's block is (n, H) f32, all heads'; head
+    ``head`` (traced) is its column, picked by a masked lane sum and laid
+    along the ``dk`` channels."""
+    g = g_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, g.shape, 1)
+    col = jnp.sum(jnp.where(lane == head, g, 0.0), axis=1, keepdims=True)
+    return jnp.broadcast_to(col, (g.shape[0], dk))
+
+
 def _kda_chunk_kernel(li_ref, slot_ref, flag_ref, q_ref, k_ref, kb_ref,
                       vb_ref, g_ref, s_in, o_ref, s_out, s_scr, *, heads: int,
-                      dk: int, dv: int):
+                      dk: int, dv: int, head_decay: bool = False):
     """Grid (head blocks, items), items innermost: the state of ``heads``
     heads rides ``s_scr`` from an item to the next item of its row.  The
     arena's block is the item's slot on the way in and, aliased, on the way
@@ -177,6 +197,7 @@ def _kda_chunk_kernel(li_ref, slot_ref, flag_ref, q_ref, k_ref, kb_ref,
     the last live item's indices: no copy, no work."""
     i = pl.program_id(1)
     flag = flag_ref[i]
+    head0 = pl.program_id(0) * heads if head_decay else None
 
     @pl.when((flag & _LIVE) != 0)
     def _live():
@@ -188,8 +209,10 @@ def _kda_chunk_kernel(li_ref, slot_ref, flag_ref, q_ref, k_ref, kb_ref,
             lv = pl.ds(pl.multiple_of(h * dv, dv), dv)
             s0 = jnp.where(first, s_in[h], s_scr[h])
             s0 = jnp.where(fresh, 0.0, s0)
-            o, s1 = _chunk_math(q_ref[:, lk], k_ref[:, lk], kb_ref[:, lk],
-                                vb_ref[:, lv], g_ref[:, lk], s0)
+            tok = (q_ref[:, lk], k_ref[:, lk], kb_ref[:, lk], vb_ref[:, lv])
+            g = _head_decay(g_ref, head0 + h, dk) \
+                if head_decay else g_ref[:, lk]
+            o, s1 = _chunk_math(*tok, g, s0)
             o_ref[:, lv] = o.astype(o_ref.dtype)
             s_scr[h] = s1
             s_out[h] = s1
@@ -202,7 +225,8 @@ def _kda_chunk_fn(q, k, kb, vb, g, state, layer, item_slot, item_flag, *,
                   interpret: bool = False):
     """q, k, kb, g (NW, n, H * dk), vb (NW, n, H * dv): the items' tokens,
     heads side by side on the lanes; state (L, slots, H, dk, dv) f32, all
-    layers' arena, updated in place at ``layer`` ((1,) int32); item_slot,
+    layers' arena, updated in place at ``layer`` ((1,) int32); ``g`` (NW,
+    n, H): one decay a head (the module docstring's last paragraph); item_slot,
     item_flag (NW,) int32 (:data:`_LIVE` | :data:`_FRESH`: start from zero |
     :data:`_FIRST`: the row's first item; dead items carry the last live
     item's slot).  Returns ``(o (NW, n, H * dv), state)``."""
@@ -219,14 +243,19 @@ def _kda_chunk_fn(q, k, kb, vb, g, state, layer, item_slot, item_flag, *,
     tok_k = pl.BlockSpec((None, n, hb * dk), item)
     tok_v = pl.BlockSpec((None, n, hb * dv), item)
     slot_spec = pl.BlockSpec((None, None, hb, dk, dv), arena)
+    head_decay = g.shape[2] != H * dk
+    tok_g, extra = tok_k, {}
+    if head_decay:  # every head's decay of the item, whatever the head block
+        tok_g = pl.BlockSpec((None, n, H), lambda h, i, *_: (i, 0, 0))
+        extra = {"head_decay": True}
     # alias indices count the scalar-prefetch operands: the arena is
     # operand 8 of (layer, slot, flag, q, k, kb, vb, g, state)
     return pl.pallas_call(
-        functools.partial(_kda_chunk_kernel, heads=hb, dk=dk, dv=dv),
+        functools.partial(_kda_chunk_kernel, heads=hb, dk=dk, dv=dv, **extra),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,  # layer, item_slot, item_flag
             grid=(H // hb, NW),
-            in_specs=[tok_k, tok_k, tok_k, tok_v, tok_k, slot_spec],
+            in_specs=[tok_k, tok_k, tok_k, tok_v, tok_g, slot_spec],
             out_specs=[tok_v, slot_spec],
             scratch_shapes=[pltpu.VMEM((hb, dk, dv), F32)],
         ),
@@ -258,6 +287,8 @@ def kda_chunk_reference(q, k, kb, vb, g, state, layer, item_slot, item_flag):
         live = (flag & _LIVE) != 0
         s0 = jnp.where((flag & _FIRST) != 0, state[li, slot], s)
         s0 = jnp.where((flag & _FRESH) != 0, 0.0, s0)
+        if gi.shape[1] != H * dk:  # one decay a head
+            gi = jnp.repeat(gi, dk, axis=1)
         o, s1 = math(heads(qi, dk), heads(ki, dk), heads(kbi, dk),
                      heads(vbi, dv), heads(gi, dk), s0)
         s1 = jnp.where(live, s1, s)
@@ -293,7 +324,8 @@ def _kda_step_kernel(li_ref, slot_ref, fresh_ref, a_ref, k_ref, kb_ref, q_ref,
 
 def _kda_step_fn(a, k, kb, q, vb, state, layer, row_slot, row_fresh, *,
                  interpret: bool = False):
-    """a (the decays ``exp(g)``), k, kb, q (B, dk, H) f32; vb (B, H, dv);
+    """a (the decays ``exp(g)``: (B, dk, H), or (B, 1, H) one a head), k,
+    kb, q (B, dk, H) f32; vb (B, H, dv);
     state (L, slots, H, dk, dv) f32, updated in place at ``layer`` and the
     rows' slots (``row_slot`` (B,); a row that is not a decode row rides
     the null slot 0); ``row_fresh`` (B,): start from zero.  Returns
@@ -301,6 +333,8 @@ def _kda_step_fn(a, k, kb, q, vb, state, layer, row_slot, row_fresh, *,
     B, dk, H = k.shape
     dv = vb.shape[2]
     col = pl.BlockSpec((None, dk, H), lambda b, *_: (b, 0, 0))
+    dec = col if a.shape[1] == dk \
+        else pl.BlockSpec((None, 1, H), lambda b, *_: (b, 0, 0))
     row = pl.BlockSpec((None, H, dv), lambda b, *_: (b, 0, 0))
     slot_spec = pl.BlockSpec(
         (None, None, H, dk, dv),
@@ -312,7 +346,7 @@ def _kda_step_fn(a, k, kb, q, vb, state, layer, row_slot, row_fresh, *,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,  # layer, row_slot, row_fresh
             grid=(B,),
-            in_specs=[col, col, col, col, row, slot_spec],
+            in_specs=[dec, col, col, col, row, slot_spec],
             out_specs=[row, slot_spec],
         ),
         out_shape=[jax.ShapeDtypeStruct((B, H, dv), F32),
@@ -358,10 +392,12 @@ def _columns(x):
 def kda_decode(q, k, kb, vb, g, state, layer: int, row_slot, row_fresh, *,
                use_pallas: bool | None = None, interpret: bool | None = None):
     """One token a row.  q, k, kb, g (B, H, dk), vb (B, H, dv); state the
-    arena; row_slot, row_fresh (B,).  Returns ``(o (B, H, dv) f32,
-    state)``."""
+    arena; row_slot, row_fresh (B,).  ``g`` (B, H): one decay a head.
+    Returns ``(o (B, H, dv) f32, state)``."""
     use_pallas, interpret = _use_kernels(use_pallas, interpret)
-    args = (_columns(jnp.exp(g.astype(F32))), _columns(k), _columns(kb),
+    a = jnp.exp(g.astype(F32))
+    args = (a[:, None, :] if g.ndim == 2 else _columns(a), _columns(k),
+            _columns(kb),
             _columns(q), vb, state, jnp.asarray(layer, jnp.int32).reshape(1),
             row_slot.astype(jnp.int32), row_fresh.astype(jnp.int32))
     if not use_pallas:
@@ -419,7 +455,7 @@ def kda_mixed(q, k, kb, vb, g, state, layer: int, items: dict, row_first,
               row_fresh, row_nvalid, row_slot, row_live, *,
               use_pallas: bool | None = None, interpret: bool | None = None):
     """A packed step's KDA mixing.  q, k, kb, g (T, H, dk), vb (T, H, dv):
-    the stream; ``items``: :func:`chunk_items` of the step (the same for
+    the stream (``g`` (T, H): one decay a head); ``items``: :func:`chunk_items` of the step (the same for
     every layer); ``row_live`` (B,): the row is no padding; rows of one
     valid token take the recurrence, the others the chunk kernel.  Returns ``(o (T, H, dv) f32, state)``; a padding
     token's ``o`` is zero."""
@@ -444,8 +480,10 @@ def kda_mixed(q, k, kb, vb, g, state, layer: int, items: dict, row_first,
             y = _mask_padding(y, ok[..., None])
         return y.reshape(NW, n, H * d)
 
+    g_items = gather(g.astype(F32)[..., None], 1, True) if g.ndim == 2 \
+        else gather(g.astype(F32), dk, True)
     args = (gather(q, dk), gather(k, dk), gather(kb, dk, True),
-            gather(vb, dv, True), gather(g.astype(F32), dk, True), state, li,
+            gather(vb, dv, True), g_items, state, li,
             items["slot"], items["flag"])
     if use_pallas:
         o_items, state = _kda_chunk(*args, interpret=interpret)

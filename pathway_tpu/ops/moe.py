@@ -7,9 +7,10 @@ layer is bound by the bytes of the expert weights it touches, not by its
 FLOPs.  The layout follows from
 that:
 
-- :func:`route` scores every token against every expert (sigmoid, f32),
-  chooses the ``top_k`` largest of ``score + bias`` and weighs them by the
-  scores alone, renormalised over the chosen;
+- :func:`route` scores every token against every expert (sigmoid, or a
+  softmax over all the experts' logits; f32), chooses the ``top_k`` largest
+  of ``score + bias`` and weighs them by the scores alone, renormalised
+  over the chosen;
 - :func:`group_rows` lays the ``tokens x top_k`` routed pairs out sorted by
   expert, each expert's group padded to whole tiles of :data:`TM` rows, so
   that a tile belongs to exactly one expert;
@@ -37,15 +38,21 @@ TM = 16  # rows a tile: one packed bf16 sublane tile
 
 
 def route(h, wg, bias, *, top_k: int, norm_topk: bool = True,
-          scale: float = 1.0, renorm_eps: float = 1e-6):
+          scale: float = 1.0, renorm_eps: float = 1e-6,
+          score: str = "sigmoid"):
     """h (T, D), wg (D, E), bias (E,) or None -> (experts (T, k) int32,
     weights (T, k) f32, scores (T, E) f32).  Scores in f32 at the highest
-    matmul precision: the choice is a comparison of neighbours.  ``bias``
-    moves the choice only; the weights are the chosen experts' scores,
-    over their sum + ``renorm_eps`` where ``norm_topk``."""
-    s = jax.nn.sigmoid(jnp.dot(
+    matmul precision: the choice is a comparison of neighbours.  ``score``:
+    ``"sigmoid"`` of each logit, or ``"softmax"`` over all ``E`` logits (the
+    chosen scores over their sum are then a softmax over the chosen logits;
+    ``renorm_eps=0``: no epsilon).  ``bias`` moves the choice only; the
+    weights are the chosen experts' scores, over their sum + ``renorm_eps``
+    where ``norm_topk``."""
+    logits = jnp.dot(
         h.astype(jnp.float32), wg.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
+        precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
+        else jax.nn.sigmoid(logits)
     sel = s if bias is None else s + bias.astype(jnp.float32)
     _, idx = jax.lax.top_k(sel, top_k)
     w = jnp.take_along_axis(s, idx, axis=1)
@@ -210,27 +217,32 @@ def grouped_matmul(x, w1, w3, w2, tile_expert, n_live, *, tm: int = TM,
 
 def expert_ffn(h, layer: dict, valid, *, top_k: int, norm_topk: bool = True,
                scale: float = 1.0, renorm_eps: float = 1e-6, h_route=None,
-               use_pallas: bool | None = None, first_expert=None):
+               use_pallas: bool | None = None, first_expert=None,
+               score: str = "sigmoid"):
     """One expert layer over a packed stream.  h (T, D) normed input in
     the experts' dtype, ``h_route`` the same before it was rounded to that
     dtype (f32; default h: the router then sees what the experts see);
     ``layer``: ``wg`` (D, E), ``expert_bias`` (E,) or absent, ``w1`` /
-    ``w3`` (E, D, F), ``w2`` (E, F, D); valid (T,) bool.  Returns
-    ``(sum_e w_e expert_e(h) (T, D), counts (E,) int32)``.
+    ``w3`` (E, D, F), ``w2`` (E, F, D); valid (T,) bool; ``score``:
+    :func:`route`'s.  Returns ``(sum_e w_e expert_e(h) (T, D), the layer's
+    device counters int32[held + len(COUNTER_TAIL)])``: the tokens each held
+    expert received and then :data:`COUNTER_TAIL`.  A step program sums the
+    vector over its expert layers and hands it to its cache
+    (kvcache/backend.py ``ExpertCounts``).
 
     ``first_expert`` (an int): the layer is one share of an expert-parallel
     deployment and holds the experts ``first_expert .. first_expert + held``
     only, ``held`` the leading axis of ``w1``.  The router keeps its ``E``
     outputs and its ``top_k``; a pair routed to an expert held elsewhere
     takes no row, touches no expert and adds nothing (its part of the sum
-    is the other shares', which no code here stands in for).  Returns
-    ``(the held experts' part (T, D), counts (held,) int32, pairs routed
-    elsewhere () int32)``."""
+    is the other shares', which no code here stands in for) and is counted
+    in the vector's ``moe_pairs_elsewhere``."""
     E = layer["wg"].shape[1]
     experts, weights, _s = route(h if h_route is None else h_route,
                                  layer["wg"], layer.get("expert_bias"),
                                  top_k=top_k, norm_topk=norm_topk,
-                                 scale=scale, renorm_eps=renorm_eps)
+                                 scale=scale, renorm_eps=renorm_eps,
+                                 score=score)
     held = E
     if first_expert is not None:
         held = layer["w1"].shape[0]
@@ -245,7 +257,17 @@ def expert_ffn(h, layer: dict, valid, *, top_k: int, norm_topk: bool = True,
         else (valid[:, None] & mine)[:, :, None]
     pairs = jnp.where(here, y[g["pair_row"]], 0)
     out = jnp.sum(pairs.astype(jnp.float32) * weights[:, :, None], axis=1)
-    if first_expert is None:
-        return out.astype(h.dtype), g["counts"]
-    elsewhere = jnp.sum(valid[:, None] & ~mine).astype(jnp.int32)
-    return out.astype(h.dtype), g["counts"], elsewhere
+    elsewhere = jnp.int32(0) if first_expert is None \
+        else jnp.sum(valid[:, None] & ~mine).astype(jnp.int32)
+    tail = jnp.stack([elsewhere, g["n_live"][0],
+                      jnp.sum(g["counts"] > 0).astype(jnp.int32),
+                      jnp.int32(1)])
+    return out.astype(h.dtype), jnp.concatenate([g["counts"], tail])
+
+
+# what follows the tokens-per-held-expert in a layer's counter vector:
+# pairs routed to experts held elsewhere; the grouped matmul's live row
+# tiles (of TM rows); held experts that received at least one pair; the
+# passes themselves (1 a call: every count above is a sum over them)
+COUNTER_TAIL = ("moe_pairs_elsewhere", "moe_live_tiles",
+                "moe_experts_touched", "moe_expert_passes")

@@ -194,6 +194,13 @@ class KVCacheStats:
       started a slot's state from zero) and
       ``pathway_kv_moe_pairs_elsewhere_total{pool}`` ((token, expert) pairs
       the router sent to experts another share holds) counters
+    - ``pathway_kv_moe_live_tiles_total{pool}`` (row tiles of 16 the grouped
+      matmul ran, summed over expert layers and steps) and
+      ``pathway_kv_moe_experts_touched_total{pool}`` (held experts that
+      received at least one pair, summed likewise) and
+      ``pathway_kv_moe_expert_passes_total{pool}`` (the expert layers'
+      passes themselves, one a layer and step: what the other two are
+      sums over) counters, of every cache whose family routes
     - ``pathway_kv_window_blocks_in_use{pool}`` / ``..._total{pool}`` gauges
       (windowed caches: blocks of the sliding-window layers' pool held, and
       its size), ``pathway_kv_window_blocks_allocated_total{pool}`` /
@@ -275,6 +282,12 @@ class KVCacheStats:
         self.moe_routed_pairs = 0
         self.moe_tokens_per_expert: list[int] = []
         self.moe_fullest_expert_tokens = 0  # sum over programs of the max
+        # the grouped matmul's live row tiles and the held experts with at
+        # least one pair, summed over expert layers and steps, and the
+        # number of those passes (one an expert layer and step)
+        self.moe_live_tiles = 0
+        self.moe_experts_touched = 0
+        self.moe_expert_passes = 0
         # state caches (kvcache/hybrid.py StateCache): the matrix-state
         # slots beside the conv ones, the first chunks that started a state
         # from zero, and the pairs routed to experts held elsewhere
@@ -305,10 +318,6 @@ class KVCacheStats:
         with self._lock:
             self.kda_state_resets += n
 
-    def record_pairs_elsewhere(self, n: int) -> None:
-        with self._lock:
-            self.moe_pairs_elsewhere += n
-
     @property
     def window_blocks_in_use(self) -> int:
         fn = self._window_blocks_in_use_fn
@@ -327,8 +336,14 @@ class KVCacheStats:
             self.kv_window_keys += keys
             self.kv_window_ctx_keys += ctx_keys
 
-    def record_moe(self, counts) -> None:
+    def record_moe(self, counts, tail=()) -> None:
+        """One step program's device counters: tokens per held expert,
+        summed over its expert layers, and ``tail``, ``(name, count)`` of
+        the counters that follow them (ops/moe.py ``COUNTER_TAIL``: each
+        is a counter of this object under its own name)."""
         with self._lock:
+            for name, n in tail:
+                setattr(self, name, getattr(self, name) + int(n))
             if len(self.moe_tokens_per_expert) != len(counts):
                 self.moe_tokens_per_expert = [0] * len(counts)
             for e, n in enumerate(counts):
@@ -551,6 +566,9 @@ class KVCacheStats:
                 "moe_routed_pairs": self.moe_routed_pairs,
                 "moe_tokens_per_expert": list(self.moe_tokens_per_expert),
                 "moe_fullest_expert_tokens": self.moe_fullest_expert_tokens,
+                "moe_live_tiles": self.moe_live_tiles,
+                "moe_experts_touched": self.moe_experts_touched,
+                "moe_expert_passes": self.moe_expert_passes,
                 "state_slots_in_use": self.state_slots_in_use,
                 "state_slots_total": self.state_slots_total,
                 "kda_state_resets": self.kda_state_resets,
@@ -1003,6 +1021,9 @@ def _render_kv_lines() -> list[str]:
         "# TYPE pathway_kv_conv_slots_total gauge",
         "# TYPE pathway_kv_moe_routed_pairs_total counter",
         "# TYPE pathway_kv_moe_tokens_per_expert_total counter",
+        "# TYPE pathway_kv_moe_live_tiles_total counter",
+        "# TYPE pathway_kv_moe_experts_touched_total counter",
+        "# TYPE pathway_kv_moe_expert_passes_total counter",
         "# TYPE pathway_kv_state_slots_in_use gauge",
         "# TYPE pathway_kv_state_slots_total gauge",
         "# TYPE pathway_kv_kda_state_resets_total counter",
@@ -1199,8 +1220,9 @@ def _render_kv_lines() -> list[str]:
                              f"{snap['kv_' + key]}")
         if snap["conv_slots_total"] or snap["window_blocks_total"]:
             # the caches of the families with expert layers
-            lines.append(f"pathway_kv_moe_routed_pairs_total{{{lbl}}} "
-                         f"{snap['moe_routed_pairs']}")
+            for key in ("moe_routed_pairs", "moe_live_tiles",
+                        "moe_experts_touched", "moe_expert_passes"):
+                lines.append(f"pathway_kv_{key}_total{{{lbl}}} {snap[key]}")
             for e, n in enumerate(snap["moe_tokens_per_expert"]):
                 lines.append(
                     f'pathway_kv_moe_tokens_per_expert_total{{{lbl},'

@@ -1,5 +1,5 @@
 """The engine<->cache contract (kvcache/backend.py) and the family seam
-(models/families.py), over the four kinds of cache that serve cells:
+(models/families.py), over the five kinds of cache that serve cells:
 
 - ``paged``: BlockPool, K/V blocks for every layer;
 - ``hybrid``: HybridCache, K/V blocks for the attention layers and a conv
@@ -9,7 +9,9 @@
   are freed behind the window;
 - ``latent_state``: StateCache, a latent pool of one array (a stored row is
   key and value both) and, under one slot a sequence, the conv inputs and
-  the f32 matrix states of the delta-rule layers.
+  the f32 matrix states of the delta-rule layers;
+- ``kv_state``: KVStateCache, that state cache with a plain K/V pool (keys
+  AND values) in the latent pool's place.
 
 Each contract test runs over every kind through ``make_backend``, the seam
 the engine builds (and, on a supervised restart, rebuilds) its cache
@@ -39,15 +41,17 @@ from pathway_tpu.models.decoder import DecoderConfig, init_decoder_params
 from pathway_tpu.models.families import step_family
 from pathway_tpu.serve import metrics as serve_metrics
 
-KINDS = ("paged", "hybrid", "windowed", "latent_state")
-PAGED_KERNEL_KINDS = KINDS[:3]  # the kinds whose kernels are _paged_*_fn
-SLOTTED = ("hybrid", "latent_state")
+KINDS = ("paged", "hybrid", "windowed", "latent_state", "kv_state")
+# the kinds whose kernels are _paged_*_fn
+PAGED_KERNEL_KINDS = ("paged", "hybrid", "windowed", "kv_state")
+SLOTTED = ("hybrid", "latent_state", "kv_state")
+STATEFUL = ("latent_state", "kv_state")  # a matrix state beside the conv slot
 _GEOM = dict(num_blocks=24, block_size=4, n_layers=2, n_heads=2, head_dim=8)
 _CONV = dict(conv_layers=3, conv_width=16, conv_slots=5)
 _WINDOW = dict(window=10, window_layers=3, round_tokens=6, max_seqs=5)
 _STATE = dict(_CONV, conv_taps=3, state_heads=2, state_dk=8, state_dv=8)
 _EXTRA = {"paged": {}, "hybrid": _CONV, "windowed": _WINDOW,
-          "latent_state": _STATE}
+          "latent_state": _STATE, "kv_state": _STATE}
 
 _CFG = DecoderConfig(
     vocab_size=64, d_model=64, n_layers=2, n_heads=8, d_ff=128, max_len=128
@@ -107,9 +111,25 @@ def kimi():
 
 
 @pytest.fixture(scope="module")
-def lfm2(lfm2_only, afmoe, kimi):
-    """The three families that bring a cache of their own, by its kind."""
-    return {"hybrid": lfm2_only, "windowed": afmoe, "latent_state": kimi}
+def qwen3_next():
+    from pathway_tpu.models.qwen3_next import (Qwen3NextConfig,
+                                               init_qwen3_next_params)
+
+    g, f = "linear_attention", "full_attention"
+    cfg = Qwen3NextConfig(vocab_size=257, d_model=64, n_heads=4,
+                          n_kv_heads=2, head_dim=16, rotary_dim=4,
+                          gdn_key_heads=2, gdn_value_heads=4, gdn_key_dim=16,
+                          gdn_value_dim=16, d_ff_expert=32, d_ff_shared=32,
+                          n_experts=8, top_k=2, layer_types=(g, f, g),
+                          max_len=256, dtype=jnp.float32, gdn_chunk=8)
+    return cfg, init_qwen3_next_params(cfg, jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module")
+def lfm2(lfm2_only, afmoe, kimi, qwen3_next):
+    """The four families that bring a cache of their own, by its kind."""
+    return {"hybrid": lfm2_only, "windowed": afmoe, "latent_state": kimi,
+            "kv_state": qwen3_next}
 
 
 def _engine(kind, params, lfm2, name, **kw):
@@ -131,7 +151,8 @@ def _cache_arrays(kind) -> int:
     """K and V pools, and the hybrid kind's conv arena or the windowed
     kind's second pool pair; the latent pool (one array) and the state
     kind's two arenas."""
-    return {"paged": 2, "hybrid": 3, "windowed": 4, "latent_state": 3}[kind]
+    return {"paged": 2, "hybrid": 3, "windowed": 4, "latent_state": 3,
+            "kv_state": 4}[kind]
 
 
 # -- the contract, over both kinds ---------------------------------------------
@@ -234,7 +255,7 @@ def test_per_shard_bytes_are_the_bytes_of_the_device_state(kind):
     assert len(state) == _cache_arrays(kind)
     # the matrix states are f32 whatever the cache's dtype
     assert [a.dtype for a in state] == [jnp.bfloat16] * (len(state) - 1) + [
-        jnp.float32 if kind == "latent_state" else jnp.bfloat16]
+        jnp.float32 if kind in STATEFUL else jnp.bfloat16]
     assert pool.per_shard_bytes == _nbytes(state)
     # what /metrics reports a shard to hold
     assert pool.stats.shard_hbm_bytes == pool.per_shard_bytes
@@ -291,7 +312,7 @@ def test_retire_hands_the_name_and_its_gauges_to_the_next_cache(kind):
     assert _gauge(lines, "pathway_kv_blocks_in_use", name) == [3.0]
     if kind in SLOTTED:
         assert _gauge(lines, "pathway_kv_conv_slots_in_use", name) == [1.0]
-    if kind == "latent_state":
+    if kind in STATEFUL:
         assert _gauge(lines, "pathway_kv_state_slots_in_use", name) == [1.0]
     if kind == "windowed":
         first.reserve_chunk(2, 9)
@@ -310,7 +331,7 @@ def test_retire_hands_the_name_and_its_gauges_to_the_next_cache(kind):
         assert _gauge(lines, "pathway_kv_conv_slots_in_use", name) == [0.0]
         assert _gauge(lines, "pathway_kv_conv_slots_total", name) \
             == [float(_CONV["conv_slots"])]
-    if kind == "latent_state":
+    if kind in STATEFUL:
         assert _gauge(lines, "pathway_kv_state_slots_total", name) \
             == [float(_CONV["conv_slots"])]
     if kind == "windowed":
@@ -583,3 +604,123 @@ def test_the_engine_imports_no_models_math():
         src = f.read()
     assert not any("models." + m in src for m in ("decoder", "lfm2", "afmoe"))
     assert "afmoe" not in src  # no keyword, no branch names the family
+
+
+# -- the fifth kind: a K/V pool beside the state arena (PR 38) ------------------
+
+
+def test_kv_state_cache_gives_blocks_and_slot_together_or_not_at_all():
+    """``KVStateCache`` is ``StateCache`` with a value pool: four device
+    arrays, the bytes of all four counted, and the slot logic it inherits
+    (blocks and slot together or neither, both freed, the invariants)."""
+    from pathway_tpu.kvcache.hybrid import KVStateCache, StateCache
+
+    assert issubclass(KVStateCache, StateCache)
+    assert issubclass(KVStateCache, HybridCache)
+    # nothing of the slot logic is written again
+    for name in ("allocate", "free_sequence", "preempt", "row_extras",
+                 "after_sync", "check_invariants", "device_state",
+                 "set_device_state", "fork", "suspend_host"):
+        assert name not in vars(KVStateCache), name
+    pool = make_backend(
+        "kv_state", num_blocks=6, block_size=8, n_layers=3, n_heads=2,
+        head_dim=256, dtype=jnp.bfloat16, name="t_cb_kv_state",
+        conv_layers=9, conv_width=8192, conv_taps=3, conv_slots=2,
+        state_heads=32, state_dk=128, state_dv=128)
+    assert type(pool) is KVStateCache and pool.cache_kind == "kv_state"
+    assert pool.k.shape == pool.v.shape == (3, 6, 8, 512)
+    assert pool.conv.shape == (9, 3, 3, 8192)
+    assert pool.state.shape == (9, 3, 32, 128, 128)
+    assert pool.state.dtype == jnp.float32
+    assert pool.per_shard_bytes == 2 * (2 * 3 * 6 * 8 * 512) \
+        + 2 * (9 * 3 * 3 * 8192) + 4 * (9 * 3 * 32 * 128 * 128)
+    k, v, conv, state = pool.device_state()
+    assert k is pool.k and v is pool.v and state is pool.state
+    pool.allocate(1, 20)                      # three blocks, one slot
+    with pytest.raises(PoolExhausted):
+        pool.allocate(2, 40)                  # five blocks: two are free
+    assert pool.slots_in_use == 1 and pool.num_free == 2
+    pool.allocate(2, 8)
+    with pytest.raises(PoolExhausted):
+        pool.allocate(3, 8)                   # a block is free, no slot is
+    assert pool.num_free == 1 and sorted(pool._slot_of) == [1, 2]
+    pool.check_invariants()
+    snap = pool.stats.snapshot()
+    assert (snap["blocks_in_use"], snap["state_slots_in_use"],
+            snap["conv_slots_in_use"]) == (4, 2, 2)
+    with pytest.raises(UnsupportedCacheOp):
+        pool.fork(1, 4)
+    victim = pool.preempt()
+    assert victim.seq_id == 2 and pool.slots_in_use == 1
+    pool._slot_of[9] = 2                      # a slot without its sequence
+    with pytest.raises(AssertionError):
+        pool.check_invariants()
+    del pool._slot_of[9]
+    pool.free_sequence(1)
+    pool.check_invariants()
+    assert pool.slots_in_use == 0 and pool.num_free == 5
+    assert pool.row_extras([], 4)[0].tolist() == [0, 0, 0, 0]
+    # what a program gives back: four arrays and the counter vector
+    pool.set_device_state(k, v, conv, state,
+                          jnp.asarray([3, 0, 5, 7, 2, 1, 4], jnp.int32))
+    pool.after_sync()
+    snap = pool.stats.snapshot()
+    assert snap["moe_tokens_per_expert"] == [3, 0, 5]
+    assert (snap["moe_routed_pairs"], snap["moe_pairs_elsewhere"],
+            snap["moe_live_tiles"], snap["moe_experts_touched"],
+            snap["moe_expert_passes"]) == (8, 7, 2, 1, 4)
+    pool.retire()
+
+
+@pytest.mark.parametrize("kind", ("hybrid", "windowed", "latent_state",
+                                  "kv_state"))
+def test_the_counter_vector_has_one_reader(kind, params, lfm2):
+    """Every family that routes hands its cache ONE vector a program:
+    tokens per held expert, then ``ops.moe.COUNTER_TAIL``; the caches fold
+    it through ``ExpertCounts.fold_expert_counts`` and nowhere else."""
+    from pathway_tpu.kvcache.backend import ExpertCounts
+    from pathway_tpu.ops.moe import COUNTER_TAIL
+
+    assert COUNTER_TAIL == ("moe_pairs_elsewhere", "moe_live_tiles",
+                            "moe_experts_touched", "moe_expert_passes")
+    eng = _engine(kind, params, lfm2, f"t_cb_counters_{kind}")
+    assert isinstance(eng.pool, ExpertCounts)
+    assert type(eng.pool).after_sync is not ExpertCounts  # the cache's own
+    eng.generate_batch([([5, 9, 20, 3, 7, 11, 2, 8, 1, 30], 6), ([41, 2], 4)])
+    snap = eng.pool.stats.snapshot()
+    cfg = lfm2[kind][0]
+    assert len(snap["moe_tokens_per_expert"]) == cfg.n_experts
+    assert snap["moe_pairs_elsewhere"] == 0     # every expert is held here
+    assert 0 < snap["moe_experts_touched"] <= snap["moe_live_tiles"] \
+        <= snap["moe_routed_pairs"] <= 16 * snap["moe_live_tiles"]
+    lines = serve_metrics.render_prometheus_lines()
+    name = f"t_cb_counters_{kind}"
+    assert _gauge(lines, "pathway_kv_moe_live_tiles_total", name) \
+        == [float(snap["moe_live_tiles"])]
+    assert _gauge(lines, "pathway_kv_moe_experts_touched_total", name) \
+        == [float(snap["moe_experts_touched"])]
+    # one pass an expert layer and forward pass: no held expert is touched
+    # more often than that
+    assert snap["moe_experts_touched"] \
+        <= cfg.n_experts * snap["moe_expert_passes"]
+    assert _gauge(lines, "pathway_kv_moe_expert_passes_total", name) \
+        == [float(snap["moe_expert_passes"])]
+
+
+def test_the_head_256_roofline_reads_the_attention_kernels_alone():
+    metrics = os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "metrics")
+    reads = {}
+    for metric in ("paged_attn_hd256_roofline", "gdn_scan_roofline",
+                   "qwen3next_expert_roofline"):
+        with open(os.path.join(metrics, metric + ".json")) as f:
+            pattern = json.load(f)["pattern"]
+        reads[metric] = [k for k in (
+            "_paged_ragged_fn", "_paged_append_fn", "_paged_write_fn",
+            "_kda_chunk_fn", "_kda_step_fn", "_moe_gmm_fn_w13",
+            "_moe_gmm_fn_w2", "_paged_latent_fn") if re.match(pattern,
+                                                              k + ".7")]
+    assert reads == {
+        "paged_attn_hd256_roofline": ["_paged_ragged_fn", "_paged_append_fn"],
+        "gdn_scan_roofline": ["_kda_chunk_fn", "_kda_step_fn"],
+        "qwen3next_expert_roofline": ["_moe_gmm_fn_w13", "_moe_gmm_fn_w2"]}

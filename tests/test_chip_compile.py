@@ -918,3 +918,180 @@ def test_kimi_packed_step_programs_lower_under_their_names(shape,
     for pool in (state[0].shape, state[2].shape):
         assert _pool_copies(compiled, pool) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 800 << 20
+
+
+# -- the qwen3_next block family (Qwen3-Next-80B-A3B's published widths) ------
+
+
+@pytest.mark.parametrize("chunk", [512, 1024], ids=["chosen", "cell"])
+def test_paged_kernels_compile_at_two_heads_of_256(shape, chunk):
+    """The three paged kernels at a head of 256 lanes (two lane tiles a
+    head, one head a group), eight query heads folded on each of 2 K/V
+    heads, a pool row of 512 lanes, at the chunk of 512 the engine's rule
+    chooses and at the 1,024 the cell runs (PERF.md, PR 38's sweep): 4,096
+    or 8,192 folded columns a K/V head, in column tiles of 256 rows under
+    the VMEM limit the call asks for; no kernel rule changed for it."""
+    import jax.numpy as jnp
+
+    pa = _paged()
+    kv, rep, hd, tables, i32 = 2, 8, 256, 512, jnp.int32
+    bf = jnp.bfloat16
+    assert pa.span_blocks(BS, tables, kv * hd) == 128 // BS
+    assert pa._heads_per_group(kv, hd, chunk * rep) == 1
+    assert pa._col_tiles(1, chunk * rep, rep, bf) == (16, 256)
+    pool = shape((3, 8193, BS, kv * hd), bf)
+    idx = (shape((1,), i32), shape((B, tables), i32), shape((B,), i32),
+           shape((B,), i32))
+    ragged = _compiled_kernel(
+        lambda q, k, v, li, bt, c0, cl: pa._paged_ragged_fn(
+            q, k, v, li, bt, c0, cl, d_true=hd),
+        shape((B, chunk, kv * rep, hd), bf), pool, pool, *idx)
+    new = shape((B, kv, hd), bf)
+    append = _compiled_kernel(
+        lambda q, k1, v1, k, v, li, bt, c0, cl, so: pa._paged_append_fn(
+            q, k1, v1, k, v, li, bt, c0, cl, so, d_true=hd),
+        shape((B, 1, kv * rep, hd), bf), new, new, pool, pool,
+        *idx, idx[-1], donate_argnums=(3, 4))
+    T = B + chunk
+    rows = shape((T, kv * hd), bf)
+    write = _compiled_kernel(
+        pa._paged_write_fn, rows, rows, pool, pool, shape((1,), i32),
+        shape((T,), i32), shape((T,), i32), donate_argnums=(2, 3))
+    for name, compiled in (("ragged", ragged), ("append", append),
+                           ("write", write)):
+        assert _pool_copies(compiled, pool.shape) == []
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        print(f"head 256 {name}: temporaries {temp} bytes")
+        assert temp < 1 << 20
+
+
+def test_kda_kernels_compile_with_one_decay_a_head(shape):
+    """The two delta-rule kernels told that the decay is one number a head:
+    the chunk kernel takes ``g`` as (items, 128, 32) f32 (every head's decay
+    of an item in one block, a head's column picked in VMEM), the step
+    kernel as (rows, 1, 32); 32 value heads of 128 x 128, nine layers'
+    arena aliased in place."""
+    import jax.numpy as jnp
+
+    from pathway_tpu.ops import kda
+
+    bf, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
+    NW, n, W = kda.n_items(528, 16), kda.CHUNK, 32 * 128
+    tok = shape((NW, n, W), bf)
+    arena = _kimi_arena(shape, layers=9)
+    chunk = _compiled_kernel(
+        kda._kda_chunk_fn, tok, tok, tok, tok, shape((NW, n, 32), f32),
+        arena, shape((1,), i32), shape((NW,), i32), shape((NW,), i32),
+        donate_argnums=(5,))
+    col = shape((16, 128, 32), f32)
+    step = _compiled_kernel(
+        kda._kda_step_fn, shape((16, 1, 32), f32), col, col, col,
+        shape((16, 32, 128), bf), arena, shape((1,), i32),
+        shape((16,), i32), shape((16,), i32), donate_argnums=(5,))
+    for compiled in (chunk, step):
+        assert compiled.as_text().count("tpu_custom_call") == 1
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_grouped_matmul_kernel_compiles_at_128_of_512_experts(shape):
+    """128 held experts of 3 x 2048 x 512 in bf16 and the routed pairs of a
+    mixed step of 528 tokens top-10 (all of them, were they to fall here),
+    in whole tiles of 16."""
+    import jax.numpy as jnp
+
+    from pathway_tpu.ops import moe
+
+    E, D, Fe, pairs = 128, 2048, 512, 528 * 10
+    tiles = moe.n_tiles(pairs, E)
+    assert tiles == 458
+    bf16 = jnp.bfloat16
+    compiled = _compiled_kernel(
+        moe._moe_gmm_fn, shape((tiles * moe.TM, D), bf16),
+        shape((E, D, Fe), bf16), shape((E, D, Fe), bf16),
+        shape((E, Fe, D), bf16), shape((tiles,), jnp.int32),
+        shape((1,), jnp.int32))
+    assert compiled.as_text().count("tpu_custom_call") == 2
+
+
+def _qwen3_next_case(shape):
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.models import families, qwen3_next as m
+
+    fam = families.Qwen3NextFamily
+    cfg = m.Qwen3NextConfig(n_held_experts=128, max_len=8192,
+                            layer_types=(m.GDN, m.GDN, m.FULL),
+                            dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(lambda: fam.plan(
+        cfg, m.init_qwen3_next_params(cfg, jax.random.PRNGKey(0)), tp=1,
+        quantize=None))
+    params = jax.tree_util.tree_map(lambda s: shape(s.shape, s.dtype), shapes)
+    assert params["layers"][0]["wqkvz"].shape == (2048, 12288)
+    assert params["layers"][0]["conv_w"].shape == (8192, 4)
+    assert params["layers"][2]["wq"].shape == (2048, 16 * 512)
+    assert params["layers"][1]["w1"].shape == (128, 2048, 512)
+    assert params["layers"][1]["wg"].shape == (2048, 512)
+    pool = shape((1, 8193, BS, 2 * 256), jnp.bfloat16)
+    state = (pool, pool, shape((2, 17, 3, 8192), jnp.bfloat16),
+             _kimi_arena(shape, layers=2))
+    return fam, cfg, params, state
+
+
+@pytest.mark.parametrize("program", ["mixed", "chained"])
+def test_qwen3_next_packed_step_programs_lower_under_their_names(
+        shape, monkeypatch, program):
+    """The family's mixed and chained programs as the engine jits them at
+    the published widths, three layers deep (two gated DeltaNet, one full;
+    128 of 512 experts held): the module is ``jit__mixed_fn`` /
+    ``jit__chained_fn``, the kernels' functions carry the names the
+    benchmark's readers search the device trace for, every kernel is in the
+    compiled text (a mixed step: step and chunk kernels a DeltaNet layer,
+    writer and attention a full layer, two grouped matmuls a layer), the
+    four cache arrays are donated, the K/V pools and the state arena enter
+    row-major and are not copied."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.kvcache.packing import RoundLayout
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fam, cfg, params, state = _qwen3_next_case(shape)
+    i32, rows, chunk, tables = jnp.int32, 16, 512, 512
+
+    def vec(*dims):
+        return shape(dims, i32)
+
+    T = rows + chunk
+    host = {"mixed": (vec(T), vec(T), vec(rows, tables), vec(rows),
+                      vec(rows), vec(rows, chunk), vec(T), vec(T), vec(T),
+                      vec(T), vec(rows), vec(rows)),
+            "chained": (vec(rows), vec(rows), vec(rows, tables),
+                        vec(rows, 16), vec(rows, 16), vec(rows))}[program]
+    fn, donated = fam.programs(cfg, "pallas", None)[program]
+    assert tuple(donated) == (1, 2, 3, 4)
+    layout = RoundLayout(host)
+    lowered = jax.jit(layout.program(fn), donate_argnums=donated).lower(
+        params, *state, shape((layout.size,), i32))
+    text = lowered.as_text()
+    assert re.search(r"module @(\w+)", text).group(1) == f"jit__{program}_fn"
+    kernels = {re.sub(r"_\d+$", "", f) for f in re.findall(
+        r"func\.func \w+ @(_(?:paged|kda|moe)_\w+)\(", text)}
+    assert kernels == ({"_paged_ragged_fn", "_paged_write_fn",
+                        "_kda_chunk_fn", "_kda_step_fn", "_moe_gmm_fn"}
+                       if program == "mixed" else
+                       {"_paged_append_fn", "_kda_step_fn", "_moe_gmm_fn"})
+    compiled = lowered.compile()
+    assert compiled.as_text().count("tpu_custom_call") == (
+        2 * 2 + 2 + 2 * 3 if program == "mixed" else 2 + 1 + 2 * 3)
+    layouts = compiled.input_formats[0]
+    for i, rank in ((1, 4), (2, 4), (4, 5)):
+        assert layouts[i].layout.major_to_minor == tuple(range(rank)), \
+            layouts[i]
+    for pool in (state[0].shape, state[3].shape):
+        assert _pool_copies(compiled, pool) == []
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"qwen3_next {program}: temporaries {temp / 2**20:.1f} MiB")
+    assert temp < 800 << 20

@@ -202,3 +202,102 @@ def test_lfm2_moe_config_import(case):
         assert 4.60e9 < cfg.param_count() < 4.61e9  # 9.2 GB in bf16
     else:
         assert cfg.n_layers == 24 and len(cfg.attn_layers) == 6
+
+
+# -- qwen3_next (PR 38) --------------------------------------------------------
+
+
+def _qwen3_next_row():
+    import json
+
+    path = "/opt/skills/guides/model-configs/architectures.jsonl"
+    try:
+        with open(path) as f:
+            rows = [json.loads(line) for line in f]
+    except OSError:
+        pytest.skip("the catalog is not here")
+    return next(r for r in rows
+                if r["name"] == "Qwen3-Next-80B-A3B-Instruct")["config"]
+
+
+def test_config_from_qwen3_next_on_the_catalog_row():
+    import types
+
+    from pathway_tpu.models import hf_import
+    from pathway_tpu.models.qwen3_next import FULL, GDN
+
+    cfg = hf_import.config_from_qwen3_next(
+        types.SimpleNamespace(**_qwen3_next_row()), dtype="bfloat16")
+    assert cfg.family == "qwen3_next" and cfg.n_layers == 48
+    assert cfg.layer_types == (GDN, GDN, GDN, FULL) * 12
+    assert cfg.full_layers == tuple(range(3, 48, 4))
+    assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
+            cfg.rotary_dim) == (2048, 16, 2, 256, 64)
+    assert (cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim,
+            cfg.gdn_value_dim, cfg.conv_kernel) == (16, 32, 128, 128, 4)
+    assert (cfg.key_width, cfg.value_width, cfg.conv_width) \
+        == (2048, 4096, 8192)
+    assert (cfg.n_experts, cfg.held_experts, cfg.share, cfg.top_k) \
+        == (512, 512, None, 10)
+    assert (cfg.d_ff_expert, cfg.d_ff_shared) == (512, 512)
+    assert cfg.rope_theta == 1e7 and cfg.norm_eps == 1e-6
+    assert cfg.max_len == 262144 and cfg.vocab_size == 151936
+    # 79.7 B parameters: 159 GB of bf16
+    assert round(cfg.param_count() / 1e9, 1) == 79.7
+
+
+def test_config_from_qwen3_next_takes_a_share_and_a_cut():
+    """The cell's cut: published layers 1-12, 128 of the 512 experts held,
+    a served context of 8,192; the issue's arithmetic to the parameter."""
+    import types
+
+    from pathway_tpu.models import hf_import
+
+    pub = dict(_qwen3_next_row(), num_hidden_layers=12, num_experts=128)
+    cfg = hf_import.config_from_qwen3_next(
+        types.SimpleNamespace(**pub), max_len=8192, router_experts=512,
+        first_expert=0)
+    assert len(cfg.gdn_layers) == 9 and cfg.full_layers == (3, 7, 11)
+    assert (cfg.n_experts, cfg.held_experts, cfg.share) == (512, 128, 0)
+    assert cfg.max_len == 8192
+    assert cfg.param_count() == 5_889_832_128       # 11.78 GB of bf16
+    other = hf_import.config_from_qwen3_next(
+        types.SimpleNamespace(**pub), router_experts=512, first_expert=384)
+    assert other.share == 384
+    with pytest.raises(ValueError, match="not a share"):
+        hf_import.config_from_qwen3_next(
+            types.SimpleNamespace(**pub), router_experts=512,
+            first_expert=385)
+
+
+@pytest.mark.parametrize("key,value,named", [
+    ("mlp_only_layers", [0], "mlp_only_layers"),
+    ("decoder_sparse_step", 2, "decoder_sparse_step"),
+    ("rope_scaling", {"type": "yarn", "factor": 4.0}, "rope_scaling"),
+    ("use_sliding_window", True, "use_sliding_window"),
+    ("tie_word_embeddings", True, "tie_word_embeddings"),
+    ("hidden_act", "gelu", "hidden_act"),
+    ("norm_topk_prob", False, "norm_topk_prob"),
+    ("num_nextn_predict_layers", 1, "multi-token prediction"),
+    ("mtp_num_hidden_layers", 1, "multi-token prediction"),
+])
+def test_config_from_qwen3_next_refuses_what_is_not_written_down(
+        key, value, named):
+    import types
+
+    from pathway_tpu.models import hf_import
+
+    pub = dict(_qwen3_next_row(), **{key: value})
+    with pytest.raises(ValueError, match="not written down") as e:
+        hf_import.config_from_qwen3_next(types.SimpleNamespace(**pub))
+    assert named in str(e.value)
+
+
+def test_config_from_qwen3_next_refuses_another_model_type():
+    import types
+
+    from pathway_tpu.models import hf_import
+
+    pub = dict(_qwen3_next_row(), model_type="qwen3_moe")
+    with pytest.raises(ValueError, match="expected a qwen3_next config"):
+        hf_import.config_from_qwen3_next(types.SimpleNamespace(**pub))

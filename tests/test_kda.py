@@ -211,3 +211,165 @@ def test_work_items_cover_every_long_row_once():
     assert not (flag[6:] & kda._LIVE).any()
     # a dead item repeats the last live item's slot: no block moves
     assert np.asarray(it["slot"]).tolist() == [2, 2, 2, 4, 5, 5] + [5] * 5
+
+
+# -- one decay a head (PR 38: the gated delta rule of models/qwen3_next.py) ----
+
+
+def _head_tokens(T, seed, key_heads=None):
+    """``_tokens`` with ONE log decay a head and token, g (T, H); with
+    ``key_heads`` fewer key heads than value heads: q, k of key head
+    ``h // (H // key_heads)`` repeated for value head ``h``."""
+    import jax.numpy as jnp
+
+    q, k, _kb, vb, g = _tokens(T, seed)
+    rng = np.random.default_rng(seed + 100)
+    beta = jnp.asarray(1 / (1 + np.exp(-rng.normal(size=(T, H, 1)))),
+                       jnp.float32)
+    if key_heads:
+        rep = H // key_heads
+        q = jnp.repeat(q[:, :key_heads], rep, axis=1)
+        k = jnp.repeat(k[:, :key_heads], rep, axis=1)
+    return q, k, k * beta, vb, g[..., 0]
+
+
+def test_one_decay_a_head_is_the_channel_rule_with_equal_channels():
+    import jax.numpy as jnp
+
+    from pathway_tpu.ops import kda
+
+    q, k, kb, vb, g = _head_tokens(40, seed=3)
+    s0 = jnp.asarray(np.random.default_rng(4).normal(size=(H, DK, DK)),
+                     jnp.float32)
+    o1, s1 = kda.kda_recurrence(q, k, kb, vb, g, s0)
+    wide = jnp.broadcast_to(g[..., None], g.shape + (DK,))
+    o2, s2 = kda.kda_recurrence(q, k, kb, vb, wide, s0)
+    np.testing.assert_array_equal(o1, o2)
+    np.testing.assert_array_equal(s1, s2)
+
+
+@pytest.mark.parametrize("use_pallas", [False, True],
+                         ids=["jnp", "interpreted_kernels"])
+@pytest.mark.parametrize("key_heads", [None, 1], ids=["h_eq", "h_div_2"])
+def test_one_decay_a_head_through_both_kernels(use_pallas, key_heads):
+    """A packed step whose decay comes as (T, H): a carried chunk of 70
+    tokens in items of 16 (the chunk kernel: a head's column of the item's
+    (n, H) block, broadcast over the channels in VMEM), a fresh chunk, a
+    decode row and a one-token remainder (the step kernel: one number a
+    head), against the token recurrence; ``h_div_2``: both value heads read
+    the ONE key head's q and k (value head h, key head h // 2)."""
+    import jax.numpy as jnp
+
+    from pathway_tpu.ops import kda
+
+    lens, starts = [70, 1, 23, 1], [9, 40, 0, 5]
+    T = sum(lens) + 3
+    toks = _head_tokens(T, seed=11, key_heads=key_heads)
+    assert toks[4].shape == (T, H)
+    if key_heads:
+        np.testing.assert_array_equal(toks[0][:, 0], toks[0][:, 1])
+    rng = np.random.default_rng(12)
+    state = jnp.asarray(rng.normal(size=(2, 6, H, DK, DK)), jnp.float32)
+    n_rows = 5
+    first = np.zeros(n_rows, np.int32)
+    nvalid = np.ones(n_rows, np.int32)
+    start = np.zeros(n_rows, np.int32)
+    slot = np.zeros(n_rows, np.int32)
+    live = np.zeros(n_rows, bool)
+    t = 0
+    for r, n in enumerate(lens):
+        first[r], nvalid[r], start[r] = t, n, starts[r]
+        slot[r], live[r] = r + 1, True
+        t += n
+    J = jnp.asarray
+    items = kda.chunk_items(J(first), J(start == 0), J(nvalid), J(slot),
+                            J(live), T, 16)
+    o, after = kda.kda_mixed(
+        *toks, jnp.array(state), 1, items, J(first), J(start == 0),
+        J(nvalid), J(slot), J(live), use_pallas=use_pallas)
+    for r, n in enumerate(lens):
+        sl = slice(first[r], first[r] + n)
+        s0 = jnp.zeros((H, DK, DK)) if starts[r] == 0 else state[1, r + 1]
+        want_o, want_s = _recurrence(toks, sl, s0)
+        np.testing.assert_allclose(o[sl], want_o, atol=2e-5)
+        np.testing.assert_allclose(after[1, r + 1], want_s, atol=2e-5)
+    np.testing.assert_array_equal(after[0], state[0])  # another layer's
+    np.testing.assert_array_equal(after[1, 5], state[1, 5])  # no row's slot
+
+
+@pytest.mark.parametrize("use_pallas", [False, True],
+                         ids=["jnp", "interpreted_kernels"])
+def test_decode_rows_with_one_decay_a_head(use_pallas):
+    import jax.numpy as jnp
+
+    from pathway_tpu.ops import kda
+
+    B = 5
+    q, k, kb, vb, g = _head_tokens(B, seed=21)
+    rng = np.random.default_rng(22)
+    state = jnp.asarray(rng.normal(size=(3, B + 1, H, DK, DK)), jnp.float32)
+    slots = jnp.asarray([3, 1, 0, 5, 2], jnp.int32)
+    fresh = jnp.asarray([0, 1, 0, 0, 0], jnp.int32)
+    o, after = kda.kda_decode(q, k, kb, vb, g, jnp.array(state), 2, slots,
+                              fresh, use_pallas=use_pallas)
+    for b in (0, 1, 3, 4):
+        s0 = jnp.zeros((H, DK, DK)) if fresh[b] else state[2, slots[b]]
+        want_o, want_s = _recurrence((q, k, kb, vb, g), slice(b, b + 1), s0)
+        np.testing.assert_allclose(o[b], want_o[0], atol=2e-5)
+        np.testing.assert_allclose(after[2, slots[b]], want_s, atol=2e-5)
+    np.testing.assert_array_equal(after[:2], state[:2])
+
+
+# What the two jitted functions trace to with a decay a CHANNEL at PR 38's
+# parent, source positions removed: sha256 of the whole jaxpr and of the
+# kernel's (dead code eliminated), first 16 digits.  They change with JAX's
+# printer; a change that is meant to touch the per-channel path prints the
+# new ones in its failure.
+_PARENT_KDA = {
+    "chunk": ("b39f76a37b91cfde", "c15cff95efe7c620"),
+    "step": ("e7f2c3aa2cc33918", "3089d8f9d795d0fb"),
+}
+
+
+@pytest.mark.parametrize("which", list(_PARENT_KDA))
+def test_per_channel_kernels_trace_to_the_parents_jaxprs(which):
+    """One decay a head is a branch on ``g``'s shape in Python: with a decay
+    a channel ``_kda_chunk_fn`` and ``_kda_step_fn`` trace to the jaxprs
+    they traced to before, so the per-channel family's programs lower and
+    run as they did."""
+    import hashlib
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax._src.interpreters import partial_eval as pe
+
+    from pathway_tpu.ops import kda
+
+    def S(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    bf, f32 = jnp.bfloat16, jnp.float32
+    NW, n, Hh, d, B = 5, 128, 32, 128, 4
+    arena = S((2, 5, Hh, d, d), f32)
+    if which == "chunk":
+        tok = S((NW, n, Hh * d), bf)
+        fn, args = kda._kda_chunk_fn, (
+            tok, tok, tok, tok, S((NW, n, Hh * d), f32), arena, S((1,)),
+            S((NW,)), S((NW,)))
+    else:
+        col = S((B, d, Hh), f32)
+        fn, args = kda._kda_step_fn, (
+            col, col, col, col, S((B, Hh, d), bf), arena, S((1,)), S((B,)),
+            S((B,)))
+    closed = jax.make_jaxpr(fn)(*args)
+    (call,) = [e for e in closed.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    kernel = call.params["jaxpr"]
+    kernel, _ = pe.dce_jaxpr(kernel, [True] * len(kernel.outvars))
+
+    def digest(x):
+        text = re.sub(r" at [^ ]*:\d+", "", str(x))
+        return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+    assert (digest(closed), digest(kernel)) == _PARENT_KDA[which]

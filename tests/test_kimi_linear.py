@@ -212,12 +212,12 @@ def test_four_shares_and_the_shared_expert_once_add_up_to_the_layer():
         for first in range(0, E, 4):
             part = {**lay, **{n: lay[n][first:first + 4]
                               for n in ("w1", "w3", "w2")}}
-            y, counts, away = expert_ffn(
+            y, vec = expert_ffn(
                 h, part, valid, top_k=k, norm_topk=True, scale=2.446,
                 renorm_eps=1e-20, use_pallas=False, first_expert=first)
             total = total + y
-            pairs += int(counts.sum())
-            elsewhere += int(away)
+            pairs += int(vec[:4].sum())     # tokens per held expert, then
+            elsewhere += int(vec[4])        # ops.moe.COUNTER_TAIL
             # the reference, given the same share, leaves out the same
             alone, _m = ref._experts(h, part, {**shape, "n_held_experts": 4,
                                                "first_expert": first})
