@@ -184,8 +184,9 @@ class ExpertCounts:
     device, one ``int32`` vector a program (ops/moe.py ``expert_ffn``,
     summed over the expert layers): the tokens each held
     expert received, then ``COUNTER_TAIL`` (pairs routed to experts held
-    elsewhere, the grouped matmul's live row tiles, held experts with at
-    least one pair, the passes counted).  Kept as they come back, read
+    elsewhere, the grouped matmul's live rows in units of 16, held experts
+    with at least one pair, the passes counted, the kernel's live row tiles
+    whatever their height).  Kept as they come back, read
     after the next sync (:meth:`fold_expert_counts`, the vector's one
     reader) into the cache's stats."""
 

@@ -41,7 +41,7 @@ arenas under one slot a sequence
 (:class:`pathway_tpu.kvcache.hybrid.StateCache`).  Every program also
 returns the expert layers' counter vector, summed: the tokens each held
 expert received, then the pairs routed to experts held elsewhere, the grouped
-matmul's live row tiles and the held experts touched
+matmul's live rows and tiles and the held experts touched
 (:data:`pathway_tpu.ops.moe.COUNTER_TAIL`).
 
 Greedy, one device.  Parameters are used in the dtype they come in (the

@@ -220,9 +220,13 @@ def _temp_bytes(cfg, *, num_blocks: int, block_size: int,
         chunk, lanes = scan(cfg)
         acts += 6 * (T // chunk + B) * chunk * lanes * 4
     if getattr(cfg, "n_experts", 0):
-        # routed pairs laid out by expert in whole tiles of 16 rows: the
-        # gathered inputs, the experts' hidden rows and their outputs
-        rows = T * cfg.top_k + 16 * cfg.n_experts
+        # routed pairs laid out by expert in whole row tiles (16 to 128
+        # rows, the kernel's own rule): the gathered inputs, the experts'
+        # hidden rows and their outputs
+        from ..ops.moe import row_tile
+
+        rows = T * cfg.top_k \
+            + row_tile(T, cfg.top_k, cfg.n_experts) * cfg.n_experts
         acts += rows * (2 * d + cfg.d_ff_expert) * itemsize
     logits = B * vocab * 4  # f32 head output
     chain = B * max(chain_steps, 1) * 4 * 2  # [B, K] ids carry + stack

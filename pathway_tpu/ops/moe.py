@@ -1,19 +1,23 @@
 """Sparse expert layer: the router, routed pairs grouped by expert, and the
 grouped matmul over the tokens each expert received.
 
-A step carries few tokens (a mixed step 48 to 272, a decode step 16) and
-many experts (32 of 3 x 2048 x 1792, or 128 of 3 x 2048 x 1024), so the
+A step carries few tokens (a decode step 16, a mixed step 272 to 1,040:
+sixteen rows and the engine's prefill chunk) and many experts (32 of 3 x
+2048 x 1792, 128 of 3 x 2048 x 1024, 64 of 256 or 128 of 512 held), so the
 layer is bound by the bytes of the expert weights it touches, not by its
-FLOPs.  The layout follows from
-that:
+FLOPs, as long as an expert's rows pass the MXU in few tiles: a weight
+tile is latched once a row tile, whatever the tile's height.  The layout
+follows from that:
 
 - :func:`route` scores every token against every expert (sigmoid, or a
   softmax over all the experts' logits; f32), chooses the ``top_k`` largest
   of ``score + bias`` and weighs them by the scores alone, renormalised
   over the chosen;
 - :func:`group_rows` lays the ``tokens x top_k`` routed pairs out sorted by
-  expert, each expert's group padded to whole tiles of :data:`TM` rows, so
-  that a tile belongs to exactly one expert;
+  expert, each expert's group padded to whole tiles of ``tm`` rows, so
+  that a tile belongs to exactly one expert; :func:`row_tile` gives ``tm``
+  from the rows an expert can expect: :data:`TM` where a step brings an
+  expert a handful of pairs, 32 where it brings ~17 to 20, 128 at ~66;
 - :func:`grouped_matmul` multiplies each tile by its expert's matrix.  The
   Pallas kernel takes ``tile_expert`` and the number of live tiles by
   scalar prefetch: its weight block index is the tile's expert, so an
@@ -34,7 +38,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-TM = 16  # rows a tile: one packed bf16 sublane tile
+TM = 16  # the unit of a row tile: one packed bf16 sublane tile
+ROW_TILES = (128, 64, 32, TM)  # the heights a row tile takes, tallest first
+
+
+def row_tile(tokens: int, top_k: int, router_experts: int) -> int:
+    """Rows a tile of the grouped matmul, from a step's shapes alone: the
+    tallest of :data:`ROW_TILES` that the mean rows an expert can expect,
+    ``tokens x top_k / router_experts``, fill at least half; :data:`TM`
+    under that.  The MXU latches a weight tile once a row tile whatever
+    the tile's height, so the kernel's time follows its live tiles: a
+    taller tile pays while it makes them fewer, which ends near one tile
+    an expert, and from there on it only runs padding (PERF.md section 6,
+    PR 39: the table behind the half)."""
+    return next((t for t in ROW_TILES
+                 if t * router_experts <= 2 * tokens * top_k), TM)
 
 
 def route(h, wg, bias, *, top_k: int, norm_topk: bool = True,
@@ -218,7 +236,7 @@ def grouped_matmul(x, w1, w3, w2, tile_expert, n_live, *, tm: int = TM,
 def expert_ffn(h, layer: dict, valid, *, top_k: int, norm_topk: bool = True,
                scale: float = 1.0, renorm_eps: float = 1e-6, h_route=None,
                use_pallas: bool | None = None, first_expert=None,
-               score: str = "sigmoid"):
+               score: str = "sigmoid", tm: int | None = None):
     """One expert layer over a packed stream.  h (T, D) normed input in
     the experts' dtype, ``h_route`` the same before it was rounded to that
     dtype (f32; default h: the router then sees what the experts see);
@@ -236,8 +254,14 @@ def expert_ffn(h, layer: dict, valid, *, top_k: int, norm_topk: bool = True,
     outputs and its ``top_k``; a pair routed to an expert held elsewhere
     takes no row, touches no expert and adds nothing (its part of the sum
     is the other shares', which no code here stands in for) and is counted
-    in the vector's ``moe_pairs_elsewhere``."""
+    in the vector's ``moe_pairs_elsewhere``.
+
+    ``tm``: rows a tile of the layout; default :func:`row_tile` of the
+    shapes here (a test passes it to hold two heights against each other:
+    a pair's row is the same dot products under any)."""
     E = layer["wg"].shape[1]
+    if tm is None:
+        tm = row_tile(h.shape[0], top_k, E)
     experts, weights, _s = route(h if h_route is None else h_route,
                                  layer["wg"], layer.get("expert_bias"),
                                  top_k=top_k, norm_topk=norm_topk,
@@ -249,9 +273,9 @@ def expert_ffn(h, layer: dict, valid, *, top_k: int, norm_topk: bool = True,
         local = experts - first_expert
         mine = (local >= 0) & (local < held)
         experts = jnp.where(mine, local, held)  # held: no expert's group
-    g = group_rows(experts, valid, held)
+    g = group_rows(experts, valid, held, tm)
     y = grouped_matmul(h[g["row_token"]], layer["w1"], layer["w3"],
-                       layer["w2"], g["tile_expert"], g["n_live"],
+                       layer["w2"], g["tile_expert"], g["n_live"], tm=tm,
                        use_pallas=use_pallas)
     here = valid[:, None, None] if first_expert is None \
         else (valid[:, None] & mine)[:, :, None]
@@ -259,15 +283,18 @@ def expert_ffn(h, layer: dict, valid, *, top_k: int, norm_topk: bool = True,
     out = jnp.sum(pairs.astype(jnp.float32) * weights[:, :, None], axis=1)
     elsewhere = jnp.int32(0) if first_expert is None \
         else jnp.sum(valid[:, None] & ~mine).astype(jnp.int32)
-    tail = jnp.stack([elsewhere, g["n_live"][0],
+    n_live = g["n_live"][0]
+    tail = jnp.stack([elsewhere, n_live * (tm // TM),
                       jnp.sum(g["counts"] > 0).astype(jnp.int32),
-                      jnp.int32(1)])
+                      jnp.int32(1), n_live])
     return out.astype(h.dtype), jnp.concatenate([g["counts"], tail])
 
 
 # what follows the tokens-per-held-expert in a layer's counter vector:
-# pairs routed to experts held elsewhere; the grouped matmul's live row
-# tiles (of TM rows); held experts that received at least one pair; the
-# passes themselves (1 a call: every count above is a sum over them)
+# pairs routed to experts held elsewhere; the grouped matmul's live rows in
+# units of TM (a live tile of 64 rows counts 4); held experts that received
+# at least one pair; the passes themselves (1 a call: every count here is a
+# sum over them); the kernel's own live row tiles, whatever their height
+# (TM x moe_live_tiles / moe_row_tiles: the mean height that ran)
 COUNTER_TAIL = ("moe_pairs_elsewhere", "moe_live_tiles",
-                "moe_experts_touched", "moe_expert_passes")
+                "moe_experts_touched", "moe_expert_passes", "moe_row_tiles")

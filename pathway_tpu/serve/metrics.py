@@ -194,12 +194,16 @@ class KVCacheStats:
       started a slot's state from zero) and
       ``pathway_kv_moe_pairs_elsewhere_total{pool}`` ((token, expert) pairs
       the router sent to experts another share holds) counters
-    - ``pathway_kv_moe_live_tiles_total{pool}`` (row tiles of 16 the grouped
-      matmul ran, summed over expert layers and steps) and
+    - ``pathway_kv_moe_live_tiles_total{pool}`` (live rows the grouped
+      matmul ran, in units of 16: a row tile of 64 counts 4; summed over
+      expert layers and steps) and
+      ``pathway_kv_moe_row_tiles_total{pool}`` (the kernel's own live row
+      tiles, whatever their height, summed likewise: 16 x live_tiles /
+      row_tiles is the mean height that ran) and
       ``pathway_kv_moe_experts_touched_total{pool}`` (held experts that
       received at least one pair, summed likewise) and
       ``pathway_kv_moe_expert_passes_total{pool}`` (the expert layers'
-      passes themselves, one a layer and step: what the other two are
+      passes themselves, one a layer and step: what the other three are
       sums over) counters, of every cache whose family routes
     - ``pathway_kv_window_blocks_in_use{pool}`` / ``..._total{pool}`` gauges
       (windowed caches: blocks of the sliding-window layers' pool held, and
@@ -282,10 +286,12 @@ class KVCacheStats:
         self.moe_routed_pairs = 0
         self.moe_tokens_per_expert: list[int] = []
         self.moe_fullest_expert_tokens = 0  # sum over programs of the max
-        # the grouped matmul's live row tiles and the held experts with at
-        # least one pair, summed over expert layers and steps, and the
-        # number of those passes (one an expert layer and step)
+        # the grouped matmul's live rows in units of 16, its own live row
+        # tiles (of 16 to 128 rows: ops/moe.py row_tile) and the held
+        # experts with at least one pair, summed over expert layers and
+        # steps, and the number of those passes (one an expert layer and step)
         self.moe_live_tiles = 0
+        self.moe_row_tiles = 0
         self.moe_experts_touched = 0
         self.moe_expert_passes = 0
         # state caches (kvcache/hybrid.py StateCache): the matrix-state
@@ -567,6 +573,7 @@ class KVCacheStats:
                 "moe_tokens_per_expert": list(self.moe_tokens_per_expert),
                 "moe_fullest_expert_tokens": self.moe_fullest_expert_tokens,
                 "moe_live_tiles": self.moe_live_tiles,
+                "moe_row_tiles": self.moe_row_tiles,
                 "moe_experts_touched": self.moe_experts_touched,
                 "moe_expert_passes": self.moe_expert_passes,
                 "state_slots_in_use": self.state_slots_in_use,
@@ -1022,6 +1029,7 @@ def _render_kv_lines() -> list[str]:
         "# TYPE pathway_kv_moe_routed_pairs_total counter",
         "# TYPE pathway_kv_moe_tokens_per_expert_total counter",
         "# TYPE pathway_kv_moe_live_tiles_total counter",
+        "# TYPE pathway_kv_moe_row_tiles_total counter",
         "# TYPE pathway_kv_moe_experts_touched_total counter",
         "# TYPE pathway_kv_moe_expert_passes_total counter",
         "# TYPE pathway_kv_state_slots_in_use gauge",
@@ -1220,7 +1228,7 @@ def _render_kv_lines() -> list[str]:
                              f"{snap['kv_' + key]}")
         if snap["conv_slots_total"] or snap["window_blocks_total"]:
             # the caches of the families with expert layers
-            for key in ("moe_routed_pairs", "moe_live_tiles",
+            for key in ("moe_routed_pairs", "moe_live_tiles", "moe_row_tiles",
                         "moe_experts_touched", "moe_expert_passes"):
                 lines.append(f"pathway_kv_{key}_total{{{lbl}}} {snap[key]}")
             for e, n in enumerate(snap["moe_tokens_per_expert"]):

@@ -662,13 +662,15 @@ def test_kv_state_cache_gives_blocks_and_slot_together_or_not_at_all():
     assert pool.row_extras([], 4)[0].tolist() == [0, 0, 0, 0]
     # what a program gives back: four arrays and the counter vector
     pool.set_device_state(k, v, conv, state,
-                          jnp.asarray([3, 0, 5, 7, 2, 1, 4], jnp.int32))
+                          jnp.asarray([3, 0, 5, 7, 8, 1, 4, 2], jnp.int32))
     pool.after_sync()
     snap = pool.stats.snapshot()
     assert snap["moe_tokens_per_expert"] == [3, 0, 5]
+    # two live row tiles of 64 rows: eight in units of 16
     assert (snap["moe_routed_pairs"], snap["moe_pairs_elsewhere"],
             snap["moe_live_tiles"], snap["moe_experts_touched"],
-            snap["moe_expert_passes"]) == (8, 7, 2, 1, 4)
+            snap["moe_expert_passes"], snap["moe_row_tiles"]) \
+        == (8, 7, 8, 1, 4, 2)
     pool.retire()
 
 
@@ -682,7 +684,8 @@ def test_the_counter_vector_has_one_reader(kind, params, lfm2):
     from pathway_tpu.ops.moe import COUNTER_TAIL
 
     assert COUNTER_TAIL == ("moe_pairs_elsewhere", "moe_live_tiles",
-                            "moe_experts_touched", "moe_expert_passes")
+                            "moe_experts_touched", "moe_expert_passes",
+                            "moe_row_tiles")
     eng = _engine(kind, params, lfm2, f"t_cb_counters_{kind}")
     assert isinstance(eng.pool, ExpertCounts)
     assert type(eng.pool).after_sync is not ExpertCounts  # the cache's own
@@ -697,6 +700,10 @@ def test_the_counter_vector_has_one_reader(kind, params, lfm2):
     name = f"t_cb_counters_{kind}"
     assert _gauge(lines, "pathway_kv_moe_live_tiles_total", name) \
         == [float(snap["moe_live_tiles"])]
+    # 12 tokens x 2 on 8 experts: every tile here is 16 rows tall
+    assert snap["moe_row_tiles"] == snap["moe_live_tiles"]
+    assert _gauge(lines, "pathway_kv_moe_row_tiles_total", name) \
+        == [float(snap["moe_row_tiles"])]
     assert _gauge(lines, "pathway_kv_moe_experts_touched_total", name) \
         == [float(snap["moe_experts_touched"])]
     # one pass an expert layer and forward pass: no held expert is touched
@@ -705,6 +712,30 @@ def test_the_counter_vector_has_one_reader(kind, params, lfm2):
         <= cfg.n_experts * snap["moe_expert_passes"]
     assert _gauge(lines, "pathway_kv_moe_expert_passes_total", name) \
         == [float(snap["moe_expert_passes"])]
+
+
+def test_a_tall_row_tile_counts_its_rows_in_units_of_16(params, lfm2):
+    """A chunk of 124 beside four rows: 128 tokens x 2 on 8 experts are 32
+    rows an expert, so the mixed steps run row tiles of 64 (each counts 4
+    in ``moe_live_tiles``, 1 in ``moe_row_tiles``) and the chains tiles of
+    16; the tokens are those of the engine whose tiles are all 16."""
+    from pathway_tpu.ops.moe import row_tile
+
+    cfg = lfm2["hybrid"][0]
+    assert row_tile(4 + 124, cfg.top_k, cfg.n_experts) == 64
+    assert row_tile(4 + 8, cfg.top_k, cfg.n_experts) == 16
+    reqs = [(list(range(1, 41)), 6), ([41, 2], 4)]
+    tall = _engine("hybrid", params, lfm2, "t_cb_tall_tile",
+                   prefill_chunk=124)
+    short = _engine("hybrid", params, lfm2, "t_cb_short_tile")
+    assert tall.generate_batch(reqs) == short.generate_batch(reqs)
+    a, b = tall.pool.stats.snapshot(), short.pool.stats.snapshot()
+    assert b["moe_live_tiles"] == b["moe_row_tiles"]
+    assert a["moe_row_tiles"] < a["moe_live_tiles"] < 4 * a["moe_row_tiles"]
+    assert a["moe_routed_pairs"] <= 16 * a["moe_live_tiles"]
+    lines = serve_metrics.render_prometheus_lines()
+    assert _gauge(lines, "pathway_kv_moe_row_tiles_total", "t_cb_tall_tile") \
+        == [float(a["moe_row_tiles"])]
 
 
 def test_the_head_256_roofline_reads_the_attention_kernels_alone():

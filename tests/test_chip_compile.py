@@ -371,25 +371,53 @@ def test_lfm2_step_programs_keep_the_pool_where_it_is(shape, program,
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+def _compiled_gmm(shape, E, D, F, tiles, tm):
+    """``_moe_gmm_fn`` over ``tiles`` row tiles of ``tm`` rows on ``E``
+    experts of 3 x D x F in bf16: its two kernels are in the text."""
+    import jax.numpy as jnp
+
+    from pathway_tpu.ops import moe
+
+    bf16 = jnp.bfloat16
+    compiled = _compiled_kernel(
+        lambda *a: moe._moe_gmm_fn(*a, tm=tm), shape((tiles * tm, D), bf16),
+        shape((E, D, F), bf16), shape((E, D, F), bf16),
+        shape((E, F, D), bf16), shape((tiles,), jnp.int32),
+        shape((1,), jnp.int32))
+    assert compiled.as_text().count("tpu_custom_call") == 2
+    return compiled
+
+
 @pytest.mark.parametrize("pairs", [64, 192], ids=["decode", "mixed"])
 def test_grouped_matmul_kernel_compiles_at_the_published_experts(shape,
                                                                  pairs):
     """32 experts of 3 x 2048 x 1792 in bf16, the routed pairs of a decode
     step (16 x 4) and of a mixed step (48 x 4), in whole tiles of 16."""
-    import jax.numpy as jnp
-
     from pathway_tpu.ops import moe
 
     E, D, F = 32, 2048, 1792
     tiles = moe.n_tiles(pairs, E)
-    bf16 = jnp.bfloat16
-    compiled = _compiled_kernel(
-        moe._moe_gmm_fn, shape((tiles * moe.TM, D), bf16),
-        shape((E, D, F), bf16), shape((E, D, F), bf16),
-        shape((E, F, D), bf16), shape((tiles,), jnp.int32),
-        shape((1,), jnp.int32))
-    assert compiled.as_text().count("tpu_custom_call") == 2
+    compiled = _compiled_gmm(shape, E, D, F, tiles, moe.TM)
     assert "_moe_gmm_fn" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tm", ["rule", 64])
+def test_grouped_matmul_kernel_compiles_at_tall_row_tiles(shape, tm):
+    """The same 32 experts at the routed pairs of a mixed step at the chosen
+    chunk (528 x 4: 66 rows an expert), in the row tile the rule gives, the
+    tallest (128 x 2,048 rows beside two 2,048 x 256 panels,
+    double-buffered: ~5 MB of VMEM), and in the one under it.  The kernel
+    asks for no VMEM limit: the compiler holds it to its own 16 MiB."""
+    from pathway_tpu.ops import moe
+
+    E, D, F, T, k = 32, 2048, 1792, 528, 4
+    if tm == "rule":
+        tm = moe.row_tile(T, k, E)
+        assert tm == 128
+    tiles = moe.n_tiles(T * k, E, tm)
+    compiled = _compiled_gmm(shape, E, D, F, tiles, tm)
+    assert "vmem_limit" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
 # -- the afmoe block family (Trinity-Mini's published widths) -----------------
@@ -482,23 +510,19 @@ def test_afmoe_mixed_step_compiles_at_the_published_widths(shape,
     assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
 
 
-def test_grouped_matmul_kernel_compiles_at_128_experts(shape):
+@pytest.mark.parametrize("tm,tiles", [(16, 264), ("rule", 196)])
+def test_grouped_matmul_kernel_compiles_at_128_experts(shape, tm, tiles):
     """128 experts of 3 x 2048 x 1024 in bf16 and the routed pairs of a
-    mixed step of 272 tokens top-8, in whole tiles of 16."""
-    import jax.numpy as jnp
-
+    mixed step of 272 tokens top-8 (17 rows an expert), in whole tiles of
+    16 and of the rule's 32."""
     from pathway_tpu.ops import moe
 
-    E, D, F, pairs = 128, 2048, 1024, 272 * 8
-    tiles = moe.n_tiles(pairs, E)
-    assert tiles == 264
-    bf16 = jnp.bfloat16
-    compiled = _compiled_kernel(
-        moe._moe_gmm_fn, shape((tiles * moe.TM, D), bf16),
-        shape((E, D, F), bf16), shape((E, D, F), bf16),
-        shape((E, F, D), bf16), shape((tiles,), jnp.int32),
-        shape((1,), jnp.int32))
-    assert compiled.as_text().count("tpu_custom_call") == 2
+    E, D, F, T, k = 128, 2048, 1024, 272, 8
+    if tm == "rule":
+        tm = moe.row_tile(T, k, E)
+        assert tm == 32
+    assert moe.n_tiles(T * k, E, tm) == tiles
+    _compiled_gmm(shape, E, D, F, tiles, tm)
 
 
 # -- the step programs behind their packed operand (PR 32) --------------------
@@ -746,6 +770,10 @@ def test_mixed_program_compiles_at_the_chosen_chunk(shape, monkeypatch,
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     chunk = _chosen_chunk(family)[0]
+    if family == "lfm2":  # 528 tokens x 4 on 32 experts: the tall row tile
+        from pathway_tpu.ops import moe
+
+        assert moe.row_tile(B + chunk, 4, 32) == 128
     table, params, state, host, calls = _family_case(shape, family, chunk)
     fn, donated = table["mixed"]
     layout = RoundLayout(host["mixed"])
@@ -993,24 +1021,19 @@ def test_kda_kernels_compile_with_one_decay_a_head(shape):
         assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-def test_grouped_matmul_kernel_compiles_at_128_of_512_experts(shape):
+@pytest.mark.parametrize("T,tm,tiles", [(528, 16, 458), (1040, 32, 453)])
+def test_grouped_matmul_kernel_compiles_at_128_of_512_experts(shape, T, tm,
+                                                              tiles):
     """128 held experts of 3 x 2048 x 512 in bf16 and the routed pairs of a
-    mixed step of 528 tokens top-10 (all of them, were they to fall here),
-    in whole tiles of 16."""
-    import jax.numpy as jnp
-
+    mixed step of 528 or 1,040 tokens top-10 (all of them, were they to
+    fall here), in the rule's tiles: 16 rows at 10.3 rows an expert of the
+    router's 512, 32 at 20.3."""
     from pathway_tpu.ops import moe
 
-    E, D, Fe, pairs = 128, 2048, 512, 528 * 10
-    tiles = moe.n_tiles(pairs, E)
-    assert tiles == 458
-    bf16 = jnp.bfloat16
-    compiled = _compiled_kernel(
-        moe._moe_gmm_fn, shape((tiles * moe.TM, D), bf16),
-        shape((E, D, Fe), bf16), shape((E, D, Fe), bf16),
-        shape((E, Fe, D), bf16), shape((tiles,), jnp.int32),
-        shape((1,), jnp.int32))
-    assert compiled.as_text().count("tpu_custom_call") == 2
+    E, D, Fe = 128, 2048, 512
+    assert moe.row_tile(T, 10, 512) == tm
+    assert moe.n_tiles(T * 10, E, tm) == tiles
+    _compiled_gmm(shape, E, D, Fe, tiles, tm)
 
 
 def _qwen3_next_case(shape):

@@ -240,55 +240,221 @@ def test_router_chooses_by_score_plus_bias_and_weighs_by_score():
                 assert np.asarray(w1)[t, j] == np.asarray(w_raw)[t, k]
 
 
-@pytest.mark.parametrize("case", ["one_idle_expert", "one_takes_all"])
-def test_grouped_matmul_kernel_matches_its_reference(case):
+def _expert_layer(T, E, bias, D=128, F=256, seed=3):
+    """(h (T, D), an expert layer of E experts with the given bias)."""
     import jax
-    import jax.numpy as jnp
 
-    from pathway_tpu.ops import moe
-
-    T, D, E, F, k = 24, 128, 8, 256, 2
-    ks = jax.random.split(jax.random.PRNGKey(3), 6)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     h = jax.random.normal(ks[0], (T, D))
-    bias = jnp.zeros(E).at[3].set(-10.0) if case == "one_idle_expert" \
-        else jnp.zeros(E).at[5].set(10.0)
     layer = {"wg": jax.random.normal(ks[1], (D, E)) / np.sqrt(D),
              "expert_bias": bias,
              "w1": jax.random.normal(ks[2], (E, D, F)) / np.sqrt(D),
              "w3": jax.random.normal(ks[3], (E, D, F)) / np.sqrt(D),
              "w2": jax.random.normal(ks[4], (E, F, D)) / np.sqrt(F)}
+    return h, layer
+
+
+def _dense_sum(h, layer, experts, weights):
+    """Every token through its own experts, one at a time."""
+    import jax
+    import jax.numpy as jnp
+
+    dense = jnp.zeros_like(h)
+    for j in range(experts.shape[1]):
+        e = experts[:, j]
+        a = jnp.einsum("td,tdf->tf", h, layer["w1"][e])
+        b = jnp.einsum("td,tdf->tf", h, layer["w3"][e])
+        dense += weights[:, j:j + 1] * jnp.einsum(
+            "tf,tfd->td", jax.nn.silu(a) * b, layer["w2"][e])
+    return np.asarray(dense)
+
+
+def _kernel_against_reference(h, layer, g, tm):
+    """The interpreted kernels against the gather reference over the live
+    rows of the layout ``g`` (tiles of ``tm`` rows)."""
+    from pathway_tpu.ops import moe
+
+    args = (h[g["row_token"]], layer["w1"], layer["w3"], layer["w2"],
+            g["tile_expert"], g["n_live"])
+    want = np.asarray(moe.moe_gmm_reference(*args, tm=tm))
+    got = np.asarray(moe._moe_gmm(*args, tm=tm, interpret=True))
+    live = int(g["n_live"][0]) * tm
+    assert got.shape[0] == g["tile_expert"].shape[0] * tm >= live
+    np.testing.assert_allclose(got[:live], want[:live], atol=2e-5)
+
+
+@pytest.mark.parametrize("tm", [16, 32, 64, 128])
+@pytest.mark.parametrize("case", ["one_idle_expert", "one_takes_all"])
+def test_grouped_matmul_kernel_matches_its_reference(case, tm):
+    import jax.numpy as jnp
+
+    from pathway_tpu.ops import moe
+
+    T, E, k = 24, 8, 2
+    bias = jnp.zeros(E).at[3].set(-10.0) if case == "one_idle_expert" \
+        else jnp.zeros(E).at[5].set(10.0)
+    h, layer = _expert_layer(T, E, bias)
     valid = jnp.arange(T) < 20
     experts, weights, _s = moe.route(h, layer["wg"], bias, top_k=k)
-    g = moe.group_rows(experts, valid, E)
+    g = moe.group_rows(experts, valid, E, tm)
     counts = np.asarray(g["counts"])
     assert counts.sum() == 20 * k
     if case == "one_idle_expert":
         assert counts[3] == 0 and 3 not in np.asarray(g["tile_expert"])
     else:
         assert counts[5] == 20
-    x = h[g["row_token"]]
-    args = (x, layer["w1"], layer["w3"], layer["w2"], g["tile_expert"],
-            g["n_live"])
-    want = np.asarray(moe.moe_gmm_reference(*args))
-    got = np.asarray(moe._moe_gmm(*args, interpret=True))
-    live = int(g["n_live"][0]) * moe.TM
-    np.testing.assert_allclose(got[:live], want[:live], atol=2e-5)
+    n_live = int(g["n_live"][0])
+    assert n_live == sum(-(-int(c) // tm) for c in counts)
+    _kernel_against_reference(h, layer, g, tm)
     # and the layer as a whole against every token through its own experts
-    out, n_tok = moe.expert_ffn(h, layer, valid, top_k=k, use_pallas=True)
-    dense = jnp.zeros_like(h)
-    for j in range(k):
-        e = experts[:, j]
-        a = jnp.einsum("td,tdf->tf", h, layer["w1"][e])
-        b = jnp.einsum("td,tdf->tf", h, layer["w3"][e])
-        dense += weights[:, j:j + 1] * jnp.einsum(
-            "tf,tfd->td", jax.nn.silu(a) * b, layer["w2"][e])
-    np.testing.assert_allclose(np.asarray(out)[:20], np.asarray(dense)[:20],
-                               atol=2e-5)
+    out, n_tok = moe.expert_ffn(h, layer, valid, top_k=k, use_pallas=True,
+                                tm=tm)
+    dense = _dense_sum(h, layer, experts, weights)
+    np.testing.assert_allclose(np.asarray(out)[:20], dense[:20], atol=2e-5)
     assert (np.asarray(out)[20:] == 0).all()
-    # tokens per expert, then ops.moe.COUNTER_TAIL
+    # tokens per expert, then ops.moe.COUNTER_TAIL: the live rows in units
+    # of 16 whatever the tile, the kernel's own tiles last
     assert (np.asarray(n_tok)[:len(counts)] == counts).all()
     assert np.asarray(n_tok)[len(counts):].tolist() == [
-        0, int(g["n_live"][0]), int((counts > 0).sum()), 1]
+        0, n_live * (tm // 16), int((counts > 0).sum()), 1, n_live]
+
+
+@pytest.mark.parametrize("tm", [32, 64])
+def test_an_experts_rows_span_two_tall_tiles_beside_an_idle_expert(tm):
+    """Every valid token chooses expert 5 (2 x tm + 8 rows: three tiles, the
+    last one nearly empty), none chooses expert 3; the second choices
+    spread over the other six."""
+    import jax.numpy as jnp
+
+    from pathway_tpu.ops import moe
+
+    E, k, n = 8, 2, 2 * tm + 8
+    T = n + 6
+    bias = jnp.zeros(E).at[5].set(10.0).at[3].set(-10.0)
+    h, layer = _expert_layer(T, E, bias, seed=5)
+    valid = jnp.arange(T) < n
+    experts, weights, _s = moe.route(h, layer["wg"], bias, top_k=k)
+    g = moe.group_rows(experts, valid, E, tm)
+    counts, tiles = np.asarray(g["counts"]), np.asarray(g["tile_expert"])
+    n_live = int(g["n_live"][0])
+    assert counts[5] == n and counts[3] == 0 and counts.sum() == n * k
+    assert (tiles[:n_live] == 5).sum() == 3 and 3 not in tiles
+    # the group's rows are consecutive over its three tiles
+    rows5 = np.sort(np.asarray(g["pair_row"])[np.asarray(
+        (experts == 5) & valid[:, None])])
+    assert (np.diff(rows5) == 1).all() and rows5[0] % tm == 0
+    _kernel_against_reference(h, layer, g, tm)
+    out, n_tok = moe.expert_ffn(h, layer, valid, top_k=k, use_pallas=True,
+                                tm=tm)
+    dense = _dense_sum(h, layer, experts, weights)
+    np.testing.assert_allclose(np.asarray(out)[:n], dense[:n], atol=2e-5)
+    assert (np.asarray(out)[n:] == 0).all()
+    assert np.asarray(n_tok)[E:].tolist() == [
+        0, n_live * (tm // 16), 7, 1, n_live]
+
+
+def test_expert_ffn_at_the_rules_tall_tile_equals_the_dense_sum_and_the_tile_of_16():
+    """128 tokens x 2 on 8 experts: 32 rows an expert, the rule's tile is
+    64.  A pair's row is the same dot products under any tile height: the
+    layer's output is its output with the tile forced to 16, bit for bit."""
+    import jax.numpy as jnp
+
+    from pathway_tpu.ops import moe
+
+    T, E, k = 128, 8, 2
+    assert moe.row_tile(T, k, E) == 64
+    h, layer = _expert_layer(T, E, jnp.zeros(E), seed=7)
+    valid = jnp.arange(T) < 120
+    experts, weights, _s = moe.route(h, layer["wg"], None, top_k=k)
+    out, n_tok = moe.expert_ffn(h, layer, valid, top_k=k, use_pallas=True)
+    dense = _dense_sum(h, layer, experts, weights)
+    np.testing.assert_allclose(np.asarray(out)[:120], dense[:120], atol=2e-5)
+    assert (np.asarray(out)[120:] == 0).all()
+    out16, n_tok16 = moe.expert_ffn(h, layer, valid, top_k=k,
+                                    use_pallas=True, tm=16)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(out16))
+    # the same pairs in fewer, taller tiles: 16 x live_tiles / row_tiles is
+    # the height that ran
+    tall, short = np.asarray(n_tok), np.asarray(n_tok16)
+    assert (tall[:E] == short[:E]).all() and tall[:E].sum() == 240
+    assert tall[E + 1] == 4 * tall[E + 4] and short[E + 1] == short[E + 4]
+    assert tall[E + 4] < short[E + 4] <= tall[E + 1]
+
+
+# the benchmark's configurations: router experts, top_k and the tokens of
+# their mixed step (sixteen rows and the engine's prefill chunk)
+_CELL_STEPS = {"lfm2": (32, 4, 528), "trinity": (128, 8, 272),
+               "kimi_linear": (256, 8, 528), "qwen3_next": (512, 10, 1040)}
+
+
+@pytest.mark.parametrize("step", ["mixed", "decode"])
+@pytest.mark.parametrize("name", sorted(_CELL_STEPS))
+def test_row_tile_at_the_benchmarks_shapes(name, step):
+    """The rule rounds up from half a rung (PERF.md section 6, PR 39: all
+    five rows of the sweep's table decided it): 128 where a mixed step
+    brings an expert 66 rows, 32 where it brings 16.5 to 20.3, 16 in every
+    decode and chain step (0.3 to 2 rows)."""
+    from pathway_tpu.ops import moe
+
+    E, k, T = _CELL_STEPS[name]
+    got = moe.row_tile(T if step == "mixed" else 16, k, E)
+    assert got == (16 if step == "decode" else 128 if name == "lfm2" else 32)
+    assert got in moe.ROW_TILES and got % moe.TM == 0
+
+
+def test_row_tile_is_the_tallest_rung_the_mean_rows_half_fill():
+    from pathway_tpu.ops import moe
+
+    for rung in (32, 64, 128):  # 8 experts: rung / 2 rows each, one fewer
+        assert moe.row_tile(rung * 4, 1, 8) == rung
+        assert moe.row_tile(rung * 4 - 1, 1, 8) == rung // 2
+    assert moe.row_tile(1, 1, 8) == moe.row_tile(127, 1, 8) == 16
+    assert moe.row_tile(10 ** 6, 8, 8) == 128
+
+
+def test_a_tile_of_16_traces_to_the_same_jaxpr_given_or_not():
+    """A shape whose tile the rule leaves at 16 is the program it was:
+    passing ``tm=16`` changes nothing in the trace."""
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.ops import moe
+
+    T, E, k = 24, 8, 2
+    assert moe.row_tile(T, k, E) == 16
+    h, layer = _expert_layer(T, E, jnp.zeros(E))
+    valid = jnp.arange(T) < 20
+    texts = [str(jax.make_jaxpr(lambda h, layer, valid: moe.expert_ffn(
+        h, layer, valid, top_k=k, use_pallas=True, **kw))(h, layer, valid))
+        for kw in ({}, {"tm": 16})]
+    assert texts[0] == texts[1] and "pallas_call" in texts[0]
+
+
+def test_hbm_plan_bills_the_rows_of_the_row_tile(monkeypatch):
+    """The ledger's temporaries for the published lfm2 widths at a chunk of
+    512 bill the layout the kernel runs: 528 x 4 pairs and a tile of 128
+    rows an expert, 112 rows an expert more than tiles of 16 would."""
+    import jax.numpy as jnp
+
+    from pathway_tpu.obs import memory
+    from pathway_tpu.ops import moe
+
+    from .utils import described_decode_plan
+
+    cfg, plan, dtype, kw = described_decode_plan("lfm2")
+    args = dict(num_blocks=2049, block_size=16, chain_steps=16,
+                prefill_chunk=512, dtype=dtype, params=plan,
+                budget_bytes=int(15.02 * 2 ** 30), reference_attn=False, **kw)
+    assert moe.row_tile(16 + 512, cfg.top_k, cfg.n_experts) == 128
+    tall = memory.hbm_plan(cfg, **args)
+    monkeypatch.setattr(moe, "row_tile", lambda *a: 16)
+    short = memory.hbm_plan(cfg, **args)
+    assert tall.temp_source == short.temp_source == "analytic"
+    wide = 2 * cfg.d_model + cfg.d_ff_expert
+    assert tall.temp_bytes - short.temp_bytes == \
+        (128 - 16) * cfg.n_experts * wide * jnp.dtype(dtype).itemsize \
+        == 42_205_184
+    assert tall.fits
 
 
 # "spans": K/V heads whose lanes are a whole tile, so a grid step of the

@@ -274,6 +274,19 @@ def test_four_shares_and_the_gated_shared_expert_once_add_up_to_the_layer():
             pairs += int(vec[:4].sum())
             elsewhere += int(vec[4])
             assert 1 <= int(vec[6]) <= 4 and int(vec[5]) >= int(vec[6])
+            # 50 x 3 on 16 experts: tiles of 16, so both counts of tiles
+            assert int(vec[8]) == int(vec[5])
+            tall = expert_ffn(
+                h, part, valid, top_k=k, norm_topk=True, renorm_eps=0.0,
+                use_pallas=False, first_expert=first, score="softmax",
+                tm=64)
+            # (the gather path's einsum sums in another order: rounding)
+            np.testing.assert_allclose(tall[0], y, atol=2e-6)
+            # a live tile of 64 rows counts 4 in units of 16, 1 as a tile
+            assert int(tall[1][5]) == 4 * int(tall[1][8]) \
+                and int(tall[1][8]) == int(vec[6])
+            assert (np.asarray(tall[1])[[0, 1, 2, 3, 4, 6, 7]]
+                    == np.asarray(vec)[[0, 1, 2, 3, 4, 6, 7]]).all()
             # the reference, given the same share, leaves out the same
             alone, _m = ref._experts(h, part, {**shape, "n_held_experts": 4,
                                                "first_expert": first})
@@ -366,9 +379,23 @@ def test_kernels_and_gather_path_emit_the_same_tokens(cfg, params,
     lines = "\n".join(render_prometheus_lines())
     for name in ("state_slots_total", "conv_slots_total",
                  "kda_state_resets_total", "moe_pairs_elsewhere_total",
-                 "moe_live_tiles_total", "moe_experts_touched_total",
-                 "moe_routed_pairs_total"):
+                 "moe_live_tiles_total", "moe_row_tiles_total",
+                 "moe_experts_touched_total", "moe_routed_pairs_total"):
         assert f'pathway_kv_{name}{{pool="t_q3n_pallas"}}' in lines, name
+    # the benchmark's reading of the tiles' rows (its counters() multiplies
+    # the live tiles by ops.moe.TM) stands: rows in units of 16, and at this
+    # engine's 36 x 3 pairs on 16 experts every tile is 16 rows tall
+    import types
+
+    from benchmark.systems.serve_qwen3_next import ServeQwen3Next
+    from pathway_tpu.ops.moe import TM
+
+    none = types.SimpleNamespace(completed=0, batches=0, batched_requests=0)
+    read = ServeQwen3Next.counters(types.SimpleNamespace(
+        engine=eng, cfg=cfg, sched=types.SimpleNamespace(stats=none)))
+    assert TM == 16 and snap["moe_row_tiles"] == snap["moe_live_tiles"] > 0
+    assert read["engine.moe_tile_rows"] == 16.0 * snap["moe_row_tiles"]
+    assert read["engine.moe_routed_pairs"] <= read["engine.moe_tile_rows"]
 
 
 def test_a_batch_emits_what_each_request_emits_alone(cfg, params,
