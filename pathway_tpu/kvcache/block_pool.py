@@ -115,7 +115,8 @@ class BlockPool(CacheBackend):
 
     def __init__(self, *, num_blocks: int, block_size: int, n_layers: int,
                  n_heads: int, head_dim: int, dtype=jnp.float32,
-                 name: str = "kvcache", mesh=None, tp_axis: str = "tp"):
+                 name: str = "kvcache", mesh=None, tp_axis: str = "tp",
+                 v_head_dim: int | None = None):
         if num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (block 0 is reserved)")
         self.num_blocks = int(num_blocks)
@@ -123,8 +124,13 @@ class BlockPool(CacheBackend):
         self.n_layers = int(n_layers)
         self.n_heads = int(n_heads)
         self.head_dim = int(head_dim)
+        # a V head of another width than a K head's (None: the same): the V
+        # pool's minor axis is n_heads * v_head_dim
+        self.v_head_dim = self.head_dim if v_head_dim is None \
+            else int(v_head_dim)
         self.dtype = dtype
         shape = (n_layers, num_blocks, block_size, n_heads * head_dim)
+        v_shape = shape[:3] + (n_heads * self.v_head_dim,)
         # Round-9 tensor parallelism: with a mesh, the K/V arrays are laid
         # out [L, NB, BS, n_kv_heads/tp * hd] PER SHARD via NamedSharding on
         # the fused axis (head-major, so each shard keeps its own heads,
@@ -147,14 +153,16 @@ class BlockPool(CacheBackend):
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             sharding = NamedSharding(mesh, P(None, None, None, tp_axis))
-            zeros = jax.jit(
-                lambda: jnp.zeros(shape, dtype), out_shardings=sharding
-            )
-            self.k = zeros()
-            self.v = zeros() if self.value_pool else None
+
+            def zeros(dims):
+                return jax.jit(lambda: jnp.zeros(dims, dtype),
+                               out_shardings=sharding)()
+
+            self.k = zeros(shape)
+            self.v = zeros(v_shape) if self.value_pool else None
         else:
             self.k = jnp.zeros(shape, dtype)
-            self.v = jnp.zeros(shape, dtype) if self.value_pool else None
+            self.v = jnp.zeros(v_shape, dtype) if self.value_pool else None
         # block 0 reserved: never allocated, target of padded writes
         self._free: list[int] = list(range(num_blocks - 1, 0, -1))
         self._ref = np.zeros(num_blocks, np.int32)
@@ -200,7 +208,8 @@ class BlockPool(CacheBackend):
     @property
     def per_shard_bytes(self) -> int:
         """K + V HBM held by EACH shard (the whole pool when tp=1)."""
-        total = int(self.k.size) * (2 if self.value_pool else 1)
+        total = int(self.k.size) + (int(self.v.size) if self.value_pool
+                                    else 0)
         return total * self.k.dtype.itemsize // self.tp
 
     @property

@@ -60,6 +60,7 @@ reserved null block), per the TPU static-shape rule.
 
 from __future__ import annotations
 
+import math
 import os
 import threading
 import time
@@ -74,7 +75,8 @@ from .. import faults, obs
 from .backend import make_backend
 from .block_pool import BlockPool, PoolExhausted  # noqa: F401 - re-export
 from .packing import RoundLayout
-from .paged_attention import query_tile_columns, span_blocks
+from .paged_attention import (query_tile_columns, span_blocks,
+                              window_pairs)
 from .prefix_cache import PrefixCache
 
 
@@ -632,7 +634,23 @@ class PagedDecodeEngine:
         self._query_tile_cols = query_tile_columns(
             np.arange(self.prefill_chunk + 1), self.prefill_chunk,
             cfg.n_heads // self.tp, self._pool_kwargs["head_dim"], lanes,
-            self._pool_kwargs["dtype"], latent=self.pool.v is None)
+            self._pool_kwargs["dtype"], latent=self.pool.v is None,
+            hd_v=self._pool_kwargs.get("v_head_dim"))
+        # on a windowed cache whose window is narrower than a chunk: the
+        # window pool's geometry as ``window_pairs`` takes it, for
+        # ``kv_window_band_pairs`` / ``kv_window_span_pairs``
+        self._window_pairs = None
+        window = self.pool.window
+        if window is not None and window < self.prefill_chunk:
+            wk, wv, hd = self.pool.kw.shape[-1], self.pool.vw.shape[-1], \
+                self.pool.head_dim
+            self._window_pairs = dict(
+                C=self.prefill_chunk, H=cfg.n_heads, hd=hd, D=wk,
+                dtype=self.pool.kw.dtype, window=window,
+                span=bs * span_blocks(bs, self.max_blocks_per_seq,
+                                      math.gcd(wk, wv)),
+                hd_v=None if self.pool.v_head_dim == hd
+                else self.pool.v_head_dim)
         # a cache that cannot share blocks (the hybrid one: a shared block
         # would skip the tokens that build the conv state) runs without a
         # prefix cache, whatever was asked for
@@ -2066,6 +2084,20 @@ class PagedDecodeEngine:
                kv_query_tile_cols=int(
                    self._query_tile_cols[row_nvalid].sum()))
 
+    def _note_window_pairs(self, ph, row_start, row_nvalid) -> None:
+        """On a windowed cache whose window is narrower than a chunk, what
+        a sliding-window layer's ragged call sees and computes for the
+        round's live rows, on ``pw.round.build`` and in the pool's
+        counters, from shapes alone (:func:`window_pairs`):
+        ``kv_window_band_pairs``, the query-key pairs inside the window,
+        and ``kv_window_span_pairs``, the pairs the kernel's live column
+        tiles times its live spans compute for them."""
+        if self._window_pairs is None:
+            return
+        band, run = window_pairs(row_start, row_nvalid, **self._window_pairs)
+        ph.set(kv_window_band_pairs=band, kv_window_span_pairs=run)
+        self.pool.stats.record_window_pairs(band, run)
+
     def _note_write_blocks(self, ph, slot_blocks) -> None:
         """``kv_write_blocks`` on a mixed round's ``pw.round.build`` and in
         the pool's counters: the distinct pool blocks the round's tokens
@@ -2285,6 +2317,7 @@ class PagedDecodeEngine:
                              row_start[:row] + row_nvalid[:row]],
                         [int(q) for q in row_nvalid[:row]])
         self._note_query_cols(ph, row_nvalid)
+        self._note_window_pairs(ph, row_start[:row], row_nvalid[:row])
         self._note_write_blocks(ph, sb[:t])
         runs = row_nvalid[:row]
         self._note_state(

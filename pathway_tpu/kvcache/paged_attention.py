@@ -80,6 +80,32 @@ folded ones at eight query heads a K/V head), which runs as it ran before.
 The engine counts how full the tiles run from the same rule
 (:func:`query_tile_columns`: ``kv_query_tile_cols`` on ``pw.round.build``).
 
+Keys and values of two widths, and the sink (PR 40): the three kernels
+and the gather reference take a V pool whose heads have a width of their
+own (``hd_v = V lanes / K/V heads``, read from the two pools' shapes: no
+argument) and ``sinks`` (None: none), one learned f32 logit a query head
+that joins its softmax's denominator and carries no value.  Both are
+static and absent from a call that gives neither: with V as wide as K and
+no sinks every kernel traces to the jaxpr it traced to before.  With ``hd_v
+!= hd`` heads go in groups whose K lanes AND V lanes are whole tiles
+(:func:`_heads_per_group`: two heads of 192 beside two of 128 are 384 and
+256 lanes), the score matmul slices ``kbuf`` by the group's K lanes and ``p
+@ v`` slices ``vbuf`` by its V lanes, ``acc`` and the output block are V's
+width, and the writer and the fused append move a K row and a V row of their
+own widths; nothing is padded in the pool.  The sinks reach the kernels as
+(Hkv, rep, 128) f32 (:func:`_sink_rows`: a K/V head's ``rep`` query heads
+down the sublanes, in the order the fold gives its columns) and are the
+state a row STARTS from (:func:`_start_row`: ``m = sink, l = 1, acc = 0``
+where it is ``-inf, 0, 0``): they cost nothing a span.  A row whose scratch
+and blocks would not fit VMEM (64 query heads of 192 beside values of 128
+at a chunk of 512: 129 MiB) is handed to the ragged kernel in pieces
+(:func:`query_pieces`, :func:`_row_pieces`: the latent kernel's cut, by the
+bytes :func:`_vmem_limit` asks for, under which every earlier geometry
+stays whole at its cell's chunk); under a window a
+piece's dead spans are its own, so a chunk wider than the window computes
+the spans its piece's columns see, not the chunk's
+(:func:`window_pairs` counts both for the engine).
+
 Round-8 raggedness (the fused mixed decode/prefill step):
 
 - every row carries ``C >= 1`` query tokens at CONSECUTIVE positions -
@@ -197,7 +223,8 @@ def _require_positive_context(C: int, context_lens, start_pos, n_valid):
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables,
                               context_lens=None, *, start_pos=None,
-                              n_valid=None, window: int | None = None):
+                              n_valid=None, window: int | None = None,
+                              sinks=None):
     """Gather-based ragged paged attention.
 
     q: (B, C, H, hd) — C consecutive query tokens per row (C=1 decode);
@@ -211,8 +238,11 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     tokens (padding columns past ``n_valid`` clamp to the last valid
     query's context — their output is garbage the caller masks).
     ``window`` (a sliding-window layer): a query at position ``p`` sees
-    the keys at ``p - window < j <= p`` only.
-    Returns (B, C, H, hd).
+    the keys at ``p - window < j <= p`` only.  The V pool's heads may have
+    a width of their own (``hd_v = v lanes / Hkv``: the output's).
+    ``sinks`` (H,) f32: one more logit a query head in its softmax, whose
+    probability is dropped (it carries no value).
+    Returns (B, C, H, hd_v).
     """
     B, C = q.shape[:2]
     _require_positive_context(C, context_lens, start_pos, n_valid)
@@ -223,7 +253,7 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     # per-(row, column) context: min(c0 + c, cl_last)
     ctx = jnp.minimum(c0[:, None] + jnp.arange(C)[None, :], cl_last[:, None])
     k = k_pool[block_tables].reshape(B, NB * BS, -1, hd)
-    v = v_pool[block_tables].reshape(B, NB * BS, -1, hd)
+    v = v_pool[block_tables].reshape(B, NB * BS, k.shape[2], -1)
     if k.shape[2] != H:  # grouped queries: each K/V head serves H/Hkv
         k = jnp.repeat(k, H // k.shape[2], axis=2)
         v = jnp.repeat(v, H // v.shape[2], axis=2)
@@ -234,12 +264,18 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     if window is not None:
         valid = valid & (k_pos >= ctx[:, :, None] - window)
     valid = valid[:, None, :, :]
-    scores = jnp.where(valid, scores, _NEG)
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
+    scores = jnp.where(valid, scores, _NEG).astype(jnp.float32)
+    if sinks is not None:
+        sink = jnp.broadcast_to(
+            jnp.asarray(sinks, jnp.float32)[None, :, None, None],
+            scores.shape[:3] + (1,))
+        scores = jnp.concatenate([scores, sink], axis=-1)
+    probs = jax.nn.softmax(scores, axis=-1)[..., :NB * BS].astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def _heads_per_group(H: int, hd: int, C: int) -> int:
+def _heads_per_group(H: int, hd: int, C: int,
+                     hd_v: int | None = None) -> int:
     """How many heads one matmul of the kernels takes.  A block of the
     pool is (BS, H*hd), heads side by side on the lane axis, and a slice
     of it that starts or ends inside a 128-lane tile costs a shuffle per
@@ -247,8 +283,12 @@ def _heads_per_group(H: int, hd: int, C: int) -> int:
     of 64), the group's query rows stacked on the sublane axis with the
     other heads' lanes zeroed.  Where no such group divides H (five heads
     a shard) or its rows would not fill a sublane tile (one decode row),
-    all heads form one group: the whole lane axis, no slice at all."""
+    all heads form one group: the whole lane axis, no slice at all.
+    ``hd_v`` (a V head of another width than a K head's): the group's lanes
+    are whole tiles in both pools (two heads of 192 beside two of 128)."""
     g = 128 // math.gcd(hd, 128)
+    if hd_v is not None:
+        g = math.lcm(g, 128 // math.gcd(hd_v, 128))
     return g if H % g == 0 and (g * C) % 8 == 0 else H
 
 
@@ -374,9 +414,14 @@ def _group(g, n_groups: int, G: int, W: int, t0, Tc: int) -> tuple:
     n_groups * G * Tc)``: what :func:`_start_row` resets) and a one-tile
     row's are the whole scratch, group ``g`` at ``g * G * C``."""
     n = G * Tc
-    lanes = slice(g * W, (g + 1) * W) if isinstance(g, int) \
-        else pl.ds(pl.multiple_of(g * W, W), W)
+    lanes = _lanes(g, W)
     return _rows(n_groups * G * t0 + g * n, n), lanes
+
+
+def _lanes(g, W: int):
+    """Group ``g``'s ``W`` lanes of a pool's block."""
+    return slice(g * W, (g + 1) * W) if isinstance(g, int) \
+        else pl.ds(pl.multiple_of(g * W, W), W)
 
 
 def _live_columns(b, c0_ref, cl_ref, rep: int, tiles: tuple | None):
@@ -390,20 +435,29 @@ def _live_columns(b, c0_ref, cl_ref, rep: int, tiles: tuple | None):
 
 
 def query_tile_columns(n_valid, C: int, H: int, hd: int, D: int, dtype,
-                       latent: bool = False):
+                       latent: bool = False, hd_v: int | None = None):
     """The query columns the kernels' live tiles cover for rows of
     ``n_valid`` live columns each (an idle row has one) of ``C``, ``H``
     query heads of ``hd`` over a pool of ``D`` lanes, queries in ``dtype``
-    (``latent``: through :func:`latent_attention`, a row in pieces): the
-    rule of :func:`_col_tiles` as :func:`_live_tiles` runs it
-    (:func:`_wide_tiles`), for the engine's ``kv_query_tile_cols``.
-    (len(n_valid),) int64."""
+    (``latent``: through :func:`latent_attention`, a row in pieces;
+    ``hd_v``: a V head of another width): the rule of :func:`_col_tiles` as
+    :func:`_live_tiles` runs it (:func:`_wide_tiles`), for the engine's
+    ``kv_query_tile_cols``.  (len(n_valid),) int64."""
+    return _piece_tile_columns(n_valid, C, H, hd, D, dtype, latent,
+                               hd_v)[0].sum(axis=1)
+
+
+def _piece_tile_columns(n_valid, C: int, H: int, hd: int, D: int, dtype,
+                        latent: bool = False, hd_v: int | None = None):
+    """``(run, cols)``: the query columns the live tiles of each piece of
+    each row cover ((rows, pieces) int64), and a piece's width."""
     n = np.asarray(n_valid, np.int64)
-    pieces = _latent_pieces(C) if latent else 1
+    pieces = _latent_pieces(C) if latent \
+        else query_pieces(C, H, hd, D, dtype, hd_v)
     C //= pieces
     rep = H * hd // D
-    tiles = _col_tiles(_heads_per_group(D // hd, hd, C * rep), C * rep, rep,
-                       dtype)
+    tiles = _col_tiles(_heads_per_group(D // hd, hd, C * rep, hd_v), C * rep,
+                       rep, dtype)
     # a piece's live columns; a piece past the row's valid ones runs one
     live = np.clip(n[:, None] - C * np.arange(pieces)[None, :], 1, C) * rep
     if tiles is None:
@@ -412,11 +466,38 @@ def query_tile_columns(n_valid, C: int, H: int, hd: int, D: int, dtype,
         first, tile = tiles
         wide = _wide_tiles(live, first, tile)
         run = np.where(wide == 0, first, wide * tile)
-    return run.sum(axis=1) // rep
+    return run // rep, C
+
+
+def window_pairs(start_pos, n_valid, C: int, H: int, hd: int, D: int, dtype,
+                 window: int, span: int, hd_v: int | None = None) -> tuple:
+    """What a sliding-window layer's call of the ragged kernel sees and what
+    it computes, for rows of ``n_valid`` live columns from position
+    ``start_pos`` (query geometry as :func:`query_tile_columns` takes it,
+    ``span`` the keys a grid step attends): ``(band, run)`` query-key pairs
+    - ``band``, each live column's ``min(context, window)``; ``run``, each
+    piece's live tile columns times the keys of its live spans (from the span
+    of the first key its first column sees to the span of its last key:
+    :func:`_first_span`).  ``band / run`` is how full the kernel's work is of
+    pairs the softmax keeps; both from shapes alone, for the engine's
+    ``kv_window_band_pairs`` / ``kv_window_span_pairs``."""
+    start = np.asarray(start_pos, np.int64)
+    n = np.asarray(n_valid, np.int64)
+    run, cols = _piece_tile_columns(n, C, H, hd, D, dtype, hd_v=hd_v)
+    c0 = start[:, None] + 1 + cols * np.arange(run.shape[1])[None, :]
+    cl = np.minimum((start + n)[:, None], c0 + cols - 1)
+    dead = c0 > cl  # a piece past the row's columns: one key, one span
+    c0, cl = np.where(dead, 1, c0), np.where(dead, 1, cl)
+    spans = (cl - 1) // span - np.maximum(c0 - window, 0) // span + 1
+    ctx = start[:, None] + 1 + np.arange(C)[None, :]
+    band = np.where(np.arange(C)[None, :] < n[:, None],
+                    np.minimum(ctx, window), 0)
+    return int(band.sum()), int((run * spans).sum() * span)
 
 
 def _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, live=None, *, C: int,
-               G: int, hd: int, tiles: tuple | None = None):
+               G: int, hd: int, tiles: tuple | None = None,
+               hd_v: int | None = None, sink_ref=None, rep: int = 1):
     """First grid step of a batch row: reset the online softmax and lay
     the row's queries out for the grouped matmuls, for the row's live
     column tiles (:func:`_live_tiles`; one tile of ``C`` columns without
@@ -425,17 +506,29 @@ def _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, live=None, *, C: int,
     the lanes of i's place in its group and zeros in the other heads' lanes,
     so that ``qm[group rows] @ k[:, group lanes].T`` is every head's own
     scores (a zero lane adds an exact 0 to the f32 sum).  The rows of the
-    tiles that are not live keep what they held, and nothing reads them."""
+    tiles that are not live keep what they held, and nothing reads them.
+    ``hd_v``: ``acc_ref`` is as wide as the group's V heads.  ``sink_ref``
+    ((Hkv, rep, 128) f32: K/V head ``kv``'s ``rep`` query heads' sinks down
+    the sublanes, broadcast over the lanes): the softmax starts from the
+    sink, ``m = sink, l = 1``, in place of ``-inf, 0`` - a tile's columns
+    start at a whole query column (:func:`_col_tiles`), so the rows of a
+    head's piece are its ``rep`` sinks over and over."""
     W, n_groups = G * hd, qm_ref.shape[0] // (G * C)
     sub = _sublanes(q_ref.dtype)
 
     def lay(t0, Tc: int):
         n = G * Tc
         tile = _rows(n_groups * G * t0, n_groups * n)  # every group's rows
-        m_ref[tile] = jnp.full((n_groups * n, m_ref.shape[1]), _NEG,
-                               m_ref.dtype)
-        l_ref[tile] = jnp.zeros((n_groups * n, l_ref.shape[1]), l_ref.dtype)
-        acc_ref[tile] = jnp.zeros((n_groups * n, W), acc_ref.dtype)
+        if sink_ref is None:
+            m_ref[tile] = jnp.full((n_groups * n, m_ref.shape[1]), _NEG,
+                                   m_ref.dtype)
+            l_ref[tile] = jnp.zeros((n_groups * n, l_ref.shape[1]),
+                                    l_ref.dtype)
+        else:
+            l_ref[tile] = jnp.ones((n_groups * n, l_ref.shape[1]),
+                                   l_ref.dtype)
+        acc_ref[tile] = jnp.zeros((n_groups * n, acc_ref.shape[1]),
+                                  acc_ref.dtype)
         own = _own_lanes(n, W, Tc, hd)
         # whole sublane tiles of the query dtype come in (they lie inside
         # the block: _col_tiles); the first tile's columns are cut from them
@@ -452,6 +545,10 @@ def _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, live=None, *, C: int,
             qg = jnp.broadcast_to(qg, (n, W)) if Tc == 1 \
                 else jnp.concatenate([qg] * G, axis=0)
             qm_ref[rows, :] = jnp.where(own, qg, 0.0).astype(qm_ref.dtype)
+            if sink_ref is not None:
+                m_ref[rows] = jnp.concatenate(
+                    [sink_ref[g * G + i] for i in range(G)
+                     for _ in range(Tc // rep)], axis=0)
 
         _each_group(n_groups, tiles, group)
 
@@ -461,7 +558,7 @@ def _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, live=None, *, C: int,
 def _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref, acc_ref,
                  live=None, *, scale: float, rep: int, C: int, G: int,
                  hd: int, window: int | None = None,
-                 tiles: tuple | None = None):
+                 tiles: tuple | None = None, hd_v: int | None = None):
     """One visible span's online-softmax update, shared by both kernels:
     the ``span`` keys (a lane tile's worth: K blocks of the pool) that grid
     step ``j`` attends, so that scores, mask, ``exp`` and row sums fill the
@@ -479,7 +576,9 @@ def _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref, acc_ref,
     queries): the ``rep`` query heads of a K/V head ride as ``rep``
     neighbouring columns of it, so of the C columns here column ``c`` is
     query column ``c // rep``.  ``window``: a column of context ``n``
-    (position ``n - 1``) sees the keys at ``n - window <= j < n`` only."""
+    (position ``n - 1``) sees the keys at ``n - window <= j < n`` only.
+    ``hd_v``: ``vbuf``'s heads are that wide, so the group's lanes of V and
+    acc's width are ``G * hd_v`` where K's are ``G * hd``."""
     W, span = G * hd, kbuf.shape[1]
     n_groups = qm_ref.shape[0] // (G * C)
 
@@ -504,7 +603,7 @@ def _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref, acc_ref,
             valid = valid & (k_pos >= col_ctx - window)
         return valid
 
-    def update(rows, lanes, valid):
+    def update(rows, lanes, vlanes, valid):
         n = valid.shape[0]
         s = jax.lax.dot_general(
             qm_ref[rows], kbuf[slot, :, lanes],
@@ -522,7 +621,7 @@ def _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref, acc_ref,
             l_ref[rows, :1] * corr + jnp.sum(p, axis=1, keepdims=True),
             (n, l_ref.shape[1]),
         )
-        vb = vbuf[slot, :, lanes]
+        vb = vbuf[slot, :, vlanes]
         acc_ref[rows] = acc_ref[rows] * corr + jax.lax.dot_general(
             p.astype(vb.dtype), vb,
             dimension_numbers=(((1,), (0,)), ((), ())),
@@ -532,18 +631,24 @@ def _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref, acc_ref,
 
     def attend(t0, Tc: int):
         valid = mask(t0, Tc)
-        _each_group(n_groups, tiles, lambda g: update(
-            *_group(g, n_groups, G, W, t0, Tc), valid))
+
+        def group(g):
+            rows, lanes = _group(g, n_groups, G, W, t0, Tc)
+            update(rows, lanes,
+                   lanes if hd_v is None else _lanes(g, G * hd_v), valid)
+
+        _each_group(n_groups, tiles, group)
 
     _live_tiles(tiles, live, C, attend)
 
 
 def _write_out(o_ref, l_ref, acc_ref, live=None, *, C: int, G: int, hd: int,
-               tiles: tuple | None = None):
-    """o (C, H*hd): each head's lanes from its own rows of acc / l, for the
+               tiles: tuple | None = None, hd_v: int | None = None):
+    """o (C, H*hd_v): each head's lanes from its own rows of acc / l, for the
     row's live column tiles (:func:`_live_tiles`); with ``tiles`` the
     columns past them are written as zeros (padding the caller drops, which
     has to be finite)."""
+    hd = hd if hd_v is None else hd_v
     W, n_groups = G * hd, acc_ref.shape[0] // (G * C)
     if tiles is not None:
         o_ref[:] = jnp.zeros_like(o_ref)
@@ -656,10 +761,9 @@ def _live_span(j, c0, jlast, window: int | None, span: int):
     return live
 
 
-def _paged_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, k_in, v_in, o_ref,
-                  kbuf, vbuf, sem, n_ref, qm_ref, m_ref, l_ref, acc_ref, *,
-                  K: int, block_size: int, scale: float, rep: int,
-                  window: int | None = None, **geom):
+def _paged_kernel(li_ref, bt_ref, c0_ref, cl_ref, *refs, K: int,
+                  block_size: int, scale: float, rep: int,
+                  window: int | None = None, sinks: bool = False, **geom):
     """Grid: (B, NS) - spans innermost, so (m, l, acc) scratch carries the
     online softmax across one sequence's spans.  Blocks: q and o
     (C, H*hd); the pools whole, in HBM, of which :func:`_next_span` brings
@@ -667,14 +771,18 @@ def _paged_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, k_in, v_in, o_ref,
     ((2, K*block_size, H*hd)).  Spans past the row's context
     (``j > jlast``) and, with a ``window``, spans wholly behind the row's
     first column's window are dead: every ``@pl.when`` below is false and
-    no copy is started, so they cost an empty grid step."""
+    no copy is started, so they cost an empty grid step.  ``sinks``: the
+    first operand is the query heads' sinks (:func:`_sink_rows`)."""
+    sink, (q_ref, k_in, v_in, o_ref, kbuf, vbuf, sem, n_ref, qm_ref, m_ref,
+           l_ref, acc_ref) = _split_sink(refs, sinks, rep)
     b = pl.program_id(0)
     j = pl.program_id(1)
     live = _live_columns(b, c0_ref, cl_ref, rep, geom["tiles"])
 
     @pl.when(j == 0)
     def _init():
-        _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, live, **geom)
+        _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, live, **geom,
+                   **sink)
 
     c0 = c0_ref[b]       # column 0's context length
     ctx = cl_ref[b]      # the row's full context (last valid column's)
@@ -698,18 +806,53 @@ def _paged_kernel(li_ref, bt_ref, c0_ref, cl_ref, q_ref, k_in, v_in, o_ref,
         _write_out(o_ref, l_ref, acc_ref, live, **geom)
 
 
+def _split_sink(refs: tuple, sinks: bool, rep: int) -> tuple:
+    """A kernel's operands after the scalar-prefetched ones: ``(what
+    :func:`_start_row` takes of the sinks, the other refs)``."""
+    if not sinks:
+        return {}, refs
+    return {"sink_ref": refs[0], "rep": rep}, refs[1:]
+
+
+def _sink_rows(sinks, Hkv: int, rep: int, operands: tuple,
+               geom: dict) -> tuple:
+    """``(operands, leading in_specs, geom)`` of a kernel call with
+    ``sinks`` (None: as they came, and nothing traced).  The sinks go first,
+    as the kernels take them: (Hkv, rep, 128) f32, K/V head ``kv``'s query
+    heads ``kv * rep + r`` down the sublanes (the order the fold gives its
+    columns, :func:`_fold_queries`), each over the lanes of the softmax
+    state; their block spec is the whole array, once."""
+    if sinks is None:
+        return operands, [], geom
+    rows = jnp.broadcast_to(
+        jnp.asarray(sinks, jnp.float32).reshape(Hkv, rep, 1), (Hkv, rep, 128))
+    spec = pl.BlockSpec((Hkv, rep, 128), lambda *_: (0, 0, 0))
+    return (rows, *operands), [spec], {**geom, "sinks": True}
+
+
 def _scratch(K: int, BS: int, D: int, pool_dtype, H: int, C: int, hd: int,
-             G: int, dtype):
+             G: int, dtype, Dv: int | None = None, hd_v: int | None = None):
+    Dv, hd_v = D if Dv is None else Dv, hd if hd_v is None else hd_v
     return [
         pltpu.VMEM((2, K * BS, D), pool_dtype),    # kbuf: this span, next
-        pltpu.VMEM((2, K * BS, D), pool_dtype),    # vbuf
+        pltpu.VMEM((2, K * BS, Dv), pool_dtype),   # vbuf
         pltpu.SemaphoreType.DMA((2,)),             # one a buffer slot
         pltpu.SMEM((1,), jnp.int32),               # live steps so far
         pltpu.VMEM((H * C, G * hd), dtype),        # qm: grouped queries
         pltpu.VMEM((H * C, 128), jnp.float32),     # m
         pltpu.VMEM((H * C, 128), jnp.float32),     # l
-        pltpu.VMEM((H * C, G * hd), jnp.float32),  # acc
+        pltpu.VMEM((H * C, G * hd_v), jnp.float32),  # acc
     ]
+
+
+def _value_geometry(hd: int, D: int, Dv: int) -> tuple:
+    """``(H, hd_v, geom)`` of pools of ``D`` key and ``Dv`` value lanes under
+    query heads of ``hd``: the K/V heads, a V head's width, and what the
+    kernels' pieces take of it - nothing where V's heads are K's width, so
+    that those kernels trace to what they traced to."""
+    H = D // hd
+    hd_v = Dv // H
+    return H, hd_v, ({} if hd_v == hd else {"hd_v": hd_v})
 
 
 def _fold_queries(q, D: int):
@@ -765,24 +908,39 @@ def _pool_spec(K: int, BS: int, D: int, window: int | None = None):
                                   (cl[b] - 1) // BS)], 0, 0))
 
 
+_VMEM_CAP = 100 * 2 ** 20   # the most a kernel call asks of the chip's 128 MiB
+_VMEM_ROOM = 24 * 2 ** 20   # over the scratch and blocks: a row tile's scores
+
+
+def _vmem_need(K: int, BS: int, D: int, pool_dtype, H: int, C: int, hd: int,
+               G: int, dtype, Dv: int | None = None,
+               hd_v: int | None = None) -> int:
+    """Bytes of VMEM a ragged kernel's row holds: its scratch
+    (:func:`_scratch`) and its double-buffered query and output blocks."""
+    Dv, hd_v = D if Dv is None else Dv, hd if hd_v is None else hd_v
+    item = jnp.dtype(dtype).itemsize
+    return 2 * K * BS * (D + Dv) * jnp.dtype(pool_dtype).itemsize \
+        + H * C * G * (hd * item + hd_v * 4) \
+        + 2 * H * C * 128 * 4 + 2 * C * (D + Dv) * item
+
+
 def _vmem_limit(K: int, BS: int, D: int, pool_dtype, H: int, C: int, hd: int,
-                G: int, dtype) -> dict:
+                G: int, dtype, Dv: int | None = None,
+                hd_v: int | None = None) -> dict:
     """``compiler_params`` of a kernel call: none while the kernel's
-    scratch and its double-buffered query and output blocks fit the
-    compiler's own scoped limit with room to spare (every geometry before
-    eight folded query heads at a chunk of 256), else a limit that holds
-    them and the scores of a row tile."""
-    need = 2 * 2 * K * BS * D * jnp.dtype(pool_dtype).itemsize \
-        + H * C * G * hd * (jnp.dtype(dtype).itemsize + 4) \
-        + 2 * H * C * 128 * 4 + 2 * 2 * C * D * jnp.dtype(dtype).itemsize
+    scratch and its double-buffered query and output blocks
+    (:func:`_vmem_need`) fit the compiler's own scoped limit with room to
+    spare (every geometry before eight folded query heads at a chunk of
+    256), else a limit that holds them and the scores of a row tile."""
+    need = _vmem_need(K, BS, D, pool_dtype, H, C, hd, G, dtype, Dv, hd_v)
     if need <= 10 * 2 ** 20:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
-        vmem_limit_bytes=int(min(need + 24 * 2 ** 20, 100 * 2 ** 20)))}
+        vmem_limit_bytes=int(min(need + _VMEM_ROOM, _VMEM_CAP)))}
 
 
-def _paged_ragged_fn(q, k_pool, v_pool, layer, block_tables, c0, cl, *,
-                     d_true: int, interpret: bool = False,
+def _paged_ragged_fn(q, k_pool, v_pool, layer, block_tables, c0, cl,
+                     sinks=None, *, d_true: int, interpret: bool = False,
                      window: int | None = None):
     """q: (B, C, H, hd); pools (L, num_blocks, BS, Hkv*hd) - ALL layers'
     stacked pool, read in place at ``layer`` ((1,) int32): the kernel's own
@@ -792,36 +950,48 @@ def _paged_ragged_fn(q, k_pool, v_pool, layer, block_tables, c0, cl, *,
     HBM is the one the kernel reads; a grid step attends K of them
     (:func:`span_blocks`); c0/cl: (B,) per-row column-0 / last-column
     context lengths; ``window``: a sliding-window layer's width (static),
-    None for full attention."""
+    None for full attention.  The V pool may have lanes of its own
+    (``Hkv * hd_v``: a V head narrower than a K head, read from the two
+    pools' shapes; the output is (B, C, H, hd_v)); ``sinks`` (H,) f32, or
+    None: a learned logit a query head that joins its softmax's
+    denominator (:func:`_start_row`)."""
     B, hd = q.shape[0], q.shape[3]
     BS, D = k_pool.shape[2:]
+    Dv = v_pool.shape[3]
     NB = block_tables.shape[1]
-    K = span_blocks(BS, NB, D)
+    K = span_blocks(BS, NB, math.gcd(D, Dv))
     qf, rep = _fold_queries(q, D)
-    C, H = qf.shape[1], D // hd
-    G = _heads_per_group(H, hd, C)
+    H, hd_v, geom = _value_geometry(hd, D, Dv)
+    C = qf.shape[1]
+    G = _heads_per_group(H, hd, C, geom.get("hd_v"))
+    operands, sink_specs, geom = _sink_rows(
+        sinks, H, rep, (qf, k_pool, v_pool), geom)
     kernel = functools.partial(
         _paged_kernel, K=K, block_size=BS, scale=1.0 / np.sqrt(d_true),
         rep=rep, C=C, G=G, hd=hd, tiles=_col_tiles(G, C, rep, q.dtype),
-        **({} if window is None else {"window": int(window)}),
+        **geom, **({} if window is None else {"window": int(window)}),
     )
-    row = pl.BlockSpec((None, C, D), lambda b, j, *_: (b, 0, 0))
-    pool = _pool_spec(K, BS, D, window)
+
+    def row(lanes):
+        return pl.BlockSpec((None, C, lanes), lambda b, j, *_: (b, 0, 0))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,  # layer, block_tables, c0, cl
         grid=(B, -(-NB // K)),
-        in_specs=[row, pool, pool],
-        out_specs=row,
-        scratch_shapes=_scratch(K, BS, D, k_pool.dtype, H, C, hd, G, q.dtype),
+        in_specs=[*sink_specs, row(D), _pool_spec(K, BS, D, window),
+                  _pool_spec(K, BS, Dv, window)],
+        out_specs=row(Dv),
+        scratch_shapes=_scratch(K, BS, D, k_pool.dtype, H, C, hd, G, q.dtype,
+                                Dv, hd_v),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, C, Dv), q.dtype),
         interpret=interpret,
-        **_vmem_limit(K, BS, D, k_pool.dtype, H, C, hd, G, q.dtype),
-    )(layer, block_tables, c0, cl, qf, k_pool, v_pool)
-    return _unfold_queries(out, q.shape, rep)
+        **_vmem_limit(K, BS, D, k_pool.dtype, H, C, hd, G, q.dtype, Dv, hd_v),
+    )(layer, block_tables, c0, cl, *operands)
+    return _unfold_queries(out, q.shape[:3] + (hd_v,), rep)
 
 
 def _make_paged_ragged():
@@ -840,11 +1010,9 @@ def _make_paged_ragged():
 _paged_ragged = _make_paged_ragged()
 
 
-def _append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, q_ref, k1_ref,
-                   v1_ref, k_in, v_in, o_ref, ko_ref, vo_ref, kbuf, vbuf,
-                   sem, n_ref, qm_ref, m_ref, l_ref, acc_ref, *, K: int,
+def _append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, *refs, K: int,
                    block_size: int, scale: float, rep: int,
-                   window: int | None = None, **geom):
+                   window: int | None = None, sinks: bool = False, **geom):
     """Round-17 fused append+attend (decode, C=1): the incoming token's
     K/V rides into the kernel as a (1, H*hd) operand, is patched into the
     tail block IN VMEM for the attention math (the HBM copy the kernel
@@ -856,12 +1024,15 @@ def _append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, q_ref, k1_ref,
     so exactly ONE block per pool per row is written (at ``j == jlast``),
     the same write set as the scatter.  Same grid / gather /
     online-softmax recurrence as :func:`_paged_kernel`."""
+    sink, (q_ref, k1_ref, v1_ref, k_in, v_in, o_ref, ko_ref, vo_ref, kbuf,
+           vbuf, sem, n_ref, qm_ref, m_ref, l_ref, acc_ref) = _split_sink(
+        refs, sinks, rep)
     b = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, **geom)
+        _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, **geom, **sink)
 
     c0 = c0_ref[b]
     ctx = cl_ref[b]
@@ -897,7 +1068,7 @@ def _append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, q_ref, k1_ref,
 
 
 def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, layer, block_tables,
-                     c0, cl, slot_offsets, *, d_true: int,
+                     c0, cl, slot_offsets, sinks=None, *, d_true: int,
                      interpret: bool = False, window: int | None = None):
     """q: (B, 1, H, hd); k_new/v_new: (B, Hkv, hd); pools
     (L, num_blocks, BS, Hkv*hd) - ALL layers' stacked pool, returned
@@ -906,54 +1077,63 @@ def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, layer, block_tables,
     copied.  Contract: the slot is the tail of the attended context
     (``slot_blocks[b] == block_tables[b, (cl[b]-1)//BS]`` and
     ``slot_offsets[b] == (cl[b]-1) % BS``) - the decode append the
-    engine constructs by definition."""
+    engine constructs by definition.  V's lanes and ``sinks``: as
+    :func:`_paged_ragged_fn` (``v_new`` (B, Hkv, hd_v))."""
     B, hd = q.shape[0], q.shape[3]
     BS, D = k_pool.shape[2:]
+    Dv = v_pool.shape[3]
     NB = block_tables.shape[1]
-    K = span_blocks(BS, NB, D)
+    K = span_blocks(BS, NB, math.gcd(D, Dv))
     qf, rep = _fold_queries(q, D)
-    C, H = qf.shape[1], D // hd
-    G = _heads_per_group(H, hd, C)
+    H, hd_v, geom = _value_geometry(hd, D, Dv)
+    C = qf.shape[1]
+    G = _heads_per_group(H, hd, C, geom.get("hd_v"))
+    operands, sink_specs, geom = _sink_rows(
+        sinks, H, rep, (qf, k_new.reshape(B, 1, D), v_new.reshape(B, 1, Dv),
+                        k_pool, v_pool), geom)
     kernel = functools.partial(
         _append_kernel, K=K, block_size=BS, scale=1.0 / np.sqrt(d_true),
-        rep=rep, C=C, G=G, hd=hd,
+        rep=rep, C=C, G=G, hd=hd, **geom,
         **({} if window is None else {"window": int(window)}),
     )
 
-    def _row(rows):
-        return pl.BlockSpec((None, rows, D), lambda b, j, *_: (b, 0, 0))
+    def _row(rows, lanes=D):
+        return pl.BlockSpec((None, rows, lanes), lambda b, j, *_: (b, 0, 0))
 
     def _slot_map(b, j, li, bt, c0, cl, so):
         # constant per row: the pool out-block IS the row's slot block
         return (li[0], bt[b, (cl[b] - 1) // BS], 0, 0)
 
-    pool = _pool_spec(K, BS, D, window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,  # layer, block_tables, c0, cl, slot_offsets
         grid=(B, -(-NB // K)),
-        in_specs=[_row(C), _row(1), _row(1), pool, pool],
+        in_specs=[*sink_specs, _row(C), _row(1), _row(1, Dv),
+                  _pool_spec(K, BS, D, window),
+                  _pool_spec(K, BS, Dv, window)],
         out_specs=[
-            _row(C),
+            _row(C, Dv),
             pl.BlockSpec((None, None, BS, D), _slot_map),
-            pl.BlockSpec((None, None, BS, D), _slot_map),
+            pl.BlockSpec((None, None, BS, Dv), _slot_map),
         ],
-        scratch_shapes=_scratch(K, BS, D, k_pool.dtype, H, C, hd, G, q.dtype),
+        scratch_shapes=_scratch(K, BS, D, k_pool.dtype, H, C, hd, G, q.dtype,
+                                Dv, hd_v),
     )
     # alias indices count the scalar-prefetch operands: pools are operands
-    # 8/9 of (layer, bt, c0, cl, so, q, k_new, v_new, k_pool, v_pool)
+    # 8/9 of (layer, bt, c0, cl, so, q, k_new, v_new, k_pool, v_pool), one
+    # later behind the sinks
+    first = 8 + len(sink_specs)
     o, k_pool, v_pool = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, C, D), q.dtype),
+            jax.ShapeDtypeStruct((B, C, Dv), q.dtype),
             jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
             jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
         ],
-        input_output_aliases={8: 1, 9: 2},
+        input_output_aliases={first: 1, first + 1: 2},
         interpret=interpret,
-    )(layer, block_tables, c0, cl, slot_offsets, qf,
-      k_new.reshape(B, 1, D), v_new.reshape(B, 1, D), k_pool, v_pool)
-    return _unfold_queries(o, q.shape, rep), k_pool, v_pool
+    )(layer, block_tables, c0, cl, slot_offsets, *operands)
+    return _unfold_queries(o, q.shape[:3] + (hd_v,), rep), k_pool, v_pool
 
 
 def _make_paged_append():
@@ -1006,10 +1186,15 @@ def _paged_write_fn(k_rows, v_rows, k_pool, v_pool, layer, slot_blocks,
     changes its layout.  The block's shape is the pool's own: lanes that
     are no whole tiles (a tp shard) come through the same block spec."""
     T, D = k_rows.shape
+    Dv = v_rows.shape[1]  # V's lanes may be its own
     BS = k_pool.shape[2]
-    row = pl.BlockSpec((None, 1, D), lambda t, *_: (t, 0, 0))
-    block = pl.BlockSpec((None, None, BS, D),
-                         lambda t, li, sb, so: (li[0], sb[t], 0, 0))
+
+    def row(lanes):
+        return pl.BlockSpec((None, 1, lanes), lambda t, *_: (t, 0, 0))
+
+    def block(lanes):
+        return pl.BlockSpec((None, None, BS, lanes),
+                            lambda t, li, sb, so: (li[0], sb[t], 0, 0))
     # alias indices count the scalar-prefetch operands: pools are operands
     # 5/6 of (layer, sb, so, k_rows, v_rows, k_pool, v_pool)
     return pl.pallas_call(
@@ -1017,15 +1202,15 @@ def _paged_write_fn(k_rows, v_rows, k_pool, v_pool, layer, slot_blocks,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,  # layer, slot_blocks, slot_offsets
             grid=(T,),
-            in_specs=[row, row, block, block],
-            out_specs=[block, block],
+            in_specs=[row(D), row(Dv), block(D), block(Dv)],
+            out_specs=[block(D), block(Dv)],
         ),
         out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
                    jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
         input_output_aliases={5: 0, 6: 1},
         interpret=interpret,
     )(layer, slot_blocks, slot_offsets, k_rows.reshape(T, 1, D),
-      v_rows.reshape(T, 1, D), k_pool, v_pool)
+      v_rows.reshape(T, 1, Dv), k_pool, v_pool)
 
 
 _paged_write = jax.jit(_paged_write_fn, static_argnames=("interpret",),
@@ -1058,6 +1243,49 @@ def _latent_pieces(C: int) -> int:
     columns into."""
     return C // _LATENT_COLS if C > _LATENT_COLS and C % _LATENT_COLS == 0 \
         else 1
+
+
+def _row_pieces(q, tables, c0, cl, n: int) -> tuple:
+    """Rows of ``C`` query columns as ``n`` kernel rows of ``C // n`` each
+    over the same table: piece i of a row holds its columns from ``i * C //
+    n`` on; one past the row's valid columns attends one key and is
+    dropped."""
+    B, C = q.shape[:2]
+    cols = C // n
+    first = c0[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :] * cols
+    live = first <= cl[:, None]
+    c0 = jnp.where(live, first, 1).reshape(B * n)
+    cl = jnp.where(live, jnp.minimum(cl[:, None], first + cols - 1),
+                   1).reshape(B * n)
+    return (q.reshape(B * n, cols, *q.shape[2:]),
+            jnp.repeat(tables, n, axis=0), c0, cl)
+
+
+def query_pieces(C: int, H: int, hd: int, D: int, dtype,
+                 hd_v: int | None = None) -> int:
+    """The kernel rows :func:`paged_attention` cuts a row of ``C`` query
+    columns of ``H`` heads of ``hd`` into, over pools of ``D`` key lanes and
+    V heads of ``hd_v``: one while what the row holds in VMEM
+    (:func:`_vmem_need`, at the widest span and a pool of the queries'
+    dtype) and the room :func:`_vmem_limit` asks over it stay under
+    :data:`_VMEM_CAP` - sixteen heads of 256 at a chunk of 1,024 ask 96.5 of
+    the 100 MiB, the most of any geometry before 64 heads of 192, and stay
+    whole - else halves until they do (64 heads of 192 beside values of 128
+    at a chunk of 512: two pieces of 256), a piece whole sublane tiles
+    wide."""
+    Hkv, rep = D // hd, H * hd // D
+    hd_v = hd if hd_v is None else hd_v
+
+    def fits(cols: int) -> bool:
+        R = cols * rep
+        G = _heads_per_group(Hkv, hd, R, None if hd_v == hd else hd_v)
+        return _vmem_need(1, _LANES, D, dtype, Hkv, R, hd, G, dtype,
+                          Hkv * hd_v, hd_v) + _VMEM_ROOM <= _VMEM_CAP
+
+    n = 1
+    while not fits(C // n) and (C // n) % 32 == 0:
+        n *= 2
+    return n
 
 
 def _latent_scratch(K: int, BS: int, W: int, pool_dtype, R: int, dtype):
@@ -1373,17 +1601,7 @@ def latent_attention(q, pool, block_tables, context_lens=None, *,
     tables = jnp.asarray(block_tables, jnp.int32)
     n = _latent_pieces(C)
     if n > 1:
-        # piece i of a row: its columns from i * _LATENT_COLS on; one past
-        # the row's valid columns attends one key
-        first = c0[:, None] \
-            + jnp.arange(n, dtype=jnp.int32)[None, :] * _LATENT_COLS
-        live = first <= cl[:, None]
-        c0 = jnp.where(live, first, 1).reshape(B * n)
-        cl = jnp.where(live, jnp.minimum(cl[:, None],
-                                         first + _LATENT_COLS - 1),
-                       1).reshape(B * n)
-        q = q.reshape(B * n, _LATENT_COLS, H, W)
-        tables = jnp.repeat(tables, n, axis=0)
+        q, tables, c0, cl = _row_pieces(q, tables, c0, cl, n)
     pp, li = _latent_layer(pool, layer)
     out = _paged_latent(
         q, pp, li, tables, c0.astype(jnp.int32), cl.astype(jnp.int32),
@@ -1456,7 +1674,7 @@ def paged_append_attend(q, k_new, v_new, k_pool, v_pool, block_tables,
                         context_lens, slot_blocks, slot_offsets, *,
                         layer=None, use_pallas: bool | None = None,
                         interpret: bool | None = None,
-                        window: int | None = None):
+                        window: int | None = None, sinks=None):
     """Fused decode append+attend over one layer of the pool: scatter
     the incoming token's K/V at ``(slot_blocks, slot_offsets)`` and
     attend through ``block_tables`` in a single program.
@@ -1485,7 +1703,7 @@ def paged_append_attend(q, k_new, v_new, k_pool, v_pool, block_tables,
             layer=layer, use_pallas=False)
         a = paged_attention_reference(
             q, _layer_of(k_pool, layer), _layer_of(v_pool, layer),
-            block_tables, context_lens, window=window,
+            block_tables, context_lens, window=window, sinks=sinks,
         )
         return a, k_pool, v_pool
     _require_positive_context(1, context_lens, None, None)
@@ -1496,6 +1714,7 @@ def paged_append_attend(q, k_new, v_new, k_pool, v_pool, block_tables,
         jnp.asarray(block_tables, jnp.int32),
         c0.astype(jnp.int32), cl_last.astype(jnp.int32),
         jnp.asarray(slot_offsets, jnp.int32),
+        *(() if sinks is None else (sinks,)),
         d_true=hd,
         interpret=(backend != "tpu") if interpret is None else interpret,
         **({} if window is None else {"window": int(window)}),
@@ -1507,14 +1726,18 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens=None, *,
                     start_pos=None, n_valid=None, layer=None,
                     use_pallas: bool | None = None,
                     interpret: bool | None = None,
-                    window: int | None = None):
+                    window: int | None = None, sinks=None):
     """Dispatch: Pallas kernel on TPU, gather reference elsewhere (the
     interpreted kernel is for tests).  Same signature/shape/raggedness
     contract as :func:`paged_attention_reference`, plus ``layer``: None
     for one layer's (num_blocks, BS, H*hd) pool slices, ``i`` for the
     stacked (L, num_blocks, BS, H*hd) pool read in place at layer i.
     The kernel reads the pools where they are, in the layout they have:
-    nothing pool-sized is padded, sliced or copied by this function."""
+    nothing pool-sized is padded, sliced or copied by this function.
+    Where a row's scratch and blocks would not fit VMEM (:func:`query_pieces`:
+    64 query heads of 192 at a chunk of 512) the kernel takes the row in
+    pieces, each a kernel row of its own over the same table, as
+    :func:`latent_attention` does."""
     backend = jax.default_backend()
     if use_pallas is None:
         use_pallas = backend == "tpu"
@@ -1522,17 +1745,23 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens=None, *,
         return paged_attention_reference(
             q, _layer_of(k_pool, layer), _layer_of(v_pool, layer),
             block_tables, context_lens,
-            start_pos=start_pos, n_valid=n_valid, window=window,
+            start_pos=start_pos, n_valid=n_valid, window=window, sinks=sinks,
         )
     B, C, H, hd = q.shape
     _require_positive_context(C, context_lens, start_pos, n_valid)
     c0, cl_last = _query_context(C, context_lens, start_pos, n_valid)
     kk, vv, li = _stacked(k_pool, v_pool, layer)
-    return _paged_ragged(
-        q, kk, vv, li,
-        jnp.asarray(block_tables, jnp.int32),
+    tables = jnp.asarray(block_tables, jnp.int32)
+    n = query_pieces(C, H, hd, kk.shape[-1], q.dtype,
+                     vv.shape[-1] * hd // kk.shape[-1])
+    if n > 1:
+        q, tables, c0, cl_last = _row_pieces(q, tables, c0, cl_last, n)
+    out = _paged_ragged(
+        q, kk, vv, li, tables,
         c0.astype(jnp.int32), cl_last.astype(jnp.int32),
+        *(() if sinks is None else (sinks,)),
         d_true=hd,
         interpret=(backend != "tpu") if interpret is None else interpret,
         **({} if window is None else {"window": int(window)}),
     )
+    return out.reshape(B, C, H, -1) if n > 1 else out

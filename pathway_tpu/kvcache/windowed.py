@@ -5,9 +5,13 @@ A block family that mixes the two (models/afmoe.py) keeps the full
 layers' K/V in the paged :class:`BlockPool` as it is - its layer axis
 counts the full layers only, a sequence's ``block_ids`` grow with the
 context - and the window layers' K/V in a second pool pair beside it,
-``(window layers, window blocks, block_size, n_kv_heads * head_dim)``, with
-a free list of its own.  Both tables are indexed by position
-(``table[p // block_size]``), so the kernels index them alike; a window
+``(window layers, window blocks, block_size, window_heads * head_dim)``
+(and ``window_heads * v_head_dim`` for the values), with a free list of its
+own.  The window pool's geometry is its own: ``window_heads`` K/V heads
+where the full pool has ``n_heads`` (None: as many), each pool billed at its
+own width (``pool_part_bytes``: the four arrays by name).  Both tables
+are indexed by position (``table[p // block_size]``), so the kernels index
+them alike; a window
 table's entries wholly behind the window point at the null block once
 their block has gone back to the free list.
 
@@ -64,7 +68,8 @@ class WindowedCache(ExpertCounts, BlockPool):
     supports_prefix = False
 
     def __init__(self, *, window: int, window_layers: int, round_tokens: int,
-                 max_seqs: int, **pool_kwargs):
+                 max_seqs: int, window_heads: int | None = None,
+                 **pool_kwargs):
         if window < 1 or window_layers < 1 or pool_kwargs["n_layers"] < 1:
             raise ValueError(
                 "a windowed cache holds at least one full and one window "
@@ -76,12 +81,16 @@ class WindowedCache(ExpertCounts, BlockPool):
         bs = int(pool_kwargs["block_size"])
         self.window_blocks = window_pool_blocks(window, round_tokens, bs,
                                                 max_seqs)
-        shape = (int(window_layers), self.window_blocks, bs,
-                 int(pool_kwargs["n_heads"]) * int(pool_kwargs["head_dim"]))
+        heads = int(pool_kwargs["n_heads"] if window_heads is None
+                    else window_heads)
+        hd = int(pool_kwargs["head_dim"])
+        shape = (int(window_layers), self.window_blocks, bs)
         dtype = pool_kwargs.get("dtype", jnp.float32)
         # before the pool registers its stats: per_shard_bytes reads them
-        self.kw = jnp.zeros(shape, dtype)
-        self.vw = jnp.zeros(shape, dtype)
+        self.kw = jnp.zeros(shape + (heads * hd,), dtype)
+        self.vw = jnp.zeros(
+            shape + (heads * int(pool_kwargs.get("v_head_dim") or hd),),
+            dtype)
         self._wfree: list[int] = list(range(self.window_blocks - 1, 0, -1))
         self._wtable: dict[int, list[int]] = {}  # by position // block_size
         self._wnext: dict[int, int] = {}   # first position yet to compute
@@ -91,11 +100,20 @@ class WindowedCache(ExpertCounts, BlockPool):
         self.stats.window_blocks_total = self.window_blocks - 1
         self.stats._window_blocks_in_use_fn = lambda: (
             0 if wref() is None else wref().window_blocks_in_use)
+        self.stats.pool_part_bytes = self.pool_part_bytes
 
     # -- capacity ----------------------------------------------------------
     @property
     def window_bytes(self) -> int:
-        return 2 * int(self.kw.size) * self.kw.dtype.itemsize
+        return (int(self.kw.size) + int(self.vw.size)) \
+            * self.kw.dtype.itemsize
+
+    @property
+    def pool_part_bytes(self) -> dict:
+        """The four pool arrays' bytes, each at its own width."""
+        return {part: int(a.size) * a.dtype.itemsize for part, a in (
+            ("full_k", self.k), ("full_v", self.v),
+            ("window_k", self.kw), ("window_v", self.vw))}
 
     @property
     def per_shard_bytes(self) -> int:

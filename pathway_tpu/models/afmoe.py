@@ -258,66 +258,81 @@ def _forward(params: dict, cfg: AfmoeConfig, k_pool, v_pool, kw_pool, vw_pool,
     return logits, k_pool, v_pool, kw_pool, vw_pool, counts
 
 
-def windowed_mixed_step(params: dict, cfg: AfmoeConfig, k_pool, v_pool,
-                        kw_pool, vw_pool, tokens, positions, row_tables,
-                        row_start, row_nvalid, row_token_idx, tok_row,
-                        tok_col, slot_blocks, slot_offsets, logit_idx,
-                        win_tables, *, attn: str = "reference"):
-    """The ragged fused step (decode rows and prompt chunks on one packed
-    stream) for this family.  A packed token is real where its row's run
-    holds it: padding tokens point at row 0, column 0, which is another
-    token's place."""
-    T = tokens.shape[0]
-    valid = row_token_idx[tok_row, tok_col] == jnp.arange(T, dtype=jnp.int32)
-    return _forward(
-        params, cfg, k_pool, v_pool, kw_pool, vw_pool, tokens, positions,
-        row_tables, row_start, row_nvalid, row_token_idx, tok_row, tok_col,
-        slot_blocks, slot_offsets, logit_idx, win_tables, valid, attn=attn,
-        decode=False)
+def windowed_steps(forward, experts_counted):
+    """The three step programs of the windowed contract over a family's
+    ``forward`` (the argument list of :func:`_forward`; called by name at
+    trace time) whose counter vector counts ``experts_counted(cfg)`` experts:
+    ``(windowed_mixed_step, windowed_decode_step, windowed_chained_decode)``,
+    what :class:`pathway_tpu.models.families.AfmoeFamily` and its subclasses
+    make their programs of."""
+
+    def windowed_mixed_step(params: dict, cfg, k_pool, v_pool, kw_pool,
+                            vw_pool, tokens, positions, row_tables, row_start,
+                            row_nvalid, row_token_idx, tok_row, tok_col,
+                            slot_blocks, slot_offsets, logit_idx, win_tables,
+                            *, attn: str = "reference"):
+        """The ragged fused step (decode rows and prompt chunks on one
+        packed stream) for this family.  A packed token is real where its
+        row's run holds it: padding tokens point at row 0, column 0, which
+        is another token's place."""
+        T = tokens.shape[0]
+        valid = row_token_idx[tok_row, tok_col] \
+            == jnp.arange(T, dtype=jnp.int32)
+        return forward(
+            params, cfg, k_pool, v_pool, kw_pool, vw_pool, tokens, positions,
+            row_tables, row_start, row_nvalid, row_token_idx, tok_row,
+            tok_col, slot_blocks, slot_offsets, logit_idx, win_tables, valid,
+            attn=attn, decode=False)
+
+    def windowed_decode_step(params: dict, cfg, k_pool, v_pool, kw_pool,
+                             vw_pool, token, positions, block_tables,
+                             slot_blocks, slot_offsets, win_tables, *,
+                             attn: str = "reference"):
+        """One token a row.  An idle row has the null block first in both
+        its tables."""
+        B = token.shape[0]
+        rows = jnp.arange(B, dtype=jnp.int32)
+        return forward(
+            params, cfg, k_pool, v_pool, kw_pool, vw_pool, token, positions,
+            block_tables, positions, jnp.ones((B,), jnp.int32), rows[:, None],
+            rows, jnp.zeros((B,), jnp.int32), slot_blocks, slot_offsets, rows,
+            win_tables, block_tables[:, 0] > 0, attn=attn, decode=True)
+
+    def windowed_chained_decode(params: dict, cfg, k_pool, v_pool, kw_pool,
+                                vw_pool, token, positions, block_tables,
+                                slot_blocks, slot_offsets, win_tables, *,
+                                attn: str = "reference"):
+        """K greedy decode steps in one program (``slot_blocks`` /
+        ``slot_offsets`` (B, K), the host's pre-extended slots; the window
+        tables hold the chain's blocks already and none is freed inside
+        it), step t's ids feeding step t + 1.  Returns ``(ids (B, K),
+        k_pool, v_pool, kw_pool, vw_pool, counts)``."""
+        from ..ops.moe import COUNTER_TAIL
+
+        K = slot_blocks.shape[1]
+        maxp = cfg.max_len - 1
+
+        def body(carry, xs):
+            tok, kp, vp, kwp, vwp, cnt = carry
+            sb, so, t = xs
+            logits, kp, vp, kwp, vwp, n_tok = windowed_decode_step(
+                params, cfg, kp, vp, kwp, vwp, tok,
+                jnp.minimum(positions + t, maxp), block_tables, sb, so,
+                win_tables, attn=attn)
+            ids = greedy_ids(logits)
+            return (ids, kp, vp, kwp, vwp, cnt + n_tok), ids
+
+        init = (token.astype(jnp.int32), k_pool, v_pool, kw_pool, vw_pool,
+                jnp.zeros((experts_counted(cfg) + len(COUNTER_TAIL),),
+                          jnp.int32))
+        (_last, k_pool, v_pool, kw_pool, vw_pool, counts), ids = jax.lax.scan(
+            body, init, (slot_blocks.T, slot_offsets.T,
+                         jnp.arange(K, dtype=jnp.int32)))
+        return ids.T, k_pool, v_pool, kw_pool, vw_pool, counts
+
+    return windowed_mixed_step, windowed_decode_step, windowed_chained_decode
 
 
-def windowed_decode_step(params: dict, cfg: AfmoeConfig, k_pool, v_pool,
-                         kw_pool, vw_pool, token, positions, block_tables,
-                         slot_blocks, slot_offsets, win_tables, *,
-                         attn: str = "reference"):
-    """One token a row.  An idle row has the null block first in both its
-    tables."""
-    B = token.shape[0]
-    rows = jnp.arange(B, dtype=jnp.int32)
-    return _forward(
-        params, cfg, k_pool, v_pool, kw_pool, vw_pool, token, positions,
-        block_tables, positions, jnp.ones((B,), jnp.int32), rows[:, None],
-        rows, jnp.zeros((B,), jnp.int32), slot_blocks, slot_offsets, rows,
-        win_tables, block_tables[:, 0] > 0, attn=attn, decode=True)
-
-
-def windowed_chained_decode(params: dict, cfg: AfmoeConfig, k_pool, v_pool,
-                            kw_pool, vw_pool, token, positions, block_tables,
-                            slot_blocks, slot_offsets, win_tables, *,
-                            attn: str = "reference"):
-    """K greedy decode steps in one program (``slot_blocks`` /
-    ``slot_offsets`` (B, K), the host's pre-extended slots; the window
-    tables hold the chain's blocks already and none is freed inside it),
-    step t's ids feeding step t + 1.  Returns ``(ids (B, K), k_pool,
-    v_pool, kw_pool, vw_pool, counts)``."""
-    from ..ops.moe import COUNTER_TAIL
-
-    K = slot_blocks.shape[1]
-    maxp = cfg.max_len - 1
-
-    def body(carry, xs):
-        tok, kp, vp, kwp, vwp, cnt = carry
-        sb, so, t = xs
-        logits, kp, vp, kwp, vwp, n_tok = windowed_decode_step(
-            params, cfg, kp, vp, kwp, vwp, tok,
-            jnp.minimum(positions + t, maxp), block_tables, sb, so,
-            win_tables, attn=attn)
-        ids = greedy_ids(logits)
-        return (ids, kp, vp, kwp, vwp, cnt + n_tok), ids
-
-    init = (token.astype(jnp.int32), k_pool, v_pool, kw_pool, vw_pool,
-            jnp.zeros((cfg.n_experts + len(COUNTER_TAIL),), jnp.int32))
-    (_last, k_pool, v_pool, kw_pool, vw_pool, counts), ids = jax.lax.scan(
-        body, init, (slot_blocks.T, slot_offsets.T,
-                     jnp.arange(K, dtype=jnp.int32)))
-    return ids.T, k_pool, v_pool, kw_pool, vw_pool, counts
+windowed_mixed_step, windowed_decode_step, windowed_chained_decode = \
+    windowed_steps(lambda *a, **kw: _forward(*a, **kw),
+                   lambda cfg: cfg.n_experts)

@@ -27,7 +27,8 @@ and gives the engine:
 
 A family is the only place that names a model's programs: the engine
 imports none of models/decoder.py, models/lfm2.py, models/afmoe.py,
-models/kimi_linear.py, models/qwen3_next.py.  The function
+models/kimi_linear.py, models/qwen3_next.py, models/mimo_v2_flash.py.  The
+function
 names ``_step_fn`` / ``_mixed_fn`` / ``_chained_fn`` are the
 device trace's (``jit__mixed_fn`` on ``XLA Modules``): the benchmark's
 readers find the programs by them, for every family alike.
@@ -230,10 +231,17 @@ class AfmoeFamily:
     tensor_parallel = False
 
     @staticmethod
-    def plan(cfg, params, *, tp: int, quantize):
-        from .afmoe import plan_params
+    def _model():
+        """The module that holds the family's three windowed step programs
+        and ``plan_params`` (a second family of window and full layers
+        names its own)."""
+        from . import afmoe
 
-        return plan_params(cfg, params)
+        return afmoe
+
+    @classmethod
+    def plan(cls, cfg, params, *, tp: int, quantize):
+        return cls._model().plan_params(cfg, params)
 
     @staticmethod
     def cache_kwargs(cfg, max_batch_size: int, round_tokens: int) -> dict:
@@ -242,9 +250,9 @@ class AfmoeFamily:
                 "window_layers": len(cfg.window_layers),
                 "round_tokens": round_tokens, "max_seqs": max_batch_size}
 
-    @staticmethod
-    def unsupported(*, tp, quantize, speculative, session_store) -> None:
-        _refuse("afmoe", (
+    @classmethod
+    def unsupported(cls, *, tp, quantize, speculative, session_store) -> None:
+        _refuse(cls.name, (
             ("tensor parallelism (tp > 1): the window pool and the expert "
              "weights have no sharded layout", tp is not None and tp > 1),
             (f"quantize={quantize!r}: no quantized plan of the expert "
@@ -256,12 +264,12 @@ class AfmoeFamily:
              session_store is not None),
         ))
 
-    @staticmethod
-    def programs(cfg, attn: str, mesh, sampled: bool = False) -> dict:
+    @classmethod
+    def programs(cls, cfg, attn: str, mesh, sampled: bool = False) -> dict:
         if sampled:
-            raise ValueError("the afmoe block family decodes greedily: it "
-                             "has no sampled step programs")
-        from . import afmoe as m
+            raise ValueError(f"the {cls.name} block family decodes "
+                             "greedily: it has no sampled step programs")
+        m = cls._model()
 
         def _step_fn(p, k_pool, v_pool, kw_pool, vw_pool, token, positions,
                      bt, sb, so, wt):
@@ -464,8 +472,33 @@ class Qwen3NextFamily:
                 "chained": (_chained_fn, donated)}
 
 
+class MimoV2FlashFamily(AfmoeFamily):
+    """models/mimo_v2_flash.py: sliding-window layers (a learned sink a
+    query head, K/V heads of their own number) and full attention layers,
+    keys wider than values, SwiGLU and routed experts (all of them, or the
+    share a chip of an expert-parallel deployment holds) with no shared
+    one, on the windowed cache, whose two pool pairs take their geometry
+    from here.  The step contract, the refusals and what the cache kind
+    rules out are :class:`AfmoeFamily`'s."""
+
+    name = "mimo_v2_flash"
+
+    @staticmethod
+    def _model():
+        from . import mimo_v2_flash
+
+        return mimo_v2_flash
+
+    @staticmethod
+    def cache_kwargs(cfg, max_batch_size: int, round_tokens: int) -> dict:
+        return {**AfmoeFamily.cache_kwargs(cfg, max_batch_size, round_tokens),
+                "v_head_dim": cfg.v_head_dim,
+                "window_heads": cfg.window_kv_heads}
+
+
 _FAMILIES = {f.name: f for f in (DecoderFamily, Lfm2Family, AfmoeFamily,
-                                 KimiLinearFamily, Qwen3NextFamily)}
+                                 KimiLinearFamily, Qwen3NextFamily,
+                                 MimoV2FlashFamily)}
 
 
 def step_family(cfg):

@@ -439,6 +439,120 @@ def config_from_qwen3_next(hf_config, *, max_len: int | None = None,
     )
 
 
+def config_from_mimo_v2_flash(hf_config, *, max_len: int | None = None,
+                              dtype="auto", router_experts: int | None = None,
+                              first_expert: int = 0):
+    """``mimo_v2_flash`` config (XiaomiMiMo/MiMo-V2-Flash's ``config.json``
+    keys) -> :class:`~pathway_tpu.models.mimo_v2_flash.MimoV2FlashConfig`.
+    Layer ``i`` is full attention where ``hybrid_layer_pattern[i] == 0`` and
+    sliding-window where it is 1 (``num_key_value_heads`` K/V heads on the
+    former, ``swa_num_key_value_heads`` on the latter, ``rope_theta`` /
+    ``swa_rope_theta``); ``int(head_dim x partial_rotary_factor)`` leading
+    values of a head take the rotary; the layers where ``moe_layer_freq`` is
+    0 (leading ones only) have the dense feed-forward.  ``max_len`` caps the
+    served context below ``max_position_embeddings``.
+
+    ``router_experts``: the router's published width where
+    ``n_routed_experts`` counts the experts this share HOLDS (one chip of an
+    expert-parallel deployment: experts ``first_expert .. first_expert +
+    n_routed_experts``).
+
+    What the keys can say and this family has not written down is refused:
+    rotary scaling, grouped routing (``n_group`` / ``topk_group`` other than
+    1), another ``topk_method`` or ``scoring_func``, shared experts, an
+    attention bias, a sink on the full layers or none on the sliding ones,
+    sliding layers of other head counts or widths than the full ones' query
+    side, unnormalised router weights, a tied head, another activation than
+    SiLU, a dense layer after an expert layer, multi-token prediction
+    layers."""
+    from .afmoe import FULL, SLIDING
+    from .mimo_v2_flash import MimoV2FlashConfig
+
+    def get(name, default=None):
+        return getattr(hf_config, name, default)
+
+    if get("model_type") != "mimo_v2_flash":
+        raise ValueError(
+            f"expected a mimo_v2_flash config, got model_type="
+            f"{get('model_type')!r}")
+    n_layers = int(hf_config.num_hidden_layers)
+    pattern = [int(p) for p in hf_config.hybrid_layer_pattern][:n_layers]
+    moe = [int(m) for m in hf_config.moe_layer_freq][:n_layers]
+    n_dense = moe.index(1) if 1 in moe else len(moe)
+    hd = int(hf_config.head_dim)
+    scaling = get("rope_scaling")
+    refused = [what for what, bad in (
+        ("hybrid_layer_pattern / moe_layer_freq shorter than "
+         "num_hidden_layers", len(pattern) < n_layers or len(moe) < n_layers),
+        ("rope_scaling", scaling is not None and (scaling.get(
+            "rope_type", scaling.get("type")) != "default")),
+        ("n_group / topk_group other than 1",
+         get("n_group", 1) != 1 or get("topk_group", 1) != 1),
+        ("topk_method other than noaux_tc",
+         get("topk_method", "noaux_tc") != "noaux_tc"),
+        ("scoring_func other than sigmoid",
+         get("scoring_func", "sigmoid") != "sigmoid"),
+        ("n_shared_experts", bool(get("n_shared_experts"))),
+        ("attention_bias", bool(get("attention_bias", False))),
+        ("add_full_attention_sink_bias",
+         bool(get("add_full_attention_sink_bias", False))),
+        ("add_swa_attention_sink_bias false",
+         not get("add_swa_attention_sink_bias", True)),
+        ("swa_num_attention_heads / swa_head_dim / swa_v_head_dim other "
+         "than the full layers'",
+         get("swa_num_attention_heads", hf_config.num_attention_heads)
+         != hf_config.num_attention_heads
+         or get("swa_head_dim", hd) != hd
+         or get("swa_v_head_dim", hf_config.v_head_dim)
+         != hf_config.v_head_dim),
+        ("sliding_window_size other than sliding_window",
+         get("sliding_window_size", hf_config.sliding_window)
+         != hf_config.sliding_window),
+        ("norm_topk_prob false", not get("norm_topk_prob", True)),
+        ("tie_word_embeddings", bool(get("tie_word_embeddings", False))),
+        ("hidden_act other than silu", get("hidden_act", "silu") != "silu"),
+        ("a dense layer after an expert layer (moe_layer_freq)",
+         0 in moe[n_dense:]),
+        ("multi-token prediction layers (num_nextn_predict_layers / "
+         "mtp_num_hidden_layers)",
+         bool(get("num_nextn_predict_layers", 0)
+              or get("mtp_num_hidden_layers", 0))),
+    ) if bad]
+    if refused:
+        raise ValueError(
+            "mimo_v2_flash: not written down here: " + "; ".join(refused))
+    positions = int(hf_config.max_position_embeddings)
+    held = int(hf_config.n_routed_experts)
+    scale = get("routed_scaling_factor")
+    return MimoV2FlashConfig(
+        vocab_size=hf_config.vocab_size,
+        d_model=hf_config.hidden_size,
+        n_heads=hf_config.num_attention_heads,
+        n_kv_heads=hf_config.num_key_value_heads,
+        window_kv_heads=int(get("swa_num_key_value_heads",
+                                hf_config.num_key_value_heads)),
+        head_dim=hd,
+        v_head_dim=int(hf_config.v_head_dim),
+        rotary_dim=int(hd * float(get("partial_rotary_factor", 1.0))),
+        d_ff=hf_config.intermediate_size,
+        d_ff_expert=hf_config.moe_intermediate_size,
+        n_experts=held if router_experts is None else int(router_experts),
+        n_held_experts=None if router_experts is None else held,
+        first_expert=int(first_expert),
+        top_k=hf_config.num_experts_per_tok,
+        n_dense_layers=n_dense,
+        layer_types=tuple(SLIDING if p else FULL for p in pattern),
+        sliding_window=int(hf_config.sliding_window),
+        rope_theta=float(get("rope_theta", 5e6)),
+        window_rope_theta=float(get("swa_rope_theta", 1e4)),
+        value_scale=float(get("attention_value_scale", 1.0)),
+        route_scale=1.0 if scale is None else float(scale),
+        norm_eps=float(get("layernorm_epsilon", 1e-5)),
+        max_len=min(positions, int(max_len)) if max_len else positions,
+        dtype=dtype,
+    )
+
+
 def params_from_lfm2_state_dict(state: dict[str, Any], cfg) -> dict:
     """Map a (torch) LFM2-family state dict onto
     :mod:`pathway_tpu.models.lfm2`'s parameter pytree, in ``cfg``'s dtype.

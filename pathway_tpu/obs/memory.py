@@ -114,7 +114,14 @@ def kv_pool_bytes(cfg, *, num_blocks: int, block_size: int, tp: int,
             * cfg.latent_lanes * itemsize
     layers, kv_heads, hd = _kv_geometry(cfg)
     heads = max(kv_heads // max(tp, 1), 1)
-    return 2 * layers * num_blocks * block_size * heads * hd * itemsize
+    return layers * num_blocks * block_size * heads * _kv_lanes(cfg, hd) \
+        * itemsize
+
+
+def _kv_lanes(cfg, hd: int) -> int:
+    """A K row's and a V row's lanes a head, together: twice ``head_dim``,
+    or a V head of the width the family gives its cache beside it."""
+    return hd + (_arenas(cfg, 0).get("v_head_dim") or hd)
 
 
 def _kv_geometry(cfg) -> tuple:
@@ -174,9 +181,12 @@ def window_pool_bytes(cfg, *, max_batch_size: int, block_size: int,
     from ..kvcache.windowed import window_pool_blocks
 
     _l, kv_heads, hd = _kv_geometry(cfg)
+    # a window pool of its own K/V heads, each pool at its own width
+    kv_heads = _arenas(cfg, max_batch_size).get("window_heads") or kv_heads
     blocks = window_pool_blocks(cfg.sliding_window, round_tokens, block_size,
                                 max_batch_size)
-    return 2 * layers * blocks * block_size * kv_heads * hd * itemsize
+    return layers * blocks * block_size * kv_heads * _kv_lanes(cfg, hd) \
+        * itemsize
 
 
 def _temp_bytes(cfg, *, num_blocks: int, block_size: int,
@@ -209,8 +219,8 @@ def _temp_bytes(cfg, *, num_blocks: int, block_size: int,
     # packed stream residuals (a family of expert layers only: no d_ff)
     acts = 6 * T * max(d, getattr(cfg, "d_ff", 0)) * itemsize
     # the rows' queries gathered (B, C, H, hd) for the ragged kernel, its
-    # output the same, each with a folded copy
-    acts += 4 * B * C * heads * hd * itemsize
+    # output the same (at a V head's width), each with a folded copy
+    acts += 2 * B * C * heads * _kv_lanes(cfg, hd) * itemsize
     from ..models.families import step_family
 
     scan = getattr(step_family(cfg), "scan_items", None)

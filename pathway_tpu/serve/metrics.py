@@ -212,7 +212,13 @@ class KVCacheStats:
       behind a window or with their sequence; freed/allocated = the share
       that went back), ``pathway_kv_window_keys_total{pool}`` /
       ``pathway_kv_window_ctx_keys_total{pool}`` counters (keys a window
-      layer's rows attended, over what they would attend with no window)
+      layer's rows attended, over what they would attend with no window),
+      ``pathway_kv_window_band_pairs_total{pool}`` /
+      ``pathway_kv_window_span_pairs_total{pool}`` counters (a mixed
+      round's query-key pairs in a window layer: seen, and computed by the
+      kernel's live tiles and spans), ``pathway_kv_pool_bytes{pool,part}``
+      gauges (``full_k`` / ``full_v`` / ``window_k`` / ``window_v``: the
+      four pool arrays, each at its own width)
     - ``pathway_kv_moe_routed_pairs_total{pool}`` counter ((token, expert)
       pairs the step programs routed, counted on the device) and
       ``pathway_kv_moe_tokens_per_expert_total{pool,expert}`` (the same,
@@ -309,6 +315,13 @@ class KVCacheStats:
         self.kv_window_blocks_freed = 0
         self.kv_window_keys = 0
         self.kv_window_ctx_keys = 0
+        # where the window is narrower than a round: the query-key pairs a
+        # window layer's rows see, and those the kernel's live tiles and
+        # spans compute for them (paged_attention.window_pairs)
+        self.kv_window_band_pairs = 0
+        self.kv_window_span_pairs = 0
+        # the four pool arrays' bytes by part (WindowedCache.pool_part_bytes)
+        self.pool_part_bytes: dict = {}
 
     @property
     def conv_slots_in_use(self) -> int:
@@ -341,6 +354,13 @@ class KVCacheStats:
         with self._lock:
             self.kv_window_keys += keys
             self.kv_window_ctx_keys += ctx_keys
+
+    def record_window_pairs(self, band: int, span: int) -> None:
+        """One mixed round's query-key pairs in a sliding-window layer: seen
+        (``band``) and computed (``span``)."""
+        with self._lock:
+            self.kv_window_band_pairs += band
+            self.kv_window_span_pairs += span
 
     def record_moe(self, counts, tail=()) -> None:
         """One step program's device counters: tokens per held expert,
@@ -586,6 +606,9 @@ class KVCacheStats:
                 "kv_window_blocks_freed": self.kv_window_blocks_freed,
                 "kv_window_keys": self.kv_window_keys,
                 "kv_window_ctx_keys": self.kv_window_ctx_keys,
+                "kv_window_band_pairs": self.kv_window_band_pairs,
+                "kv_window_span_pairs": self.kv_window_span_pairs,
+                "pool_part_bytes": dict(self.pool_part_bytes),
             }
 
 
@@ -1042,6 +1065,9 @@ def _render_kv_lines() -> list[str]:
         "# TYPE pathway_kv_window_blocks_freed_total counter",
         "# TYPE pathway_kv_window_keys_total counter",
         "# TYPE pathway_kv_window_ctx_keys_total counter",
+        "# TYPE pathway_kv_window_band_pairs_total counter",
+        "# TYPE pathway_kv_window_span_pairs_total counter",
+        "# TYPE pathway_kv_pool_bytes gauge",
     ]
     for s in stats:
         snap = s.snapshot()
@@ -1223,9 +1249,13 @@ def _render_kv_lines() -> list[str]:
             for key in ("window_blocks_in_use", "window_blocks_total"):
                 lines.append(f"pathway_kv_{key}{{{lbl}}} {snap[key]}")
             for key in ("window_blocks_allocated", "window_blocks_freed",
-                        "window_keys", "window_ctx_keys"):
+                        "window_keys", "window_ctx_keys", "window_band_pairs",
+                        "window_span_pairs"):
                 lines.append(f"pathway_kv_{key}_total{{{lbl}}} "
                              f"{snap['kv_' + key]}")
+            for part, n in snap["pool_part_bytes"].items():
+                lines.append(
+                    f'pathway_kv_pool_bytes{{{lbl},part="{part}"}} {n}')
         if snap["conv_slots_total"] or snap["window_blocks_total"]:
             # the caches of the families with expert layers
             for key in ("moe_routed_pairs", "moe_live_tiles", "moe_row_tiles",

@@ -301,3 +301,138 @@ def test_config_from_qwen3_next_refuses_another_model_type():
     pub = dict(_qwen3_next_row(), model_type="qwen3_moe")
     with pytest.raises(ValueError, match="expected a qwen3_next config"):
         hf_import.config_from_qwen3_next(types.SimpleNamespace(**pub))
+
+
+# -- mimo_v2_flash (PR 40) -------------------------------------------------------
+
+
+def _mimo_row():
+    import json
+
+    path = "/opt/skills/guides/model-configs/architectures.jsonl"
+    try:
+        with open(path) as f:
+            rows = [json.loads(line) for line in f]
+    except OSError:
+        pytest.skip("the catalog is not here")
+    return next(r for r in rows if r["name"] == "MiMo-V2-Flash")["config"]
+
+
+def test_config_from_mimo_v2_flash_on_the_catalog_row():
+    import types
+
+    from pathway_tpu.models import hf_import
+    from pathway_tpu.models.afmoe import FULL, SLIDING
+
+    cfg = hf_import.config_from_mimo_v2_flash(
+        types.SimpleNamespace(**_mimo_row()), dtype="bfloat16")
+    assert cfg.family == "mimo_v2_flash" and cfg.n_layers == 48
+    assert cfg.full_layers == (0, 5, 11, 17, 23, 29, 35, 41, 47)
+    assert len(cfg.window_layers) == 39
+    assert cfg.layer_types[:6] == (FULL,) + (SLIDING,) * 4 + (FULL,)
+    assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.window_kv_heads,
+            cfg.head_dim, cfg.v_head_dim, cfg.rotary_dim) \
+        == (4096, 64, 4, 8, 192, 128, 64)
+    assert (cfg.n_experts, cfg.held_experts, cfg.share, cfg.top_k,
+            cfg.n_dense_layers) == (256, 256, None, 8, 1)
+    assert (cfg.d_ff, cfg.d_ff_expert, cfg.sliding_window) \
+        == (16384, 2048, 128)
+    assert (cfg.rope_theta, cfg.window_rope_theta) == (5e6, 1e4)
+    assert (cfg.value_scale, cfg.route_scale, cfg.norm_eps) \
+        == (0.707, 1.0, 1e-5)
+    assert cfg.max_len == 262144 and cfg.vocab_size == 152576
+    # 308.8 B parameters: 617.6 GB of bf16
+    assert round(cfg.param_count() / 1e9, 1) == 308.8
+
+
+def test_config_from_mimo_v2_flash_takes_a_share_and_a_cut():
+    """The cell's cut: published layers 0-10, 16 of the 256 experts held, a
+    served context of 8,192; the issue's arithmetic (6,516M parameters =
+    13.03 GB of bf16)."""
+    import types
+
+    from pathway_tpu.models import hf_import
+
+    row = _mimo_row()
+    pub = dict(row, num_hidden_layers=11, n_routed_experts=16,
+               hybrid_layer_pattern=row["hybrid_layer_pattern"][:11],
+               moe_layer_freq=row["moe_layer_freq"][:11])
+    cfg = hf_import.config_from_mimo_v2_flash(
+        types.SimpleNamespace(**pub), max_len=8192, router_experts=256,
+        first_expert=0)
+    assert cfg.full_layers == (0, 5) and len(cfg.window_layers) == 9
+    assert (cfg.n_experts, cfg.held_experts, cfg.share) == (256, 16, 0)
+    assert cfg.max_len == 8192
+    assert round(cfg.param_count() / 1e6) == 6516
+    other = hf_import.config_from_mimo_v2_flash(
+        types.SimpleNamespace(**pub), router_experts=256, first_expert=240)
+    assert other.share == 240
+    with pytest.raises(ValueError, match="not a share"):
+        hf_import.config_from_mimo_v2_flash(
+            types.SimpleNamespace(**pub), router_experts=256,
+            first_expert=241)
+
+
+@pytest.mark.parametrize("key,value,named", [
+    ("rope_scaling", {"type": "yarn", "factor": 4.0}, "rope_scaling"),
+    ("n_group", 8, "n_group"),
+    ("topk_group", 4, "n_group"),
+    ("n_shared_experts", 1, "n_shared_experts"),
+    ("attention_bias", True, "attention_bias"),
+    ("add_full_attention_sink_bias", True, "add_full_attention_sink_bias"),
+    ("add_swa_attention_sink_bias", False, "add_swa_attention_sink_bias"),
+    ("swa_head_dim", 128, "swa_num_attention_heads"),
+    ("norm_topk_prob", False, "norm_topk_prob"),
+    ("tie_word_embeddings", True, "tie_word_embeddings"),
+    ("hidden_act", "gelu", "hidden_act"),
+    ("scoring_func", "softmax", "scoring_func"),
+    ("moe_layer_freq", [0, 1, 0] + [1] * 45, "dense layer after"),
+    ("num_nextn_predict_layers", 3, "multi-token prediction"),
+])
+def test_config_from_mimo_v2_flash_refuses_what_is_not_written_down(
+        key, value, named):
+    import types
+
+    from pathway_tpu.models import hf_import
+
+    pub = dict(_mimo_row(), **{key: value})
+    with pytest.raises(ValueError, match="not written down") as e:
+        hf_import.config_from_mimo_v2_flash(types.SimpleNamespace(**pub))
+    assert named in str(e.value)
+
+
+def test_config_from_mimo_v2_flash_refuses_another_model_type():
+    import types
+
+    from pathway_tpu.models import hf_import
+
+    pub = dict(_mimo_row(), model_type="mimo_v2")
+    with pytest.raises(ValueError, match="expected a mimo_v2_flash"):
+        hf_import.config_from_mimo_v2_flash(types.SimpleNamespace(**pub))
+
+
+def test_the_cells_configuration_keeps_the_catalogs_widths():
+    """Every number of the catalog row's ``config`` stands in
+    ``mimo-v2-flash-serve.json`` under the same key, but the four keys
+    ``reduced`` names."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mimo-v2-flash-serve.json")) as f:
+        ours = json.load(f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry = next(c for c in json.load(f)["configs"]
+                     if c["name"] == "mimo-v2-flash-serve")
+    reduced = set(entry["reduced"])
+    assert reduced == {"num_hidden_layers", "hybrid_layer_pattern",
+                       "moe_layer_freq", "n_routed_experts"}
+    for key, value in _mimo_row().items():
+        if key in reduced:
+            assert ours[key] != value
+        else:
+            assert ours[key] == value, key
+    assert ours["hybrid_layer_pattern"] == _mimo_row()[
+        "hybrid_layer_pattern"][:11]
+    assert ours["router_experts"] == 256 and len(ours["reduced"]) == 4

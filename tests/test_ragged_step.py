@@ -802,6 +802,120 @@ def test_decode_kernels_trace_to_the_parents_jaxpr(case):
         == _PARENT_DECODE_KERNELS[case]
 
 
+# What the MIXED step's kernels (a chunk's columns in tiles; a verify
+# width in one) and the K/V writer traced to at PR 40's parent, hashed as
+# above: the kernels learned a V head of its own width and the sinks
+# (PR 40), both absent from a call that gives neither.
+_PARENT_MIXED_KERNELS = {
+    "gpt2_256_ragged": "364d86c2946fb86b",
+    "lfm2_512_ragged": "51a95f48766c3884",
+    "trinity_256_ragged": "81a1b6d787799aaa",
+    "qwen3next_512_ragged": "7c6e913f55c649a6",
+    "verify_5_ragged": "a77c53f6148a33ad",
+    "gpt2_write": "a831d69314074ac7", "trinity_write": "1e5196ff916eb8e4",
+    "tp_shard_write": "7827ed98349a42c8",
+}
+
+
+@pytest.mark.parametrize("case", list(_PARENT_MIXED_KERNELS))
+def test_mixed_kernels_trace_to_the_parents_jaxpr(case):
+    """``sinks=None`` and a V pool as wide as the K pool: the ragged kernel
+    at the five configurations' chunk geometries and the writer trace to
+    the jaxprs they traced to before the two arguments existed."""
+    import hashlib
+    import re
+
+    from jax._src.interpreters import partial_eval as pe
+
+    pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
+
+    def S(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    B, BS, NB, bf = 4, 16, 20, jnp.bfloat16
+    name, kind = case.rsplit("_", 1)
+    if kind == "write":
+        D = {"gpt2": 1280, "trinity": 512, "tp_shard": 320}[name]
+        pool, rows = S((2, 41, BS, D), bf), S((37, D), bf)
+        fn, args, kw = pa._paged_write_fn, (
+            rows, rows, pool, pool, S((1,)), S((37,)), S((37,))), {}
+    else:
+        H, kv, hd, window, C = {
+            "gpt2_256": (20, 20, 64, None, 256),
+            "lfm2_512": (32, 8, 64, None, 512),
+            "trinity_256": (32, 4, 128, 2048, 256),
+            "qwen3next_512": (16, 2, 256, None, 512),
+            "verify_5": (32, 4, 128, None, 5)}[name]
+        pool = S((2, 41, BS, kv * hd), bf)
+        kw = {"d_true": hd, **({} if window is None else {"window": window})}
+        fn, args = pa._paged_ragged_fn, (
+            S((B, C, H, hd), bf), pool, pool, S((1,)), S((B, NB)), S((B,)),
+            S((B,)))
+    closed = jax.make_jaxpr(lambda *a: fn(*a, **kw))(*args)
+    (call,) = [e for e in closed.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    kernel = call.params["jaxpr"]
+    kernel, _ = pe.dce_jaxpr(kernel, [True] * len(kernel.outvars))
+    text = re.sub(r" at [^ ]*:\d+", "", str(kernel))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == _PARENT_MIXED_KERNELS[case]
+
+
+# The ragged kernel as ``paged_attention`` DISPATCHES it at each cell's own
+# chunk (the rule's 256 / 512; ``trinity``'s given 256 and ``qwen3next``'s
+# given 1,024), hashed at PR 40's parent with the kernel's output block: the
+# dispatch cuts a row whose scratch would not fit VMEM into pieces
+# (``query_pieces``), and none of these rows is cut - sixteen heads of 256 at
+# 1,024 columns are the largest that fit.
+_PARENT_DISPATCHED = {
+    "gpt2_256": ((20, 20, 64, None), "364d86c2946fb86b", (4, 256, 1280)),
+    "lfm2_512": ((32, 8, 64, None), "51a95f48766c3884", (4, 2048, 512)),
+    "trinity_256": ((32, 4, 128, 2048), "81a1b6d787799aaa", (4, 2048, 512)),
+    "qwen3next_1024": ((16, 2, 256, None), "b527ae2d5f83f6d3",
+                       (4, 8192, 512)),
+}
+
+
+def _pallas_calls(jaxpr):
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            yield e
+        for v in e.params.values():
+            for j in (v if isinstance(v, (list, tuple)) else [v]):
+                j = getattr(j, "jaxpr", j)
+                if hasattr(j, "eqns"):
+                    yield from _pallas_calls(j)
+
+
+@pytest.mark.parametrize("case", list(_PARENT_DISPATCHED))
+def test_dispatched_kernels_at_the_cells_chunks_are_the_parents(case):
+    import hashlib
+    import re
+
+    from jax._src.interpreters import partial_eval as pe
+
+    pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
+
+    def S(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    (H, kv, hd, window), parent, block = _PARENT_DISPATCHED[case]
+    B, BS, NB, bf, C = 4, 16, 20, jnp.bfloat16, int(case.rsplit("_", 1)[1])
+    assert pa.query_pieces(C, H, hd, kv * hd, bf) == 1
+    pool = S((2, 41, BS, kv * hd), bf)
+    kw = {} if window is None else {"window": window}
+    closed = jax.make_jaxpr(lambda q, k, v, bt, sp, nv: pa.paged_attention(
+        q, k, v, bt, start_pos=sp, n_valid=nv, layer=1, use_pallas=True,
+        interpret=True, **kw))(
+        S((B, C, H, hd), bf), pool, pool, S((B, NB)), S((B,)), S((B,)))
+    (call,) = _pallas_calls(closed.jaxpr)
+    assert call.outvars[0].aval.shape == block
+    kernel = call.params["jaxpr"]
+    kernel, _ = pe.dce_jaxpr(kernel, [True] * len(kernel.outvars))
+    text = re.sub(r" at [^ ]*:\d+", "", str(kernel))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == parent
+
+
 # -- the mixed step's K/V writer ---------------------------------------------
 
 # Streams as ``_build_mixed`` packs them, (block, offset) a token over
