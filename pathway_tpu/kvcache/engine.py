@@ -75,8 +75,8 @@ from .. import faults, obs
 from .backend import make_backend
 from .block_pool import BlockPool, PoolExhausted  # noqa: F401 - re-export
 from .packing import RoundLayout
-from .paged_attention import (query_tile_columns, span_blocks,
-                              window_pairs)
+from .paged_attention import (query_layout, query_tile_columns,
+                              span_blocks, window_pairs)
 from .prefix_cache import PrefixCache
 
 
@@ -627,30 +627,47 @@ class PagedDecodeEngine:
         # blocks of 16), for ``kv_key_lanes`` on ``pw.round.build``
         lanes = self.pool.k.shape[-1] // self.tp
         self._span_keys = bs * span_blocks(bs, self.max_blocks_per_seq, lanes)
-        # the query columns the live tiles of a mixed step's row cover, by
-        # its valid columns 0 .. chunk (the kernels' own tile rule, on a
-        # shard's query heads and pool lanes; a latent pool, which has no V,
-        # is attended in pieces), for ``kv_query_tile_cols``
+        # how a mixed step's ragged calls lay the rows out on the full pool
+        # (``query_layout``'s ``(P, N)``: N kernel rows of P query columns;
+        # a latent pool, which has no V, keeps its rows, in pieces of its
+        # own), and the query columns the live tiles of a row cover, by its
+        # valid columns 0 .. chunk (the kernels' own tile rule, on a shard's
+        # query heads and pool lanes), for ``kv_query_slots`` and
+        # ``kv_query_tile_cols``
+        B, C, H = self.max_batch_size, self.prefill_chunk, cfg.n_heads // self.tp
+        hd, hd_v = self._pool_kwargs["head_dim"], \
+            self._pool_kwargs.get("v_head_dim")
+        latent, dtype = self.pool.v is None, self._pool_kwargs["dtype"]
+        keys = self.max_blocks_per_seq * bs
+        self._query_layout = (C, B) if latent else query_layout(
+            self.mixed_tokens, B, C, H, hd, lanes, dtype,
+            Dv=self.pool.v.shape[-1] // self.tp, keys=keys)
+        cols = None if latent else self._query_layout[0]
         self._query_tile_cols = query_tile_columns(
-            np.arange(self.prefill_chunk + 1), self.prefill_chunk,
-            cfg.n_heads // self.tp, self._pool_kwargs["head_dim"], lanes,
-            self._pool_kwargs["dtype"], latent=self.pool.v is None,
-            hd_v=self._pool_kwargs.get("v_head_dim"))
+            np.arange(C + 1), C, H, hd, lanes, dtype, latent=latent,
+            hd_v=hd_v, cols=cols)
+        # a kernel row past the rows' pieces runs one column's first tile
+        self._idle_tile_cols = int(query_tile_columns(
+            [1], C, H, hd, lanes, dtype, latent=latent, hd_v=hd_v,
+            cols=cols)[0])
         # on a windowed cache whose window is narrower than a chunk: the
-        # window pool's geometry as ``window_pairs`` takes it, for
-        # ``kv_window_band_pairs`` / ``kv_window_span_pairs``
+        # window pool's geometry and layout as ``window_pairs`` takes them,
+        # for ``kv_window_band_pairs`` / ``kv_window_span_pairs``
         self._window_pairs = None
         window = self.pool.window
-        if window is not None and window < self.prefill_chunk:
+        if window is not None and window < C:
             wk, wv, hd = self.pool.kw.shape[-1], self.pool.vw.shape[-1], \
                 self.pool.head_dim
             self._window_pairs = dict(
-                C=self.prefill_chunk, H=cfg.n_heads, hd=hd, D=wk,
-                dtype=self.pool.kw.dtype, window=window,
+                C=C, H=cfg.n_heads, hd=hd, D=wk, dtype=self.pool.kw.dtype,
+                window=window,
                 span=bs * span_blocks(bs, self.max_blocks_per_seq,
                                       math.gcd(wk, wv)),
                 hd_v=None if self.pool.v_head_dim == hd
-                else self.pool.v_head_dim)
+                else self.pool.v_head_dim,
+                cols=query_layout(self.mixed_tokens, B, C, cfg.n_heads, hd,
+                                  wk, self.pool.kw.dtype, Dv=wv,
+                                  keys=keys)[0])
         # a cache that cannot share blocks (the hybrid one: a shared block
         # would skip the tokens that build the conv state) runs without a
         # prefix cache, whatever was asked for
@@ -2074,15 +2091,24 @@ class PagedDecodeEngine:
             self.pool.stats.record_window_keys(seen, keys)
 
     def _note_query_cols(self, ph, row_nvalid) -> None:
-        """What a mixed round's calls of the ragged kernels run, on
-        ``pw.round.build``: ``kv_query_cols``, the live query columns of
-        ALL the step's rows (an idle row has one: the kernel runs it), and
-        ``kv_query_tile_cols``, the columns their live tiles cover
-        (:func:`query_tile_columns`).  A decode step's or a chain's rows are
+        """What a mixed round's calls of the ragged kernels run on the full
+        pool, on ``pw.round.build``: ``kv_query_cols``, the live query
+        columns of ALL the step's rows (an idle row has one: the kernel
+        runs it); ``kv_query_slots``, the query slots the dispatch lays out
+        for them (:func:`query_layout`: N kernel rows of P columns, B x C
+        where it keeps the rows), also in the pool's counters; and
+        ``kv_query_tile_cols``, the columns the kernel rows' live tiles
+        cover (:func:`query_tile_columns`; a kernel row past the rows'
+        pieces runs one column's).  A decode step's or a chain's rows are
         one column and one tile each: nothing to count."""
-        ph.set(kv_query_cols=int(row_nvalid.sum()),
+        P, N = self._query_layout
+        spare = N - int((-(-row_nvalid // P)).sum())
+        slots = N * P
+        ph.set(kv_query_cols=int(row_nvalid.sum()), kv_query_slots=slots,
                kv_query_tile_cols=int(
-                   self._query_tile_cols[row_nvalid].sum()))
+                   self._query_tile_cols[row_nvalid].sum())
+               + spare * self._idle_tile_cols)
+        self.pool.stats.record_query_slots(slots)
 
     def _note_window_pairs(self, ph, row_start, row_nvalid) -> None:
         """On a windowed cache whose window is narrower than a chunk, what

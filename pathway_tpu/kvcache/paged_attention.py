@@ -96,15 +96,31 @@ own widths; nothing is padded in the pool.  The sinks reach the kernels as
 (Hkv, rep, 128) f32 (:func:`_sink_rows`: a K/V head's ``rep`` query heads
 down the sublanes, in the order the fold gives its columns) and are the
 state a row STARTS from (:func:`_start_row`: ``m = sink, l = 1, acc = 0``
-where it is ``-inf, 0, 0``): they cost nothing a span.  A row whose scratch
-and blocks would not fit VMEM (64 query heads of 192 beside values of 128
-at a chunk of 512: 129 MiB) is handed to the ragged kernel in pieces
-(:func:`query_pieces`, :func:`_row_pieces`: the latent kernel's cut, by the
-bytes :func:`_vmem_limit` asks for, under which every earlier geometry
-stays whole at its cell's chunk); under a window a
-piece's dead spans are its own, so a chunk wider than the window computes
-the spans its piece's columns see, not the chunk's
-(:func:`window_pairs` counts both for the engine).
+where it is ``-inf, 0, 0``): they cost nothing a span.
+
+The pieces (PR 41): a mixed step's queries are a packed stream of T tokens
+(B + the chunk) that its B rows of C columns hold about a sixteenth of at
+the chunk's width: the rows as they stood (B x C query slots, gathered,
+folded into the kernel's order, relaid where a head is no whole lane tiles,
+streamed into the kernel and its output back, whatever the rows hold) cost
+the long configurations a third of their step.  :func:`paged_attention`
+takes the packed stream and cuts the kernel's rows from it
+(:func:`_pieces`, on the device from the step's own arrays): a row of ``n``
+valid columns is ``ceil(n / P)`` kernel rows of ``P`` columns each, over
+the row's table, its columns' contexts from the piece's first
+(:func:`_row_pieces`' rule), and only ``N = T // P + B`` of them (the rows'
+at most T + B columns never need more), the output gathered back to the
+stream.  ``P`` is a rule of shapes (:func:`query_layout`: a kernel row
+costs its query slots' bytes, which cross HBM :data:`_SLOT_PASSES` times,
+and its walk of a table; P is the cheapest width of whole sublane tiles
+that fits VMEM, the rows as they are where they cost no more - GPT-2's
+cell).  The kernel does not change: it sees rows of P columns.  A query
+column sees the same spans in the same order as in its row (a span behind a
+piece's window, which the row still walked, is all masked for the piece's
+columns and leaves their softmax as it was), so the outputs are the rows'
+bit for bit.  Under a window a piece's dead spans are its own, so a chunk
+wider than the window computes the spans its piece's columns see, not the
+chunk's (:func:`window_pairs` counts both for the engine).
 
 Round-8 raggedness (the fused mixed decode/prefill step):
 
@@ -435,58 +451,70 @@ def _live_columns(b, c0_ref, cl_ref, rep: int, tiles: tuple | None):
 
 
 def query_tile_columns(n_valid, C: int, H: int, hd: int, D: int, dtype,
-                       latent: bool = False, hd_v: int | None = None):
+                       latent: bool = False, hd_v: int | None = None,
+                       cols: int | None = None):
     """The query columns the kernels' live tiles cover for rows of
     ``n_valid`` live columns each (an idle row has one) of ``C``, ``H``
     query heads of ``hd`` over a pool of ``D`` lanes, queries in ``dtype``
     (``latent``: through :func:`latent_attention`, a row in pieces;
-    ``hd_v``: a V head of another width): the rule of :func:`_col_tiles` as
-    :func:`_live_tiles` runs it (:func:`_wide_tiles`), for the engine's
-    ``kv_query_tile_cols``.  (len(n_valid),) int64."""
+    ``hd_v``: a V head of another width; ``cols``: the ``P`` of
+    :func:`query_layout`, a row in pieces of its live columns): the rule of
+    :func:`_col_tiles` as :func:`_live_tiles` runs it (:func:`_wide_tiles`),
+    for the engine's ``kv_query_tile_cols``.  (len(n_valid),) int64."""
     return _piece_tile_columns(n_valid, C, H, hd, D, dtype, latent,
-                               hd_v)[0].sum(axis=1)
+                               hd_v, cols)[0].sum(axis=1)
 
 
 def _piece_tile_columns(n_valid, C: int, H: int, hd: int, D: int, dtype,
-                        latent: bool = False, hd_v: int | None = None):
+                        latent: bool = False, hd_v: int | None = None,
+                        cols: int | None = None):
     """``(run, cols)``: the query columns the live tiles of each piece of
-    each row cover ((rows, pieces) int64), and a piece's width."""
+    each row cover ((rows, pieces) int64; 0 where a row has no such piece)
+    and a piece's width.  A latent row is C // cols pieces whatever it
+    holds, one past its valid columns running one column; a row of the
+    K/V pools is the ``ceil(n / cols)`` pieces of its live columns."""
     n = np.asarray(n_valid, np.int64)
-    pieces = _latent_pieces(C) if latent \
-        else query_pieces(C, H, hd, D, dtype, hd_v)
-    C //= pieces
+    if latent:
+        cols = C // _latent_pieces(C)
+    cols = C if cols is None else cols
+    pieces = -(-C // cols)
     rep = H * hd // D
-    tiles = _col_tiles(_heads_per_group(D // hd, hd, C * rep, hd_v), C * rep,
-                       rep, dtype)
-    # a piece's live columns; a piece past the row's valid ones runs one
-    live = np.clip(n[:, None] - C * np.arange(pieces)[None, :], 1, C) * rep
+    tiles = _col_tiles(_heads_per_group(D // hd, hd, cols * rep, hd_v),
+                       cols * rep, rep, dtype)
+    # a piece's live columns; a latent piece past the row's valid ones runs
+    # one
+    live = np.clip(n[:, None] - cols * np.arange(pieces)[None, :],
+                   1 if latent else 0, cols) * rep
     if tiles is None:
-        run = np.full_like(live, C * rep)
+        run = np.full_like(live, cols * rep)
     else:
         first, tile = tiles
         wide = _wide_tiles(live, first, tile)
         run = np.where(wide == 0, first, wide * tile)
-    return run // rep, C
+    return np.where(live > 0, run, 0) // rep, cols
 
 
 def window_pairs(start_pos, n_valid, C: int, H: int, hd: int, D: int, dtype,
-                 window: int, span: int, hd_v: int | None = None) -> tuple:
+                 window: int, span: int, hd_v: int | None = None,
+                 cols: int | None = None) -> tuple:
     """What a sliding-window layer's call of the ragged kernel sees and what
     it computes, for rows of ``n_valid`` live columns from position
     ``start_pos`` (query geometry as :func:`query_tile_columns` takes it,
-    ``span`` the keys a grid step attends): ``(band, run)`` query-key pairs
-    - ``band``, each live column's ``min(context, window)``; ``run``, each
-    piece's live tile columns times the keys of its live spans (from the span
-    of the first key its first column sees to the span of its last key:
-    :func:`_first_span`).  ``band / run`` is how full the kernel's work is of
-    pairs the softmax keeps; both from shapes alone, for the engine's
-    ``kv_window_band_pairs`` / ``kv_window_span_pairs``."""
+    ``cols`` the layout's piece, ``span`` the keys a grid step attends):
+    ``(band, run)`` query-key pairs - ``band``, each live column's
+    ``min(context, window)``; ``run``, each piece's live tile columns times
+    the keys of its live spans (from the span of the first key its first
+    column sees to the span of its last key: :func:`_first_span`).  ``band
+    / run`` is how full the kernel's work is of pairs the softmax keeps;
+    both from shapes alone, for the engine's ``kv_window_band_pairs`` /
+    ``kv_window_span_pairs``."""
     start = np.asarray(start_pos, np.int64)
     n = np.asarray(n_valid, np.int64)
-    run, cols = _piece_tile_columns(n, C, H, hd, D, dtype, hd_v=hd_v)
+    run, cols = _piece_tile_columns(n, C, H, hd, D, dtype, hd_v=hd_v,
+                                    cols=cols)
     c0 = start[:, None] + 1 + cols * np.arange(run.shape[1])[None, :]
     cl = np.minimum((start + n)[:, None], c0 + cols - 1)
-    dead = c0 > cl  # a piece past the row's columns: one key, one span
+    dead = c0 > cl  # no such piece: run is 0 there
     c0, cl = np.where(dead, 1, c0), np.where(dead, 1, cl)
     spans = (cl - 1) // span - np.maximum(c0 - window, 0) // span + 1
     ctx = start[:, None] + 1 + np.arange(C)[None, :]
@@ -954,7 +982,10 @@ def _paged_ragged_fn(q, k_pool, v_pool, layer, block_tables, c0, cl,
     (``Hkv * hd_v``: a V head narrower than a K head, read from the two
     pools' shapes; the output is (B, C, H, hd_v)); ``sinks`` (H,) f32, or
     None: a learned logit a query head that joins its softmax's
-    denominator (:func:`_start_row`)."""
+    denominator (:func:`_start_row`).  A mixed step's rows come here as
+    :func:`query_layout` lays them out: the B rows at the chunk's width, or
+    N pieces of P query columns cut from the packed stream (:func:`_pieces`),
+    each a row of the grid over its row's table."""
     B, hd = q.shape[0], q.shape[3]
     BS, D = k_pool.shape[2:]
     Dv = v_pool.shape[3]
@@ -1263,29 +1294,75 @@ def _row_pieces(q, tables, c0, cl, n: int) -> tuple:
 
 def query_pieces(C: int, H: int, hd: int, D: int, dtype,
                  hd_v: int | None = None) -> int:
-    """The kernel rows :func:`paged_attention` cuts a row of ``C`` query
-    columns of ``H`` heads of ``hd`` into, over pools of ``D`` key lanes and
-    V heads of ``hd_v``: one while what the row holds in VMEM
-    (:func:`_vmem_need`, at the widest span and a pool of the queries'
-    dtype) and the room :func:`_vmem_limit` asks over it stay under
-    :data:`_VMEM_CAP` - sixteen heads of 256 at a chunk of 1,024 ask 96.5 of
-    the 100 MiB, the most of any geometry before 64 heads of 192, and stay
-    whole - else halves until they do (64 heads of 192 beside values of 128
-    at a chunk of 512: two pieces of 256), a piece whole sublane tiles
-    wide."""
-    Hkv, rep = D // hd, H * hd // D
-    hd_v = hd if hd_v is None else hd_v
+    """The kernel rows :func:`query_layout` makes of ONE row of ``C`` query
+    columns called alone (one, where it fits VMEM), for the benchmark's
+    kernel smoke (``benchmark/tests/smoke_mimo_v2_flash.py``)."""
+    Dv = D if hd_v is None else D // hd * hd_v
+    return -(-C // query_layout(C, 1, C, H, hd, D, dtype, Dv=Dv,
+                                keys=_LANES)[0])
+
+
+_SPLIT_TRIPS = 5  # HBM round trips of a slot where the fold splits lane tiles
+
+
+def query_layout(T: int, B: int, C: int, H: int, hd: int, D: int, dtype, *,
+                 Dv: int | None = None, keys: int,
+                 pool_dtype=None) -> tuple:
+    """``(P, N)``: how :func:`paged_attention` lays a packed step of ``T``
+    tokens in ``B`` rows of ``C`` columns out for the ragged kernel - ``H``
+    query heads of ``hd`` over pools of ``D`` key and ``Dv`` value lanes,
+    tables of ``keys`` keys; ``(C, B)`` where it keeps the rows, else ``N =
+    T // P + B`` kernel rows of ``P`` query columns (:func:`_pieces`: a row
+    of ``n`` columns takes ``ceil(n / P)``, so the rows' at most ``T + B``
+    columns never need more).  A rule of shapes alone.
+
+    A layout costs its kernel rows, each its query slots and its walk of a
+    table.  A slot is ``H x (hd + hd_v)`` values that make one round trip
+    through HBM (gathered into the rows, read by the kernel, its output
+    written), and :data:`_SPLIT_TRIPS` where the fold splits lane tiles
+    (``rep`` query heads a K/V head of a width that is no whole lane tiles:
+    the fold and the unfold are then relayouts, each way, not moves of whole
+    tiles).  A walk is ``keys x (D + Dv)`` values: a grid row reads its live
+    spans, steps through its dead ones, and a piece of a chunk row reads its
+    row's keys again.  P is the cheapest of C and the widths of whole
+    sublane tiles under it that fit VMEM (:func:`_vmem_need` and the room
+    :func:`_vmem_limit` asks, under :data:`_VMEM_CAP`: 64 heads of 192
+    beside 128 at 512 columns do not); the rows where they cost no more.
+    The cells' geometries (B = 16, T = B + C, bf16;
+    ``tests/test_ragged_step.py`` pins them), as a ragged call's time at
+    each width on the chip ranks them (PERF.md section 6, PR 41):
+
+    - GPT-2-large, 20 heads of 64, rep 1, C 256, 1,024 keys: the rows;
+    - LFM2, 32 heads of 64 on 8, C 512, 2,048 keys: P 64;
+    - Trinity, 32 heads of 128 on 4, C 256, 8,192 keys: the rows, on full
+      and window layers;
+    - Qwen3-Next, 16 heads of 256 on 2, C 1,024, 8,192 keys: P 256;
+    - MiMo-V2-Flash, 64 heads of 192 (values 128) on 4 (full layers) and 8
+      (window layers), C 512, 8,192 keys: P 64 on both."""
+    Dv = D if Dv is None else Dv
+    Hkv = D // hd
+    rep, hd_v = H // Hkv, Dv // Hkv
+    split = rep > 1 and hd % _LANES != 0
+    slot = H * (hd + hd_v) * jnp.dtype(dtype).itemsize \
+        * (_SPLIT_TRIPS if split else 1)
+    walk = keys * (D + Dv) * jnp.dtype(pool_dtype or dtype).itemsize
 
     def fits(cols: int) -> bool:
         R = cols * rep
         G = _heads_per_group(Hkv, hd, R, None if hd_v == hd else hd_v)
-        return _vmem_need(1, _LANES, D, dtype, Hkv, R, hd, G, dtype,
-                          Hkv * hd_v, hd_v) + _VMEM_ROOM <= _VMEM_CAP
+        return _vmem_need(1, _LANES, D, dtype, Hkv, R, hd, G, dtype, Dv,
+                          hd_v) + _VMEM_ROOM <= _VMEM_CAP
 
-    n = 1
-    while not fits(C // n) and (C // n) % 32 == 0:
-        n *= 2
-    return n
+    sub = _sublanes(dtype)
+    widths = [C] + [sub << i for i in range((C // sub).bit_length())
+                    if sub << i < C][::-1]
+    best = None
+    for P in [P for P in widths if fits(P)] or widths[-1:]:
+        N = B if P == C else T // P + B
+        cost = N * (P * slot + walk)
+        if best is None or cost < best[0]:
+            best = (cost, P, N)
+    return best[1:]
 
 
 def _latent_scratch(K: int, BS: int, W: int, pool_dtype, R: int, dtype):
@@ -1722,8 +1799,41 @@ def paged_append_attend(q, k_new, v_new, k_pool, v_pool, block_tables,
     return (a, kk[0], vv[0]) if layer is None else (a, kk, vv)
 
 
+def _pieces(row_start, row_nvalid, row_token_idx, tok_row, tok_col, P: int,
+            N: int) -> tuple:
+    """The kernel rows of a packed step in pieces of ``P`` query columns
+    (:func:`query_layout`), from the step's own arrays on the device: a
+    row of ``n`` valid columns takes ``ceil(n / P)`` pieces, in row order,
+    piece ``k`` of it the columns ``k*P ..`` (a decode row and an idle row
+    one each); the ``N`` pieces past the rows' are idle kernel rows
+    (context 1).  Returns ``(row, token, c0, cl, back)``: each piece's row
+    (N,) (its table's), the packed tokens of its columns (N, P) (past the
+    row's valid columns, its last one's: padding the kernel clamps), its
+    first and last columns' contexts (N,) (:func:`_row_pieces`' rule), and
+    ``(piece, column)`` of each packed token, the gather of the output
+    back to the stream."""
+    B = row_nvalid.shape[0]
+    n = jnp.asarray(row_nvalid, jnp.int32)
+    count = (n + P - 1) // P
+    end = jnp.cumsum(count)
+    first = end - count
+    p = jnp.arange(N, dtype=jnp.int32)
+    row = jnp.minimum(
+        jnp.sum(end[None, :] <= p[:, None], axis=1, dtype=jnp.int32), B - 1)
+    col0 = (p - first[row]) * P
+    start = jnp.asarray(row_start, jnp.int32)[row]
+    live = p < end[-1]
+    c0 = start + 1 + col0
+    cl = jnp.minimum(start + n[row], c0 + P - 1)
+    cols = jnp.minimum(col0[:, None] + jnp.arange(P, dtype=jnp.int32)[None],
+                       n[row][:, None] - 1)
+    return (row, row_token_idx[row[:, None], cols], jnp.where(live, c0, 1),
+            jnp.where(live, cl, 1),
+            (first[tok_row] + tok_col // P, tok_col % P))
+
+
 def paged_attention(q, k_pool, v_pool, block_tables, context_lens=None, *,
-                    start_pos=None, n_valid=None, layer=None,
+                    start_pos=None, n_valid=None, packed=None, layer=None,
                     use_pallas: bool | None = None,
                     interpret: bool | None = None,
                     window: int | None = None, sinks=None):
@@ -1734,34 +1844,93 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens=None, *,
     stacked (L, num_blocks, BS, H*hd) pool read in place at layer i.
     The kernel reads the pools where they are, in the layout they have:
     nothing pool-sized is padded, sliced or copied by this function.
-    Where a row's scratch and blocks would not fit VMEM (:func:`query_pieces`:
-    64 query heads of 192 at a chunk of 512) the kernel takes the row in
-    pieces, each a kernel row of its own over the same table, as
-    :func:`latent_attention` does."""
+
+    ``packed`` (a mixed step): ``q`` is the step's packed stream (T, H, hd)
+    and ``packed = (row_token_idx (B, C), tok_row (T,), tok_col (T,))`` place
+    its tokens in the B rows of ``block_tables`` / ``start_pos`` /
+    ``n_valid`` (the rows' valid columns are packed tokens: they add up to
+    at most T); returns the packed output (T, H, hd_v).  The reference path
+    gathers the rows (B, C, H, hd) and back.  The kernel takes the rows as
+    :func:`query_layout` lays them out from shapes alone: as the B rows at
+    the chunk's width, or as N kernel rows of P query columns cut from the
+    stream (:func:`_pieces`), only as many as the live rows need - the
+    queries gathered, folded and streamed into the kernel, and its output
+    back, are N x P slots where the rows are B x C.  Without ``packed``
+    ``q`` is (B, C, H, hd) rows: the rule keeps them (a stream of B x C
+    tokens needs no fewer slots) unless they do not fit VMEM, and then a
+    column past a row's valid ones returns its last valid one's output."""
     backend = jax.default_backend()
     if use_pallas is None:
         use_pallas = backend == "tpu"
     if not use_pallas:
+        if packed is not None:
+            idx, tok_row, tok_col = packed
+            return paged_attention_reference(
+                q[idx], _layer_of(k_pool, layer), _layer_of(v_pool, layer),
+                block_tables, start_pos=start_pos, n_valid=n_valid,
+                window=window, sinks=sinks)[tok_row, tok_col]
         return paged_attention_reference(
             q, _layer_of(k_pool, layer), _layer_of(v_pool, layer),
             block_tables, context_lens,
             start_pos=start_pos, n_valid=n_valid, window=window, sinks=sinks,
         )
-    B, C, H, hd = q.shape
-    _require_positive_context(C, context_lens, start_pos, n_valid)
-    c0, cl_last = _query_context(C, context_lens, start_pos, n_valid)
     kk, vv, li = _stacked(k_pool, v_pool, layer)
     tables = jnp.asarray(block_tables, jnp.int32)
-    n = query_pieces(C, H, hd, kk.shape[-1], q.dtype,
-                     vv.shape[-1] * hd // kk.shape[-1])
-    if n > 1:
-        q, tables, c0, cl_last = _row_pieces(q, tables, c0, cl_last, n)
-    out = _paged_ragged(
-        q, kk, vv, li, tables,
-        c0.astype(jnp.int32), cl_last.astype(jnp.int32),
-        *(() if sinks is None else (sinks,)),
-        d_true=hd,
-        interpret=(backend != "tpu") if interpret is None else interpret,
-        **({} if window is None else {"window": int(window)}),
-    )
-    return out.reshape(B, C, H, -1) if n > 1 else out
+    extra = () if sinks is None else (sinks,)
+    kw = dict(window=window,
+              interpret=(backend != "tpu") if interpret is None else interpret)
+    if packed is not None:
+        _require_positive_context(1, None, start_pos, n_valid)
+        return _ragged_packed(q, kk, vv, li, tables,
+                              jnp.asarray(start_pos, jnp.int32),
+                              jnp.asarray(n_valid, jnp.int32), *packed,
+                              *extra, **kw)
+    B, C, H, hd = q.shape
+    _require_positive_context(C, context_lens, start_pos, n_valid)
+    c0, cl = (x.astype(jnp.int32) for x in _query_context(
+        C, context_lens, start_pos, n_valid))
+    if _layout(q, B * C, B, C, kk, vv, tables) == (C, B):
+        return _paged_ragged(q, kk, vv, li, tables, c0, cl, *extra,
+                             **_kernel_kw(hd, **kw))
+    # rows that do not fit VMEM: a stream of B x C tokens, row b's at b * C
+    start, nv = c0 - 1, cl - c0 + 1
+    col = jnp.minimum(jnp.arange(C, dtype=jnp.int32)[None], nv[:, None] - 1)
+    out = _ragged_packed(
+        q.reshape(B * C, H, hd), kk, vv, li, tables, start, nv,
+        jnp.arange(B, dtype=jnp.int32)[:, None] * C + col,
+        jnp.repeat(jnp.arange(B, dtype=jnp.int32), C), col.reshape(-1),
+        *extra, **kw)
+    return out.reshape(B, C, H, -1)
+
+
+def _layout(q, T: int, B: int, C: int, kk, vv, tables) -> tuple:
+    """:func:`query_layout` of a call of these queries on these pools and
+    tables."""
+    return query_layout(T, B, C, *q.shape[-2:], kk.shape[3], q.dtype,
+                        Dv=vv.shape[3], keys=tables.shape[1] * kk.shape[2],
+                        pool_dtype=kk.dtype)
+
+
+def _kernel_kw(hd: int, window: int | None, interpret: bool) -> dict:
+    return dict(d_true=hd, interpret=interpret,
+                **({} if window is None else {"window": int(window)}))
+
+
+def _ragged_packed(q, kk, vv, li, tables, start, nv, idx, tok_row, tok_col,
+                   sinks=None, *, window: int | None, interpret: bool,
+                   layout: tuple | None = None):
+    """The kernel call of a packed step (:func:`paged_attention`) in the
+    layout :func:`query_layout` gives (``layout``: ``(P, N)`` forced, for
+    tests and the chip's sweeps)."""
+    T, H, hd = q.shape
+    B, C = idx.shape
+    P, N = layout or _layout(q, T, B, C, kk, vv, tables)
+    extra = () if sinks is None else (sinks,)
+    kw = _kernel_kw(hd, window, interpret)
+    if (P, N) == (C, B):  # the rows as they are
+        out = _paged_ragged(q[idx], kk, vv, li, tables, start + 1, start + nv,
+                            *extra, **kw)
+        return out[tok_row, tok_col]
+    row, tok, c0, cl, back = _pieces(start, nv, idx, tok_row, tok_col, P, N)
+    out = _paged_ragged(q[tok], kk, vv, li, tables[row], c0, cl, *extra, **kw)
+    return out[back]

@@ -205,6 +205,7 @@ def _forward(params: dict, cfg: AfmoeConfig, k_pool, v_pool, kw_pool, vw_pool,
     win_blocks = jnp.where(
         slot_blocks > 0,
         win_tables[tok_row, positions // kw_pool.shape[2]], 0)
+    rows = (row_token_idx, tok_row, tok_col)  # the stream's tokens in rows
     fi = wi = 0
     for li, (kind, lay) in enumerate(zip(cfg.layer_types, params["layers"])):
         h = _rms(x, lay["norm_in"], eps, dtype)
@@ -230,9 +231,8 @@ def _forward(params: dict, cfg: AfmoeConfig, k_pool, v_pool, kw_pool, vw_pool,
                 *pools, blocks, slot_offsets, k1, v1, layer=layer,
                 use_pallas=kernels)
             a = paged_attention(
-                q[row_token_idx], *pools, tables, start_pos=row_start,
-                n_valid=row_nvalid, layer=layer, use_pallas=kernels,
-                window=window)[tok_row, tok_col]
+                q, *pools, tables, start_pos=row_start, n_valid=row_nvalid,
+                packed=rows, layer=layer, use_pallas=kernels, window=window)
         if kind == SLIDING:
             kw_pool, vw_pool = pools
             wi += 1

@@ -666,6 +666,7 @@ def paged_mixed_step(params: dict, cfg: DecoderConfig, k_pool: jax.Array,
     kernels = attn == "pallas"
     T = tokens.shape[0]
     hd = cfg.d_model // cfg.n_heads
+    rows = (row_token_idx, tok_row, tok_col)  # the stream's tokens in rows
     # padding tokens may carry position 0 already; clamp defensively so a
     # caller bug cannot index past the embedding table
     pos = jnp.minimum(positions, cfg.max_len - 1)
@@ -679,7 +680,6 @@ def paged_mixed_step(params: dict, cfg: DecoderConfig, k_pool: jax.Array,
         q = q.reshape(T, -1, hd)
         k1 = k1.reshape(T, -1, hd)
         v1 = v1.reshape(T, -1, hd)
-        q_rows = q[row_token_idx]  # (B, C, H[/tp], hd)
         # every token's row lands before any row's attention gathers
         # (the returned pools carry the dependence): a reader of a shared
         # prefix may attend what its writer fills in this same step
@@ -687,11 +687,9 @@ def paged_mixed_step(params: dict, cfg: DecoderConfig, k_pool: jax.Array,
             k_pool, v_pool, slot_blocks, slot_offsets, k1, v1, layer=li,
             use_pallas=kernels,
         )
-        a_rows = paged_attention(
-            q_rows, k_pool, v_pool, row_tables, start_pos=row_start,
-            n_valid=row_nvalid, layer=li, use_pallas=kernels,
-        )
-        a = a_rows[tok_row, tok_col]  # back to the packed (T, H[/tp], hd)
+        a = paged_attention(q, k_pool, v_pool, row_tables, start_pos=row_start,
+                            n_valid=row_nvalid, packed=rows, layer=li,
+                            use_pallas=kernels)  # (T, H[/tp], hd)
         x = x + _row_proj(layer, a.reshape(T, -1), "wo", "bo", tp_axis)
         h = _layer_norm(x, layer["ln2_scale"], layer["ln2_bias"], eps)
         ff = act(_proj_p(layer, h, "w_up", "b_up"))

@@ -244,6 +244,7 @@ def _forward(params: dict, cfg: Lfm2Config, k_pool, v_pool, conv, tokens,
     # later tokens of a run, the row's slot for its first two
     slot_of_tok = row_slot[tok_row]
     has1, has2 = (positions >= 1)[:, None], (positions >= 2)[:, None]
+    rows = (row_token_idx, tok_row, tok_col)  # the stream's tokens in rows
     ai = ci = 0
     for li, (kind, lay) in enumerate(zip(cfg.layer_types, params["layers"])):
         h = _rms(x, lay["norm_op"], eps, dtype)
@@ -265,9 +266,9 @@ def _forward(params: dict, cfg: Lfm2Config, k_pool, v_pool, conv, tokens,
                     k_pool, v_pool, slot_blocks, slot_offsets, k1, v1,
                     layer=ai, use_pallas=kernels)
                 a = paged_attention(
-                    q[row_token_idx], k_pool, v_pool, row_tables,
-                    start_pos=row_start, n_valid=row_nvalid, layer=ai,
-                    use_pallas=kernels)[tok_row, tok_col]
+                    q, k_pool, v_pool, row_tables, start_pos=row_start,
+                    n_valid=row_nvalid, packed=rows, layer=ai,
+                    use_pallas=kernels)
             x = x + a.reshape(T, -1).astype(dtype) @ lay["wo"]
             ai += 1
         else:

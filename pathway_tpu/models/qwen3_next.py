@@ -341,6 +341,7 @@ def _forward(params: dict, cfg: Qwen3NextConfig, k_pool, v_pool, conv, state,
     counts = jnp.zeros((cfg.held_experts + len(COUNTER_TAIL),), jnp.int32)
     slot_of_tok = row_slot[tok_row]
     row_first = row_token_idx[:, 0]
+    rows = (row_token_idx, tok_row, tok_col)  # the stream's tokens in rows
     row_fresh = _fresh_rows(row_start)
     if not decode:
         items = kda.chunk_items(row_first, row_fresh, row_nvalid, row_slot,
@@ -386,9 +387,9 @@ def _forward(params: dict, cfg: Qwen3NextConfig, k_pool, v_pool, conv, state,
                     k_pool, v_pool, slot_blocks, slot_offsets, k1, v1,
                     layer=fi, use_pallas=kernels)
                 a = paged_attention(
-                    q[row_token_idx], k_pool, v_pool, row_tables,
-                    start_pos=row_start, n_valid=row_nvalid, layer=fi,
-                    use_pallas=kernels)[tok_row, tok_col]
+                    q, k_pool, v_pool, row_tables, start_pos=row_start,
+                    n_valid=row_nvalid, packed=rows, layer=fi,
+                    use_pallas=kernels)
             o = _gated(a.reshape(T, -1), qg[:, :, 1].reshape(T, -1), dtype)
             x = x + o @ lay["wo"]
             fi += 1

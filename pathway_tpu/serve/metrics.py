@@ -174,6 +174,10 @@ class KVCacheStats:
     - ``pathway_kv_write_blocks_total{pool}``  counter (distinct pool
       blocks the mixed steps' tokens landed in: what the K/V writer moves
       a layer and pool, against ``mixed_tokens_used`` rows)
+    - ``pathway_kv_query_slots_total{pool}``  counter (query slots the
+      mixed steps' ragged calls laid out on the full pool: N kernel rows x P
+      columns of ``paged_attention.query_layout``, against the rows' live
+      columns)
     - ``pathway_kv_spec_proposed_total{pool}``  counter (Round-18: draft
       tokens proposed into verify dispatches)
     - ``pathway_kv_spec_accepted_total{pool}``  counter (draft tokens the
@@ -263,6 +267,7 @@ class KVCacheStats:
         self.kv_keys = 0
         self.kv_key_lanes = 0
         self.kv_write_blocks = 0
+        self.kv_query_slots = 0
         # Round-18 speculative decoding: proposed/accepted/rejected draft
         # tokens, total verify-emitted tokens and verify dispatches
         self.spec_proposed = 0
@@ -471,6 +476,11 @@ class KVCacheStats:
         with self._lock:
             self.kv_write_blocks += blocks
 
+    def record_query_slots(self, slots: int) -> None:
+        """Query slots one mixed step's ragged calls laid out (a layer)."""
+        with self._lock:
+            self.kv_query_slots += slots
+
     def record_engine_restart(self, rebuild_seconds: float) -> None:
         """One supervised engine restart (pool rebuild time only; the
         failure -> first-recovered-token window lands separately via
@@ -574,6 +584,7 @@ class KVCacheStats:
                 "kv_keys": self.kv_keys,
                 "kv_key_lanes": self.kv_key_lanes,
                 "kv_write_blocks": self.kv_write_blocks,
+                "kv_query_slots": self.kv_query_slots,
                 "spec_proposed": self.spec_proposed,
                 "spec_accepted": self.spec_accepted,
                 "spec_rejected": self.spec_rejected,
@@ -1037,6 +1048,7 @@ def _render_kv_lines() -> list[str]:
         "# TYPE pathway_kv_attended_keys_total counter",
         "# TYPE pathway_kv_attended_key_lanes_total counter",
         "# TYPE pathway_kv_write_blocks_total counter",
+        "# TYPE pathway_kv_query_slots_total counter",
         "# TYPE pathway_kv_spec_proposed_total counter",
         "# TYPE pathway_kv_spec_accepted_total counter",
         "# TYPE pathway_kv_spec_rejected_total counter",
@@ -1191,6 +1203,9 @@ def _render_kv_lines() -> list[str]:
         lines.append(
             f"pathway_kv_write_blocks_total{{{lbl}}} "
             f"{snap['kv_write_blocks']}"
+        )
+        lines.append(
+            f"pathway_kv_query_slots_total{{{lbl}}} {snap['kv_query_slots']}"
         )
         # Round-18 speculative decoding: draft proposal/acceptance flow
         lines.append(

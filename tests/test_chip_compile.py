@@ -1129,7 +1129,7 @@ def test_qwen3_next_packed_step_programs_lower_under_their_names(
 # layers, 16 x 42 + 1 window blocks in 9.
 
 
-@pytest.mark.parametrize("C", [1, 256, 5], ids=["decode", "piece", "verify"])
+@pytest.mark.parametrize("C", [1, 64, 5], ids=["decode", "piece", "verify"])
 @pytest.mark.parametrize("kind", ["full", "sliding"])
 def test_paged_kernels_compile_at_keys_of_192_beside_values_of_128(shape,
                                                                    kind, C):
@@ -1138,10 +1138,10 @@ def test_paged_kernels_compile_at_keys_of_192_beside_values_of_128(shape,
     whole tiles), ``acc`` and the output at V's width, the pool rows 768 /
     512 (full) and 1,536 / 1,024 (sliding) lanes, never padded; on the
     sliding layers a window of 128 and the sinks as the softmax's starting
-    state.  At one column (the fused append too), at the 256 columns a piece
-    of a chunk of 512 is (:func:`query_pieces`: the widest whose scratch and
-    blocks fit the 100 MiB a call may ask of VMEM; a chunk of 256 is one
-    piece), and at a verify width of 5."""
+    state.  At one column (the fused append too), at the 64 columns of the
+    pieces :func:`query_layout` cuts a mixed step's rows into (24 kernel rows
+    for a step of 528 tokens in sixteen rows of 512), and at a verify width
+    of 5."""
     import jax.numpy as jnp
 
     pa = _paged()
@@ -1149,13 +1149,14 @@ def test_paged_kernels_compile_at_keys_of_192_beside_values_of_128(shape,
     kv, layers, blocks, window = (4, 2, 8193, None) if kind == "full" \
         else (8, 9, 16 * 42 + 1, 128)
     rep = H // kv
-    pieces = pa.query_pieces(512, H, hd, kv * hd, bf, hd_v)
-    assert pieces == 2 and pa.query_pieces(256, H, hd, kv * hd, bf, hd_v) == 1
+    P, N = pa.query_layout(B + 512, B, 512, H, hd, kv * hd, bf,
+                           Dv=kv * hd_v, keys=tables * BS)
+    assert (P, N) == (64, 24)
     assert pa.span_blocks(BS, tables, math.gcd(kv * hd, kv * hd_v)) == 128 // BS
     assert pa._heads_per_group(kv, hd, C * rep, hd_v) == 2
     kpool = shape((layers, blocks, BS, kv * hd), bf)
     vpool = shape((layers, blocks, BS, kv * hd_v), bf)
-    rows = B * pieces if C == 256 else B
+    rows = N if C == P else B
     idx = (shape((1,), i32), shape((rows, tables), i32), shape((rows,), i32),
            shape((rows,), i32))
     sinks = () if window is None else (shape((H,), jnp.float32),)
@@ -1181,10 +1182,57 @@ def test_paged_kernels_compile_at_keys_of_192_beside_values_of_128(shape,
         temp = c.memory_analysis().temp_size_in_bytes
         print(f"mimo {kind} C={C} {name}: temporaries {temp} bytes, "
               f"VMEM asked {pa._vmem_limit(128 // BS, BS, kv * hd, bf, kv, C * rep, hd, 2, bf, kv * hd_v, hd_v) or 'default'}")
-        # in HBM: nothing at a column or five; at the pieces of a chunk of
-        # 512 the folded queries (16 x 512 x 12,288 bf16: 201 MB) and the
-        # output before and after its unfold (2 x 134 MB)
-        assert temp < (1 << 20 if C != 256 else 480 << 20)
+        # in HBM: nothing at a column, at five, or at the pieces (two pieces
+        # of 256 a row held 470 MB of folded queries and outputs)
+        assert temp < 1 << 20
+
+
+@pytest.mark.parametrize(
+    "H,kv,hd,hd_v,C,tables,window",
+    [(32, 8, 64, 64, 512, 128, None), (16, 2, 256, 256, 1024, 512, None),
+     (64, 4, 192, 128, 512, 512, None), (64, 8, 192, 128, 512, 512, 128)],
+    ids=["lfm2", "qwen3next", "mimo_full", "mimo_window"])
+def test_ragged_kernel_compiles_at_the_cells_pieces(shape, H, kv, hd, hd_v, C,
+                                                    tables, window):
+    """``_paged_ragged_fn`` at the layout :func:`query_layout` gives each cell
+    whose rows it cuts (sixteen rows, a step of 16 + C tokens): N kernel rows
+    of P query columns, each over a table of the cell's width.  The VMEM the
+    call asks for covers the scratch the kernel declares, and the queries
+    and the output move no HBM temporaries of their own."""
+    import jax.numpy as jnp
+
+    pa = _paged()
+    bf, i32 = jnp.bfloat16, jnp.int32
+    P, N = pa.query_layout(B + C, B, C, H, hd, kv * hd, bf, Dv=kv * hd_v,
+                           keys=tables * BS)
+    assert P < C and N == (B + C) // P + B
+    kpool = shape((2, 2049, BS, kv * hd), bf)
+    vpool = shape((2, 2049, BS, kv * hd_v), bf)
+    idx = (shape((1,), i32), shape((N, tables), i32), shape((N,), i32),
+           shape((N,), i32))
+    sinks = () if window is None else (shape((H,), jnp.float32),)
+    kw = {"d_true": hd, **({} if window is None else {"window": window})}
+    compiled = _compiled_kernel(
+        lambda *a: pa._paged_ragged_fn(*a, **kw),
+        shape((N, P, H, hd), bf), kpool, vpool, *idx, *sinks)
+    assert _pool_copies(compiled, kpool.shape) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    rep, R = H // kv, P * H // kv
+    hv = None if hd_v == hd else hd_v
+    G = pa._heads_per_group(kv, hd, R, hv)
+    K = pa.span_blocks(BS, tables, math.gcd(kv * hd, kv * hd_v))
+    scratch = sum(
+        math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
+        for s in pa._scratch(K, BS, kv * hd, bf, kv, R, hd, G, bf,
+                             kv * hd_v, hd_v) if hasattr(s, "dtype")
+        and len(s.shape) > 1)
+    asked = pa._vmem_limit(K, BS, kv * hd, bf, kv, R, hd, G, bf, kv * hd_v,
+                           hd_v)
+    limit = asked["compiler_params"].vmem_limit_bytes if asked \
+        else 16 * 2 ** 20  # the compiler's own scoped limit
+    print(f"{H}x{hd} on {kv}: P {P}, N {N}, rep {rep}, scratch "
+          f"{scratch / 2 ** 20:.1f} MiB, limit {limit / 2 ** 20:.0f} MiB")
+    assert scratch < limit <= 100 * 2 ** 20
 
 
 def _mimo_case(shape):

@@ -265,6 +265,30 @@ def test_kernels_and_gather_path_emit_the_same_tokens():
     assert a == b
 
 
+@pytest.mark.parametrize("P", [16, 8])
+def test_kernels_in_pieces_emit_the_gather_paths_tokens(monkeypatch, P):
+    """The family's mixed step with its ragged calls' rows cut from the
+    packed stream in pieces of ``P`` query columns (``query_layout`` steered
+    here, for both layer kinds): the gather path's tokens, to the last."""
+    pa = _pa()
+    cfg = _cfg(head_dim=256, v_head_dim=128, rotary_dim=64,
+               layer_types=(S, F))
+    params = _init(cfg, 1)
+    reqs = _requests(seed=3)[:3]
+    a = _engine(cfg, params, f"t_mimo_gather_{P}").generate_batch(reqs)
+    calls = []
+
+    def pieces(T, B, C, *_a, **_k):
+        calls.append((T, B, C))
+        return P, T // P + B
+
+    monkeypatch.setattr(pa, "query_layout", pieces)
+    b = _engine(cfg, params, f"t_mimo_pieces_{P}",
+                attn="pallas").generate_batch(reqs)
+    assert a == b
+    assert (20, 4, 16) in calls  # the mixed step's: T = B + C
+
+
 def test_tokens_are_the_references_best(cfg, params, clean_tokens):
     for (prompt, _n), tokens in zip(_requests(), clean_tokens):
         want, _m = _reference(params, cfg, prompt, tokens)
@@ -389,13 +413,15 @@ def test_round_build_counts_the_window_pairs_by_hand(cfg, params):
     """``kv_window_band_pairs`` / ``kv_window_span_pairs`` of a mixed round
     against a count by hand: one prompt of 70 tokens alone, a chunk of 32
     over a window of 24, blocks of 8 (a span is a block here: pools of 48
-    lanes are no whole tiles): one tile a row (the width divides no tile),
-    so a chunk row runs its 32 columns over every span from the one that
-    holds its first column's oldest key to the one that holds its last
-    key."""
+    lanes are no whole tiles).  The rule lays both pools out in pieces of 8
+    columns (heads of 24 lanes split tiles); a piece of 8 columns is 32
+    folded ones (rep 4), a first tile of 8 (2 columns) or one wide tile of 32
+    (8 columns), over every span from the one that holds its first column's
+    oldest key to the one that holds its last key."""
     from pathway_tpu import obs
 
     eng = _engine(cfg, params, "t_mimo_pairs", prefill_chunk=32)
+    assert eng._query_layout == (8, 8) and eng._window_pairs["cols"] == 8
     eng.generate(_prompts([70], seed=8)[0], 2)
     builds = [s.attrs for s in obs.recorder().snapshot()
               if s.name == "pw.round.build" and s.attrs
@@ -404,9 +430,14 @@ def test_round_build_counts_the_window_pairs_by_hand(cfg, params):
     hand = []
     for start, n in ((0, 32), (32, 32), (64, 6)):
         band = sum(min(p + 1, WINDOW) for p in range(start, start + n))
-        first = max(start + 1 - WINDOW, 0) // 8
-        last = (start + n - 1) // 8
-        hand.append((band, 32 * (last - first + 1) * 8))
+        run = 0
+        for k in range(-(-n // 8)):
+            cols = min(8, n - 8 * k)
+            c0 = start + 1 + 8 * k
+            first = max(c0 - WINDOW, 0) // 8
+            last = (c0 + cols - 2) // 8
+            run += (2 if cols <= 2 else 8) * (last - first + 1) * 8
+        hand.append((band, run))
     got = [(a["kv_window_band_pairs"], a["kv_window_span_pairs"])
            for a in builds[:3]]
     assert got == hand, (got, hand)
@@ -420,24 +451,56 @@ def test_round_build_counts_the_window_pairs_by_hand(cfg, params):
     assert wide.pool.stats.snapshot()["kv_window_span_pairs"] == 0
 
 
+def test_round_build_counts_the_query_slots_of_the_pieces(cfg, params):
+    """``kv_query_cols`` / ``kv_query_slots`` / ``kv_query_tile_cols`` of a
+    mixed round in pieces, by hand: the toy's full pool (8 query heads of 24
+    on one K/V head: rep 8, heads that split tiles) at a chunk of 32 and
+    four rows is N = 36 // 8 + 4 = 8 kernel rows of P = 8 columns, 64 slots
+    whatever the round holds; a piece of 8 columns is 64 folded ones, a
+    first tile of 8 (one column) or a wide one of 64 (eight); a kernel row
+    past the rows' pieces runs one column's tile.  The slots reach the
+    pool's counter and ``/metrics``."""
+    from pathway_tpu.serve import metrics as serve_metrics
+
+    class _Ph:
+        def set(self, **attrs):
+            self.attrs = attrs
+
+    eng = _engine(cfg, params, "t_mimo_slots", prefill_chunk=32)
+    assert eng._query_layout == (8, 8)
+    for rows, cols, tiles in (([1, 1, 9, 32], 43, 1 + 1 + 9 + 32),
+                              ([1, 1, 1, 1], 4, 4 + 4)):
+        ph = _Ph()
+        eng._note_query_cols(ph, np.asarray(rows, np.int32))
+        assert ph.attrs == {"kv_query_cols": cols, "kv_query_slots": 64,
+                            "kv_query_tile_cols": tiles}
+    assert eng.pool.stats.snapshot()["kv_query_slots"] == 128
+    assert 'pathway_kv_query_slots_total{pool="t_mimo_slots"} 128' \
+        in serve_metrics.render_prometheus_lines()
+
+
 def test_window_pairs_walks_the_kernels_pieces_and_tiles():
     """At the cell's geometry (64 query heads of 192 on 8 K/V heads, a chunk
-    of 512 in two pieces of 256, tiles of 8 / 128 columns, spans of 128
-    keys, a window of 128): a whole chunk from position 1,024 sees 128 keys
-    a column and computes three spans a piece; a decode row at context
-    3,000 sees 128 and computes a tile of one column over two spans; an
-    idle piece costs one tile over one span."""
+    of 512 the rule lays out in pieces of 64 columns, tiles of 8 / 128
+    folded columns, spans of 128 keys, a window of 128): a whole chunk from
+    position 1,024 sees 128 keys a column and computes two spans a piece of
+    64 columns; a decode row at context 3,000 sees 128 and computes one
+    column's tile over two spans (no dead piece of its own: the rows'
+    pieces are their live columns')."""
     import jax.numpy as jnp
 
     pa = _pa()
-    args = (512, 64, 192, 8 * 192, jnp.bfloat16, 128, 128)
-    band, run = pa.window_pairs([1024], [512], *args, hd_v=128)
+    bf = jnp.bfloat16
+    assert pa.query_layout(528, 16, 512, 64, 192, 8 * 192, bf, Dv=8 * 128,
+                           keys=8192) == (64, 24)
+    args = (512, 64, 192, 8 * 192, bf, 128, 128)
+    band, run = pa.window_pairs([1024], [512], *args, hd_v=128, cols=64)
     assert band == 512 * 128
-    assert run == 2 * 256 * 3 * 128
-    band, run = pa.window_pairs([2999], [1], *args, hd_v=128)
-    first = pa._col_tiles(2, 256 * 8, 8, jnp.bfloat16)[0] // 8
-    assert band == 128
-    assert run == first * 2 * 128 + first * 128
+    assert run == 8 * 64 * 2 * 128
+    band, run = pa.window_pairs([2999], [1], *args, hd_v=128, cols=64)
+    first = pa._col_tiles(2, 64 * 8, 8, bf)[0] // 8
+    assert band == 128 and first == 1
+    assert run == first * 2 * 128
 
 
 # -- the cache with a window pool of its own geometry ------------------------------
@@ -606,11 +669,12 @@ def test_the_writer_takes_two_row_widths():
 
 
 def test_a_long_row_is_attended_in_pieces(monkeypatch):
-    """``query_pieces``: one kernel row while the row's scratch and blocks
-    and the room over them fit the most a call asks of VMEM (every geometry
-    the benchmark had, at its cell's chunk: sixteen heads of 256 at 1,024
-    ask 96.5 of the 100 MiB), halves past it; a row cut in pieces reads
-    what the whole row reads."""
+    """Rows handed to ``paged_attention`` whole (B, C, H, hd): the rule keeps
+    them while a row's scratch and blocks and the room over them fit the
+    most a call asks of VMEM (every older cell's geometry at its chunk:
+    sixteen heads of 256 at 1,024 ask 96.5 of the 100 MiB), and cuts them
+    from a stream of B x C tokens past it (64 heads of 192 beside 128 at
+    512); a row cut in pieces reads what the whole row reads."""
     import jax.numpy as jnp
 
     pa = _pa()
@@ -623,7 +687,7 @@ def test_a_long_row_is_attended_in_pieces(monkeypatch):
         + pa._VMEM_ROOM == 101187584 <= pa._VMEM_CAP
     for kv in (4, 8):  # the full layers' K/V heads, the sliding layers'
         assert pa.query_pieces(256, 64, 192, kv * 192, bf, 128) == 1
-        assert pa.query_pieces(512, 64, 192, kv * 192, bf, 128) == 2
+        assert pa.query_pieces(512, 64, 192, kv * 192, bf, 128) > 1
     rng = np.random.default_rng(5)
     B, C, H, KV, hd, BS, NB = 2, 64, 4, 2, 128, 16, 8
     kp = jnp.asarray(rng.standard_normal((B * NB + 1, BS, KV * hd)),
@@ -639,7 +703,9 @@ def test_a_long_row_is_attended_in_pieces(monkeypatch):
     monkeypatch.setattr(pa, "_VMEM_CAP", pa._vmem_need(
         1, 128, KV * hd, jnp.float32, KV, C // 4 * (H // KV), hd, 1,
         jnp.float32))
-    assert pa.query_pieces(C, H, hd, KV * hd, jnp.float32) == 4
+    P, N = pa.query_layout(B * C, B, C, H, hd, KV * hd, jnp.float32,
+                           keys=NB * BS)
+    assert P <= C // 4 and N == B * C // P + B
     got = np.asarray(pa.paged_attention(
         q, kp, kp, bt, start_pos=sp, n_valid=nv, use_pallas=True,
         interpret=True, window=24))

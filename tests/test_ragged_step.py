@@ -916,6 +916,223 @@ def test_dispatched_kernels_at_the_cells_chunks_are_the_parents(case):
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == parent
 
 
+# -- the packed dispatch: kernel rows cut from the stream (PR 41) -------------
+
+# A mixed step as ``_build_mixed`` packs it, (start, valid columns) a row: a
+# decode row deep in its sequence, a chunk whose 37 columns cross piece
+# boundaries, an idle row (context 1 on the null block), a decode row near
+# its start, a short chunk inside one piece, two idle rows; T = B + C = 55
+# tokens (no multiple of the pieces), 48 of them live, the rest padding.
+_STREAM_ROWS = [(90, 1), (17, 37), _IDLE, (5, 1), (0, 9), _IDLE, _IDLE]
+
+
+def _packed_case(rng, rows, C, H, KV, hd, hd_v, dtype, BS=16, NB=8):
+    """(q (T, H, hd), K and V pools (1, blocks, BS, lanes), tables, start,
+    valid, row_token_idx, tok_row, tok_col, live tokens)."""
+    B, T = len(rows), len(rows) + C
+    idx = np.zeros((B, C), np.int32)
+    tok_row, tok_col = np.zeros(T, np.int32), np.zeros(T, np.int32)
+    tables = np.zeros((B, NB), np.int32)
+    t = 0
+    for b, (start, n) in enumerate(rows):
+        if (start, n) == _IDLE:  # no token of the stream: its first
+            continue
+        idx[b, :n], idx[b, n:] = np.arange(t, t + n), t + n - 1
+        tok_row[t:t + n], tok_col[t:t + n] = b, np.arange(n)
+        tables[b] = 1 + b * NB + rng.permutation(NB)
+        t += n
+    blocks = 1 + B * NB
+
+    def draw(*dims):
+        return jnp.asarray(rng.standard_normal(dims), dtype)
+
+    return (draw(T, H, hd), draw(1, blocks, BS, KV * hd),
+            draw(1, blocks, BS, KV * hd_v), jnp.asarray(tables),
+            jnp.asarray([r[0] for r in rows], jnp.int32),
+            jnp.asarray([r[1] for r in rows], jnp.int32), jnp.asarray(idx),
+            jnp.asarray(tok_row), jnp.asarray(tok_col), t)
+
+
+_PIECE_CASES = {
+    # query heads, K/V heads, head, value head, window, sinks: each cell's
+    # head geometry (its rep, its lanes a head) at a chunk of 48 columns
+    "mimo_full_rep16": (32, 2, 192, 128, None, False),
+    "mimo_sliding_rep8_sinks": (16, 2, 192, 128, 24, True),
+    "qwen3next_hd256": (16, 2, 256, 256, None, False),
+    "trinity_window": (16, 2, 128, 128, 40, False),
+    "lfm2_rep4": (8, 2, 64, 64, None, False),
+    "gpt2_rep1": (4, 4, 64, 64, None, False),
+}
+
+
+@pytest.mark.parametrize("case", list(_PIECE_CASES))
+def test_piece_dispatch_is_the_row_dispatch_bit_for_bit(case):
+    """The kernel rows cut from the packed stream in pieces of 16 and of 8
+    query columns (``_pieces``; N = T // P + B of them) give every live
+    token the bits the rows at the chunk's width give it (the same spans in
+    the same order: a span behind a piece's window leaves its columns'
+    softmax as it was), and both the gather reference's values, at every
+    cell's head geometry, interpreted, in f32."""
+    pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
+    H, KV, hd, hd_v, window, sinks = _PIECE_CASES[case]
+    C, f32 = 48, jnp.float32
+    rng = np.random.default_rng(41)
+    q, kp, vp, tables, sp, nv, idx, tr, tc, t = _packed_case(
+        rng, _STREAM_ROWS, C, H, KV, hd, hd_v, f32)
+    snk = jnp.asarray(rng.standard_normal(H), f32) if sinks else None
+    kw = {} if window is None else {"window": window}
+    want = np.asarray(pa.paged_attention(
+        q, kp[0], vp[0], tables, start_pos=sp, n_valid=nv,
+        packed=(idx, tr, tc), use_pallas=False, sinks=snk, **kw))[:t]
+    B, T = len(_STREAM_ROWS), q.shape[0]
+    got = {}
+    for P in (C, 16, 8):
+        layout = (C, B) if P == C else (P, T // P + B)
+        got[P] = np.asarray(pa._ragged_packed(
+            q, kp, vp, jnp.zeros((1,), jnp.int32), tables, sp, nv, idx, tr,
+            tc, snk, window=window, interpret=True, layout=layout))[:t]
+        np.testing.assert_allclose(got[P], want, rtol=2e-5, atol=2e-5)
+    for P in (16, 8):
+        assert (got[P].view(np.uint32) == got[C].view(np.uint32)).all()
+    # the dispatch itself, in the layout its rule gives these shapes
+    rule = np.asarray(pa.paged_attention(
+        q, kp, vp, tables, start_pos=sp, n_valid=nv, packed=(idx, tr, tc),
+        layer=0, use_pallas=True, interpret=True, sinks=snk, **kw))[:t]
+    assert (rule.view(np.uint32) == got[C].view(np.uint32)).all()
+
+
+def test_pieces_by_hand():
+    """``_pieces`` at P = 16 over ``_STREAM_ROWS``: each row's pieces in row
+    order (the 37-column chunk in three, the others in one), the spare
+    kernel rows idle at context 1, a piece's columns its row's tokens
+    (padding: the row's last), its contexts ``_row_pieces``' rule, and the
+    way back to every packed token."""
+    pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
+    rng = np.random.default_rng(0)
+    *_, tables, sp, nv, idx, tr, tc, t = _packed_case(
+        rng, _STREAM_ROWS, 48, 2, 2, 64, 64, jnp.float32)
+    P, N = 16, 55 // 16 + 7
+    row, tok, c0, cl, piece, col = (np.asarray(x) for x in jax.tree.leaves(
+        pa._pieces(sp, nv, idx, tr, tc, P, N)))
+    assert row.tolist() == [0, 1, 1, 1, 2, 3, 4, 5, 6] + [6]
+    assert c0.tolist() == [91, 18, 34, 50, 1, 6, 1, 1, 1, 1]
+    assert cl.tolist() == [91, 33, 49, 54, 1, 6, 9, 1, 1, 1]
+    assert tok[0].tolist() == [0] * P                   # a decode row
+    assert tok[1].tolist() == list(range(1, 17))        # the chunk's first
+    assert tok[3].tolist() == [33, 34, 35, 36, 37] + [37] * 11
+    assert tok[6].tolist() == list(range(39, 48)) + [47] * 7
+    assert piece[:t].tolist() == [0] + [1] * 16 + [2] * 16 + [3] * 5 \
+        + [5] + [6] * 9
+    assert col[:t].tolist() == [0] + list(range(16)) * 2 + list(range(5)) \
+        + [0] + list(range(9))
+    # the rows' pieces never outgrow N: a row of n columns takes ceil(n/P)
+    # and the rows hold at most T + B columns
+    for n in ([48] + [1] * 6, [16, 16, 16] + [1] * 4, [1] * 7):
+        assert sum(-(-k // P) for k in n) <= N
+
+
+# The rule's layout at each cell's geometry (sixteen rows, T = B + the
+# chunk, bf16, the cell's table): the widths a chip sweep of one ragged call
+# ranks within 4% of the fastest (PERF.md section 6, PR 41), the rows where
+# pieces do not pay.  (query heads, K/V heads, head, value head, chunk,
+# table keys) -> (P, N).
+_CELL_LAYOUTS = {
+    "gpt2_256": ((20, 20, 64, 64, 256, 1024), (256, 16)),
+    "lfm2_512": ((32, 8, 64, 64, 512, 2048), (64, 24)),
+    "trinity_256": ((32, 4, 128, 128, 256, 8192), (256, 16)),
+    "qwen3next_1024": ((16, 2, 256, 256, 1024, 8192), (256, 20)),
+    "mimo_full_512": ((64, 4, 192, 128, 512, 8192), (64, 24)),
+    "mimo_window_512": ((64, 8, 192, 128, 512, 8192), (64, 24)),
+}
+
+
+@pytest.mark.parametrize("case", list(_CELL_LAYOUTS))
+def test_query_layout_at_the_cells_geometries(case):
+    pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
+    (H, kv, hd, hd_v, C, keys), want = _CELL_LAYOUTS[case]
+    bf = jnp.bfloat16
+    got = pa.query_layout(16 + C, 16, C, H, hd, kv * hd, bf, Dv=kv * hd_v,
+                          keys=keys)
+    assert got == want
+    P, N = got
+    # the pieces never outgrow N, and whole sublane tiles of bf16
+    assert N == 16 or (N == (16 + C) // P + 16 and P % 16 == 0)
+    # a stream of B x C tokens (the row form) never takes pieces where the
+    # rows fit VMEM
+    if P == C:
+        assert pa.query_layout(16 * C, 16, C, H, hd, kv * hd, bf,
+                               Dv=kv * hd_v, keys=keys) == (C, 16)
+
+
+# The ragged kernel as ``paged_attention`` dispatches a packed step at each
+# cell's own geometry: where the rule keeps the rows, the kernel of PR 40's
+# parent (sha256 of its jaxpr as above, sixteen rows and the cell's table,
+# hashed from the parent's checkout), else a block of P x rep folded columns
+# a kernel row.
+_PACKED_DISPATCH = {
+    "gpt2_256": ((20, 20, 64, None, 256, 64), "6797903cc8f4a7fe",
+                 (16, 256, 1280)),
+    "trinity_256": ((32, 4, 128, None, 256, 512), "c17ef3b42a7204dc",
+                    (16, 2048, 512)),
+    "trinity_window_256": ((32, 4, 128, 2048, 256, 512), "e8718f2d1b2aac07",
+                           (16, 2048, 512)),
+    "lfm2_512": ((32, 8, 64, None, 512, 128), None, (24, 256, 512)),
+    "qwen3next_1024": ((16, 2, 256, None, 1024, 512), None, (20, 2048, 512)),
+}
+
+
+@pytest.mark.parametrize("case", list(_PACKED_DISPATCH))
+def test_packed_dispatch_at_the_cells_geometries(case):
+    import hashlib
+    import re
+
+    from jax._src.interpreters import partial_eval as pe
+
+    pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
+
+    def S(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    (H, kv, hd, window, C, NB), parent, block = _PACKED_DISPATCH[case]
+    B, BS, bf = 16, 16, jnp.bfloat16
+    T = B + C
+    pool = S((2, 41, BS, kv * hd), bf)
+    kw = {} if window is None else {"window": window}
+    closed = jax.make_jaxpr(
+        lambda q, k, v, bt, sp, nv, idx, tr, tc: pa.paged_attention(
+            q, k, v, bt, start_pos=sp, n_valid=nv, packed=(idx, tr, tc),
+            layer=1, use_pallas=True, interpret=True, **kw))(
+        S((T, H, hd), bf), pool, pool, S((B, NB)), S((B,)), S((B,)),
+        S((B, C)), S((T,)), S((T,)))
+    (call,) = _pallas_calls(closed.jaxpr)
+    assert call.outvars[0].aval.shape == block
+    assert closed.out_avals[0].shape == (T, H, hd)
+    if parent is not None:
+        kernel = call.params["jaxpr"]
+        kernel, _ = pe.dce_jaxpr(kernel, [True] * len(kernel.outvars))
+        text = re.sub(r" at [^ ]*:\d+", "", str(kernel))
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == parent
+
+
+@pytest.mark.parametrize(
+    "n,C,cols,want",
+    [([1, 1, 9, 32, 64], 512, 64, [2, 2, 32, 32, 64]),
+     ([1, 65, 130], 512, 64, [2, 64 + 2, 64 + 64 + 2]),
+     ([1, 9, 64], 64, None, [2, 32, 64])],
+    ids=["one_piece_a_row", "rows_in_pieces", "the_row_whole"])
+def test_query_tile_columns_walk_the_pieces_by_hand(n, C, cols, want):
+    """``query_tile_columns`` with the layout's piece (``cols``) at LFM2's
+    heads (two heads of 64 a group, rep 4, bf16): a piece of 64 columns is
+    256 folded ones, a first tile of 8 (2 columns) or wide tiles of 128 (32
+    columns); a row's pieces are its live columns' only (65 columns: a full
+    piece and one of one column), each with its own tiles."""
+    pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
+    bf = jnp.bfloat16
+    assert pa._col_tiles(2, 64 * 4, 4, bf) == (8, 128)
+    got = pa.query_tile_columns(np.asarray(n), C, 32, 64, 512, bf, cols=cols)
+    assert [int(g) for g in got] == want
+
+
 # -- the mixed step's K/V writer ---------------------------------------------
 
 # Streams as ``_build_mixed`` packs them, (block, offset) a token over

@@ -594,8 +594,9 @@ def test_build_counts_the_query_columns_its_tiles_cover(wide_params):
 
     ph, rows = _Ph(), np.array([1, 1, 9, 64], np.int32)
     eng._note_query_cols(ph, rows)
+    assert eng._query_layout == (64, 4)  # rep 1: the rule keeps the rows
     assert ph.attrs == {
-        "kv_query_cols": 75,
+        "kv_query_cols": 75, "kv_query_slots": 4 * 64,
         "kv_query_tile_cols": int(pa.query_tile_columns(
             rows, 64, 8, 64, 512, eng._pool_kwargs["dtype"]).sum())}
     assert ph.attrs["kv_query_tile_cols"] == 8 + 8 + 64 + 64
