@@ -627,29 +627,27 @@ class PagedDecodeEngine:
         # blocks of 16), for ``kv_key_lanes`` on ``pw.round.build``
         lanes = self.pool.k.shape[-1] // self.tp
         self._span_keys = bs * span_blocks(bs, self.max_blocks_per_seq, lanes)
-        # how a mixed step's ragged calls lay the rows out on the full pool
-        # (``query_layout``'s ``(P, N)``: N kernel rows of P query columns;
-        # a latent pool, which has no V, keeps its rows, in pieces of its
-        # own), and the query columns the live tiles of a row cover, by its
-        # valid columns 0 .. chunk (the kernels' own tile rule, on a shard's
-        # query heads and pool lanes), for ``kv_query_slots`` and
-        # ``kv_query_tile_cols``
+        # how a mixed step's ragged or latent calls lay the rows out on the
+        # full pool (``query_layout``'s ``(P, N)``: N kernel rows of P query
+        # columns; a latent pool has no V), and the query columns the live
+        # tiles of a row cover, by its valid columns 0 .. chunk (the kernels'
+        # own tile rule, on a shard's query heads and pool lanes), for
+        # ``kv_query_slots`` and ``kv_query_tile_cols``
         B, C, H = self.max_batch_size, self.prefill_chunk, cfg.n_heads // self.tp
         hd, hd_v = self._pool_kwargs["head_dim"], \
             self._pool_kwargs.get("v_head_dim")
-        latent, dtype = self.pool.v is None, self._pool_kwargs["dtype"]
+        dtype = self._pool_kwargs["dtype"]
         keys = self.max_blocks_per_seq * bs
-        self._query_layout = (C, B) if latent else query_layout(
+        self._query_layout = query_layout(
             self.mixed_tokens, B, C, H, hd, lanes, dtype,
-            Dv=self.pool.v.shape[-1] // self.tp, keys=keys)
-        cols = None if latent else self._query_layout[0]
+            Dv=0 if self.pool.v is None else self.pool.v.shape[-1] // self.tp,
+            keys=keys)
+        cols = self._query_layout[0]
         self._query_tile_cols = query_tile_columns(
-            np.arange(C + 1), C, H, hd, lanes, dtype, latent=latent,
-            hd_v=hd_v, cols=cols)
+            np.arange(C + 1), C, H, hd, lanes, dtype, hd_v=hd_v, cols=cols)
         # a kernel row past the rows' pieces runs one column's first tile
         self._idle_tile_cols = int(query_tile_columns(
-            [1], C, H, hd, lanes, dtype, latent=latent, hd_v=hd_v,
-            cols=cols)[0])
+            [1], C, H, hd, lanes, dtype, hd_v=hd_v, cols=cols)[0])
         # on a windowed cache whose window is narrower than a chunk: the
         # window pool's geometry and layout as ``window_pairs`` takes them,
         # for ``kv_window_band_pairs`` / ``kv_window_span_pairs``
@@ -2091,12 +2089,14 @@ class PagedDecodeEngine:
             self.pool.stats.record_window_keys(seen, keys)
 
     def _note_query_cols(self, ph, row_nvalid) -> None:
-        """What a mixed round's calls of the ragged kernels run on the full
-        pool, on ``pw.round.build``: ``kv_query_cols``, the live query
-        columns of ALL the step's rows (an idle row has one: the kernel
-        runs it); ``kv_query_slots``, the query slots the dispatch lays out
-        for them (:func:`query_layout`: N kernel rows of P columns, B x C
-        where it keeps the rows), also in the pool's counters; and
+        """What a mixed round's calls of the ragged kernels (the latent
+        kernel on a latent pool) run on the full pool, on
+        ``pw.round.build``: ``kv_query_cols``, the live query columns of ALL
+        the step's rows (an idle row has one: the kernel runs it);
+        ``kv_query_slots``, the query slots the dispatch lays out for them
+        (:func:`query_layout`, which lays the K/V pools and the latent pool
+        out alike: N kernel rows of P columns, B x C where it keeps the
+        rows), also in the pool's counters; and
         ``kv_query_tile_cols``, the columns the kernel rows' live tiles
         cover (:func:`query_tile_columns`; a kernel row past the rows'
         pieces runs one column's).  A decode step's or a chain's rows are

@@ -107,18 +107,21 @@ the long configurations a third of their step.  :func:`paged_attention`
 takes the packed stream and cuts the kernel's rows from it
 (:func:`_pieces`, on the device from the step's own arrays): a row of ``n``
 valid columns is ``ceil(n / P)`` kernel rows of ``P`` columns each, over
-the row's table, its columns' contexts from the piece's first
-(:func:`_row_pieces`' rule), and only ``N = T // P + B`` of them (the rows'
-at most T + B columns never need more), the output gathered back to the
-stream.  ``P`` is a rule of shapes (:func:`query_layout`: a kernel row
-costs its query slots' bytes, which cross HBM :data:`_SLOT_PASSES` times,
-and its walk of a table; P is the cheapest width of whole sublane tiles
-that fits VMEM, the rows as they are where they cost no more - GPT-2's
-cell).  The kernel does not change: it sees rows of P columns.  A query
-column sees the same spans in the same order as in its row (a span behind a
-piece's window, which the row still walked, is all masked for the piece's
-columns and leaves their softmax as it was), so the outputs are the rows'
-bit for bit.  Under a window a piece's dead spans are its own, so a chunk
+the row's table, its columns' contexts from the piece's first, and only
+``N = T // P + B`` of them (the rows' at most T + B columns never need
+more), the output gathered back to the stream.  ``P`` is a rule of shapes
+(:func:`query_layout`: a kernel row costs its query slots' bytes, which
+cross HBM once, :data:`_SPLIT_TRIPS` times where the fold splits lane
+tiles, and its walk of a table; P is the cheapest width of whole sublane
+tiles that fits VMEM, the rows as they are where they cost no more -
+GPT-2's cell).  The kernel does not change: it sees rows of P columns.
+:func:`latent_attention` lays the latent pool's call out by the same rule
+and the same pieces, through the same dispatch (:func:`_dispatch`; a pool
+of one array: its walk is one array's, its output a query head wide).  A
+query column sees the same spans in the same order as in its row (a span
+behind a piece's window, which the row still walked, is all masked for the
+piece's columns and leaves their softmax as it was), so the outputs are the
+rows' bit for bit.  Under a window a piece's dead spans are its own, so a chunk
 wider than the window computes the spans its piece's columns see, not the
 chunk's (:func:`window_pairs` counts both for the engine).
 
@@ -451,40 +454,33 @@ def _live_columns(b, c0_ref, cl_ref, rep: int, tiles: tuple | None):
 
 
 def query_tile_columns(n_valid, C: int, H: int, hd: int, D: int, dtype,
-                       latent: bool = False, hd_v: int | None = None,
-                       cols: int | None = None):
+                       hd_v: int | None = None, cols: int | None = None):
     """The query columns the kernels' live tiles cover for rows of
     ``n_valid`` live columns each (an idle row has one) of ``C``, ``H``
     query heads of ``hd`` over a pool of ``D`` lanes, queries in ``dtype``
-    (``latent``: through :func:`latent_attention`, a row in pieces;
-    ``hd_v``: a V head of another width; ``cols``: the ``P`` of
-    :func:`query_layout`, a row in pieces of its live columns): the rule of
-    :func:`_col_tiles` as :func:`_live_tiles` runs it (:func:`_wide_tiles`),
-    for the engine's ``kv_query_tile_cols``.  (len(n_valid),) int64."""
-    return _piece_tile_columns(n_valid, C, H, hd, D, dtype, latent,
-                               hd_v, cols)[0].sum(axis=1)
+    (``hd_v``: a V head of another width; ``cols``: the ``P`` of
+    :func:`query_layout`, a row in pieces of its live columns - on the K/V
+    pools and on the latent pool alike): the rule of :func:`_col_tiles` as
+    :func:`_live_tiles` runs it (:func:`_wide_tiles`), for the engine's
+    ``kv_query_tile_cols``.  (len(n_valid),) int64."""
+    return _piece_tile_columns(n_valid, C, H, hd, D, dtype, hd_v,
+                               cols)[0].sum(axis=1)
 
 
 def _piece_tile_columns(n_valid, C: int, H: int, hd: int, D: int, dtype,
-                        latent: bool = False, hd_v: int | None = None,
-                        cols: int | None = None):
+                        hd_v: int | None = None, cols: int | None = None):
     """``(run, cols)``: the query columns the live tiles of each piece of
-    each row cover ((rows, pieces) int64; 0 where a row has no such piece)
-    and a piece's width.  A latent row is C // cols pieces whatever it
-    holds, one past its valid columns running one column; a row of the
-    K/V pools is the ``ceil(n / cols)`` pieces of its live columns."""
+    each row cover ((rows, pieces) int64; 0 where a row has no such piece:
+    a row is the ``ceil(n / cols)`` pieces of its live columns) and a
+    piece's width."""
     n = np.asarray(n_valid, np.int64)
-    if latent:
-        cols = C // _latent_pieces(C)
     cols = C if cols is None else cols
     pieces = -(-C // cols)
     rep = H * hd // D
     tiles = _col_tiles(_heads_per_group(D // hd, hd, cols * rep, hd_v),
                        cols * rep, rep, dtype)
-    # a piece's live columns; a latent piece past the row's valid ones runs
-    # one
-    live = np.clip(n[:, None] - cols * np.arange(pieces)[None, :],
-                   1 if latent else 0, cols) * rep
+    live = np.clip(n[:, None] - cols * np.arange(pieces)[None, :], 0,
+                   cols) * rep
     if tiles is None:
         run = np.full_like(live, cols * rep)
     else:
@@ -1248,50 +1244,6 @@ _paged_write = jax.jit(_paged_write_fn, static_argnames=("interpret",),
                        donate_argnums=(2, 3))
 
 
-# -- the latent pool (PR 33): key and value are one stored row ---------------
-#
-# Multi-head latent attention in its absorbed form: a token leaves one row
-# in the cache, ``[c (kv_lora_rank) ; k_r (qk_rope_head_dim) ; 0]`` padded to
-# whole lane tiles (``W`` lanes: 512 + 64 + 64 of padding at the published
-# widths), every query head attends that one row (the key is the whole row,
-# the value the same row: the lanes past ``c`` come out as the scores' mix of
-# ``k_r`` and of zeros, and the caller drops them), and the per-head halves
-# of ``W_kv_b`` are applied to the queries before and to the mix after.  To
-# the kernels that is grouped-query attention with ONE K/V head of ``W``
-# lanes and all query heads folded on it as neighbouring columns
-# (:func:`_fold_queries`), on a pool that is one array: the kernels below
-# are the paged ones (:func:`_next_span`, :func:`_attend_span`: the same
-# span grid, gather and online softmax) with one pool operand, one span
-# buffer and one aliased output.  Their jitted functions are named
-# ``_paged_latent_fn`` / ``_paged_latent_append_fn`` /
-# ``_paged_latent_write_fn`` for the device trace.
-
-_LATENT_COLS = 64  # query columns a kernel row takes of a longer chunk
-
-
-def _latent_pieces(C: int) -> int:
-    """The kernel rows :func:`latent_attention` cuts a row of ``C`` query
-    columns into."""
-    return C // _LATENT_COLS if C > _LATENT_COLS and C % _LATENT_COLS == 0 \
-        else 1
-
-
-def _row_pieces(q, tables, c0, cl, n: int) -> tuple:
-    """Rows of ``C`` query columns as ``n`` kernel rows of ``C // n`` each
-    over the same table: piece i of a row holds its columns from ``i * C //
-    n`` on; one past the row's valid columns attends one key and is
-    dropped."""
-    B, C = q.shape[:2]
-    cols = C // n
-    first = c0[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :] * cols
-    live = first <= cl[:, None]
-    c0 = jnp.where(live, first, 1).reshape(B * n)
-    cl = jnp.where(live, jnp.minimum(cl[:, None], first + cols - 1),
-                   1).reshape(B * n)
-    return (q.reshape(B * n, cols, *q.shape[2:]),
-            jnp.repeat(tables, n, axis=0), c0, cl)
-
-
 def query_pieces(C: int, H: int, hd: int, D: int, dtype,
                  hd_v: int | None = None) -> int:
     """The kernel rows :func:`query_layout` makes of ONE row of ``C`` query
@@ -1308,9 +1260,11 @@ _SPLIT_TRIPS = 5  # HBM round trips of a slot where the fold splits lane tiles
 def query_layout(T: int, B: int, C: int, H: int, hd: int, D: int, dtype, *,
                  Dv: int | None = None, keys: int,
                  pool_dtype=None) -> tuple:
-    """``(P, N)``: how :func:`paged_attention` lays a packed step of ``T``
-    tokens in ``B`` rows of ``C`` columns out for the ragged kernel - ``H``
-    query heads of ``hd`` over pools of ``D`` key and ``Dv`` value lanes,
+    """``(P, N)``: how :func:`paged_attention` and :func:`latent_attention`
+    lay a packed step of ``T`` tokens in ``B`` rows of ``C`` columns out for
+    their kernel - ``H`` query heads of ``hd`` over pools of ``D`` key and
+    ``Dv`` value lanes (``Dv = 0``: no V pool, the K pool's rows are the
+    values too and the output is a query head wide - the latent pool),
     tables of ``keys`` keys; ``(C, B)`` where it keeps the rows, else ``N =
     T // P + B`` kernel rows of ``P`` query columns (:func:`_pieces`: a row
     of ``n`` columns takes ``ceil(n / P)``, so the rows' at most ``T + B``
@@ -1322,15 +1276,15 @@ def query_layout(T: int, B: int, C: int, H: int, hd: int, D: int, dtype, *,
     written), and :data:`_SPLIT_TRIPS` where the fold splits lane tiles
     (``rep`` query heads a K/V head of a width that is no whole lane tiles:
     the fold and the unfold are then relayouts, each way, not moves of whole
-    tiles).  A walk is ``keys x (D + Dv)`` values: a grid row reads its live
-    spans, steps through its dead ones, and a piece of a chunk row reads its
-    row's keys again.  P is the cheapest of C and the widths of whole
-    sublane tiles under it that fit VMEM (:func:`_vmem_need` and the room
-    :func:`_vmem_limit` asks, under :data:`_VMEM_CAP`: 64 heads of 192
-    beside 128 at 512 columns do not); the rows where they cost no more.
-    The cells' geometries (B = 16, T = B + C, bf16;
-    ``tests/test_ragged_step.py`` pins them), as a ragged call's time at
-    each width on the chip ranks them (PERF.md section 6, PR 41):
+    tiles).  A walk is ``keys x (D + Dv)`` values (one array's on the
+    latent pool): a grid row reads its live spans, steps through its dead
+    ones, and a piece of a chunk row reads its row's keys again.  P is the
+    cheapest of C and the widths of whole sublane tiles under it that fit
+    VMEM (:func:`_vmem_need` and the room :func:`_vmem_limit` asks, under
+    :data:`_VMEM_CAP`: 64 heads of 192 beside 128 at 512 columns do not);
+    the rows where they cost no more.  The cells' geometries (B = 16, T = B
+    + C, bf16; ``tests/test_ragged_step.py`` pins them), as a call's time at
+    each width on the chip ranks them (PERF.md section 6):
 
     - GPT-2-large, 20 heads of 64, rep 1, C 256, 1,024 keys: the rows;
     - LFM2, 32 heads of 64 on 8, C 512, 2,048 keys: P 64;
@@ -1338,10 +1292,12 @@ def query_layout(T: int, B: int, C: int, H: int, hd: int, D: int, dtype, *,
       and window layers;
     - Qwen3-Next, 16 heads of 256 on 2, C 1,024, 8,192 keys: P 256;
     - MiMo-V2-Flash, 64 heads of 192 (values 128) on 4 (full layers) and 8
-      (window layers), C 512, 8,192 keys: P 64 on both."""
+      (window layers), C 512, 8,192 keys: P 64 on both;
+    - Kimi-Linear's latent pool, 32 heads folded on one stored row of 640
+      lanes, C 512, 8,192 keys: P 64 (N 24)."""
     Dv = D if Dv is None else Dv
     Hkv = D // hd
-    rep, hd_v = H // Hkv, Dv // Hkv
+    rep, hd_v = H // Hkv, Dv // Hkv or hd
     split = rep > 1 and hd % _LANES != 0
     slot = H * (hd + hd_v) * jnp.dtype(dtype).itemsize \
         * (_SPLIT_TRIPS if split else 1)
@@ -1350,8 +1306,8 @@ def query_layout(T: int, B: int, C: int, H: int, hd: int, D: int, dtype, *,
     def fits(cols: int) -> bool:
         R = cols * rep
         G = _heads_per_group(Hkv, hd, R, None if hd_v == hd else hd_v)
-        return _vmem_need(1, _LANES, D, dtype, Hkv, R, hd, G, dtype, Dv,
-                          hd_v) + _VMEM_ROOM <= _VMEM_CAP
+        return _vmem_need(1, _LANES, D, dtype, Hkv, R, hd, G, dtype,
+                          Dv or D, hd_v) + _VMEM_ROOM <= _VMEM_CAP
 
     sub = _sublanes(dtype)
     widths = [C] + [sub << i for i in range((C // sub).bit_length())
@@ -1364,6 +1320,26 @@ def query_layout(T: int, B: int, C: int, H: int, hd: int, D: int, dtype, *,
             best = (cost, P, N)
     return best[1:]
 
+
+# -- the latent pool: key and value are one stored row ----------------------
+#
+# Multi-head latent attention in its absorbed form: a token leaves one row
+# in the cache, ``[c (kv_lora_rank) ; k_r (qk_rope_head_dim) ; 0]`` padded to
+# whole lane tiles (``W`` lanes: 512 + 64 + 64 of padding at the published
+# widths), every query head attends that one row (the key is the whole row,
+# the value the same row: the lanes past ``c`` come out as the scores' mix of
+# ``k_r`` and of zeros, and the caller drops them), and the per-head halves
+# of ``W_kv_b`` are applied to the queries before and to the mix after.  To
+# the kernels that is grouped-query attention with ONE K/V head of ``W``
+# lanes and all query heads folded on it as neighbouring columns
+# (:func:`_fold_queries`), on a pool that is one array: the kernels below
+# are the paged ones (:func:`_next_span`, :func:`_attend_span`: the same
+# span grid, gather and online softmax) with one pool operand, one span
+# buffer and one aliased output.  A mixed step's call is laid out as the
+# K/V pools' are (:func:`query_layout` with no V pool, :func:`_pieces`).
+# Their jitted functions are named ``_paged_latent_fn`` /
+# ``_paged_latent_append_fn`` / ``_paged_latent_write_fn`` for the device
+# trace.
 
 def _latent_scratch(K: int, BS: int, W: int, pool_dtype, R: int, dtype):
     return [
@@ -1656,35 +1632,38 @@ def latent_append_attend(q, row_new, pool, block_tables, context_lens,
 
 
 def latent_attention(q, pool, block_tables, context_lens=None, *,
-                     start_pos=None, n_valid=None, scale: float, layer=None,
-                     use_pallas: bool | None = None,
+                     start_pos=None, n_valid=None, packed=None, scale: float,
+                     layer=None, use_pallas: bool | None = None,
                      interpret: bool | None = None):
     """Ragged latent attention (:func:`paged_attention` for the latent
-    pool).  A chunk's rows are handed to the kernel in pieces of
-    :data:`_LATENT_COLS` query columns, each a kernel row of its own over
-    the same table (all heads of a column are folded on the one stored row:
-    32 heads x 256 columns would be 8,192 query rows in VMEM); a piece past
-    a row's valid columns attends one key and is dropped."""
+    pool, with its ``packed`` form: a mixed step's stream (T, H, W) in, the
+    packed output (T, H, W) out).  The kernel takes the rows as
+    :func:`query_layout` lays them out for a pool of one array (``Dv = 0``):
+    N kernel rows of P query columns cut from the packed stream
+    (:func:`_pieces`; 24 of 64 at Kimi-Linear's cell, where the rows at the
+    chunk's width were 128 pieces of 64, most of them idle), or the rows as
+    they are where they cost no more; all heads of a column are folded on
+    the one stored row.  Rows (B, C, H, W) without ``packed`` go the same
+    way, as a stream of B x C tokens where the rule cuts them."""
     backend = jax.default_backend()
     if use_pallas is None:
         use_pallas = backend == "tpu"
     if not use_pallas:
+        if packed is not None:
+            idx, tok_row, tok_col = packed
+            return latent_attention_reference(
+                q[idx], _layer_of(pool, layer), block_tables,
+                start_pos=start_pos, n_valid=n_valid,
+                scale=scale)[tok_row, tok_col]
         return latent_attention_reference(
             q, _layer_of(pool, layer), block_tables, context_lens,
             start_pos=start_pos, n_valid=n_valid, scale=scale)
-    B, C, H, W = q.shape
-    _require_positive_context(C, context_lens, start_pos, n_valid)
-    c0, cl = _query_context(C, context_lens, start_pos, n_valid)
-    tables = jnp.asarray(block_tables, jnp.int32)
-    n = _latent_pieces(C)
-    if n > 1:
-        q, tables, c0, cl = _row_pieces(q, tables, c0, cl, n)
     pp, li = _latent_layer(pool, layer)
-    out = _paged_latent(
-        q, pp, li, tables, c0.astype(jnp.int32), cl.astype(jnp.int32),
-        scale=float(scale),
+    call = _latent_call(
+        pp, li, scale=float(scale),
         interpret=(backend != "tpu") if interpret is None else interpret)
-    return out.reshape(B, C, H, W)
+    return _dispatch(call, q, pp, None, block_tables, context_lens,
+                     start_pos, n_valid, packed)
 
 
 def _stacked(k_pool, v_pool, layer):
@@ -1809,9 +1788,8 @@ def _pieces(row_start, row_nvalid, row_token_idx, tok_row, tok_col, P: int,
     (context 1).  Returns ``(row, token, c0, cl, back)``: each piece's row
     (N,) (its table's), the packed tokens of its columns (N, P) (past the
     row's valid columns, its last one's: padding the kernel clamps), its
-    first and last columns' contexts (N,) (:func:`_row_pieces`' rule), and
-    ``(piece, column)`` of each packed token, the gather of the output
-    back to the stream."""
+    first and last columns' contexts (N,), and ``(piece, column)`` of each
+    packed token, the gather of the output back to the stream."""
     B = row_nvalid.shape[0]
     n = jnp.asarray(row_nvalid, jnp.int32)
     count = (n + P - 1) // P
@@ -1875,62 +1853,82 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens=None, *,
             start_pos=start_pos, n_valid=n_valid, window=window, sinks=sinks,
         )
     kk, vv, li = _stacked(k_pool, v_pool, layer)
-    tables = jnp.asarray(block_tables, jnp.int32)
+    call = _ragged_call(
+        kk, vv, li, sinks, hd=q.shape[-1], window=window,
+        interpret=(backend != "tpu") if interpret is None else interpret)
+    return _dispatch(call, q, kk, vv, block_tables, context_lens, start_pos,
+                     n_valid, packed)
+
+
+def _ragged_call(kk, vv, li, sinks, *, hd: int, window: int | None,
+                 interpret: bool):
+    """The ragged kernel on these pools, as :func:`_dispatch` calls it:
+    ``call(rows, tables, c0, cl)``."""
     extra = () if sinks is None else (sinks,)
-    kw = dict(window=window,
-              interpret=(backend != "tpu") if interpret is None else interpret)
-    if packed is not None:
-        _require_positive_context(1, None, start_pos, n_valid)
-        return _ragged_packed(q, kk, vv, li, tables,
-                              jnp.asarray(start_pos, jnp.int32),
-                              jnp.asarray(n_valid, jnp.int32), *packed,
-                              *extra, **kw)
-    B, C, H, hd = q.shape
-    _require_positive_context(C, context_lens, start_pos, n_valid)
-    c0, cl = (x.astype(jnp.int32) for x in _query_context(
-        C, context_lens, start_pos, n_valid))
-    if _layout(q, B * C, B, C, kk, vv, tables) == (C, B):
-        return _paged_ragged(q, kk, vv, li, tables, c0, cl, *extra,
-                             **_kernel_kw(hd, **kw))
-    # rows that do not fit VMEM: a stream of B x C tokens, row b's at b * C
-    start, nv = c0 - 1, cl - c0 + 1
-    col = jnp.minimum(jnp.arange(C, dtype=jnp.int32)[None], nv[:, None] - 1)
-    out = _ragged_packed(
-        q.reshape(B * C, H, hd), kk, vv, li, tables, start, nv,
-        jnp.arange(B, dtype=jnp.int32)[:, None] * C + col,
-        jnp.repeat(jnp.arange(B, dtype=jnp.int32), C), col.reshape(-1),
-        *extra, **kw)
-    return out.reshape(B, C, H, -1)
+    kw = dict(d_true=hd, interpret=interpret,
+              **({} if window is None else {"window": int(window)}))
+    return lambda q, tables, c0, cl: _paged_ragged(
+        q, kk, vv, li, tables, c0, cl, *extra, **kw)
+
+
+def _latent_call(pool, li, *, scale: float, interpret: bool):
+    """The latent kernel on this pool, as :func:`_dispatch` calls it."""
+    return lambda q, tables, c0, cl: _paged_latent(
+        q, pool, li, tables, c0, cl, scale=scale, interpret=interpret)
 
 
 def _layout(q, T: int, B: int, C: int, kk, vv, tables) -> tuple:
     """:func:`query_layout` of a call of these queries on these pools and
-    tables."""
+    tables (``vv`` None: the latent pool, one array)."""
     return query_layout(T, B, C, *q.shape[-2:], kk.shape[3], q.dtype,
-                        Dv=vv.shape[3], keys=tables.shape[1] * kk.shape[2],
+                        Dv=0 if vv is None else vv.shape[3],
+                        keys=tables.shape[1] * kk.shape[2],
                         pool_dtype=kk.dtype)
 
 
-def _kernel_kw(hd: int, window: int | None, interpret: bool) -> dict:
-    return dict(d_true=hd, interpret=interpret,
-                **({} if window is None else {"window": int(window)}))
+def _dispatch(call, q, kk, vv, block_tables, context_lens, start_pos,
+              n_valid, packed):
+    """:func:`paged_attention`'s and :func:`latent_attention`'s kernel
+    ``call`` in the layout :func:`query_layout` gives for the queries and
+    the pools ``kk`` / ``vv``: a packed step's stream through
+    :func:`_packed`; rows (B, C, H, hd) as they are where the rule keeps
+    them (a stream of B x C tokens needs no fewer slots), else as that
+    stream, row b's tokens at b * C, where a column past a row's valid ones
+    returns its last valid one's output."""
+    tables = jnp.asarray(block_tables, jnp.int32)
+    if packed is not None:
+        idx = packed[0]
+        _require_positive_context(1, None, start_pos, n_valid)
+        return _packed(call, q, tables, jnp.asarray(start_pos, jnp.int32),
+                       jnp.asarray(n_valid, jnp.int32), *packed,
+                       _layout(q, q.shape[0], *idx.shape, kk, vv, tables))
+    B, C = q.shape[:2]
+    _require_positive_context(C, context_lens, start_pos, n_valid)
+    c0, cl = (x.astype(jnp.int32) for x in _query_context(
+        C, context_lens, start_pos, n_valid))
+    layout = _layout(q, B * C, B, C, kk, vv, tables)
+    if layout == (C, B):
+        return call(q, tables, c0, cl)
+    start, nv = c0 - 1, cl - c0 + 1
+    col = jnp.minimum(jnp.arange(C, dtype=jnp.int32)[None], nv[:, None] - 1)
+    rows = jnp.arange(B, dtype=jnp.int32)
+    out = _packed(call, q.reshape(B * C, *q.shape[2:]), tables, start, nv,
+                  rows[:, None] * C + col, jnp.repeat(rows, C),
+                  col.reshape(-1), layout)
+    return out.reshape(B, C, *out.shape[1:])
 
 
-def _ragged_packed(q, kk, vv, li, tables, start, nv, idx, tok_row, tok_col,
-                   sinks=None, *, window: int | None, interpret: bool,
-                   layout: tuple | None = None):
-    """The kernel call of a packed step (:func:`paged_attention`) in the
-    layout :func:`query_layout` gives (``layout``: ``(P, N)`` forced, for
-    tests and the chip's sweeps)."""
-    T, H, hd = q.shape
+def _packed(call, q, tables, start, nv, idx, tok_row, tok_col,
+            layout: tuple):
+    """The kernel ``call(rows, tables, c0, cl)`` on a packed step (q (T, H,
+    hd), ``start`` / ``nv`` and the packing as :func:`paged_attention` takes
+    them) in ``layout`` (:func:`query_layout`'s ``(P, N)``; forced by tests
+    and the chip's sweeps): the B rows at the chunk's width, or N kernel
+    rows of P query columns cut from the stream (:func:`_pieces`).  Returns
+    the packed output."""
+    P, N = layout
     B, C = idx.shape
-    P, N = layout or _layout(q, T, B, C, kk, vv, tables)
-    extra = () if sinks is None else (sinks,)
-    kw = _kernel_kw(hd, window, interpret)
     if (P, N) == (C, B):  # the rows as they are
-        out = _paged_ragged(q[idx], kk, vv, li, tables, start + 1, start + nv,
-                            *extra, **kw)
-        return out[tok_row, tok_col]
+        return call(q[idx], tables, start + 1, start + nv)[tok_row, tok_col]
     row, tok, c0, cl, back = _pieces(start, nv, idx, tok_row, tok_col, P, N)
-    out = _paged_ragged(q[tok], kk, vv, li, tables[row], c0, cl, *extra, **kw)
-    return out[back]
+    return call(q[tok], tables[row], c0, cl)[back]

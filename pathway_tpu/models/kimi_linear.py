@@ -430,9 +430,10 @@ def _forward(params: dict, cfg: KimiLinearConfig, pool, conv, state, tokens,
                 pool = latent_write_rows(pool, slot_blocks, slot_offsets,
                                          row, layer=mi, use_pallas=kernels)
                 a = latent_attention(
-                    q_abs[row_token_idx], pool, row_tables,
-                    start_pos=row_start, n_valid=row_nvalid, scale=scale,
-                    layer=mi, use_pallas=kernels)[tok_row, tok_col]
+                    q_abs, pool, row_tables, start_pos=row_start,
+                    n_valid=row_nvalid,
+                    packed=(row_token_idx, tok_row, tok_col), scale=scale,
+                    layer=mi, use_pallas=kernels)
             o = jnp.einsum("thc,hcv->thv", a[..., :r], lay["w_vb"])
             x = x + o.reshape(T, -1).astype(dtype) @ lay["wo"]
             mi += 1

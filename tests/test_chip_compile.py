@@ -827,29 +827,48 @@ def test_kda_kernels_compile_at_the_published_widths(shape):
 
 def test_latent_kernels_compile_at_the_published_widths(shape):
     """The latent pool's three kernels at 640 lanes a stored row (576 and
-    64 of padding), 32 query heads folded on it: the ragged kernel on pieces
-    of 64 query columns (2,048 folded rows, as Trinity's eight heads at a
-    chunk of 256; 128 pieces at the cell's chunk of 512), the fused append
-    at one column, the writer; the pool is
-    one operand and is never copied."""
+    64 of padding), 32 query heads folded on it: the ragged call of a mixed
+    step of 528 packed tokens in the layout its rule gives (24 kernel rows
+    of 64 query columns, 2,048 folded rows each, as Trinity's eight heads at
+    a chunk of 256), the queries' gather into the pieces and the output's
+    back to the stream included; the fused append at one column; the
+    writer.  The pool is one operand and is never copied, and the packed
+    call's temporaries are under what the rows at the chunk's width hold
+    (the sixteen rows' 335 MB of gathered queries)."""
+    import functools
+
     import jax.numpy as jnp
 
     pa = _paged()
     bf, i32 = jnp.bfloat16, jnp.int32
     pool = shape((3, 8193, BS, 640), bf)
-    pieces = 16 * 512 // pa._LATENT_COLS
-    assert pa._latent_pieces(512) * 16 == pieces
+    rows, chunk, tables = 16, 512, 512
+    T = rows + chunk
+    assert pa.query_layout(T, rows, chunk, 32, 640, 640, bf, Dv=0,
+                           keys=tables * BS) == (64, 24)
     # a piece's 64 x 32 folded rows: a first tile of one query column's 32
     # heads, wide ones of 256 rows (eight query columns)
-    assert pa._col_tiles(1, pa._LATENT_COLS * 32, 32, bf) == (32, 256)
-    import functools
+    assert pa._col_tiles(1, 64 * 32, 32, bf) == (32, 256)
+    kw = dict(scale=192 ** -0.5, layer=1, use_pallas=True, interpret=False)
 
+    def packed(q, p, bt, sp, nv, idx, tr, tc):
+        return pa.latent_attention(q, p, bt, start_pos=sp, n_valid=nv,
+                                   packed=(idx, tr, tc), **kw)
+
+    def the_rows(q, p, bt, sp, nv, idx, tr, tc):
+        return pa.latent_attention(q[idx], p, bt, start_pos=sp, n_valid=nv,
+                                   **kw)[tr, tc]
+
+    args = (shape((T, 32, 640), bf), pool, shape((rows, tables), i32),
+            shape((rows,), i32), shape((rows,), i32),
+            shape((rows, chunk), i32), shape((T,), i32), shape((T,), i32))
+    ragged = _compiled_kernel(packed, *args)
+    assert "bf16[24,2048,640]" in ragged.as_text()
+    temp, rows_temp = (c.memory_analysis().temp_size_in_bytes for c in (
+        ragged, _compiled_kernel(the_rows, *args)))
+    print(f"latent call temporaries: {temp} packed, {rows_temp} the rows")
+    assert temp < rows_temp
     scaled = functools.partial
-    ragged = _compiled_kernel(
-        scaled(pa._paged_latent_fn, scale=192 ** -0.5),
-        shape((pieces, pa._LATENT_COLS, 32, 640), bf),
-        pool, shape((1,), i32), shape((pieces, 512), i32),
-        shape((pieces,), i32), shape((pieces,), i32))
     append = _compiled_kernel(
         scaled(pa._paged_latent_append_fn, scale=192 ** -0.5),
         shape((16, 1, 32, 640), bf),

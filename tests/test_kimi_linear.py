@@ -293,6 +293,56 @@ def test_kernels_and_gather_path_emit_the_same_tokens(cfg, params,
         in lines
 
 
+@pytest.mark.parametrize("P", [16, 8])
+def test_latent_kernel_in_pieces_emits_the_gather_paths_tokens(
+        cfg, params, clean_tokens, monkeypatch, P):
+    """The family's mixed step with its latent calls' rows cut from the
+    packed stream in pieces of ``P`` query columns (``query_layout`` steered
+    here; at the toy's shapes the rule keeps the rows): the gather path's
+    tokens, to the last."""
+    pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
+    calls = []
+
+    def pieces(T, B, C, *_a, **_k):
+        calls.append((T, B, C))
+        return P, T // P + B
+
+    monkeypatch.setattr(pa, "query_layout", pieces)
+    eng = _engine(cfg, params, f"t_kimi_pieces_{P}", attn="pallas")
+    assert eng.generate_batch(_requests()) == clean_tokens
+    assert (36, 4, 32) in calls  # the mixed step's: T = B + C
+
+
+def test_round_build_counts_the_latent_query_slots(cfg, params):
+    """``kv_query_cols`` / ``kv_query_slots`` / ``kv_query_tile_cols`` of a
+    mixed round on the latent pool, laid out as the K/V pools are: the toy's
+    four heads folded on one stored row of 128 lanes (f32) at a chunk of 128
+    and four rows are N = 132 // 32 + 4 = 8 kernel rows of P = 32 columns,
+    256 slots whatever the round holds; a piece of 32 columns is 128 folded
+    ones, a first tile of 8 (two columns) or one wide tile (the piece); a
+    kernel row past the rows' pieces runs one column's tile.  A served
+    batch counts the 256 slots once a mixed round in the pool's counter."""
+
+    class _Ph:
+        def set(self, **attrs):
+            self.attrs = attrs
+
+    eng = _engine(cfg, params, "t_kimi_slots", prefill_chunk=128)
+    assert eng._query_layout == (32, 8)
+    for rows, cols, tiles in (([1, 1, 9, 128], 139, 2 + 2 + 32 + 128 + 2),
+                              ([1, 1, 1, 1], 4, 4 * 2 + 4 * 2)):
+        ph = _Ph()
+        eng._note_query_cols(ph, np.asarray(rows, np.int32))
+        assert ph.attrs == {"kv_query_cols": cols, "kv_query_slots": 256,
+                            "kv_query_tile_cols": tiles}
+    before = eng.pool.stats.snapshot()
+    eng.generate_batch(_requests()[:3])
+    after = eng.pool.stats.snapshot()
+    rounds = after["mixed_steps"] - before["mixed_steps"]
+    assert rounds > 0
+    assert after["kv_query_slots"] - before["kv_query_slots"] == 256 * rounds
+
+
 def test_a_batch_emits_what_each_request_emits_alone(cfg, params,
                                                      clean_tokens):
     eng = _engine(cfg, params, "t_kimi_alone")

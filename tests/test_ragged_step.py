@@ -660,57 +660,61 @@ def test_col_tiles_keep_every_read_inside_the_block(G, rep, dtype):
     assert tiled
 
 
-@pytest.mark.parametrize("C", [64, 128], ids=["one_piece", "two_pieces"])
-def test_tiled_latent_kernel_matches_reference_interpreted(C):
-    """The latent form: one stored row of 128 lanes is key and value, the
-    eight query heads folded on it (G = 1); a chunk of 128 goes in two
-    pieces of 64 query columns, each a kernel row with tiles of its own."""
+@pytest.mark.parametrize("P", [None, 16], ids=["rows_kept", "as_a_stream"])
+@pytest.mark.parametrize("C", [64, 128], ids=["chunk_64", "chunk_128"])
+def test_latent_rows_form_matches_reference_interpreted(monkeypatch, C, P):
+    """The latent form on rows (B, C, H, W): one stored row of 128 lanes is
+    key and value, the eight query heads folded on it (G = 1, tiles of 8 /
+    256 folded columns a kernel row).  The rule keeps these rows; steered to
+    pieces of 16 columns, the rows go through the packed dispatch as a
+    stream of B x C tokens and each live column reads what it reads on the
+    rows."""
     pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
-    H, W, tiles = 8, 128, (8, 256)
-    piece = C // pa._latent_pieces(C)
-    assert pa._col_tiles(1, piece * H, H, jnp.float32) == tiles
-    rows = [_IDLE, (200, 1), (40, 1), (96, piece - 3), _IDLE,
-            (130, piece // 2 + 1), (30, C)]
+    H, W = 8, 128
+    assert pa._col_tiles(1, 64 * H, H, jnp.float32) == (8, 256)
+    rows = [_IDLE, (200, 1), (40, 1), (96, 61), _IDLE, (130, 33), (30, C)]
     rng = np.random.default_rng(12)
     q, pool, tables, sp, nv = _paged_case(rng, rows, C, H, W, W, jnp.float32)
+    B = len(rows)
+    assert pa.query_layout(B * C, B, C, H, W, W, jnp.float32, Dv=0,
+                           keys=tables.shape[1] * 16) == (C, B)
+    if P is not None:
+        monkeypatch.setattr(pa, "query_layout",
+                            lambda T, B, C, *_a, **_k: (P, T // P + B))
     want = pa.latent_attention_reference(
         q, pool, tables, start_pos=sp, n_valid=nv, scale=0.09)
     got = pa.latent_attention(
         q, pool, tables, start_pos=sp, n_valid=nv, scale=0.09,
         use_pallas=True, interpret=True)
     _assert_live_columns(got, want, rows, [C] * len(rows), 2e-5)
-    # a row's pieces: its live columns' tiles, one first tile a dead piece
-    cols = pa.query_tile_columns(np.asarray(nv), C, H, W, W, jnp.float32,
-                                 latent=True)
-    dead = (C // piece - 1) * (tiles[0] // H)
-    assert [int(c) for c in cols][:3] == [1 + dead] * 3
-    assert int(cols[-1]) == C
 
 
 @pytest.mark.parametrize(
-    "C,H,hd,D,dtype,latent,at",
-    [(256, 20, 64, 1280, "bfloat16", False, {1: 8, 8: 8, 9: 128, 133: 256}),
-     (512, 32, 64, 512, "bfloat16", False, {1: 2, 2: 2, 3: 32, 214: 224}),
-     (256, 32, 128, 512, "bfloat16", False, {1: 2, 3: 32, 33: 64}),
-     (512, 32, 640, 640, "bfloat16", True,
-      {1: 8, 2: 15, 64: 71, 65: 71, 512: 512}),
-     (32, 2, 64, 128, "float32", False, {1: 8, 9: 32}),
-     (1, 20, 64, 1280, "bfloat16", False, {1: 1}),
-     (1, 32, 64, 512, "bfloat16", False, {1: 1}),
-     (40, 8, 128, 128, "float32", False, {1: 40, 40: 40})],
+    "C,H,hd,D,dtype,cols,at",
+    [(256, 20, 64, 1280, "bfloat16", None, {1: 8, 8: 8, 9: 128, 133: 256}),
+     (512, 32, 64, 512, "bfloat16", None, {1: 2, 2: 2, 3: 32, 214: 224}),
+     (256, 32, 128, 512, "bfloat16", None, {1: 2, 3: 32, 33: 64}),
+     (512, 32, 640, 640, "bfloat16", 64,
+      {1: 1, 2: 8, 63: 64, 64: 64, 65: 65, 66: 72, 512: 512}),
+     (32, 2, 64, 128, "float32", None, {1: 8, 9: 32}),
+     (1, 20, 64, 1280, "bfloat16", None, {1: 1}),
+     (1, 32, 64, 512, "bfloat16", None, {1: 1}),
+     (40, 8, 128, 128, "float32", None, {1: 40, 40: 40})],
     ids=["gpt2_large_256", "lfm2_512", "trinity_256", "kimi_512", "toy_f32",
          "decode_row", "decode_row_folded", "width_no_tile_divides"],
 )
 def test_query_tile_columns_walks_the_kernels_tiles(C, H, hd, D, dtype,
-                                                    latent, at):
+                                                    cols, at):
     """``query_tile_columns`` (the engine's ``kv_query_tile_cols``) at the
     cells' published geometries: never under the live columns, never over
     the chunk, the whole chunk for a full row, and the readings a walk of
-    ``_col_tiles`` by hand gives."""
+    ``_col_tiles`` by hand gives.  The latent pool (``kimi_512``) in the
+    pieces of 64 columns its layout gives: a piece of one column runs one
+    column's first tile (32 folded rows), a longer one wide tiles of eight
+    columns, and a row is its live columns' pieces alone."""
     pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
     ns = np.arange(1, C + 1)
-    got = pa.query_tile_columns(ns, C, H, hd, D, jnp.dtype(dtype),
-                                latent=latent)
+    got = pa.query_tile_columns(ns, C, H, hd, D, jnp.dtype(dtype), cols=cols)
     assert (got >= ns).all() and (got <= C).all() and int(got[-1]) == C
     assert (np.diff(got) >= 0).all()
     assert {n: int(got[n - 1]) for n in at} == at
@@ -985,12 +989,13 @@ def test_piece_dispatch_is_the_row_dispatch_bit_for_bit(case):
         q, kp[0], vp[0], tables, start_pos=sp, n_valid=nv,
         packed=(idx, tr, tc), use_pallas=False, sinks=snk, **kw))[:t]
     B, T = len(_STREAM_ROWS), q.shape[0]
+    call = pa._ragged_call(kp, vp, jnp.zeros((1,), jnp.int32), snk, hd=hd,
+                           window=window, interpret=True)
     got = {}
     for P in (C, 16, 8):
         layout = (C, B) if P == C else (P, T // P + B)
-        got[P] = np.asarray(pa._ragged_packed(
-            q, kp, vp, jnp.zeros((1,), jnp.int32), tables, sp, nv, idx, tr,
-            tc, snk, window=window, interpret=True, layout=layout))[:t]
+        got[P] = np.asarray(pa._packed(call, q, tables, sp, nv, idx, tr, tc,
+                                       layout))[:t]
         np.testing.assert_allclose(got[P], want, rtol=2e-5, atol=2e-5)
     for P in (16, 8):
         assert (got[P].view(np.uint32) == got[C].view(np.uint32)).all()
@@ -1001,12 +1006,54 @@ def test_piece_dispatch_is_the_row_dispatch_bit_for_bit(case):
     assert (rule.view(np.uint32) == got[C].view(np.uint32)).all()
 
 
+_LATENT_LAYOUTS = {"rule": None, "pieces_of_16": 16, "rows": 48}
+
+
+@pytest.mark.parametrize("case", list(_LATENT_LAYOUTS))
+def test_latent_packed_call_matches_reference_interpreted(case):
+    """The latent call on a packed step (``latent_attention(packed=)``, the
+    form Kimi-Linear's mixed step takes) over ``_STREAM_ROWS`` - idle rows,
+    decode rows, a chunk row over several pieces, rows that end mid-piece,
+    the stream's last live token - on a pool of one array (eight query heads
+    folded on one stored row of 128 lanes that is key and value): the
+    gather reference's values at the rule's layout (pieces of 8 columns at
+    these shapes), at pieces of 16 and at the rows at the chunk's width,
+    and the rows' bits at every width; interpreted, in f32."""
+    pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
+    H, W, C, f32 = 8, 128, 48, jnp.float32
+    rng = np.random.default_rng(42)
+    q, pool, _v, tables, sp, nv, idx, tr, tc, t = _packed_case(
+        rng, _STREAM_ROWS, C, H, 1, W, W, f32)
+    B, T = len(_STREAM_ROWS), q.shape[0]
+    packed = (idx, tr, tc)
+    want = np.asarray(pa.latent_attention(
+        q, pool[0], tables, start_pos=sp, n_valid=nv, packed=packed,
+        scale=0.09, use_pallas=False))[:t]
+    call = pa._latent_call(pool, jnp.zeros((1,), jnp.int32), scale=0.09,
+                           interpret=True)
+    P = _LATENT_LAYOUTS[case]
+    if P is None:
+        assert pa._layout(q, T, B, C, pool, None, tables) == (8, T // 8 + B)
+        got = pa.latent_attention(
+            q, pool, tables, start_pos=sp, n_valid=nv, packed=packed,
+            scale=0.09, layer=0, use_pallas=True, interpret=True)
+    else:
+        got = pa._packed(call, q, tables, sp, nv, idx, tr, tc,
+                         (C, B) if P == C else (P, T // P + B))
+    got = np.asarray(got)
+    assert got.shape == (T, H, W) and np.isfinite(got).all()
+    np.testing.assert_allclose(got[:t], want, rtol=2e-5, atol=2e-5)
+    rows = np.asarray(pa._packed(call, q, tables, sp, nv, idx, tr, tc,
+                                 (C, B)))[:t]
+    assert (got[:t].view(np.uint32) == rows.view(np.uint32)).all()
+
+
 def test_pieces_by_hand():
     """``_pieces`` at P = 16 over ``_STREAM_ROWS``: each row's pieces in row
     order (the 37-column chunk in three, the others in one), the spare
     kernel rows idle at context 1, a piece's columns its row's tokens
-    (padding: the row's last), its contexts ``_row_pieces``' rule, and the
-    way back to every packed token."""
+    (padding: the row's last), its contexts (its first column's and its last
+    valid one's), and the way back to every packed token."""
     pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
     rng = np.random.default_rng(0)
     *_, tables, sp, nv, idx, tr, tc, t = _packed_case(
@@ -1043,6 +1090,9 @@ _CELL_LAYOUTS = {
     "qwen3next_1024": ((16, 2, 256, 256, 1024, 8192), (256, 20)),
     "mimo_full_512": ((64, 4, 192, 128, 512, 8192), (64, 24)),
     "mimo_window_512": ((64, 8, 192, 128, 512, 8192), (64, 24)),
+    # the latent pool: 32 heads folded on one stored row of 640 lanes that
+    # is key and value (no V pool: a value head of 0)
+    "kimi_latent_512": ((32, 1, 640, 0, 512, 8192), (64, 24)),
 }
 
 
@@ -1062,6 +1112,30 @@ def test_query_layout_at_the_cells_geometries(case):
     if P == C:
         assert pa.query_layout(16 * C, 16, C, H, hd, kv * hd, bf,
                                Dv=kv * hd_v, keys=keys) == (C, 16)
+
+
+def test_query_layout_prices_a_pool_of_one_array():
+    """``Dv = 0`` (the latent pool): a kernel row's slot is a query head in
+    and a query head out, its walk one array's keys.  Four rows of 64
+    columns, eight heads folded on 128 lanes in f32, tables of 256 keys:
+    pieces of 16 by the cost counted here by hand, where a slot of the
+    query alone or a walk of two arrays (a pool with a V: ``Dv = W``) keeps
+    the rows."""
+    pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
+    B, C, H, W, keys, f32 = 4, 64, 8, 128, 256, jnp.float32
+    T = B + C
+
+    def by_hand(slot, walk):  # the cheapest, the widest of equal costs
+        costs = [((B if P == C else T // P + B) * (P * slot + walk), P)
+                 for P in (64, 32, 16, 8)]
+        P = min(costs, key=lambda c: c[0])[1]
+        return P, B if P == C else T // P + B
+
+    slot, walk = H * 2 * W * 4, keys * W * 4
+    assert pa.query_layout(T, B, C, H, W, W, f32, Dv=0, keys=keys) \
+        == by_hand(slot, walk) == (16, 8)
+    assert by_hand(slot // 2, walk) == by_hand(slot, 2 * walk) == (C, B)
+    assert pa.query_layout(T, B, C, H, W, W, f32, Dv=W, keys=keys) == (C, B)
 
 
 # The ragged kernel as ``paged_attention`` dispatches a packed step at each
@@ -1112,6 +1186,42 @@ def test_packed_dispatch_at_the_cells_geometries(case):
         kernel, _ = pe.dce_jaxpr(kernel, [True] * len(kernel.outvars))
         text = re.sub(r" at [^ ]*:\d+", "", str(kernel))
         assert hashlib.sha256(text.encode()).hexdigest()[:16] == parent
+
+
+def test_latent_packed_dispatch_at_the_cells_geometry():
+    """Kimi-Linear's latent call as ``latent_attention`` dispatches a packed
+    step at the cell's geometry (sixteen rows, a chunk of 512, tables of 512
+    blocks, 32 heads folded on 640 lanes, bf16): ONE kernel call on 24
+    kernel rows of 64 x 32 folded columns (the rows at the chunk's width
+    were 128 pieces of 64), the packed output, and the kernel
+    ``_paged_latent_fn`` traced to before this layout at those rows (sha256
+    of its jaxpr as above): the layout moved, the kernel did not."""
+    import hashlib
+    import re
+
+    from jax._src.interpreters import partial_eval as pe
+
+    pa = importlib.import_module("pathway_tpu.kvcache.paged_attention")
+
+    def S(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    B, C, NB, H, W, bf = 16, 512, 512, 32, 640, jnp.bfloat16
+    T = B + C
+    closed = jax.make_jaxpr(
+        lambda q, p, bt, sp, nv, idx, tr, tc: pa.latent_attention(
+            q, p, bt, start_pos=sp, n_valid=nv, packed=(idx, tr, tc),
+            scale=192 ** -0.5, layer=1, use_pallas=True, interpret=True))(
+        S((T, H, W), bf), S((3, 41, 16, W), bf), S((B, NB)), S((B,)),
+        S((B,)), S((B, C)), S((T,)), S((T,)))
+    (call,) = _pallas_calls(closed.jaxpr)
+    assert call.outvars[0].aval.shape == (24, 64 * H, W)
+    assert closed.out_avals[0].shape == (T, H, W)
+    kernel = call.params["jaxpr"]
+    kernel, _ = pe.dce_jaxpr(kernel, [True] * len(kernel.outvars))
+    text = re.sub(r" at [^ ]*:\d+", "", str(kernel))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == "a539e3397c6c813d"
 
 
 @pytest.mark.parametrize(
