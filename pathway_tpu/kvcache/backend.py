@@ -68,7 +68,7 @@ with the geometry its block family names (models/families.py
 from __future__ import annotations
 
 import abc
-from typing import Callable
+import importlib
 
 
 class UnsupportedCacheOp(NotImplementedError):
@@ -211,11 +211,13 @@ class ExpertCounts:
             self.stats.record_moe(counts[:-n], zip(COUNTER_TAIL, counts[-n:]))
 
 
-_BACKENDS: dict[str, Callable] = {}
-
-
-def register_backend(kind: str, factory: Callable) -> None:
-    _BACKENDS[kind] = factory
+# a cache kind: the module (of this package) and the class that hold it,
+# imported on first use (they import the ABC from here)
+_KINDS = {"paged": ("block_pool", "BlockPool"),
+          "hybrid": ("hybrid", "HybridCache"),
+          "windowed": ("windowed", "WindowedCache"),
+          "latent_state": ("hybrid", "StateCache"),
+          "kv_state": ("hybrid", "KVStateCache")}
 
 
 def make_backend(kind: str, **kwargs) -> CacheBackend:
@@ -229,33 +231,9 @@ def make_backend(kind: str, **kwargs) -> CacheBackend:
     → :class:`~pathway_tpu.kvcache.hybrid.StateCache` /
     :class:`~pathway_tpu.kvcache.hybrid.KVStateCache` (a latent pool, or a
     K/V pool, beside a conv slot and a matrix state a sequence)."""
-    if kind not in _BACKENDS:
-        # lazy registration avoids import cycles: block_pool/hybrid
-        # import nothing from here at module scope except the ABC
-        if kind == "paged":
-            from .block_pool import BlockPool
-
-            register_backend("paged", BlockPool)
-        elif kind == "hybrid":
-            from .hybrid import HybridCache
-
-            register_backend("hybrid", HybridCache)
-        elif kind == "windowed":
-            from .windowed import WindowedCache
-
-            register_backend("windowed", WindowedCache)
-        elif kind == "latent_state":
-            from .hybrid import StateCache
-
-            register_backend("latent_state", StateCache)
-        elif kind == "kv_state":
-            from .hybrid import KVStateCache
-
-            register_backend("kv_state", KVStateCache)
-        else:
-            raise ValueError(
-                f"unknown cache backend {kind!r}; "
-                f"registered: {sorted(_BACKENDS)} + builtin: paged, hybrid, "
-                "windowed, latent_state, kv_state"
-            )
-    return _BACKENDS[kind](**kwargs)
+    if kind not in _KINDS:
+        raise ValueError(f"unknown cache backend {kind!r}; known: "
+                         f"{', '.join(_KINDS)}")
+    module, cls = _KINDS[kind]
+    return getattr(importlib.import_module(f"{__package__}.{module}"),
+                   cls)(**kwargs)

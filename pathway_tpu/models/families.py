@@ -27,20 +27,19 @@ and gives the engine:
 
 A family is the only place that names a model's programs: the engine
 imports none of models/decoder.py, models/lfm2.py, models/afmoe.py,
-models/kimi_linear.py, models/qwen3_next.py, models/mimo_v2_flash.py.  The
-function
-names ``_step_fn`` / ``_mixed_fn`` / ``_chained_fn`` are the
+models/kimi_linear.py, models/qwen3_next.py, models/mimo_v2_flash.py,
+models/blocks.py.  The five routed families share one step contract
+(:class:`_RoutedFamily` over :func:`pathway_tpu.models.blocks.greedy_steps`).
+The function names ``_step_fn`` / ``_mixed_fn`` / ``_chained_fn`` are the
 device trace's (``jit__mixed_fn`` on ``XLA Modules``): the benchmark's
 readers find the programs by them, for every family alike.
 """
 
 from __future__ import annotations
 
-import jax.numpy as jnp
+import importlib
 
-
-def _ids(logits):
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+from . import blocks
 
 
 def _refuse(family: str, asked: tuple) -> None:
@@ -117,7 +116,8 @@ class DecoderFamily:
                 out, k_pool, v_pool = d.paged_decode_step(
                     p, cfg, k_pool, v_pool, token, positions, bt, sb, so,
                     attn=attn, tp_axis=tp_axis, head_fn=head_at(samp))
-                return (_ids(out) if logits_out else out), k_pool, v_pool
+                return (blocks.greedy_ids(out) if logits_out else out), \
+                    k_pool, v_pool
 
             return over_mesh(body, p, k_pool, v_pool, *host)
 
@@ -130,7 +130,8 @@ class DecoderFamily:
                     row_start, row_nvalid, row_token_idx, tok_row, tok_col,
                     sb, so, logit_idx, attn=attn, tp_axis=tp_axis,
                     head_fn=head_at(samp))
-                return (_ids(out) if logits_out else out), k_pool, v_pool
+                return (blocks.greedy_ids(out) if logits_out else out), \
+                    k_pool, v_pool
 
             return over_mesh(body, p, k_pool, v_pool, *host)
 
@@ -148,119 +149,46 @@ class DecoderFamily:
                 "chained": (_chained_fn, (1, 2))}
 
 
-class Lfm2Family:
-    """models/lfm2.py: conv and grouped-query attention mixers, SwiGLU and
-    routed experts, on the hybrid cache.  Greedy on one device."""
+# a routed cache kind: the prefix of its step functions' names, and its
+# device arrays
+_STEP_CONTRACT = {"hybrid": ("hybrid", 3), "windowed": ("windowed", 4),
+                  "latent_state": ("state", 3), "kv_state": ("kv_state", 4)}
 
-    name = "lfm2"
-    cache_kind = "hybrid"
+
+class _RoutedFamily:
+    """A routed family (models/blocks.py): greedy on one device, its three
+    step programs made by :func:`pathway_tpu.models.blocks.greedy_steps` in
+    its module, ``models/<name>.py``, under the names of its cache kind,
+    every cache array donated.  A subclass declares its ``name``,
+    ``cache_kind``, ``cache_kwargs``, ``scan_items`` where it has a chunked
+    scan, and ``refusals``: why it has no tensor parallelism, no speculative
+    drafting and no host tiering."""
+
+    name: str
+    cache_kind: str
+    refusals: dict
     greedy_only = True
     tensor_parallel = False
 
-    @staticmethod
-    def plan(cfg, params, *, tp: int, quantize):
-        from .lfm2 import plan_params
-
-        return plan_params(cfg, params)
-
-    @staticmethod
-    def cache_kwargs(cfg, max_batch_size: int, round_tokens: int) -> dict:
-        return {"n_layers": len(cfg.attn_layers), "n_heads": cfg.n_kv_heads,
-                "head_dim": cfg.head_dim,
-                "conv_layers": len(cfg.conv_layers),
-                "conv_width": cfg.d_model, "conv_slots": max_batch_size}
-
-    @staticmethod
-    def unsupported(*, tp, quantize, speculative, session_store) -> None:
-        _refuse("lfm2", (
-            ("tensor parallelism (tp > 1): the conv arena and the expert "
-             "weights have no sharded layout", tp is not None and tp > 1),
-            (f"quantize={quantize!r}: no quantized plan of the expert "
-             "weights", quantize is not None),
-            ("speculative drafting: a rejected draft would have to roll "
-             "the conv state back", speculative not in (None, False)),
-            ("host tiering (session_store): a resumed block skips the "
-             "tokens that build the conv state", session_store is not None),
-        ))
-
-    @staticmethod
-    def programs(cfg, attn: str, mesh, sampled: bool = False) -> dict:
-        if sampled:
-            raise ValueError("the lfm2 block family decodes greedily: it "
-                             "has no sampled step programs")
-        from . import lfm2 as m
-
-        def _step_fn(p, k_pool, v_pool, conv, token, positions, bt, sb, so,
-                     slots):
-            logits, *state = m.hybrid_decode_step(
-                p, cfg, k_pool, v_pool, conv, token, positions, bt, sb, so,
-                slots, attn=attn)
-            return (m.greedy_ids(logits), *state)
-
-        def _mixed_fn(p, k_pool, v_pool, conv, tokens, positions,
-                      row_tables, row_start, row_nvalid, row_token_idx,
-                      tok_row, tok_col, sb, so, logit_idx, slots):
-            logits, *state = m.hybrid_mixed_step(
-                p, cfg, k_pool, v_pool, conv, tokens, positions, row_tables,
-                row_start, row_nvalid, row_token_idx, tok_row, tok_col, sb,
-                so, logit_idx, slots, attn=attn)
-            return (m.greedy_ids(logits), *state)
-
-        def _chained_fn(p, k_pool, v_pool, conv, token, positions, bt, sb,
-                        so, slots):
-            return m.hybrid_chained_decode(
-                p, cfg, k_pool, v_pool, conv, token, positions, bt, sb, so,
-                slots, attn=attn)
-
-        return {"step": (_step_fn, (1, 2, 3)), "mixed": (_mixed_fn, (1, 2, 3)),
-                "chained": (_chained_fn, (1, 2, 3))}
-
-
-class AfmoeFamily:
-    """models/afmoe.py: sliding-window and full attention layers with a
-    gated output, sandwich norms, SwiGLU and routed experts beside a shared
-    one, on the windowed cache.  Greedy on one device.  Beyond what
-    :meth:`unsupported` refuses, the cache kind runs without a prefix cache
-    whatever was asked for (a shared block may be freed behind one
-    sequence's window while another still reads it), and a sampled request
-    fails alone (``greedy_only``)."""
-
-    name = "afmoe"
-    cache_kind = "windowed"
-    greedy_only = True
-    tensor_parallel = False
-
-    @staticmethod
-    def _model():
-        """The module that holds the family's three windowed step programs
-        and ``plan_params`` (a second family of window and full layers
-        names its own)."""
-        from . import afmoe
-
-        return afmoe
+    @classmethod
+    def _model(cls):
+        return importlib.import_module(f"{__package__}.{cls.name}")
 
     @classmethod
     def plan(cls, cfg, params, *, tp: int, quantize):
         return cls._model().plan_params(cfg, params)
 
-    @staticmethod
-    def cache_kwargs(cfg, max_batch_size: int, round_tokens: int) -> dict:
-        return {"n_layers": len(cfg.full_layers), "n_heads": cfg.n_kv_heads,
-                "head_dim": cfg.head_dim, "window": cfg.sliding_window,
-                "window_layers": len(cfg.window_layers),
-                "round_tokens": round_tokens, "max_seqs": max_batch_size}
-
     @classmethod
     def unsupported(cls, *, tp, quantize, speculative, session_store) -> None:
+        why = cls.refusals
         _refuse(cls.name, (
-            ("tensor parallelism (tp > 1): the window pool and the expert "
-             "weights have no sharded layout", tp is not None and tp > 1),
+            (f"tensor parallelism (tp > 1): {why['tp']}",
+             tp is not None and tp > 1),
             (f"quantize={quantize!r}: no quantized plan of the expert "
              "weights", quantize is not None),
-            ("speculative drafting: a rejected draft's slots cannot be "
-             "rolled back behind the window", speculative not in (None, False)),
-            ("host tiering (session_store): a suspended sequence has lost "
-             "the window layers' keys behind its window",
+            (f"speculative drafting: {why['speculative']}",
+             speculative not in (None, False)),
+            (f"host tiering (session_store): {why['session_store']}",
              session_store is not None),
         ))
 
@@ -270,54 +198,100 @@ class AfmoeFamily:
             raise ValueError(f"the {cls.name} block family decodes "
                              "greedily: it has no sampled step programs")
         m = cls._model()
+        prefix, n = _STEP_CONTRACT[cls.cache_kind]
 
-        def _step_fn(p, k_pool, v_pool, kw_pool, vw_pool, token, positions,
-                     bt, sb, so, wt):
-            logits, *state = m.windowed_decode_step(
-                p, cfg, k_pool, v_pool, kw_pool, vw_pool, token, positions,
-                bt, sb, so, wt, attn=attn)
-            return (m.greedy_ids(logits), *state)
+        # the module's step functions are looked up at trace time
+        def _step_fn(p, *arrays):
+            logits, *cache = getattr(m, f"{prefix}_decode_step")(
+                p, cfg, *arrays, attn=attn)
+            return (blocks.greedy_ids(logits), *cache)
 
-        def _mixed_fn(p, k_pool, v_pool, kw_pool, vw_pool, tokens, positions,
-                      row_tables, row_start, row_nvalid, row_token_idx,
-                      tok_row, tok_col, sb, so, logit_idx, wt):
-            logits, *state = m.windowed_mixed_step(
-                p, cfg, k_pool, v_pool, kw_pool, vw_pool, tokens, positions,
-                row_tables, row_start, row_nvalid, row_token_idx, tok_row,
-                tok_col, sb, so, logit_idx, wt, attn=attn)
-            return (m.greedy_ids(logits), *state)
+        def _mixed_fn(p, *arrays):
+            logits, *cache = getattr(m, f"{prefix}_mixed_step")(
+                p, cfg, *arrays, attn=attn)
+            return (blocks.greedy_ids(logits), *cache)
 
-        def _chained_fn(p, k_pool, v_pool, kw_pool, vw_pool, token,
-                        positions, bt, sb, so, wt):
-            return m.windowed_chained_decode(
-                p, cfg, k_pool, v_pool, kw_pool, vw_pool, token, positions,
-                bt, sb, so, wt, attn=attn)
+        def _chained_fn(p, *arrays):
+            return getattr(m, f"{prefix}_chained_decode")(
+                p, cfg, *arrays, attn=attn)
 
-        donated = (1, 2, 3, 4)
+        donated = tuple(range(1, n + 1))
         return {"step": (_step_fn, donated), "mixed": (_mixed_fn, donated),
                 "chained": (_chained_fn, donated)}
 
 
-class KimiLinearFamily:
+class Lfm2Family(_RoutedFamily):
+    """models/lfm2.py: conv and grouped-query attention mixers, SwiGLU and
+    routed experts, on the hybrid cache."""
+
+    name = "lfm2"
+    cache_kind = "hybrid"
+    refusals = {
+        "tp": "the conv arena and the expert weights have no sharded layout",
+        "speculative": "a rejected draft would have to roll the conv state "
+                       "back",
+        "session_store": "a resumed block skips the tokens that build the "
+                         "conv state"}
+
+    @staticmethod
+    def cache_kwargs(cfg, max_batch_size: int, round_tokens: int) -> dict:
+        return {"n_layers": len(cfg.attn_layers), "n_heads": cfg.n_kv_heads,
+                "head_dim": cfg.head_dim,
+                "conv_layers": len(cfg.conv_layers),
+                "conv_width": cfg.d_model, "conv_slots": max_batch_size}
+
+
+class AfmoeFamily(_RoutedFamily):
+    """models/afmoe.py: sliding-window and full attention layers with a
+    gated output, sandwich norms, SwiGLU and routed experts beside a shared
+    one, on the windowed cache.  Beyond what :meth:`unsupported` refuses,
+    the cache kind runs without a prefix cache whatever was asked for (a
+    shared block may be freed behind one sequence's window while another
+    still reads it), and a sampled request fails alone
+    (``greedy_only``)."""
+
+    name = "afmoe"
+    cache_kind = "windowed"
+    refusals = {
+        "tp": "the window pool and the expert weights have no sharded "
+              "layout",
+        "speculative": "a rejected draft's slots cannot be rolled back "
+                       "behind the window",
+        "session_store": "a suspended sequence has lost the window layers' "
+                         "keys behind its window"}
+
+    @staticmethod
+    def cache_kwargs(cfg, max_batch_size: int, round_tokens: int) -> dict:
+        return {"n_layers": len(cfg.full_layers), "n_heads": cfg.n_kv_heads,
+                "head_dim": cfg.head_dim, "window": cfg.sliding_window,
+                "window_layers": len(cfg.window_layers),
+                "round_tokens": round_tokens, "max_seqs": max_batch_size}
+
+
+# why a family with a matrix state a sequence has no drafting and no tiering
+_STATE_REFUSALS = {
+    "speculative": "a rejected draft would have to roll the matrix state "
+                   "back",
+    "session_store": "a resumed block skips the tokens that build the "
+                     "matrix state (it would take a snapshot of the state "
+                     "at every block boundary, as prefix sharing and fork "
+                     "would)"}
+
+
+class KimiLinearFamily(_RoutedFamily):
     """models/kimi_linear.py: Kimi Delta Attention and latent attention
     layers, SwiGLU and routed experts (all of them, or the share a chip of
     an expert-parallel deployment holds) beside a shared one, on the
-    latent-and-state cache.  Greedy on one device.  Beyond what
-    :meth:`unsupported` refuses, the cache kind runs without a prefix cache
-    and without fork whatever was asked for (a shared block would skip the
-    tokens that build the matrix state), and a sampled request fails alone
-    (``greedy_only``)."""
+    latent-and-state cache.  Beyond what :meth:`unsupported` refuses, the
+    cache kind runs without a prefix cache and without fork whatever was
+    asked for (a shared block would skip the tokens that build the matrix
+    state), and a sampled request fails alone (``greedy_only``)."""
 
     name = "kimi_linear"
     cache_kind = "latent_state"
-    greedy_only = True
-    tensor_parallel = False
-
-    @staticmethod
-    def plan(cfg, params, *, tp: int, quantize):
-        from .kimi_linear import plan_params
-
-        return plan_params(cfg, params)
+    refusals = {"tp": "the state arena, the latent pool and the held "
+                      "experts have no sharded layout and no exchange",
+                **_STATE_REFUSALS}
 
     @staticmethod
     def cache_kwargs(cfg, max_batch_size: int, round_tokens: int) -> dict:
@@ -335,76 +309,20 @@ class KimiLinearFamily:
         what obs/memory.py bills the mixed step's temporaries by."""
         return cfg.kda_chunk, cfg.kda_width
 
-    @staticmethod
-    def unsupported(*, tp, quantize, speculative, session_store) -> None:
-        _refuse("kimi_linear", (
-            ("tensor parallelism (tp > 1): the state arena, the latent pool "
-             "and the held experts have no sharded layout and no exchange",
-             tp is not None and tp > 1),
-            (f"quantize={quantize!r}: no quantized plan of the expert "
-             "weights", quantize is not None),
-            ("speculative drafting: a rejected draft would have to roll "
-             "the matrix state back", speculative not in (None, False)),
-            ("host tiering (session_store): a resumed block skips the "
-             "tokens that build the matrix state (it would take a snapshot "
-             "of the state at every block boundary, as prefix sharing and "
-             "fork would)", session_store is not None),
-        ))
 
-    @staticmethod
-    def programs(cfg, attn: str, mesh, sampled: bool = False) -> dict:
-        if sampled:
-            raise ValueError("the kimi_linear block family decodes greedily: "
-                             "it has no sampled step programs")
-        from . import kimi_linear as m
-
-        def _step_fn(p, pool, conv, state, token, positions, bt, sb, so,
-                     slots):
-            logits, *cache = m.state_decode_step(
-                p, cfg, pool, conv, state, token, positions, bt, sb, so,
-                slots, attn=attn)
-            return (m.greedy_ids(logits), *cache)
-
-        def _mixed_fn(p, pool, conv, state, tokens, positions, row_tables,
-                      row_start, row_nvalid, row_token_idx, tok_row, tok_col,
-                      sb, so, logit_idx, slots):
-            logits, *cache = m.state_mixed_step(
-                p, cfg, pool, conv, state, tokens, positions, row_tables,
-                row_start, row_nvalid, row_token_idx, tok_row, tok_col, sb,
-                so, logit_idx, slots, attn=attn)
-            return (m.greedy_ids(logits), *cache)
-
-        def _chained_fn(p, pool, conv, state, token, positions, bt, sb, so,
-                        slots):
-            return m.state_chained_decode(
-                p, cfg, pool, conv, state, token, positions, bt, sb, so,
-                slots, attn=attn)
-
-        return {"step": (_step_fn, (1, 2, 3)), "mixed": (_mixed_fn, (1, 2, 3)),
-                "chained": (_chained_fn, (1, 2, 3))}
-
-
-class Qwen3NextFamily:
+class Qwen3NextFamily(_RoutedFamily):
     """models/qwen3_next.py: gated DeltaNet (one decay a head) and gated
     full-attention layers (rotary on a part of the head), a softmax router
     over routed experts (all of them, or the share a chip of an
     expert-parallel deployment holds) beside a sigmoid-gated shared one, on
-    the K/V-and-state cache.  Greedy on one device.  Beyond what
-    :meth:`unsupported` refuses, the cache kind runs without a prefix cache
-    and without fork whatever was asked for (a shared block would skip the
-    tokens that build the matrix state), and a sampled request fails alone
-    (``greedy_only``)."""
+    the K/V-and-state cache.  What the cache kind rules out is
+    :class:`KimiLinearFamily`'s."""
 
     name = "qwen3_next"
     cache_kind = "kv_state"
-    greedy_only = True
-    tensor_parallel = False
-
-    @staticmethod
-    def plan(cfg, params, *, tp: int, quantize):
-        from .qwen3_next import plan_params
-
-        return plan_params(cfg, params)
+    refusals = {"tp": "the state arena, the K/V pool of two heads and the "
+                      "held experts have no sharded layout and no exchange",
+                **_STATE_REFUSALS}
 
     @staticmethod
     def cache_kwargs(cfg, max_batch_size: int, round_tokens: int) -> dict:
@@ -422,55 +340,6 @@ class Qwen3NextFamily:
         """As :meth:`KimiLinearFamily.scan_items`."""
         return cfg.gdn_chunk, cfg.value_width
 
-    @staticmethod
-    def unsupported(*, tp, quantize, speculative, session_store) -> None:
-        _refuse("qwen3_next", (
-            ("tensor parallelism (tp > 1): the state arena, the K/V pool of "
-             "two heads and the held experts have no sharded layout and no "
-             "exchange", tp is not None and tp > 1),
-            (f"quantize={quantize!r}: no quantized plan of the expert "
-             "weights", quantize is not None),
-            ("speculative drafting: a rejected draft would have to roll "
-             "the matrix state back", speculative not in (None, False)),
-            ("host tiering (session_store): a resumed block skips the "
-             "tokens that build the matrix state (it would take a snapshot "
-             "of the state at every block boundary, as prefix sharing and "
-             "fork would)", session_store is not None),
-        ))
-
-    @staticmethod
-    def programs(cfg, attn: str, mesh, sampled: bool = False) -> dict:
-        if sampled:
-            raise ValueError("the qwen3_next block family decodes greedily: "
-                             "it has no sampled step programs")
-        from . import qwen3_next as m
-
-        def _step_fn(p, k_pool, v_pool, conv, state, token, positions, bt,
-                     sb, so, slots):
-            logits, *cache = m.kv_state_decode_step(
-                p, cfg, k_pool, v_pool, conv, state, token, positions, bt,
-                sb, so, slots, attn=attn)
-            return (m.greedy_ids(logits), *cache)
-
-        def _mixed_fn(p, k_pool, v_pool, conv, state, tokens, positions,
-                      row_tables, row_start, row_nvalid, row_token_idx,
-                      tok_row, tok_col, sb, so, logit_idx, slots):
-            logits, *cache = m.kv_state_mixed_step(
-                p, cfg, k_pool, v_pool, conv, state, tokens, positions,
-                row_tables, row_start, row_nvalid, row_token_idx, tok_row,
-                tok_col, sb, so, logit_idx, slots, attn=attn)
-            return (m.greedy_ids(logits), *cache)
-
-        def _chained_fn(p, k_pool, v_pool, conv, state, token, positions, bt,
-                        sb, so, slots):
-            return m.kv_state_chained_decode(
-                p, cfg, k_pool, v_pool, conv, state, token, positions, bt,
-                sb, so, slots, attn=attn)
-
-        donated = (1, 2, 3, 4)
-        return {"step": (_step_fn, donated), "mixed": (_mixed_fn, donated),
-                "chained": (_chained_fn, donated)}
-
 
 class MimoV2FlashFamily(AfmoeFamily):
     """models/mimo_v2_flash.py: sliding-window layers (a learned sink a
@@ -478,16 +347,10 @@ class MimoV2FlashFamily(AfmoeFamily):
     keys wider than values, SwiGLU and routed experts (all of them, or the
     share a chip of an expert-parallel deployment holds) with no shared
     one, on the windowed cache, whose two pool pairs take their geometry
-    from here.  The step contract, the refusals and what the cache kind
-    rules out are :class:`AfmoeFamily`'s."""
+    from here.  The refusals and what the cache kind rules out are
+    :class:`AfmoeFamily`'s."""
 
     name = "mimo_v2_flash"
-
-    @staticmethod
-    def _model():
-        from . import mimo_v2_flash
-
-        return mimo_v2_flash
 
     @staticmethod
     def cache_kwargs(cfg, max_batch_size: int, round_tokens: int) -> dict:

@@ -57,8 +57,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .blocks import (_carried, _conv_inputs, _fresh_rows, _l2norm, _rms,
+                     _swiglu, cast_plan, decay_parameters, greedy_steps)
 from .encoder import _resolve_dtype
-from .lfm2 import _rms, _swiglu, greedy_ids  # noqa: F401
 
 KDA, MLA = "kda", "mla"
 _LANES = 128
@@ -164,28 +165,13 @@ class KimiLinearConfig:
                 + (self.n_layers - n_moe) * dense + n_moe * moe)
 
 
-def decay_parameters(key, n_heads: int, width: int, *, low=0.955,
-                     high=0.9998):
-    """``(A_log (n_heads,), dt_bias (width,))`` f32 such that, where the
-    low-rank decay projection gives zero, a channel's decay a token
-    ``exp(-exp(A_log) softplus(dt_bias))`` is log-uniform in its distance
-    from 1 between ``low`` and ``high``: ``A_log = 0`` and ``dt_bias`` the
-    inverse softplus of ``-log(decay)``.  With ``W_fb`` drawn at a fifth of
-    1/sqrt(fan_in) (:func:`init_kimi_linear_params`) the projection is
-    N(0, 0.2^2) a channel and moves the rate by a factor of at most 2.2 at
-    four deviations: every decay a token lies between 0.9 and 0.9999."""
-    u = jax.random.uniform(key, (width,), jnp.float32)
-    rate = -jnp.log(1.0 - jnp.exp(
-        np.log(1.0 - low) + u * (np.log(1.0 - high) - np.log(1.0 - low))))
-    return jnp.zeros((n_heads,), jnp.float32), jnp.log(jnp.expm1(rate))
-
-
 def init_kimi_linear_params(cfg: KimiLinearConfig, rng: jax.Array,
                             dtype=None) -> dict:
     """Random parameters in the layout the step programs read: matrices
     N(0, 1/fan_in), embeddings 0.02, norm scales 1 +- 0.1, expert bias 0.02,
     conv taps N(0, 1/taps), the decay's parameters by
-    :func:`decay_parameters` (``w_fb`` at a fifth of its fan-in scale).  The output projections (``wo``, ``w2``, the
+    :func:`~pathway_tpu.models.blocks.decay_parameters` (``w_fb`` at a fifth
+    of its fan-in scale).  The output projections (``wo``, ``w2``, the
     shared expert's too) of every layer after the first are scaled by
     ``1 / sqrt(2 (L - 1))`` (the reasoning of
     :func:`pathway_tpu.models.lfm2.init_lfm2_params`)."""
@@ -241,23 +227,13 @@ def init_kimi_linear_params(cfg: KimiLinearConfig, rng: jax.Array,
     return params
 
 
-_F32_LEAVES = ("expert_bias", "a_log", "dt_bias")
-
-
 def plan_params(cfg: KimiLinearConfig, params: dict) -> dict:
-    """What the engine dispatches with: the parameters as they are where
-    they already have the configuration's dtype, else cast once (the
-    router's bias and the decay's parameters stay f32), and ``W_kv_b`` of
-    every latent layer cut into the halves the absorbed form applies:
-    ``w_kb`` (H, qk_nope, rank), to the queries, and ``w_vb`` (H, rank,
-    v_head_dim), to the mix."""
-    dtype = _resolve_dtype(cfg.dtype)
-
-    def cast(path, leaf):
-        keep = any(getattr(k, "key", None) in _F32_LEAVES for k in path)
-        return leaf if keep or leaf.dtype == dtype else leaf.astype(dtype)
-
-    plan = jax.tree_util.tree_map_with_path(cast, params)
+    """:func:`~pathway_tpu.models.blocks.cast_plan` with the router's bias
+    and the decay's parameters kept f32, and ``W_kv_b`` of every latent
+    layer cut into the halves the absorbed form applies: ``w_kb`` (H,
+    qk_nope, rank), to the queries, and ``w_vb`` (H, rank, v_head_dim), to
+    the mix."""
+    plan = cast_plan(cfg, params, ("expert_bias", "a_log", "dt_bias"))
     H, nope, r = cfg.n_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
     layers = []
     for kind, lay in zip(cfg.layer_types, plan["layers"]):
@@ -273,27 +249,6 @@ def plan_params(cfg: KimiLinearConfig, params: dict) -> dict:
 # -- the block math -----------------------------------------------------------
 
 
-def _l2norm(x, eps: float = 1e-6):
-    x32 = x.astype(jnp.float32)
-    return x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, -1, keepdims=True) + eps)
-
-
-def _conv_inputs(u, carried, tok_col, positions, taps: int):
-    """The ``taps - 1`` inputs before each token of the stream, oldest
-    first: from the stream where the token's run holds them, else from the
-    row's carried inputs ``carried`` (T, taps - 1, W: the inputs before the
-    run's first token, oldest first), and zero before the sequence."""
-    col = tok_col[:, None]
-    prev = []
-    for j in range(taps - 1, 0, -1):
-        held = jnp.take_along_axis(
-            carried, jnp.clip(taps - 1 - j + tok_col, 0, taps - 2
-                              )[:, None, None], axis=1)[:, 0]
-        p = jnp.where(col >= j, jnp.roll(u, j, axis=0), held)
-        prev.append(jnp.where((positions >= j)[:, None], p, 0))
-    return prev
-
-
 def _beta(lay, h):
     """The delta rule's step size, one a head: (T, H) f32 in (0, 1)."""
     return jax.nn.sigmoid(jnp.dot(h, lay["wb"],
@@ -307,18 +262,6 @@ def _log_decay(lay, h, H: int, dk: int):
     f = jnp.dot(h @ lay["w_fa"], lay["w_fb"], preferred_element_type=f32)
     return -jnp.exp(lay["a_log"].astype(f32))[None, :, None] \
         * jax.nn.softplus((f + lay["dt_bias"].astype(f32)).reshape(-1, H, dk))
-
-
-def _carried(prev: list, u):
-    """The conv inputs a token leaves behind it, oldest first: the last
-    ``taps - 2`` of those before it, and its own."""
-    return jnp.stack(prev[1:] + [u], axis=1)
-
-
-def _fresh_rows(row_start):
-    """Rows whose run is their sequence's first: their state starts from
-    zero inside the program, whatever the slot's last owner left."""
-    return row_start == 0
 
 
 def _absorbed_query(qh, w_kb, nope: int, pad: int):
@@ -454,65 +397,8 @@ def _forward(params: dict, cfg: KimiLinearConfig, pool, conv, state, tokens,
     return logits, pool, conv, state, counts
 
 
-def state_mixed_step(params: dict, cfg: KimiLinearConfig, pool, conv, state,
-                     tokens, positions, row_tables, row_start, row_nvalid,
-                     row_token_idx, tok_row, tok_col, slot_blocks,
-                     slot_offsets, logit_idx, row_slot, *,
-                     attn: str = "reference"):
-    """The ragged fused step (decode rows and prompt chunks on one packed
-    stream) for this family.  A packed token is real where its row's run
-    holds it (padding tokens point at row 0, column 0, which is another
-    token's place); a row is real where its first token is its own."""
-    T, B = tokens.shape[0], row_start.shape[0]
-    valid = row_token_idx[tok_row, tok_col] == jnp.arange(T, dtype=jnp.int32)
-    first = row_token_idx[:, 0]
-    row_live = valid[first] & (tok_row[first] == jnp.arange(B, dtype=jnp.int32))
-    return _forward(
-        params, cfg, pool, conv, state, tokens, positions, row_tables,
-        row_start, row_nvalid, row_token_idx, tok_row, tok_col, slot_blocks,
-        slot_offsets, logit_idx, row_slot, valid, row_live, attn=attn,
-        decode=False)
-
-
-def state_decode_step(params: dict, cfg: KimiLinearConfig, pool, conv, state,
-                      token, positions, block_tables, slot_blocks,
-                      slot_offsets, row_slot, *, attn: str = "reference"):
-    """One token a row.  An idle row has the null block first in its table
-    and rides slot 0."""
-    B = token.shape[0]
-    rows = jnp.arange(B, dtype=jnp.int32)
-    live = block_tables[:, 0] > 0
-    return _forward(
-        params, cfg, pool, conv, state, token, positions, block_tables,
-        positions, jnp.ones((B,), jnp.int32), rows[:, None], rows,
-        jnp.zeros((B,), jnp.int32), slot_blocks, slot_offsets, rows,
-        row_slot, live, live, attn=attn, decode=True)
-
-
-def state_chained_decode(params: dict, cfg: KimiLinearConfig, pool, conv,
-                         state, token, positions, block_tables, slot_blocks,
-                         slot_offsets, row_slot, *, attn: str = "reference"):
-    """K greedy decode steps in one program (``slot_blocks`` /
-    ``slot_offsets`` (B, K), the host's pre-extended slots), step t's ids
-    feeding step t + 1, the state riding the scan.  Returns ``(ids (B, K),
-    pool, conv, state, counts)``."""
-    from ..ops.moe import COUNTER_TAIL
-
-    K = slot_blocks.shape[1]
-    maxp = cfg.max_len - 1
-
-    def body(carry, xs):
-        tok, pl_, cv, st, cnt = carry
-        sb, so, t = xs
-        logits, pl_, cv, st, n_tok = state_decode_step(
-            params, cfg, pl_, cv, st, tok, jnp.minimum(positions + t, maxp),
-            block_tables, sb, so, row_slot, attn=attn)
-        ids = greedy_ids(logits)
-        return (ids, pl_, cv, st, cnt + n_tok), ids
-
-    init = (token.astype(jnp.int32), pool, conv, state,
-            jnp.zeros((cfg.held_experts + len(COUNTER_TAIL),), jnp.int32))
-    (_last, pool, conv, state, counts), ids = jax.lax.scan(
-        body, init, (slot_blocks.T, slot_offsets.T,
-                     jnp.arange(K, dtype=jnp.int32)))
-    return ids.T, pool, conv, state, counts
+# the three programs of the step contract; ``_forward`` is found by name at
+# trace time
+state_mixed_step, state_decode_step, state_chained_decode = greedy_steps(
+    lambda *a, **kw: _forward(*a, **kw), lambda cfg: cfg.held_experts,
+    row_live=True)

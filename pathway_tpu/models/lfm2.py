@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .blocks import _rms, _rope, _swiglu, cast_plan, greedy_steps
 from .encoder import _resolve_dtype
 
 ATTENTION, CONV = "full_attention", "conv"
@@ -169,51 +170,9 @@ def init_lfm2_params(cfg: Lfm2Config, rng: jax.Array, dtype=None) -> dict:
 
 
 def plan_params(cfg: Lfm2Config, params: dict) -> dict:
-    """What the engine dispatches with: the parameters as they are where
-    they already have the configuration's dtype, else cast once (the
-    router's bias stays f32).  No fused or quantized plan for this family
-    yet."""
-    dtype = _resolve_dtype(cfg.dtype)
-
-    def cast(path, leaf):
-        keep = any(getattr(k, "key", None) == "expert_bias" for k in path)
-        return leaf if keep or leaf.dtype == dtype else leaf.astype(dtype)
-
-    return jax.tree_util.tree_map_with_path(cast, params)
-
-
-def greedy_ids(logits):
-    """The token a row emits: the argmax inside the program, so that only
-    ``int32`` ids cross to the host."""
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-
-# -- the block math -----------------------------------------------------------
-
-
-def _rms(x, scale, eps: float, dtype=None):
-    """RMSNorm in f32; the result in ``dtype`` (default: x's)."""
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (y * scale.astype(jnp.float32)).astype(dtype or x.dtype)
-
-
-def _rope(x, positions, theta: float):
-    """Rotate-half rotary over the whole head.  x (T, H, hd); positions
-    (T,).  Angles in f32."""
-    hd = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
-    ang = jnp.concatenate([ang, ang], axis=-1)[:, None, :]   # (T, 1, hd)
-    x32 = x.astype(jnp.float32)
-    rot = jnp.concatenate([-x32[..., hd // 2:], x32[..., : hd // 2]], -1)
-    return (x32 * jnp.cos(ang) + rot * jnp.sin(ang)).astype(x.dtype)
-
-
-def _swiglu(lay, h):
-    a = jnp.dot(h, lay["w1"], preferred_element_type=jnp.float32)
-    b = jnp.dot(h, lay["w3"], preferred_element_type=jnp.float32)
-    return jnp.dot((a * jax.nn.sigmoid(a) * b).astype(h.dtype), lay["w2"])
+    """:func:`~pathway_tpu.models.blocks.cast_plan` with the router's bias
+    kept f32.  No fused or quantized plan for this family yet."""
+    return cast_plan(cfg, params, ("expert_bias",))
 
 
 def _forward(params: dict, cfg: Lfm2Config, k_pool, v_pool, conv, tokens,
@@ -314,61 +273,7 @@ def _forward(params: dict, cfg: Lfm2Config, k_pool, v_pool, conv, tokens,
     return logits, k_pool, v_pool, conv, counts
 
 
-def hybrid_mixed_step(params: dict, cfg: Lfm2Config, k_pool, v_pool, conv,
-                      tokens, positions, row_tables, row_start, row_nvalid,
-                      row_token_idx, tok_row, tok_col, slot_blocks,
-                      slot_offsets, logit_idx, row_slot, *,
-                      attn: str = "reference"):
-    """The ragged fused step (decode rows and prompt chunks on one packed
-    stream) for this family.  A packed token is real where its row's run
-    holds it: padding tokens point at row 0, column 0, which is another
-    token's place."""
-    T = tokens.shape[0]
-    valid = row_token_idx[tok_row, tok_col] == jnp.arange(T, dtype=jnp.int32)
-    return _forward(
-        params, cfg, k_pool, v_pool, conv, tokens, positions, row_tables,
-        row_start, row_nvalid, row_token_idx, tok_row, tok_col, slot_blocks,
-        slot_offsets, logit_idx, row_slot, valid, attn=attn, decode=False)
-
-
-def hybrid_decode_step(params: dict, cfg: Lfm2Config, k_pool, v_pool, conv,
-                       token, positions, block_tables, slot_blocks,
-                       slot_offsets, row_slot, *, attn: str = "reference"):
-    """One token a row.  An idle row has the null block first in its
-    table and rides slot 0."""
-    B = token.shape[0]
-    rows = jnp.arange(B, dtype=jnp.int32)
-    return _forward(
-        params, cfg, k_pool, v_pool, conv, token, positions, block_tables,
-        positions, jnp.ones((B,), jnp.int32), rows[:, None], rows,
-        jnp.zeros((B,), jnp.int32), slot_blocks, slot_offsets, rows,
-        row_slot, block_tables[:, 0] > 0, attn=attn, decode=True)
-
-
-def hybrid_chained_decode(params: dict, cfg: Lfm2Config, k_pool, v_pool,
-                          conv, token, positions, block_tables, slot_blocks,
-                          slot_offsets, row_slot, *, attn: str = "reference"):
-    """K greedy decode steps in one program (``slot_blocks`` /
-    ``slot_offsets`` (B, K), the host's pre-extended slots), step t's ids
-    feeding step t + 1.  Returns ``(ids (B, K), k_pool, v_pool, conv,
-    counts)``."""
-    from ..ops.moe import COUNTER_TAIL
-
-    K = slot_blocks.shape[1]
-    maxp = cfg.max_len - 1
-
-    def body(carry, xs):
-        tok, kp, vp, cv, cnt = carry
-        sb, so, t = xs
-        logits, kp, vp, cv, n_tok = hybrid_decode_step(
-            params, cfg, kp, vp, cv, tok, jnp.minimum(positions + t, maxp),
-            block_tables, sb, so, row_slot, attn=attn)
-        ids = greedy_ids(logits)
-        return (ids, kp, vp, cv, cnt + n_tok), ids
-
-    init = (token.astype(jnp.int32), k_pool, v_pool, conv,
-            jnp.zeros((cfg.n_experts + len(COUNTER_TAIL),), jnp.int32))
-    (_last, k_pool, v_pool, conv, counts), ids = jax.lax.scan(
-        body, init, (slot_blocks.T, slot_offsets.T,
-                     jnp.arange(K, dtype=jnp.int32)))
-    return ids.T, k_pool, v_pool, conv, counts
+# the three programs of the step contract; ``_forward`` is found by name at
+# trace time
+hybrid_mixed_step, hybrid_decode_step, hybrid_chained_decode = greedy_steps(
+    lambda *a, **kw: _forward(*a, **kw), lambda cfg: cfg.n_experts)

@@ -57,10 +57,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .afmoe import FULL, SLIDING, windowed_steps
+from .blocks import (FULL, SLIDING, _partial_rope, _rms, _swiglu, cast_plan,
+                     greedy_steps)
 from .encoder import _resolve_dtype
-from .lfm2 import _rms, _swiglu, greedy_ids  # noqa: F401
-from .qwen3_next import _partial_rope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -200,20 +199,10 @@ def init_mimo_v2_flash_params(cfg: MimoV2FlashConfig, rng: jax.Array,
     return params
 
 
-_F32_LEAVES = ("sinks", "expert_bias")
-
-
 def plan_params(cfg: MimoV2FlashConfig, params: dict) -> dict:
-    """What the engine dispatches with: the parameters as they are where
-    they already have the configuration's dtype, else cast once (the sinks
-    and the router's bias stay f32)."""
-    dtype = _resolve_dtype(cfg.dtype)
-
-    def cast(path, leaf):
-        keep = any(getattr(k, "key", None) in _F32_LEAVES for k in path)
-        return leaf if keep or leaf.dtype == dtype else leaf.astype(dtype)
-
-    return jax.tree_util.tree_map_with_path(cast, params)
+    """:func:`~pathway_tpu.models.blocks.cast_plan` with the sinks and the
+    router's bias kept f32."""
+    return cast_plan(cfg, params, ("sinks", "expert_bias"))
 
 
 def _values(v, scale: float):
@@ -305,5 +294,5 @@ def _forward(params: dict, cfg: MimoV2FlashConfig, k_pool, v_pool, kw_pool,
 # the three programs of the windowed contract, with ``afmoe``'s argument
 # lists; ``_forward`` is found by name at trace time
 windowed_mixed_step, windowed_decode_step, windowed_chained_decode = \
-    windowed_steps(lambda *a, **kw: _forward(*a, **kw),
-                   lambda cfg: cfg.held_experts)
+    greedy_steps(lambda *a, **kw: _forward(*a, **kw),
+                 lambda cfg: cfg.held_experts)

@@ -64,11 +64,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .afmoe import _gated
+from .blocks import (_carried, _conv_inputs, _fresh_rows, _gated, _l2norm,
+                     _partial_rope, _rms, _swiglu, cast_plan,
+                     decay_parameters, greedy_steps)
 from .encoder import _resolve_dtype
-from .kimi_linear import (_carried, _conv_inputs, _fresh_rows, _l2norm,
-                          decay_parameters)
-from .lfm2 import _rms, _rope, _swiglu, greedy_ids  # noqa: F401
 
 GDN, FULL = "linear_attention", "full_attention"
 
@@ -180,7 +179,7 @@ def init_qwen3_next_params(cfg: Qwen3NextConfig, rng: jax.Array,
     N(0, 1/fan_in), embeddings 0.02, zero-centred norm scales N(0, 0.1^2)
     (the plain one of the DeltaNet output norm 1 +- 0.1), conv taps N(0,
     1/taps), the decay's parameters by
-    :func:`pathway_tpu.models.kimi_linear.decay_parameters` (the ``a`` half
+    :func:`pathway_tpu.models.blocks.decay_parameters` (the ``a`` half
     of ``wba`` at a fifth of its fan-in scale, so that a head's decay a
     token lies between 0.9 and 0.9999).  The output projections (``wo``,
     ``w2``, the shared expert's too) of every layer after the first are
@@ -229,20 +228,10 @@ def init_qwen3_next_params(cfg: Qwen3NextConfig, rng: jax.Array,
     return params
 
 
-_F32_LEAVES = ("a_log", "dt_bias")
-
-
 def plan_params(cfg: Qwen3NextConfig, params: dict) -> dict:
-    """What the engine dispatches with: the parameters as they are where
-    they already have the configuration's dtype, else cast once (the
-    decay's parameters stay f32)."""
-    dtype = _resolve_dtype(cfg.dtype)
-
-    def cast(path, leaf):
-        keep = any(getattr(k, "key", None) in _F32_LEAVES for k in path)
-        return leaf if keep or leaf.dtype == dtype else leaf.astype(dtype)
-
-    return jax.tree_util.tree_map_with_path(cast, params)
+    """:func:`~pathway_tpu.models.blocks.cast_plan` with the decay's
+    parameters kept f32."""
+    return cast_plan(cfg, params, ("a_log", "dt_bias"))
 
 
 # -- the block math -----------------------------------------------------------
@@ -256,13 +245,6 @@ def _scale(w):
 def _zrms(x, w, eps: float, dtype=None):
     """``N(x; w)``: RMSNorm in f32 with the zero-centred scale ``w``."""
     return _rms(x, _scale(w), eps, dtype)
-
-
-def _partial_rope(x, positions, theta: float, rot: int):
-    """Rotate-half rotary on the first ``rot`` of a head's values, the
-    others pass.  x (T, H, hd)."""
-    return jnp.concatenate(
-        [_rope(x[..., :rot], positions, theta), x[..., rot:]], axis=-1)
 
 
 def _key_heads(x, rep: int):
@@ -408,68 +390,8 @@ def _forward(params: dict, cfg: Qwen3NextConfig, k_pool, v_pool, conv, state,
     return logits, k_pool, v_pool, conv, state, counts
 
 
-def kv_state_mixed_step(params: dict, cfg: Qwen3NextConfig, k_pool, v_pool,
-                        conv, state, tokens, positions, row_tables,
-                        row_start, row_nvalid, row_token_idx, tok_row,
-                        tok_col, slot_blocks, slot_offsets, logit_idx,
-                        row_slot, *, attn: str = "reference"):
-    """The ragged fused step (decode rows and prompt chunks on one packed
-    stream) for this family.  A packed token is real where its row's run
-    holds it (padding tokens point at row 0, column 0, which is another
-    token's place); a row is real where its first token is its own."""
-    T, B = tokens.shape[0], row_start.shape[0]
-    valid = row_token_idx[tok_row, tok_col] == jnp.arange(T, dtype=jnp.int32)
-    first = row_token_idx[:, 0]
-    row_live = valid[first] & (tok_row[first] == jnp.arange(B, dtype=jnp.int32))
-    return _forward(
-        params, cfg, k_pool, v_pool, conv, state, tokens, positions,
-        row_tables, row_start, row_nvalid, row_token_idx, tok_row, tok_col,
-        slot_blocks, slot_offsets, logit_idx, row_slot, valid, row_live,
-        attn=attn, decode=False)
-
-
-def kv_state_decode_step(params: dict, cfg: Qwen3NextConfig, k_pool, v_pool,
-                         conv, state, token, positions, block_tables,
-                         slot_blocks, slot_offsets, row_slot, *,
-                         attn: str = "reference"):
-    """One token a row.  An idle row has the null block first in its table
-    and rides slot 0."""
-    B = token.shape[0]
-    rows = jnp.arange(B, dtype=jnp.int32)
-    live = block_tables[:, 0] > 0
-    return _forward(
-        params, cfg, k_pool, v_pool, conv, state, token, positions,
-        block_tables, positions, jnp.ones((B,), jnp.int32), rows[:, None],
-        rows, jnp.zeros((B,), jnp.int32), slot_blocks, slot_offsets, rows,
-        row_slot, live, live, attn=attn, decode=True)
-
-
-def kv_state_chained_decode(params: dict, cfg: Qwen3NextConfig, k_pool,
-                            v_pool, conv, state, token, positions,
-                            block_tables, slot_blocks, slot_offsets,
-                            row_slot, *, attn: str = "reference"):
-    """K greedy decode steps in one program (``slot_blocks`` /
-    ``slot_offsets`` (B, K), the host's pre-extended slots), step t's ids
-    feeding step t + 1, the state riding the scan.  Returns ``(ids (B, K),
-    k_pool, v_pool, conv, state, counts)``."""
-    from ..ops.moe import COUNTER_TAIL
-
-    K = slot_blocks.shape[1]
-    maxp = cfg.max_len - 1
-
-    def body(carry, xs):
-        tok, kp, vp, cv, st, cnt = carry
-        sb, so, t = xs
-        logits, kp, vp, cv, st, n_tok = kv_state_decode_step(
-            params, cfg, kp, vp, cv, st, tok,
-            jnp.minimum(positions + t, maxp), block_tables, sb, so, row_slot,
-            attn=attn)
-        ids = greedy_ids(logits)
-        return (ids, kp, vp, cv, st, cnt + n_tok), ids
-
-    init = (token.astype(jnp.int32), k_pool, v_pool, conv, state,
-            jnp.zeros((cfg.held_experts + len(COUNTER_TAIL),), jnp.int32))
-    (_last, k_pool, v_pool, conv, state, counts), ids = jax.lax.scan(
-        body, init, (slot_blocks.T, slot_offsets.T,
-                     jnp.arange(K, dtype=jnp.int32)))
-    return ids.T, k_pool, v_pool, conv, state, counts
+# the three programs of the step contract; ``_forward`` is found by name at
+# trace time
+kv_state_mixed_step, kv_state_decode_step, kv_state_chained_decode = \
+    greedy_steps(lambda *a, **kw: _forward(*a, **kw),
+                 lambda cfg: cfg.held_experts, row_live=True)

@@ -589,21 +589,46 @@ def _imported_modules(path: str, package: str) -> set[str]:
     return found
 
 
+_FAMILY_MODULES = ("decoder", "lfm2", "afmoe", "kimi_linear", "qwen3_next",
+                   "mimo_v2_flash")
+
+
 def test_the_engine_imports_no_models_math():
-    # kvcache/engine.py -> models/families.py -> models/decoder.py |
-    # models/lfm2.py | models/afmoe.py: the engine reaches a model's
-    # programs through its family and no other way
+    # kvcache/engine.py -> models/families.py -> the family modules and
+    # models/blocks.py: the engine reaches a model's programs through its
+    # family and no other way
     import pathway_tpu.kvcache.engine as engine_mod
 
     mods = _imported_modules(engine_mod.__file__, "pathway_tpu.kvcache")
     assert "pathway_tpu.models.families" in mods
     models = {m for m in mods if m.startswith("pathway_tpu.models")}
     assert not {m for m in models
-                if m.split(".")[2] in ("decoder", "lfm2", "afmoe")}, models
+                if m.split(".")[2] in _FAMILY_MODULES + ("blocks",)}, models
     with open(engine_mod.__file__) as f:
         src = f.read()
-    assert not any("models." + m in src for m in ("decoder", "lfm2", "afmoe"))
+    assert not any("models." + m in src
+                   for m in _FAMILY_MODULES + ("blocks",))
     assert "afmoe" not in src  # no keyword, no branch names the family
+
+
+@pytest.mark.parametrize("family", _FAMILY_MODULES)
+def test_a_family_module_imports_no_other_family_module(family):
+    """Each family module takes what it shares from models/blocks.py (or
+    models/encoder.py), never from another family module, and blocks.py
+    imports no family module: a family is removed or edited alone."""
+    import importlib
+
+    mod = importlib.import_module(f"pathway_tpu.models.{family}")
+    mods = _imported_modules(mod.__file__, "pathway_tpu.models")
+    others = {m for m in mods if m.startswith("pathway_tpu.models.")
+              and m.split(".")[2] in _FAMILY_MODULES
+              and m.split(".")[2] != family}
+    assert not others, others
+    blocks = importlib.import_module("pathway_tpu.models.blocks")
+    assert not {m for m in _imported_modules(blocks.__file__,
+                                             "pathway_tpu.models")
+                if m.startswith("pathway_tpu.models.")
+                and m.split(".")[2] == family}
 
 
 # -- the fifth kind: a K/V pool beside the state arena (PR 38) ------------------

@@ -100,7 +100,7 @@ def _logits_through_engine(cfg, params, prompt, n_new, name, monkeypatch,
     import jax
     import jax.numpy as jnp
 
-    from pathway_tpu.models import kimi_linear
+    from pathway_tpu.models import blocks
 
     caught = []
 
@@ -109,7 +109,7 @@ def _logits_through_engine(cfg, params, prompt, n_new, name, monkeypatch,
                            ordered=True)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    monkeypatch.setattr(kimi_linear, "greedy_ids", spy)
+    monkeypatch.setattr(blocks, "greedy_ids", spy)
     eng = _engine(cfg, params, name, **kw)
     tokens = eng.generate(prompt, n_new)
     jax.effects_barrier()

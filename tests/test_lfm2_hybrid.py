@@ -112,7 +112,7 @@ def _logits_through_engine(cfg, params, prompt, n_new, name, attn,
     import jax
     import jax.numpy as jnp
 
-    from pathway_tpu.models import lfm2
+    from pathway_tpu.models import blocks
 
     caught = []
 
@@ -121,7 +121,7 @@ def _logits_through_engine(cfg, params, prompt, n_new, name, attn,
                            ordered=True)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    monkeypatch.setattr(lfm2, "greedy_ids", spy)
+    monkeypatch.setattr(blocks, "greedy_ids", spy)
     eng = _engine(cfg, params, name, attn=attn)
     tokens = eng.generate(prompt, n_new)
     jax.effects_barrier()

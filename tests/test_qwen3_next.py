@@ -98,7 +98,7 @@ def _spy(monkeypatch, rows=slice(0, 1)):
     import jax
     import jax.numpy as jnp
 
-    from pathway_tpu.models import qwen3_next
+    from pathway_tpu.models import blocks
 
     caught = []
 
@@ -107,7 +107,7 @@ def _spy(monkeypatch, rows=slice(0, 1)):
                            logits, ordered=True)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    monkeypatch.setattr(qwen3_next, "greedy_ids", spy)
+    monkeypatch.setattr(blocks, "greedy_ids", spy)
     return caught
 
 
