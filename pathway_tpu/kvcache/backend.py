@@ -2,7 +2,7 @@
 
 The engine (engine.py: admission, rounds, restart, sessions) programs
 against :class:`CacheBackend`, not against a layout; how a sequence's
-decode state lives in HBM is the backend's.  Five kinds serve cells:
+decode state lives in HBM is the backend's.  Six kinds serve cells:
 
 - ``"paged"`` — :class:`~pathway_tpu.kvcache.block_pool.BlockPool`: K/V
   blocks for every layer, addressed through per-sequence block tables;
@@ -28,6 +28,10 @@ decode state lives in HBM is the backend's.  Five kinds serve cells:
   blocks go back to their free list once they lie behind the window.
   Preemption only: a shared block could be freed behind one sequence's
   window while another still reads it.
+- ``"window_state"`` — :class:`~pathway_tpu.kvcache.windowed
+  .WindowStateCache`: the windowed kind's two pools and tables beside the
+  state kind's slot arena (carried conv inputs and an f32 state a scan
+  layer), one slot a sequence.  Preemption only, as both.
 
 A backend owns:
 
@@ -80,8 +84,8 @@ class CacheBackend(abc.ABC):
     """Abstract engine↔cache contract.  See the module docstring for
     which side owns which invariant."""
 
-    #: "paged" | "hybrid" | "windowed" | "latent_state" | "kv_state" — the
-    #: factory key
+    #: "paged" | "hybrid" | "windowed" | "latent_state" | "kv_state" |
+    #: "window_state" — the factory key
     cache_kind: str = "abstract"
     #: positions a sliding-window layer's query sees (itself included);
     #: None: every layer keeps every key
@@ -180,17 +184,22 @@ class CacheBackend(abc.ABC):
 
 
 class ExpertCounts:
-    """What the step programs of a family with expert layers count on the
-    device, one ``int32`` vector a program (ops/moe.py ``expert_ffn``,
-    summed over the expert layers): the tokens each held
+    """What the step programs of a routed family count on the device, one
+    ``int32`` vector a program (ops/moe.py ``expert_ffn``, summed over the
+    expert layers): the tokens each held
     expert received, then ``COUNTER_TAIL`` (pairs routed to experts held
     elsewhere, the grouped matmul's live rows in units of 16, held experts
     with at least one pair, the passes counted, the kernel's live row tiles
-    whatever their height).  Kept as they come back, read
+    whatever their height), then the family's own ``counters`` (a stat's
+    name each; none by default).  Kept as they come back, read
     after the next sync (:meth:`fold_expert_counts`, the vector's one
     reader) into the cache's stats."""
 
     _expert_counts: tuple = ()  # the programs' counts not yet read back
+
+    def __init__(self, *, counters: tuple = (), **kwargs):
+        self.counters = tuple(counters)
+        super().__init__(**kwargs)
 
     def keep_expert_counts(self, counts) -> None:
         try:
@@ -205,10 +214,11 @@ class ExpertCounts:
         from ..ops.moe import COUNTER_TAIL
 
         pending, self._expert_counts = self._expert_counts, ()
-        n = len(COUNTER_TAIL)
+        tail = COUNTER_TAIL + self.counters
+        n = len(tail)
         for counts in pending:
             counts = np.asarray(counts)
-            self.stats.record_moe(counts[:-n], zip(COUNTER_TAIL, counts[-n:]))
+            self.stats.record_moe(counts[:-n], zip(tail, counts[-n:]))
 
 
 # a cache kind: the module (of this package) and the class that hold it,
@@ -217,7 +227,8 @@ _KINDS = {"paged": ("block_pool", "BlockPool"),
           "hybrid": ("hybrid", "HybridCache"),
           "windowed": ("windowed", "WindowedCache"),
           "latent_state": ("hybrid", "StateCache"),
-          "kv_state": ("hybrid", "KVStateCache")}
+          "kv_state": ("hybrid", "KVStateCache"),
+          "window_state": ("windowed", "WindowStateCache")}
 
 
 def make_backend(kind: str, **kwargs) -> CacheBackend:
@@ -230,7 +241,9 @@ def make_backend(kind: str, **kwargs) -> CacheBackend:
     table for the sliding-window layers); ``"latent_state"`` / ``"kv_state"``
     → :class:`~pathway_tpu.kvcache.hybrid.StateCache` /
     :class:`~pathway_tpu.kvcache.hybrid.KVStateCache` (a latent pool, or a
-    K/V pool, beside a conv slot and a matrix state a sequence)."""
+    K/V pool, beside a conv slot and a matrix state a sequence);
+    ``"window_state"`` → :class:`~pathway_tpu.kvcache.windowed
+    .WindowStateCache` (the windowed pools beside that slot arena)."""
     if kind not in _KINDS:
         raise ValueError(f"unknown cache backend {kind!r}; known: "
                          f"{', '.join(_KINDS)}")

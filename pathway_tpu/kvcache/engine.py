@@ -641,7 +641,7 @@ class PagedDecodeEngine:
         self._query_layout = query_layout(
             self.mixed_tokens, B, C, H, hd, lanes, dtype,
             Dv=0 if self.pool.v is None else self.pool.v.shape[-1] // self.tp,
-            keys=keys)
+            keys=keys, v_heads=self.family.attn_v_heads(cfg))
         cols = self._query_layout[0]
         self._query_tile_cols = query_tile_columns(
             np.arange(C + 1), C, H, hd, lanes, dtype, hd_v=hd_v, cols=cols)

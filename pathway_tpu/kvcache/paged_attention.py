@@ -243,7 +243,7 @@ def _require_positive_context(C: int, context_lens, start_pos, n_valid):
 def paged_attention_reference(q, k_pool, v_pool, block_tables,
                               context_lens=None, *, start_pos=None,
                               n_valid=None, window: int | None = None,
-                              sinks=None):
+                              sinks=None, v_heads: int | None = None):
     """Gather-based ragged paged attention.
 
     q: (B, C, H, hd) — C consecutive query tokens per row (C=1 decode);
@@ -260,7 +260,10 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     the keys at ``p - window < j <= p`` only.  The V pool's heads may have
     a width of their own (``hd_v = v lanes / Hkv``: the output's).
     ``sinks`` (H,) f32: one more logit a query head in its softmax, whose
-    probability is dropped (it carries no value).
+    probability is dropped (it carries no value).  ``v_heads`` (None: as
+    many as K/V heads): the V pool holds that many heads, each ``v lanes /
+    v_heads`` wide, and K/V head ``j`` reads V head ``j % v_heads`` - a
+    differential layer's two key halves over one set of values.
     Returns (B, C, H, hd_v).
     """
     B, C = q.shape[:2]
@@ -272,7 +275,9 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     # per-(row, column) context: min(c0 + c, cl_last)
     ctx = jnp.minimum(c0[:, None] + jnp.arange(C)[None, :], cl_last[:, None])
     k = k_pool[block_tables].reshape(B, NB * BS, -1, hd)
-    v = v_pool[block_tables].reshape(B, NB * BS, k.shape[2], -1)
+    v = v_pool[block_tables].reshape(B, NB * BS, v_heads or k.shape[2], -1)
+    if v_heads is not None:  # K/V head j reads V head j % v_heads
+        v = jnp.tile(v, (1, 1, k.shape[2] // v_heads, 1))
     if k.shape[2] != H:  # grouped queries: each K/V head serves H/Hkv
         k = jnp.repeat(k, H // k.shape[2], axis=2)
         v = jnp.repeat(v, H // v.shape[2], axis=2)
@@ -521,7 +526,8 @@ def window_pairs(start_pos, n_valid, C: int, H: int, hd: int, D: int, dtype,
 
 def _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, live=None, *, C: int,
                G: int, hd: int, tiles: tuple | None = None,
-               hd_v: int | None = None, sink_ref=None, rep: int = 1):
+               hd_v: int | None = None, sink_ref=None, rep: int = 1,
+               v_heads: int | None = None):
     """First grid step of a batch row: reset the online softmax and lay
     the row's queries out for the grouped matmuls, for the row's live
     column tiles (:func:`_live_tiles`; one tile of ``C`` columns without
@@ -582,7 +588,8 @@ def _start_row(q_ref, qm_ref, m_ref, l_ref, acc_ref, live=None, *, C: int,
 def _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref, acc_ref,
                  live=None, *, scale: float, rep: int, C: int, G: int,
                  hd: int, window: int | None = None,
-                 tiles: tuple | None = None, hd_v: int | None = None):
+                 tiles: tuple | None = None, hd_v: int | None = None,
+                 v_heads: int | None = None):
     """One visible span's online-softmax update, shared by both kernels:
     the ``span`` keys (a lane tile's worth: K blocks of the pool) that grid
     step ``j`` attends, so that scores, mask, ``exp`` and row sums fill the
@@ -602,7 +609,12 @@ def _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref, acc_ref,
     query column ``c // rep``.  ``window``: a column of context ``n``
     (position ``n - 1``) sees the keys at ``n - window <= j < n`` only.
     ``hd_v``: ``vbuf``'s heads are that wide, so the group's lanes of V and
-    acc's width are ``G * hd_v`` where K's are ``G * hd``."""
+    acc's width are ``G * hd_v`` where K's are ``G * hd``.  ``v_heads``
+    (a differential layer): ``vbuf`` holds that many heads and K/V head
+    ``j`` reads V head ``j % v_heads``, so group ``g``'s V lanes are those of
+    group ``g % (v_heads / G)``; a group wider than the V heads (every head
+    one group) takes ``p @ v`` over all of V once and adds it to each of its
+    ``G / v_heads`` copies in acc."""
     W, span = G * hd, kbuf.shape[1]
     n_groups = qm_ref.shape[0] // (G * C)
 
@@ -627,7 +639,7 @@ def _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref, acc_ref,
             valid = valid & (k_pos >= col_ctx - window)
         return valid
 
-    def update(rows, lanes, vlanes, valid):
+    def update(rows, lanes, vlanes, valid, copies: int = 1):
         n = valid.shape[0]
         s = jax.lax.dot_general(
             qm_ref[rows], kbuf[slot, :, lanes],
@@ -646,11 +658,22 @@ def _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref, acc_ref,
             (n, l_ref.shape[1]),
         )
         vb = vbuf[slot, :, vlanes]
-        acc_ref[rows] = acc_ref[rows] * corr + jax.lax.dot_general(
-            p.astype(vb.dtype), vb,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        if copies == 1:
+            acc_ref[rows] = acc_ref[rows] * corr + jax.lax.dot_general(
+                p.astype(vb.dtype), vb,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        else:
+            pv = jax.lax.dot_general(
+                p.astype(vb.dtype), vb,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            w = pv.shape[1]
+            for r in range(copies):
+                part = slice(r * w, (r + 1) * w)
+                acc_ref[rows, part] = acc_ref[rows, part] * corr + pv
         m_ref[rows] = jnp.broadcast_to(m_new, (n, m_ref.shape[1]))
 
     def attend(t0, Tc: int):
@@ -658,8 +681,14 @@ def _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref, acc_ref,
 
         def group(g):
             rows, lanes = _group(g, n_groups, G, W, t0, Tc)
-            update(rows, lanes,
-                   lanes if hd_v is None else _lanes(g, G * hd_v), valid)
+            if v_heads is None:
+                update(rows, lanes,
+                       lanes if hd_v is None else _lanes(g, G * hd_v), valid)
+            elif G <= v_heads:
+                update(rows, lanes, _lanes(g % (v_heads // G), G * hd_v),
+                       valid)
+            else:
+                update(rows, lanes, slice(None), valid, G // v_heads)
 
         _each_group(n_groups, tiles, group)
 
@@ -667,7 +696,8 @@ def _attend_span(j, c0, ctx, kbuf, vbuf, slot, qm_ref, m_ref, l_ref, acc_ref,
 
 
 def _write_out(o_ref, l_ref, acc_ref, live=None, *, C: int, G: int, hd: int,
-               tiles: tuple | None = None, hd_v: int | None = None):
+               tiles: tuple | None = None, hd_v: int | None = None,
+               v_heads: int | None = None):
     """o (C, H*hd_v): each head's lanes from its own rows of acc / l, for the
     row's live column tiles (:func:`_live_tiles`); with ``tiles`` the
     columns past them are written as zeros (padding the caller drops, which
@@ -869,14 +899,25 @@ def _scratch(K: int, BS: int, D: int, pool_dtype, H: int, C: int, hd: int,
     ]
 
 
-def _value_geometry(hd: int, D: int, Dv: int) -> tuple:
+def _value_geometry(hd: int, D: int, Dv: int,
+                    v_heads: int | None = None) -> tuple:
     """``(H, hd_v, geom)`` of pools of ``D`` key and ``Dv`` value lanes under
     query heads of ``hd``: the K/V heads, a V head's width, and what the
     kernels' pieces take of it - nothing where V's heads are K's width, so
-    that those kernels trace to what they traced to."""
+    that those kernels trace to what they traced to.  ``v_heads``: V holds
+    that many heads (:func:`_attend_span`), none: as many as K."""
     H = D // hd
+    if v_heads is not None:
+        return H, Dv // v_heads, {"hd_v": Dv // v_heads, "v_heads": v_heads}
     hd_v = Dv // H
     return H, hd_v, ({} if hd_v == hd else {"hd_v": hd_v})
+
+
+def _check_v_groups(G: int, v_heads: int | None) -> None:
+    """A group of K/V heads reads whole groups of V heads, or all of them
+    over and over (:func:`_attend_span`)."""
+    if v_heads is not None and (v_heads % G if G <= v_heads else G % v_heads):
+        raise ValueError(f"{G} heads a group do not tile {v_heads} V heads")
 
 
 def _fold_queries(q, D: int):
@@ -945,7 +986,7 @@ def _vmem_need(K: int, BS: int, D: int, pool_dtype, H: int, C: int, hd: int,
     item = jnp.dtype(dtype).itemsize
     return 2 * K * BS * (D + Dv) * jnp.dtype(pool_dtype).itemsize \
         + H * C * G * (hd * item + hd_v * 4) \
-        + 2 * H * C * 128 * 4 + 2 * C * (D + Dv) * item
+        + 2 * H * C * 128 * 4 + 2 * C * (D + H * hd_v) * item
 
 
 def _vmem_limit(K: int, BS: int, D: int, pool_dtype, H: int, C: int, hd: int,
@@ -965,7 +1006,7 @@ def _vmem_limit(K: int, BS: int, D: int, pool_dtype, H: int, C: int, hd: int,
 
 def _paged_ragged_fn(q, k_pool, v_pool, layer, block_tables, c0, cl,
                      sinks=None, *, d_true: int, interpret: bool = False,
-                     window: int | None = None):
+                     window: int | None = None, v_heads: int | None = None):
     """q: (B, C, H, hd); pools (L, num_blocks, BS, Hkv*hd) - ALL layers'
     stacked pool, read in place at ``layer`` ((1,) int32): the kernel's own
     copies carry the layer, so no layer is ever sliced out of the pool.  A
@@ -978,7 +1019,9 @@ def _paged_ragged_fn(q, k_pool, v_pool, layer, block_tables, c0, cl,
     (``Hkv * hd_v``: a V head narrower than a K head, read from the two
     pools' shapes; the output is (B, C, H, hd_v)); ``sinks`` (H,) f32, or
     None: a learned logit a query head that joins its softmax's
-    denominator (:func:`_start_row`).  A mixed step's rows come here as
+    denominator (:func:`_start_row`); ``v_heads``: the V pool's heads where
+    K/V head ``j`` reads V head ``j % v_heads`` (a differential layer; the
+    output is (B, C, H, Dv / v_heads)).  A mixed step's rows come here as
     :func:`query_layout` lays them out: the B rows at the chunk's width, or
     N pieces of P query columns cut from the packed stream (:func:`_pieces`),
     each a row of the grid over its row's table."""
@@ -988,9 +1031,10 @@ def _paged_ragged_fn(q, k_pool, v_pool, layer, block_tables, c0, cl,
     NB = block_tables.shape[1]
     K = span_blocks(BS, NB, math.gcd(D, Dv))
     qf, rep = _fold_queries(q, D)
-    H, hd_v, geom = _value_geometry(hd, D, Dv)
+    H, hd_v, geom = _value_geometry(hd, D, Dv, v_heads)
     C = qf.shape[1]
     G = _heads_per_group(H, hd, C, geom.get("hd_v"))
+    _check_v_groups(G, v_heads)
     operands, sink_specs, geom = _sink_rows(
         sinks, H, rep, (qf, k_pool, v_pool), geom)
     kernel = functools.partial(
@@ -1007,14 +1051,14 @@ def _paged_ragged_fn(q, k_pool, v_pool, layer, block_tables, c0, cl,
         grid=(B, -(-NB // K)),
         in_specs=[*sink_specs, row(D), _pool_spec(K, BS, D, window),
                   _pool_spec(K, BS, Dv, window)],
-        out_specs=row(Dv),
+        out_specs=row(H * hd_v),
         scratch_shapes=_scratch(K, BS, D, k_pool.dtype, H, C, hd, G, q.dtype,
                                 Dv, hd_v),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C, Dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, C, H * hd_v), q.dtype),
         interpret=interpret,
         **_vmem_limit(K, BS, D, k_pool.dtype, H, C, hd, G, q.dtype, Dv, hd_v),
     )(layer, block_tables, c0, cl, *operands)
@@ -1025,7 +1069,8 @@ def _make_paged_ragged():
     """Jit the standalone kernel entry point through the device cost
     observatory (Round-14); falls back to a plain jit while the obs
     package is still importing (circular-import window)."""
-    kwargs = dict(static_argnames=("d_true", "interpret", "window"))
+    kwargs = dict(static_argnames=("d_true", "interpret", "window",
+                                   "v_heads"))
     try:
         from ..obs.profiler import profiled_jit
 
@@ -1096,7 +1141,8 @@ def _append_kernel(li_ref, bt_ref, c0_ref, cl_ref, so_ref, *refs, K: int,
 
 def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, layer, block_tables,
                      c0, cl, slot_offsets, sinks=None, *, d_true: int,
-                     interpret: bool = False, window: int | None = None):
+                     interpret: bool = False, window: int | None = None,
+                     v_heads: int | None = None):
     """q: (B, 1, H, hd); k_new/v_new: (B, Hkv, hd); pools
     (L, num_blocks, BS, Hkv*hd) - ALL layers' stacked pool, returned
     UPDATED at ``layer`` ((1,) int32), aliased in place on TPU: one tail
@@ -1104,17 +1150,18 @@ def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, layer, block_tables,
     copied.  Contract: the slot is the tail of the attended context
     (``slot_blocks[b] == block_tables[b, (cl[b]-1)//BS]`` and
     ``slot_offsets[b] == (cl[b]-1) % BS``) - the decode append the
-    engine constructs by definition.  V's lanes and ``sinks``: as
-    :func:`_paged_ragged_fn` (``v_new`` (B, Hkv, hd_v))."""
+    engine constructs by definition.  V's lanes, ``sinks`` and ``v_heads``:
+    as :func:`_paged_ragged_fn` (``v_new`` (B, V heads, hd_v))."""
     B, hd = q.shape[0], q.shape[3]
     BS, D = k_pool.shape[2:]
     Dv = v_pool.shape[3]
     NB = block_tables.shape[1]
     K = span_blocks(BS, NB, math.gcd(D, Dv))
     qf, rep = _fold_queries(q, D)
-    H, hd_v, geom = _value_geometry(hd, D, Dv)
+    H, hd_v, geom = _value_geometry(hd, D, Dv, v_heads)
     C = qf.shape[1]
     G = _heads_per_group(H, hd, C, geom.get("hd_v"))
+    _check_v_groups(G, v_heads)
     operands, sink_specs, geom = _sink_rows(
         sinks, H, rep, (qf, k_new.reshape(B, 1, D), v_new.reshape(B, 1, Dv),
                         k_pool, v_pool), geom)
@@ -1138,7 +1185,7 @@ def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, layer, block_tables,
                   _pool_spec(K, BS, D, window),
                   _pool_spec(K, BS, Dv, window)],
         out_specs=[
-            _row(C, Dv),
+            _row(C, H * hd_v),
             pl.BlockSpec((None, None, BS, D), _slot_map),
             pl.BlockSpec((None, None, BS, Dv), _slot_map),
         ],
@@ -1153,7 +1200,7 @@ def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, layer, block_tables,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, C, Dv), q.dtype),
+            jax.ShapeDtypeStruct((B, C, H * hd_v), q.dtype),
             jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
             jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
         ],
@@ -1164,7 +1211,8 @@ def _paged_append_fn(q, k_new, v_new, k_pool, v_pool, layer, block_tables,
 
 
 def _make_paged_append():
-    kwargs = dict(static_argnames=("d_true", "interpret", "window"),
+    kwargs = dict(static_argnames=("d_true", "interpret", "window",
+                                   "v_heads"),
                   donate_argnums=(3, 4))
     try:
         from ..obs.profiler import profiled_jit
@@ -1259,7 +1307,7 @@ _SPLIT_TRIPS = 5  # HBM round trips of a slot where the fold splits lane tiles
 
 def query_layout(T: int, B: int, C: int, H: int, hd: int, D: int, dtype, *,
                  Dv: int | None = None, keys: int,
-                 pool_dtype=None) -> tuple:
+                 pool_dtype=None, v_heads: int | None = None) -> tuple:
     """``(P, N)``: how :func:`paged_attention` and :func:`latent_attention`
     lay a packed step of ``T`` tokens in ``B`` rows of ``C`` columns out for
     their kernel - ``H`` query heads of ``hd`` over pools of ``D`` key and
@@ -1294,10 +1342,13 @@ def query_layout(T: int, B: int, C: int, H: int, hd: int, D: int, dtype, *,
     - MiMo-V2-Flash, 64 heads of 192 (values 128) on 4 (full layers) and 8
       (window layers), C 512, 8,192 keys: P 64 on both;
     - Kimi-Linear's latent pool, 32 heads folded on one stored row of 640
-      lanes, C 512, 8,192 keys: P 64 (N 24)."""
+      lanes, C 512, 8,192 keys: P 64 (N 24).
+
+    ``v_heads`` (a differential layer, :func:`_paged_ragged_fn`): a query
+    head's output is ``Dv / v_heads`` wide."""
     Dv = D if Dv is None else Dv
     Hkv = D // hd
-    rep, hd_v = H // Hkv, Dv // Hkv or hd
+    rep, hd_v = H // Hkv, (Dv // v_heads if v_heads else Dv // Hkv) or hd
     split = rep > 1 and hd % _LANES != 0
     slot = H * (hd + hd_v) * jnp.dtype(dtype).itemsize \
         * (_SPLIT_TRIPS if split else 1)
@@ -1730,7 +1781,8 @@ def paged_append_attend(q, k_new, v_new, k_pool, v_pool, block_tables,
                         context_lens, slot_blocks, slot_offsets, *,
                         layer=None, use_pallas: bool | None = None,
                         interpret: bool | None = None,
-                        window: int | None = None, sinks=None):
+                        window: int | None = None, sinks=None,
+                        v_heads: int | None = None):
     """Fused decode append+attend over one layer of the pool: scatter
     the incoming token's K/V at ``(slot_blocks, slot_offsets)`` and
     attend through ``block_tables`` in a single program.
@@ -1760,6 +1812,7 @@ def paged_append_attend(q, k_new, v_new, k_pool, v_pool, block_tables,
         a = paged_attention_reference(
             q, _layer_of(k_pool, layer), _layer_of(v_pool, layer),
             block_tables, context_lens, window=window, sinks=sinks,
+            **({} if v_heads is None else {"v_heads": v_heads}),
         )
         return a, k_pool, v_pool
     _require_positive_context(1, context_lens, None, None)
@@ -1774,6 +1827,7 @@ def paged_append_attend(q, k_new, v_new, k_pool, v_pool, block_tables,
         d_true=hd,
         interpret=(backend != "tpu") if interpret is None else interpret,
         **({} if window is None else {"window": int(window)}),
+        **({} if v_heads is None else {"v_heads": int(v_heads)}),
     )
     return (a, kk[0], vv[0]) if layer is None else (a, kk, vv)
 
@@ -1814,7 +1868,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens=None, *,
                     start_pos=None, n_valid=None, packed=None, layer=None,
                     use_pallas: bool | None = None,
                     interpret: bool | None = None,
-                    window: int | None = None, sinks=None):
+                    window: int | None = None, sinks=None,
+                    v_heads: int | None = None):
     """Dispatch: Pallas kernel on TPU, gather reference elsewhere (the
     interpreted kernel is for tests).  Same signature/shape/raggedness
     contract as :func:`paged_attention_reference`, plus ``layer``: None
@@ -1840,33 +1895,36 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens=None, *,
     backend = jax.default_backend()
     if use_pallas is None:
         use_pallas = backend == "tpu"
+    vh = {} if v_heads is None else {"v_heads": int(v_heads)}
     if not use_pallas:
         if packed is not None:
             idx, tok_row, tok_col = packed
             return paged_attention_reference(
                 q[idx], _layer_of(k_pool, layer), _layer_of(v_pool, layer),
                 block_tables, start_pos=start_pos, n_valid=n_valid,
-                window=window, sinks=sinks)[tok_row, tok_col]
+                window=window, sinks=sinks, **vh)[tok_row, tok_col]
         return paged_attention_reference(
             q, _layer_of(k_pool, layer), _layer_of(v_pool, layer),
             block_tables, context_lens,
             start_pos=start_pos, n_valid=n_valid, window=window, sinks=sinks,
+            **vh,
         )
     kk, vv, li = _stacked(k_pool, v_pool, layer)
     call = _ragged_call(
         kk, vv, li, sinks, hd=q.shape[-1], window=window,
-        interpret=(backend != "tpu") if interpret is None else interpret)
+        interpret=(backend != "tpu") if interpret is None else interpret,
+        **vh)
     return _dispatch(call, q, kk, vv, block_tables, context_lens, start_pos,
-                     n_valid, packed)
+                     n_valid, packed, **vh)
 
 
 def _ragged_call(kk, vv, li, sinks, *, hd: int, window: int | None,
-                 interpret: bool):
+                 interpret: bool, **vh):
     """The ragged kernel on these pools, as :func:`_dispatch` calls it:
-    ``call(rows, tables, c0, cl)``."""
+    ``call(rows, tables, c0, cl)``; ``vh``: ``v_heads``, where given."""
     extra = () if sinks is None else (sinks,)
     kw = dict(d_true=hd, interpret=interpret,
-              **({} if window is None else {"window": int(window)}))
+              **({} if window is None else {"window": int(window)}), **vh)
     return lambda q, tables, c0, cl: _paged_ragged(
         q, kk, vv, li, tables, c0, cl, *extra, **kw)
 
@@ -1877,17 +1935,17 @@ def _latent_call(pool, li, *, scale: float, interpret: bool):
         q, pool, li, tables, c0, cl, scale=scale, interpret=interpret)
 
 
-def _layout(q, T: int, B: int, C: int, kk, vv, tables) -> tuple:
+def _layout(q, T: int, B: int, C: int, kk, vv, tables, **vh) -> tuple:
     """:func:`query_layout` of a call of these queries on these pools and
     tables (``vv`` None: the latent pool, one array)."""
     return query_layout(T, B, C, *q.shape[-2:], kk.shape[3], q.dtype,
                         Dv=0 if vv is None else vv.shape[3],
                         keys=tables.shape[1] * kk.shape[2],
-                        pool_dtype=kk.dtype)
+                        pool_dtype=kk.dtype, **vh)
 
 
 def _dispatch(call, q, kk, vv, block_tables, context_lens, start_pos,
-              n_valid, packed):
+              n_valid, packed, **vh):
     """:func:`paged_attention`'s and :func:`latent_attention`'s kernel
     ``call`` in the layout :func:`query_layout` gives for the queries and
     the pools ``kk`` / ``vv``: a packed step's stream through
@@ -1901,12 +1959,13 @@ def _dispatch(call, q, kk, vv, block_tables, context_lens, start_pos,
         _require_positive_context(1, None, start_pos, n_valid)
         return _packed(call, q, tables, jnp.asarray(start_pos, jnp.int32),
                        jnp.asarray(n_valid, jnp.int32), *packed,
-                       _layout(q, q.shape[0], *idx.shape, kk, vv, tables))
+                       _layout(q, q.shape[0], *idx.shape, kk, vv, tables,
+                               **vh))
     B, C = q.shape[:2]
     _require_positive_context(C, context_lens, start_pos, n_valid)
     c0, cl = (x.astype(jnp.int32) for x in _query_context(
         C, context_lens, start_pos, n_valid))
-    layout = _layout(q, B * C, B, C, kk, vv, tables)
+    layout = _layout(q, B * C, B, C, kk, vv, tables, **vh)
     if layout == (C, B):
         return call(q, tables, c0, cl)
     start, nv = c0 - 1, cl - c0 + 1

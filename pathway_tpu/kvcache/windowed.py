@@ -35,6 +35,11 @@ is rebuilt by recompute.
 Prefix sharing, fork, host tiering and rolled-back slots are off: a
 shared block may be freed behind one sequence's window while another
 still reads it (ROADMAP B3).
+
+:class:`WindowStateCache` (PR 45) is this cache with the slot arena of
+:class:`~pathway_tpu.kvcache.hybrid.KVStateCache` beside it, one slot a
+sequence: a family whose window and full layers sit beside scan layers
+(models/phi4_mini_flash.py).
 """
 
 from __future__ import annotations
@@ -46,6 +51,7 @@ import numpy as np
 
 from .backend import ExpertCounts, UnsupportedCacheOp
 from .block_pool import BlockPool, PoolExhausted, SequenceState
+from .hybrid import HybridCache, KVStateCache
 
 
 def window_seq_blocks(window: int, round_tokens: int, block_size: int) -> int:
@@ -273,3 +279,31 @@ class WindowedCache(ExpertCounts, BlockPool):
                 assert len(table) == self.blocks_for(self._wnext[seq_id]), (
                     f"sequence {seq_id}: window table / reserved positions "
                     "mismatch")
+
+
+class WindowStateCache(WindowedCache, KVStateCache):
+    """A windowed cache with a state slot a sequence: the full layers' K/V
+    in the paged pool, the sliding-window layers' in the window pool (both
+    :class:`WindowedCache`'s, with its freeing rule), and one slot of
+    :class:`~pathway_tpu.kvcache.hybrid.KVStateCache`'s arena a sequence (the
+    carried conv inputs and the f32 state of a family's scan layers,
+    models/phi4_mini_flash.py), claimed and given back with the blocks.  A
+    fresh slot's state is zeroed by the step programs themselves, as the
+    state kind's is.  ``device_state() = (k, v, kw, vw, conv, state)``;
+    ``row_extras``: the rows' window tables, then their slots.  What
+    neither parent allows (prefix sharing, fork, host tiering, rolled-back
+    slots) this kind refuses too."""
+
+    cache_kind = "window_state"
+
+    def device_state(self) -> tuple:
+        return (self.k, self.v, self.kw, self.vw, self.conv, self.state)
+
+    def set_device_state(self, k, v, kw, vw, conv, state, counts) -> None:
+        self.k, self.v, self.kw, self.vw = k, v, kw, vw
+        self.conv, self.state = conv, state
+        self.keep_expert_counts(counts)
+
+    def row_extras(self, seq_ids, n_rows: int, table_blocks: int = 0) -> tuple:
+        return WindowedCache.row_extras(self, seq_ids, n_rows, table_blocks) \
+            + HybridCache.row_extras(self, seq_ids, n_rows, table_blocks)

@@ -1,13 +1,15 @@
 """What the routed block families share: their layer math, the plan cast,
 and the greedy step contract.
 
-The five routed families (:mod:`~pathway_tpu.models.lfm2`,
+The six routed families (:mod:`~pathway_tpu.models.lfm2`,
 :mod:`~pathway_tpu.models.afmoe`, :mod:`~pathway_tpu.models.kimi_linear`,
 :mod:`~pathway_tpu.models.qwen3_next`,
-:mod:`~pathway_tpu.models.mimo_v2_flash`) each write ONE ``_forward`` over a
-packed stream; :func:`greedy_steps` makes the three step programs of the
-paged engine's contract from it, and a family module takes every helper it
-shares from here, never from another family module.  A family module keeps
+:mod:`~pathway_tpu.models.mimo_v2_flash`, and
+:mod:`~pathway_tpu.models.phi4_mini_flash`, which has no expert layer and
+counts zero experts) each write ONE ``_forward`` over a packed stream;
+:func:`greedy_steps` makes the three step programs of the paged engine's
+contract from it, and a family module takes every helper it shares from
+here, never from another family module.  A family module keeps
 a module-level binding of each helper its own ``_forward`` calls, so that a
 test may replace one on that family alone.
 
@@ -138,20 +140,29 @@ def decay_parameters(key, n_heads: int, width: int, *, low=0.955,
 # -- the step contract --------------------------------------------------------
 
 
-def greedy_steps(forward, experts_counted, row_live: bool = False):
+def greedy_steps(forward, experts_counted, row_live: bool = False,
+                 per_row: int = 1, counters: tuple = ()):
     """The three step programs of a routed family, ``(mixed, decode,
     chained)``, over ``(params, cfg, *cache arrays, *host arrays, attn=)``.
 
     ``forward`` takes the argument list of
     :func:`pathway_tpu.models.decoder.paged_mixed_step` with the family's
-    cache arrays for the K/V pools and its one per-row array (arena slots,
-    or window tables) after ``logit_idx``, then ``valid`` (T,) which tokens
-    are real and, where ``row_live``, ``row_live`` (B,) which rows are;
-    ``decode=True`` where every row is one token at column 0.  It returns
-    ``(logits (B, V) f32, *cache arrays, counts)``: the expert layers'
-    counter vector of ``experts_counted(cfg)`` experts and
-    :data:`pathway_tpu.ops.moe.COUNTER_TAIL`.  A family passes a lambda that
-    names its ``_forward``, so that the function is found at trace time."""
+    cache arrays for the K/V pools and its ``per_row`` per-row arrays (arena
+    slots, or window tables, or both: the cache's ``row_extras``) after
+    ``logit_idx``, then ``valid`` (T,) which tokens are real and, where
+    ``row_live``, ``row_live`` (B,) which rows are; ``decode=True`` where
+    every row is one token at column 0.  It returns ``(logits (B, V) f32,
+    *cache arrays, counts)``: the expert layers' counter vector of
+    ``experts_counted(cfg)`` experts (none, for a family without expert
+    layers), :data:`pathway_tpu.ops.moe.COUNTER_TAIL` and the family's own
+    ``counters`` (names of the cache's stats; the cache folds them under
+    those names).  A family passes a lambda that names its ``_forward``, so
+    that the function is found at trace time."""
+    n = per_row
+
+    def split(args):
+        """``(cache arrays, the five decode arrays, per-row arrays)``."""
+        return args[:-5 - n], args[-5 - n:-n], args[-n:]
 
     def mixed(params: dict, cfg, *args, attn: str = "reference"):
         """The ragged fused step (decode rows and prompt chunks on one
@@ -159,7 +170,7 @@ def greedy_steps(forward, experts_counted, row_live: bool = False):
         (padding tokens point at row 0, column 0, which is another token's
         place); a row is real where its first token is its own."""
         (tokens, _positions, _tables, row_start, _nvalid, row_token_idx,
-         tok_row, tok_col) = args[-12:-4]
+         tok_row, tok_col) = args[-11 - n:-3 - n]
         T = tokens.shape[0]
         valid = row_token_idx[tok_row, tok_col] \
             == jnp.arange(T, dtype=jnp.int32)
@@ -175,8 +186,8 @@ def greedy_steps(forward, experts_counted, row_live: bool = False):
     def decode(params: dict, cfg, *args, attn: str = "reference"):
         """One token a row.  An idle row has the null block first in its
         tables and rides slot 0."""
-        *cache, token, positions, block_tables, slot_blocks, slot_offsets, \
-            per_row = args
+        cache, (token, positions, block_tables, slot_blocks,
+                slot_offsets), extras = split(args)
         B = token.shape[0]
         rows = jnp.arange(B, dtype=jnp.int32)
         # where the mask goes among the operations shows in the lowered
@@ -188,7 +199,7 @@ def greedy_steps(forward, experts_counted, row_live: bool = False):
         masks = (live, live) if row_live else (block_tables[:, 0] > 0,)
         return forward(
             params, cfg, *cache, token, positions, block_tables, *index,
-            slot_blocks, slot_offsets, rows, per_row, *masks, attn=attn,
+            slot_blocks, slot_offsets, rows, *extras, *masks, attn=attn,
             decode=True)
 
     def chained(params: dict, cfg, *args, attn: str = "reference"):
@@ -199,8 +210,8 @@ def greedy_steps(forward, experts_counted, row_live: bool = False):
         scan.  Returns ``(ids (B, K), *cache arrays, counts)``."""
         from ..ops.moe import COUNTER_TAIL
 
-        *cache, token, positions, block_tables, slot_blocks, slot_offsets, \
-            per_row = args
+        cache, (token, positions, block_tables, slot_blocks,
+                slot_offsets), extras = split(args)
         K = slot_blocks.shape[1]
         maxp = cfg.max_len - 1
 
@@ -209,13 +220,13 @@ def greedy_steps(forward, experts_counted, row_live: bool = False):
             sb, so, t = xs
             logits, *arrays, n_tok = decode(
                 params, cfg, *arrays, tok, jnp.minimum(positions + t, maxp),
-                block_tables, sb, so, per_row, attn=attn)
+                block_tables, sb, so, *extras, attn=attn)
             ids = greedy_ids(logits)
             return (ids, *arrays, cnt + n_tok), ids
 
         init = (token.astype(jnp.int32), *cache,
-                jnp.zeros((experts_counted(cfg) + len(COUNTER_TAIL),),
-                          jnp.int32))
+                jnp.zeros((experts_counted(cfg) + len(COUNTER_TAIL)
+                           + len(counters),), jnp.int32))
         (_last, *cache, counts), ids = jax.lax.scan(
             body, init, (slot_blocks.T, slot_offsets.T,
                          jnp.arange(K, dtype=jnp.int32)))

@@ -12,6 +12,8 @@ and gives the engine:
   and its geometry (the K/V pool's layers and heads are the family's to
   say; ``round_tokens``: the most one round adds to a sequence, which
   sizes a windowed cache's second pool);
+- ``attn_v_heads(cfg)``: the paged kernels' ``v_heads`` (a differential
+  family's value heads, shared by a pair of key heads), None elsewhere;
 - ``programs(cfg, attn, mesh, sampled=False)``: the table of step
   programs ``step``, ``mixed``, ``chained`` as ``(function, donated
   argument numbers)``, each ``(params, *cache arrays, *host arrays) ->
@@ -28,8 +30,10 @@ and gives the engine:
 A family is the only place that names a model's programs: the engine
 imports none of models/decoder.py, models/lfm2.py, models/afmoe.py,
 models/kimi_linear.py, models/qwen3_next.py, models/mimo_v2_flash.py,
-models/blocks.py.  The five routed families share one step contract
-(:class:`_RoutedFamily` over :func:`pathway_tpu.models.blocks.greedy_steps`).
+models/phi4_mini_flash.py, models/blocks.py.  The six routed families share
+one step contract (:class:`_RoutedFamily` over
+:func:`pathway_tpu.models.blocks.greedy_steps`); five of them have expert
+layers, and ``phi4_mini_flash`` has none (its programs count zero experts).
 The function names ``_step_fn`` / ``_mixed_fn`` / ``_chained_fn`` are the
 device trace's (``jit__mixed_fn`` on ``XLA Modules``): the benchmark's
 readers find the programs by them, for every family alike.
@@ -71,6 +75,12 @@ class DecoderFamily:
     def cache_kwargs(cfg, max_batch_size: int, round_tokens: int) -> dict:
         return {"n_layers": cfg.n_layers, "n_heads": cfg.n_heads,
                 "head_dim": cfg.d_model // cfg.n_heads}
+
+    @staticmethod
+    def attn_v_heads(cfg) -> int | None:
+        """The value heads the paged kernels' ``v_heads`` names, for the
+        engine's layout of a call; None: a value head a key head."""
+        return None
 
     @staticmethod
     def unsupported(**asked) -> None:
@@ -152,7 +162,8 @@ class DecoderFamily:
 # a routed cache kind: the prefix of its step functions' names, and its
 # device arrays
 _STEP_CONTRACT = {"hybrid": ("hybrid", 3), "windowed": ("windowed", 4),
-                  "latent_state": ("state", 3), "kv_state": ("kv_state", 4)}
+                  "latent_state": ("state", 3), "kv_state": ("kv_state", 4),
+                  "window_state": ("window_state", 6)}
 
 
 class _RoutedFamily:
@@ -162,13 +173,15 @@ class _RoutedFamily:
     every cache array donated.  A subclass declares its ``name``,
     ``cache_kind``, ``cache_kwargs``, ``scan_items`` where it has a chunked
     scan, and ``refusals``: why it has no tensor parallelism, no speculative
-    drafting and no host tiering."""
+    drafting and no host tiering (and, where it has no expert layer, no
+    quantized plan: ``quantize``)."""
 
     name: str
     cache_kind: str
     refusals: dict
     greedy_only = True
     tensor_parallel = False
+    attn_v_heads = staticmethod(DecoderFamily.attn_v_heads)
 
     @classmethod
     def _model(cls):
@@ -184,8 +197,9 @@ class _RoutedFamily:
         _refuse(cls.name, (
             (f"tensor parallelism (tp > 1): {why['tp']}",
              tp is not None and tp > 1),
-            (f"quantize={quantize!r}: no quantized plan of the expert "
-             "weights", quantize is not None),
+            (f"quantize={quantize!r}: "
+             + why.get("quantize", "no quantized plan of the expert weights"),
+             quantize is not None),
             (f"speculative drafting: {why['speculative']}",
              speculative not in (None, False)),
             (f"host tiering (session_store): {why['session_store']}",
@@ -359,9 +373,63 @@ class MimoV2FlashFamily(AfmoeFamily):
                 "window_heads": cfg.window_kv_heads}
 
 
+class Phi4MiniFlashFamily(_RoutedFamily):
+    """models/phi4_mini_flash.py: Mamba selective-scan layers and
+    sliding-window differential attention, one full layer whose K/V the
+    cross-decoder's attention reads, gated memory units, SwiGLU and no
+    expert layer, on the window-and-state cache: the full layer's K/V in
+    the paged pool (one layer), the window layers' in the window pool, the
+    Mamba layers' carried conv inputs and f32 state in a slot a sequence.
+    Its step programs run the cross-decoder and the head over the rows'
+    emitting tokens alone, and count on the device the rows each decoder
+    ran (``COUNTERS`` of its module, folded by the cache).  Beyond what
+    :meth:`unsupported` refuses, the cache kind runs without a prefix cache
+    and without fork (a shared block may be freed behind a window, and
+    would skip the tokens that build the scan state), and a sampled request
+    fails alone (``greedy_only``)."""
+
+    name = "phi4_mini_flash"
+    cache_kind = "window_state"
+    refusals = {"tp": "the window pool, the scan arena and the differential "
+                      "heads have no sharded layout",
+                "quantize": "no quantized plan of its weights",
+                "speculative": "a rejected draft would have to roll the scan "
+                               "state back",
+                "session_store": "a resumed block skips the tokens that "
+                                 "build the scan state, and a suspended "
+                                 "sequence has lost its window's keys"}
+
+    @classmethod
+    def cache_kwargs(cls, cfg, max_batch_size: int,
+                     round_tokens: int) -> dict:
+        return {"n_layers": len(cfg.full_layers), "n_heads": cfg.n_kv_heads,
+                "head_dim": cfg.head_dim, "window": cfg.sliding_window,
+                "window_layers": len(cfg.window_layers),
+                "round_tokens": round_tokens, "max_seqs": max_batch_size,
+                "conv_layers": len(cfg.mamba_layers),
+                "conv_width": cfg.d_inner, "conv_taps": cfg.d_conv - 1,
+                "conv_slots": max_batch_size, "state_heads": 1,
+                "state_dk": cfg.d_state, "state_dv": cfg.d_inner,
+                "counters": cls._model().COUNTERS}
+
+    @staticmethod
+    def scan_items(cfg) -> tuple:
+        """As :meth:`KimiLinearFamily.scan_items`: the selective scan's
+        items (ops/ssm.py)."""
+        from ..ops.ssm import CHUNK
+
+        return CHUNK, cfg.d_inner
+
+    @staticmethod
+    def attn_v_heads(cfg) -> int:
+        """The value heads every key head's pair shares (the paged
+        kernels' ``v_heads``), for the engine's layout of a call."""
+        return cfg.v_heads
+
+
 _FAMILIES = {f.name: f for f in (DecoderFamily, Lfm2Family, AfmoeFamily,
                                  KimiLinearFamily, Qwen3NextFamily,
-                                 MimoV2FlashFamily)}
+                                 MimoV2FlashFamily, Phi4MiniFlashFamily)}
 
 
 def step_family(cfg):

@@ -553,6 +553,56 @@ def config_from_mimo_v2_flash(hf_config, *, max_len: int | None = None,
     )
 
 
+def config_from_phi4flash(hf_config, *, max_len: int | None = None,
+                          dtype="auto", d_state: int = 16, d_conv: int = 4,
+                          expand: int = 2, dt_rank: int | None = None):
+    """``phi4flash`` config (microsoft/Phi-4-mini-flash-reasoning's
+    ``config.json`` keys) -> :class:`~pathway_tpu.models.phi4_mini_flash
+    .Phi4MiniFlashConfig`.  The Mamba sizes are not keys of the config but
+    the modeling code's own (``d_state`` 16, ``d_conv`` 4, ``expand`` 2,
+    ``dt_rank = ceil(hidden_size / 16)``), given here; ``head_dim`` is
+    ``hidden_size / num_attention_heads``.  ``max_len`` caps the served
+    context below ``max_position_embeddings``.
+
+    What the keys can say and this family has not written down is refused:
+    an untied head, biases in the feed-forward or the head, another
+    activation than SiLU, a Mamba layer other than every second one."""
+    from .phi4_mini_flash import Phi4MiniFlashConfig
+
+    def get(name, default=None):
+        return getattr(hf_config, name, default)
+
+    if get("model_type") != "phi4flash":
+        raise ValueError(
+            f"expected a phi4flash config, got model_type="
+            f"{get('model_type')!r}")
+    refused = [what for what, bad in (
+        ("tie_word_embeddings false", not get("tie_word_embeddings", True)),
+        ("mlp_bias", bool(get("mlp_bias", False))),
+        ("lm_head_bias", bool(get("lm_head_bias", False))),
+        (f"hidden_act {get('hidden_act')!r}",
+         get("hidden_act", "silu") != "silu"),
+        ("mb_per_layer other than 2", int(get("mb_per_layer", 2)) != 2),
+    ) if bad]
+    if refused:
+        raise ValueError("the phi4_mini_flash family does not support "
+                         + "; ".join(refused))
+    d = int(hf_config.hidden_size)
+    n_heads = int(hf_config.num_attention_heads)
+    positions = int(hf_config.max_position_embeddings)
+    return Phi4MiniFlashConfig(
+        vocab_size=int(hf_config.vocab_size), d_model=d,
+        n_layers=int(hf_config.num_hidden_layers), n_heads=n_heads,
+        n_kv_heads=int(hf_config.num_key_value_heads),
+        head_dim=d // n_heads, d_ff=int(hf_config.intermediate_size),
+        sliding_window=int(hf_config.sliding_window), mb_per_layer=2,
+        d_state=d_state, d_conv=d_conv, expand=expand,
+        dt_rank=-(-d // 16) if dt_rank is None else dt_rank,
+        norm_eps=float(get("layer_norm_eps", 1e-5)),
+        max_len=positions if max_len is None else min(max_len, positions),
+        dtype=dtype)
+
+
 def params_from_lfm2_state_dict(state: dict[str, Any], cfg) -> dict:
     """Map a (torch) LFM2-family state dict onto
     :mod:`pathway_tpu.models.lfm2`'s parameter pytree, in ``cfg``'s dtype.

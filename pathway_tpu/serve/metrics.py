@@ -305,6 +305,12 @@ class KVCacheStats:
         self.moe_row_tiles = 0
         self.moe_experts_touched = 0
         self.moe_expert_passes = 0
+        # a family whose later layers run on the rows' emitting tokens alone
+        # (models/phi4_mini_flash.py COUNTERS): the rows its mixed steps ran
+        # those layers over, and the packed tokens they ran the layers below
+        # over, counted on the device
+        self.cross_decoder_rows = 0
+        self.self_decoder_rows = 0
         # state caches (kvcache/hybrid.py StateCache): the matrix-state
         # slots beside the conv ones, the first chunks that started a state
         # from zero, and the pairs routed to experts held elsewhere
@@ -380,7 +386,7 @@ class KVCacheStats:
             for e, n in enumerate(counts):
                 self.moe_tokens_per_expert[e] += int(n)
             self.moe_routed_pairs += int(sum(int(n) for n in counts))
-            self.moe_fullest_expert_tokens += int(max(counts))
+            self.moe_fullest_expert_tokens += int(max(counts, default=0))
 
     def record_prefix_hit(self, n: int = 1) -> None:
         with self._lock:
@@ -607,6 +613,8 @@ class KVCacheStats:
                 "moe_row_tiles": self.moe_row_tiles,
                 "moe_experts_touched": self.moe_experts_touched,
                 "moe_expert_passes": self.moe_expert_passes,
+                "cross_decoder_rows": self.cross_decoder_rows,
+                "self_decoder_rows": self.self_decoder_rows,
                 "state_slots_in_use": self.state_slots_in_use,
                 "state_slots_total": self.state_slots_total,
                 "kda_state_resets": self.kda_state_resets,

@@ -1339,3 +1339,135 @@ def test_mimo_packed_step_programs_lower_under_their_names(
     temp = compiled.memory_analysis().temp_size_in_bytes
     print(f"mimo {program}: temporaries {temp / 2**20:.1f} MiB")
     assert temp < 1200 << 20
+
+
+# -- the phi4_mini_flash block family (Phi-4-mini-flash-reasoning's widths) --
+
+
+def test_mamba_kernels_compile_at_the_published_widths(shape):
+    """The selective scan's two kernels at 5,120 channels of 16 states, the
+    arena of 9 Mamba layers and 16 rows, and the items of a step of 16 rows
+    + the chunk of 128 the rule gives the whole model on one chip
+    (``obs.memory.choose_engine_config``): the temporaries each call asks
+    of HBM beside its operands are reported."""
+    import jax.numpy as jnp
+
+    from pathway_tpu.ops import ssm
+
+    f32, bf, i32 = jnp.float32, jnp.bfloat16, jnp.int32
+    NW, n, E, N = ssm.n_items(16 + 128, 16), ssm.CHUNK, 5120, 16
+    arena = shape((9, 17, 1, N, E), f32)
+    tok = shape((NW, n, E), f32)
+    chunk = _compiled_kernel(
+        ssm._mamba_chunk_fn, tok, tok, shape((NW, n, N), f32),
+        shape((NW, n, N), f32), shape((N, E), f32), arena,
+        shape((1,), i32), shape((NW,), i32), shape((NW,), i32),
+        donate_argnums=(5,))
+    lane, col = shape((16, 1, E), f32), shape((16, N, 1), f32)
+    step = _compiled_kernel(
+        ssm._mamba_step_fn, lane, lane, col, col, shape((N, E), f32), arena,
+        shape((1,), i32), shape((16,), i32), shape((16,), i32),
+        donate_argnums=(5,))
+    for name, c in (("chunk", chunk), ("step", step)):
+        mem = c.memory_analysis()
+        print(f"_mamba_{name}_fn: {NW if name == 'chunk' else 16} rows, "
+              f"temporaries {mem.temp_size_in_bytes / 2 ** 20:.1f} MiB, "
+              f"operands {mem.argument_size_in_bytes / 2 ** 20:.1f} MiB")
+        assert mem.temp_size_in_bytes < 64 << 20
+        assert _pool_copies(c, arena.shape) == []
+    assert bf  # the chunk's inputs are f32: the kernel reads no bf16
+
+
+@pytest.mark.parametrize("C", [1, 16], ids=["decode", "piece"])
+def test_differential_kernels_compile_at_the_published_widths(shape, C):
+    """The ragged and append kernels with ``v_heads``: 40 query heads of 64
+    on 20 key heads, 10 value heads of 128 shared by both key halves, the
+    full layer's pool of one layer; a cross-attention row (one column) and a
+    piece of 16 columns."""
+    import jax.numpy as jnp
+
+    pa = _paged()
+    bf, i32 = jnp.bfloat16, jnp.int32
+    pool = shape((1, 8193, BS, 1280), bf)
+    idx = (shape((1,), i32), shape((B, 512), i32), shape((B,), i32),
+           shape((B,), i32))
+    q = shape((B, C, 40, 64), bf)
+    compiled = _compiled_kernel(
+        lambda q, kp, vp, li, bt, c0, cl: pa._paged_ragged_fn(
+            q, kp, vp, li, bt, c0, cl, d_true=64, v_heads=10),
+        q, pool, pool, *idx)
+    assert _pool_copies(compiled, pool.shape) == []
+    if C == 1:
+        new = (shape((B, 20, 64), bf), shape((B, 10, 128), bf))
+        compiled = _compiled_kernel(
+            lambda q, kn, vn, kp, vp, li, bt, c0, cl, so: pa._paged_append_fn(
+                q, kn, vn, kp, vp, li, bt, c0, cl, so, d_true=64, v_heads=10),
+            q, *new, pool, pool, *idx, shape((B,), i32),
+            donate_argnums=(3, 4))
+        assert _pool_copies(compiled, pool.shape) == []
+
+
+@pytest.mark.parametrize("program", ["mixed", "chained"])
+def test_phi4_packed_step_programs_lower_under_their_names(
+        shape, monkeypatch, program):
+    """The family's mixed and chained programs as the engine jits them at
+    the published widths and the chosen chunk of 128, eight layers deep (the
+    stack's pattern: Mamba, window, Mamba, window, the memory Mamba, the
+    full layer, a memory unit, a cross layer): the kernels carry the names
+    the benchmark's readers search the device trace for, the six cache
+    arrays are donated and not copied, and the temporaries are reported."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.kvcache.packing import RoundLayout
+    from pathway_tpu.models import families, phi4_mini_flash as m
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fam = families.Phi4MiniFlashFamily
+    cfg = m.Phi4MiniFlashConfig(n_layers=8, max_len=8192,
+                                dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(lambda: fam.plan(
+        cfg, m.init_phi4_mini_flash_params(cfg, jax.random.PRNGKey(0)),
+        tp=1, quantize=None))
+    params = jax.tree_util.tree_map(lambda s: shape(s.shape, s.dtype), shapes)
+    kw = fam.cache_kwargs(cfg, 16, 128)
+    assert (kw["conv_layers"], kw["window_layers"], kw["n_layers"]) \
+        == (3, 2, 1)
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    state = (shape((1, 8193, BS, 1280), bf), shape((1, 8193, BS, 1280), bf),
+             shape((2, 673, BS, 1280), bf), shape((2, 673, BS, 1280), bf),
+             shape((3, 17, 3, 5120), bf), shape((3, 17, 1, 16, 5120), f32))
+    rows, chunk, tables = 16, 128, 512
+
+    def vec(*dims):
+        return shape(dims, i32)
+
+    T = rows + chunk
+    host = {"mixed": (vec(T), vec(T), vec(rows, tables), vec(rows),
+                      vec(rows), vec(rows, chunk), vec(T), vec(T), vec(T),
+                      vec(T), vec(rows), vec(rows, tables), vec(rows)),
+            "chained": (vec(rows), vec(rows), vec(rows, tables),
+                        vec(rows, 16), vec(rows, 16), vec(rows, tables),
+                        vec(rows))}[program]
+    fn, donated = fam.programs(cfg, "pallas", None)[program]
+    assert tuple(donated) == (1, 2, 3, 4, 5, 6)
+    layout = RoundLayout(host)
+    lowered = jax.jit(layout.program(fn), donate_argnums=donated).lower(
+        params, *state, shape((layout.size,), i32))
+    text = lowered.as_text()
+    assert re.search(r"module @(\w+)", text).group(1) == f"jit__{program}_fn"
+    kernels = {re.sub(r"_\d+$", "", f) for f in re.findall(
+        r"func\.func \w+ @(_(?:paged|mamba)_\w+)\(", text)}
+    assert kernels == ({"_paged_ragged_fn", "_paged_write_fn",
+                        "_mamba_chunk_fn", "_mamba_step_fn"}
+                       if program == "mixed" else
+                       {"_paged_append_fn", "_paged_ragged_fn",
+                        "_mamba_step_fn"})
+    compiled = lowered.compile()
+    for pool in state:
+        assert _pool_copies(compiled, pool.shape) == []
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"phi4 {program}: temporaries {temp / 2**20:.1f} MiB")
+    assert temp < 1200 << 20

@@ -299,7 +299,7 @@ def test_it_serves_through_the_scheduler_on_the_windowed_kind(cfg, params,
     from pathway_tpu.models import families
     from pathway_tpu.serve.scheduler import RequestScheduler
 
-    assert len(families._FAMILIES) == 6
+    assert len(families._FAMILIES) == 7  # PR 45: phi4_mini_flash
     assert families.step_family(cfg).cache_kind == "windowed"
     eng = _engine(cfg, params, "t_mimo_sched")
     assert eng.pool.cache_kind == "windowed" and eng.prefix is None

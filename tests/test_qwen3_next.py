@@ -540,7 +540,7 @@ def test_a_sampled_request_fails_alone_and_five_families_are_known(cfg):
 
     assert families.step_family(cfg) is families.Qwen3NextFamily
     assert families.Qwen3NextFamily.greedy_only
-    assert len(families._FAMILIES) == 6  # the sixth: PR 40
+    assert len(families._FAMILIES) == 7  # the sixth: PR 40; the seventh: PR 45
     with pytest.raises(ValueError, match="decodes greedily"):
         families.Qwen3NextFamily.programs(cfg, "reference", None,
                                           sampled=True)
