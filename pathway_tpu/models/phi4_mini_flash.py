@@ -121,10 +121,19 @@ class Phi4MiniFlashConfig:
         return self.expand * self.d_model
 
     @property
+    def rows_only_layers(self) -> tuple:
+        """The cross-decoder (its memory units and cross-attention layers):
+        the layers a mixed step runs over the rows' emitting tokens alone
+        (:func:`_forward`), which the chunk rule bills on the batch's rows
+        (:func:`pathway_tpu.obs.memory.step_flops`)."""
+        return tuple(i for i, t in enumerate(self.layer_types)
+                     if t in (GMU, CROSS))
+
+    @property
     def n_self(self) -> int:
         """Layers every token runs: the self-decoder, the memory layer and
-        the full layer."""
-        return self.n_layers // 2 + 2
+        the full layer, below the cross-decoder."""
+        return self.n_layers - len(self.rows_only_layers)
 
     @property
     def memory_layer(self) -> int:
@@ -413,7 +422,7 @@ def _forward(params: dict, cfg: Phi4MiniFlashConfig, k_pool, v_pool, kw_pool,
     # its own position over the full layer's pool as this step left it
     xr = x[logit_idx]                                          # (B, D)
     at = jnp.where(row_live, positions[logit_idx], 0)  # idle: one key
-    for li in range(cfg.n_self, cfg.n_layers):
+    for li in cfg.rows_only_layers:
         kind, lay = cfg.layer_types[li], params["layers"][li]
         h = _layer_norm(xr, lay, "ln1", eps, dtype)
         if kind == GMU:

@@ -489,9 +489,12 @@ _CHAIN_LADDER = (16, 8, 4, 1)
 # prefill chunk rungs (tokens; rounded up to whole blocks).  The top and
 # the ridge share below are fixed from one sweep of 64 / 128 / 256 / 512 in
 # serve_closed16 and lfm2_closed16 (PERF.md section 6, PR 34)
+# and checked by one of 128 / 256 / 512 in phi4flash_closed16_longshort, the
+# first cell whose bill has rows-only layers (PERF.md section 6)
 _CHUNK_LADDER = (512, 256, 128, 64, 32)
 # the share of the step's streaming time its matmuls may fill: at 1.0 a
-# step of max_batch_size + chunk tokens sits on the chip's ridge
+# step of max_batch_size rows and a chunk of tokens (:func:`step_flops`)
+# sits on the chip's ridge
 _CHUNK_RIDGE_SHARE = 1.0
 
 
@@ -517,29 +520,42 @@ def resolve_roof(explicit: dict | None = None) -> tuple[dict | None, str]:
     return None, roof["source"]
 
 
-def step_flops_per_token(cfg, params, *, tp: int = 1) -> int:
-    """Matmul FLOPs one token costs a step, per shard, from the decode
-    plan's own leaves as the ledger bills them: 2 an element of every leaf
-    of two axes or more; a routed expert layer (three axes, as many
-    leading entries as the configuration holds experts) at the share of
-    its experts a token is routed to.  Without the pytree, twice the
-    ledger's parameter count."""
-    if params is None:
-        return 2 * _params_bytes(cfg, None, tp, 1)  # a byte a parameter
+def step_flops(cfg, params, *, rows: int, tokens: int, tp: int = 1) -> int:
+    """Matmul FLOPs of a step of ``rows`` rows and ``tokens`` prompt tokens,
+    per shard, from the decode plan's own leaves as the ledger bills them:
+    2 an element of every leaf of two axes or more; a routed expert layer
+    (three axes, as many leading entries as the configuration holds
+    experts) at the share of its experts a token is routed to.  Every leaf
+    is billed on the rows; on the tokens, all but the leaves of the layers
+    the configuration's ``rows_only_layers`` names (``params["layers"][i]``:
+    a cross-decoder the mixed program runs over the rows' emitting tokens
+    alone).  With none declared, ``(rows + tokens) x`` the bill of one
+    token.  Without the pytree, twice the ledger's parameter count a row
+    or token."""
+    if params is None:  # a byte a parameter
+        return (rows + tokens) * 2 * _params_bytes(cfg, None, tp, 1)
     import jax
 
     # the experts whose matrices the plan holds (a share of an
     # expert-parallel deployment holds fewer than the router chooses among)
     held = getattr(cfg, "held_experts", getattr(cfg, "n_experts", 0))
     share = getattr(cfg, "top_k", 0) / max(getattr(cfg, "n_experts", held), 1)
-    flops = 0.0
-    for leaf in jax.tree_util.tree_leaves(params):
-        shape = getattr(leaf, "shape", ())
-        if len(shape) < 2:
-            continue
-        routed = held and len(shape) == 3 and shape[0] == held
-        flops += 2.0 * leaf.size * (share if routed else 1.0)
-    return int(flops // max(tp, 1))
+
+    def bill(tree) -> float:
+        flops = 0.0
+        for leaf in jax.tree_util.tree_leaves(tree):
+            shape = getattr(leaf, "shape", ())
+            if len(shape) < 2:
+                continue
+            routed = held and len(shape) == 3 and shape[0] == held
+            flops += 2.0 * leaf.size * (share if routed else 1.0)
+        return flops
+
+    every = bill(params)
+    rows_only = bill([params["layers"][i]
+                      for i in getattr(cfg, "rows_only_layers", ())])
+    tp = max(tp, 1)
+    return rows * int(every // tp) + tokens * int((every - rows_only) // tp)
 
 
 def choose_engine_config(cfg, *, params=None, tp: int = 1, dtype=None,
@@ -574,11 +590,14 @@ def choose_engine_config(cfg, *, params=None, tp: int = 1, dtype=None,
       pool at that chunk, and the pool at full coverage; (2) the rung is
       no wider than the prompt cap (the largest of ``seq_buckets``, or
       ``cfg.max_len``); (3) the step stays on the bytes side of the chip's
-      ridge: ``(max_batch_size + rung) x`` :func:`step_flops_per_token`
-      over the roof's peak is no more than the bytes a step streams (the
-      ledger's ``params_bytes``) over its bandwidth, so the chunk rides on
-      weights the step reads anyway, and a plan of fewer bytes (int8,
-      bf16) gets a narrower chunk than an f32 one of the same shapes.
+      ridge: :func:`step_flops` of ``max_batch_size`` rows and the rung's
+      tokens (every leaf on the rows; the layers a configuration runs over
+      its rows' emitting tokens alone, its ``rows_only_layers``, on the
+      rows only) over the roof's peak is no more than the bytes a step
+      streams (the ledger's ``params_bytes``) over its bandwidth, so the
+      chunk rides on weights the step reads anyway, and a plan of fewer
+      bytes (int8, bf16) gets a narrower chunk than an f32 one of the same
+      shapes.
       Where no budget or no roof resolves (:func:`resolve_roof`: the CPU),
       or no rung passes, two blocks, reported as a default.
 
@@ -692,18 +711,25 @@ def _choose_chunk(cfg, plan: HbmPlan, fits, *, params, covered: bool,
             "full coverage: no bytes to spare for a wider step)"
     cap = min(max(seq_buckets), cfg.max_len) if seq_buckets \
         else cfg.max_len
-    token_s = step_flops_per_token(cfg, params, tp=plan.tp) / roof["peak"]
+    b, peak = plan.max_batch_size, roof["peak"]
+
+    def step_s(rows: int, tokens: int) -> float:
+        return step_flops(cfg, params, rows=rows, tokens=tokens,
+                          tp=plan.tp) / peak
+
+    row_s, token_s = step_s(1, 0), step_s(0, 1)
+    # the rows-only layers billed on the batch's rows alone, where declared
+    bill = f"{b} + " if row_s == token_s else f"{b} rows x " \
+        f"{row_s * 1e6:.2f} us + "
     stream_s = _CHUNK_RIDGE_SHARE * plan.params_bytes / roof["membw"]
     for rung in _CHUNK_LADDER:
         rung = -(-rung // bs) * bs
         if rung <= two_blocks:
             break
-        if rung <= cap and fits(rung) \
-                and (plan.max_batch_size + rung) * token_s <= stream_s:
+        if rung <= cap and fits(rung) and step_s(b, rung) <= stream_s:
             return rung, (
-                f"ridge: {plan.max_batch_size} + {rung} tokens x "
-                f"{token_s * 1e6:.2f} us of matmuls <= "
-                f"{stream_s * 1e3:.2f} ms of streamed weights "
+                f"ridge: {bill}{rung} tokens x {token_s * 1e6:.2f} us of "
+                f"matmuls <= {stream_s * 1e3:.2f} ms of streamed weights "
                 f"({roof_source}); prompt cap {cap}; ledger fits")
     return two_blocks, "default: two blocks (no wider rung passes the " \
         "ledger, the prompt cap and the ridge)"

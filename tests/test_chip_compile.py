@@ -1347,15 +1347,16 @@ def test_mimo_packed_step_programs_lower_under_their_names(
 def test_mamba_kernels_compile_at_the_published_widths(shape):
     """The selective scan's two kernels at 5,120 channels of 16 states, the
     arena of 9 Mamba layers and 16 rows, and the items of a step of 16 rows
-    + the chunk of 128 the rule gives the whole model on one chip
-    (``obs.memory.choose_engine_config``): the temporaries each call asks
-    of HBM beside its operands are reported."""
+    + the chunk of 256 the rule gives the whole model on one chip
+    (``obs.memory.choose_engine_config``, the cross-decoder billed on the
+    rows): the temporaries each call asks of HBM beside its operands are
+    reported."""
     import jax.numpy as jnp
 
     from pathway_tpu.ops import ssm
 
     f32, bf, i32 = jnp.float32, jnp.bfloat16, jnp.int32
-    NW, n, E, N = ssm.n_items(16 + 128, 16), ssm.CHUNK, 5120, 16
+    NW, n, E, N = ssm.n_items(16 + 256, 16), ssm.CHUNK, 5120, 16
     arena = shape((9, 17, 1, N, E), f32)
     tok = shape((NW, n, E), f32)
     chunk = _compiled_kernel(
@@ -1411,7 +1412,7 @@ def test_differential_kernels_compile_at_the_published_widths(shape, C):
 def test_phi4_packed_step_programs_lower_under_their_names(
         shape, monkeypatch, program):
     """The family's mixed and chained programs as the engine jits them at
-    the published widths and the chosen chunk of 128, eight layers deep (the
+    the published widths and the chosen chunk of 256, eight layers deep (the
     stack's pattern: Mamba, window, Mamba, window, the memory Mamba, the
     full layer, a memory unit, a cross layer): the kernels carry the names
     the benchmark's readers search the device trace for, the six cache
@@ -1432,14 +1433,14 @@ def test_phi4_packed_step_programs_lower_under_their_names(
         cfg, m.init_phi4_mini_flash_params(cfg, jax.random.PRNGKey(0)),
         tp=1, quantize=None))
     params = jax.tree_util.tree_map(lambda s: shape(s.shape, s.dtype), shapes)
-    kw = fam.cache_kwargs(cfg, 16, 128)
+    kw = fam.cache_kwargs(cfg, 16, 256)
     assert (kw["conv_layers"], kw["window_layers"], kw["n_layers"]) \
         == (3, 2, 1)
     bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
     state = (shape((1, 8193, BS, 1280), bf), shape((1, 8193, BS, 1280), bf),
-             shape((2, 673, BS, 1280), bf), shape((2, 673, BS, 1280), bf),
+             shape((2, 801, BS, 1280), bf), shape((2, 801, BS, 1280), bf),
              shape((3, 17, 3, 5120), bf), shape((3, 17, 1, 16, 5120), f32))
-    rows, chunk, tables = 16, 128, 512
+    rows, chunk, tables = 16, 256, 512
 
     def vec(*dims):
         return shape(dims, i32)

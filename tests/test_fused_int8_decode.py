@@ -349,7 +349,66 @@ def test_chunk_follows_the_bytes_a_step_streams():
     cfg, plan, _d, _r = _choose("lfm2")
     dense = 2 * sum(w.size for w in jax.tree_util.tree_leaves(plan)
                     if w.ndim >= 2)
-    assert obs_memory.step_flops_per_token(cfg, plan) < 0.3 * dense
+    assert obs_memory.step_flops(cfg, plan, rows=0, tokens=1) < 0.3 * dense
+
+
+def _token_flops(cfg, tree) -> float:
+    """A token's matmul FLOPs over ``tree``'s leaves, the ledger's way: 2
+    an element of a leaf of two axes or more, a held routed expert's at the
+    router's share."""
+    held = getattr(cfg, "held_experts", getattr(cfg, "n_experts", 0))
+    share = getattr(cfg, "top_k", 0) / max(getattr(cfg, "n_experts", held), 1)
+    return sum(2.0 * w.size * (share if held and w.ndim == 3
+                               and w.shape[0] == held else 1.0)
+               for w in jax.tree_util.tree_leaves(tree) if w.ndim >= 2)
+
+
+# the rung and the reckoning the rule gives each configuration whose cell
+# leaves the chunk to it, under the described v5e; phi4_undeclared is
+# Phi-4-mini-flash with its cross-decoder billed on every token, as every
+# layer of the other four still is
+_WIDTHS = "of matmuls <= {} ms of streamed weights (explicit); prompt cap {}"
+_RIDGES = {
+    "gpt2_large_f32": (256, "16 + 256 tokens x 7.85 us", ("3.78", 1024)),
+    "lfm2": (512, "16 + 512 tokens x 9.21 us", ("11.25", 1024)),
+    "mimo": (512, "16 + 512 tokens x 26.55 us", ("15.91", 8192)),
+    "phi4": (256, "16 rows x 39.11 us + 256 tokens x 25.13 us",
+             ("9.41", 8192)),
+    "phi4_undeclared": (128, "16 + 128 tokens x 39.11 us", ("9.41", 8192)),
+}
+
+
+@pytest.mark.parametrize("name", list(_RIDGES))
+def test_the_ridge_bills_rows_only_layers_on_the_rows(name, monkeypatch):
+    """A step of B rows and C prompt tokens is billed every leaf on the B
+    rows and, on the C tokens, all but the layers the configuration runs
+    over its rows' emitting tokens alone (``rows_only_layers``: the
+    Phi-4-mini-flash cross-decoder, layers 18-31): 256 where every token
+    billed all 32 layers gives 128.  A configuration that declares none is
+    billed ``(B + C) x`` a token's whole bill, and keeps its rung and its
+    reckoning."""
+    from pathway_tpu.models import phi4_mini_flash
+
+    if name == "phi4_undeclared":
+        monkeypatch.setattr(phi4_mini_flash.Phi4MiniFlashConfig,
+                            "rows_only_layers", ())
+    cfg, plan, _d, res = _choose(name.split("_undeclared")[0])
+    chunk, bill, widths = _RIDGES[name]
+    assert res["prefill_chunk"] == chunk
+    assert res["chunk_source"] == f"ridge: {bill} " + _WIDTHS.format(
+        *widths) + "; ledger fits"
+    B = res["max_batch_size"]
+    rows_only = getattr(cfg, "rows_only_layers", ())
+    every = _token_flops(cfg, plan)
+    token = every - _token_flops(cfg, [plan["layers"][i] for i in rows_only])
+    assert obs_memory.step_flops(cfg, plan, rows=B, tokens=chunk) \
+        == B * int(every) + chunk * int(token)
+    if name == "phi4":
+        assert rows_only == tuple(range(18, 32)) and token < 0.65 * every
+    else:
+        assert not rows_only
+        assert obs_memory.step_flops(cfg, plan, rows=B, tokens=chunk) \
+            == (B + chunk) * int(every)
 
 
 @pytest.mark.parametrize("case", ["explicit", "no_budget", "no_roof",

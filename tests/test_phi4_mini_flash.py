@@ -386,12 +386,44 @@ def test_mixed_rows_at_different_phases(cfg, params):
     assert out[2] == want.argmax(-1).tolist()
 
 
+def _layer_matmul_rows(jaxpr, params) -> dict:
+    """``{layer: the leading sizes of the operands its matrices (its leaves
+    of two axes or more) multiply}`` in a jaxpr traced with ``params`` as
+    its first arguments, through the calls it nests."""
+    import jax
+    from jax.extend.core import Var
+
+    paths = jax.tree_util.tree_flatten_with_path(params)[0]
+    owner = {}
+    for var, (path, leaf) in zip(jaxpr.jaxpr.invars, paths):
+        if path[0].key == "layers" and leaf.ndim >= 2:  # as the rule bills
+            owner[var] = path[1].idx
+    rows: dict = {}
+
+    def walk(jx, owner):
+        for e in jx.eqns:
+            if e.primitive.name == "dot_general":
+                a, b = e.invars
+                for w, x in ((b, a), (a, b)):
+                    if w in owner:
+                        rows.setdefault(owner[w], set()).add(
+                            x.aval.shape[0])
+            for sub in jax.core.jaxprs_in_params(e.params):
+                walk(sub, {i: owner[o] for o, i in zip(e.invars, sub.invars)
+                           if isinstance(o, Var) and o in owner})
+
+    walk(jaxpr.jaxpr, owner)
+    return rows
+
+
 def test_the_skip_equals_a_forward_without_it():
     """The mixed program runs the cross-decoder on the B rows' emitting
     tokens: its memory units multiply (B, D) rows by their (D, E) gate,
-    never the stream's (T, D); and its logits at the token a row emits,
-    wherever in the row it stands, are those of the reference, which runs
-    every layer over every token."""
+    never the stream's (T, D), and the layers whose matrices take the B
+    rows, and no token of the stream, are exactly the configuration's
+    ``rows_only_layers``, which the chunk rule bills on the rows; and its
+    logits at the token a row emits, wherever in the row it stands, are
+    those of the reference, which runs every layer over every token."""
     import jax
     import jax.numpy as jnp
 
@@ -433,6 +465,15 @@ def test_the_skip_equals_a_forward_without_it():
     shapes = {tuple(e.invars[0].aval.shape) for e in gate}
     assert shapes == {(B, cfg.d_model)}
     assert len(gate) == cfg.layer_types.count(m.GMU)
+    by_layer = _layer_matmul_rows(jax.make_jaxpr(
+        lambda p, *a: m.window_state_mixed_step(p, cfg, *a))(
+            params, *cache, *host(C - 1)), params)
+    assert sorted(by_layer) == list(range(cfg.n_layers))
+    assert cfg.rows_only_layers == (6, 7)
+    assert {li for li, seen in by_layer.items() if seen == {B}} \
+        == set(cfg.rows_only_layers)
+    assert {li for li, seen in by_layer.items() if seen == {T}} \
+        == set(range(cfg.n_layers)) - set(cfg.rows_only_layers)
     ref = importlib.import_module("benchmark.reference.phi4_mini_flash_f32")
     want = np.asarray(ref.logits_at(params, _shape(cfg), prompt,
                                     np.arange(C)))

@@ -504,12 +504,12 @@ def test_hbm_plan_bills_the_pool_and_both_arenas_to_the_byte(cfg, params):
 
 
 def test_step_flops_count_the_held_experts_at_the_routers_share(cfg, params):
-    """``step_flops_per_token`` (the chunk rule's): a held expert's leaves
+    """``step_flops`` (the chunk rule's): a held expert's leaves
     at ``top_k / n_experts``, the router's width, whatever share is held."""
     from pathway_tpu.obs import memory
 
     plan = _engine(cfg, params, "t_q3n_flops").params
-    got = memory.step_flops_per_token(cfg, plan)
+    got = memory.step_flops(cfg, plan, rows=0, tokens=1)
     import jax
 
     want = 0.0

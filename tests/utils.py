@@ -403,14 +403,42 @@ def lfm2_feed(cfg, params, prompt, chunk, *, conv0=None, slot=1,
 
 
 def described_decode_plan(name: str):
-    """``(cfg, decode plan as shapes, dtype, engine keywords)`` of the
-    benchmark's two short-prompt configurations, nothing allocated:
-    ``gpt2_large_f32`` / ``gpt2_large_int8`` (the f32 plan as the chip
-    keeps it, the int8-resident one) and ``lfm2`` (LFM2-8B-A1B's published
-    layers 1-13 in bf16, batch 16 as its cell asks)."""
+    """``(cfg, decode plan as shapes, dtype, engine keywords)`` of a
+    benchmark configuration, nothing allocated: ``gpt2_large_f32`` /
+    ``gpt2_large_int8`` (the f32 plan as the chip keeps it, the
+    int8-resident one), ``lfm2`` (LFM2-8B-A1B's published layers 1-13 in
+    bf16, batch 16 as its cell asks), and ``mimo`` / ``phi4`` (the
+    ``mimo-v2-flash-serve`` / ``phi-4-mini-flash-reasoning-serve`` files
+    through their systems' own configuration, with the batch and the
+    ``seq_buckets`` their files give the engine)."""
     import jax
     import jax.numpy as jnp
 
+    if name in ("mimo", "phi4"):
+        import importlib
+        import json
+        import pathlib
+
+        from pathway_tpu.models import families
+
+        file, system, module = {
+            "mimo": ("mimo-v2-flash-serve", "serve_mimo_v2_flash",
+                     "mimo_v2_flash"),
+            "phi4": ("phi-4-mini-flash-reasoning-serve",
+                     "serve_phi4_mini_flash", "phi4_mini_flash")}[name]
+        root = pathlib.Path(__file__).resolve().parents[1]
+        config = json.loads(
+            (root / "benchmark" / "configs" / f"{file}.json").read_text())
+        cfg = importlib.import_module(
+            f"benchmark.systems.{system}").decoder_config(config)
+        init = getattr(importlib.import_module(
+            f"pathway_tpu.models.{module}"), f"init_{module}_params")
+        fam = families.step_family(cfg)
+        engine = config["engine"]
+        return cfg, jax.eval_shape(lambda: fam.plan(
+            cfg, init(cfg, jax.random.PRNGKey(0)), tp=1, quantize=None)), \
+            jnp.bfloat16, {"max_batch_size": engine["max_batch_size"],
+                           "seq_buckets": tuple(engine["seq_buckets"])}
     if name.startswith("gpt2_large"):
         from pathway_tpu.models import decoder
 
